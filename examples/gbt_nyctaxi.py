@@ -16,11 +16,6 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import jax  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import raydp_tpu  # noqa: E402
 import raydp_tpu.dataframe as rdf  # noqa: E402
 from data_process import nyc_taxi_preprocess, synthetic_taxi  # noqa: E402

@@ -1147,7 +1147,7 @@ class Cluster:
                         f"batch RPC to worker {worker_id} failed: {code} "
                         "(cluster is shutting down)"
                     ) from exc
-                # Same death taxonomy as submit_async: UNAVAILABLE /
+                # Same classes of death as submit_async: UNAVAILABLE /
                 # CANCELLED mean the worker is gone and the idempotent
                 # stage tasks may re-run elsewhere; anything else is a
                 # hard error.

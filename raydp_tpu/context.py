@@ -97,6 +97,9 @@ def init(
     reference: python/raydp/context.py:176-184).
     """
     global _session
+    from raydp_tpu.utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()  # the driver compiles the training steps
     with _lock:
         if _session is not None and not _session.stopped:
             raise RuntimeError(

@@ -65,7 +65,7 @@ class TransformerConfig:
     remat: bool = False              # checkpoint blocks (memory-bound fits)
     dtype: Any = jnp.bfloat16        # compute dtype (MXU-friendly)
     param_dtype: Any = jnp.float32
-    mesh: Any = None                 # required for ring/ulysses
+    mesh: Any = None                 # ring/ulysses; flash on >1 device
 
     @property
     def head_dim(self) -> int:
@@ -169,12 +169,20 @@ class MultiHeadAttention(nn.Module):
                 q, k, v, mesh=cfg.mesh, causal=cfg.causal
             )
         elif cfg.attention_impl == "flash":
-            from raydp_tpu.ops.flash_attention import flash_attention
-
-            out = flash_attention(
-                q, k, v, causal=cfg.causal,
-                interpret=jax.default_backend() == "cpu",
+            from raydp_tpu.ops.flash_attention import (
+                flash_attention,
+                sharded_flash_attention,
             )
+
+            # Mosaic-compiled, TPU only: off the chip this raises
+            # instead of quietly running the Pallas interpreter. On
+            # more than one device the config has to carry the mesh.
+            if cfg.mesh is not None:
+                out = sharded_flash_attention(
+                    q, k, v, mesh=cfg.mesh, causal=cfg.causal
+                )
+            else:
+                out = flash_attention(q, k, v, causal=cfg.causal)
         else:
             raise ValueError(
                 f"unknown attention_impl {cfg.attention_impl!r}"

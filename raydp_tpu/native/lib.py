@@ -9,8 +9,9 @@ across rows.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
-from typing import List, Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import numpy as np
 
@@ -25,6 +26,8 @@ _COL_TYPES = {
     np.dtype(np.uint8): 5,
 }
 
+logger = logging.getLogger(__name__)
+
 _lib = None
 _lib_tried = False
 
@@ -38,6 +41,10 @@ def _load() -> Optional[ctypes.CDLL]:
         return None
     path = build.ensure_built()
     if path is None:
+        logger.warning(
+            "native data-plane library did not build (no g++ or no "
+            "sources); gather and hash-partition run on the numpy twins"
+        )
         return None
     lib = ctypes.CDLL(path)
     lib.rdp_gather.argtypes = [
@@ -71,6 +78,19 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def native_status() -> Dict[str, object]:
+    """Which implementation this process uses: ``{"native", "path",
+    "built_here"}`` — ``built_here`` says whether this process compiled
+    the library itself or loaded one already built from the same
+    sources and flags."""
+    lib = _load()
+    return {
+        "native": lib is not None,
+        "path": lib._name if lib is not None else None,
+        "built_here": lib is not None and build.built_here,
+    }
 
 
 def gather_matrix(

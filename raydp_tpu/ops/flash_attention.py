@@ -12,8 +12,10 @@ logsumexp) — so training never materializes the S×S score matrix either,
 which is the whole long-context point (a dense-recompute backward would
 put an O(S²) cliff right back at seq 8k–16k).
 
-Falls back to interpret mode off-TPU (pallas guide: Debugging) so tests
-exercise identical code paths on the CPU mesh.
+The kernels are compiled by Mosaic and run on a TPU only; on any other
+backend the call raises. ``interpret=True`` (pallas guide: Debugging)
+runs the same kernel bodies in the Pallas interpreter — the tests pass
+it explicitly to check the math on the CPU mesh; no model code does.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -198,6 +201,41 @@ def flash_attention(
     Differentiable via custom_vjp; forward AND backward are blockwise
     pallas kernels (no S×S materialization anywhere)."""
     return _flash_vjp(q, k, v, causal, block_q, block_kv, interpret)
+
+
+def sharded_flash_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    mesh: Mesh,
+    causal: bool = False,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """:func:`flash_attention` on a device mesh.
+
+    XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so on more than one device the call
+    sits in a ``shard_map``: each device runs the kernel on its own
+    batch rows (``dp``) and heads (``tp``). Attention is independent
+    across both, so no collective is needed; the sequence stays whole
+    on every device (sequence parallelism is ring attention's job). A
+    dimension its mesh axis does not divide (the batch-1 sample of
+    ``model.init``) stays whole as well."""
+    def axis(name, size):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and size % n == 0 else None
+
+    spec = P(axis("dp", q.shape[0]), None, axis("tp", q.shape[2]), None)
+    return jax.shard_map(
+        functools.partial(
+            flash_attention, causal=causal, interpret=interpret
+        ),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        # pallas_call's out_shape carries no varying-axes annotation.
+        check_vma=False,
+    )(q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

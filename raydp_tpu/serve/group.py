@@ -22,6 +22,7 @@ HTTP frontend degrades to 429 + Retry-After.
 """
 from __future__ import annotations
 
+import glob
 import logging
 import os
 import random
@@ -53,6 +54,7 @@ from raydp_tpu.serve.replica_main import (
 )
 from raydp_tpu.telemetry import accounting as _acct
 from raydp_tpu.telemetry import events as _events
+from raydp_tpu.utils.compile_cache import cpu_requested
 from raydp_tpu.utils.profiling import metrics
 
 logger = logging.getLogger(__name__)
@@ -71,6 +73,14 @@ _REGISTER_TIMEOUT_S = 30.0
 
 class ServeError(RuntimeError):
     """Serving control-plane failure (spawn, registration, budget)."""
+
+
+def _tpu_chip_nodes() -> List[str]:
+    """Device nodes of the TPU chips this host exposes (vfio groups
+    from v5e on, ``/dev/accel*`` before). Reading ``/dev`` creates no
+    JAX backend — the driver has to stay off the chip its replica
+    needs."""
+    return glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*")
 
 
 class _ReplicaSlot:
@@ -427,6 +437,24 @@ class ReplicaGroup:
         has no capacity for the group."""
         if self._started:
             raise ServeError(f"replica group {self.label} already started")
+        if (
+            self.replicas > 1
+            and not cpu_requested()
+            and _tpu_chip_nodes()
+        ):
+            # Every replica is a process with this environment, and the
+            # first to create a TPU client takes every chip of the host
+            # (libtpu's lockfile); the rest would die right after
+            # registering. Say so now instead of serving on one lineage
+            # while the others burn their restart budget.
+            raise ServeError(
+                f"replica group {self.label}: {self.replicas} replicas "
+                "on a host with TPU chips, but nothing gives each "
+                "replica process a chip of its own, so only the first "
+                "could get one. Start one replica per host "
+                "(replicas=1), or set JAX_PLATFORMS=cpu for a model "
+                "that stays off the chip."
+            )
         self._stopping.clear()
         self._job_ctx = _acct.current_job()
         self._owns_job_ctx = self._job_ctx is None

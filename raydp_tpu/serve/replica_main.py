@@ -28,6 +28,7 @@ import cloudpickle
 from raydp_tpu import fault as _fault
 from raydp_tpu.cluster.rpc import RpcClient, RpcServer
 from raydp_tpu.telemetry import events as _events
+from raydp_tpu.utils.compile_cache import ensure_compile_cache
 from raydp_tpu.utils.profiling import metrics
 
 logger = logging.getLogger(__name__)
@@ -317,6 +318,7 @@ def main() -> None:
                "%(asctime)s %(message)s",
     )
     _fault.install_sigterm_drain()
+    ensure_compile_cache()
     replica = ServeReplica(
         replica=int(os.environ[ENV_REPLICA]),
         incarnation=int(os.environ.get(ENV_INCARNATION, "0")),

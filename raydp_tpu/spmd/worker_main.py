@@ -43,6 +43,7 @@ from raydp_tpu.telemetry import flight_recorder as _flight
 from raydp_tpu.telemetry import logs as _logs
 from raydp_tpu.telemetry import propagation as trace_prop
 from raydp_tpu.telemetry import watchdog as _watchdog
+from raydp_tpu.utils.compile_cache import ensure_compile_cache
 from raydp_tpu.utils.net import local_ip
 
 logger = logging.getLogger(__name__)
@@ -416,6 +417,7 @@ def main() -> int:
         level=logging.INFO,
         format=f"[spmd-{os.environ.get(ENV_RANK, '?')}] %(levelname)s %(message)s",
     )
+    ensure_compile_cache()
     # Join the driver's job trace before any span is recorded, and its
     # job identity before any usage is billed; flush tail spans on
     # interpreter exit.

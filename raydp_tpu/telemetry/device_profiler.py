@@ -51,7 +51,7 @@ import zipfile
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-from raydp_tpu.utils.profiling import metrics
+from raydp_tpu.utils.profiling import local_devices_if_initialized, metrics
 
 __all__ = [
     "enabled",
@@ -81,9 +81,9 @@ def enabled() -> bool:
 # -- device peaks (roofline ceilings) ---------------------------------------
 
 # device_kind substring → (peak dense bf16 FLOP/s, HBM bytes/s) per chip.
-# Public numbers; good to the precision a live MFU gauge needs. CPUs and
-# unknown accelerators get no entry → MFU is not reported rather than
-# invented.
+# Public numbers; good to the precision a live MFU gauge needs. A CPU has
+# no entry and reports no MFU; an accelerator that matches no entry is an
+# error, so a new chip cannot run with a silently missing roofline.
 _DEVICE_PEAKS = (
     ("v6e", 918e12, 1640e9),
     ("v5p", 459e12, 2765e9),
@@ -99,31 +99,30 @@ def device_peaks() -> Dict[str, Optional[float]]:
     """``{"flops_per_sec", "mem_bw", "devices", "kind"}`` for the local
     devices — peak numbers are PER HOST (per-chip peak × local device
     count), matching the per-process step accounting that divides by
-    them. All-None on CPU/unknown backends."""
+    them. All-None in a process that holds no backend; no peaks on
+    CPU; ``ValueError`` for an accelerator ``_DEVICE_PEAKS`` does not
+    list."""
     out: Dict[str, Optional[float]] = {
         "flops_per_sec": None, "mem_bw": None, "devices": None, "kind": None,
     }
-    try:
-        import sys
-
-        jax = sys.modules.get("jax")  # never import-triggers a backend
-        if jax is None:
+    devs = local_devices_if_initialized()
+    if not devs:
+        return out
+    kind = devs[0].device_kind
+    out["devices"] = float(len(devs))
+    out["kind"] = kind
+    if devs[0].platform == "cpu":
+        return out
+    for tag, flops, bw in _DEVICE_PEAKS:
+        if tag in kind.lower():
+            out["flops_per_sec"] = flops * len(devs)
+            out["mem_bw"] = bw * len(devs)
             return out
-        devs = jax.local_devices()
-        if not devs:
-            return out
-        kind = getattr(devs[0], "device_kind", "") or ""
-        out["devices"] = float(len(devs))
-        out["kind"] = kind
-        lk = kind.lower()
-        for tag, flops, bw in _DEVICE_PEAKS:
-            if tag in lk:
-                out["flops_per_sec"] = flops * len(devs)
-                out["mem_bw"] = bw * len(devs)
-                break
-    except Exception:
-        pass
-    return out
+    raise ValueError(
+        f"no peak FLOP/s and bandwidth entry for device_kind {kind!r} "
+        f"(platform {devs[0].platform!r}); add it to _DEVICE_PEAKS in "
+        "raydp_tpu/telemetry/device_profiler.py"
+    )
 
 
 # -- per-compiled-function cost registry ------------------------------------

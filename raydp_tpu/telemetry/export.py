@@ -362,7 +362,7 @@ def render_prometheus(view: Dict[str, Any]) -> str:
     )
     compile_failures = _Family(
         "raydp_compile_failures_total", "counter",
-        "XLA compiles that raised (remote-compile HTTP errors included).",
+        "First dispatches of a jitted step that raised while compiling.",
     )
     restarts = _Family(
         "raydp_restarts_total", "counter",

@@ -55,16 +55,10 @@ def _guard_compile(jitted: Callable, label: str) -> Callable:
     """Surface first-dispatch (compile-time) failures with XLA detail.
 
     The first call of a jit'd step is where tracing + backend compile
-    happen; an opaque failure there (the remote-compile HTTP 500 being
-    the classic) would otherwise reach the user with no hint of which
-    step, how long the compile ran, or what the service said. Later
-    calls pass through untouched — runtime errors are not compile
-    errors and must not be relabelled as such.
-
-    Retryable failures (``CompileError.retryable``: the remote compile
-    service itself fell over with a 5xx) are re-dispatched up to
-    ``RAYDP_TPU_COMPILE_RETRIES`` times (default 1) before surfacing —
-    a crashed compile helper should cost one retry, not the job.
+    happen; a failure there would otherwise reach the user with no hint
+    of which step it was or how long the compile ran. Later calls pass
+    through untouched — runtime errors are not compile errors and must
+    not be relabelled as such.
     """
     state = {"first": True}
 
@@ -73,48 +67,22 @@ def _guard_compile(jitted: Callable, label: str) -> Callable:
             return jitted(*args, **kwargs)
         from raydp_tpu.utils.profiling import enrich_compile_error
 
+        start = time.monotonic()
         try:
-            retries = max(
-                0, int(os.environ.get("RAYDP_TPU_COMPILE_RETRIES", "1"))
+            out = jitted(*args, **kwargs)
+        except Exception as exc:
+            payload = sum(
+                getattr(leaf, "nbytes", 0) or 0
+                for leaf in jax.tree_util.tree_leaves((args, kwargs))
             )
-        except ValueError:
-            retries = 1
-        attempt = 0
-        while True:
-            start = time.monotonic()
-            try:
-                out = jitted(*args, **kwargs)
-                # First dispatch ≈ trace + backend compile: bill it to
-                # the job ledger so usage_report shows compile cost per
-                # job, not just per process.
-                _acct.add_usage(
-                    _acct.COMPILE_SECONDS, time.monotonic() - start
-                )
-                break
-            except Exception as exc:
-                try:
-                    payload = sum(
-                        getattr(leaf, "nbytes", 0) or 0
-                        for leaf in jax.tree_util.tree_leaves(
-                            (args, kwargs)
-                        )
-                    )
-                except Exception:
-                    payload = None
-                enriched = enrich_compile_error(
-                    exc, time.monotonic() - start, label,
-                    payload_bytes=payload,
-                )
-                if getattr(enriched, "retryable", False) and attempt < retries:
-                    attempt += 1
-                    logger.warning(
-                        "compile of %r failed with a retryable service "
-                        "error (HTTP %s); retry %d/%d",
-                        label, getattr(enriched, "http_status", "?"),
-                        attempt, retries,
-                    )
-                    continue
-                raise enriched from exc
+            raise enrich_compile_error(
+                exc, time.monotonic() - start, label,
+                payload_bytes=payload,
+            ) from exc
+        # First dispatch ≈ trace + backend compile: bill it to the job
+        # ledger so usage_report shows compile cost per job, not just
+        # per process.
+        _acct.add_usage(_acct.COMPILE_SECONDS, time.monotonic() - start)
         state["first"] = False
         # First dispatch is also the cost-analysis moment: register
         # analytical FLOPs/bytes for the MFU/roofline gauges. lower()
@@ -283,7 +251,7 @@ class JAXEstimator:
         self.prefetch = prefetch
         # How many sharded batch transfers _sharded_prefetch keeps in
         # flight ahead of the train step (>=1; 2 = classic double
-        # buffering, deeper absorbs high-RTT device links).
+        # buffering).
         self.infeed_depth = max(1, infeed_depth)
         self.drop_last = drop_last
         # PRNG implementation for the training rng chain (init, shuffle,
@@ -494,8 +462,7 @@ class JAXEstimator:
         ``_shard_batch`` transfers (async device_puts onto the mesh) in
         flight while the caller's train step computes, so the chip never
         stalls on H2D (SURVEY §7.3 "double-buffered infeed without device
-        stalls", deepened past one transfer for high-RTT device links —
-        r4 verdict Weak #4). Initializes model state from the first host
+        stalls"). Initializes model state from the first host
         batch before sharding it. Yields ``(x_dev, y_dev,
         host_batch_len)``."""
         from collections import deque

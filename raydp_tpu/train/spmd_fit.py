@@ -282,12 +282,8 @@ def fit_spmd(
             def work(ctx, payload, resume_from=resume,
                      _store_mode=store_mode, _master=master,
                      _namespace=namespace, _blocks=blocks):
-                import os as _os
-
                 import jax
 
-                if _os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
-                    jax.config.update("jax_platforms", "cpu")
                 ctx.init_jax_distributed()
 
                 import numpy as np

@@ -35,6 +35,7 @@ __all__ = [
     "annotate",
     "install_compile_listener",
     "enrich_compile_error",
+    "local_devices_if_initialized",
     "sample_resource_gauges",
     "cost_analysis_summary",
 ]
@@ -346,7 +347,7 @@ def install_compile_listener() -> bool:
     ``compile/seconds`` via ``jax.monitoring``.
 
     Every backend-compile jax performs (jit tracing-triggered, AOT
-    ``.compile()``, remote TPU compile) emits a ``*compile*`` duration
+    ``.compile()``) emits a ``*compile*`` duration
     event; counting them here gives compile-time accounting on every
     process — driver, SPMD ranks, cluster workers — without wrapping
     individual ``jax.jit`` sites. Idempotent; returns False when the
@@ -358,7 +359,10 @@ def install_compile_listener() -> bool:
         from jax import monitoring as _mon
 
         def _on_duration(event: str, duration: float, **kw) -> None:
-            if "compile" not in event:
+            # The persistent cache's own bookkeeping is not time spent:
+            # on a hit it reports compile_time_saved_sec, the time the
+            # cache SAVED, which would otherwise be added as if spent.
+            if "compile" not in event or "/compilation_cache/" in event:
                 return
             # Count the top-level backend_compile events once; finer
             # sub-phase events still add their seconds to the total.
@@ -375,25 +379,17 @@ def install_compile_listener() -> bool:
     return True
 
 
-_REMOTE_COMPILE_RE = None
-
-
 class CompileError(RuntimeError):
-    """Structured XLA compile failure.
+    """Structured compile or dispatch failure.
 
-    Carries everything a supervisor or retry loop needs to decide what
-    to do, instead of a bare string: ``label`` (which jitted step),
-    ``duration_s`` (how long the compile ran), ``endpoint`` /
-    ``http_status`` (set for remote-compile failures),
-    ``server_exception`` (the service-side failure class parsed from
-    the HTTP body — exception name or helper exit code),
-    ``payload_bytes`` (size of the program's argument payload, the
-    lever that decides "too large for the helper"), ``xla_detail``
-    (whatever compiler diagnostics the original text contained), and
-    ``retryable`` — True only for remote-compile HTTP 5xx, where the
-    compile *service* failed (helper OOM-killed, subprocess crash) and
-    an identical request can succeed; a 4xx or a local compiler
-    diagnostic is deterministic and retrying it just burns time.
+    Carries what a supervisor needs to decide what to do, instead of a
+    bare string: ``label`` (which jitted step or shipped function),
+    ``duration_s`` (how long the compile ran), ``payload_bytes`` (size
+    of the argument payload), ``server_exception`` (the failure class
+    the receiving side reported, for a dispatch that died in
+    transport), ``xla_detail`` (the compiler's own message), and
+    ``retryable`` — a compiler diagnostic is deterministic and never
+    retryable; a staged dispatch that failed in transport can be.
     """
 
     def __init__(
@@ -402,8 +398,6 @@ class CompileError(RuntimeError):
         *,
         label: str,
         duration_s: float,
-        endpoint: Optional[str] = None,
-        http_status: Optional[int] = None,
         server_exception: Optional[str] = None,
         payload_bytes: Optional[int] = None,
         xla_detail: str = "",
@@ -412,35 +406,10 @@ class CompileError(RuntimeError):
         super().__init__(message)
         self.label = label
         self.duration_s = duration_s
-        self.endpoint = endpoint
-        self.http_status = http_status
         self.server_exception = server_exception
         self.payload_bytes = payload_bytes
         self.xla_detail = xla_detail
         self.retryable = retryable
-
-
-_SERVER_EXC_RE = None
-
-
-def _server_exception_class(body: str) -> Optional[str]:
-    """Service-side failure class from a remote-compile HTTP body:
-    a Python/C++ exception name when one is present, else the helper's
-    exit code (``subprocess-exit-N``)."""
-    global _SERVER_EXC_RE
-    if _SERVER_EXC_RE is None:
-        import re
-
-        _SERVER_EXC_RE = re.compile(
-            r"\b([A-Za-z_][\w.]*(?:Error|Exception))\b"
-            r"|subprocess exit code (\d+)"
-        )
-    m = _SERVER_EXC_RE.search(body or "")
-    if m is None:
-        return None
-    if m.group(1):
-        return m.group(1)
-    return f"subprocess-exit-{m.group(2)}"
 
 
 def enrich_compile_error(
@@ -449,87 +418,19 @@ def enrich_compile_error(
     label: str,
     payload_bytes: Optional[int] = None,
 ) -> "CompileError":
-    """Build an actionable, structured error for a failed XLA compile.
-
-    Remote-compile failures surface as an opaque
-    ``INTERNAL: http://...:PORT/remote_compile: HTTP 500:
-    tpu_compile_helper subprocess exit code N`` with none of the
-    compiler's own diagnostics (BENCH_r04/r05: the seq-16384 dense-
-    attention path). Wrap them (and any other compile-time failure) in
-    a :class:`CompileError` carrying the compile duration, the phase
-    label, the endpoint/status, and every line of XLA/compiler detail
-    present in the original text — with ``retryable`` set for 5xx
-    service failures so callers can re-dispatch once instead of dying.
-    Chain with ``raise ... from exc`` at the call site to keep the
-    original traceback."""
-    global _REMOTE_COMPILE_RE
-    if _REMOTE_COMPILE_RE is None:
-        import re
-
-        _REMOTE_COMPILE_RE = re.compile(
-            r"(https?://\S+/remote_compile):\s*HTTP (\d+)(?::\s*(.*))?",
-            re.DOTALL,
-        )
-    text = str(exc)
-    lines = [
-        f"XLA compilation failed in {label!r} after {duration_s:.1f}s"
-        f" ({type(exc).__name__})."
-    ]
-    endpoint: Optional[str] = None
-    http_status: Optional[int] = None
-    server_exception: Optional[str] = None
-    detail = ""
-    retryable = False
-    m = _REMOTE_COMPILE_RE.search(text)
-    if m:
-        endpoint, status, body = m.group(1), m.group(2), m.group(3)
-        http_status = int(status)
-        # 5xx: the compile SERVICE fell over under this request (helper
-        # OOM/crash) — the identical request can succeed on a retry.
-        # 4xx means the request itself was rejected; deterministic.
-        retryable = 500 <= http_status < 600
-        lines.append(
-            f"The compile was served remotely by {endpoint} which"
-            f" returned HTTP {status} — the compiler error below is"
-            " everything the compile service reported:"
-        )
-        detail = (body or "").strip()
-        lines.append(f"  {detail if detail else '(no body)'}")
-        server_exception = _server_exception_class(detail)
-        if server_exception:
-            lines.append(
-                f"Service-side failure class: {server_exception}."
-            )
-        if payload_bytes:
-            lines.append(
-                f"Argument payload shipped with the program: "
-                f"{payload_bytes} bytes."
-            )
-        lines.append(
-            "Likely causes: the program is too large for the compile"
-            " helper (seen at seq>=16384 dense attention — shrink the"
-            " per-stage program or use flash attention), or the helper"
-            " OOM-killed; retry with a smaller shape to confirm."
-        )
-        if retryable:
-            lines.append(
-                "This failure class is transient "
-                "(CompileError.retryable=True); RAYDP_TPU_COMPILE_RETRIES "
-                "controls automatic re-dispatch."
-            )
-    else:
-        detail = text.strip()
-        lines.append(f"Compiler said: {detail or '(empty message)'}")
+    """Wrap a first-dispatch failure in a :class:`CompileError` that
+    says which step failed, how long the compile ran and what the
+    compiler said. Chain with ``raise ... from exc`` at the call site
+    to keep the original traceback."""
+    detail = str(exc).strip()
     err = CompileError(
-        "\n".join(lines),
+        f"XLA compilation failed in {label!r} after {duration_s:.1f}s"
+        f" ({type(exc).__name__}).\n"
+        f"Compiler said: {detail or '(empty message)'}",
         label=label,
         duration_s=duration_s,
-        endpoint=endpoint,
-        http_status=http_status,
-        server_exception=server_exception,
         payload_bytes=payload_bytes,
         xla_detail=detail,
-        retryable=retryable,
     )
     metrics.counter_add("compile/failures")
     metrics.counter_add("compile/seconds", duration_s)
@@ -543,17 +444,7 @@ def enrich_compile_error(
             "compile/failed",
             label=label,
             duration_s=round(duration_s, 3),
-            retryable=retryable,
-            **{
-                k: v
-                for k, v in (
-                    ("endpoint", endpoint),
-                    ("http_status", http_status),
-                    ("server_exception", server_exception),
-                    ("payload_bytes", payload_bytes),
-                )
-                if v
-            },
+            **({"payload_bytes": payload_bytes} if payload_bytes else {}),
         )
     except Exception:
         pass
@@ -598,6 +489,27 @@ def cost_analysis_summary(jitted, args, kwargs) -> Optional[Dict[str, float]]:
     return {"flops": flops, "bytes": nbytes, "collective_bytes": coll}
 
 
+def local_devices_if_initialized() -> list:
+    """This process's devices, or ``[]`` when it holds no backend.
+
+    ``jax.local_devices()`` CREATES a backend where none exists. In a
+    ``fit_spmd`` or serving driver that takes the chip away from the
+    rank or replica that needs it; in a rank it breaks the later
+    ``jax.distributed.initialize``. Telemetry that only wants to look
+    at devices therefore asks whether a backend exists first (jax 0.9
+    has no public spelling of that question)."""
+    import sys
+
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return []
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return []
+    return jax.local_devices()
+
+
 def sample_resource_gauges(registry: Optional[MetricsRegistry] = None) -> None:
     """Refresh the resource-accounting gauges on ``registry`` (default:
     the process registry): host RSS current/peak, per-process device HBM
@@ -612,29 +524,22 @@ def sample_resource_gauges(registry: Optional[MetricsRegistry] = None) -> None:
     if rss:
         reg.gauge_set("mem/rss_bytes", rss)
         reg.gauge_max("mem/rss_peak_bytes", peak)
-    try:
-        import sys
-
-        jax = sys.modules.get("jax")  # never import-triggers a backend
-        if jax is not None:
-            used = hwm = 0
-            have = False
-            for dev in jax.local_devices():
-                stats = dev.memory_stats()
-                if not stats:
-                    continue
-                have = True
-                used += int(stats.get("bytes_in_use", 0) or 0)
-                hwm += int(
-                    stats.get("peak_bytes_in_use", 0)
-                    or stats.get("bytes_in_use", 0)
-                    or 0
-                )
-            if have:
-                reg.gauge_set("hbm/used_bytes", used)
-                reg.gauge_max("hbm/peak_bytes", hwm)
-    except Exception:
-        pass  # no backend yet / unsupported device: skip HBM gauges
+    used = hwm = 0
+    have = False
+    for dev in local_devices_if_initialized():
+        stats = dev.memory_stats()
+        if not stats:
+            continue
+        have = True
+        used += int(stats.get("bytes_in_use", 0) or 0)
+        hwm += int(
+            stats.get("peak_bytes_in_use", 0)
+            or stats.get("bytes_in_use", 0)
+            or 0
+        )
+    if have:
+        reg.gauge_set("hbm/used_bytes", used)
+        reg.gauge_max("hbm/peak_bytes", hwm)
     try:
         from raydp_tpu.store.object_store import get_current_store
 

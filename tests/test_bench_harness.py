@@ -1,10 +1,9 @@
 """Unit tests for the bench.py harness plumbing.
 
 The bench's *numbers* come from real runs; what must never regress is
-the machinery that guarantees a run cannot be lost: partial-result
-streaming, signal/atexit emission, config filtering, and the
-budget-capped baseline loops (the r3 round lost ALL its perf evidence
-to a probe loop that printed nothing — VERDICT r3 item 1).
+the machinery that guarantees a run cannot be lost or misread:
+partial-result streaming, the device stamp on every result, config
+filtering, and the budget-capped baseline loops.
 """
 import json
 import os
@@ -43,12 +42,9 @@ def test_only_filter_unknown_names_drop_silently(monkeypatch):
     assert bench._only_filter(["a"]) == []
 
 
-def test_only_names_exist_in_matrices():
-    cpu_names = [n for n, _ in bench.CPU_MATRIX]
-    # Every chip config must resolve to a CPU_MATRIX function — the
-    # chip worker looks them up by name.
-    for name in bench.CHIP_MATRIX_NAMES:
-        assert name in cpu_names
+def test_matrix_names_are_unique():
+    names = [n for n, _ in bench.MATRIX]
+    assert len(names) == len(set(names))
 
 
 # ----------------------------------------------------- _torch_rate
@@ -123,30 +119,29 @@ def test_torch_rate_deadline_guard_still_yields_a_rate(monkeypatch):
 
 # ----------------------------------------------------- emission
 
-def test_write_json_atomic_and_merge_chip_sidecar(tmp_path, monkeypatch):
-    sidecar = str(tmp_path / "chip.json")
-    bench._write_json_atomic(
-        sidecar,
-        {"device": "TPU vTest", "configs": {"x": {"samples_per_sec": 5}}},
-    )
-    state = {"chip_device": None, "chip": {}, "notes": []}
-    monkeypatch.setattr(bench, "_STATE", state, raising=False)
-    bench._merge_chip_sidecar(sidecar)
-    assert state["chip_device"] == "TPU vTest"
-    assert state["chip"]["x"]["samples_per_sec"] == 5
+def test_results_are_stamped_with_the_device(tmp_path, monkeypatch):
+    """Every result names the platform, device kind and device count it
+    ran on, and a section that raises is recorded without stopping the
+    run (main() turns it into a non-zero exit)."""
+    stamp = {"platform": "tpu", "device_kind": "TPU vTest",
+             "device_count": 4}
+    state = {"configs": {}, "device": stamp, "profile": None,
+             "analysis": None, "notes": [], "emitted": False}
+    monkeypatch.setattr(bench, "_STATE", state)
+    monkeypatch.setenv("RAYDP_TPU_BENCH_PARTIAL", str(tmp_path / "p.json"))
 
+    def boom():
+        raise RuntimeError("section broke")
 
-def test_merge_chip_sidecar_tolerates_garbage(tmp_path, monkeypatch):
-    sidecar = str(tmp_path / "chip.json")
-    with open(sidecar, "w") as f:
-        f.write("{not json")
-    monkeypatch.setattr(
-        bench, "_STATE",
-        {"chip_device": None, "chip": {}, "notes": []},
-        raising=False,
-    )
-    bench._merge_chip_sidecar(sidecar)  # must not raise
-    bench._merge_chip_sidecar(str(tmp_path / "missing.json"))
+    bench._record("good", lambda: {"samples_per_sec": 5})
+    bench._record("bad", boom)
+    for name in ("good", "bad"):
+        assert {k: state["configs"][name][k] for k in stamp} == stamp
+    assert state["configs"]["bad"]["error"] == "RuntimeError: section broke"
+    out = bench._assemble()
+    assert {k: out[k] for k in stamp} == stamp
+    with open(tmp_path / "p.json") as f:
+        assert json.load(f)["configs"]["good"]["samples_per_sec"] == 5
 
 
 def test_timed_train_steps_returns_wall_time():

@@ -30,7 +30,6 @@ def test_submit_env_handoff_reaches_init(tmp_path):
     """--num-workers/--name/--conf land in the driver's session config."""
     driver = tmp_path / "driver.py"
     driver.write_text(
-        "import jax; jax.config.update('jax_platforms', 'cpu')\n"
         "import os\n"
         "import raydp_tpu\n"
         "s = raydp_tpu.init()\n"
@@ -65,7 +64,6 @@ def test_submit_explicit_args_beat_env(tmp_path):
     """A driver that hardcodes a value keeps it; env fills only gaps."""
     driver = tmp_path / "driver.py"
     driver.write_text(
-        "import jax; jax.config.update('jax_platforms', 'cpu')\n"
         "import raydp_tpu\n"
         "s = raydp_tpu.init(num_workers=2)\n"
         "print('WORKERS', s.config.num_workers)\n"

@@ -198,7 +198,6 @@ def _run_in_mode(session, mode, pipeline, fn_name):
         return ns[fn_name]()
     addr = session.cluster.master.address
     script = (
-        "import jax; jax.config.update('jax_platforms', 'cpu')\n"
         "import json, raydp_tpu\n"
         f"s = raydp_tpu.connect({addr!r})\n"
         + pipeline

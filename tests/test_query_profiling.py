@@ -171,59 +171,39 @@ def test_compile_error_enrichment():
     from raydp_tpu.train.estimator import _guard_compile
     from raydp_tpu.utils.profiling import CompileError
 
-    http_500 = (
-        "INTERNAL: http://10.0.0.1:8471/remote_compile: HTTP 500: "
-        "tpu_compile_helper subprocess exit code 137"
+    # What a local compiler says when a program does not fit the chip.
+    compiler_msg = (
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 21.50G of 15.75G hbm."
     )
     calls = {"n": 0}
 
-    def step(x):
+    def too_big(x):
         calls["n"] += 1
-        if calls["n"] == 1:
-            raise RuntimeError(http_500)
-        return x + 1
+        raise RuntimeError(compiler_msg)
 
     before = metrics.snapshot().get("counters", {}).get(
         "compile/failures", 0.0
     )
-    guarded = _guard_compile(step, "train_step")
-    # A transient 5xx from the compile SERVICE costs one automatic
-    # re-dispatch (RAYDP_TPU_COMPILE_RETRIES), not the job.
-    assert guarded(1) == 2
-    assert calls["n"] == 2
-    after = metrics.snapshot()["counters"]["compile/failures"]
-    assert after == before + 1  # the failed attempt still counts
-
-    # A PERSISTENT 5xx exhausts the retry budget and surfaces as a
-    # structured CompileError with the enrichment intact.
-    def always_500(x):
-        raise RuntimeError(http_500)
-
+    # A first-dispatch failure is wrapped with the step label, the
+    # compile duration and the compiler's own words — and is raised at
+    # once: a compiler diagnostic is deterministic, nothing retries it.
     with pytest.raises(CompileError) as exc_info:
-        _guard_compile(always_500, "train_step")(1)
-    msg = str(exc_info.value)
+        _guard_compile(too_big, "train_step")(np.ones(4, np.float32))
+    assert calls["n"] == 1
+    err = exc_info.value
+    msg = str(err)
     assert "train_step" in msg
-    assert "remote_compile" in msg
-    assert "HTTP 500" in msg
+    assert "Ran out of memory in memory space hbm" in msg
     assert re.search(r"after \d+\.\d+s", msg)
-    assert exc_info.value.retryable is True
-    assert exc_info.value.__cause__ is not None  # original traceback kept
-
-    # 4xx means the request itself was rejected — deterministic, so it
-    # surfaces immediately without burning a retry.
-    calls_4xx = {"n": 0}
-
-    def rejected(x):
-        calls_4xx["n"] += 1
-        raise RuntimeError(
-            "INTERNAL: http://10.0.0.1:8471/remote_compile: HTTP 400: "
-            "program rejected"
-        )
-
-    with pytest.raises(CompileError) as exc_4xx:
-        _guard_compile(rejected, "train_step")(1)
-    assert calls_4xx["n"] == 1
-    assert exc_4xx.value.retryable is False
+    assert err.label == "train_step"
+    assert err.duration_s >= 0.0
+    assert err.payload_bytes == 16
+    assert err.xla_detail == compiler_msg
+    assert err.retryable is False
+    assert isinstance(err.__cause__, RuntimeError)  # original traceback kept
+    after = metrics.snapshot()["counters"]["compile/failures"]
+    assert after == before + 1
 
     def runtime_fail(x):
         if x > 1:
