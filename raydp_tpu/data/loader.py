@@ -488,7 +488,8 @@ class JaxShardLoader:
                 # (device link wedge) is a classic silent hang.
                 with _overlap.tracker.ingest(), \
                      _watchdog.inflight("ingest/device_put",
-                                        rank=self._rank):
+                                        rank=self._rank), \
+                     span("ingest/device_put", rank=self._rank):
                     buf = jax.device_put(chunk.buf, device)
                 batch_counter("ingest/device_puts")
                 return self._unpack_device(buf, chunk.rows)
@@ -496,7 +497,8 @@ class JaxShardLoader:
             if device is not None:
                 with _overlap.tracker.ingest(), \
                      _watchdog.inflight("ingest/device_put",
-                                        rank=self._rank):
+                                        rank=self._rank), \
+                     span("ingest/device_put", rank=self._rank):
                     x = jax.device_put(x, device)
                     y = jax.device_put(y, device) if y is not None else None
                 batch_counter(
@@ -589,11 +591,10 @@ def _background(it: Iterator, depth: int):
         while True:
             if err:
                 raise err[0]
-            t0 = time.perf_counter()
-            item = q.get()
-            metrics.counter_add(
-                "ingest/wait_seconds", time.perf_counter() - t0
-            )
+            # One span per chunk, closed before the yield below.
+            with span("ingest/wait") as sp:
+                item = q.get()
+            metrics.counter_add("ingest/wait_seconds", sp.duration_s)
             if err:
                 # Raced with the failure while pulling: prefer the error
                 # over any still-buffered item.

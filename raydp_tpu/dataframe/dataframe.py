@@ -33,6 +33,7 @@ from raydp_tpu.dataframe.scheduler import (
     resolve as _resolve_parts,
     when_settled as _when_settled,
 )
+from raydp_tpu.telemetry import span
 from raydp_tpu.telemetry.progress import stage_store
 from raydp_tpu.utils.profiling import metrics
 
@@ -979,9 +980,12 @@ class DataFrame:
         return df._with(fn)
 
     # -- actions --------------------------------------------------------
+    # An action's df/action span is the driver's whole time in it; the
+    # df/stage spans inside are the engine's, the rest is driver work.
     def collect_partitions(self) -> List[pa.Table]:
-        df = self._flush()
-        return [df._executor.materialize(p) for p in df._parts]
+        with span("df/action", op="collect"):
+            df = self._flush()
+            return [df._executor.materialize(p) for p in df._parts]
 
     def to_arrow(self) -> pa.Table:
         return _concat(self.collect_partitions())
@@ -992,14 +996,15 @@ class DataFrame:
     toPandas = to_pandas
 
     def count(self) -> int:
-        df = self._flush()
-        total = 0
-        for part in df._parts:
-            rows = df._executor.num_rows(part)
-            if rows < 0:
-                rows = df._executor.materialize(part).num_rows
-            total += rows
-        return total
+        with span("df/action", op="count"):
+            df = self._flush()
+            total = 0
+            for part in df._parts:
+                rows = df._executor.num_rows(part)
+                if rows < 0:
+                    rows = df._executor.materialize(part).num_rows
+                total += rows
+            return total
 
     def show(self, n: int = 20) -> None:
         print(self.limit(n).to_pandas().to_string())

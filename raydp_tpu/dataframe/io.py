@@ -16,6 +16,7 @@ from raydp_tpu.dataframe import aqe as _aqe
 from raydp_tpu.dataframe import expr as E
 from raydp_tpu.dataframe.dataframe import DataFrame, _node, _split_sizes
 from raydp_tpu.dataframe.executor import Executor, LocalExecutor
+from raydp_tpu.telemetry import span
 from raydp_tpu.utils.profiling import metrics
 
 
@@ -81,9 +82,10 @@ def from_arrow(table: pa.Table, num_partitions: int = 1) -> DataFrame:
 
 
 def from_pandas(df, num_partitions: int = 1) -> DataFrame:
-    return from_arrow(
-        pa.Table.from_pandas(df, preserve_index=False), num_partitions
-    )
+    with span("df/from_pandas", rows=len(df)):
+        return from_arrow(
+            pa.Table.from_pandas(df, preserve_index=False), num_partitions
+        )
 
 
 def from_refs(refs: Sequence[Any]) -> DataFrame:

@@ -30,6 +30,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -103,6 +104,25 @@ class Span:
             "pid": os.getpid(),
             "tid": self.tid,
         }
+
+
+def _annotation(name: str, step_num: Optional[int], attrs: Dict[str, Any]):
+    """The profiler annotation of a span; nothing in a process that has
+    not imported jax (an ETL worker before its first task must not import
+    it because of a span). With no profile running, entering one is a
+    flag check."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    scalars = {
+        k: v for k, v in attrs.items()
+        if isinstance(v, (bool, int, float, str))
+    }
+    if step_num is not None:
+        return profiler.StepTraceAnnotation(
+            name, step_num=step_num, **scalars
+        )
+    return profiler.TraceAnnotation(name, **scalars)
 
 
 class SpanRecorder:
@@ -204,10 +224,20 @@ class SpanRecorder:
         self._append(sp)
 
     @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[Span]:
+    def span(
+        self, name: str, step_num: Optional[int] = None, **attrs: Any
+    ) -> Iterator[Span]:
+        """A span around the block, and the same region as an annotation
+        of ``jax.profiler``: in any profile captured meanwhile it sits on
+        this thread's line of ``/host:CPU``, on the device plane's clock.
+        ``step_num`` (not recorded as an attr) makes it a step annotation,
+        which groups the device's ops by step. :meth:`start`/:meth:`finish`
+        pairs may close out of order or on another thread, which the
+        profiler's annotations must not, and stay unbridged."""
         sp = self.start(name, **attrs)
         try:
-            yield sp
+            with _annotation(name, step_num, attrs):
+                yield sp
         except BaseException:
             sp.status = "error"
             raise

@@ -397,8 +397,14 @@ class JAXEstimator:
             # Global grad-norm rides along for the anomaly sentinel: an
             # Inf/NaN here flags divergence one step before the loss
             # shows it, and computing it on device costs one reduction.
-            gnorm = optax.global_norm(grads)
-            return state.apply_gradients(grads=grads), loss_val, gnorm
+            # The model's ops carry their flax module path; these two
+            # scopes name the only ops of the step that no module owns,
+            # so a device trace splits the whole step by part.
+            with jax.named_scope("part:grad_norm"):
+                gnorm = optax.global_norm(grads)
+            with jax.named_scope("part:update"):
+                state = state.apply_gradients(grads=grads)
+            return state, loss_val, gnorm
 
         return train_step
 
@@ -522,7 +528,9 @@ class JAXEstimator:
             x_sharding = self.data_sharding
         # Ingest bracket: sharded transfers that run while late ETL
         # partitions are still producing accrue pipeline overlap credit.
-        with _overlap.tracker.ingest():
+        # infeed/put is the host's time in the put calls on the step
+        # loop's thread, not the transfer (that runs behind the step).
+        with _overlap.tracker.ingest(), span("infeed/put"):
             if n_proc > 1:
                 xd = jax.make_array_from_process_local_data(x_sharding, x)
                 yd = (
@@ -539,7 +547,19 @@ class JAXEstimator:
             )
             return xd, yd
 
-    def _finish_epoch(
+    def _finish_epoch(self, epoch: int, *tail) -> Dict[str, float]:
+        """Per-epoch tail shared by stream and scan paths, as the span
+        ``train/epoch_end``: what the host does between the loss fetch
+        and the next epoch. It closes before the flush, which drains
+        finished spans only."""
+        with span("train/epoch_end", epoch=epoch):
+            metrics = self._epoch_tail(epoch, *tail)
+        # Epoch boundary = natural flush point for the span ring buffer
+        # (no-op unless RAYDP_TPU_TELEMETRY_DIR is configured).
+        flush_spans()
+        return metrics
+
+    def _epoch_tail(
         self,
         epoch: int,
         t0: float,
@@ -547,8 +567,7 @@ class JAXEstimator:
         n_samples: int,
         evaluate_ds: Optional[MLDataset],
     ) -> Dict[str, float]:
-        """Per-epoch tail shared by stream and scan paths: metrics dict,
-        optional eval, callbacks, checkpoint."""
+        """Metrics dict, optional eval, callbacks, checkpoint."""
         from raydp_tpu.utils.profiling import metrics as _m
 
         dt = time.perf_counter() - t0
@@ -595,9 +614,6 @@ class JAXEstimator:
                 self.checkpoint_dir, step=epoch,
                 data_position=(epoch + 1, 0),
             )
-        # Epoch boundary = natural flush point for the span ring buffer
-        # (no-op unless RAYDP_TPU_TELEMETRY_DIR is configured).
-        flush_spans()
         return metrics
 
     def _drain_preemption(
@@ -784,9 +800,13 @@ class JAXEstimator:
             # The epoch span covers only the batch loop (it closes before
             # _finish_epoch so a flush there sees it finished); step spans
             # nest under it via the thread-local stack. Step timing here is
-            # DISPATCH time (async jax: the device may still be computing)
-            # — steady-state it converges to true step time because the
-            # pipeline is throughput-bound, and compile steps stand out.
+            # DISPATCH time (async jax), which is not a step time: the call
+            # returns once the step is queued, and blocks only while the
+            # device's queue is full. In a device-bound loop that is most
+            # of the wall time (70% on a v5e, the rest at the loss fetch),
+            # in a host-bound loop next to none. The device's step time is
+            # in a profile, where this span is a step annotation (step_num
+            # = the optimizer step) above the ops it dispatched.
             _flight.record("train", "epoch_start", epoch=epoch,
                            mode="stream")
             with span("train/epoch", epoch=epoch, mode="stream"):
@@ -801,7 +821,8 @@ class JAXEstimator:
                         "train/step", epoch=epoch, step=b_idx,
                         stall_after_s=(_watchdog.long_stall_s()
                                        if b_idx == 0 else None),
-                    ), span("train/step", epoch=epoch, step=b_idx) as sp:
+                    ), span("train/step", epoch=epoch, step=b_idx,
+                            step_num=steps_done) as sp:
                         while True:
                             try:
                                 (
@@ -880,9 +901,11 @@ class JAXEstimator:
                             "epoch %d step %d loss %.5f",
                             epoch, n_batches, float(loss_val),  # sync: opt-in
                         )
-            train_loss = float(loss_sum) / max(1, n_batches) if (
-                loss_sum is not None
-            ) else 0.0
+            # The host waits here for the device to drain the epoch.
+            with span("train/loss_fetch", epoch=epoch):
+                train_loss = float(loss_sum) / max(1, n_batches) if (
+                    loss_sum is not None
+                ) else 0.0
             if sentinel is not None:
                 # Epoch boundary always checks (the sampled cadence may
                 # never have landed on a NaN step in a short epoch).

@@ -22,6 +22,8 @@ Covers the tracing layers end to end, all on the CPU backend:
 import json
 import os
 import threading
+
+import pytest
 import time
 
 from raydp_tpu.telemetry import (
@@ -505,3 +507,294 @@ def test_two_worker_fit_produces_single_distributed_trace(tmp_path):
     assert "critical path:" in text
     assert "per-rank step skew:" in text
     assert "slowest:" in text
+
+
+# ---------------------------------------------------------------------
+# The profiler bridge: every span() is also a jax.profiler annotation
+
+
+def _tiny_fit_estimator(rows=256, batch=64):
+    import numpy as np
+    import pandas as pd
+
+    from raydp_tpu.models.mlp import taxi_fare_regressor
+    from raydp_tpu.train.estimator import JAXEstimator
+
+    rng = np.random.default_rng(0)
+    df = pd.DataFrame(rng.random((rows, 4)), columns=list("abcd"))
+    df["y"] = df.a * 2 + df.b
+    est = JAXEstimator(
+        model=taxi_fare_regressor(), loss="mse", num_epochs=1,
+        batch_size=batch, feature_columns=list("abcd"), label_column="y",
+        epoch_mode="stream",
+    )
+    return est, df
+
+
+def _host_annotations(trace_dir):
+    """``[(name, start_ns, end_ns, stats, line index)]`` of the host
+    plane of the newest profile under ``trace_dir``."""
+    import glob
+    import warnings
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"
+    )))[-1]
+    out = []
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:CPU"):
+                continue
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                dict(e.stats), i))
+    return out
+
+
+def test_fit_spans_land_on_the_profile_nested_as_the_parent_links(tmp_path):
+    import jax
+
+    from raydp_tpu.telemetry import recorder
+
+    est, df = _tiny_fit_estimator()
+    est.fit_on_df(df)  # compile outside the profile
+    recorder.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        est.fit_on_df(df)
+    finally:
+        jax.profiler.stop_trace()
+    notes = _host_annotations(str(tmp_path))
+    by_name = {}
+    for note in notes:
+        by_name.setdefault(note[0], []).append(note)
+
+    steps = sorted(by_name["train/step"], key=lambda n: n[1])
+    assert len(steps) == 4
+    # Step annotations: the profiler groups device ops under step_num, the
+    # optimizer step (the first fit took steps 0-3).
+    assert [s[3]["step_num"] for s in steps] == [4, 5, 6, 7]
+    assert [s[3]["step"] for s in steps] == [0, 1, 2, 3]
+    assert all(s[3]["_r"] == 1 for s in steps)
+    for name, at_least in (("infeed/put", 4), ("ingest/wait", 4),
+                           ("train/loss_fetch", 1), ("train/epoch_end", 1),
+                           ("train/epoch", 1), ("train/fit", 1),
+                           ("df/from_pandas", 1)):
+        assert len(by_name.get(name, [])) >= at_least, name
+    assert by_name["train/epoch"][0][3] == {"epoch": 0, "mode": "stream"}
+
+    # Nesting in the profile follows the recorder's parent links: a span
+    # and its parent recorded on one thread are one annotation inside the
+    # other, on one line.
+    spans = {s.span_id: s for s in recorder.spans()}
+    fit = by_name["train/fit"][0]
+    checked = 0
+    for sp in spans.values():
+        parent = spans.get(sp.parent_id)
+        if parent is None or parent.tid != sp.tid or sp.kind != "span":
+            continue
+        assert any(
+            o[4] == i[4] and o[1] <= i[1] and i[2] <= o[2]
+            for i in by_name[sp.name] for o in by_name[parent.name]
+        ), (sp.name, parent.name)
+        checked += 1
+    assert checked >= 10
+    # train/epoch > train/step, infeed/put, ingest/wait; the loss fetch
+    # and the epoch's tail follow the epoch span inside train/fit.
+    epoch = by_name["train/epoch"][0]
+    for name in ("train/step", "infeed/put", "ingest/wait"):
+        assert all(epoch[1] <= n[1] and n[2] <= epoch[2]
+                   for n in by_name[name]), name
+    fetch, tail = by_name["train/loss_fetch"][0], by_name["train/epoch_end"][0]
+    assert epoch[2] <= fetch[1] <= fetch[2] <= tail[1] <= tail[2] <= fit[2]
+
+
+def test_spans_without_a_profile_are_the_seeds():
+    """No profile running: what the recorder holds of the spans the seed
+    had is unchanged in names, attrs and order (``step_num`` is the
+    annotation's, not an attr), and the new ones are there beside them."""
+    from raydp_tpu.telemetry import recorder
+
+    est, df = _tiny_fit_estimator()
+    recorder.clear()
+    est.fit_on_df(df)
+    main = threading.get_ident()
+    mine = [s for s in recorder.spans() if s.tid == main and s.kind == "span"]
+    new = {"infeed/put", "ingest/wait", "train/loss_fetch", "train/epoch_end",
+           "df/action", "df/from_pandas"}
+    seeds = [(s.name, s.attrs) for s in mine if s.name not in new]
+    assert seeds == [
+        ("train/step", {"epoch": 0, "step": 0}),
+        ("train/step", {"epoch": 0, "step": 1}),
+        ("train/step", {"epoch": 0, "step": 2}),
+        ("train/step", {"epoch": 0, "step": 3}),
+        ("train/epoch", {"epoch": 0, "mode": "stream"}),
+        ("train/fit", {"epochs": 1}),
+    ]
+    assert {s.name for s in mine} >= new - {"df/action"}
+    assert all(s.end_mono is not None and s.status == "ok" for s in mine)
+
+
+def test_a_span_does_not_import_jax():
+    """A process that has not imported jax does not import it because of
+    a span. (``import raydp_tpu`` imports jax today, so the recorder's
+    module is loaded by path here, as a leaner worker would have it.)"""
+    import subprocess
+    import sys
+
+    code = (
+        "import importlib.util, sys\n"
+        "spec = importlib.util.spec_from_file_location(\n"
+        "    'spans', 'raydp_tpu/telemetry/spans.py')\n"
+        "spans = importlib.util.module_from_spec(spec)\n"
+        "sys.modules['spans'] = spans\n"
+        "spec.loader.exec_module(spans)\n"
+        "assert 'jax' not in sys.modules, 'imported with the recorder'\n"
+        "r = spans.SpanRecorder()\n"
+        "with r.span('worker/task', step_num=3, op='x'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'imported by a span'\n"
+        "assert [s.name for s in r.spans()] == ['worker/task']\n"
+        "assert r.spans()[0].attrs == {'op': 'x'}\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", code], cwd=repo, capture_output=True,
+        text=True, timeout=120,
+    )
+    assert done.returncode == 0, done.stderr
+
+
+def test_span_opened_before_the_profile_starts_is_not_in_it(tmp_path):
+    """The benchmark starts its profile from an epoch-end callback, inside
+    ``train/epoch_end``: that annotation is simply not in the profile."""
+    import jax
+
+    rec = SpanRecorder()
+    with rec.span("train/epoch_end", epoch=0):
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("train/epoch", epoch=1):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    names = [n[0] for n in _host_annotations(str(tmp_path))]
+    assert "train/epoch" in names and "train/epoch_end" not in names
+    assert [s.name for s in rec.spans()] == ["train/epoch_end", "train/epoch"]
+
+
+def test_span_closed_after_the_profile_stops_is_harmless(tmp_path):
+    import jax
+
+    rec = SpanRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("train/epoch", epoch=0):
+            pass
+        cm = rec.span("train/epoch_end", epoch=0)
+        cm.__enter__()
+    finally:
+        jax.profiler.stop_trace()
+    cm.__exit__(None, None, None)
+    with rec.span("train/epoch", epoch=1):
+        pass
+    assert [s.name for s in rec.spans()] == [
+        "train/epoch", "train/epoch_end", "train/epoch"
+    ]
+    assert all(s.end_mono is not None for s in rec.spans())
+    names = [n[0] for n in _host_annotations(str(tmp_path))]
+    assert names.count("train/epoch") == 1
+
+
+# ---------------------------------------------------------------------
+# The train step's scopes: every op of the compiled step falls into a part
+
+
+def _compiled_step_text(config_name, mode, monkeypatch):
+    """Compiled HLO text of the tiny configuration's train step, built by
+    the benchmark's builder through ``JAXEstimator`` (stream: the jitted
+    step; scan: the jitted epoch of two steps)."""
+    import sys
+
+    import jax
+    import numpy as np
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (os.path.join(repo, "tests", "benchmark"),
+              os.path.join(repo, "benchmark")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import bench_tree
+    import harness
+
+    import raydp_tpu.train.estimator as estimator_module
+    from raydp_tpu.parallel import MeshSpec
+
+    # The guard wraps the jitted function in a plain one; lower the jitted.
+    monkeypatch.setattr(estimator_module, "_guard_compile", lambda f, _: f)
+    sizes = dict(bench_tree.TINY_CONFIGS[config_name])
+    if "vocab_sizes" in sizes:
+        # Eight tables (the published model has 26): with four, the loss
+        # alone is an eighth of the tiny step's instructions.
+        sizes["vocab_sizes"] = sizes["vocab_sizes"] + [20, 30, 40, 60]
+    model = harness.load_module(os.path.join(
+        repo, "benchmark", "configs", sizes["builder"] + ".py"
+    ))
+    traffic = {"seq_len": 16, "per_chip_batch": 8}
+    mesh = MeshSpec(dp=1)
+    est = estimator_module.JAXEstimator(
+        **model.estimator_kwargs(sizes, traffic, mesh), batch_size=8,
+        mesh=mesh, seed=1, epoch_mode=mode,
+    )
+    x = model.check_batch(sizes, traffic, 1)
+    y = np.zeros(len(x), est.label_dtype)
+    est._init_state(x)
+    key = jax.random.PRNGKey(0)
+    if mode == "stream":
+        xd, yd = est._shard_batch(x, y)
+        lowered = est._train_step.lower(est._state, xd, yd, key)
+    else:
+        lowered = est._build_epoch_fn(2, len(x) // 2).lower(
+            est._state, x, y, key
+        )
+    return sizes["builder"], lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("mode", ["stream", "scan"])
+@pytest.mark.parametrize("config_name", ["bert_tiny", "dlrm_tiny"])
+def test_compiled_step_splits_into_parts(config_name, mode, monkeypatch):
+    import re
+
+    builder, text = _compiled_step_text(config_name, mode, monkeypatch)
+    import program_trace
+
+    assert "part:update" in text and "part:grad_norm" in text
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "parts", builder + ".json")) as f:
+        rules = program_trace.compile_rules(json.load(f))
+    # Scope paths start at the jitted function; a parameter's name
+    # (``state.params[...]``) and a reducer's body (``reduce_sum``) are not
+    # operations of the step with a scope.
+    scopes = [s for s in re.findall(r'op_name="([^"]*)"', text)
+              if s.startswith("jit(")]
+    if mode == "scan":
+        # The step is the body of the epoch's scan; the loop's own
+        # plumbing and the epoch's shuffle are not the step's.
+        body = re.compile(r"^jit\([^)]*\)/while/body/closed_call/")
+        scopes = [s for s in scopes if body.match(s)]
+    counts = {}
+    for scope in scopes:
+        part = program_trace.part_of(scope, rules)
+        counts[part] = counts.get(part, 0) + 1
+    named = {part for _, part in rules}
+    assert set(counts) - {"rest"} == named, counts
+    assert counts.get("rest", 0) < sum(counts.values()) / 5, counts
+    # Backward work lands with its part: the transposed paths match too.
+    backward = [s for s in scopes if "transpose(jvp(" in s]
+    assert backward and any(
+        program_trace.part_of(s, rules) != "rest" for s in backward
+    )
