@@ -19,6 +19,24 @@ TPU-first design:
   replicated with a plain gather; ``'auto'`` switches on vocab size AND
   backend (accelerators only — on CPU the one-hot is pure flop
   inflation, so auto always gathers there).
+* **The row path of ``take``** — a ``take`` table sows the ids it looks
+  up (collection ``ROW_IDS``) and accepts the gathered rows from outside
+  (collection ``ROWS``). ``JAXEstimator``'s train step uses both
+  (``train/rowsparse.py``): it gathers the float32 rows of the batch's
+  distinct ids once, the forward casts that ``[n, D]`` block to
+  ``dtype``, the gradient is taken with respect to the block, and the
+  optimizer runs on the block and on the same rows of its own state
+  before one in-place row scatter — no pass over the table. It engages,
+  per table and with no switch, when the table is large enough for it
+  to beat a dense pass (128 rows for each id the step looks up in it,
+  and 32 MiB: measured, ``rowsparse.MIN_ROWS_PER_ID``), its rows are
+  not sharded over the mesh, and
+  the optimizer is row-exact (a zero-gradient row keeps value and state:
+  Adagrad, plain SGD; not Adam, momentum or weight decay — probed, see
+  ``rowsparse.row_exact``). Otherwise, and in ``predict``/``evaluate``
+  or any plain ``apply``, the lookup is ``table.astype(dtype)[ids]`` as
+  before. Out-of-range ids are invalid input either way; the row path
+  reads the last row for them where the dense lookup reads NaN.
 * **Dot-product feature interaction** with static lower-triangle
   indices (no dynamic shapes), bf16 through the trunk, f32 logits.
 * Multi-hot bags: pass ids ``[B, n_tables, L]`` with sum/mean pooling —
@@ -89,6 +107,17 @@ class DLRMConfig:
         return "onehot" if jax.default_backend() != "cpu" else "take"
 
 
+#: The row path's two variable collections (``raydp_tpu/train/rowsparse.py``
+#: is the other side). A module whose parameter is looked up by row sows
+#: the flat ids into ``ROW_IDS`` under the parameter's own name, whenever
+#: the caller makes that collection mutable; an empty id vector says the
+#: lookup is not a gather. Where the caller passes ``ROWS`` with an entry
+#: of that name, it holds ``param[ids]``, ``[len(ids), D]``, and the module
+#: uses it instead of reading the parameter.
+ROW_IDS = "row_ids"
+ROWS = "rows"
+
+
 def _mlp_init(*logical_axes):
     return nn.with_logical_partitioning(
         nn.initializers.xavier_uniform(), logical_axes
@@ -120,13 +149,16 @@ class ShardedEmbedding(nn.Module):
             ),
             (self.vocab_size, self.embed_dim),
             self.param_dtype,
-        ).astype(self.dtype)
+        )
 
         squeeze = ids.ndim == 1
         if squeeze:
             ids = ids[:, None]              # [B, 1] — unify with bags
 
         if self.impl == "onehot":
+            # No row of a contraction can be handed in from outside.
+            self._sow_row_ids(jnp.zeros((0,), jnp.int32))
+            table = table.astype(self.dtype)
             # Sum over the bag inside the contraction: multiply the
             # one-hot along L before the matmul so the [B, V] operand is
             # the pooled bag indicator and the psum moves B×D, not B×L×D.
@@ -134,13 +166,29 @@ class ShardedEmbedding(nn.Module):
             bag = oh.sum(axis=1)            # [B, V]
             out = bag @ table               # GSPMD: local matmul + psum(tp)
         elif self.impl == "take":
-            out = jnp.take(table, ids, axis=0).sum(axis=1)
+            self._sow_row_ids(ids.reshape(-1))
+            if self.has_variable(ROWS, "table"):
+                # Row path: the caller gathered table[ids] already, in
+                # the table's own dtype; the table is not read here.
+                rows = self.get_variable(ROWS, "table").astype(self.dtype)
+                out = rows.reshape(ids.shape + (self.embed_dim,)).sum(axis=1)
+            else:
+                out = jnp.take(
+                    table.astype(self.dtype), ids, axis=0
+                ).sum(axis=1)
         else:
             raise ValueError(f"unknown embedding impl {self.impl!r}")
 
         if self.pooling == "mean" and not squeeze:
             out = out / ids.shape[1]
         return out                           # [B, D]
+
+    def _sow_row_ids(self, ids):
+        # Not while initializing: ``init`` makes every collection mutable,
+        # and the ids are no variable of the model.
+        if not self.is_initializing():
+            self.sow(ROW_IDS, "table", ids,
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
 
 
 class DotInteraction(nn.Module):

@@ -12,6 +12,7 @@ class, no process groups, no allreduce hooks).
 """
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import time
@@ -287,6 +288,10 @@ class JAXEstimator:
         self._train_step = None
         self._eval_step = None
         self._predict_step = None
+        # Row path of the train step: None until the first step is
+        # built, then its plan function, or False where nothing takes it.
+        self._row_plan = None
+        self._sample_batch = None
         # Device-plane state: live only while a stream fit runs.
         self._phases = None
         self._sentinel = None
@@ -365,6 +370,9 @@ class JAXEstimator:
             lambda: nn.unbox(create()), out_shardings=shardings
         ), "init_state")()
         self._state_shardings = shardings
+        self._sample_batch = jax.ShapeDtypeStruct(
+            (self.batch_size,) + tuple(sample_x.shape[1:]), sample.dtype
+        )
         self._build_steps()
 
     def _make_train_step(self):
@@ -374,26 +382,30 @@ class JAXEstimator:
         takes_deterministic = self._model_takes_deterministic()
         use_aux = self.aux_losses
 
-        def train_step(state: TrainState, x, y, rng):
+        def apply_kwargs(rng):
+            return (
+                dict(deterministic=False, rngs={"dropout": rng})
+                if takes_deterministic
+                else {}
+            )
+
+        def loss_of(state: TrainState, variables, x, y, rng):
             target = y if y is not None else x  # self-supervised: x IS y
-
-            def compute(params):
-                kwargs = (
-                    dict(deterministic=False, rngs={"dropout": rng})
-                    if takes_deterministic
-                    else {}
+            kwargs = apply_kwargs(rng)
+            if use_aux:
+                preds, mut = state.apply_fn(
+                    variables, x, mutable=["losses"], **kwargs
                 )
-                if use_aux:
-                    preds, mut = state.apply_fn(
-                        params, x, mutable=["losses"], **kwargs
-                    )
-                    from raydp_tpu.models.moe import moe_aux_loss
+                from raydp_tpu.models.moe import moe_aux_loss
 
-                    return loss_fn(preds, target) + moe_aux_loss(mut)
-                preds = state.apply_fn(params, x, **kwargs)
-                return loss_fn(preds, target)
+                return loss_fn(preds, target) + moe_aux_loss(mut)
+            preds = state.apply_fn(variables, x, **kwargs)
+            return loss_fn(preds, target)
 
-            loss_val, grads = jax.value_and_grad(compute)(state.params)
+        def train_step(state: TrainState, x, y, rng):
+            loss_val, grads = jax.value_and_grad(
+                lambda params: loss_of(state, params, x, y, rng)
+            )(state.params)
             # Global grad-norm rides along for the anomaly sentinel: an
             # Inf/NaN here flags divergence one step before the loss
             # shows it, and computing it on device costs one reduction.
@@ -406,7 +418,61 @@ class JAXEstimator:
                 state = state.apply_gradients(grads=grads)
             return state, loss_val, gnorm
 
-        return train_step
+        choose = self._row_path()
+        if choose is None:
+            return train_step
+        from raydp_tpu.models.dlrm import ROW_IDS
+        from raydp_tpu.train import rowsparse
+
+        def ids_of(state: TrainState, x, rng):
+            # Dead code but for the ids: no other output is used.
+            _, mut = state.apply_fn(
+                state.params, x, mutable=[ROW_IDS], **apply_kwargs(rng)
+            )
+            return mut.get(ROW_IDS, {})
+
+        return rowsparse.make_step(loss_of, ids_of, choose, train_step)
+
+    def _row_path(self):
+        """The plan function of the row path (``train/rowsparse.py``), or
+        None where no table of this model and optimizer can take it: the
+        step is then the dense one, op for op. Decided once, from one
+        abstract apply at the configured batch size; the verdict goes to
+        two gauges and one log line."""
+        if self._row_plan is not None:
+            return self._row_plan or None
+        from raydp_tpu.models.dlrm import ROW_IDS
+        from raydp_tpu.train import rowsparse
+
+        variables = self._state.params
+        self._row_plan = False
+        sown = {}
+        if isinstance(variables, dict) and "params" in variables:
+            sown = jax.eval_shape(
+                lambda v, x: self._model.apply(v, x, mutable=[ROW_IDS])[1],
+                variables, self._sample_batch,
+            ).get(ROW_IDS, {})
+        ids = rowsparse.table_paths(sown)
+        tables = {p: rowsparse.leaf_at(variables, p) for p in ids}
+        mesh = self._ensure_mesh()
+
+        def row_sharded(path) -> bool:
+            if not isinstance(self._state_shardings, TrainState):
+                return False  # one replicated sharding for the state
+            spec = rowsparse.leaf_at(self._state_shardings.params, path).spec
+            axes = spec[0] if len(spec) else None
+            axes = (axes,) if isinstance(axes, str) else (axes or ())
+            return any(mesh.shape[a] > 1 for a in axes)
+
+        choose = functools.partial(
+            rowsparse.plan, row_sharded=row_sharded,
+            tx_row_exact=bool(ids) and rowsparse.row_exact(self._tx),
+        )
+        verdicts = choose(ids, tables)
+        rowsparse.report(verdicts, tables)
+        if rowsparse.ROW in verdicts.values():
+            self._row_plan = choose
+        return self._row_plan or None
 
     def _build_steps(self) -> None:
         loss_fn = self._loss_fn
