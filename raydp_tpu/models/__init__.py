@@ -6,6 +6,7 @@ from raydp_tpu.models.transformer import (
     TransformerConfig,
     TransformerEncoder,
     bert_base,
+    olmoe,
     param_shardings,
     tiny_transformer,
 )
@@ -21,7 +22,6 @@ from raydp_tpu.models.dlrm import (
 )
 
 from raydp_tpu.models.moe import (
-    MoEBlock,
     MoEClassifier,
     MoEConfig,
     MoELayer,
@@ -31,7 +31,6 @@ from raydp_tpu.models.moe import (
 
 __all__ = [
     "PipelinedClassifier",
-    "MoEBlock",
     "MoEClassifier",
     "MoEConfig",
     "MoELayer",
@@ -52,6 +51,7 @@ __all__ = [
     "SequenceClassifier",
     "CausalLM",
     "bert_base",
+    "olmoe",
     "tiny_transformer",
     "param_shardings",
 ]

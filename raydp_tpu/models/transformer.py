@@ -61,6 +61,18 @@ class TransformerConfig:
     n_segments: int = 2
     dropout_rate: float = 0.1
     causal: bool = False
+    # What describes an architecture (the defaults are BERT's block).
+    norm: str = "layernorm"          # layernorm | rmsnorm
+    norm_eps: float = 1e-6
+    positions: str = "learned"       # learned (a position table) | rotary
+    rope_theta: float = 10000.0
+    qk_norm: bool = False            # norm over the whole q and k projections
+    use_bias: bool = True            # biases of the block's projections
+    ffn: str = "gelu"                # gelu (dense) | moe (routed SwiGLU)
+    n_experts: int = 0
+    top_k: int = 0
+    d_expert: int = 0
+    # How to run it.
     attention_impl: str = "dense"    # dense | ring | ulysses | flash
     remat: bool = False              # checkpoint blocks (memory-bound fits)
     dtype: Any = jnp.bfloat16        # compute dtype (MXU-friendly)
@@ -71,6 +83,15 @@ class TransformerConfig:
     def head_dim(self) -> int:
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    def moe_config(self):
+        from raydp_tpu.models.moe import MoEConfig
+
+        return MoEConfig(
+            d_model=self.d_model, d_ff=self.d_expert,
+            n_experts=self.n_experts, top_k=self.top_k,
+            dtype=self.dtype, param_dtype=self.param_dtype,
+        )
 
 
 def _dense_init(*logical_axes: str):
@@ -83,6 +104,35 @@ def _embed_init(*logical_axes: str):
     return nn.with_logical_partitioning(
         nn.initializers.normal(stddev=0.02), logical_axes
     )
+
+
+def _norm(cfg: TransformerConfig, name: str, dtype=None) -> nn.Module:
+    """The configuration's norm over the feature axis; its output in
+    ``dtype`` (the compute dtype unless given)."""
+    cls = {"layernorm": nn.LayerNorm, "rmsnorm": nn.RMSNorm}[cfg.norm]
+    return cls(
+        epsilon=cfg.norm_eps, dtype=dtype or cfg.dtype,
+        param_dtype=cfg.param_dtype, name=name,
+        scale_init=nn.with_logical_partitioning(
+            nn.initializers.ones, ("embed",)
+        ),
+    )
+
+
+def rotary(x, positions, theta: float):
+    """Rotary position embedding (Su et al. 2021) in the half-split form
+    of the published OLMoE/NeoX code: feature i pairs with i + D/2.
+    ``x`` [B, S, H, D], ``positions`` [B or 1, S]; float32 inside."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
+        jnp.float32
+    )
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
 
 
 class MultiHeadAttention(nn.Module):
@@ -103,12 +153,24 @@ class MultiHeadAttention(nn.Module):
             features=(3, cfg.n_heads, cfg.head_dim),
             axis=-1,
             kernel_init=_dense_init("embed", "qkv", "heads", "kv"),
-            use_bias=True,
+            use_bias=cfg.use_bias,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             name="qkv",
         )(x)
         q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+        if cfg.qk_norm:
+            # Over the whole projection, before the split into heads.
+            flat = q.shape[:-2] + (cfg.d_model,)
+            q = _norm(cfg, "q_norm")(q.reshape(flat)).reshape(q.shape)
+            k = _norm(cfg, "k_norm")(k.reshape(flat)).reshape(k.shape)
+        if cfg.positions == "rotary":
+            if cache_mode == "step":
+                pos = cache_positions[:, None]
+            else:
+                pos = jnp.arange(x.shape[-2])[None, :]
+            q = rotary(q, pos, cfg.rope_theta)
+            k = rotary(k, pos, cfg.rope_theta)
 
         if cache_mode is not None:
             # Per-slot KV cache rows (serve-plane autoregressive decode).
@@ -192,7 +254,7 @@ class MultiHeadAttention(nn.Module):
             features=cfg.d_model,
             axis=(-2, -1),
             kernel_init=_dense_init("heads", "kv", "embed"),
-            use_bias=True,
+            use_bias=cfg.use_bias,
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             name="out",
@@ -203,7 +265,10 @@ class MultiHeadAttention(nn.Module):
 
 
 class TransformerBlock(nn.Module):
-    """Pre-LN encoder block (trains stably in bf16 without warmup tricks)."""
+    """Pre-norm block (trains stably in bf16 without warmup tricks). Norm,
+    positions, QK-norm, biases and the kind of FFN come from the
+    configuration: BERT's encoder block and a routed decoder block are
+    the same code."""
 
     cfg: TransformerConfig
 
@@ -218,12 +283,7 @@ class TransformerBlock(nn.Module):
         kv_len: Optional[int] = None,
     ):
         cfg = self.cfg
-        y = nn.LayerNorm(
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="ln_attn",
-            scale_init=nn.with_logical_partitioning(
-                nn.initializers.ones, ("embed",)
-            ),
-        )(x)
+        y = _norm(cfg, "ln_attn")(x)
         x = x + MultiHeadAttention(cfg, name="attn")(
             y,
             deterministic,
@@ -232,27 +292,35 @@ class TransformerBlock(nn.Module):
             kv_len=kv_len,
         )
 
-        y = nn.LayerNorm(
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="ln_mlp",
-            scale_init=nn.with_logical_partitioning(
-                nn.initializers.ones, ("embed",)
-            ),
-        )(x)
-        y = nn.Dense(
-            cfg.d_ff,
-            kernel_init=_dense_init("embed", "mlp"),
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            name="mlp_up",
-        )(y)
-        y = nn.gelu(y)
-        y = nn.Dense(
-            cfg.d_model,
-            kernel_init=_dense_init("mlp", "embed"),
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            name="mlp_down",
-        )(y)
+        if cfg.ffn == "moe":
+            from raydp_tpu.models.moe import MoELayer
+
+            # The norm's output stays float32 for the router: one bf16
+            # rounding less between near-equal experts. The experts get
+            # it in the compute dtype.
+            y = _norm(cfg, "ln_mlp", jnp.float32)(x)
+            y = MoELayer(cfg.moe_config(), name="moe")(y)
+        elif cfg.ffn == "gelu":
+            y = _norm(cfg, "ln_mlp")(x)
+            y = nn.Dense(
+                cfg.d_ff,
+                kernel_init=_dense_init("embed", "mlp"),
+                use_bias=cfg.use_bias,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                name="mlp_up",
+            )(y)
+            y = nn.gelu(y)
+            y = nn.Dense(
+                cfg.d_model,
+                kernel_init=_dense_init("mlp", "embed"),
+                use_bias=cfg.use_bias,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                name="mlp_down",
+            )(y)
+        else:
+            raise ValueError(f"unknown ffn {cfg.ffn!r}")
         if cfg.dropout_rate > 0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic)
         x = x + y
@@ -285,17 +353,19 @@ class TransformerEncoder(nn.Module):
             embedding_init=_embed_init("vocab", "embed"),
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="tok_embed",
         )(input_ids)
-        if cache_mode == "step":
-            # Each slot's token sits at its own absolute position — the
-            # slot's current cache length, not a shared arange.
-            pos = jnp.minimum(cache_positions, cfg.max_len - 1)[:, None]
-        else:
-            pos = jnp.arange(input_ids.shape[-1])[None, :]
-        x = x + nn.Embed(
-            cfg.max_len, cfg.d_model,
-            embedding_init=_embed_init("seq", "embed"),
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="pos_embed",
-        )(pos)
+        if cfg.positions == "learned":
+            if cache_mode == "step":
+                # Each slot's token sits at its own absolute position —
+                # the slot's current cache length, not a shared arange.
+                pos = jnp.minimum(cache_positions, cfg.max_len - 1)[:, None]
+            else:
+                pos = jnp.arange(input_ids.shape[-1])[None, :]
+            x = x + nn.Embed(
+                cfg.max_len, cfg.d_model,
+                embedding_init=_embed_init("seq", "embed"),
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                name="pos_embed",
+            )(pos)
         if segment_ids is not None:
             x = x + nn.Embed(
                 cfg.n_segments, cfg.d_model,
@@ -324,12 +394,7 @@ class TransformerEncoder(nn.Module):
                 cache_positions=cache_positions,
                 kv_len=kv_len,
             )
-        return nn.LayerNorm(
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="ln_final",
-            scale_init=nn.with_logical_partitioning(
-                nn.initializers.ones, ("embed",)
-            ),
-        )(x)
+        return _norm(cfg, "ln_final")(x)
 
 
 class SequenceClassifier(nn.Module):
@@ -367,6 +432,7 @@ class SequenceClassifier(nn.Module):
 class CausalLM(nn.Module):
     """Decoder-only LM: the long-context flagship — pair with
     ``attention_impl='ring'`` to scale sequence length over the sp axis.
+    The output head is a matrix of its own (never tied to ``tok_embed``).
 
     Besides the teacher-forced ``__call__``, exposes the serve-plane
     decode pair: :meth:`prefill` runs the prompt once, writing per-slot
@@ -387,6 +453,7 @@ class CausalLM(nn.Module):
         self.lm_head = nn.Dense(
             self.cfg.vocab_size,
             kernel_init=_dense_init("embed", "vocab"),
+            use_bias=self.cfg.use_bias,
             dtype=jnp.float32,
             param_dtype=self.cfg.param_dtype,
         )
@@ -456,6 +523,24 @@ class CausalLM(nn.Module):
 def bert_base(**overrides) -> TransformerConfig:
     """BERT-base (the GLUE fine-tune target)."""
     return TransformerConfig(**overrides)
+
+
+def olmoe(**overrides) -> TransformerConfig:
+    """OLMoE-1B-7B (Muennighoff et al. 2024, arXiv:2409.02060; the
+    ``config.json`` of allenai/OLMoE-1B-7B-0125-Instruct): 16 pre-norm
+    decoder layers of width 2048, 16 heads of 128 with rotary positions
+    and an RMSNorm over the whole q and k projections, no biases, 64
+    SwiGLU experts of width 1024 with the 8 largest router probabilities
+    used as they are, vocabulary 50304, context 4096."""
+    defaults = dict(
+        vocab_size=50304, d_model=2048, n_heads=16, n_layers=16,
+        max_len=4096, dropout_rate=0.0, causal=True,
+        norm="rmsnorm", norm_eps=1e-5, positions="rotary",
+        rope_theta=10000.0, qk_norm=True, use_bias=False,
+        ffn="moe", n_experts=64, top_k=8, d_expert=1024,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
 
 
 def tiny_transformer(**overrides) -> TransformerConfig:

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -192,15 +193,29 @@ def flash_attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     causal: bool = False,
-    block_q: int = 128,
-    block_kv: int = 128,
+    block_q: Optional[int] = None,
+    block_kv: Optional[int] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Shapes [B, S, H, D] → [B, S, H, D]. S must divide by the blocks.
+    """Shapes [B, S, H, D] → [B, S, H, D]. S must divide by the blocks;
+    a block left out is the largest of 1024, 512, 256, 128 that divides S.
 
     Differentiable via custom_vjp; forward AND backward are blockwise
     pallas kernels (no S×S materialization anywhere)."""
-    return _flash_vjp(q, k, v, causal, block_q, block_kv, interpret)
+    s = q.shape[1]
+    return _flash_vjp(
+        q, k, v, causal, _block(block_q, s), _block(block_kv, s), interpret
+    )
+
+
+def _block(block: Optional[int], s: int) -> int:
+    """A tile edge. Large tiles pay on the chip: causal, S = 4096, 16
+    heads of 128, bf16, forward and backward on a TPU v5 lite took 39.2 ms
+    with 128 x 128 tiles, 17.0 with 256, 8.26 with 512 and 6.35 with 1024
+    (dense attention 14.7; PERF.md §6, PR 26)."""
+    if block is not None:
+        return block
+    return next((b for b in (1024, 512, 256, 128) if s % b == 0), s)
 
 
 def sharded_flash_attention(

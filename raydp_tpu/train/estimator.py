@@ -338,6 +338,8 @@ class JAXEstimator:
         sample = jnp.asarray(sample_x[:1])
         model, tx = self._model, self._tx
 
+        from raydp_tpu.models.moe import STATS as moe_stats
+
         def create():
             variables = model.init(rng, sample)
             # Output collections sown during init (MoE aux losses,
@@ -347,7 +349,7 @@ class JAXEstimator:
                 variables = {
                     k: v
                     for k, v in variables.items()
-                    if k not in ("losses", "intermediates")
+                    if k not in ("losses", "intermediates", moe_stats)
                 }
             return TrainState.create(
                 apply_fn=model.apply, params=variables, tx=tx
@@ -376,8 +378,12 @@ class JAXEstimator:
         self._build_steps()
 
     def _make_train_step(self):
-        """The (state, x, y, rng) → (state, loss) step shared by the
-        stream and scan paths."""
+        """The (state, x, y, rng) → (state, loss, grad norm, stats) step
+        shared by the stream and scan paths. ``stats`` is what the model
+        sowed about the step besides its loss (``models/moe.step_stats``:
+        the tokens each expert received), ``{}`` for most models; the
+        stream loop sums it on the device and fetches it with the epoch's
+        loss."""
         loss_fn = self._loss_fn
         takes_deterministic = self._model_takes_deterministic()
         use_aux = self.aux_losses
@@ -393,30 +399,35 @@ class JAXEstimator:
             target = y if y is not None else x  # self-supervised: x IS y
             kwargs = apply_kwargs(rng)
             if use_aux:
-                preds, mut = state.apply_fn(
-                    variables, x, mutable=["losses"], **kwargs
-                )
-                from raydp_tpu.models.moe import moe_aux_loss
+                from raydp_tpu.models import moe
 
-                return loss_fn(preds, target) + moe_aux_loss(mut)
+                preds, mut = state.apply_fn(
+                    variables, x, mutable=["losses", moe.STATS], **kwargs
+                )
+                with jax.named_scope("part:loss"):
+                    loss = loss_fn(preds, target) + moe.moe_aux_loss(mut)
+                return loss, moe.step_stats(mut)
             preds = state.apply_fn(variables, x, **kwargs)
-            return loss_fn(preds, target)
+            with jax.named_scope("part:loss"):
+                return loss_fn(preds, target), {}
 
         def train_step(state: TrainState, x, y, rng):
-            loss_val, grads = jax.value_and_grad(
-                lambda params: loss_of(state, params, x, y, rng)
+            (loss_val, stats), grads = jax.value_and_grad(
+                lambda params: loss_of(state, params, x, y, rng),
+                has_aux=True,
             )(state.params)
             # Global grad-norm rides along for the anomaly sentinel: an
             # Inf/NaN here flags divergence one step before the loss
             # shows it, and computing it on device costs one reduction.
             # The model's ops carry their flax module path; these two
-            # scopes name the only ops of the step that no module owns,
-            # so a device trace splits the whole step by part.
+            # scopes and ``part:loss`` name the only ops of the step that
+            # no module owns, so a device trace splits the whole step by
+            # part.
             with jax.named_scope("part:grad_norm"):
                 gnorm = optax.global_norm(grads)
             with jax.named_scope("part:update"):
                 state = state.apply_gradients(grads=grads)
-            return state, loss_val, gnorm
+            return state, loss_val, gnorm, stats
 
         choose = self._row_path()
         if choose is None:
@@ -519,6 +530,13 @@ class JAXEstimator:
         self._predict_step = _guard_compile(
             jax.jit(predict_step), "predict_step"
         )
+
+    def _pairs(self, loader):
+        """``(x, y)`` host batches of a loader; a label-less loader
+        (``self_supervised``) yields bare feature batches, so y is None."""
+        if self.label_column:
+            return loader
+        return ((x, None) for x in loader)
 
     def _model_takes_deterministic(self) -> bool:
         import inspect
@@ -846,7 +864,7 @@ class JAXEstimator:
                 loader.set_epoch(epoch)
             # Accumulate the loss ON DEVICE: a float() per step would sync
             # host↔device and serialize the prefetch/double-buffer pipeline.
-            loss_sum = None
+            loss_sum = stats_sum = None
             n_batches, n_samples = 0, 0
             to_skip = skip_batches if epoch == start_epoch else 0
             b_idx = to_skip
@@ -854,7 +872,7 @@ class JAXEstimator:
             def host_batches():
                 skipped = 0
                 for loader in loaders:
-                    for x, y in loader:
+                    for x, y in self._pairs(loader):
                         if skipped < to_skip:
                             skipped += 1
                             continue
@@ -893,6 +911,7 @@ class JAXEstimator:
                             try:
                                 (
                                     self._state, loss_val, grad_norm,
+                                    stats,
                                 ) = self._train_step(
                                     self._state, xd, yd, step_rng
                                 )
@@ -939,6 +958,10 @@ class JAXEstimator:
                     loss_sum = (
                         loss_val if loss_sum is None else loss_sum + loss_val
                     )
+                    if stats:
+                        stats_sum = stats if stats_sum is None else (
+                            jax.tree_util.tree_map(jnp.add, stats_sum, stats)
+                        )
                     n_batches += 1
                     b_idx += 1
                     steps_done += 1
@@ -972,6 +995,10 @@ class JAXEstimator:
                 train_loss = float(loss_sum) / max(1, n_batches) if (
                     loss_sum is not None
                 ) else 0.0
+                if stats_sum is not None:
+                    from raydp_tpu.models.moe import report_epoch
+
+                    report_epoch(jax.device_get(stats_sum), n_batches)
             if sentinel is not None:
                 # Epoch boundary always checks (the sampled cadence may
                 # never have landed on a NaN step in a short epoch).
@@ -1080,7 +1107,9 @@ class JAXEstimator:
                     xs, step = inp
                     ys = None
                 step_key = jax.random.fold_in(key, step)
-                state, loss_val, gnorm = train_step(state, xs, ys, step_key)
+                state, loss_val, gnorm, _ = train_step(
+                    state, xs, ys, step_key
+                )
                 return state, (loss_val, gnorm)
 
             xs_in = (
@@ -1260,7 +1289,7 @@ class JAXEstimator:
 
         def host_batches():
             for loader in loaders:
-                yield from loader
+                yield from self._pairs(loader)
 
         # Same double-buffered sharded infeed as fit(): batch N+1's H2D
         # overlaps batch N's eval step. Eval infeed must NOT accrue into

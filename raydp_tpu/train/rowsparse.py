@@ -286,8 +286,9 @@ def row_exact(tx: optax.GradientTransformation) -> bool:
 
 
 def make_step(loss_of, ids_of, choose, dense_step):
-    """The ``(state, x, y, rng) → (state, loss, gnorm)`` step on the row
-    path. ``loss_of(state, variables, x, y, rng)`` is the objective,
+    """The ``(state, x, y, rng) → (state, loss, gnorm, stats)`` step on the
+    row path. ``loss_of(state, variables, x, y, rng)`` is the objective
+    and what the model sowed about the step,
     ``ids_of(state, x, rng)`` the ``ROW_IDS`` collection of one apply,
     ``choose(ids, tables)`` the plan; a step whose plan is empty at its
     shapes is ``dense_step``."""
@@ -328,7 +329,9 @@ def make_step(loss_of, ids_of, choose, dense_step):
                 state, {**full, ROWS: unflatten_dict(rows)}, x, y, rng
             )
 
-        loss_val, grads = jax.value_and_grad(compute)(compact)
+        (loss_val, stats), grads = jax.value_and_grad(
+            compute, has_aux=True
+        )(compact)
         # The blocks hold every nonzero entry of the tables' gradients,
         # duplicates summed: the norm is the dense one.
         with jax.named_scope("part:grad_norm"):
@@ -340,6 +343,6 @@ def make_step(loss_of, ids_of, choose, dense_step):
         state = state.replace(
             step=state.step + 1, params=params, opt_state=opt_state
         )
-        return state, loss_val, gnorm
+        return state, loss_val, gnorm, stats
 
     return train_step

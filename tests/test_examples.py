@@ -14,6 +14,7 @@ EXAMPLES = [
     "jax_titanic.py",
     "dlrm_criteo.py",
     "bert_glue.py",
+    "olmoe_finetune.py",
     "gbt_nyctaxi.py",
     "spmd_job.py",
     "pod_driver.py",

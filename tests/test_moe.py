@@ -1,5 +1,6 @@
 """MoE tests: routing conservation, single-expert equivalence to a dense
-FFN, capacity drops, aux loss, expert-sharded execution on the mesh."""
+SwiGLU FFN, no token lost under extreme imbalance, aux loss, expert-sharded
+execution on the mesh."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +8,7 @@ import flax.linen as nn
 import pytest
 
 from raydp_tpu.models.moe import (
-    MoEBlock,
+    STATS,
     MoEConfig,
     MoELayer,
     moe_aux_loss,
@@ -21,68 +22,76 @@ def _tokens(t=32, d=32, seed=0):
     return jnp.asarray(rng.standard_normal((t, d)).astype(np.float32))
 
 
+def _init(layer, x):
+    """Parameters alone: what init sowed would be summed into an apply's."""
+    return {"params": nn.unbox(layer.init(jax.random.PRNGKey(0), x))["params"]}
+
+
+def _every_expert_masked(params, x, top_k):
+    """The layer written the plain way: every token through every expert,
+    times the router's probabilities where they are among the k largest."""
+    p = params["params"]
+    probs = jax.nn.softmax(x @ p["router"]["kernel"], axis=-1)
+    kth = jnp.sort(probs, axis=-1)[:, -top_k][:, None]
+    # Ties (a zero router) go to the lowest indices, as lax.top_k's do.
+    rank = jnp.argsort(jnp.argsort(-probs, axis=-1, stable=True), axis=-1)
+    weights = jnp.where((probs >= kth) & (rank < top_k), probs, 0.0)
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", x, p["w_gate"])) * jnp.einsum(
+        "td,edf->tef", x, p["w_up"]
+    )
+    return jnp.einsum("tef,efd,te->td", h, p["w_down"], weights)
+
+
 def test_single_expert_equals_dense_ffn():
-    """E=1, k=1, ample capacity: the MoE must reduce to a plain gelu FFN
-    with gate weight exactly 1 (softmax over one expert)."""
-    cfg = tiny_moe(n_experts=1, top_k=1, capacity_factor=1.0)
+    """E=1, k=1: the MoE must reduce to a plain SwiGLU FFN with gate
+    weight exactly 1 (softmax over one expert)."""
+    cfg = tiny_moe(n_experts=1, top_k=1)
     x = _tokens(16, cfg.d_model)
     layer = MoELayer(cfg)
-    params = nn.unbox(layer.init(jax.random.PRNGKey(0), x))
-    out, _ = layer.apply(params, x, mutable=["losses"])
+    params = _init(layer, x)
+    out, _ = layer.apply(params, x, mutable=["losses", STATS])
 
     p = params["params"]
-    h = jax.nn.gelu(x @ p["w_up"][0] + p["b_up"][0])
-    want = h @ p["w_down"][0] + p["b_down"][0]
+    want = (jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_up"][0])) @ p[
+        "w_down"
+    ][0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
 
 def test_topk_dispatch_conservation():
-    """With ample capacity every token is dispatched exactly top_k times
-    and combine weights equal its top-k router probabilities."""
-    cfg = tiny_moe(n_experts=4, top_k=2, capacity_factor=8.0)
+    """Every token reaches exactly top_k experts, weighted by its k
+    largest router probabilities as they are: the layer equals "every
+    expert on every token, masked", and the expert counts sum to T·k."""
+    cfg = tiny_moe(n_experts=4, top_k=2)
     x = _tokens(24, cfg.d_model, seed=1)
     layer = MoELayer(cfg)
-    params = layer.init(jax.random.PRNGKey(0), x)
-
-    # Reach into the router to recompute expectations.
-    router_kernel = nn.unbox(params)["params"]["router"]["kernel"]
-    probs = jax.nn.softmax(x @ router_kernel, axis=-1)
-    topk = jnp.sort(probs, axis=-1)[:, -2:].sum(-1)
-
-    # Re-run the layer capturing dispatch/combine via the ffn being
-    # identity-free: use capture through output magnitude instead —
-    # simpler: recompute with a fork that returns internals is overkill;
-    # assert instead that no token is dropped by checking the layer is
-    # close to a "full dispatch" manual computation.
-    out, _ = layer.apply(params, x, mutable=["losses"])
-    assert np.isfinite(np.asarray(out)).all()
-    # Combine-weight sum per token == sum of its top-2 probs; verify via
-    # linearity: scaling expert outputs is hard, so check the gates by
-    # reproducing the routing math.
-    masked = probs
-    total_gate = jnp.zeros(probs.shape[0])
-    for _ in range(2):
-        idx = jnp.argmax(masked, -1)
-        oh = jax.nn.one_hot(idx, 4)
-        total_gate = total_gate + (probs * oh).sum(-1)
-        masked = masked * (1 - oh)
+    params = _init(layer, x)
+    out, state = layer.apply(params, x, mutable=["losses", STATS])
     np.testing.assert_allclose(
-        np.asarray(total_gate), np.asarray(topk), atol=1e-6
+        np.asarray(out), np.asarray(_every_expert_masked(params, x, 2)),
+        atol=1e-5,
     )
+    counts = np.asarray(state[STATS]["expert_tokens"])
+    assert counts.sum() == 24 * 2
 
 
-def test_capacity_drops_tokens():
-    """capacity_factor≈0 forces drops: output must be ~zero for dropped
-    tokens (residual carries them), never NaN."""
-    cfg = tiny_moe(n_experts=2, top_k=1, capacity_factor=1e-6)
-    x = _tokens(16, cfg.d_model, seed=2)
+def test_no_token_is_lost_when_every_token_picks_the_same_experts():
+    """A zero router sends every token to experts 0 and 1 (ties go to the
+    lowest index): twelve times their fair share. No capacity, so every
+    token still gets both experts' outputs."""
+    cfg = tiny_moe(n_experts=4, top_k=2)
+    x = _tokens(48, cfg.d_model, seed=2)
     layer = MoELayer(cfg)
-    params = layer.init(jax.random.PRNGKey(0), x)
-    out, _ = layer.apply(params, x, mutable=["losses"])
-    # capacity = 1 per expert → at most 2 tokens produce nonzero output
-    nonzero = np.abs(np.asarray(out)).sum(axis=-1) > 1e-6
-    assert nonzero.sum() <= 2
-    assert np.isfinite(np.asarray(out)).all()
+    params = _init(layer, x)
+    params["params"]["router"]["kernel"] = jnp.zeros_like(
+        params["params"]["router"]["kernel"]
+    )
+    out, state = layer.apply(params, x, mutable=["losses", STATS])
+    counts = np.asarray(state[STATS]["expert_tokens"])
+    np.testing.assert_array_equal(counts, [48, 48, 0, 0])
+    want = _every_expert_masked(params, x, 2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+    assert (np.abs(np.asarray(out)).sum(axis=-1) > 1e-6).all()
 
 
 def test_aux_loss_sown():
@@ -102,10 +111,10 @@ def test_expert_sharded_on_mesh(eight_cpu_devices):
     from jax.sharding import NamedSharding, PartitionSpec as P
     from raydp_tpu.models.transformer import param_shardings
 
-    cfg = tiny_moe(n_experts=4, top_k=2, capacity_factor=4.0)
+    cfg = tiny_moe(n_experts=4, top_k=2)
     x = _tokens(32, cfg.d_model, seed=3)
     layer = MoELayer(cfg)
-    params = nn.unbox(layer.init(jax.random.PRNGKey(0), x))
+    params = _init(layer, x)
     want, _ = layer.apply(params, x, mutable=["losses"])
 
     mesh = MeshSpec(dp=4, tp=2).build()
@@ -113,7 +122,7 @@ def test_expert_sharded_on_mesh(eight_cpu_devices):
         layer, mesh, x,
         rules=(("expert", "dp"), ("embed", None), ("mlp", "tp")),
     )
-    params_sh = jax.device_put(params, shardings)
+    params_sh = jax.device_put(params, {"params": shardings["params"]})
     assert params_sh["params"]["w_up"].sharding.spec[0] == "dp"
     xd = jax.device_put(x, NamedSharding(mesh, P("dp")))
 
@@ -129,14 +138,18 @@ def test_expert_sharded_on_mesh(eight_cpu_devices):
 
 
 def test_moe_block_trains():
-    """An MoEBlock (attention + routed FFN) takes gradient steps and the
-    combined task+aux loss decreases."""
+    """A TransformerBlock whose FFN is routed (attention + MoE) takes
+    gradient steps and the combined task+aux loss decreases."""
     import optax
-    from raydp_tpu.models.transformer import tiny_transformer
+    from raydp_tpu.models.transformer import (
+        TransformerBlock,
+        tiny_transformer,
+    )
 
-    tcfg = tiny_transformer(d_model=32, n_heads=4, d_ff=64, dtype=jnp.float32)
-    mcfg = tiny_moe(d_model=32, d_ff=64)
-    block = MoEBlock(tcfg, mcfg)
+    block = TransformerBlock(tiny_transformer(
+        d_model=32, n_heads=4, dtype=jnp.float32, ffn="moe", n_experts=4,
+        top_k=2, d_expert=64,
+    ))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((4, 8, 32)).astype(np.float32))
     y = jnp.asarray(rng.standard_normal((4, 8, 32)).astype(np.float32))
@@ -188,7 +201,6 @@ def test_moe_classifier_through_estimator(eight_cpu_devices):
     )
     moe = MoEConfig(
         d_model=cfg.d_model, d_ff=cfg.d_ff, n_experts=4, top_k=1,
-        capacity_factor=2.0,
     )
     est = JAXEstimator(
         model=MoEClassifier(cfg=cfg, moe=moe, num_classes=2),
@@ -217,5 +229,6 @@ def test_moe_classifier_through_estimator(eight_cpu_devices):
     assert all(
         "dp" in str(x.sharding.spec) for _, x in expert_leaves
     ), [str(x.sharding.spec) for _, x in expert_leaves]
-    # the losses collection was stripped from trainable state
+    # the sown collections were stripped from trainable state
     assert "losses" not in est._state.params
+    assert STATS not in est._state.params

@@ -1,0 +1,417 @@
+"""The OLMoE-style decoder on the normal path, at tiny widths on the CPU
+(hidden 64, 8 experts top-2, vocabulary 512, sequence 32): the program
+against the benchmark's plain reference (logits, loss, gradients), the
+pieces of the block against a few lines of ``jax.numpy``, the permutation's
+backward, no scatter of ``T·k`` rows in the compiled step, BERT's parameter
+tree and checkpoints unchanged; and the routed layer and the flash kernels
+at the published widths compiled for a described TPU v5e."""
+import importlib.util
+import os
+import re
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from raydp_tpu.models import CausalLM, SequenceClassifier, bert_base
+from raydp_tpu.models.moe import (
+    STATS,
+    MoEConfig,
+    MoELayer,
+    combine_rows,
+    moe_aux_loss,
+    take_rows,
+)
+from raydp_tpu.models.transformer import MultiHeadAttention, rotary
+from raydp_tpu.train.losses import lm_crossentropy
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SIZES = {
+    "vocab_size": 512, "hidden_size": 64, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "num_hidden_layers": 2,
+    "max_position_embeddings": 32, "rms_norm_eps": 1e-5, "rope_theta": 10000,
+    "num_experts": 8, "num_experts_per_tok": 2, "intermediate_size": 32,
+    "norm_topk_prob": False, "tie_word_embeddings": False,
+    "attention_impl": "dense", "compute_dtype": "float32",
+    "param_dtype": "float32",
+}
+SEQ = 32
+
+
+@pytest.fixture(scope="module")
+def builder():
+    """The benchmark's builder file: the plain reference lives there."""
+    path = os.path.join(REPO, "benchmark", "configs", "olmoe_causal_lm.py")
+    spec = importlib.util.spec_from_file_location("olmoe_builder", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def tiny(builder):
+    model = CausalLM(builder.model_config(SIZES))
+    ids = jnp.asarray(np.random.default_rng(0).integers(
+        0, SIZES["vocab_size"], (2, SEQ)).astype(np.int32))
+    variables = nn.unbox(model.init(jax.random.PRNGKey(0), ids))
+    return model, {"params": variables["params"]}, ids
+
+
+def _program_loss(model, variables, ids):
+    logits, sown = model.apply(variables, ids, mutable=["losses", STATS])
+    return lm_crossentropy(logits, ids) + moe_aux_loss(sown)
+
+
+def _rel(got, want):
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
+# ---------------------------------------------- program against reference
+
+def test_logits_match_the_plain_reference(builder, tiny):
+    model, variables, ids = tiny
+    got = model.apply(variables, ids)
+    want = builder.reference_logits(variables, ids, SIZES)
+    assert got.shape == (2, SEQ, SIZES["vocab_size"])
+    assert _rel(got, want) < 1e-5
+
+
+def test_loss_and_gradients_match_the_plain_reference(builder, tiny):
+    model, variables, ids = tiny
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda v: _program_loss(model, v, ids)
+    ))(variables)
+    want_loss, want_grads = builder.reference_loss_and_grads(
+        variables, ids, SIZES
+    )
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    errors = jax.tree_util.tree_map(_rel, grads, want_grads)
+    assert max(jax.tree_util.tree_leaves(errors)) < 1e-4, errors
+
+
+@pytest.mark.parametrize("departure", [
+    "no_qk_norm", "renormalised_top_k", "8_bit_trunk",
+])
+def test_tolerance_refuses_a_departure_from_the_mathematics(
+    builder, tiny, departure
+):
+    """What the chip's check must catch: QK-norm left out of the program,
+    the top-k probabilities renormalised to sum to one, or a trunk in the
+    precision below bfloat16. Here, in float32 at init, the program is
+    within 1e-5 of the reference and each departure over 2% (on the chip,
+    on a trained state: 5-12% against 32-70%, tolerance 25%)."""
+    import dataclasses
+
+    model, variables, ids = tiny
+    want = builder.reference_logits(variables, ids, SIZES)
+    if departure == "no_qk_norm":
+        got = CausalLM(dataclasses.replace(model.cfg, qk_norm=False)).apply(
+            variables, ids
+        )
+    elif departure == "renormalised_top_k":
+        got = builder.reference_logits(
+            variables, ids, dict(SIZES, norm_topk_prob=True)
+        )
+    else:
+        got = builder.reference_logits(
+            variables, ids, SIZES, trunk=jnp.float8_e4m3fn
+        )
+    assert _rel(got, want) > 0.02
+
+
+def test_extreme_imbalance_loses_no_token(builder, tiny):
+    """A zero router in every layer sends all 64 tokens to experts 0 and
+    1; the program still equals the reference on every position."""
+    model, variables, ids = tiny
+    zeroed = jax.tree_util.tree_map_with_path(
+        lambda path, a: jnp.zeros_like(a)
+        if "router" in jax.tree_util.keystr(path) else a, variables,
+    )
+    got, sown = model.apply(zeroed, ids, mutable=["losses", STATS])
+    want = builder.reference_logits(zeroed, ids, SIZES)
+    assert _rel(got, want) < 1e-5
+    for counts in jax.tree_util.tree_leaves(sown[STATS]):
+        np.testing.assert_array_equal(
+            np.asarray(counts), [2 * SEQ, 2 * SEQ, 0, 0, 0, 0, 0, 0]
+        )
+
+
+def test_dropless_layer_is_every_expert_on_every_token_masked():
+    cfg = MoEConfig(d_model=64, d_ff=32, n_experts=8, top_k=2,
+                    dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (3, 20, 64)).astype(np.float32))
+    layer = MoELayer(cfg)
+    p = nn.unbox(layer.init(jax.random.PRNGKey(1), x))["params"]
+    got, sown = layer.apply({"params": p}, x, mutable=["losses", STATS])
+    tokens = x.reshape(-1, 64)
+    probs = jax.nn.softmax(tokens @ p["router"]["kernel"], axis=-1)
+    kth = jnp.sort(probs, axis=-1)[:, -2][:, None]
+    weights = jnp.where(probs >= kth, probs, 0.0)
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", tokens, p["w_gate"])) * (
+        jnp.einsum("td,edf->tef", tokens, p["w_up"]))
+    want = jnp.einsum("tef,efd,te->td", h, p["w_down"], weights)
+    np.testing.assert_allclose(
+        np.asarray(got.reshape(-1, 64)), np.asarray(want), atol=2e-5
+    )
+    assert float(sown[STATS]["expert_tokens"].sum()) == 60 * 2
+
+
+# ------------------------------------------------- the permutation's vjp
+
+def test_take_rows_vjp_is_the_plain_gathers_gradient():
+    rng = np.random.default_rng(2)
+    t, k, d = 16, 4, 8
+    x = jnp.asarray(rng.standard_normal((t, d)).astype(np.float32))
+    order = jnp.asarray(rng.permutation(t * k).astype(np.int32))
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    cot = jnp.asarray(rng.standard_normal((t * k, d)).astype(np.float32))
+
+    def plain(x):
+        return jnp.sum(x[order // k] * cot)
+
+    def ours(x):
+        return jnp.sum(take_rows(x, order, inverse, k) * cot)
+
+    np.testing.assert_allclose(jax.grad(ours)(x), jax.grad(plain)(x),
+                               rtol=1e-6, atol=1e-6)
+    rows = jnp.asarray(rng.standard_normal((t * k, d)).astype(np.float32))
+    np.testing.assert_allclose(
+        jax.grad(lambda r: jnp.sum(take_rows(r, inverse, order) * cot))(rows),
+        jax.grad(lambda r: jnp.sum(r[inverse] * cot))(rows),
+        rtol=1e-6, atol=1e-6,
+    )
+    # ... and neither direction lowers to a scatter; the plain one does.
+    lowered = jax.jit(jax.grad(ours)).lower(x).as_text()
+    assert "scatter" not in lowered and "stablehlo.gather" in lowered
+    assert "scatter" in jax.jit(jax.grad(plain)).lower(x).as_text()
+
+
+def test_combine_rows_vjp_is_the_plain_formulas_gradient():
+    rng = np.random.default_rng(3)
+    t, k, d = 12, 2, 8
+    rows = jnp.asarray(rng.standard_normal((t * k, d)).astype(np.float32))
+    gate = jnp.asarray(rng.random((t, k)).astype(np.float32))
+    order = jnp.asarray(rng.permutation(t * k).astype(np.int32))
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    cot = jnp.asarray(rng.standard_normal((t, d)).astype(np.float32))
+
+    def plain(rows, gate):
+        pairs = rows[inverse].reshape(t, k, d)
+        return jnp.sum(jnp.sum(pairs * gate[..., None], axis=1) * cot)
+
+    def ours(rows, gate):
+        return jnp.sum(combine_rows(rows, gate, order, inverse) * cot)
+
+    assert float(ours(rows, gate)) == pytest.approx(float(plain(rows, gate)))
+    for got, want in zip(jax.grad(ours, (0, 1))(rows, gate),
+                         jax.grad(plain, (0, 1))(rows, gate)):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def _scatter_update_rows(hlo: str):
+    """Leading dimension of the updates operand of every scatter."""
+    rows = []
+    for line in hlo.splitlines():
+        if " scatter(" not in line:
+            continue
+        shapes = re.findall(r"\w+\[([\d,]*)\]", line.split(" scatter(")[1])
+        updates = shapes[-1] if shapes else ""
+        rows.append(int(updates.split(",")[0]) if updates else 1)
+    return rows
+
+
+def test_compiled_step_has_no_scatter_over_the_token_expert_pairs(tiny):
+    """65,536 rows on the chip, 2 x 32 x 2 = 128 here: the only scatter of
+    rows a step may keep is the embedding's gradient (one row a token)."""
+    model, variables, ids = tiny
+    tx = optax.adamw(4e-4)
+
+    def step(variables, opt_state, ids):
+        loss, grads = jax.value_and_grad(
+            lambda v: _program_loss(model, v, ids)
+        )(variables)
+        updates, opt_state = tx.update(grads, opt_state, variables)
+        return optax.apply_updates(variables, updates), opt_state, loss
+
+    hlo = jax.jit(step).lower(
+        variables, tx.init(variables), ids
+    ).compile().as_text()
+    pairs = ids.size * SIZES["num_experts_per_tok"]
+    assert pairs not in _scatter_update_rows(hlo)
+    assert all(rows <= ids.size for rows in _scatter_update_rows(hlo))
+
+
+# ------------------------------------- the block's pieces, three lines each
+
+def test_rotary_against_three_lines_of_jnp():
+    x = jnp.asarray(np.random.default_rng(4).standard_normal(
+        (2, 8, 3, 16)).astype(np.float32))
+    got = rotary(x, jnp.arange(8)[None, :], 10000.0)
+    angle = np.arange(8)[:, None] * 10000.0 ** (-np.arange(8) / 8)
+    cos, sin = np.cos(angle)[None, :, None], np.sin(angle)[None, :, None]
+    want = jnp.concatenate([x[..., :8] * cos - x[..., 8:] * sin,
+                            x[..., 8:] * cos + x[..., :8] * sin], -1)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # Scores depend on the distance alone: shift both by 3 positions.
+    q, k = x[:, :, :1], x[:, :, 1:2]
+    near = jnp.einsum("bqhd,bkhd->bqk", rotary(q, jnp.arange(8)[None], 1e4),
+                      rotary(k, jnp.arange(8)[None], 1e4))
+    far = jnp.einsum("bqhd,bkhd->bqk", rotary(q, 3 + jnp.arange(8)[None], 1e4),
+                     rotary(k, 3 + jnp.arange(8)[None], 1e4))
+    np.testing.assert_allclose(near, far, rtol=1e-4, atol=1e-4)
+
+
+def test_rms_norm_and_qk_norm_against_three_lines_of_jnp(tiny):
+    model, variables, ids = tiny
+    cfg = model.cfg
+    x = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (2, SEQ, 64)).astype(np.float32))
+    attn = variables["params"]["encoder"]["block_0"]["attn"]
+    attn = dict(attn, q_norm={"scale": attn["q_norm"]["scale"] * 1.5})
+    got = MultiHeadAttention(cfg).apply({"params": attn}, x)
+
+    def rms(a, scale):
+        return a / jnp.sqrt(jnp.mean(a * a, -1, keepdims=True) + 1e-5) * scale
+
+    qkv = jnp.einsum("bsd,dthk->bsthk", x, attn["qkv"]["kernel"])
+    q = rms(qkv[:, :, 0].reshape(2, SEQ, 64), attn["q_norm"]["scale"])
+    k = rms(qkv[:, :, 1].reshape(2, SEQ, 64), attn["k_norm"]["scale"])
+    pos = jnp.arange(SEQ)[None]
+    q = rotary(q.reshape(2, SEQ, 4, 16), pos, 1e4)
+    k = rotary(k.reshape(2, SEQ, 4, 16), pos, 1e4)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / 4.0
+    scores = jnp.where(jnp.tril(jnp.ones((SEQ, SEQ), bool)), scores, -jnp.inf)
+    ctx = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
+                     qkv[:, :, 2])
+    want = jnp.einsum("bqhd,hdm->bqm", ctx, attn["out"]["kernel"])
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    # No bias, no position table, a head of its own.
+    assert set(attn["qkv"]) == {"kernel"} and set(attn["out"]) == {"kernel"}
+    enc = variables["params"]["encoder"]
+    assert "pos_embed" not in enc and set(enc["ln_final"]) == {"scale"}
+    assert set(variables["params"]["lm_head"]) == {"kernel"}
+
+
+# ------------------------------------------------- BERT stays what it was
+
+def test_bert_parameter_tree_and_checkpoint_are_unchanged(tmp_path):
+    """The shared block reads its kind from the configuration; BERT's
+    defaults give the tree PR 25's checkpoints were written with."""
+    from raydp_tpu.train import JAXEstimator
+
+    cfg = bert_base(vocab_size=100, d_model=32, n_heads=2, n_layers=2,
+                    d_ff=64, max_len=16)
+    model = SequenceClassifier(cfg=cfg, num_classes=2)
+    ids = jnp.zeros((1, 16), jnp.int32)
+    tree = jax.tree_util.tree_map(
+        lambda a: tuple(a.shape),
+        nn.unbox(model.init(jax.random.PRNGKey(0), ids))["params"],
+    )
+    block = {
+        "attn": {"out": {"bias": (32,), "kernel": (2, 16, 32)},
+                 "qkv": {"bias": (3, 2, 16), "kernel": (32, 3, 2, 16)}},
+        "ln_attn": {"bias": (32,), "scale": (32,)},
+        "ln_mlp": {"bias": (32,), "scale": (32,)},
+        "mlp_down": {"bias": (32,), "kernel": (64, 32)},
+        "mlp_up": {"bias": (64,), "kernel": (32, 64)},
+    }
+    assert tree == {
+        "encoder": {
+            "block_0": block, "block_1": block,
+            "ln_final": {"bias": (32,), "scale": (32,)},
+            "pos_embed": {"embedding": (16, 32)},
+            "tok_embed": {"embedding": (100, 32)},
+        },
+        "head": {"bias": (2,), "kernel": (32, 2)},
+        "pooler": {"bias": (32,), "kernel": (32, 32)},
+    }
+
+    def estimator():
+        return JAXEstimator(
+            model=model, optimizer=optax.adamw(2e-5), loss="softmax_ce",
+            feature_columns=["t"], label_column="y", batch_size=4,
+            feature_dtype=np.int32, label_dtype=np.int32, seed=0,
+        )
+
+    x = np.random.default_rng(0).integers(0, 100, (4, 16)).astype(np.int32)
+    first = estimator()
+    first._init_state(x)
+    first.save(str(tmp_path / "ckpt"))
+    second = estimator()
+    second.restore(str(tmp_path / "ckpt"), sample_x=x)
+    np.testing.assert_array_equal(first.predict(x), second.predict(x))
+
+
+# ------------------------------ published widths, compiled for the chip
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described TPU v5e (nothing runs on it). Only the process that is
+    given this file loads the TPU's library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def test_routed_layer_compiles_for_the_chip_at_published_widths(
+    one_chip, monkeypatch
+):
+    """8,192 tokens, 64 experts of 2048 x 1024, 8 a token: Mosaic accepts
+    the grouped-matmul tiling, the layer fits, and no scatter moves the
+    65,536 (token, expert) rows in either direction."""
+    # The kernels are compiled for the described chip, not interpreted.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = MoELayer(MoEConfig(d_model=2048, d_ff=1024, n_experts=64, top_k=8))
+    x = jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: nn.unbox(layer.init(jax.random.PRNGKey(0), jnp.zeros(
+            x.shape, x.dtype)))["params"]
+    )
+
+    def loss(p, x):
+        out, sown = layer.apply({"params": p}, x, mutable=["losses", STATS])
+        return jnp.mean(out.astype(jnp.float32) ** 2) + moe_aux_loss(sown)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        _on(one_chip, params), _on(one_chip, x)
+    ).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 9      # 3 products x 3 passes
+    assert all(rows < 8192 for rows in _scatter_update_rows(hlo))
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
+def test_flash_kernels_compile_for_the_chip_at_the_cells_shape(one_chip):
+    from raydp_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((2, 4096, 16, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True).astype(
+            jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on(one_chip, (q, q, q))
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    # No S x S scores: dense attention keeps 2 x 16 x 4096^2 float32 here.
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9

@@ -97,7 +97,7 @@ def _run_steps(est, batches):
     out = []
     for x, y in batches:
         xd, yd = est._shard_batch(x, y)
-        est._state, loss, gnorm = est._train_step(est._state, xd, yd, rng)
+        est._state, loss, gnorm, _ = est._train_step(est._state, xd, yd, rng)
         out.append((float(loss), float(gnorm)))
     return out
 
