@@ -448,10 +448,6 @@ def bench_bert():
         feature_dtype=np.int32,
         label_dtype=np.int32,
         shuffle=False,
-        # rbg: dropout-mask generation is ~25% of this step under the
-        # default threefry PRNG; rbg is also the partitionable impl on
-        # multi-chip meshes.
-        rng_impl="rbg",
         # One dispatch per epoch (dataset is small enough to live on
         # device): measured +7% over the streaming loop on CPU, and on
         # chip it removes every per-step host dispatch.

@@ -13,6 +13,8 @@ from typing import Callable, Optional, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from raydp_tpu.models.dropout import Dropout
+
 
 class MLP(nn.Module):
     """Dense stack: hidden layers + linear head."""
@@ -30,9 +32,7 @@ class MLP(nn.Module):
             x = nn.Dense(width, dtype=self.dtype)(x)
             x = self.activation(x)
             if self.dropout_rate > 0:
-                x = nn.Dropout(self.dropout_rate)(
-                    x, deterministic=deterministic
-                )
+                x = Dropout(self.dropout_rate)(x, deterministic)
         x = nn.Dense(self.out_dim, dtype=self.dtype)(x)
         return x
 

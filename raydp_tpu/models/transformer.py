@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from raydp_tpu.models.dropout import Dropout
 from raydp_tpu.ops.attention import (
     cached_decode_attention,
     reference_attention,
@@ -260,7 +261,7 @@ class MultiHeadAttention(nn.Module):
             name="out",
         )(out)
         if cfg.dropout_rate > 0:
-            out = nn.Dropout(cfg.dropout_rate)(out, deterministic)
+            out = Dropout(cfg.dropout_rate)(out, deterministic)
         return out
 
 
@@ -322,7 +323,7 @@ class TransformerBlock(nn.Module):
         else:
             raise ValueError(f"unknown ffn {cfg.ffn!r}")
         if cfg.dropout_rate > 0:
-            y = nn.Dropout(cfg.dropout_rate)(y, deterministic)
+            y = Dropout(cfg.dropout_rate)(y, deterministic)
         x = x + y
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
@@ -374,7 +375,7 @@ class TransformerEncoder(nn.Module):
                 name="seg_embed",
             )(segment_ids)
         if cfg.dropout_rate > 0:
-            x = nn.Dropout(cfg.dropout_rate)(x, deterministic)
+            x = Dropout(cfg.dropout_rate)(x, deterministic)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         # remat: recompute block activations in the backward instead of
         # storing them — the standard FLOPs-for-HBM trade that unlocks

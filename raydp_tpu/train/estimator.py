@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raydp_tpu import fault as _fault
 from raydp_tpu.data.ml_dataset import MLDataset
+from raydp_tpu.models import dropout
 from raydp_tpu.parallel.mesh import MeshSpec
 from raydp_tpu.telemetry import accounting as _acct
 from raydp_tpu.telemetry import event as _event
@@ -153,7 +154,6 @@ class JAXEstimator:
         prefetch: int = 2,
         infeed_depth: int = 2,
         drop_last: bool = False,
-        rng_impl: Optional[str] = None,
         train_config: Optional[Any] = None,
         data_config: Optional[Any] = None,
     ):
@@ -255,15 +255,6 @@ class JAXEstimator:
         # buffering).
         self.infeed_depth = max(1, infeed_depth)
         self.drop_last = drop_last
-        # PRNG implementation for the training rng chain (init, shuffle,
-        # dropout). None = jax's default (threefry). 'rbg' trades
-        # threefry's sharding-invariant bit streams for a much cheaper
-        # generator — the big win for dropout-heavy models: threefry mask
-        # generation measured ~25% of a BERT CPU train step, and on TPU
-        # rbg is the partitionable choice that avoids cross-chip rng
-        # gathers. The rng chain is rebuilt from (seed, rng_impl) on
-        # every fit/resume, so resume determinism holds per impl.
-        self.rng_impl = rng_impl
         # Model-parallel wiring: when the model carries flax logical-axis
         # metadata (all transformer/DLRM models in this repo do), state is
         # initialized SHARDED over the mesh per ``logical_rules`` — tp/sp
@@ -321,20 +312,13 @@ class JAXEstimator:
     def replicated(self) -> NamedSharding:
         return NamedSharding(self._ensure_mesh(), P())
 
-    def _prng_key(self, seed: int):
-        """A root key honoring ``rng_impl`` (typed keys propagate their
-        impl through every split/fold_in downstream)."""
-        if self.rng_impl:
-            return jax.random.key(seed, impl=self.rng_impl)
-        return jax.random.PRNGKey(seed)
-
     def _init_state(self, sample_x: np.ndarray) -> None:
         if self._state is not None:
             return
         import flax.linen as nn
 
         mesh = self._ensure_mesh()
-        rng = self._prng_key(self.seed)
+        rng = jax.random.PRNGKey(self.seed)
         sample = jnp.asarray(sample_x[:1])
         model, tx = self._model, self._tx
 
@@ -389,8 +373,11 @@ class JAXEstimator:
         use_aux = self.aux_losses
 
         def apply_kwargs(rng):
+            # ``rng`` is the step's key of the threefry chain; the masks
+            # come from the chip's bit generator (``models/dropout.py``).
             return (
-                dict(deterministic=False, rngs={"dropout": rng})
+                dict(deterministic=False,
+                     rngs={"dropout": dropout.key_for(rng)})
                 if takes_deterministic
                 else {}
             )
@@ -489,6 +476,13 @@ class JAXEstimator:
         loss_fn = self._loss_fn
         metric_fns = list(self._metrics)
         train_step = self._make_train_step()
+        sites, words = (
+            dropout.census(
+                self._model.apply, self._state.params, self._sample_batch
+            )
+            if self._model_takes_deterministic() else (0, 0)
+        )
+        dropout.report(sites, words)
 
         use_aux = self.aux_losses
 
@@ -813,7 +807,7 @@ class JAXEstimator:
             )
             for rank in range(train_ds.num_shards)
         ]
-        rng = self._prng_key(self.seed + 1)
+        rng = jax.random.PRNGKey(self.seed + 1)
         start_epoch, skip_batches = 0, 0
         if resume_from is not None:
             cols = train_ds.shard_columns(0, list(self.feature_columns))
@@ -1164,7 +1158,7 @@ class JAXEstimator:
         xd = jax.device_put(x, sharding)
         yd = jax.device_put(y, sharding) if y is not None else None
         epoch_fn = self._build_epoch_fn(n_steps, batch)
-        rng = self._prng_key(self.seed + 1)
+        rng = jax.random.PRNGKey(self.seed + 1)
         failures = 0
         # Scan mode has no per-step host loop, so phase accounting does
         # not apply; the sentinels still check each epoch's synced loss

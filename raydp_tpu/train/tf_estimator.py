@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from raydp_tpu.models.dropout import Dropout
 from raydp_tpu.train.estimator import JAXEstimator, TrainingCallback
 
 _ACTIVATIONS: Dict[str, Callable] = {
@@ -83,10 +84,7 @@ class KerasSequential(nn.Module):
                 act = c.get("activation", "linear") or "linear"
                 x = _activation(act)(x)
             elif cls == "Dropout":
-                x = nn.Dropout(
-                    rate=float(c.get("rate", 0.5)),
-                    deterministic=deterministic,
-                )(x)
+                x = Dropout(float(c.get("rate", 0.5)))(x, deterministic)
             elif cls == "Activation":
                 x = _activation(c["activation"])(x)
             elif cls in ("BatchNormalization", "LayerNormalization"):

@@ -335,3 +335,215 @@ def test_bad_epoch_mode_rejected():
             model=MLP(hidden=(4,)), epoch_mode="warp",
             feature_columns=["a"], label_column="y",
         )
+
+
+# ---------------------------------------------------------------------
+# Dropout masks: the chip's bit generator, once per site (PR 27). The
+# step's rng chain, ``model.init`` and the shuffle stay on threefry.
+def _tiny_estimator(kind, dropout_rate=0.1, **kwargs):
+    """A tiny encoder classifier (2 layers: 2 x 2 + 1 dropout sites, the
+    25-site analogue of BERT-base), packed DLRM or routed causal LM, and
+    one batch for it."""
+    import jax.numpy as jnp
+
+    from raydp_tpu.models import CausalLM, SequenceClassifier, bert_base, olmoe
+    from raydp_tpu.models.dlrm import PackedDLRM, tiny_dlrm
+
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, 100, (8, 16)).astype(np.int32)
+    common = dict(batch_size=8, seed=1, epoch_mode="stream",
+                  feature_columns=["x"], label_column="y")
+    if kind == "encoder":
+        cfg = bert_base(
+            vocab_size=100, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+            max_len=16, dropout_rate=dropout_rate, dtype=jnp.float32,
+        )
+        est = JAXEstimator(
+            model=SequenceClassifier(cfg=cfg, num_classes=2),
+            optimizer=optax.adamw(1e-3), loss="softmax_ce",
+            feature_dtype=np.int32, label_dtype=np.int32,
+            **{**common, **kwargs},
+        )
+        return est, tokens, np.zeros(8, np.int32)
+    if kind == "moe":
+        cfg = olmoe(
+            vocab_size=100, d_model=32, n_heads=2, n_layers=1, max_len=16,
+            n_experts=4, top_k=2, d_expert=16, dtype=jnp.float32,
+        )
+        est = JAXEstimator(
+            model=CausalLM(cfg), optimizer=optax.adamw(1e-3), loss="lm_ce",
+            self_supervised=True, aux_losses=True, feature_dtype=np.int32,
+            **{**common, "label_column": None, **kwargs},
+        )
+        return est, tokens, None
+    cfg = tiny_dlrm(
+        vocab_sizes=(50, 200, 30), embedding_impl="take", dtype=jnp.float32
+    )
+    x = np.concatenate(
+        [rng.standard_normal((8, cfg.dense_features)),
+         rng.integers(0, 30, (8, 3))], axis=1,
+    ).astype(np.float32)
+    est = JAXEstimator(
+        model=PackedDLRM(cfg), optimizer=optax.adagrad(0.05), loss="bce",
+        **{**common, **kwargs},
+    )
+    return est, x, np.zeros(8, np.float32)
+
+
+def _lowered_step(est, x, y, monkeypatch):
+    import jax
+
+    import raydp_tpu.train.estimator as estimator_module
+
+    # The guard wraps the jitted function in a plain one; lower the jitted.
+    monkeypatch.setattr(estimator_module, "_guard_compile", lambda f, _: f)
+    est._init_state(x)
+    xd, yd = est._shard_batch(x, y)
+    return est._train_step.lower(
+        est._state, xd, yd, jax.random.PRNGKey(0)
+    ).as_text()
+
+
+@pytest.mark.parametrize("kind,dropout_rate,sites", [
+    ("encoder", 0.1, 5), ("encoder", 0.0, 0), ("moe", 0.0, 0),
+    ("dlrm", 0.0, 0),
+])
+def test_dropout_masks_lower_to_the_bit_generator(
+    kind, dropout_rate, sites, monkeypatch
+):
+    import re
+
+    from raydp_tpu.utils.profiling import metrics
+
+    est, x, y = _tiny_estimator(kind, dropout_rate)
+    text = _lowered_step(est, x, y, monkeypatch)
+    mask_words = 8 * 16 * 32
+    assert metrics.gauge_value("train/dropout_sites") == sites
+    assert (metrics.gauge_value("train/dropout_mask_words_per_step")
+            == sites * mask_words)
+    generated = re.findall(
+        r"stablehlo\.rng_bit_generator.*-> \(tensor<[^>]*>, tensor<([^>]*)>\)",
+        text,
+    )
+    if not sites:
+        # No site draws a mask: the derived key is dead code, and the
+        # step lowers as it did before there was one.
+        assert not generated and "threefry" not in text
+        return
+    assert generated and set(generated) == {"8x16x32xui32"}
+    # ... and each mask is pinned as what the backward reads.
+    assert text.count("stablehlo.optimization_barrier") >= sites
+    # What is left of threefry works on keys (fold_in of a module's path,
+    # the step key's four words), never on a mask's worth of words.
+    threefry = [line for line in text.splitlines()
+                if line.lstrip().startswith("func.func") and "threefry" in line]
+    assert threefry
+    for line in threefry:
+        for dims in re.findall(r"tensor<((?:\d+x)*)ui32>", line):
+            words = int(np.prod([int(d) for d in dims.split("x") if d] or [1]))
+            assert words < mask_words / 64, line
+
+
+@pytest.mark.parametrize("kind", ["encoder", "mlp"])
+def test_init_and_eval_do_not_depend_on_the_mask_generator(kind):
+    """The parameters of a seed are those of a plain threefry
+    ``PRNGKey(seed)`` init, and evaluation draws no mask: only the
+    ``dropout`` collection's key changed."""
+    import flax.linen as nn
+    import jax
+
+    if kind == "encoder":
+        est, x, _ = _tiny_estimator("encoder", seed=7)
+    else:
+        est = JAXEstimator(
+            model=MLP(hidden=(16,), out_dim=1, dropout_rate=0.5),
+            batch_size=8, feature_columns=["x"], label_column="y", seed=7,
+        )
+        x = np.random.default_rng(0).standard_normal((8, 4)).astype(np.float32)
+    est._init_state(x)
+    sample = x[:1]
+    want = nn.unbox(jax.jit(
+        lambda: est._model.init(jax.random.PRNGKey(7), sample)
+    )())
+    got = jax.device_get(est._state.params)
+    flat_want = jax.tree_util.tree_leaves_with_path(want)
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    assert [p for p, _ in flat_got] == [p for p, _ in flat_want]
+    for (_, a), (_, b) in zip(flat_got, flat_want):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_allclose(
+        est.predict(x), est._model.apply(want, x), rtol=1e-6, atol=1e-6
+    )
+
+
+_PROBE_RATE = 0.1
+
+
+def _mask_probe(rows):
+    """``Dropout(x + w)`` with ``w`` one zero-initialised parameter per
+    element of the batch: under ``loss = sum`` and plain SGD of rate 1
+    the parameter moves by ``-mask / keep`` a step, so after a fit it IS
+    the sum of the masks the steps drew."""
+    import flax.linen as nn
+
+    from raydp_tpu.models.dropout import Dropout
+
+    class MaskProbe(nn.Module):
+        @nn.compact
+        def __call__(self, x, deterministic=True):
+            w = self.param("w", nn.initializers.zeros, (rows, x.shape[-1]))
+            return Dropout(_PROBE_RATE)(x + w, deterministic)
+
+    return MaskProbe()
+
+
+def _fit_mask_probe(rows, cols, steps, **kwargs):
+    """How often each element was kept over the ``steps`` steps of one
+    epoch, and the loss history."""
+    import jax
+
+    est = JAXEstimator(
+        model=_mask_probe(rows), optimizer=optax.sgd(1.0),
+        loss=lambda preds, _: preds.sum(), batch_size=rows, num_epochs=1,
+        feature_columns=[f"c{i}" for i in range(cols)], label_column="y",
+        shuffle=False, seed=3, **kwargs,
+    )
+    frame = pd.DataFrame(
+        np.ones((rows * steps, cols), np.float32), columns=est.feature_columns
+    )
+    frame["y"] = np.float32(0)
+    est.fit_on_df(rdf.from_pandas(frame, num_partitions=2), num_shards=1)
+    w = np.asarray(jax.device_get(est._state.params["params"]["w"]))
+    kept = np.rint(-w * (1 - _PROBE_RATE)).astype(int)
+    return kept, [h["train_loss"] for h in est.history]
+
+
+@pytest.mark.parametrize("epoch_mode", ["stream", "scan"])
+def test_dropout_masks_keep_nine_in_ten_and_follow_seed_and_step(epoch_mode):
+    kept, losses = _fit_mask_probe(4096, 64, steps=2, epoch_mode=epoch_mode)
+    assert set(np.unique(kept)) == {0, 1, 2}
+    assert abs(kept.mean() / 2 - 0.9) < 0.01
+    # Two steps, two masks: kept by exactly one of them is 2 x 0.9 x 0.1.
+    assert abs((kept == 1).mean() - 0.18) < 0.02
+    # The stream is a function of (seed, step): a second fit repeats it.
+    again, losses_again = _fit_mask_probe(
+        4096, 64, steps=2, epoch_mode=epoch_mode
+    )
+    np.testing.assert_array_equal(kept, again)
+    assert losses == losses_again
+
+
+@pytest.mark.parametrize("dp", [2, 4])
+def test_dropout_mask_shards_do_not_repeat_each_other(dp, eight_cpu_devices):
+    kept, _ = _fit_mask_probe(
+        64, 256, steps=1, mesh=MeshSpec(dp=dp), epoch_mode="stream"
+    )
+    assert abs(kept.mean() - 0.9) < 0.02
+    shards = kept.reshape(dp, 64 // dp, 256)
+    for i in range(dp):
+        for j in range(i + 1, dp):
+            # Independent masks agree on 0.82 of their elements.
+            assert (shards[i] == shards[j]).mean() < 0.9, (i, j)
+    # One mask whatever the mesh: the same seed on one device.
+    single, _ = _fit_mask_probe(64, 256, steps=1, epoch_mode="stream")
+    np.testing.assert_array_equal(kept, single)

@@ -4,6 +4,8 @@ The resume test is exact: a run interrupted at a mid-epoch checkpoint and
 resumed must reproduce the uninterrupted run's parameters bit-for-bit
 (deterministic per-epoch shuffle + fast-forwarded rng chain).
 """
+import functools
+
 import numpy as np
 import pandas as pd
 import pytest
@@ -70,18 +72,24 @@ def test_train_and_data_config_objects_wire():
     assert history[-1]["train_loss"] < history[0]["train_loss"]
 
 
-def test_midepoch_resume_is_exact(tmp_path):
+@pytest.mark.parametrize("dropout_rate", [0.0, 0.25])
+def test_midepoch_resume_is_exact(tmp_path, dropout_rate):
+    # With dropout the resumed run must also draw the masks the
+    # uninterrupted one drew: they are a function of (seed, step).
     ds = _ds()
     ckpt = str(tmp_path / "ck")
+    est = functools.partial(
+        _est, model=MLP(hidden=(16,), out_dim=1, dropout_rate=dropout_rate)
+    )
 
     # Uninterrupted run: 3 epochs.
-    a = _est()
+    a = est()
     a.fit(ds)
     params_a = jax.device_get(a._state.params)
 
     # Interrupted run: checkpoints every 3 steps; pretend it died, then a
     # FRESH estimator resumes from a mid-epoch checkpoint.
-    b1 = _est(checkpoint_dir=ckpt, save_every_steps=3)
+    b1 = est(checkpoint_dir=ckpt, save_every_steps=3)
     b1.fit(ds)
     # pick a checkpoint strictly inside the run (epoch > 0 preferred)
     import os
@@ -93,7 +101,7 @@ def test_midepoch_resume_is_exact(tmp_path):
     assert mids, "no mid-epoch checkpoints written"
     middle = mids[len(mids) // 2]
 
-    b2 = _est()
+    b2 = est()
     b2.fit(ds, resume_from=os.path.join(ckpt, middle))
     params_b = jax.device_get(b2._state.params)
 
