@@ -1781,79 +1781,6 @@ def bench_etl_shuffle():
     return out
 
 
-# ----------------------------------------------------------- device plane
-
-def bench_device_plane():
-    """Device-performance-plane evidence: (a) the phase fractions the
-    step accounting reports on a synthetic stream fit (they must sum to
-    ~1.0), and (b) the plane's overhead against the same fit with
-    ``RAYDP_TPU_DEVICE_PLANE=0`` — interleaved runs + medians, same
-    discipline as ``stage_stats_overhead``; budget <5%."""
-    import pandas as pd
-
-    from raydp_tpu.models.mlp import MLP
-    from raydp_tpu.train.estimator import JAXEstimator
-
-    n_rows, n_feat, batch = 16_384, 14, 256
-    rs = np.random.RandomState(11)
-    x = rs.rand(n_rows, n_feat).astype(np.float32)
-    w = rs.rand(n_feat, 1).astype(np.float32)
-    y = (x @ w).astype(np.float32)
-    cols = [f"f{i}" for i in range(n_feat)]
-    df = pd.DataFrame(x, columns=cols)
-    df["label"] = y
-
-    def one_fit():
-        est = JAXEstimator(
-            model=MLP(hidden=(64, 32), out_dim=1),
-            loss="mse",
-            num_epochs=1,
-            batch_size=batch,
-            feature_columns=cols,
-            label_column="label",
-            epoch_mode="stream",
-        )
-        t0 = time.perf_counter()
-        history = est.fit_on_df(df)
-        return time.perf_counter() - t0, history
-
-    one_fit()  # warm the jit caches both arms share
-    ons, offs = [], []
-    phases = None
-    try:
-        for i in range(10):
-            if i % 2 == 0:
-                dt, history = one_fit()
-                ons.append(dt)
-                phases = history[-1].get("phases") or phases
-            else:
-                os.environ["RAYDP_TPU_DEVICE_PLANE"] = "0"
-                offs.append(one_fit()[0])
-                os.environ.pop("RAYDP_TPU_DEVICE_PLANE", None)
-    finally:
-        os.environ.pop("RAYDP_TPU_DEVICE_PLANE", None)
-    ons.sort(), offs.sort()
-    on_s, off_s = ons[len(ons) // 2], offs[len(offs) // 2]
-    out = {
-        "samples_per_sec": round(n_rows / on_s, 1),
-        "unit": "samples/s",
-        "enabled_s": round(on_s, 4),
-        "disabled_s": round(off_s, 4),
-        "overhead_frac": round(
-            (on_s - off_s) / off_s if off_s else 0.0, 4
-        ),
-        "baseline": "same fit with RAYDP_TPU_DEVICE_PLANE=0",
-    }
-    if phases:
-        out["phases"] = phases
-        out["frac_sum"] = round(sum(
-            phases.get(k, 0.0)
-            for k in ("input_wait_frac", "dispatch_frac",
-                      "compute_frac", "collective_frac")
-        ), 4)
-    return out
-
-
 # ----------------------------------------------------------- job accounting
 
 def bench_job_accounting():
@@ -2306,7 +2233,7 @@ def bench_multi_tenant():
 def _capture_gang_profile() -> dict:
     """``--profile``: spin a 2-rank SPMD gang running a small stream
     fit and gang-capture a trace mid-training; the merged Perfetto path
-    + the fit's phase fractions stamp into the result JSON. CPU-pinned
+    stamps into the result JSON. CPU-pinned
     (the evidence is the machinery, not chip speed)."""
     import threading as _threading
 
@@ -2337,8 +2264,7 @@ def _capture_gang_profile() -> dict:
             label_column="label",
             epoch_mode="stream",
         )
-        history = est.fit_on_df(df)
-        return history[-1].get("phases")
+        est.fit_on_df(df)
 
     job = SPMDJob(
         "bench-profile", world_size=2,
@@ -2350,7 +2276,7 @@ def _capture_gang_profile() -> dict:
 
         def _run():
             try:
-                results["phases"] = job.run(rank_fit, timeout=300.0)
+                job.run(rank_fit, timeout=300.0)
             except Exception as exc:
                 results["error"] = f"{type(exc).__name__}: {exc}"
 
@@ -2363,8 +2289,6 @@ def _capture_gang_profile() -> dict:
             "merged_trace": merged.get("merged_trace"),
             "ranks": merged.get("ranks"),
         }
-        if results.get("phases"):
-            profile["phases"] = results["phases"]
         if results.get("error"):
             profile["fit_error"] = results["error"]
         if merged.get("errors"):
@@ -2822,7 +2746,6 @@ MATRIX = [
     # device math — full size even at the reduced CPU sizes.
     ("dataplane", bench_dataplane),
     # Phase-accounting overhead + fraction evidence (host-side fit).
-    ("device_plane", bench_device_plane),
     # Job-accounting-plane overhead + per-job attribution evidence
     # (host-side ETL under an explicit job scope).
     ("job_accounting", bench_job_accounting),
@@ -2870,7 +2793,7 @@ MATRIX = [
 _STATE = {
     "configs": {},    # name -> stamped result
     "device": None,   # {"platform", "device_kind", "device_count"}
-    "profile": None,  # --profile: merged gang trace path + phases
+    "profile": None,  # --profile: merged gang trace path
     "analysis": None,  # raydpcheck wall-time (checker perf regression)
     "notes": [],
     "emitted": False,

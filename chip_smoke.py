@@ -284,9 +284,6 @@ def run_train(sizes: dict, platform: str, mesh_axes: dict) -> dict:
                 fit["steady_samples_per_s"] = round(
                     history[-1]["samples_per_sec"], 1
                 )
-            # None on a TPU today: the fit derives it from
-            # Lowered.cost_analysis(), which this backend leaves empty.
-            fit["mfu_reported_by_fit"] = history[-1].get("mfu")
             log(f"fit: {fit}")
             out["fits"].append(fit)
     finally:

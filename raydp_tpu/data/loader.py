@@ -584,10 +584,10 @@ def _background(it: Iterator, depth: int):
     thread.start()
 
     def consume():
-        # Consumer-side starvation is the input-wait half of the step
-        # phase model: every second spent blocked here is a second the
-        # training loop sat idle waiting for data. The producer already
-        # accounts its own pack/put time; this counter closes the gap.
+        # Consumer-side starvation: every second spent blocked here is
+        # a second the training loop sat idle waiting for data. The
+        # producer already accounts its own pack/put time; this counter
+        # closes the gap.
         while True:
             if err:
                 raise err[0]

@@ -1315,7 +1315,7 @@ class GroupedData:
                 else:
                     merge_specs.append((p, mergeable[op]))
                     rename[f"{p}_{mergeable[op]}"] = p
-            merged = t.group_by(keys).aggregate(merge_specs)
+            merged = _group_agg(t, keys, merge_specs)
             merged = merged.rename_columns(
                 [rename.get(c, c) for c in merged.column_names]
             )
@@ -1339,7 +1339,7 @@ class GroupedData:
                     {**{k: pc.take(t.column(k), parents) for k in keys},
                      p: flat}
                 )
-                sub_agg = sub.group_by(keys).aggregate([(p, final)])
+                sub_agg = _group_agg(sub, keys, [(p, final)])
                 sub_agg = sub_agg.rename_columns(
                     [p if c == f"{p}_{final}" else c
                      for c in sub_agg.column_names]
@@ -2069,6 +2069,18 @@ def _shuffle_join(
     return out
 
 
+# Aggregators whose answer depends on row order: pyarrow refuses them
+# in a threaded group-by.
+_ORDERED_AGGS = frozenset({"first", "last", "first_last"})
+
+
+def _group_agg(t: pa.Table, keys: List[str], aggs: list) -> pa.Table:
+    """``t.group_by(keys).aggregate(aggs)``, on one thread only where
+    an aggregator is ordered; counts and sums keep arrow's threads."""
+    ordered = any(agg[1] in _ORDERED_AGGS for agg in aggs)
+    return t.group_by(keys, use_threads=not ordered).aggregate(aggs)
+
+
 def _direct_agg_supported(specs: List[Tuple[str, str]]) -> bool:
     """Ops arrow's hash aggregation can finalize in ONE pass. collect_*
     need the flatten/re-aggregate dance (null-dropping list semantics),
@@ -2117,7 +2129,7 @@ def _direct_agg(
             out_names.append(f"{op}({col_name})")
         else:
             raise ValueError(f"unsupported aggregation {op!r}")
-    agged = t.group_by(keys).aggregate(arrow_aggs)
+    agged = _group_agg(t, keys, arrow_aggs)
     n_keys = len(agged.column_names) - len(arrow_aggs)
     arrays = {
         k: agged.column(i)
@@ -2154,7 +2166,7 @@ def _local_agg(
         else:
             arrow_op = "distinct" if op == "cdistinct" else op
             arrow_aggs.append((col_name, arrow_op))
-    out = t.group_by(keys).aggregate(arrow_aggs)
+    out = _group_agg(t, keys, arrow_aggs)
     # Positional rename: pyarrow emits key columns first, then one output
     # per aggregation IN ORDER (duplicate names possible when two partials
     # lower to the same arrow op, e.g. collect_set + count_distinct).

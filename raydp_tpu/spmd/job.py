@@ -578,8 +578,6 @@ class SPMDJob:
         RSS, device HBM used/peak, plus XLA compile counters — the
         training-side face of the query-profiling plane. Ranks that have
         not yet shipped gauges appear with empty dicts."""
-        from raydp_tpu.telemetry import device_profiler
-
         view = self.telemetry.merged()
         ranks = {}
         for rid, sections in sorted((view.get("workers") or {}).items()):
@@ -594,23 +592,6 @@ class SPMDJob:
                 "compile_seconds": counters.get("compile/seconds", 0.0),
                 "compile_failures": counters.get("compile/failures", 0),
             }
-            # Device performance plane, when the rank has shipped phase
-            # gauges (set at each epoch boundary by the estimator).
-            fractions = {
-                name: gauges[f"phase/{name}"]
-                for name in ("input_wait_frac", "dispatch_frac",
-                             "compute_frac", "collective_frac")
-                if f"phase/{name}" in gauges
-            }
-            if fractions:
-                ranks[rid]["phases"] = fractions
-                ranks[rid]["bound"] = device_profiler.classify_fractions(
-                    fractions,
-                    gauges.get("roofline/intensity_flops_per_byte"),
-                    gauges.get("roofline/machine_balance"),
-                )
-            if "mfu" in gauges:
-                ranks[rid]["mfu"] = gauges["mfu"]
         agg = view.get("aggregate") or {}
         agg_gauges = agg.get("gauges") or {}
         agg_counters = agg.get("counters") or {}

@@ -35,11 +35,9 @@ without pulling in jax):
   postmortem bundles (event tail + all-thread stacks), and
   trace-stamped JSONL structured logs.
 
-* :mod:`~raydp_tpu.telemetry.device_profiler` — the device performance
-  plane: per-step phase breakdown (input-wait / dispatch / compute /
-  collective), live MFU + roofline bound-ness from HLO cost analysis,
-  gang-coordinated ``jax.profiler`` capture merged into one Perfetto
-  trace (``Cluster.capture_profile()`` / ``/debug/profile``), and
+* :mod:`~raydp_tpu.telemetry.device_profiler` — gang-coordinated
+  ``jax.profiler`` capture merged into one Perfetto trace
+  (``Cluster.capture_profile()`` / ``/debug/profile``), and
   NaN / step-regression anomaly sentinels.
 
 * :mod:`~raydp_tpu.telemetry.accounting` /
@@ -113,9 +111,7 @@ from raydp_tpu.telemetry.events import (
 )
 from raydp_tpu.telemetry.device_profiler import (
     AnomalySentinel,
-    StepPhaseAccumulator,
     capture_trace_archive,
-    classify_fractions,
     merge_rank_traces,
 )
 from raydp_tpu.telemetry.progress import (
@@ -204,9 +200,7 @@ __all__ = [
     "load_event_records",
     "mttr_report",
     "AnomalySentinel",
-    "StepPhaseAccumulator",
     "capture_trace_archive",
-    "classify_fractions",
     "merge_rank_traces",
     "Watchdog",
     "inflight",

@@ -40,10 +40,6 @@ __all__ = [
 
 STEP_SPAN = "train/step"
 DATA_SPANS = ("ingest/chunk",)
-PHASES_EVENT = "train/phases"
-_PHASE_FRACS = (
-    "input_wait_frac", "dispatch_frac", "compute_frac", "collective_frac",
-)
 
 
 def _pct(sorted_vals: List[float], q: float) -> float:
@@ -170,50 +166,6 @@ def _data_compute(
     return split
 
 
-def _device_plane(
-    records: List[Dict[str, Any]], labels: Dict[int, str]
-) -> Dict[str, Dict[str, Any]]:
-    """Per-process step-phase breakdown from ``train/phases`` events
-    (one per epoch, emitted by the estimator's device plane). Fractions
-    are wall-weighted across the process's epochs; ``bound`` and ``mfu``
-    come from the latest epoch — the steady-state view."""
-    by_proc: Dict[str, List[Dict[str, Any]]] = {}
-    for rec in records:
-        if rec.get("name") != PHASES_EVENT or rec.get("kind") != "event":
-            continue
-        attrs = rec.get("attrs") or {}
-        by_proc.setdefault(_proc_label(rec, labels), []).append(
-            {"seq": rec.get("seq", 0), **attrs}
-        )
-    plane: Dict[str, Dict[str, Any]] = {}
-    for label, epochs in by_proc.items():
-        epochs.sort(key=lambda e: e["seq"])
-        total_wall = sum(float(e.get("wall_s", 0.0)) for e in epochs)
-        entry: Dict[str, Any] = {
-            "epochs": len(epochs),
-            "steps": int(sum(e.get("steps", 0) for e in epochs)),
-            "wall_s": round(total_wall, 6),
-        }
-        for frac in _PHASE_FRACS:
-            weighted = sum(
-                float(e.get(frac, 0.0)) * float(e.get("wall_s", 0.0))
-                for e in epochs
-            )
-            entry[frac] = round(
-                weighted / total_wall if total_wall > 0 else 0.0, 4
-            )
-        last = epochs[-1]
-        entry["bound"] = last.get("bound", "?")
-        if "mfu" in last:
-            entry["mfu"] = last["mfu"]
-        if "intensity_flops_per_byte" in last:
-            entry["intensity_flops_per_byte"] = (
-                last["intensity_flops_per_byte"]
-            )
-        plane[label] = entry
-    return plane
-
-
 def _job_rollup(
     records: List[Dict[str, Any]], offsets: Dict[int, float]
 ) -> Dict[str, Dict[str, Any]]:
@@ -269,10 +221,8 @@ def analyze_records(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         "critical_path": _critical_path(main_trace, offsets, labels),
         "step_skew": _step_skew(main_trace, labels),
         "data_compute": _data_compute(main_trace, labels),
-        # All records, not just the dominant trace: a standalone fit's
-        # phase events may carry their own trace id.
-        "device_plane": _device_plane(records, labels),
-        # Likewise all records: each job's timeline is its own trace.
+        # All records, not just the dominant trace: each job's
+        # timeline is its own trace.
         "jobs": _job_rollup(records, offsets),
     }
 
@@ -374,26 +324,6 @@ def format_report(report: Dict[str, Any]) -> str:
             f" · compute {entry['compute_s']:.4f}s"
             f" · data-wait {entry['data_frac'] * 100:.1f}%"
         )
-    plane = report.get("device_plane") or {}
-    if plane:
-        lines += ["", "device plane (step phases):"]
-        lines.append(
-            f"  {'rank':<16} {'steps':>6} {'input':>7} {'dispatch':>8}"
-            f" {'compute':>8} {'coll':>6}  bound"
-        )
-        for label in sorted(plane):
-            entry = plane[label]
-            extra = ""
-            if "mfu" in entry:
-                extra = f" · mfu {entry['mfu'] * 100:.1f}%"
-            lines.append(
-                f"  {label:<16} {entry['steps']:>6}"
-                f" {entry['input_wait_frac'] * 100:>6.1f}%"
-                f" {entry['dispatch_frac'] * 100:>7.1f}%"
-                f" {entry['compute_frac'] * 100:>7.1f}%"
-                f" {entry['collective_frac'] * 100:>5.1f}%"
-                f"  {entry['bound']}{extra}"
-            )
     jobs = report.get("jobs") or {}
     if jobs:
         lines += ["", "jobs (event timeline):"]

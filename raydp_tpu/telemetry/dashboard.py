@@ -1,7 +1,7 @@
 """Unified flywheel dashboard: the whole system in one view.
 
-Every plane built so far reports somewhere — training MFU and step
-phases in the device profiler, ETL stage rows in the operator metrics,
+Every plane built so far reports somewhere — training step times and
+anomalies in the estimator, ETL stage rows in the operator metrics,
 serving latency/fill/shed in the replica group, pool size and queue
 depth in the autoscaler and arbiter, objective status in the SLO
 engine — but each lives behind its own report call. This module folds
@@ -81,18 +81,12 @@ def build(
     shuffle_bytes = g("shuffle/bytes") or 0.0
     shuffle_local = g("shuffle/local_bytes") or 0.0
     train = {
-        "mfu": _rounded(g("mfu")),
         "step_p50_ms": _ms(g("train/step/p50_s")),
         "step_p99_ms": _ms(g("train/step/p99_s")),
         "steps": g("train/step/count"),
         "restarts": g("restarts/total"),
         "preemptions": g("preemptions/total"),
         "watchdog_stalls": g("watchdog/stalls"),
-        "phase_fractions": {
-            name: _rounded(g(f"phase/{name}_frac"))
-            for name in ("input_wait", "dispatch", "compute", "collective")
-            if g(f"phase/{name}_frac") is not None
-        },
         "anomalies": _collect_prefix(flat, "anomalies/"),
     }
     etl = {

@@ -405,12 +405,6 @@ def render_prometheus(view: Dict[str, Any]) -> str:
         "MetricsRegistry gauges without a dedicated family, one series "
         "per (worker, name).",
     )
-    mfu = _Family(
-        "raydp_mfu", "gauge",
-        "Model FLOPs utilization: analytical step FLOPs (HLO cost "
-        "analysis) over measured step wall x device peak. Absent on "
-        "backends without a known peak (CPU).",
-    )
     anomalies = _Family(
         "raydp_anomalies_total", "counter",
         "Training anomaly sentinel trips (kind=nan_loss|nan_grad_norm|"
@@ -418,8 +412,8 @@ def render_prometheus(view: Dict[str, Any]) -> str:
     )
     step_hist = _Family(
         "raydp_step_seconds", "histogram",
-        "Training step wall time (jitted-call dispatch; donated-buffer "
-        "block makes steady-state dispatch = device step time).",
+        "Training step dispatch time: the jitted call, which blocks only "
+        "while the device's queue is full. Not a device step time.",
     )
     generic_hist = _Family(
         "raydp_histogram", "histogram",
@@ -1116,8 +1110,6 @@ def render_prometheus(view: Dict[str, Any]) -> str:
                         sim_knee.add({"worker": worker_id}, value)
                     elif name == "sim/events_per_s":
                         sim_events_rate.add({"worker": worker_id}, value)
-                    elif name == "mfu":
-                        mfu.add({"worker": worker_id}, value)
                     elif name.startswith("slo/status/"):
                         slo_status.add(
                             {"worker": worker_id,
@@ -1238,7 +1230,7 @@ def render_prometheus(view: Dict[str, Any]) -> str:
                    loadgen_achieved_rps, loadgen_knee_rps,
                    events_dropped, slo_status, slo_burn, slo_breaches,
                    host_rss,
-                   hbm_bytes, store_occupancy, mfu, anomalies, step_hist,
+                   hbm_bytes, store_occupancy, anomalies, step_hist,
                    generic_hist, gauges):
         lines.extend(family.render())
     return "\n".join(lines) + ("\n" if lines else "")

@@ -54,7 +54,6 @@ __all__ = [
     "SLO_BURN_THRESHOLD_ENV",
     "SLO_RECOVERY_EVALS_ENV",
     "SLO_QUEUE_WAIT_ENV",
-    "SLO_MFU_FLOOR_ENV",
     "slo_enabled",
     "Objective",
     "SloConfig",
@@ -72,7 +71,6 @@ SLO_BUDGET_ENV = "RAYDP_TPU_SLO_BUDGET"
 SLO_BURN_THRESHOLD_ENV = "RAYDP_TPU_SLO_BURN_THRESHOLD"
 SLO_RECOVERY_EVALS_ENV = "RAYDP_TPU_SLO_RECOVERY_EVALS"
 SLO_QUEUE_WAIT_ENV = "RAYDP_TPU_SLO_QUEUE_WAIT_S"
-SLO_MFU_FLOOR_ENV = "RAYDP_TPU_SLO_MFU_FLOOR"
 
 #: Fixed thresholds for the rate objectives (rates are "per second of
 #: wall clock"; any sustained nonzero restart/stall rate is already an
@@ -123,7 +121,7 @@ class Objective:
     is ``"value"`` (judge windowed sample values against the
     threshold) or ``"rate"`` (judge the windowed per-second increase).
     ``op`` is ``"gt"`` (violating when above the threshold) or
-    ``"lt"`` (below — e.g. an MFU floor).
+    ``"lt"`` (below — e.g. a throughput floor).
     """
 
     name: str
@@ -163,11 +161,9 @@ class SloConfig:
 
 def default_objectives() -> List[Objective]:
     """The built-in flywheel objectives, thresholds from the existing
-    env surface. The MFU floor ships disabled (0.0) until
-    ``RAYDP_TPU_SLO_MFU_FLOOR`` is set — there is no universal floor
-    across models and backends."""
+    env surface."""
     serve_slo_s = _env_float("RAYDP_TPU_SERVE_SLO_MS", 50.0) / 1000.0
-    objectives = [
+    return [
         Objective(
             name="serve_p99",
             series="serve/latency/p99_s",
@@ -227,17 +223,6 @@ def default_objectives() -> List[Objective]:
                         "training)",
         ),
     ]
-    mfu_floor = _env_float(SLO_MFU_FLOOR_ENV, 0.0)
-    if mfu_floor > 0.0:
-        objectives.append(Objective(
-            name="mfu_floor",
-            series="mfu",
-            signal="value",
-            op="lt",
-            threshold=mfu_floor,
-            description="model FLOPs utilization floor",
-        ))
-    return objectives
 
 
 @dataclass

@@ -4,8 +4,8 @@ Every Prometheus family in :mod:`~raydp_tpu.telemetry.export` is an
 instantaneous value: the exposition answers "what is the counter NOW",
 never "what was it doing over the last minute". Windowed questions —
 is the serve p99 above its SLO *sustained*, is the shed rate rising,
-did MFU fall off a cliff — need short-horizon history, and requiring
-an external Prometheus server for them makes the SLO engine
+did ingest throughput fall off a cliff — need short-horizon history,
+and requiring an external Prometheus server for them makes the SLO engine
 (:mod:`~raydp_tpu.telemetry.slo`) unusable in tests, CI gates, and
 single-host runs.
 
@@ -18,8 +18,8 @@ no new collection paths). Like every other plane it is memory-bounded
 kill-switched (``RAYDP_TPU_TIMESERIES=0`` makes sampling a no-op).
 
 Series names are the flattened registry names (``serve/rejected``,
-``mfu``, ``serve/latency/p99_s``, ``ingest/rows/per_sec``), so the
-per-job label dimension comes through unchanged: job-attributed
+``train/step/p99_s``, ``serve/latency/p99_s``, ``ingest/rows/per_sec``),
+so the per-job label dimension comes through unchanged: job-attributed
 counters are already namespaced ``job/<job_id>/<kind>`` by the
 accounting ledger.
 """

@@ -31,10 +31,9 @@ from raydp_tpu.data.ml_dataset import MLDataset
 from raydp_tpu.models import dropout
 from raydp_tpu.parallel.mesh import MeshSpec
 from raydp_tpu.telemetry import accounting as _acct
-from raydp_tpu.telemetry import event as _event
 from raydp_tpu.telemetry import events as _events
 from raydp_tpu.telemetry import flush_spans, span
-from raydp_tpu.telemetry import device_profiler as _devplane
+from raydp_tpu.telemetry.device_profiler import AnomalySentinel
 from raydp_tpu.telemetry import flight_recorder as _flight
 from raydp_tpu.telemetry import overlap as _overlap
 from raydp_tpu.telemetry import watchdog as _watchdog
@@ -86,14 +85,6 @@ def _guard_compile(jitted: Callable, label: str) -> Callable:
         # per process.
         _acct.add_usage(_acct.COMPILE_SECONDS, time.monotonic() - start)
         state["first"] = False
-        # First dispatch is also the cost-analysis moment: register
-        # analytical FLOPs/bytes for the MFU/roofline gauges. lower()
-        # only re-traces (the jit cache keeps the compiled executable),
-        # and a backend without cost analysis is a silent no-op.
-        try:
-            _devplane.note_compiled(label, jitted, args, kwargs)
-        except Exception:
-            pass
         return out
 
     return wrapped
@@ -283,8 +274,7 @@ class JAXEstimator:
         # built, then its plan function, or False where nothing takes it.
         self._row_plan = None
         self._sample_batch = None
-        # Device-plane state: live only while a stream fit runs.
-        self._phases = None
+        # Anomaly sentinel of the latest fit.
         self._sentinel = None
         self.history: List[Dict[str, float]] = []
 
@@ -554,27 +544,10 @@ class JAXEstimator:
         if depth is None:
             depth = self.infeed_depth
         window: deque = deque()
-        # Phase accounting (when a fit is live): time blocked pulling
-        # the next host batch is the step's input-wait; shard +
-        # device_put time is host dispatch. Both accrue against the
-        # step that consumes them.
-        phases = self._phases
-        it = iter(host_iter)
-        while True:
-            t0 = time.perf_counter()
-            try:
-                x, y = next(it)
-            except StopIteration:
-                break
-            if phases is not None:
-                phases.note_input_wait(time.perf_counter() - t0)
+        for x, y in host_iter:
             if self._state is None:
                 self._init_state(x)
-            t1 = time.perf_counter()
-            item = self._shard_batch(x, y) + (len(x),)
-            if phases is not None:
-                phases.note_dispatch(time.perf_counter() - t1)
-            window.append(item)
+            window.append(self._shard_batch(x, y) + (len(x),))
             if len(window) > depth:
                 yield window.popleft()
         while window:
@@ -665,20 +638,6 @@ class JAXEstimator:
             "samples": n_samples,
             "samples_per_sec": n_samples / max(1e-9, dt),
         }
-        if self._phases is not None and self._phases.epoch_steps:
-            # Phase breakdown + bound-ness for THIS epoch; the summary
-            # also refreshes the live gauges (phase/*_frac, mfu) and is
-            # dropped into the span shards as a train/phases event so
-            # analyze.py sees it per process/rank.
-            phase_summary = self._phases.epoch_summary()
-            metrics["phases"] = phase_summary
-            metrics["bound"] = phase_summary["bound"]
-            if "mfu" in phase_summary:
-                metrics["mfu"] = phase_summary["mfu"]
-            _event("train/phases", epoch=epoch, **{
-                k: v for k, v in phase_summary.items()
-                if isinstance(v, (int, float, str))
-            })
         if evaluate_ds is not None:
             metrics.update(self.evaluate(evaluate_ds, prefix="eval_"))
         self.history.append(metrics)
@@ -844,14 +803,9 @@ class JAXEstimator:
                 rng, _ = jax.random.split(rng)
         steps_done = int(self._state.step) if self._state is not None else 0
         failures = 0
-        # Device performance plane: phase accumulator feeds _finish_epoch
-        # (and the phase/* gauges); the sentinel checks loss/grad-norm
-        # finiteness on a sampled cadence and watches for step-time
-        # regressions. RAYDP_TPU_DEVICE_PLANE=0 turns both off.
-        if _devplane.enabled():
-            self._phases = _devplane.StepPhaseAccumulator("train_step")
-            self._sentinel = _devplane.AnomalySentinel()
-        sentinel = self._sentinel
+        # The sentinel checks loss/grad-norm finiteness on a sampled
+        # cadence and watches for step-time regressions.
+        sentinel = self._sentinel = AnomalySentinel()
         for epoch in range(start_epoch, epochs):
             t0 = time.perf_counter()
             for loader in loaders:
@@ -875,6 +829,7 @@ class JAXEstimator:
             from raydp_tpu.utils.profiling import metrics as _m
 
             step_timer = _m.timer("train/step")
+            step_hist = _m.histogram("train/step_seconds")
             # The epoch span covers only the batch loop (it closes before
             # _finish_epoch so a flush there sees it finished); step spans
             # nest under it via the thread-local stack. Step timing here is
@@ -934,21 +889,17 @@ class JAXEstimator:
                                     exc_info=True,
                                 )
                     step_timer.observe(sp.duration_s)
-                    if self._phases is not None:
-                        self._phases.step(sp.duration_s)
-                    if sentinel is not None:
-                        sentinel.observe_step(
-                            sp.duration_s, b_idx, epoch=epoch
+                    step_hist.observe(sp.duration_s)
+                    sentinel.observe_step(sp.duration_s, b_idx, epoch=epoch)
+                    if sentinel.wants_check(steps_done + 1):
+                        # Sampled sync point (the ONLY per-loop
+                        # float() besides the epoch boundary).
+                        sentinel.check_loss(
+                            float(loss_val), b_idx, epoch=epoch
                         )
-                        if sentinel.wants_check(steps_done + 1):
-                            # Sampled sync point (the ONLY per-loop
-                            # float() besides the epoch boundary).
-                            sentinel.check_loss(
-                                float(loss_val), b_idx, epoch=epoch
-                            )
-                            sentinel.check_grad_norm(
-                                float(grad_norm), b_idx, epoch=epoch
-                            )
+                        sentinel.check_grad_norm(
+                            float(grad_norm), b_idx, epoch=epoch
+                        )
                     loss_sum = (
                         loss_val if loss_sum is None else loss_sum + loss_val
                     )
@@ -993,12 +944,10 @@ class JAXEstimator:
                     from raydp_tpu.models.moe import report_epoch
 
                     report_epoch(jax.device_get(stats_sum), n_batches)
-            if sentinel is not None:
-                # Epoch boundary always checks (the sampled cadence may
-                # never have landed on a NaN step in a short epoch).
-                sentinel.check_loss(train_loss, b_idx, epoch=epoch)
+            # Epoch boundary always checks (the sampled cadence may
+            # never have landed on a NaN step in a short epoch).
+            sentinel.check_loss(train_loss, b_idx, epoch=epoch)
             self._finish_epoch(epoch, t0, train_loss, n_samples, evaluate_ds)
-        self._phases = None  # stop attributing eval/predict infeed
         for cb in self.callbacks:
             cb.on_train_end(self.history)
         return self.history
@@ -1160,13 +1109,9 @@ class JAXEstimator:
         epoch_fn = self._build_epoch_fn(n_steps, batch)
         rng = jax.random.PRNGKey(self.seed + 1)
         failures = 0
-        # Scan mode has no per-step host loop, so phase accounting does
-        # not apply; the sentinels still check each epoch's synced loss
-        # and worst grad-norm.
-        sentinel = (
-            _devplane.AnomalySentinel() if _devplane.enabled() else None
-        )
-        self._sentinel = sentinel
+        # Scan mode has no per-step host loop: the sentinel checks each
+        # epoch's synced loss and worst grad-norm.
+        sentinel = self._sentinel = AnomalySentinel()
         for epoch in range(epochs):
             t0 = time.perf_counter()
             rng, key = jax.random.split(rng)
@@ -1203,11 +1148,10 @@ class JAXEstimator:
                             exc_info=True,
                         )
                 train_loss = float(mean_loss)  # one sync per epoch
-                if sentinel is not None:
-                    sentinel.check_loss(train_loss, n_steps, epoch=epoch)
-                    sentinel.check_grad_norm(
-                        float(max_gnorm), n_steps, epoch=epoch
-                    )
+                sentinel.check_loss(train_loss, n_steps, epoch=epoch)
+                sentinel.check_grad_norm(
+                    float(max_gnorm), n_steps, epoch=epoch
+                )
             # True-sample throughput: padded duplicate rows don't count.
             metrics = self._finish_epoch(
                 epoch, t0, train_loss, n_true, evaluate_ds
@@ -1286,20 +1230,14 @@ class JAXEstimator:
                 yield from self._pairs(loader)
 
         # Same double-buffered sharded infeed as fit(): batch N+1's H2D
-        # overlaps batch N's eval step. Eval infeed must NOT accrue into
-        # the train-step phase accumulator (per-epoch eval would inflate
-        # the next epoch's input-wait), so it is parked for the loop.
-        phases, self._phases = self._phases, None
-        try:
-            for xd, yd, blen in self._sharded_prefetch(host_batches()):
-                w = float(blen)
-                out = self._eval_step(self._state, xd, yd)
-                for k, v in out.items():
-                    vw = v * w
-                    totals[k] = vw if k not in totals else totals[k] + vw
-                weight_total += w
-        finally:
-            self._phases = phases
+        # overlaps batch N's eval step.
+        for xd, yd, blen in self._sharded_prefetch(host_batches()):
+            w = float(blen)
+            out = self._eval_step(self._state, xd, yd)
+            for k, v in out.items():
+                vw = v * w
+                totals[k] = vw if k not in totals else totals[k] + vw
+            weight_total += w
         return {
             f"{prefix}{k}": float(v) / max(1e-9, weight_total)
             for k, v in totals.items()

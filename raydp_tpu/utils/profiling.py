@@ -37,7 +37,6 @@ __all__ = [
     "enrich_compile_error",
     "local_devices_if_initialized",
     "sample_resource_gauges",
-    "cost_analysis_summary",
 ]
 
 
@@ -449,44 +448,6 @@ def enrich_compile_error(
     except Exception:
         pass
     return err
-
-
-def cost_analysis_summary(jitted, args, kwargs) -> Optional[Dict[str, float]]:
-    """Analytical FLOPs/bytes for one jitted function at given args.
-
-    ``jitted.lower(...)`` re-traces but does NOT backend-compile (the
-    live dispatch keeps its own jit cache), so calling this once at
-    first dispatch costs one extra trace, never a second XLA compile.
-    Returns ``{"flops", "bytes", "collective_bytes"}`` or None when the
-    running jax/backend exposes no cost analysis. ``collective_bytes``
-    sums the operand bytes of cross-replica ops when the analysis
-    reports them (TPU backends); 0.0 where it does not (CPU)."""
-    try:
-        cost = jitted.lower(*args, **kwargs).cost_analysis()
-    except Exception:
-        return None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    if not isinstance(cost, dict):
-        return None
-    flops = float(cost.get("flops", 0.0) or 0.0)
-    nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)
-    coll = 0.0
-    for key, value in cost.items():
-        # TPU analyses tag collective traffic with the op family in the
-        # key (e.g. "bytes accessed ... all-reduce"); nothing on CPU.
-        lk = key.lower()
-        if "bytes" in lk and any(
-            tag in lk for tag in ("all-reduce", "all-gather",
-                                  "collective", "reduce-scatter")
-        ):
-            try:
-                coll += float(value)
-            except (TypeError, ValueError):
-                pass
-    if flops <= 0.0 and nbytes <= 0.0:
-        return None
-    return {"flops": flops, "bytes": nbytes, "collective_bytes": coll}
 
 
 def local_devices_if_initialized() -> list:

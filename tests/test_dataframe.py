@@ -378,6 +378,50 @@ def test_agg_first_last_and_count_distinct():
     assert first["last(v)"].tolist() == [20, 30, 40]
 
 
+class _SpyTable:
+    """A pyarrow table that records how ``group_by`` is asked for
+    threads (``pa.Table`` itself is immutable: no attribute can be
+    patched on it)."""
+
+    def __init__(self, table):
+        self._table = table
+        self.use_threads = []
+
+    def group_by(self, keys, use_threads=True):
+        self.use_threads.append(use_threads)
+        return self._table.group_by(keys, use_threads=use_threads)
+
+    def __getattr__(self, name):
+        return getattr(self._table, name)
+
+
+@pytest.mark.parametrize("agg_fn", ["_direct_agg", "_local_agg"])
+@pytest.mark.parametrize("specs, threads, expected", [
+    # Ordered aggregators: pyarrow refuses them under threads.
+    ([("v", "first"), ("v", "last"), ("v", "sum")], False,
+     [[10, 30, 40], [20, 35, 40], [40, 65, 40]]),
+    # Counting stages keep arrow's threads.
+    ([("v", "count"), ("v", "sum")], True,
+     [[3, 2, 1], [40, 65, 40]]),
+])
+def test_group_by_threads_unless_an_aggregator_is_ordered(
+    agg_fn, specs, threads, expected
+):
+    from raydp_tpu.dataframe import dataframe as dfmod
+
+    table = _SpyTable(pa.table({
+        "k": [0, 0, 1, 0, 1, 2],
+        "v": [10, 10, 30, 20, 35, 40],
+    }))
+    out = getattr(dfmod, agg_fn)(table, ["k"], specs)
+    assert table.use_threads == [threads]
+    out = out.sort_by("k")
+    assert out.column("k").to_pylist() == [0, 1, 2]
+    # Key columns first, then one output per aggregation in order.
+    got = [out.column(1 + i).to_pylist() for i in range(len(specs))]
+    assert got == expected
+
+
 def test_agg_fanout_scales_beyond_old_cap():
     import numpy as np
     import pandas as pd

@@ -151,7 +151,7 @@ def test_flatten_view_merges_aggregate_and_driver():
 
 def test_sampler_kill_switch(monkeypatch):
     sampler = TimeSeriesSampler(config=TimeSeriesConfig(interval_s=0.1))
-    metrics.gauge_set("mfu", 0.5)
+    metrics.gauge_set("serve/batch_fill", 0.5)
     assert sampler.sample(wall=T) > 0
     monkeypatch.setenv("RAYDP_TPU_TIMESERIES", "0")
     assert sampler.sample(wall=T + 1) == 0      # live-checked, no thread
@@ -273,27 +273,25 @@ def test_rate_signal_sums_matching_series():
 def test_lt_objective_floors():
     store = _store()
     obj = Objective(
-        name="mfu_floor", series="mfu", signal="value", op="lt",
-        threshold=0.3,
+        name="fill_floor", series="serve/batch_fill", signal="value",
+        op="lt", threshold=0.3,
     )
     eng = _engine(store, [obj])
     for i in range(10):
-        store.record("mfu", 0.5, wall=T + i)
+        store.record("serve/batch_fill", 0.5, wall=T + i)
     assert eng.evaluate(now=T + 9) == []        # above the floor: fine
     for i in range(10, 20):
-        store.record("mfu", 0.1, wall=T + i)
+        store.record("serve/batch_fill", 0.1, wall=T + i)
     assert [t["kind"] for t in eng.evaluate(now=T + 20)] == ["breach"]
 
 
 def test_default_objectives_cover_the_flywheel():
     names = {o.name for o in default_objectives()}
-    assert {
+    assert names == {
         "serve_p99", "serve_shed_rate", "worker_stalls",
         "worker_restart_rate", "gang_restart_rate",
         "arbiter_starvation", "ingest_starvation",
-    } <= names
-    # the MFU floor ships disabled until the env sets a floor
-    assert "mfu_floor" not in names
+    }
 
 
 # ---------------------------------------------------------------------
@@ -424,15 +422,16 @@ def test_dashboard_document_and_renderer():
     metrics.counter_add("serve/requests", 5)
     metrics.counter_add("serve/replies", 5)
     metrics.gauge_set("serve/batch_fill", 0.75)
-    metrics.gauge_set("mfu", 0.41)
+    metrics.timer("train/step").observe(0.004)
     dash = dash_mod.local_dashboard()
     for section in _SECTIONS:
         assert section in dash, section
     assert dash["serve"]["requests"] == 5
     assert dash["serve"]["batch_fill"] == 0.75
-    assert dash["train"]["mfu"] == 0.41
+    assert dash["train"]["steps"] == 1
+    assert dash["train"]["step_p50_ms"] == 4.0
     text = dash_mod.format_dashboard(dash)
-    assert "serve" in text and "mfu" in text
+    assert "serve" in text and "step_p50_ms" in text
 
 
 def test_debug_dashboard_route():
