@@ -21,7 +21,7 @@ import os
 import threading
 import time
 from collections import OrderedDict
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, List, Optional, Sequence
 
 import pyarrow as pa
@@ -1080,34 +1080,42 @@ class ClusterExecutor(Executor):
         return [f.result() for f in futures]
 
     def _put_async(self, table):
-        """Ingest a partition: scattered to a worker round-robin so initial
-        placement is distributed across nodes (Spark parallelize lands
-        blocks on executors, not the driver) — without this, every
-        partition would start on the driver node and locality routing
-        would keep all work there. Written holder-owned: base data must
-        survive pool shrinks (kill_worker contract).
+        """Ingest a partition, holder-owned (base data must survive pool
+        shrinks: the kill_worker contract), placed round-robin over the
+        alive workers so initial placement is spread across NODES (Spark
+        parallelize lands blocks on executors, not the driver) — without
+        that every partition would start on the driver node and locality
+        routing would keep all work there.
 
-        The table itself travels the DATA plane (``data_args``): it is
-        written once into the driver's shm store and the RunTask envelope
-        carries only the ref — a co-located worker re-puts it from a
-        zero-copy mmap view, a remote one streams it from the driver
-        node's agent in bounded chunks. No table bytes ride the control
-        plane."""
-        workers = self.cluster.alive_workers()
-        if not workers:
-            from concurrent.futures import Future
-
+        Where the round-robin target sits on the node this process's
+        store writes to, there is nothing to place: the driver's own put
+        IS the partition — one IPC write into the segment, no task, no
+        staged copy, no worker re-put, no RegisterObject (the master's
+        store is the directory). A target on another node — or any
+        target, for a ``RemoteCluster`` client, whose store proxy is on
+        no data node — gets the table over the DATA plane
+        (``data_args``): staged once in the driver-node store, the
+        RunTask envelope carries only the ref, and the worker streams it
+        from the driver node's agent and re-puts it on its own node. No
+        table bytes ride the control plane either way."""
+        workers = sorted(
+            self.cluster.alive_workers(), key=lambda w: w.worker_id
+        )
+        target = (
+            workers[next(self._put_rr) % len(workers)] if workers else None
+        )
+        if target is None or target.node_id == self.store.node_id:
+            metrics.counter_add("df/ingest_partitions_local")
             f = Future()
             f.set_result(self.store.put_arrow_table(table))
             return f
-        ordered = sorted(w.worker_id for w in workers)
-        target = ordered[next(self._put_rr) % len(ordered)]
 
         def ingest(ctx, t):
             return ctx.put_table(t, holder=True)
 
+        metrics.counter_add("df/ingest_partitions_shipped")
         return self.cluster.submit_async(
-            ingest, worker_id=target, data_args=(table,)
+            ingest, worker_id=target.worker_id, data_args=(table,)
         )
 
     def num_rows(self, part):

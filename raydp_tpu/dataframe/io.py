@@ -16,6 +16,7 @@ from raydp_tpu.dataframe import aqe as _aqe
 from raydp_tpu.dataframe import expr as E
 from raydp_tpu.dataframe.dataframe import DataFrame, _node, _split_sizes
 from raydp_tpu.dataframe.executor import Executor, LocalExecutor
+from raydp_tpu.store.object_store import ObjectRef
 from raydp_tpu.telemetry import span
 from raydp_tpu.utils.profiling import metrics
 
@@ -53,39 +54,34 @@ def _scan_distributed(split_specs, reader) -> Optional[DataFrame]:
     return DataFrame([f.result() for f in futures], ex)
 
 
-def _compact(t: pa.Table) -> pa.Table:
-    """Rebuild ``t`` on its own buffers via an IPC round-trip.
-
-    ``Table.slice`` is zero-copy: the slice keeps the PARENT's buffers,
-    and pickle serializes those in full — so shipping N slices of one
-    table to the workers moves N× the whole table over the control
-    plane, not 1× (measured: a 4.5 MB slice of a 36 MB table pickles at
-    36 MB; with 8 partitions that is 288 MB of ingest traffic and the
-    driver-side stall that starves worker heartbeats). The IPC writer
-    truncates buffers to the slice, so one memcpy-speed round-trip makes
-    the partition self-contained before it is pickled into a task."""
-    sink = pa.BufferOutputStream()
-    with pa.ipc.new_stream(sink, t.schema) as w:
-        w.write_table(t)
-    return pa.ipc.open_stream(sink.getvalue()).read_all()
-
-
 def from_arrow(table: pa.Table, num_partitions: int = 1) -> DataFrame:
+    """Row-range slices of ``table`` become the partitions. The slices
+    are zero-copy views: a store-backed executor's IPC write cuts each
+    to its own bytes, once, into the segment that is the partition."""
     if num_partitions <= 1:
         return _distribute([table])
     sizes = _split_sizes(table.num_rows, num_partitions)
     parts, offset = [], 0
     for size in sizes:
-        parts.append(_compact(table.slice(offset, size)))
+        parts.append(table.slice(offset, size))
         offset += size
     return _distribute(parts)
 
 
 def from_pandas(df, num_partitions: int = 1) -> DataFrame:
-    with span("df/from_pandas", rows=len(df)):
-        return from_arrow(
+    with span("df/from_pandas", rows=len(df)) as sp:
+        out = from_arrow(
             pa.Table.from_pandas(df, preserve_index=False), num_partitions
         )
+        # ``local``: partitions that took no task — in-memory tables, or
+        # refs the driver's own store wrote (shipped ones land elsewhere).
+        sp.attrs["partitions"] = len(out._parts)
+        sp.attrs["local"] = sum(
+            not isinstance(p, ObjectRef)
+            or p.node_id == out._executor.store.node_id
+            for p in out._parts
+        )
+        return out
 
 
 def from_refs(refs: Sequence[Any]) -> DataFrame:
@@ -100,7 +96,6 @@ def from_refs(refs: Sequence[Any]) -> DataFrame:
     """
     from raydp_tpu.context import current_session
     from raydp_tpu.dataframe.executor import ClusterExecutor
-    from raydp_tpu.store.object_store import ObjectRef
 
     refs = list(refs)
     if not refs:

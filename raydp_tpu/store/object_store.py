@@ -146,18 +146,39 @@ class ObjectStore:
                 seg.buf[: flat.nbytes] = flat
         finally:
             seg.close()
-        ref = ObjectRef(object_id, view.nbytes, owner, num_rows, self.node_id)
+        return self._record(object_id, view.nbytes, owner, num_rows)
+
+    def put_arrow_table(self, table: pa.Table, owner: str = OWNER_HOLDER) -> ObjectRef:
+        """Serialize an Arrow table as an IPC stream into the store.
+
+        The stream is written ONCE, straight into the segment, through
+        the segment's file (no intermediate buffer, and no dry run to
+        size a mapping: the file grows as the writer appends). The IPC
+        writer truncates buffers to a slice, so a slice of a larger
+        table costs the slice's bytes. ``ref.size`` is the stream's byte
+        count, which is the segment's size. A full /dev/shm surfaces as
+        the writer's ``OSError``, not as a SIGBUS on a mapped page.
+        """
+        object_id = secrets.token_hex(16)
+        name = self._segment_name(object_id)
+        path = shm.create_for_writing(name)
+        try:
+            with pa.OSFile(path, "wb") as sink:
+                with pa.ipc.new_stream(sink, table.schema) as writer:
+                    writer.write_table(table)
+                size = sink.tell()
+        except BaseException:
+            shm.unlink(name)
+            raise
+        return self._record(object_id, size, owner, table.num_rows)
+
+    def _record(self, object_id: str, size: int, owner: str,
+                num_rows: int) -> ObjectRef:
+        """Enter a segment this store just wrote into the directory."""
+        ref = ObjectRef(object_id, size, owner, num_rows, self.node_id)
         with self._lock:
             self._objects[object_id] = ref
         return ref
-
-    def put_arrow_table(self, table: pa.Table, owner: str = OWNER_HOLDER) -> ObjectRef:
-        """Serialize an Arrow table as an IPC stream into the store."""
-        sink = pa.BufferOutputStream()
-        with pa.ipc.new_stream(sink, table.schema) as writer:
-            writer.write_table(table)
-        buf = sink.getvalue()
-        return self.put(buf, owner=owner, num_rows=table.num_rows)
 
     # -- read path ------------------------------------------------------
     def get_buffer(self, ref_or_id) -> pa.Buffer:

@@ -94,6 +94,15 @@ def create(name: str, size: int) -> ShmSegment:
     return ShmSegment(name=name, size=size, _mmap=mm)
 
 
+def create_for_writing(name: str) -> str:
+    """Create an empty segment (fails if it exists) and return its path,
+    for a writer that fills it through the FILE API: ``write()`` copies
+    whole buffers into the page cache, where a store through a fresh
+    mapping faults in every 4 KiB page first."""
+    create(name, 0)
+    return _path(name)
+
+
 def open_segment(name: str, readonly: bool = True) -> ShmSegment:
     """Attach to an existing segment."""
     flags = os.O_RDONLY if readonly else os.O_RDWR

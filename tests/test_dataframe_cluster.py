@@ -130,3 +130,77 @@ def test_union_mixed_executors(session):
     # and the reverse direction: cluster parts materialize into local
     out2 = local_df.union(cluster_df)
     assert sorted(out2.to_pandas()["x"].tolist()) == [1, 2, 3, 4, 5]
+
+
+def _ingest_counters():
+    from raydp_tpu.utils.profiling import metrics
+
+    counters = metrics.snapshot()["counters"]
+    return (counters.get("df/ingest_partitions_local", 0.0),
+            counters.get("df/ingest_partitions_shipped", 0.0))
+
+
+def test_ingest_on_the_drivers_node_is_the_drivers_put(session, monkeypatch):
+    """Every worker sits on the driver's node, so there is nothing to
+    place: ``from_pandas`` submits NO task, each partition is the
+    driver's own holder-owned put of a slice (the slice's bytes, not the
+    parent's), in order — and, since no worker wrote them, the frame
+    computes after every worker alive at ingest is gone. LAST in this
+    module: it replaces the session's workers."""
+    from raydp_tpu.store.object_store import OWNER_HOLDER, ObjectRef
+    from raydp_tpu.telemetry import recorder
+
+    cluster = session.cluster
+    n = 20_003
+    rng = np.random.default_rng(5)
+    pdf = pd.DataFrame({
+        "i": np.arange(n, dtype=np.int64),
+        "k": rng.integers(0, 11, n),
+        "v": rng.standard_normal(n),
+    })
+
+    def no_task(*args, **kwargs):
+        raise AssertionError("ingest on the driver's node submitted a task")
+
+    local0, shipped0 = _ingest_counters()
+    with monkeypatch.context() as m:
+        m.setattr(cluster, "submit_async", no_task)
+        m.setattr(cluster, "submit_batch", no_task)
+        df = rdf.from_pandas(pdf, num_partitions=8)
+    local1, shipped1 = _ingest_counters()
+    assert (local1 - local0, shipped1 - shipped0) == (8, 0)
+    sp = [s for s in recorder.spans() if s.name == "df/from_pandas"][-1]
+    assert sp.attrs == {"rows": n, "partitions": 8, "local": 8}
+
+    store = cluster.master.store
+    refs = df._parts
+    assert all(isinstance(r, ObjectRef) for r in refs)
+    assert {(r.owner, r.node_id) for r in refs} == {
+        (OWNER_HOLDER, store.node_id)
+    }
+    assert [store.get_ref(r.object_id) for r in refs] == refs
+    # rows and order: partition j is the parent's j-th row range
+    assert [r.num_rows for r in refs] == [2501] * 3 + [2500] * 5
+    got = pd.concat(
+        [store.get_arrow_table(r).to_pandas() for r in refs],
+        ignore_index=True,
+    )
+    pd.testing.assert_frame_equal(got, pdf)
+    # a partition costs its slice, not the 8x larger parent
+    whole = store.put_arrow_table(pa.Table.from_pandas(pdf))
+    assert max(r.size for r in refs) < whole.size / 7
+    store.delete(whole)
+
+    expected = pdf.groupby("k", as_index=False)["v"].sum()
+    at_ingest = [w.worker_id for w in cluster.alive_workers()]
+    for wid in at_ingest:
+        cluster.kill_worker(wid)
+    cluster.request_workers(len(at_ingest))
+    assert not {w.worker_id for w in cluster.alive_workers()} & set(at_ingest)
+    assert all(store.contains(r) for r in refs)
+    out = (
+        df.groupBy("k").agg({"v": "sum"}).to_pandas()
+        .sort_values("k").reset_index(drop=True)
+    )
+    assert out["k"].tolist() == expected["k"].tolist()
+    assert np.allclose(out["sum(v)"].to_numpy(), expected["v"].to_numpy())

@@ -14,6 +14,7 @@ import pytest
 import raydp_tpu
 import raydp_tpu.dataframe as rdf
 from raydp_tpu.data import MLDataset
+from raydp_tpu.dataframe.scheduler import resolve
 from raydp_tpu.utils.sharding import (
     assignment_sample_counts,
     divide_blocks_local,
@@ -100,6 +101,28 @@ def test_mldataset_locality_on_two_hosts():
             len(ds.shard_columns(r, ["a"])["a"]) for r in range(2)
         )
         assert total == 2 * ds.rows_per_shard
+    finally:
+        raydp_tpu.stop()
+
+
+def test_stage_stays_on_each_partitions_node_after_mixed_ingest():
+    """Two of four partitions are the driver's own put (node-0), two are
+    shipped to node-1; a narrow stage runs each partition where its
+    bytes are, so every output block is on its input's node."""
+    raydp_tpu.init(
+        app_name="locality-ingest", num_workers=2, num_virtual_nodes=2
+    )
+    try:
+        pdf = pd.DataFrame({"a": np.arange(4000, dtype=np.float64)})
+        df = rdf.from_pandas(pdf, num_partitions=4)
+        src_nodes = [r.node_id for r in df._parts]
+        assert sorted(src_nodes) == ["node-0", "node-0", "node-1", "node-1"]
+        out = df.withColumn("b", rdf.col("a") + 1.0).persist()
+        out_refs = resolve(out._parts)
+        assert [r.node_id for r in out_refs] == src_nodes
+        got = out.to_pandas()
+        assert got["a"].tolist() == pdf["a"].tolist()
+        assert got["b"].tolist() == (pdf["a"] + 1.0).tolist()
     finally:
         raydp_tpu.stop()
 

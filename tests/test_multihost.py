@@ -120,6 +120,58 @@ def test_dataframe_pipeline_across_hosts(twohost):
     assert np.allclose(out["sum(v2)"].to_numpy(), expected["v2"].to_numpy())
 
 
+def test_ingest_ships_only_to_the_other_node(twohost):
+    """Initial placement is across NODES: a partition whose round-robin
+    target sits on the driver's node is the driver's own put (no task),
+    one whose target sits on the other node is still shipped over the
+    data plane and lands THERE, holder-owned and in the directory. A
+    stage over the mixed frame returns the right rows, in order."""
+    from tests.test_dataframe_cluster import _ingest_counters as counters
+
+    cluster = twohost.cluster
+    store = cluster.master.store
+    n = 4001
+    pdf = pd.DataFrame({
+        "i": np.arange(n, dtype=np.int64),
+        "v": np.random.default_rng(2).standard_normal(n),
+    })
+    targets = sorted(cluster.alive_workers(), key=lambda w: w.worker_id)
+    tasks = []
+    submit_async = cluster.submit_async
+
+    def counting_submit(fn, *args, **kwargs):
+        tasks.append(kwargs.get("worker_id"))
+        return submit_async(fn, *args, **kwargs)
+
+    before = counters()
+    cluster.submit_async = counting_submit
+    try:
+        df = rdf.from_pandas(pdf, num_partitions=4)
+    finally:
+        del cluster.submit_async
+    after = counters()
+    refs = df._parts
+    want_nodes = [targets[j % 2].node_id for j in range(4)]
+    assert sorted(want_nodes) == ["node-0"] * 2 + ["node-1"] * 2
+    assert [r.node_id for r in refs] == want_nodes
+    assert (after[0] - before[0], after[1] - before[1]) == (2, 2)
+    assert tasks == [t.worker_id for t in targets if t.node_id == "node-1"] * 2
+    assert all(r.owner == OWNER_HOLDER for r in refs)
+    assert [store.get_ref(r.object_id) for r in refs] == refs
+    assert [r.num_rows for r in refs] == [1001, 1000, 1000, 1000]
+    # the staged scratch copies of the shipped partitions are gone
+    assert {r.object_id for r in store.refs()} >= {r.object_id for r in refs}
+    assert sum(r.num_rows for r in store.refs()) == n
+
+    out = (
+        df.withColumn("w", rdf.col("v") * 2.0)
+        .filter(rdf.col("i") % 3 == 0)
+        .to_pandas()
+    )
+    expected = pdf[pdf.i % 3 == 0].assign(w=lambda d: d.v * 2.0)
+    pd.testing.assert_frame_equal(out, expected.reset_index(drop=True))
+
+
 def test_broadcast_join_across_hosts(twohost):
     left = rdf.from_pandas(
         pd.DataFrame({"k": [0, 1, 2, 3] * 50, "a": range(200)}),

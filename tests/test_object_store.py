@@ -1,6 +1,7 @@
 """Object store: zero-copy round trips, ownership transfer, owner-death
 semantics (behavior parity with reference
 python/raydp/tests/test_data_owner_transfer.py), cross-process reads."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -48,6 +49,64 @@ def test_arrow_roundtrip_zero_copy(store):
     # re-reading and comparing addresses are stable per-open.
     out2 = store.get_arrow_table(ref)
     assert out2.equals(t)
+
+
+def _ipc_size(t):
+    sink = pa.BufferOutputStream()
+    with pa.ipc.new_stream(sink, t.schema) as w:
+        w.write_table(t)
+    return sink.getvalue().size
+
+
+_PARENT = _table(80_000, seed=3)
+
+
+def _chunked():
+    a, b, c = _table(300, 1), _table(5, 2), _table(1200, 4)
+    t = pa.concat_tables([a, b, c])
+    assert t.column("x").num_chunks == 3
+    return t
+
+
+_PUT_CASES = {
+    "plain": lambda: _table(1000),
+    "slice": lambda: _PARENT.slice(10_001, 10_000),
+    "zero_rows": lambda: _table(1000).slice(0, 0),
+    "zero_columns": lambda: pa.table({}),
+    "chunked": _chunked,
+    "dictionary": lambda: pa.table({
+        "d": pa.array(["a", "b", None, "a"] * 250).dictionary_encode(),
+        "i": pa.array(range(1000), type=pa.int32()),
+    }),
+    "nulls": lambda: pa.table({
+        "f": pa.array([None if i % 3 == 0 else float(i) for i in range(999)]),
+        "s": pa.array([None if i % 5 == 0 else str(i) for i in range(999)]),
+        "all_null": pa.nulls(999, pa.int64()),
+    }).slice(7, 900),  # bitmaps that start off a byte boundary
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PUT_CASES))
+def test_put_arrow_table_is_the_stream_in_the_segment(store, case):
+    """The IPC stream is written straight into the segment: the table
+    reads back equal, and ``ref.size`` is the stream's byte count, which
+    is the segment's size on disk. A slice costs its own bytes, not its
+    parent's (the writer truncates buffers to the slice)."""
+    t = _PUT_CASES[case]()
+    ref = store.put_arrow_table(t, owner="w1")
+    out = store.get_arrow_table(ref)
+    assert out.schema.equals(t.schema)
+    assert out.equals(t)
+    assert ref.num_rows == t.num_rows and ref.owner == "w1"
+    path = os.path.join(shm.shm_dir(), store._segment_name(ref.object_id))
+    assert ref.size == os.path.getsize(path) == _ipc_size(t)
+    assert store.get_ref(ref.object_id) == ref
+    if case == "slice":
+        # take() copies: the same rows on buffers of their own
+        own = _ipc_size(t.take(pa.array(range(t.num_rows))))
+        assert abs(ref.size - own) <= 0.01 * own
+        assert ref.size < _ipc_size(_PARENT) / 7
+    assert store.delete(ref) and not store.contains(ref)
 
 
 def test_owner_death_cleans_up(store):
