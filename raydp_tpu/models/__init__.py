@@ -6,6 +6,7 @@ from raydp_tpu.models.transformer import (
     TransformerConfig,
     TransformerEncoder,
     bert_base,
+    granite_h_micro,
     olmoe,
     param_shardings,
     tiny_transformer,
@@ -51,6 +52,7 @@ __all__ = [
     "SequenceClassifier",
     "CausalLM",
     "bert_base",
+    "granite_h_micro",
     "olmoe",
     "tiny_transformer",
     "param_shardings",

@@ -1,0 +1,217 @@
+"""The Mamba-2 mixer (Dao & Gu 2024, arXiv:2405.21060) as the
+``transformers`` ``GraniteMoeHybrid``/``Bamba`` modelling code has it: the
+state-space layer a hybrid stack puts where most of its attention was.
+
+    [z, xBC, dt] = W_in y
+    xBC          = silu(conv1d_causal_depthwise(xBC, k) + b)       (``conv``)
+    x, B, C      = split(xBC)
+    dt           = softplus(dt + dt_bias);  A = -exp(A_log)
+    h_t          = exp(dt_t A) h_{t-1} + dt_t x_t ⊗ B_t;  y_t = h_t C_t + D x_t
+    out          = W_out (rms(y · silu(z)) · w)
+
+The gate goes in BEFORE the norm, which runs over all ``heads × head_dim``
+features. Module paths: ``mamba/{in_proj,conv,ssd,gate_norm,out_proj}``;
+the scan itself is ``ops/ssd.py``.
+"""
+from __future__ import annotations
+
+import functools
+import logging
+import math
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from raydp_tpu.ops.ssd import ssd_chunked
+
+logger = logging.getLogger(__name__)
+
+SCAN_IMPLEMENTATION = "chunked state-space dual form in jax.numpy (ops/ssd.py)"
+CONV_IMPLEMENTATION = "shifted multiply-adds"
+
+
+def _replicated(init):
+    return nn.with_logical_partitioning(init, (None,))
+
+
+def _decay_rate_init(key, shape, dtype):
+    """``A_log`` with ``A = exp(A_log)`` uniform in [1, 16], as published."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _step_bias_init(key, shape, dtype, lo=1e-3, hi=1e-1):
+    """``dt_bias`` with ``softplus(dt_bias)`` log-uniform in [lo, hi] (the
+    inverse softplus of the draw), as published: decays neither 0 nor 1."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, dtype, math.log(lo), math.log(hi)
+    ))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _conv_init(taps: int):
+    """``torch.nn.Conv1d``'s default for a depthwise kernel of ``taps``
+    taps, weight and bias alike: uniform in ±1/√taps."""
+    bound = 1.0 / math.sqrt(taps)
+
+    def init(key, shape, dtype):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+    return init
+
+
+class CausalConv1d(nn.Module):
+    """Depthwise causal convolution over the sequence with a bias, and the
+    SiLU that follows it: ``silu(b + Σ_j w_j · in_{t-(k-1)+j})``, zeros
+    before the first token. ``k`` shifted multiply-adds in float32
+    (``kernel`` [k, channels])."""
+
+    taps: int
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        channels = x.shape[-1]
+        init = _conv_init(self.taps)
+        kernel = self.param(
+            "kernel", nn.with_logical_partitioning(init, (None, None)),
+            (self.taps, channels), self.param_dtype,
+        )
+        bias = self.param(
+            "bias", _replicated(init), (channels,), self.param_dtype,
+        )
+        s = x.shape[-2]
+        padded = jnp.pad(x, ((0, 0), (self.taps - 1, 0), (0, 0)))
+        out = bias.astype(jnp.float32)
+        for j in range(self.taps):
+            out = out + kernel[j].astype(jnp.float32) * padded[
+                :, j:j + s
+            ].astype(jnp.float32)
+        return jax.nn.silu(out).astype(self.dtype)
+
+
+class SelectiveScan(nn.Module):
+    """The scan's own parameters (``A_log``, ``dt_bias``, ``D``, one a
+    head, float32) and the call of ``ssd_chunked``."""
+
+    chunk: int
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x, dt, B, C):
+        heads = x.shape[-2]
+        a_log = self.param(
+            "A_log", _replicated(_decay_rate_init), (heads,), self.param_dtype
+        )
+        dt_bias = self.param(
+            "dt_bias", _replicated(_step_bias_init), (heads,),
+            self.param_dtype,
+        )
+        skip = self.param(
+            "D", _replicated(nn.initializers.ones), (heads,), self.param_dtype
+        )
+        dt = jax.nn.softplus(
+            dt.astype(jnp.float32) + dt_bias.astype(jnp.float32)
+        )
+        # A sequence shorter than a chunk (the batch-1 sample of
+        # ``model.init``, a test) is one chunk.
+        chunk = min(self.chunk, x.shape[1])
+        return ssd_chunked(
+            x, dt, -jnp.exp(a_log.astype(jnp.float32)), B, C, skip, chunk
+        )
+
+
+class GatedRMSNorm(nn.Module):
+    """``rms(y · silu(z)) · w`` over the whole feature axis, float32
+    inside: the gate enters before the norm."""
+
+    epsilon: float
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, y, z):
+        scale = self.param(
+            "scale", _replicated(nn.initializers.ones), (y.shape[-1],),
+            self.param_dtype,
+        )
+        y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        y = y * jax.lax.rsqrt(
+            jnp.mean(y * y, axis=-1, keepdims=True) + self.epsilon
+        )
+        return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    """``cfg`` is a ``TransformerConfig`` with the ``ssm_*`` sizes set.
+    Input ``[B, S, d_model]`` → output ``[B, S, d_model]``."""
+
+    cfg: object
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        heads, p, n, g = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                          cfg.ssm_groups)
+        inner = heads * p
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+        )
+        init = nn.initializers.xavier_uniform()
+        zxbcdt = dense(
+            2 * inner + 2 * g * n + heads, name="in_proj",
+            kernel_init=nn.with_logical_partitioning(init, ("embed", None)),
+        )(x)
+        z, xbc, dt = jnp.split(
+            zxbcdt, [inner, 2 * inner + 2 * g * n], axis=-1
+        )
+        xbc = CausalConv1d(
+            cfg.ssm_conv, cfg.dtype, cfg.param_dtype, name="conv"
+        )(xbc)
+        xs, B, C = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+        lead = x.shape[:-1]
+        y = SelectiveScan(cfg.ssm_chunk, cfg.param_dtype, name="ssd")(
+            xs.reshape(*lead, heads, p), dt,
+            B.reshape(*lead, g, n), C.reshape(*lead, g, n),
+        )
+        y = GatedRMSNorm(
+            cfg.norm_eps, cfg.dtype, cfg.param_dtype, name="gate_norm"
+        )(y.reshape(*lead, inner), z)
+        return dense(
+            cfg.d_model, name="out_proj",
+            kernel_init=nn.with_logical_partitioning(init, (None, "embed")),
+        )(y)
+
+
+def layers_of(cfg) -> int:
+    kinds = getattr(cfg, "layer_types", None) or ()
+    return sum(1 for kind in kinds if kind == "mamba")
+
+
+def report(cfg, tokens_per_step: int) -> None:
+    """Static for a compiled step: three gauges and one log line where the
+    step is built (as ``models/dropout.report``), nothing per step. All
+    zero for a stack without state-space layers."""
+    from raydp_tpu.utils.profiling import metrics
+
+    layers = layers_of(cfg)
+    chunks = state = 0
+    if layers:
+        chunks = layers * -(-tokens_per_step // cfg.ssm_chunk)
+        state = 4 * layers * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
+    metrics.gauge_set("ssm/layers", layers)
+    metrics.gauge_set("ssm/chunks_per_step", chunks)
+    metrics.gauge_set("ssm/state_bytes_per_sequence", state)
+    if layers:
+        logger.info(
+            "hybrid stack: %d mamba and %d attention layers; attention %d "
+            "query / %d key-value heads of %d; scan %d heads of %d in %d "
+            "group(s), state %d, chunk %d (%d chunks a step); scan: %s; "
+            "convolution: %d taps as %s",
+            layers, len(cfg.layer_types) - layers, cfg.n_heads,
+            cfg.kv_heads, cfg.head_dim, cfg.ssm_heads, cfg.ssm_head_dim,
+            cfg.ssm_groups, cfg.ssm_state, cfg.ssm_chunk, chunks,
+            SCAN_IMPLEMENTATION, cfg.ssm_conv, CONV_IMPLEMENTATION,
+        )

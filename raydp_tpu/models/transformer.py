@@ -22,6 +22,7 @@ TPU-first design:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -65,14 +66,29 @@ class TransformerConfig:
     # What describes an architecture (the defaults are BERT's block).
     norm: str = "layernorm"          # layernorm | rmsnorm
     norm_eps: float = 1e-6
-    positions: str = "learned"       # learned (a position table) | rotary
+    positions: str = "learned"       # learned (a table) | rotary | none
     rope_theta: float = 10000.0
     qk_norm: bool = False            # norm over the whole q and k projections
     use_bias: bool = True            # biases of the block's projections
-    ffn: str = "gelu"                # gelu (dense) | moe (routed SwiGLU)
+    ffn: str = "gelu"                # gelu | swiglu (dense) | moe (routed)
     n_experts: int = 0
     top_k: int = 0
     d_expert: int = 0
+    # Layer i's mixer: "attention" | "mamba"; None = attention everywhere.
+    layer_types: Optional[Tuple[str, ...]] = None
+    n_kv_heads: Optional[int] = None       # None = n_heads (no grouping)
+    attention_scale: Optional[float] = None    # None = head_dim ** -0.5
+    tie_head: bool = False           # CausalLM's logits from tok_embed
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0      # logits are DIVIDED by it
+    # The Mamba-2 mixer's sizes (models/mamba.py).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
     # How to run it.
     attention_impl: str = "dense"    # dense | ring | ulysses | flash
     remat: bool = False              # checkpoint blocks (memory-bound fits)
@@ -84,6 +100,29 @@ class TransformerConfig:
     def head_dim(self) -> int:
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The mixer of every layer, ``n_layers`` long."""
+        kinds = self.layer_types or ("attention",) * self.n_layers
+        if len(kinds) != self.n_layers or set(kinds) - {"attention", "mamba"}:
+            raise ValueError(
+                f"layer_types {kinds!r} does not name the mixers of "
+                f"{self.n_layers} layers"
+            )
+        return tuple(kinds)
+
+    @property
+    def serves_from_kv_cache(self) -> bool:
+        """Whether every layer's state is the per-slot K/V cache that
+        ``CausalLM.prefill``/``decode_step`` keep."""
+        return set(self.kinds) == {"attention"} and (
+            self.kv_heads == self.n_heads
+        )
 
     def moe_config(self):
         from raydp_tpu.models.moe import MoEConfig
@@ -150,21 +189,45 @@ class MultiHeadAttention(nn.Module):
         kv_len: Optional[int] = None,
     ):
         cfg = self.cfg
-        qkv = nn.DenseGeneral(
-            features=(3, cfg.n_heads, cfg.head_dim),
-            axis=-1,
-            kernel_init=_dense_init("embed", "qkv", "heads", "kv"),
-            use_bias=cfg.use_bias,
-            dtype=cfg.dtype,
+        scale = cfg.attention_scale
+        project = functools.partial(
+            nn.DenseGeneral, axis=-1, use_bias=cfg.use_bias, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
-            name="qkv",
-        )(x)
-        q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+        )
+        if cfg.kv_heads == cfg.n_heads:
+            # One fused projection where the head counts are equal (the
+            # layout every checkpoint so far was written with).
+            qkv = project(
+                features=(3, cfg.n_heads, cfg.head_dim),
+                kernel_init=_dense_init("embed", "qkv", "heads", "kv"),
+                name="qkv",
+            )(x)
+            q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+        else:
+            # Grouped-query heads: each key-value head serves
+            # n_heads / n_kv_heads query heads.
+            if cache_mode is not None:
+                raise NotImplementedError(
+                    "the decode cache holds one key-value head a query head"
+                )
+            q = project(
+                features=(cfg.n_heads, cfg.head_dim),
+                kernel_init=_dense_init("embed", "heads", "kv"), name="q",
+            )(x)
+            kv = project(
+                features=(2, cfg.kv_heads, cfg.head_dim),
+                kernel_init=_dense_init("embed", "qkv", "heads", "kv"),
+                name="kv",
+            )(x)
+            k, v = kv[..., 0, :, :], kv[..., 1, :, :]
         if cfg.qk_norm:
             # Over the whole projection, before the split into heads.
-            flat = q.shape[:-2] + (cfg.d_model,)
-            q = _norm(cfg, "q_norm")(q.reshape(flat)).reshape(q.shape)
-            k = _norm(cfg, "k_norm")(k.reshape(flat)).reshape(k.shape)
+            q = _norm(cfg, "q_norm")(
+                q.reshape(q.shape[:-2] + (-1,))
+            ).reshape(q.shape)
+            k = _norm(cfg, "k_norm")(
+                k.reshape(k.shape[:-2] + (-1,))
+            ).reshape(k.shape)
         if cfg.positions == "rotary":
             if cache_mode == "step":
                 pos = cache_positions[:, None]
@@ -200,7 +263,7 @@ class MultiHeadAttention(nn.Module):
                 cv.value = jax.lax.dynamic_update_slice_in_dim(
                     cv.value, v.astype(cfg.dtype), 0, axis=1
                 )
-                out = reference_attention(q, k, v, causal=True)
+                out = reference_attention(q, k, v, causal=True, scale=scale)
             elif cache_mode == "step":
                 # One token per slot: scatter K/V at each slot's current
                 # cache length, then attend over a static kv_len-bucket
@@ -218,18 +281,24 @@ class MultiHeadAttention(nn.Module):
                     ck.value[:, :kv_len],
                     cv.value[:, :kv_len],
                     cache_positions + 1,
+                    scale=scale,
                 )
             else:
                 raise ValueError(f"unknown cache_mode {cache_mode!r}")
         elif cfg.attention_impl == "dense":
-            out = reference_attention(q, k, v, causal=cfg.causal)
-        elif cfg.attention_impl == "ring":
-            out = ring_attention(
-                q, k, v, mesh=cfg.mesh, causal=cfg.causal
+            out = reference_attention(
+                q, k, v, causal=cfg.causal, scale=scale
             )
-        elif cfg.attention_impl == "ulysses":
-            out = ulysses_attention(
-                q, k, v, mesh=cfg.mesh, causal=cfg.causal
+        elif cfg.attention_impl in ("ring", "ulysses"):
+            # Both move K and V a query head at a time: grouped heads are
+            # repeated first.
+            group = cfg.n_heads // cfg.kv_heads
+            if group > 1:
+                k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+            attend = (ring_attention if cfg.attention_impl == "ring"
+                      else ulysses_attention)
+            out = attend(
+                q, k, v, mesh=cfg.mesh, causal=cfg.causal, scale=scale
             )
         elif cfg.attention_impl == "flash":
             from raydp_tpu.ops.flash_attention import (
@@ -242,10 +311,12 @@ class MultiHeadAttention(nn.Module):
             # more than one device the config has to carry the mesh.
             if cfg.mesh is not None:
                 out = sharded_flash_attention(
-                    q, k, v, mesh=cfg.mesh, causal=cfg.causal
+                    q, k, v, mesh=cfg.mesh, causal=cfg.causal, scale=scale
                 )
             else:
-                out = flash_attention(q, k, v, causal=cfg.causal)
+                out = flash_attention(
+                    q, k, v, causal=cfg.causal, scale=scale
+                )
         else:
             raise ValueError(
                 f"unknown attention_impl {cfg.attention_impl!r}"
@@ -268,10 +339,12 @@ class MultiHeadAttention(nn.Module):
 class TransformerBlock(nn.Module):
     """Pre-norm block (trains stably in bf16 without warmup tricks). Norm,
     positions, QK-norm, biases and the kind of FFN come from the
-    configuration: BERT's encoder block and a routed decoder block are
-    the same code."""
+    configuration, the mixer from the stack's per-layer pattern: BERT's
+    encoder block, a routed decoder block and both layers of a hybrid
+    state-space stack are the same code."""
 
     cfg: TransformerConfig
+    mixer: str = "attention"         # attention | mamba
 
     @nn.compact
     def __call__(
@@ -284,15 +357,35 @@ class TransformerBlock(nn.Module):
         kv_len: Optional[int] = None,
     ):
         cfg = self.cfg
-        y = _norm(cfg, "ln_attn")(x)
-        x = x + MultiHeadAttention(cfg, name="attn")(
-            y,
-            deterministic,
-            cache_mode=cache_mode,
-            cache_positions=cache_positions,
-            kv_len=kv_len,
-        )
 
+        def scaled(branch):
+            if cfg.residual_multiplier == 1.0:
+                return branch
+            return branch * cfg.residual_multiplier
+
+        if self.mixer == "mamba":
+            from raydp_tpu.models.mamba import Mamba2Mixer
+
+            if cache_mode is not None:
+                raise NotImplementedError(
+                    "no decode cache for a state-space layer's state"
+                )
+            x = x + scaled(
+                Mamba2Mixer(cfg, name="mamba")(_norm(cfg, "ln_mamba")(x))
+            )
+        else:
+            x = x + scaled(MultiHeadAttention(cfg, name="attn")(
+                _norm(cfg, "ln_attn")(x),
+                deterministic,
+                cache_mode=cache_mode,
+                cache_positions=cache_positions,
+                kv_len=kv_len,
+            ))
+
+        dense = functools.partial(
+            nn.Dense, use_bias=cfg.use_bias, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+        )
         if cfg.ffn == "moe":
             from raydp_tpu.models.moe import MoELayer
 
@@ -303,33 +396,37 @@ class TransformerBlock(nn.Module):
             y = MoELayer(cfg.moe_config(), name="moe")(y)
         elif cfg.ffn == "gelu":
             y = _norm(cfg, "ln_mlp")(x)
-            y = nn.Dense(
-                cfg.d_ff,
-                kernel_init=_dense_init("embed", "mlp"),
-                use_bias=cfg.use_bias,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
+            y = dense(
+                cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
                 name="mlp_up",
             )(y)
             y = nn.gelu(y)
-            y = nn.Dense(
-                cfg.d_model,
-                kernel_init=_dense_init("mlp", "embed"),
-                use_bias=cfg.use_bias,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
+            y = dense(
+                cfg.d_model, kernel_init=_dense_init("mlp", "embed"),
                 name="mlp_down",
             )(y)
+        elif cfg.ffn == "swiglu":
+            # Dense gated MLP, one fused input projection: [gate, up].
+            y = _norm(cfg, "ln_mlp")(x)
+            gate, up = jnp.split(dense(
+                2 * cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
+                name="mlp_in",
+            )(y), 2, axis=-1)
+            y = dense(
+                cfg.d_model, kernel_init=_dense_init("mlp", "embed"),
+                name="mlp_out",
+            )(nn.silu(gate) * up)
         else:
             raise ValueError(f"unknown ffn {cfg.ffn!r}")
         if cfg.dropout_rate > 0:
             y = Dropout(cfg.dropout_rate)(y, deterministic)
-        x = x + y
+        x = x + scaled(y)
         return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
 class TransformerEncoder(nn.Module):
-    """Token + position (+ optional segment) embeddings, N blocks, final LN.
+    """Token + position (+ optional segment) embeddings, N blocks (layer
+    i's mixer from ``cfg.layer_types``), final LN.
 
     Input: int32 token ids [B, S] (+ optional segment ids). Output:
     [B, S, d_model] hidden states.
@@ -354,6 +451,11 @@ class TransformerEncoder(nn.Module):
             embedding_init=_embed_init("vocab", "embed"),
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="tok_embed",
         )(input_ids)
+        if cfg.embedding_multiplier != 1.0:
+            # Under the embedding's scope, for the same reason as the
+            # logit scaling under ``lm_head``.
+            with jax.named_scope("tok_embed"):
+                x = x * cfg.embedding_multiplier
         if cfg.positions == "learned":
             if cache_mode == "step":
                 # Each slot's token sits at its own absolute position —
@@ -387,8 +489,8 @@ class TransformerEncoder(nn.Module):
             if cfg.remat
             else TransformerBlock
         )
-        for i in range(cfg.n_layers):
-            x = block_cls(cfg, name=f"block_{i}")(
+        for i, kind in enumerate(cfg.kinds):
+            x = block_cls(cfg, kind, name=f"block_{i}")(
                 x,
                 deterministic,
                 cache_mode=cache_mode,
@@ -430,10 +532,53 @@ class SequenceClassifier(nn.Module):
         )(pooled)
 
 
+class _TiedHead(nn.Module):
+    """Logits from the embedding table, in float32; no parameter of its
+    own, a module so that its ops carry the scope ``lm_head``."""
+
+    @nn.compact
+    def __call__(self, h, table):
+        return jnp.einsum(
+            "...d,vd->...v", h.astype(jnp.float32),
+            table.astype(jnp.float32),
+        )
+
+
+def _logits(lm: "CausalLM", h):
+    """The head's logits. A function, not a method: flax would put a
+    method's name into the scope of every op under it, and ``lm_head``'s
+    ops keep the path they had."""
+    cfg = lm.cfg
+    if cfg.tie_head:
+        table = nn.unbox(
+            lm.encoder.get_variable("params", "tok_embed")
+        )["embedding"]
+        logits = lm.lm_head(h, table)
+    else:
+        logits = lm.lm_head(h)
+    if cfg.logits_scaling != 1.0:
+        # Under the head's scope: a pass over the logits belongs to the
+        # head's share of a device trace, not to no part at all.
+        with jax.named_scope("lm_head"):
+            logits = logits / cfg.logits_scaling
+    return logits
+
+
+def _require_kv_cache(cfg: TransformerConfig) -> None:
+    if not cfg.serves_from_kv_cache:
+        raise NotImplementedError(
+            "prefill/decode_step keep one K/V row a query head and layer; "
+            "a stack with state-space layers or grouped key-value heads "
+            "needs a cache of its own (ROADMAP R4)"
+        )
+
+
 class CausalLM(nn.Module):
     """Decoder-only LM: the long-context flagship — pair with
     ``attention_impl='ring'`` to scale sequence length over the sp axis.
-    The output head is a matrix of its own (never tied to ``tok_embed``).
+    The output head is a matrix of its own unless ``cfg.tie_head``: then
+    the logits are the final hidden states times ``tok_embed``'s table, in
+    float32 (the scope is ``lm_head`` either way).
 
     Besides the teacher-forced ``__call__``, exposes the serve-plane
     decode pair: :meth:`prefill` runs the prompt once, writing per-slot
@@ -451,17 +596,20 @@ class CausalLM(nn.Module):
         # Attribute names double as scope names, keeping the param tree
         # ("encoder", "lm_head") identical to the old nn.compact layout.
         self.encoder = TransformerEncoder(self.cfg)
-        self.lm_head = nn.Dense(
-            self.cfg.vocab_size,
-            kernel_init=_dense_init("embed", "vocab"),
-            use_bias=self.cfg.use_bias,
-            dtype=jnp.float32,
-            param_dtype=self.cfg.param_dtype,
-        )
+        if self.cfg.tie_head:
+            self.lm_head = _TiedHead()
+        else:
+            self.lm_head = nn.Dense(
+                self.cfg.vocab_size,
+                kernel_init=_dense_init("embed", "vocab"),
+                use_bias=self.cfg.use_bias,
+                dtype=jnp.float32,
+                param_dtype=self.cfg.param_dtype,
+            )
 
     def __call__(self, input_ids, deterministic: bool = True):
         h = self.encoder(input_ids, None, deterministic)
-        return self.lm_head(h)
+        return _logits(self, h)
 
     def prefill(self, input_ids, lengths):
         """Prompt pass that populates the KV cache.
@@ -472,11 +620,12 @@ class CausalLM(nn.Module):
         real position — argmax of which is the sequence's first generated
         token (so TTFT costs exactly one forward pass).
         """
+        _require_kv_cache(self.cfg)
         h = self.encoder(input_ids, None, True, cache_mode="prefill")
         last = jnp.take_along_axis(
             h, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
         )
-        return self.lm_head(last)[:, 0]
+        return _logits(self, last)[:, 0]
 
     def decode_step(self, tokens, cache_positions, kv_len: int):
         """One decode iteration over the whole slot batch.
@@ -486,6 +635,7 @@ class CausalLM(nn.Module):
         written to), ``kv_len`` static cache-length bucket. Apply with the
         ``"cache"`` collection mutable; returns next-token logits [B, V].
         """
+        _require_kv_cache(self.cfg)
         h = self.encoder(
             tokens,
             None,
@@ -494,12 +644,13 @@ class CausalLM(nn.Module):
             cache_positions=cache_positions,
             kv_len=kv_len,
         )
-        return self.lm_head(h)[:, 0]
+        return _logits(self, h)[:, 0]
 
     def init_cache(self, batch: int):
         """Shape-only helper: an all-zeros cache pytree for ``batch``
         slots (what one jitted prefill would create, without running it)."""
         cfg = self.cfg
+        _require_kv_cache(self.cfg)
         shape = (batch, cfg.max_len, cfg.n_heads, cfg.head_dim)
 
         def zeros(_):
@@ -539,6 +690,32 @@ def olmoe(**overrides) -> TransformerConfig:
         norm="rmsnorm", norm_eps=1e-5, positions="rotary",
         rope_theta=10000.0, qk_norm=True, use_bias=False,
         ffn="moe", n_experts=64, top_k=8, d_expert=1024,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def granite_h_micro(**overrides) -> TransformerConfig:
+    """IBM Granite 4.0-H Micro (3B, dense; ``config.json`` of
+    ibm-granite/granite-4.0-h-micro, ``model_type`` granitemoehybrid): 40
+    pre-norm layers of width 2048, a Mamba-2 mixer (64 heads of 64, state
+    128, one group, 4-tap convolution, chunks of 256) in 36 of them and
+    grouped-query attention (32 query / 8 key-value heads of 64, no
+    positions, softmax scale 1/64) in layers 5, 15, 25 and 35; a dense
+    SwiGLU MLP of width 8192 after either; RMSNorm; embedding × 12, every
+    residual branch × 0.22, logits ÷ 8; vocabulary 100352, tied head."""
+    n_layers = overrides.get("n_layers", 40)
+    defaults = dict(
+        vocab_size=100352, d_model=2048, n_heads=32, n_kv_heads=8,
+        n_layers=n_layers, d_ff=8192, max_len=131072, dropout_rate=0.0,
+        causal=True, norm="rmsnorm", norm_eps=1e-5, positions="none",
+        use_bias=False, ffn="swiglu", attention_scale=0.015625,
+        layer_types=tuple(
+            "attention" if i % 10 == 5 else "mamba" for i in range(n_layers)
+        ),
+        tie_head=True, embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+        ssm_groups=1, ssm_conv=4, ssm_chunk=256,
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

@@ -26,21 +26,50 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
+def _scale(scale: Optional[float], head_dim: int) -> float:
+    """The softmax scale: ``head_dim ** -0.5`` unless the architecture
+    publishes another."""
+    return 1.0 / math.sqrt(head_dim) if scale is None else scale
+
+
 def reference_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
     v: jnp.ndarray,
     causal: bool = False,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Plain softmax attention. Shapes: [B, S, H, D] → [B, S, H, D]."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    """Plain softmax attention. Shapes: q [B, S, H, D], k and v [B, S,
+    Hkv, D] with H a multiple of Hkv (each key-value head serves H / Hkv
+    consecutive query heads) → [B, S, H, D]."""
+    scale = _scale(scale, q.shape[-1])
+    h, h_kv = q.shape[2], k.shape[2]
+    if h != h_kv:
+        b, s_q, _, d = q.shape
+        out = _grouped_attention(
+            q.reshape(b, s_q, h_kv, h // h_kv, d), k, v, causal, scale
+        )
+        return out.reshape(b, s_q, h, d)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
-        s_q, s_k = scores.shape[-2], scores.shape[-1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
-        scores = jnp.where(mask, scores, -jnp.inf)
+        scores = jnp.where(_causal_mask(scores), scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+def _causal_mask(scores):
+    s_q, s_k = scores.shape[-2], scores.shape[-1]
+    return jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+
+
+def _grouped_attention(q, k, v, causal: bool, scale: float):
+    """``q`` [B, S, Hkv, G, D] against ``k``, ``v`` [B, S, Hkv, D]: K and
+    V are read once a group, not repeated."""
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) * scale
+    if causal:
+        scores = jnp.where(_causal_mask(scores), scores, -jnp.inf)
+    weights = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", weights, v)
 
 
 NEG_INF = -1e30
@@ -51,6 +80,7 @@ def cached_decode_attention(
     k_cache: jnp.ndarray,
     v_cache: jnp.ndarray,
     lengths: jnp.ndarray,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Single-step decode attention over a per-slot KV cache.
 
@@ -62,7 +92,7 @@ def cached_decode_attention(
     model's max_len — slicing the cache before calling keeps the score
     matrix O(B·T) per step.
     """
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    scale = _scale(scale, q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache) * scale
     valid = jnp.arange(k_cache.shape[1])[None, :] < lengths[:, None]  # [B, T]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
@@ -70,12 +100,13 @@ def cached_decode_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v_cache)
 
 
-def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
+def _ring_attention_local(q, k, v, axis_name: str, causal: bool,
+                          scale: Optional[float] = None):
     """Per-device ring step. q/k/v local: [B, S_l, H, D]."""
     n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, s_l, h, d = q.shape
-    scale = 1.0 / math.sqrt(d)
+    scale = _scale(scale, d)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     q_pos = rank * s_l + jnp.arange(s_l)  # global query positions
@@ -129,6 +160,7 @@ def ring_attention(
     axis_name: str = "sp",
     causal: bool = False,
     batch_axis: Optional[str] = "dp",
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Sequence-parallel attention over an ICI ring.
 
@@ -140,7 +172,8 @@ def ring_attention(
     spec = P(batch, axis_name, None, None)
     fn = jax.shard_map(
         functools.partial(
-            _ring_attention_local, axis_name=axis_name, causal=causal
+            _ring_attention_local, axis_name=axis_name, causal=causal,
+            scale=scale,
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -149,7 +182,8 @@ def ring_attention(
     return fn(q, k, v)
 
 
-def _ulysses_local(q, k, v, axis_name: str, causal: bool):
+def _ulysses_local(q, k, v, axis_name: str, causal: bool,
+                   scale: Optional[float] = None):
     """all_to_all: [B, S/n, H, D] → [B, S, H/n, D], full attention, back."""
     # axis 1 (local seq) gathers; axis 2 (heads) scatters.
     def swap_in(x):
@@ -163,7 +197,7 @@ def _ulysses_local(q, k, v, axis_name: str, causal: bool):
         )
 
     q_h, k_h, v_h = swap_in(q), swap_in(k), swap_in(v)
-    out = reference_attention(q_h, k_h, v_h, causal=causal)
+    out = reference_attention(q_h, k_h, v_h, causal=causal, scale=scale)
     return swap_out(out)
 
 
@@ -175,6 +209,7 @@ def ulysses_attention(
     axis_name: str = "sp",
     causal: bool = False,
     batch_axis: Optional[str] = "dp",
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Head-sharded (DeepSpeed-Ulysses-style) sequence parallelism: heads
     must divide by the sp mesh size."""
@@ -186,7 +221,9 @@ def ulysses_attention(
     batch = batch_axis if batch_axis and mesh.shape.get(batch_axis, 1) > 1 else None
     spec = P(batch, axis_name, None, None)
     fn = jax.shard_map(
-        functools.partial(_ulysses_local, axis_name=axis_name, causal=causal),
+        functools.partial(
+            _ulysses_local, axis_name=axis_name, causal=causal, scale=scale
+        ),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

@@ -12,6 +12,16 @@ logsumexp) — so training never materializes the S×S score matrix either,
 which is the whole long-context point (a dense-recompute backward would
 put an O(S²) cliff right back at seq 8k–16k).
 
+Grouped key-value heads (``k``, ``v`` with fewer heads than ``q``): query
+head ``hi`` reads key-value head ``hi // group`` through the BlockSpec
+index maps, so K and V are never repeated in HBM. The dk/dv kernel's grid
+runs over the KEY-VALUE heads with the group's query heads folded into
+its innermost (sequential) dimension beside the q tiles: one accumulator
+pass writes each dk/dv tile once, in [B, Hkv, S, D]. The alternative, a
+per-query-head dk/dv summed by XLA afterwards, writes and re-reads
+``group`` times the bytes for nothing. With ``group == 1`` every grid and
+index map is the one it was before grouping existed.
+
 The kernels are compiled by Mosaic and run on a TPU only; on any other
 backend the call raises. ``interpret=True`` (pallas guide: Debugging)
 runs the same kernel bodies in the Pallas interpreter — the tests pass
@@ -147,16 +157,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_kv: int,
-                    causal: bool, scale: float, q_block: int):
+                    causal: bool, scale: float, q_block: int,
+                    q_tiles: Optional[int] = None):
     """dk/dv for one kv tile, accumulated over q tiles (innermost).
 
     dv = pᵀ · g;  dk = scale · dsᵀ · q.
+
+    With grouped key-value heads the innermost dimension runs over the
+    group's query heads too, ``q_tiles`` tiles each (``None``: no groups).
     """
     ki = pl.program_id(2)   # kv tile is the OUTER tile here
-    qi = pl.program_id(3)
-    n_q = pl.num_programs(3)
+    step = pl.program_id(3)
+    n_steps = pl.num_programs(3)
+    qi = step if q_tiles is None else step % q_tiles
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -179,14 +194,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds.astype(q.dtype).T, q, preferred_element_type=jnp.float32
         ) * scale
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_kv", "interpret")
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_kv", "interpret", "scale"),
 )
 def flash_attention(
     q: jnp.ndarray,
@@ -196,15 +212,24 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_kv: Optional[int] = None,
     interpret: bool = False,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Shapes [B, S, H, D] → [B, S, H, D]. S must divide by the blocks;
-    a block left out is the largest of 1024, 512, 256, 128 that divides S.
+    """``q`` [B, S, H, D], ``k`` and ``v`` [B, S, Hkv, D] with H a multiple
+    of Hkv → [B, S, H, D]. S must divide by the blocks; a block left out
+    is the largest of 1024, 512, 256, 128 that divides S. ``scale`` is the
+    softmax scale, ``D ** -0.5`` when left out.
 
     Differentiable via custom_vjp; forward AND backward are blockwise
     pallas kernels (no S×S materialization anywhere)."""
-    s = q.shape[1]
+    s, d = q.shape[1], q.shape[3]
+    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+        raise ValueError(
+            f"{q.shape[2]} query heads over key-value shapes {k.shape}, "
+            f"{v.shape}"
+        )
     return _flash_vjp(
-        q, k, v, causal, _block(block_q, s), _block(block_kv, s), interpret
+        q, k, v, causal, _block(block_q, s), _block(block_kv, s), interpret,
+        1.0 / math.sqrt(d) if scale is None else scale,
     )
 
 
@@ -225,6 +250,7 @@ def sharded_flash_attention(
     mesh: Mesh,
     causal: bool = False,
     interpret: bool = False,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """:func:`flash_attention` on a device mesh.
 
@@ -235,15 +261,16 @@ def sharded_flash_attention(
     across both, so no collective is needed; the sequence stays whole
     on every device (sequence parallelism is ring attention's job). A
     dimension its mesh axis does not divide (the batch-1 sample of
-    ``model.init``) stays whole as well."""
+    ``model.init``) stays whole as well; heads are split only where ``tp``
+    divides the key-value heads, so a group stays on one device."""
     def axis(name, size):
         n = mesh.shape.get(name, 1)
         return name if n > 1 and size % n == 0 else None
 
-    spec = P(axis("dp", q.shape[0]), None, axis("tp", q.shape[2]), None)
+    spec = P(axis("dp", q.shape[0]), None, axis("tp", k.shape[2]), None)
     return jax.shard_map(
         functools.partial(
-            flash_attention, causal=causal, interpret=interpret
+            flash_attention, causal=causal, interpret=interpret, scale=scale
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -253,29 +280,36 @@ def sharded_flash_attention(
     )(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_vjp(q, k, v, causal, block_q, block_kv, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_vjp(q, k, v, causal, block_q, block_kv, interpret, scale):
     out_t, _, _, _, _ = _flash_forward(
-        q, k, v, causal, block_q, block_kv, interpret
+        q, k, v, causal, block_q, block_kv, interpret, scale
     )
     return jnp.einsum("bhsd->bshd", out_t)
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_kv, interpret):
+def _flash_fwd_rule(q, k, v, causal, block_q, block_kv, interpret, scale):
     out_t, lse, qt, kt, vt = _flash_forward(
-        q, k, v, causal, block_q, block_kv, interpret
+        q, k, v, causal, block_q, block_kv, interpret, scale
     )
     # Residuals stay in the kernels' [B,H,S,D] layout — the backward
     # would otherwise re-transpose q/k/v/out all over again.
     return jnp.einsum("bhsd->bshd", out_t), (qt, kt, vt, out_t, lse)
 
 
-def _flash_bwd_rule(causal, block_q, block_kv, interpret, res, g):
+def _kv_head(group: int):
+    """Key-value head of query head ``hi``; the identity without groups."""
+    return (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
+
+
+def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
     qt, kt, vt, out_t, lse = res
     b, h, s, d = qt.shape
+    h_kv = kt.shape[1]
+    group = h // h_kv
+    kv_of = _kv_head(group)
     block_q = min(block_q, s)
     block_kv = min(block_kv, s)
-    scale = 1.0 / math.sqrt(d)
 
     gt = jnp.einsum("bshd->bhsd", g)
     # delta_i = Σ_d dO_i · O_i — the softmax-jacobian row term.
@@ -287,7 +321,7 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, res, g):
         (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
     )
     kv_spec = pl.BlockSpec(
-        (1, 1, block_kv, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)
+        (1, 1, block_kv, d), lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0)
     )
     row_spec = pl.BlockSpec(
         (1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
@@ -305,26 +339,32 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, res, g):
         interpret=interpret,
     )(qt, kt, vt, gt, lse, delta)
 
-    # dk/dv iterate kv as the outer tile, q innermost.
-    q_spec_t = pl.BlockSpec(
-        (1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, qi, 0)
-    )
+    # dk/dv iterate kv as the outer tile, q innermost; the grid's heads
+    # are the key-value heads, and a group's query heads share the
+    # innermost dimension with the q tiles (step = head in group × q
+    # tiles + q tile).
+    q_tiles = s // block_q
+    if group == 1:
+        q_at = lambda bi, hi, ki, qi: (bi, hi, qi, 0)  # noqa: E731
+    else:
+        q_at = lambda bi, hi, ki, step: (  # noqa: E731
+            bi, hi * group + step // q_tiles, step % q_tiles, 0
+        )
+    q_spec_t = pl.BlockSpec((1, 1, block_q, d), q_at)
     kv_spec_t = pl.BlockSpec(
         (1, 1, block_kv, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)
     )
-    row_spec_t = pl.BlockSpec(
-        (1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, qi, 0)
-    )
+    row_spec_t = pl.BlockSpec((1, 1, block_q, 1), q_at)
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, block_kv=block_kv, causal=causal, scale=scale,
-            q_block=block_q,
+            q_block=block_q, q_tiles=None if group == 1 else q_tiles,
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b, h, s, d), kt.dtype),
-            jax.ShapeDtypeStruct((b, h, s, d), vt.dtype),
+            jax.ShapeDtypeStruct((b, h_kv, s, d), kt.dtype),
+            jax.ShapeDtypeStruct((b, h_kv, s, d), vt.dtype),
         ),
-        grid=(b, h, s // block_kv, s // block_q),
+        grid=(b, h_kv, s // block_kv, group * q_tiles),
         in_specs=[
             q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
             row_spec_t,
@@ -352,14 +392,15 @@ def _flash_forward(
     block_q: int,
     block_kv: int,
     interpret: bool,
+    scale: float,
 ):
     b, s, h, d = q.shape
+    kv_of = _kv_head(h // k.shape[2])
     block_q = min(block_q, s)
     block_kv = min(block_kv, s)
     if s % block_q or s % block_kv:
         raise ValueError(f"seq len {s} not divisible by blocks "
                          f"({block_q}, {block_kv})")
-    scale = 1.0 / math.sqrt(d)
 
     # [B, S, H, D] → [B, H, S, D] for row-major q/kv tiles.
     qt = jnp.einsum("bshd->bhsd", q)
@@ -386,10 +427,12 @@ def _flash_forward(
                 (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)
+                (1, 1, block_kv, d),
+                lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)
+                (1, 1, block_kv, d),
+                lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0),
             ),
         ],
         out_specs=(

@@ -28,7 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raydp_tpu import fault as _fault
 from raydp_tpu.data.ml_dataset import MLDataset
-from raydp_tpu.models import dropout
+from raydp_tpu.models import dropout, mamba
 from raydp_tpu.parallel.mesh import MeshSpec
 from raydp_tpu.telemetry import accounting as _acct
 from raydp_tpu.telemetry import events as _events
@@ -473,6 +473,10 @@ class JAXEstimator:
             if self._model_takes_deterministic() else (0, 0)
         )
         dropout.report(sites, words)
+        mamba.report(
+            getattr(self._model, "cfg", None),
+            tokens_per_step=int(np.prod(self._sample_batch.shape)),
+        )
 
         use_aux = self.aux_losses
 

@@ -15,6 +15,7 @@ EXAMPLES = [
     "dlrm_criteo.py",
     "bert_glue.py",
     "olmoe_finetune.py",
+    "granite_finetune.py",
     "gbt_nyctaxi.py",
     "spmd_job.py",
     "pod_driver.py",

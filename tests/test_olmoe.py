@@ -415,3 +415,56 @@ def test_flash_kernels_compile_for_the_chip_at_the_cells_shape(one_chip):
     assert compiled.as_text().count("tpu_custom_call") == 3
     # No S x S scores: dense attention keeps 2 x 16 x 4096^2 float32 here.
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_grouped_flash_kernels_compile_for_the_chip_at_granites_shape(
+    one_chip
+):
+    """32 query heads over 8 key-value heads of 64 (half a lane tile), the
+    published softmax scale: Mosaic accepts the grouped index maps and the
+    dk/dv grid over key-value heads; dk and dv come out in the key-value
+    shape, nothing repeated."""
+    from raydp_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, scale=1 / 64).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on(one_chip, (q, kv, kv))
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    dq, dk, dv = jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
+
+
+def test_chunked_scan_compiles_for_the_chip_at_published_widths(one_chip):
+    """One sequence of 4,096 tokens, 64 heads of 64, state 128, chunks of
+    256, forward and backward in plain ``jax.numpy``: it compiles, and XLA
+    fuses the [tokens, heads, chunk] decay and score arrays (268 MB each in
+    float32, were they stored) into the matmuls that use them: 118 MB of
+    temporaries, not a handful of those arrays."""
+    from raydp_tpu.ops.ssd import ssd_chunked
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (
+        jax.ShapeDtypeStruct((1, 4096, 64, 64), bf16),
+        jax.ShapeDtypeStruct((1, 4096, 64), f32),
+        jax.ShapeDtypeStruct((64,), f32),
+        jax.ShapeDtypeStruct((1, 4096, 1, 128), bf16),
+        jax.ShapeDtypeStruct((1, 4096, 1, 128), bf16),
+        jax.ShapeDtypeStruct((64,), f32),
+    )
+
+    def loss(*a):
+        return jnp.sum(ssd_chunked(*a, 256).astype(f32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *_on(one_chip, args)
+    ).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.25e9, temp
