@@ -609,6 +609,11 @@ class CausalLM(nn.Module):
 
     def __call__(self, input_ids, deterministic: bool = True):
         h = self.encoder(input_ids, None, deterministic)
+        # The final norm's output is written once. Fused into the head's
+        # products instead, the norm is computed again inside the weight
+        # gradient, whose tiling gets worse for it (PERF.md §6, PR 31:
+        # 10.9 -> 11.9 ms at [2, 4096, 2048] x [2048, 50304]).
+        h = jax.lax.optimization_barrier(h)
         return _logits(self, h)
 
     def prefill(self, input_ids, lengths):

@@ -5,6 +5,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Union
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -49,12 +50,69 @@ def softmax_crossentropy(logits, targets):
 def lm_crossentropy(logits, tokens):
     """Next-token language-modeling loss: ``logits`` are the model's
     outputs on the full sequence ``tokens`` — position t predicts token
-    t+1 (the self-supervised objective; targets are the inputs shifted)."""
-    return jnp.mean(
-        optax.softmax_cross_entropy_with_integer_labels(
-            logits[:, :-1, :], tokens[:, 1:].astype(jnp.int32)
-        )
-    )
+    t+1 (the self-supervised objective; targets are the inputs shifted).
+
+    The TARGETS are shifted, never the logits: the last position gets a
+    weight of 0 and the mean runs over the same ``B * (S - 1)`` terms as
+    ``logits[:, :-1]`` against ``tokens[:, 1:]`` would give. Do not
+    "simplify" it back to that slice: an array of ``[B, S-1, V]`` (4,095
+    rows) does not tile on the chip, and XLA then copies the logits four
+    times around the loss, 17-22 ms of a 203 ms step at
+    ``[1, 4096, 100352]`` float32 (PERF.md §6, PR 31). The backward pass
+    is written out (``jax.custom_vjp``) as ONE elementwise expression
+    over the logits as the head wrote them; autodiff of the log-softmax
+    saves a second copy of the logits and scatter-adds the label's
+    gradient into a zero array. float32 throughout."""
+    targets = jnp.roll(tokens.astype(jnp.int32), -1, axis=1)
+    return _shifted_ce(logits, targets)
+
+
+def _weight_and_count(logits):
+    """``[1, S]`` weight that leaves the last position out, and the
+    number of terms of the mean."""
+    b, s = logits.shape[:2]
+    weight = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
+    return weight, b * (s - 1)
+
+
+def _is_target(logits, targets):
+    vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    return vocab == targets[..., None]
+
+
+def _shifted_ce_fwd(logits, targets):
+    x = logits.astype(jnp.float32)
+    top = jnp.max(x, axis=-1)
+    lse = top + jnp.log(jnp.sum(jnp.exp(x - top[..., None]), axis=-1))
+    label = jnp.sum(jnp.where(_is_target(x, targets), x, 0.0), axis=-1)
+    weight, count = _weight_and_count(x)
+    loss = jnp.sum((lse - label) * weight) / count
+    return loss, (logits, lse, targets)
+
+
+@jax.custom_vjp
+def _shifted_ce(logits, targets):
+    return _shifted_ce_fwd(logits, targets)[0]
+
+
+def _shifted_ce_bwd(residuals, g):
+    logits, lse, targets = residuals
+    x = logits.astype(jnp.float32)
+    weight, count = _weight_and_count(x)
+    scale = (weight * (g / count))[..., None]
+    grad = (
+        jnp.exp(x - lse[..., None])
+        - _is_target(x, targets).astype(jnp.float32)
+    ) * scale
+    # Written once, then read by the head's two backward products. Left
+    # to itself XLA fuses the expression into the operand of both, and
+    # each computes it again for every tile of its output: 7.5 ms of the
+    # step above, 4.7 at ``[2, 4096, 50304]`` (PERF.md §6, PR 31).
+    grad = jax.lax.optimization_barrier(grad.astype(logits.dtype))
+    return grad, None
+
+
+_shifted_ce.defvjp(_shifted_ce_fwd, _shifted_ce_bwd)
 
 
 LOSSES: Dict[str, Callable] = {

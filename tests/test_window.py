@@ -344,7 +344,9 @@ def session():
 def test_window_on_cluster_executor(session):
     """Window + posexplode runs through real ETL workers + shm store."""
     pdf = pd.DataFrame(
-        {"c0": ["u"] * 4 + ["v"] * 2, "c1": ["w"] * 3 + ["u"] * 3}
+        # No two values of a column tie: row_number over equal counts
+        # follows the order the workers' partitions arrive in.
+        {"c0": ["u"] * 4 + ["v"] * 2, "c1": ["w"] * 4 + ["u"] * 2}
     )
     df = rdf.from_pandas(pdf, num_partitions=2)
     melted = df.posexplode(["c0", "c1"], pos_name="column_id",
