@@ -7,6 +7,7 @@ from raydp_tpu.models.transformer import (
     TransformerEncoder,
     bert_base,
     granite_h_micro,
+    lfm2_8b_a1b,
     olmoe,
     param_shardings,
     tiny_transformer,
@@ -53,6 +54,7 @@ __all__ = [
     "CausalLM",
     "bert_base",
     "granite_h_micro",
+    "lfm2_8b_a1b",
     "olmoe",
     "tiny_transformer",
     "param_shardings",

@@ -60,11 +60,27 @@ def _conv_init(taps: int):
     return init
 
 
+def causal_depthwise_conv(x, kernel, bias=None):
+    """``out_t = bias + Σ_j kernel[j] · x_{t-(k-1)+j}`` over the sequence
+    axis of ``x`` [B, S, channels], zeros before the first token: ``k``
+    shifted multiply-adds in float32 (``kernel`` [k, channels]), the form
+    measurement pinned for 4 taps (PERF.md §6, PR 30) and for the 3 taps of
+    ``models/shortconv.py`` (PR 32). Returns float32."""
+    taps, s = kernel.shape[0], x.shape[-2]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = None if bias is None else bias.astype(jnp.float32)
+    for j in range(taps):
+        term = kernel[j].astype(jnp.float32) * padded[
+            :, j:j + s
+        ].astype(jnp.float32)
+        out = term if out is None else out + term
+    return out
+
+
 class CausalConv1d(nn.Module):
     """Depthwise causal convolution over the sequence with a bias, and the
     SiLU that follows it: ``silu(b + Σ_j w_j · in_{t-(k-1)+j})``, zeros
-    before the first token. ``k`` shifted multiply-adds in float32
-    (``kernel`` [k, channels])."""
+    before the first token (``kernel`` [k, channels])."""
 
     taps: int
     dtype: jnp.dtype
@@ -81,14 +97,9 @@ class CausalConv1d(nn.Module):
         bias = self.param(
             "bias", _replicated(init), (channels,), self.param_dtype,
         )
-        s = x.shape[-2]
-        padded = jnp.pad(x, ((0, 0), (self.taps - 1, 0), (0, 0)))
-        out = bias.astype(jnp.float32)
-        for j in range(self.taps):
-            out = out + kernel[j].astype(jnp.float32) * padded[
-                :, j:j + s
-            ].astype(jnp.float32)
-        return jax.nn.silu(out).astype(self.dtype)
+        return jax.nn.silu(
+            causal_depthwise_conv(x, kernel, bias)
+        ).astype(self.dtype)
 
 
 class SelectiveScan(nn.Module):
@@ -186,8 +197,7 @@ class Mamba2Mixer(nn.Module):
 
 
 def layers_of(cfg) -> int:
-    kinds = getattr(cfg, "layer_types", None) or ()
-    return sum(1 for kind in kinds if kind == "mamba")
+    return sum(1 for kind in getattr(cfg, "kinds", ()) if kind == "mamba")
 
 
 def report(cfg, tokens_per_step: int) -> None:
@@ -210,7 +220,7 @@ def report(cfg, tokens_per_step: int) -> None:
             "query / %d key-value heads of %d; scan %d heads of %d in %d "
             "group(s), state %d, chunk %d (%d chunks a step); scan: %s; "
             "convolution: %d taps as %s",
-            layers, len(cfg.layer_types) - layers, cfg.n_heads,
+            layers, cfg.kinds.count("attention"), cfg.n_heads,
             cfg.kv_heads, cfg.head_dim, cfg.ssm_heads, cfg.ssm_head_dim,
             cfg.ssm_groups, cfg.ssm_state, cfg.ssm_chunk, chunks,
             SCAN_IMPLEMENTATION, cfg.ssm_conv, CONV_IMPLEMENTATION,

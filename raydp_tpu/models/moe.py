@@ -19,10 +19,28 @@ the published OLMoE block (Muennighoff et al. 2024, arXiv:2409.02060):
   updates, 95 ns a row: PERF.md §6, PR 25).
 * Expert weights carry the logical axes ``('expert', 'embed', 'mlp')``
   (experts over ``dp``, an expert's FFN over ``tp``); no biases.
+* What the router does is configuration of the one layer: ``scoring``
+  ``softmax`` (OLMoE) or ``sigmoid``; a ``selection_bias`` added to the
+  scores for the selection only (the buffer ``expert_bias`` in collection
+  :data:`BUFFERS`, drawn at init by :func:`balancing_bias`: no gradient,
+  and the estimator's step leaves it as it was); ``normalize_gates`` divides the selected scores by their sum +
+  1e-6; ``gate_scale`` multiplies them.
+* The share of an expert-parallel deployment: the router keeps all
+  ``n_experts`` outputs and ``top_k`` experts a token, the layer HOLDS
+  experts ``[first_expert, first_expert + held_experts)`` — their weights
+  are the only ones it has — sorts the pairs on absent experts behind the
+  held ones, runs the grouped matmuls over the held rows alone (the group
+  sizes sum to fewer than ``T·k`` rows and the kernel's grid ends there)
+  and returns the part of the sum its own experts give. No pair on a held
+  expert is dropped whatever the routing, so every array keeps ``T·k``
+  rows; what lies behind the held rows is never computed and is masked
+  where rows go back to tokens. On one chip the layer runs without its
+  exchange: nothing stands in for the absent chips.
 * The load-balancing loss (``E · Σ_e f_e · p_e``, weight 0.01) and the
   router z-loss (``mean(logsumexp(logits)²)``, weight 0.001) are sown into
-  the ``'losses'`` collection as ``moe_aux``; pull them with
-  :func:`moe_aux_loss`. The tokens each expert received are sown into
+  the ``'losses'`` collection as ``moe_aux`` (nothing where both weights
+  are 0); pull them with :func:`moe_aux_loss`. The tokens each expert
+  received (and, for a share, each held expert) are sown into
   :data:`STATS`; ``JAXEstimator`` sums them over an epoch on the device
   (:func:`step_stats`, :func:`report_epoch`).
 """
@@ -31,7 +49,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,18 +70,70 @@ __all__ = [
 logger = logging.getLogger(__name__)
 
 STATS = "moe_stats"
+# Collection of what a layer reads and no gradient step may change: the
+# router's selection bias. ``JAXEstimator``'s step hands it on as it was.
+BUFFERS = "buffers"
+
+
+BALANCING_ROUNDS = 4
+
+
+def balancing_bias(scores, top_k: int):
+    """``expert_bias`` as drawn at init: the values under which every
+    expert is among the ``top_k`` of ``scores + bias`` for the same share
+    of the tokens ``model.init`` is given (``scores`` ``[T, E]``). A token
+    picks expert e when e's biased score beats its rival, the ``top_k``-th
+    largest of the others; each round moves ``b_e`` so that ``top_k / E``
+    of the tokens do, all experts at once, and a few rounds settle what
+    the experts' moves do to each other's rivals. It stands for what the
+    buffer is in a trained checkpoint, the bias that balances the experts'
+    loads: a router of random weights sends a Zipf-distributed corpus's
+    few frequent tokens to a few experts, and which ones differs by seed
+    (PERF.md §6, PR 32: with a bias drawn at random the pairs on 8 of 32
+    experts were 24% of all at one seed and 37% at another, 4.4% apart in
+    step time). The published checkpoints' values are loaded over it; the
+    public config gives no update rule, so nothing updates it after init."""
+    e = scores.shape[-1]
+    bias = jnp.zeros((e,), scores.dtype)
+    for _ in range(BALANCING_ROUNDS):
+        ranked = scores + bias
+        top, _ = jax.lax.top_k(ranked, top_k + 1)
+        kth, below = top[:, top_k - 1:top_k], top[:, top_k:]
+        rival = jnp.where(ranked >= kth, below, kth)           # [T, E]
+        bias = bias - jnp.quantile(ranked - rival, 1.0 - top_k / e, axis=0)
+    return bias - jnp.mean(bias)
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     d_model: int = 768
     d_ff: int = 3072                 # width of one expert
-    n_experts: int = 8
+    n_experts: int = 8               # the router's outputs
     top_k: int = 2
     aux_loss_weight: float = 1e-2    # load balancing
     z_loss_weight: float = 1e-3      # router z-loss
+    scoring: str = "softmax"         # softmax | sigmoid
+    selection_bias: bool = False     # scores + expert_bias pick the top k
+    normalize_gates: bool = False    # selected scores / (their sum + 1e-6)
+    gate_scale: float = 1.0
+    # The share held here: experts [first_expert, first_expert + held).
+    first_expert: int = 0
+    held_experts: Optional[int] = None   # None = all n_experts
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+
+    @property
+    def held(self) -> int:
+        held = self.n_experts if self.held_experts is None else (
+            self.held_experts
+        )
+        if not (0 < held and 0 <= self.first_expert
+                and self.first_expert + held <= self.n_experts):
+            raise ValueError(
+                f"experts [{self.first_expert}, {self.first_expert + held}) "
+                f"are not among the {self.n_experts} routed over"
+            )
+        return held
 
 
 def _expert_init(*logical_axes: str):
@@ -73,59 +143,71 @@ def _expert_init(*logical_axes: str):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def take_rows(x, perm, inverse, fan: int = 1):
+def take_rows(x, perm, inverse, fan: int = 1, live=None):
     """``x[perm // fan]`` for a permutation ``perm`` of ``range(len(x) *
     fan)`` with inverse ``inverse``: every row of ``x`` goes to ``fan``
     places. The transpose of a permutation is its inverse, so the
     cotangent is ``g[inverse]`` summed over each row's ``fan`` copies — a
-    gather, where ``jax.grad`` of the plain gather is a scatter-add."""
+    gather, where ``jax.grad`` of the plain gather is a scatter-add.
+    ``live`` ``[len(x), fan]`` (a share's layer) says which copies anyone
+    computed on: the cotangent of the others is not read."""
     return x[perm // fan] if fan > 1 else x[perm]
 
 
-def _take_rows_fwd(x, perm, inverse, fan):
-    return take_rows(x, perm, inverse, fan), inverse
+def _take_rows_fwd(x, perm, inverse, fan, live):
+    return take_rows(x, perm, inverse, fan, live), (inverse, live)
 
 
-def _take_rows_bwd(fan, inverse, g):
+def _take_rows_bwd(fan, res, g):
+    inverse, live = res
     gx = g[inverse]
-    if fan > 1:
-        gx = gx.reshape(-1, fan, gx.shape[-1]).sum(axis=1)
-    return gx, None, None
+    if fan > 1 or live is not None:
+        gx = gx.reshape(-1, fan, gx.shape[-1])
+        if live is not None:
+            gx = jnp.where(live[..., None], gx, 0)
+        gx = gx.sum(axis=1)
+    return gx, None, None, None
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 @jax.custom_vjp
-def combine_rows(rows, gate, order, inverse):
+def combine_rows(rows, gate, order, inverse, live=None):
     """Expert-ordered ``rows`` ``[T·k, D]`` back to their tokens: token
     t's output is the sum over its k pairs of ``gate[t, j]`` times the
     pair's row, float32 inside. The cotangents need no row of a ``T·k``
     array out of order: a pair's is its token's times its gate, read from
-    the ``[T, D]`` cotangent in expert order."""
+    the ``[T, D]`` cotangent in expert order. ``live`` ``[T, k]`` (a
+    share's layer) says which pairs have a row that was computed: the
+    others add nothing and their gates get no cotangent."""
     t, k = gate.shape
     pairs = rows[inverse].reshape(t, k, rows.shape[-1])
+    if live is not None:
+        pairs = jnp.where(live[..., None], pairs, 0)
     return jnp.sum(
         pairs.astype(jnp.float32) * gate[..., None], axis=1
     ).astype(rows.dtype)
 
 
-def _combine_rows_fwd(rows, gate, order, inverse):
-    return combine_rows(rows, gate, order, inverse), (
-        rows, gate, order, inverse
+def _combine_rows_fwd(rows, gate, order, inverse, live):
+    return combine_rows(rows, gate, order, inverse, live), (
+        rows, gate, order, inverse, live
     )
 
 
 def _combine_rows_bwd(res, g):
-    rows, gate, order, inverse = res
+    rows, gate, order, inverse, live = res
     k = gate.shape[1]
     g_rows = g[order // k].astype(jnp.float32)            # [T·k, D]
     d_rows = g_rows * gate.reshape(-1)[order][:, None]
     d_gate = jnp.sum(rows.astype(jnp.float32) * g_rows, axis=-1)
+    d_gate = d_gate[inverse].reshape(gate.shape)
+    if live is not None:
+        d_gate = jnp.where(live, d_gate, 0)
     return (
-        d_rows.astype(rows.dtype),
-        d_gate[inverse].reshape(gate.shape).astype(gate.dtype),
-        None, None,
+        d_rows.astype(rows.dtype), d_gate.astype(gate.dtype),
+        None, None, None,
     )
 
 
@@ -133,19 +215,29 @@ combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 @functools.lru_cache(maxsize=None)
-def _log_once(n_experts: int, top_k: int, d_ff: int) -> None:
+def _log_once(cfg: "MoEConfig") -> None:
     logger.info(
-        "MoE layer: %d experts of width %d, top-%d, no capacity; grouped "
-        "matmul: %s", n_experts, d_ff, top_k, IMPLEMENTATION,
+        "MoE layer: %d experts of width %d, top-%d of %s scores%s%s, no "
+        "capacity; holds experts [%d, %d); grouped matmul: %s",
+        cfg.n_experts, cfg.d_ff, cfg.top_k, cfg.scoring,
+        " + selection bias" if cfg.selection_bias else "",
+        ", gates normalised" if cfg.normalize_gates else "",
+        cfg.first_expert, cfg.first_expert + cfg.held, IMPLEMENTATION,
     )
+
+
+def _add(a, b):
+    return a + b
 
 
 class MoELayer(nn.Module):
     """Top-k routed SwiGLU experts over the trailing feature axis.
 
     Input ``[..., D]`` → output ``[..., D]`` in the compute dtype; tokens
-    are the flattened leading axes, and every token reaches all ``top_k``
-    of its experts. A float32 input reaches the router as it is.
+    are the flattened leading axes, and every token reaches all of its
+    ``top_k`` experts that this layer holds (all of them unless the
+    configuration names a share). A float32 input reaches the router as it
+    is.
     """
 
     cfg: MoEConfig
@@ -159,9 +251,10 @@ class MoELayer(nn.Module):
             raise ValueError(f"feature dim {d} != cfg.d_model {cfg.d_model}")
         tokens = x.reshape(-1, d)
         n_tokens = tokens.shape[0]
-        e, k = cfg.n_experts, cfg.top_k
+        e, k, held = cfg.n_experts, cfg.top_k, cfg.held
+        first, share = cfg.first_expert, held < cfg.n_experts
         if self.is_initializing():
-            _log_once(e, k, cfg.d_ff)
+            _log_once(cfg)
 
         # Router in f32 regardless of trunk dtype.
         logits = nn.Dense(
@@ -173,44 +266,63 @@ class MoELayer(nn.Module):
             precision=jax.lax.Precision.HIGHEST,
             name="router",
         )(tokens.astype(jnp.float32))
+        if cfg.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {cfg.scoring!r}")
         with jax.named_scope("router"):
-            probs = jax.nn.softmax(logits, axis=-1)            # [T, E]
-            _, expert = jax.lax.top_k(probs, k)                # [T, k]
+            probs = (jax.nn.softmax(logits, axis=-1)           # [T, E]
+                     if cfg.scoring == "softmax" else jax.nn.sigmoid(logits))
+            ranked = probs
+            if cfg.selection_bias:
+                # The bias enters the selection only: the gates are scores.
+                ranked = probs + self.variable(
+                    BUFFERS, "expert_bias", balancing_bias, probs, k
+                ).value
+            _, expert = jax.lax.top_k(ranked, k)               # [T, k]
             chosen = jax.nn.one_hot(expert, e, dtype=jnp.float32)
             # The chosen probabilities as a product with the one-hot
             # choice: top_k's own values would give the router's
             # gradient as a scatter of T·k updates.
             gate = jnp.einsum("te,tke->tk", probs, chosen)
+            if cfg.normalize_gates:
+                gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-6)
+            if cfg.gate_scale != 1.0:
+                gate = gate * cfg.gate_scale
             counts = chosen.sum(axis=(0, 1))                   # [E]
-            # E · Σ_e f_e · p_e with f the share of the T·k pairs that
-            # went to e (k at uniform routing), and the z-loss.
-            balance = e * jnp.sum(
-                counts / n_tokens * probs.mean(axis=0)
-            )
-            z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+            if cfg.aux_loss_weight or cfg.z_loss_weight:
+                # E · Σ_e f_e · p_e with f the share of the T·k pairs
+                # that went to e (k at uniform routing), and the z-loss.
+                balance = e * jnp.sum(
+                    counts / n_tokens * probs.mean(axis=0)
+                )
+                z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+                self.sow(
+                    "losses", "moe_aux",
+                    cfg.aux_loss_weight * balance + cfg.z_loss_weight * z,
+                    reduce_fn=_add,
+                    init_fn=lambda: jnp.zeros((), jnp.float32),
+                )
             self.sow(
-                "losses", "moe_aux",
-                cfg.aux_loss_weight * balance + cfg.z_loss_weight * z,
-                reduce_fn=lambda a, b: a + b,
-                init_fn=lambda: jnp.zeros((), jnp.float32),
-            )
-            self.sow(
-                STATS, "expert_tokens", counts,
-                reduce_fn=lambda a, b: a + b,
+                STATS, "expert_tokens", counts, reduce_fn=_add,
                 init_fn=lambda: jnp.zeros((e,), jnp.float32),
             )
+            if share:
+                counts = counts[first:first + held]
+                self.sow(
+                    STATS, "held_tokens", counts, reduce_fn=_add,
+                    init_fn=lambda: jnp.zeros((held,), jnp.float32),
+                )
 
         w_gate = self.param(
             "w_gate", _expert_init("expert", "embed", "mlp"),
-            (e, d, cfg.d_ff), cfg.param_dtype,
+            (held, d, cfg.d_ff), cfg.param_dtype,
         )
         w_up = self.param(
             "w_up", _expert_init("expert", "embed", "mlp"),
-            (e, d, cfg.d_ff), cfg.param_dtype,
+            (held, d, cfg.d_ff), cfg.param_dtype,
         )
         w_down = self.param(
             "w_down", _expert_init("expert", "mlp", "embed"),
-            (e, cfg.d_ff, d), cfg.param_dtype,
+            (held, cfg.d_ff, d), cfg.param_dtype,
         )
 
         with jax.named_scope("permute"):
@@ -218,12 +330,20 @@ class MoELayer(nn.Module):
             # the pairs by expert, ``inverse`` is each pair's place in
             # that list: two sorts, no scatter.
             pairs = jnp.arange(n_tokens * k, dtype=jnp.int32)
-            _, order = jax.lax.sort_key_val(
-                expert.reshape(-1).astype(jnp.int32), pairs
-            )
+            key, live = expert.reshape(-1).astype(jnp.int32), None
+            if share:
+                # Held experts are groups 0..held-1; a pair on an absent
+                # expert sorts behind them all, where no group reaches.
+                key = key - first
+                live = (key >= 0) & (key < held)
+                key = jnp.where(live, key, held)
+                live = live.reshape(n_tokens, k)
+            _, order = jax.lax.sort_key_val(key, pairs)
             _, inverse = jax.lax.sort_key_val(order, pairs)
             group_sizes = counts.astype(jnp.int32)
-            rows = take_rows(tokens.astype(cfg.dtype), order, inverse, k)
+            rows = take_rows(
+                tokens.astype(cfg.dtype), order, inverse, k, live
+            )
         with jax.named_scope("experts"):
             w_gate, w_up, w_down = (
                 w.astype(cfg.dtype) for w in (w_gate, w_up, w_down)
@@ -233,7 +353,7 @@ class MoELayer(nn.Module):
             ) * grouped_matmul(rows, w_up, group_sizes)
             rows = grouped_matmul(h, w_down, group_sizes)
         with jax.named_scope("unpermute"):
-            out = combine_rows(rows, gate, order, inverse)
+            out = combine_rows(rows, gate, order, inverse, live)
         return out.reshape(*lead_shape, d)
 
 
@@ -281,26 +401,58 @@ def moe_aux_loss(variables) -> jnp.ndarray:
 def step_stats(variables) -> dict:
     """What one step's ``mutable=['losses', STATS]`` state says about its
     routing, as device values: ``{}`` for a model with no routed layer,
-    else the auxiliary loss and the tokens each expert received, summed
-    over the layers."""
-    sown = jax.tree_util.tree_leaves(variables.get(STATS, {}))
+    else the auxiliary loss and the tokens each expert received (a share's
+    layers also: each held expert), summed over the layers."""
+    from flax.traverse_util import flatten_dict
+
+    sown: dict = {}
+    for path, counts in flatten_dict(dict(variables.get(STATS, {}))).items():
+        sown[path[-1]] = sown.get(path[-1], 0) + counts
     if not sown:
         return {}
-    return {"aux_loss": moe_aux_loss(variables), "expert_tokens": sum(sown)}
+    return {"aux_loss": moe_aux_loss(variables), **sown}
 
 
 def report_epoch(stats: dict, n_steps: int) -> None:
     """Gauges from an epoch's summed :func:`step_stats`, fetched with the
-    epoch's loss: the mean auxiliary loss a step, and the fullest expert's
-    tokens over the mean expert's."""
+    epoch's loss: the mean auxiliary loss a step, the (token, expert) pairs
+    a step routes and how many of them landed on experts held here (all of
+    them unless the layers are a share: a quarter at uniform routing over
+    four shares), and the fullest held expert's tokens over the mean held
+    expert's."""
     import numpy as np
 
     from raydp_tpu.utils.profiling import metrics
 
     tokens = np.asarray(stats["expert_tokens"], np.float64)
+    held = np.asarray(stats.get("held_tokens", tokens), np.float64)
     metrics.gauge_set("moe/aux_loss", float(stats["aux_loss"]) / n_steps)
-    metrics.gauge_set("moe/load_max_over_mean", tokens.max() / tokens.mean())
+    metrics.gauge_set("moe/load_max_over_mean", held.max() / held.mean())
     metrics.gauge_set("moe/expert_tokens_per_step", tokens.sum() / n_steps)
+    metrics.gauge_set("moe/held_pairs_per_step", held.sum() / n_steps)
+    metrics.gauge_set("moe/held_pair_share", held.sum() / tokens.sum())
+
+
+def report(model) -> None:
+    """Static for a compiled step: two gauges where the step is built (as
+    ``models/mamba.report``): the experts the routed layers route over and
+    how many of them this process holds. Zero for a model without one."""
+    from raydp_tpu.utils.profiling import metrics
+
+    cfg, moe = getattr(model, "cfg", None), getattr(model, "moe", None)
+    if moe is None and "moe" in getattr(cfg, "ffn_kinds", ()):
+        moe = cfg.moe_config()
+    routed, held = (moe.n_experts, moe.held) if moe is not None else (0, 0)
+    metrics.gauge_set("moe/experts_routed", routed)
+    metrics.gauge_set("moe/experts_held", held)
+    if held < routed:
+        logger.info(
+            "routed layers: a share of an expert-parallel deployment, "
+            "experts [%d, %d) of %d held here, top-%d over all %d; pairs on "
+            "absent experts cost no matmul row and nothing stands in for "
+            "their exchange", moe.first_expert, moe.first_expert + held,
+            routed, moe.top_k, routed,
+        )
 
 
 def tiny_moe(**overrides) -> MoEConfig:

@@ -68,14 +68,29 @@ class TransformerConfig:
     norm_eps: float = 1e-6
     positions: str = "learned"       # learned (a table) | rotary | none
     rope_theta: float = 10000.0
-    qk_norm: bool = False            # norm over the whole q and k projections
+    # Norm of q and k before the positions: False | True or "projection"
+    # (over the whole projection) | "head" (over each head's values, one
+    # learned weight of head_dim).
+    qk_norm: Any = False
     use_bias: bool = True            # biases of the block's projections
     ffn: str = "gelu"                # gelu | swiglu (dense) | moe (routed)
-    n_experts: int = 0
+    n_experts: int = 0               # the router's outputs
     top_k: int = 0
     d_expert: int = 0
-    # Layer i's mixer: "attention" | "mamba"; None = attention everywhere.
+    # What the router of a routed layer does, and the share of the experts
+    # held here (``models/moe.py``; the defaults are OLMoE's layer).
+    router_scoring: str = "softmax"  # softmax | sigmoid
+    router_bias: bool = False        # expert_bias, for the selection only
+    norm_top_k: bool = False         # gates = selected scores / their sum
+    routed_scaling: float = 1.0
+    first_expert: int = 0            # experts [first, first + held) are here
+    experts_held: Optional[int] = None      # None = all n_experts
+    moe_loss_weights: Tuple[float, float] = (1e-2, 1e-3)   # balance, z
+    # Layer i as "<mixer>" or "<mixer>:<ffn>": the mixer is "attention" |
+    # "mamba" | "conv", the FFN kind one of ``ffn``'s and ``ffn`` itself
+    # where the entry names none. None = attention and ``ffn`` everywhere.
     layer_types: Optional[Tuple[str, ...]] = None
+    conv_taps: int = 3               # the "conv" mixer (models/shortconv.py)
     n_kv_heads: Optional[int] = None       # None = n_heads (no grouping)
     attention_scale: Optional[float] = None    # None = head_dim ** -0.5
     tie_head: bool = False           # CausalLM's logits from tok_embed
@@ -106,15 +121,30 @@ class TransformerConfig:
         return self.n_kv_heads or self.n_heads
 
     @property
-    def kinds(self) -> Tuple[str, ...]:
-        """The mixer of every layer, ``n_layers`` long."""
-        kinds = self.layer_types or ("attention",) * self.n_layers
-        if len(kinds) != self.n_layers or set(kinds) - {"attention", "mamba"}:
+    def layers(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, FFN kind) of every layer, ``n_layers`` long."""
+        entries = self.layer_types or ("attention",) * self.n_layers
+        layers = tuple(
+            tuple((entry.split(":", 1) + [self.ffn])[:2]) for entry in entries
+        )
+        if (len(layers) != self.n_layers
+                or {m for m, _ in layers} - {"attention", "mamba", "conv"}
+                or {f for _, f in layers} - {"gelu", "swiglu", "moe"}):
             raise ValueError(
-                f"layer_types {kinds!r} does not name the mixers of "
-                f"{self.n_layers} layers"
+                f"layer_types {entries!r} does not name the mixers (and FFN "
+                f"kinds) of {self.n_layers} layers"
             )
-        return tuple(kinds)
+        return layers
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The mixer of every layer."""
+        return tuple(mixer for mixer, _ in self.layers)
+
+    @property
+    def ffn_kinds(self) -> Tuple[str, ...]:
+        """The FFN kind of every layer."""
+        return tuple(ffn for _, ffn in self.layers)
 
     @property
     def serves_from_kv_cache(self) -> bool:
@@ -130,6 +160,11 @@ class TransformerConfig:
         return MoEConfig(
             d_model=self.d_model, d_ff=self.d_expert,
             n_experts=self.n_experts, top_k=self.top_k,
+            aux_loss_weight=self.moe_loss_weights[0],
+            z_loss_weight=self.moe_loss_weights[1],
+            scoring=self.router_scoring, selection_bias=self.router_bias,
+            normalize_gates=self.norm_top_k, gate_scale=self.routed_scaling,
+            first_expert=self.first_expert, held_experts=self.experts_held,
             dtype=self.dtype, param_dtype=self.param_dtype,
         )
 
@@ -220,7 +255,10 @@ class MultiHeadAttention(nn.Module):
                 name="kv",
             )(x)
             k, v = kv[..., 0, :, :], kv[..., 1, :, :]
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            # Over each head's values, one weight of head_dim for all heads.
+            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
+        elif cfg.qk_norm in (True, "projection"):
             # Over the whole projection, before the split into heads.
             q = _norm(cfg, "q_norm")(
                 q.reshape(q.shape[:-2] + (-1,))
@@ -228,6 +266,8 @@ class MultiHeadAttention(nn.Module):
             k = _norm(cfg, "k_norm")(
                 k.reshape(k.shape[:-2] + (-1,))
             ).reshape(k.shape)
+        elif cfg.qk_norm:
+            raise ValueError(f"unknown qk_norm {cfg.qk_norm!r}")
         if cfg.positions == "rotary":
             if cache_mode == "step":
                 pos = cache_positions[:, None]
@@ -338,13 +378,15 @@ class MultiHeadAttention(nn.Module):
 
 class TransformerBlock(nn.Module):
     """Pre-norm block (trains stably in bf16 without warmup tricks). Norm,
-    positions, QK-norm, biases and the kind of FFN come from the
-    configuration, the mixer from the stack's per-layer pattern: BERT's
-    encoder block, a routed decoder block and both layers of a hybrid
-    state-space stack are the same code."""
+    positions, QK-norm and biases come from the configuration, the mixer
+    and the kind of FFN from the stack's per-layer pattern: BERT's encoder
+    block, a routed decoder block, both layers of a hybrid state-space
+    stack and the dense and routed layers of a short-convolution hybrid
+    are the same code."""
 
     cfg: TransformerConfig
-    mixer: str = "attention"         # attention | mamba
+    mixer: str = "attention"         # attention | mamba | conv
+    ffn: Optional[str] = None        # None = cfg.ffn
 
     @nn.compact
     def __call__(
@@ -373,6 +415,16 @@ class TransformerBlock(nn.Module):
             x = x + scaled(
                 Mamba2Mixer(cfg, name="mamba")(_norm(cfg, "ln_mamba")(x))
             )
+        elif self.mixer == "conv":
+            from raydp_tpu.models.shortconv import ShortConv
+
+            if cache_mode is not None:
+                raise NotImplementedError(
+                    "no decode cache for a short convolution's last tokens"
+                )
+            x = x + scaled(
+                ShortConv(cfg, name="conv")(_norm(cfg, "ln_conv")(x))
+            )
         else:
             x = x + scaled(MultiHeadAttention(cfg, name="attn")(
                 _norm(cfg, "ln_attn")(x),
@@ -386,7 +438,8 @@ class TransformerBlock(nn.Module):
             nn.Dense, use_bias=cfg.use_bias, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
         )
-        if cfg.ffn == "moe":
+        ffn = self.ffn or cfg.ffn
+        if ffn == "moe":
             from raydp_tpu.models.moe import MoELayer
 
             # The norm's output stays float32 for the router: one bf16
@@ -394,7 +447,7 @@ class TransformerBlock(nn.Module):
             # it in the compute dtype.
             y = _norm(cfg, "ln_mlp", jnp.float32)(x)
             y = MoELayer(cfg.moe_config(), name="moe")(y)
-        elif cfg.ffn == "gelu":
+        elif ffn == "gelu":
             y = _norm(cfg, "ln_mlp")(x)
             y = dense(
                 cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
@@ -405,7 +458,7 @@ class TransformerBlock(nn.Module):
                 cfg.d_model, kernel_init=_dense_init("mlp", "embed"),
                 name="mlp_down",
             )(y)
-        elif cfg.ffn == "swiglu":
+        elif ffn == "swiglu":
             # Dense gated MLP, one fused input projection: [gate, up].
             y = _norm(cfg, "ln_mlp")(x)
             gate, up = jnp.split(dense(
@@ -417,7 +470,7 @@ class TransformerBlock(nn.Module):
                 name="mlp_out",
             )(nn.silu(gate) * up)
         else:
-            raise ValueError(f"unknown ffn {cfg.ffn!r}")
+            raise ValueError(f"unknown ffn {ffn!r}")
         if cfg.dropout_rate > 0:
             y = Dropout(cfg.dropout_rate)(y, deterministic)
         x = x + scaled(y)
@@ -426,7 +479,7 @@ class TransformerBlock(nn.Module):
 
 class TransformerEncoder(nn.Module):
     """Token + position (+ optional segment) embeddings, N blocks (layer
-    i's mixer from ``cfg.layer_types``), final LN.
+    i's mixer and FFN kind from ``cfg.layer_types``), final LN.
 
     Input: int32 token ids [B, S] (+ optional segment ids). Output:
     [B, S, d_model] hidden states.
@@ -489,8 +542,8 @@ class TransformerEncoder(nn.Module):
             if cfg.remat
             else TransformerBlock
         )
-        for i, kind in enumerate(cfg.kinds):
-            x = block_cls(cfg, kind, name=f"block_{i}")(
+        for i, (mixer, ffn) in enumerate(cfg.layers):
+            x = block_cls(cfg, mixer, ffn, name=f"block_{i}")(
                 x,
                 deterministic,
                 cache_mode=cache_mode,
@@ -568,7 +621,8 @@ def _require_kv_cache(cfg: TransformerConfig) -> None:
     if not cfg.serves_from_kv_cache:
         raise NotImplementedError(
             "prefill/decode_step keep one K/V row a query head and layer; "
-            "a stack with state-space layers or grouped key-value heads "
+            "a stack with state-space or convolution layers or grouped "
+            "key-value heads "
             "needs a cache of its own (ROADMAP R4)"
         )
 
@@ -721,6 +775,38 @@ def granite_h_micro(**overrides) -> TransformerConfig:
         tie_head=True, embedding_multiplier=12.0, residual_multiplier=0.22,
         logits_scaling=8.0, ssm_heads=64, ssm_head_dim=64, ssm_state=128,
         ssm_groups=1, ssm_conv=4, ssm_chunk=256,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def lfm2_8b_a1b(**overrides) -> TransformerConfig:
+    """Liquid AI LFM2-8B-A1B (8.3B parameters, 1.5B active; ``config.json``
+    of LiquidAI/LFM2-8B-A1B, ``model_type`` lfm2_moe): 24 pre-norm layers
+    of width 2048, a gated 3-tap short convolution in 18 of them and
+    grouped-query attention (32 query / 8 key-value heads of 64, an RMSNorm
+    over each head's q and k, rotary positions at theta 1e6) in layers 2,
+    6, 10, 14, 18 and 21; a dense SwiGLU FFN of width 7168 in the first
+    two layers and 32 SwiGLU experts of width 1792 in the others, 4 a
+    token by sigmoid score + ``expert_bias``, their scores divided by
+    their sum; RMSNorm, no biases, no auxiliary loss; vocabulary 65536,
+    tied head. ``experts_held``/``first_expert`` give a layer the share of
+    an expert-parallel deployment; ``n_layers`` keeps the model's own first
+    layers."""
+    attention = (2, 6, 10, 14, 18, 21)
+    n_layers = overrides.get("n_layers", 24)
+    defaults = dict(
+        vocab_size=65536, d_model=2048, n_heads=32, n_kv_heads=8,
+        n_layers=n_layers, d_ff=7168, max_len=128000, dropout_rate=0.0,
+        causal=True, norm="rmsnorm", norm_eps=1e-5, positions="rotary",
+        rope_theta=1e6, qk_norm="head", use_bias=False, ffn="moe",
+        n_experts=32, top_k=4, d_expert=1792, router_scoring="sigmoid",
+        router_bias=True, norm_top_k=True, routed_scaling=1.0,
+        moe_loss_weights=(0.0, 0.0), conv_taps=3, tie_head=True,
+        layer_types=tuple(
+            ("attention" if i in attention else "conv")
+            + (":swiglu" if i < 2 else ":moe") for i in range(n_layers)
+        ),
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

@@ -28,7 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raydp_tpu import fault as _fault
 from raydp_tpu.data.ml_dataset import MLDataset
-from raydp_tpu.models import dropout, mamba
+from raydp_tpu.models import dropout, mamba, moe, shortconv
 from raydp_tpu.parallel.mesh import MeshSpec
 from raydp_tpu.telemetry import accounting as _acct
 from raydp_tpu.telemetry import events as _events
@@ -312,8 +312,6 @@ class JAXEstimator:
         sample = jnp.asarray(sample_x[:1])
         model, tx = self._model, self._tx
 
-        from raydp_tpu.models.moe import STATS as moe_stats
-
         def create():
             variables = model.init(rng, sample)
             # Output collections sown during init (MoE aux losses,
@@ -323,7 +321,7 @@ class JAXEstimator:
                 variables = {
                     k: v
                     for k, v in variables.items()
-                    if k not in ("losses", "intermediates", moe_stats)
+                    if k not in ("losses", "intermediates", moe.STATS)
                 }
             return TrainState.create(
                 apply_fn=model.apply, params=variables, tx=tx
@@ -376,8 +374,6 @@ class JAXEstimator:
             target = y if y is not None else x  # self-supervised: x IS y
             kwargs = apply_kwargs(rng)
             if use_aux:
-                from raydp_tpu.models import moe
-
                 preds, mut = state.apply_fn(
                     variables, x, mutable=["losses", moe.STATS], **kwargs
                 )
@@ -403,8 +399,15 @@ class JAXEstimator:
             with jax.named_scope("part:grad_norm"):
                 gnorm = optax.global_norm(grads)
             with jax.named_scope("part:update"):
-                state = state.apply_gradients(grads=grads)
-            return state, loss_val, gnorm, stats
+                new = state.apply_gradients(grads=grads)
+            if isinstance(state.params, dict) and moe.BUFFERS in state.params:
+                # What the model reads and no step may change (the
+                # router's selection bias): no gradient reaches it, and the
+                # optimizer's weight decay does not either.
+                new = new.replace(params={
+                    **new.params, moe.BUFFERS: state.params[moe.BUFFERS]
+                })
+            return new, loss_val, gnorm, stats
 
         choose = self._row_path()
         if choose is None:
@@ -477,6 +480,8 @@ class JAXEstimator:
             getattr(self._model, "cfg", None),
             tokens_per_step=int(np.prod(self._sample_batch.shape)),
         )
+        shortconv.report(getattr(self._model, "cfg", None))
+        moe.report(self._model)
 
         use_aux = self.aux_losses
 
@@ -945,9 +950,7 @@ class JAXEstimator:
                     loss_sum is not None
                 ) else 0.0
                 if stats_sum is not None:
-                    from raydp_tpu.models.moe import report_epoch
-
-                    report_epoch(jax.device_get(stats_sum), n_batches)
+                    moe.report_epoch(jax.device_get(stats_sum), n_batches)
             # Epoch boundary always checks (the sampled cadence may
             # never have landed on a NaN step in a short epoch).
             sentinel.check_loss(train_loss, b_idx, epoch=epoch)

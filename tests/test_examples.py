@@ -16,6 +16,7 @@ EXAMPLES = [
     "bert_glue.py",
     "olmoe_finetune.py",
     "granite_finetune.py",
+    "lfm2_finetune.py",
     "gbt_nyctaxi.py",
     "spmd_job.py",
     "pod_driver.py",

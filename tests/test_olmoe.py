@@ -468,3 +468,87 @@ def test_chunked_scan_compiles_for_the_chip_at_published_widths(one_chip):
     ).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 0.25e9, temp
+
+
+def test_a_share_of_the_routed_layer_compiles_at_lfm2s_widths(
+    one_chip, monkeypatch
+):
+    """8,192 tokens, 32 experts routed over by sigmoid score + bias, 4 a
+    token, the 8 experts of 2048 x 1792 that one chip of four holds: the
+    group sizes sum to fewer rows than the arrays have and Mosaic accepts
+    it; only the held experts' weights exist; no scatter moves the 32,768
+    (token, expert) rows in either direction."""
+    from raydp_tpu.models.moe import BUFFERS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = MoELayer(MoEConfig(
+        d_model=2048, d_ff=1792, n_experts=32, top_k=4, scoring="sigmoid",
+        selection_bias=True, normalize_gates=True, aux_loss_weight=0.0,
+        z_loss_weight=0.0, first_expert=0, held_experts=8,
+    ))
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: nn.unbox(layer.init(jax.random.PRNGKey(0), jnp.zeros(
+            x.shape, x.dtype)))
+    )
+    params, buffers = variables["params"], variables[BUFFERS]
+    assert params["w_gate"].shape == (8, 2048, 1792)
+    assert params["router"]["kernel"].shape == (2048, 32)
+    assert buffers["expert_bias"].shape == (32,)
+
+    def loss(p, x, b):
+        out, _ = layer.apply({"params": p, BUFFERS: b}, x, mutable=[STATS])
+        return jnp.mean(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        _on(one_chip, params), _on(one_chip, x), _on(one_chip, buffers)
+    ).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 9      # 3 products x 3 passes
+    assert all(rows < 8192 for rows in _scatter_update_rows(hlo))
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_grouped_flash_kernels_compile_at_lfm2s_sequence(one_chip):
+    """The first shape over 4,096 tokens: S = 8,192, 32 query heads over 8
+    key-value heads of 64, scale 1/8."""
+    from raydp_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True).astype(
+            jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_on(one_chip, (q, kv, kv))
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    # No S x S scores: one head's would be 268 MB in float32.
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_short_convolution_compiles_to_few_passes_at_lfm2s_widths(one_chip):
+    """[1, 8192, 2048]: both gates and the three shifted multiply-adds are
+    loop fusions between the two projections, forward and backward; no
+    convolution op, no [8192, 3 x 2048] float32 array is kept."""
+    from raydp_tpu.models.shortconv import ShortConv
+    from raydp_tpu.models.transformer import lfm2_8b_a1b
+
+    op = ShortConv(lfm2_8b_a1b(n_layers=1))
+    u = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: nn.unbox(op.init(jax.random.PRNGKey(0), jnp.zeros(
+            u.shape, u.dtype)))["params"]
+    )
+    assert params["in_proj"]["kernel"].shape == (2048, 6144)
+    assert params["conv"]["kernel"].shape == (3, 2048)
+
+    def loss(p, u):
+        return jnp.mean(op.apply({"params": p}, u).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        _on(one_chip, params), _on(one_chip, u)
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
