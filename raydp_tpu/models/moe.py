@@ -31,18 +31,28 @@ the published OLMoE block (Muennighoff et al. 2024, arXiv:2409.02060):
   are the only ones it has — sorts the pairs on absent experts behind the
   held ones, runs the grouped matmuls over the held rows alone (the group
   sizes sum to fewer than ``T·k`` rows and the kernel's grid ends there)
-  and returns the part of the sum its own experts give. No pair on a held
-  expert is dropped whatever the routing, so every array keeps ``T·k``
-  rows; what lies behind the held rows is never computed and is masked
-  where rows go back to tokens. On one chip the layer runs without its
+  and returns the part of the sum its own experts give. The held pairs
+  are the first places of the sorted order, so a share's expert path —
+  the row gather, the grouped matmuls, the SwiGLU, the way back to tokens
+  and all their cotangents — runs over the first ``C`` rows, not ``T·k``:
+  ``C`` is one and a half times the pairs uniform routing sends here,
+  ``1.5 · T·k · held / n_experts``, rounded up to the grouped matmul's row
+  tile and never more than ``T·k`` (:func:`compact_rows`; nothing sets
+  it). No pair on a held expert is dropped whatever the routing: a
+  layer-step whose held pairs exceed ``C`` takes the ``T·k``-row path
+  instead, forward and backward, inside a ``lax.cond`` that no other
+  layer-step enters (:func:`_hand_in`; counted in ``overflow``). A pair
+  sorted behind the ``C`` rows reads the last of them and is masked where
+  rows go back to tokens. On one chip the layer runs without its
   exchange: nothing stands in for the absent chips.
 * The load-balancing loss (``E · Σ_e f_e · p_e``, weight 0.01) and the
   router z-loss (``mean(logsumexp(logits)²)``, weight 0.001) are sown into
   the ``'losses'`` collection as ``moe_aux`` (nothing where both weights
   are 0); pull them with :func:`moe_aux_loss`. The tokens each expert
-  received (and, for a share, each held expert) are sown into
-  :data:`STATS`; ``JAXEstimator`` sums them over an epoch on the device
-  (:func:`step_stats`, :func:`report_epoch`).
+  received (and, for a share, each held expert, and whether the held
+  pairs exceeded ``C``) are sown into :data:`STATS`; ``JAXEstimator`` sums
+  them over an epoch on the device (:func:`step_stats`,
+  :func:`report_epoch`).
 """
 from __future__ import annotations
 
@@ -55,7 +65,11 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
-from raydp_tpu.ops.grouped_matmul import IMPLEMENTATION, grouped_matmul
+from raydp_tpu.ops.grouped_matmul import (
+    IMPLEMENTATION,
+    TILING,
+    grouped_matmul,
+)
 
 __all__ = [
     "MoEConfig",
@@ -150,7 +164,9 @@ def take_rows(x, perm, inverse, fan: int = 1, live=None):
     cotangent is ``g[inverse]`` summed over each row's ``fan`` copies — a
     gather, where ``jax.grad`` of the plain gather is a scatter-add.
     ``live`` ``[len(x), fan]`` (a share's layer) says which copies anyone
-    computed on: the cotangent of the others is not read."""
+    computed on: the cotangent of the others is not read. A share's
+    ``perm`` is the first ``C`` places of the permutation and ``inverse``
+    never points behind them: ``C`` rows come out, and ``g`` has ``C``."""
     return x[perm // fan] if fan > 1 else x[perm]
 
 
@@ -180,7 +196,9 @@ def combine_rows(rows, gate, order, inverse, live=None):
     array out of order: a pair's is its token's times its gate, read from
     the ``[T, D]`` cotangent in expert order. ``live`` ``[T, k]`` (a
     share's layer) says which pairs have a row that was computed: the
-    others add nothing and their gates get no cotangent."""
+    others add nothing and their gates get no cotangent. A share hands
+    ``rows`` ``[C, D]`` with the first ``C`` places of ``order`` and an
+    ``inverse`` that never points behind them."""
     t, k = gate.shape
     pairs = rows[inverse].reshape(t, k, rows.shape[-1])
     if live is not None:
@@ -212,6 +230,113 @@ def _combine_rows_bwd(res, g):
 
 
 combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def compact_rows(cfg: "MoEConfig", n_tokens: int) -> int:
+    """``C``, the rows the expert path of a layer over ``n_tokens`` tokens
+    runs over: all ``T·k`` where every expert is held; for a share one
+    and a half times the pairs uniform routing sends to its experts,
+    rounded up to the grouped matmul's row tile. One and a half: balanced
+    loads bring 25.0-26.1% of the pairs to a quarter of the experts and
+    the most lopsided routing a chip has shown 36.7% (PERF.md §6, PR 32);
+    37.5% holds both, and what it does not hold takes all the rows."""
+    pairs = n_tokens * cfg.top_k
+    if cfg.held == cfg.n_experts:
+        return pairs
+    rows = -(-3 * pairs * cfg.held // (2 * cfg.n_experts))
+    return min(pairs, -(-rows // TILING[0]) * TILING[0])
+
+
+def _experts(operands, routing, rows: Optional[int] = None):
+    """The expert path over the first ``rows`` places of the sorted pairs
+    (all ``T·k`` unless given): ``operands`` = tokens ``[T, D]``, gates
+    ``[T, k]`` and the three stacked weights, ``routing`` = ``order``,
+    ``inverse``, the held experts' ``group_sizes`` and ``live``. With fewer
+    rows than pairs every array between the two token-side gathers has
+    ``rows`` rows, and group sizes that sum to more are cut there."""
+    tokens, gate, w_gate, w_up, w_down = operands
+    order, inverse, group_sizes, live = routing
+    with jax.named_scope("permute"):
+        if rows is not None and rows < order.shape[0]:
+            order = order[:rows]
+            inverse = jnp.minimum(inverse, rows - 1)
+            ends = jnp.minimum(jnp.cumsum(group_sizes), rows)
+            group_sizes = jnp.diff(ends, prepend=0)
+        x = take_rows(tokens, order, inverse, gate.shape[1], live)
+    with jax.named_scope("experts"):
+        w_gate, w_up, w_down = (
+            w.astype(tokens.dtype) for w in (w_gate, w_up, w_down)
+        )
+        h = jax.nn.silu(
+            grouped_matmul(x, w_gate, group_sizes)
+        ) * grouped_matmul(x, w_up, group_sizes)
+        y = grouped_matmul(h, w_down, group_sizes)
+    with jax.named_scope("unpermute"):
+        return combine_rows(y, gate, order, inverse, live)
+
+
+# A share's guard. The compact path ``_experts(..., rows=C)`` stands in the
+# program as it would without a guard, under plain autodiff; around it:
+#
+#     operands, wire = _hand_in(operands, routing, overflow)
+#     out = _hand_out(_experts(operands, routing, C), wire, operands, ...)
+#
+# ``_hand_out`` gives the compact result unless the layer-step's held pairs
+# exceed ``C``: then, inside a ``lax.cond``, the ``T·k``-row path's. Its
+# cotangent goes to the compact path and, over ``wire`` (zeros nobody
+# reads, there to carry it), to ``_hand_in``'s backward, which by then also
+# holds the compact path's five cotangents and hands them on through a
+# second ``cond`` — or, on overflow, the ``T·k``-row path's in their place.
+# A branch not taken computes nothing and writes nothing: no residual and
+# no zero gradient of a ``T·k``-row array or of a stacked weight exists for
+# the sake of the guard.
+
+@jax.custom_vjp
+def _hand_in(operands, routing, overflow):
+    return operands, jnp.zeros_like(operands[0])
+
+
+def _hand_in_fwd(operands, routing, overflow):
+    return _hand_in(operands, routing, overflow), (
+        operands, routing, overflow
+    )
+
+
+def _hand_in_bwd(res, cotangents):
+    operands, routing, overflow = res
+    compact, g = cotangents
+
+    def whole(_):
+        _, pull = jax.vjp(lambda *o: _experts(o, routing), *operands)
+        return pull(g)
+
+    # Between barriers, or XLA moves the compact path's last ops and the
+    # gradients' first users (their casts, so the optimizer's fusions read
+    # float32) into the branches.
+    compact = jax.lax.optimization_barrier(compact)
+    grads = jax.lax.cond(overflow, whole, lambda c: c, compact)
+    return jax.lax.optimization_barrier(grads), None, None
+
+
+_hand_in.defvjp(_hand_in_fwd, _hand_in_bwd)
+
+
+@jax.custom_vjp
+def _hand_out(out, wire, operands, routing, overflow):
+    return jax.lax.optimization_barrier(jax.lax.cond(
+        overflow, lambda: _experts(operands, routing), lambda: out
+    ))
+
+
+def _hand_out_fwd(out, wire, operands, routing, overflow):
+    return _hand_out(out, wire, operands, routing, overflow), None
+
+
+def _hand_out_bwd(_, g):
+    return g, g, None, None, None
+
+
+_hand_out.defvjp(_hand_out_fwd, _hand_out_bwd)
 
 
 @functools.lru_cache(maxsize=None)
@@ -340,20 +465,33 @@ class MoELayer(nn.Module):
                 live = live.reshape(n_tokens, k)
             _, order = jax.lax.sort_key_val(key, pairs)
             _, inverse = jax.lax.sort_key_val(order, pairs)
-            group_sizes = counts.astype(jnp.int32)
-            rows = take_rows(
-                tokens.astype(cfg.dtype), order, inverse, k, live
+            routing = (order, inverse, counts.astype(jnp.int32), live)
+            operands = (tokens.astype(cfg.dtype), gate, w_gate, w_up, w_down)
+        # Nothing reads what ``init`` computes: no guard to compile there.
+        rows = n_tokens * k if self.is_initializing() else (
+            compact_rows(cfg, n_tokens)
+        )
+        if rows == n_tokens * k:
+            out = _experts(operands, routing)
+        else:
+            with jax.named_scope("permute"):
+                overflow = counts.sum() > rows
+                self.sow(
+                    STATS, "overflow", overflow.astype(jnp.float32),
+                    reduce_fn=_add,
+                    init_fn=lambda: jnp.zeros((), jnp.float32),
+                )
+            with jax.named_scope("experts"):
+                # In the compute dtype before the guard, so that what the
+                # guard hands on is the kernels' own weight gradient.
+                operands = operands[:2] + tuple(
+                    w.astype(cfg.dtype) for w in operands[2:]
+                )
+            operands, wire = _hand_in(operands, routing, overflow)
+            out = _hand_out(
+                _experts(operands, routing, rows), wire, operands, routing,
+                overflow,
             )
-        with jax.named_scope("experts"):
-            w_gate, w_up, w_down = (
-                w.astype(cfg.dtype) for w in (w_gate, w_up, w_down)
-            )
-            h = jax.nn.silu(
-                grouped_matmul(rows, w_gate, group_sizes)
-            ) * grouped_matmul(rows, w_up, group_sizes)
-            rows = grouped_matmul(h, w_down, group_sizes)
-        with jax.named_scope("unpermute"):
-            out = combine_rows(rows, gate, order, inverse, live)
         return out.reshape(*lead_shape, d)
 
 
@@ -418,8 +556,12 @@ def report_epoch(stats: dict, n_steps: int) -> None:
     epoch's loss: the mean auxiliary loss a step, the (token, expert) pairs
     a step routes and how many of them landed on experts held here (all of
     them unless the layers are a share: a quarter at uniform routing over
-    four shares), and the fullest held expert's tokens over the mean held
-    expert's."""
+    four shares), the fullest held expert's tokens over the mean held
+    expert's, and ``moe/overflow_layer_steps``: the layer-steps of the
+    epoch whose held pairs exceeded :func:`compact_rows` and took all
+    ``T·k`` rows inside the guard (0 where loads are balanced, and always
+    where every expert is held; a reader who sees it rise knows why the
+    step slowed)."""
     import numpy as np
 
     from raydp_tpu.utils.profiling import metrics
@@ -431,27 +573,37 @@ def report_epoch(stats: dict, n_steps: int) -> None:
     metrics.gauge_set("moe/expert_tokens_per_step", tokens.sum() / n_steps)
     metrics.gauge_set("moe/held_pairs_per_step", held.sum() / n_steps)
     metrics.gauge_set("moe/held_pair_share", held.sum() / tokens.sum())
+    metrics.gauge_set(
+        "moe/overflow_layer_steps", float(stats.get("overflow", 0.0))
+    )
 
 
-def report(model) -> None:
-    """Static for a compiled step: two gauges where the step is built (as
-    ``models/mamba.report``): the experts the routed layers route over and
-    how many of them this process holds. Zero for a model without one."""
+def report(model, tokens_per_step: int) -> None:
+    """Static for a compiled step: three gauges where the step is built
+    (as ``models/mamba.report``): the experts the routed layers route over,
+    how many of them this process holds, and ``moe/compact_rows``, the
+    rows a layer's expert path runs over (:func:`compact_rows`: all
+    ``T·k`` pairs of the step's tokens unless the layers are a share).
+    Zero for a model without a routed layer."""
     from raydp_tpu.utils.profiling import metrics
 
     cfg, moe = getattr(model, "cfg", None), getattr(model, "moe", None)
     if moe is None and "moe" in getattr(cfg, "ffn_kinds", ()):
         moe = cfg.moe_config()
     routed, held = (moe.n_experts, moe.held) if moe is not None else (0, 0)
+    rows = compact_rows(moe, tokens_per_step) if moe is not None else 0
     metrics.gauge_set("moe/experts_routed", routed)
     metrics.gauge_set("moe/experts_held", held)
+    metrics.gauge_set("moe/compact_rows", rows)
     if held < routed:
         logger.info(
             "routed layers: a share of an expert-parallel deployment, "
             "experts [%d, %d) of %d held here, top-%d over all %d; pairs on "
             "absent experts cost no matmul row and nothing stands in for "
-            "their exchange", moe.first_expert, moe.first_expert + held,
-            routed, moe.top_k, routed,
+            "their exchange; the expert path runs over %d of a step's %d "
+            "pairs, and a layer-step with more on held experts over all of "
+            "them", moe.first_expert, moe.first_expert + held,
+            routed, moe.top_k, routed, rows, tokens_per_step * moe.top_k,
         )
 
 

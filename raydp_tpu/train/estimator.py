@@ -476,12 +476,12 @@ class JAXEstimator:
             if self._model_takes_deterministic() else (0, 0)
         )
         dropout.report(sites, words)
+        tokens_per_step = int(np.prod(self._sample_batch.shape))
         mamba.report(
-            getattr(self._model, "cfg", None),
-            tokens_per_step=int(np.prod(self._sample_batch.shape)),
+            getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
         )
         shortconv.report(getattr(self._model, "cfg", None))
-        moe.report(self._model)
+        moe.report(self._model, tokens_per_step=tokens_per_step)
 
         use_aux = self.aux_losses
 

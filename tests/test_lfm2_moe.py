@@ -10,7 +10,9 @@ selection bias that training leaves as it was, the old families untouched,
 and no serving from a K/V cache the stack does not have."""
 import dataclasses
 import importlib.util
+import json
 import os
+import re
 
 import flax.linen as nn
 import jax
@@ -363,6 +365,149 @@ def test_an_empty_and_a_full_share_lose_nothing(uncut, case):
         np.testing.assert_allclose(np.asarray(out), want, rtol=2e-4, atol=2e-5)
 
 
+def _share_and_grads(variables, x, first=4):
+    """A share's part, what it sowed, and the gradients of a weighted sum
+    of it with respect to its input, its router and its three weights."""
+    layer = _layer(first, 2)
+
+    def part(v, x):
+        out, sown = layer.apply(v, x, mutable=[moe_module.STATS])
+        return jnp.sum(
+            out * jnp.cos(jnp.arange(out.size).reshape(out.shape))
+        ), (out, sown[moe_module.STATS])
+
+    (d_v, d_x), (out, stats) = jax.grad(
+        part, argnums=(0, 1), has_aux=True)(variables, x)
+    p = d_v["params"]
+    return out, stats, (
+        d_x, p["router"]["kernel"], p["w_gate"], p["w_up"], p["w_down"])
+
+
+@pytest.mark.parametrize("routing,rows", [
+    ("as_drawn", "below"), ("as_drawn", "at"), ("as_drawn", "above"),
+    ("every_pair", "below"), ("every_pair", "tile"), ("no_pair", "tile"),
+])
+def test_a_compact_share_is_the_whole_share(uncut, monkeypatch, routing, rows):
+    """The expert path over ``C`` rows against the same path over all
+    ``T·k`` (what the layer was before it had a ``C``): output and every
+    gradient equal, with ``C`` below, at and above the pairs that land on
+    the share's experts. Below, the layer-step takes all the rows inside
+    the guard and says so; nothing is dropped in any case."""
+    from raydp_tpu.utils.profiling import metrics
+
+    _, variables, x = uncut
+    first, pairs = 4, 3 * 7 * 2
+    if routing != "as_drawn":
+        bias = jnp.zeros((8,)).at[first:first + 2].set(
+            10.0 if routing == "every_pair" else -10.0)
+        variables = dict(
+            variables, **{moe_module.BUFFERS: {"expert_bias": bias}})
+    mine = _held_by(variables, first)
+    assert moe_module.compact_rows(_layer(first, 2).cfg, 3 * 7) == pairs
+    want_out, stats, want = _share_and_grads(mine, x)
+    assert "overflow" not in stats              # C = T·k: no guard
+    held = int(stats["held_tokens"].sum())
+    if routing == "as_drawn":
+        assert 2 < held < pairs - 3
+    else:
+        assert held == (pairs if routing == "every_pair" else 0)
+    c = {"below": held - 2, "at": held, "above": held + 3, "tile": 8}[rows]
+    monkeypatch.setattr(moe_module, "compact_rows", lambda cfg, n: c)
+    got_out, stats, got = _share_and_grads(mine, x)
+    assert float(stats["overflow"]) == (held > c)
+    assert float(jnp.abs(want_out).max()) > 0 or routing == "no_pair"
+    np.testing.assert_allclose(
+        np.asarray(got_out), np.asarray(want_out), rtol=1e-6, atol=1e-6)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-6,
+            atol=1e-6 * max(1.0, float(jnp.abs(b).max())))
+    if routing == "no_pair":
+        np.testing.assert_array_equal(np.asarray(got_out), 0.0)
+    if routing == "every_pair":
+        whole = _layer().apply(variables, x, mutable=[moe_module.STATS])[0]
+        np.testing.assert_allclose(
+            np.asarray(got_out), np.asarray(whole), rtol=2e-4, atol=2e-5)
+    if held:                    # what an epoch of one such step reports
+        moe_module.report_epoch(
+            {"aux_loss": 0.0, **jax.device_get(stats)}, n_steps=1)
+        assert metrics.gauge_value("moe/overflow_layer_steps") == (held > c)
+
+
+def test_compact_rows_follow_the_share_and_the_row_tile():
+    """One and a half times the pairs uniform routing sends to the held
+    experts, in whole row tiles of the grouped matmul, never above
+    ``T·k``; all of them where every expert is held."""
+    cell = lfm2_8b_a1b(n_layers=7, experts_held=8).moe_config()
+    assert (cell.n_experts, cell.top_k, cell.held) == (32, 4, 8)
+    assert moe_module.compact_rows(cell, 8192) == 12288
+    assert moe_module.compact_rows(cell, 1000) == 1536      # 1,500 -> 3 tiles
+    assert moe_module.compact_rows(cell, 100) == 400        # T·k
+    half = MoEConfig(n_experts=8, top_k=2, held_experts=4)
+    assert moe_module.compact_rows(half, 4096) == 6144      # 0.75 T·k
+    assert moe_module.compact_rows(
+        MoEConfig(n_experts=8, top_k=2, held_experts=6), 4096) == 8192
+    assert moe_module.compact_rows(olmoe().moe_config(), 8192) == 65536
+
+
+def test_the_compact_path_keeps_its_place_in_the_part_rules(
+    tiny, monkeypatch
+):
+    """The benchmark splits a step by op name (``benchmark/parts/
+    lfm2_moe_lm.json``, first match wins) and wants ``moe/permute``,
+    ``moe/unpermute`` and ``moe/experts/jit(gmm)`` ADJACENT: JAX writes
+    ``cond/branch_N_fun`` into the name of what a branch holds, so the
+    compact path — every gather and kernel a step runs — stands outside
+    the guard, and only the guard's rows-for-all remainder carries it."""
+    with open(os.path.join(REPO, "benchmark", "parts", "lfm2_moe_lm.json")) as f:
+        rules = [(re.compile(pattern), part) for pattern, part in json.load(f)]
+
+    def part_of(name):
+        return next((p for rule, p in rules if rule.search(name)), "rest")
+
+    model, variables, ids = tiny
+    pairs = ids.size * SIZES["num_experts_per_tok"]
+    monkeypatch.setattr(moe_module, "compact_rows", lambda cfg, n: pairs // 2)
+    text = jax.jit(jax.grad(
+        lambda v: lm_crossentropy(_logits(model, v, ids), ids)
+    )).lower(variables).as_text(debug_info=True)
+    # Whole op names. A kernel is ``pallas_call`` inside its ``jit(gmm)``
+    # or ``jit(tgmm)``, which the text names where it is called.
+    names = set(re.findall(r'loc\("(jit\([^"]+)"', text))
+    assert 'loc("pallas_call"' in text
+    names |= {n + "/pallas_call" for n in names
+              if re.search(r"/jit\(t?gmm\)$", n)}
+    routed = {n for n in names if re.search(r"/block_\d+/moe/", n)}
+    guard = {n for n in routed if "/moe/cond/branch_" in n}
+    path = routed - guard
+    assert not [n for n in names - guard if "cond/branch_" in n]
+    # The guard holds a whole expert path of its own, forward and backward,
+    # and none of it counts as a row move or a kernel of the step.
+    for op in ("gather", "jit(gmm)/pallas_call", "jit(tgmm)/pallas_call"):
+        assert [n for n in guard if n.endswith(op)], op
+    assert {part_of(n) for n in guard} == {"moe_rest"}
+    # The compact path: kernels and gathers where rules 6 and 7 look.
+    kernels = {n for n in path if n.endswith("/pallas_call")}
+    assert kernels and {part_of(n) for n in kernels} == {"moe_gmm"}
+    gathers = {n for n in path if n.endswith("/gather")}
+    assert {part_of(n) for n in gathers} == {"moe_permute"}
+    # The forward, its second run (the blocks are checkpointed; the way
+    # back to tokens is not needed again) and the backward.
+    forward, again, backward = (
+        "/encoder/block_", "/rematted_computation/block_", "/checkpoint/block_")
+    for found, op, passes in (
+        (kernels, "jit(gmm)", (forward, again, backward)),
+        (kernels, "jit(tgmm)", (backward,)),
+        (gathers, "/moe/permute/", (forward, again, backward)),
+        (gathers, "/moe/unpermute/", (forward, backward)),
+    ):
+        assert {p for p in (forward, again, backward)
+                if [n for n in found if op in n and p in n]} == set(passes), op
+    assert {part_of(n) for n in path} == {
+        "moe_permute", "moe_gmm", "moe_rest"}
+
+
 def test_the_selection_bias_is_drawn_to_balance_the_init_sample():
     """A router of random weights on a Zipf-distributed corpus: without a
     bias the fullest expert gets several times the emptiest's tokens;
@@ -539,6 +684,9 @@ def test_fit_trains_and_leaves_the_selection_bias_as_drawn(builder, tiny):
     assert metrics.gauge_value("conv/taps") == 3
     assert metrics.gauge_value("moe/experts_routed") == 8
     assert metrics.gauge_value("moe/experts_held") == 2
+    # 1.5 x 512 x 2 / 8 = 192 rows, in row tiles of 512: all 512 pairs.
+    assert metrics.gauge_value("moe/compact_rows") == 8 * SEQ * 2
+    assert metrics.gauge_value("moe/overflow_layer_steps") == 0
     assert metrics.gauge_value("ssm/layers") == 0
     pairs = metrics.gauge_value("moe/expert_tokens_per_step")
     assert pairs == 3 * 8 * SEQ * 2

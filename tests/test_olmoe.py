@@ -22,6 +22,7 @@ from raydp_tpu.models.moe import (
     MoEConfig,
     MoELayer,
     combine_rows,
+    compact_rows,
     moe_aux_loss,
     take_rows,
 )
@@ -243,6 +244,46 @@ def test_compiled_step_has_no_scatter_over_the_token_expert_pairs(tiny):
     pairs = ids.size * SIZES["num_experts_per_tok"]
     assert pairs not in _scatter_update_rows(hlo)
     assert all(rows <= ids.size for rows in _scatter_update_rows(hlo))
+
+
+def _eqns(jaxpr, into="pallas_call"):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, down to
+    but not into a kernel's body."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == into:
+            continue
+        for sub in jax.tree_util.tree_leaves(
+            list(eqn.params.values()),
+            is_leaf=lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr"),
+        ):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield from _eqns(sub, into)
+
+
+def test_a_layer_that_holds_every_expert_has_no_guard_and_all_its_rows(tiny):
+    """Only a share runs its experts over fewer rows than ``T·k`` behind a
+    guard (``models/moe.compact_rows``): with all 8 experts held the step
+    has no ``cond``, and every kernel and row gather of the routed layers
+    sees all 2 x 32 x 2 = 128 pairs, as before there was a guard."""
+    model, variables, ids = tiny
+    pairs = ids.size * SIZES["num_experts_per_tok"]
+    assert compact_rows(model.cfg.moe_config(), ids.size) == pairs
+    eqns = list(_eqns(jax.make_jaxpr(jax.grad(
+        lambda v: _program_loss(model, v, ids)))(variables).jaxpr))
+    assert not [e for e in eqns if e.primitive.name == "cond"]
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 2 * 9                  # 3 products x 3 passes
+    assert all(
+        any(pairs in v.aval.shape for v in e.invars + e.outvars)
+        for e in kernels
+    )
+    # Rows of the hidden width that a gather moves: two each way a layer.
+    moved = [e.outvars[0].aval.shape[0] for e in eqns
+             if e.primitive.name == "gather"
+             and e.outvars[0].aval.shape[1:] == (SIZES["hidden_size"],)]
+    assert moved == [pairs] * (2 * 4), moved
 
 
 # ------------------------------------- the block's pieces, three lines each
@@ -507,6 +548,13 @@ def test_a_share_of_the_routed_layer_compiles_at_lfm2s_widths(
     assert hlo.count("tpu_custom_call") >= 9      # 3 products x 3 passes
     assert all(rows < 8192 for rows in _scatter_update_rows(hlo))
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    # The kernels a step runs see 1.5 x 8,192 rows; all 32,768 only inside
+    # the guard's branches (the layer-step whose held pairs exceed them).
+    assert compact_rows(layer.cfg, 8192) == 12288
+    kernels = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    path = [k for k in kernels if "/cond/branch_" not in k]
+    assert len(path) == 9 and len(kernels) > len(path)
+    assert all("[12288," in k and "[32768," not in k for k in path)
 
 
 def test_grouped_flash_kernels_compile_at_lfm2s_sequence(one_chip):
