@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, List, Optional
 import cloudpickle
 import pyarrow as pa
 
+from raydp_tpu.cluster.cluster import call_envelope, task_stamps
 from raydp_tpu.cluster.master import SERVICE, WorkerInfo
 from raydp_tpu.cluster.rpc import RpcClient, RpcError
 from raydp_tpu.store.object_store import OWNER_HOLDER, ObjectRef
@@ -487,12 +488,16 @@ class RemoteCluster:
                     target = workers[(rr + attempt) % len(workers)]
                 client = self._worker_client(target)
                 try:
-                    reply = client.call("RunTask", payload, timeout=timeout)
+                    reply, envelope = call_envelope(
+                        client, "RunTask", payload, timeout,
+                        target.worker_id, 1,
+                    )
                     if meta_sink is not None:
                         try:
                             meta_sink(
                                 0, target.worker_id,
                                 reply.get("exec_s", 0.0),
+                                task_stamps(envelope, reply),
                             )
                         except Exception:
                             pass
@@ -529,8 +534,8 @@ class RemoteCluster:
                      meta_sink: Optional[Callable] = None) -> List[Future]:
         """Client-mode twin of ``Cluster.submit_batch``: one RunTaskBatch
         envelope per worker, one Future per spec (in order).
-        ``meta_sink(spec_index, worker_id, exec_s)`` fires before the
-        matching future resolves, mirroring the in-process Cluster."""
+        ``meta_sink(spec_index, worker_id, exec_s, stamps)`` fires before
+        the matching future resolves, mirroring the in-process Cluster."""
         futures: List[Future] = [Future() for _ in specs]
         if not specs:
             return futures
@@ -554,6 +559,16 @@ class RemoteCluster:
 
     def _run_batch(self, specs, futures, timeout, retries, meta_sink=None):
         import grpc
+
+        from raydp_tpu.telemetry import propagation as _prop
+
+        # An envelope's own thread does not inherit this one's trace
+        # context: hand it on (as Cluster._run_batch does).
+        trace_ctx = _prop.current_context()
+
+        def in_trace(wid, idxs) -> None:
+            with _prop.propagated(trace_ctx):
+                call_group(wid, idxs)
 
         staged = [self._stage_data_args(s.data_args) for s in specs]
         try:
@@ -592,10 +607,10 @@ class RemoteCluster:
                             if staged[i]:
                                 task["data_refs"] = staged[i]
                             tasks.append(task)
-                        reply = client.call(
-                            "RunTaskBatch",
+                        reply, envelope = call_envelope(
+                            client, "RunTaskBatch",
                             {"fns": fn_blobs, "tasks": tasks},
-                            timeout=timeout,
+                            timeout, wid, len(tasks),
                         )
                         # Per-envelope streaming: resolve this worker's
                         # futures the moment IT replies, not after the
@@ -605,7 +620,8 @@ class RemoteCluster:
                                 if meta_sink is not None:
                                     try:
                                         meta_sink(
-                                            i, wid, res.get("exec_s", 0.0)
+                                            i, wid, res.get("exec_s", 0.0),
+                                            task_stamps(envelope, res),
                                         )
                                     except Exception:
                                         pass
@@ -636,7 +652,7 @@ class RemoteCluster:
                         results[wid] = exc
 
                 threads = [
-                    threading.Thread(target=call_group, args=(wid, idxs),
+                    threading.Thread(target=in_trace, args=(wid, idxs),
                                      daemon=True)
                     for wid, idxs in groups.items()
                 ]

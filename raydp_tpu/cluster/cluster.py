@@ -34,6 +34,7 @@ from raydp_tpu.cluster.master import AppMaster, WorkerInfo
 from raydp_tpu.cluster.rpc import RpcClient, RpcError
 from raydp_tpu.config import ClusterConfig
 from raydp_tpu.store.object_store import DEFAULT_NODE
+from raydp_tpu.telemetry import span
 
 logger = logging.getLogger(__name__)
 
@@ -71,6 +72,47 @@ class _WorkerGone(Exception):
 #: Sentinel outcome: the envelope thread already resolved its futures
 #: inline (per-envelope streaming) — nothing left for the joiner to do.
 _BATCH_RESOLVED = object()
+
+#: Process-wide envelope numbers: the ``env`` attr of a ``stage/envelope``
+#: span, and the key under which the stage's ``stage/close`` span lists
+#: that envelope's worker-side stamps.
+_ENVELOPE_SEQ = itertools.count(1)
+
+
+def call_envelope(client: RpcClient, method: str, payload: dict,
+                  timeout: float, worker_id: str, tasks: int):
+    """One task envelope (``RunTask`` / ``RunTaskBatch``) to one worker,
+    inside a ``stage/envelope`` span around the ``client.call`` alone, on
+    the calling thread (through the span bridge it sits on the device
+    trace's clock). Returns ``(reply, envelope)``: ``envelope`` holds the
+    driver's ``send``/``reply`` stamps (``perf_counter``), the worker's
+    own ``recv``/``ret`` (its ``perf_counter``: only differences are
+    meaningful off this host) and the envelope's number ``env`` — what
+    ``meta_sink`` carries per task, with the task's ``start``/``end``
+    added. ``None`` from a worker whose reply has no stamps."""
+    env = next(_ENVELOPE_SEQ)
+    with span("stage/envelope", worker=worker_id, tasks=tasks,
+              env=env) as sp:
+        reply = client.call(method, payload, timeout=timeout)
+        got = time.perf_counter()
+        if "recv" not in reply:
+            return reply, None
+        envelope = {
+            "env": env, "send": sp.start_mono, "reply": got,
+            "recv": reply["recv"], "ret": reply["ret"],
+        }
+        # The recorder's copy of the span gets the worker's interval at
+        # exit (the profiler's annotation took its attrs at entry).
+        sp.attrs["worker_us"] = round((reply["ret"] - reply["recv"]) * 1e6)
+    return reply, envelope
+
+
+def task_stamps(envelope: Optional[dict], res: dict) -> Optional[dict]:
+    """``envelope`` with one task's body ``start``/``end`` (the worker's
+    ``perf_counter``): the fourth argument of a ``meta_sink``."""
+    if envelope is None or "start" not in res:
+        return None
+    return dict(envelope, start=res["start"], end=res["end"])
 
 
 class Cluster:
@@ -883,10 +925,13 @@ class Cluster:
                     last = ClusterError(f"worker {target} is gone")
                     continue
                 try:
-                    reply = client.call("RunTask", payload, timeout=timeout)
+                    reply, envelope = call_envelope(
+                        client, "RunTask", payload, timeout, target, 1
+                    )
                     if meta_sink is not None:
                         try:
-                            meta_sink(0, target, reply.get("exec_s", 0.0))
+                            meta_sink(0, target, reply.get("exec_s", 0.0),
+                                      task_stamps(envelope, reply))
                         except Exception:
                             pass  # stats sink must never fail the task
                     return reply["result"]
@@ -977,10 +1022,11 @@ class Cluster:
         reassigned to surviving workers (stage tasks are idempotent),
         up to ``retries`` rounds.
 
-        ``meta_sink(spec_index, worker_id, exec_s)`` — optional per-task
-        completion callback carrying the executing worker and its
-        measured task seconds (stage-stats attribution); invoked before
-        the matching future resolves.
+        ``meta_sink(spec_index, worker_id, exec_s, stamps)`` — optional
+        per-task completion callback carrying the executing worker, its
+        measured task seconds and the envelope's and the task's stamps
+        (:func:`call_envelope`, :func:`task_stamps`; stage-stats
+        attribution); invoked before the matching future resolves.
         """
         futures: List[Future] = [Future() for _ in specs]
         if not specs:
@@ -1013,6 +1059,19 @@ class Cluster:
         retries: int,
         meta_sink: Optional[Callable] = None,
     ) -> None:
+        from raydp_tpu.telemetry import accounting as _acct
+        from raydp_tpu.telemetry import propagation as _prop
+
+        # Each envelope gets a thread of its own, which inherits neither
+        # the trace context nor the job this one runs under: hand both
+        # on, so ``stage/envelope`` (and through it the worker's spans)
+        # parents under the submitting stage and bills its job.
+        trace_ctx, job_ctx = _prop.current_context(), _acct.current_job()
+
+        def envelope(*args) -> None:
+            with _prop.propagated(trace_ctx), _acct.job_scope(job_ctx):
+                self._call_batch_into(*args)
+
         staged = [self._stage_data_args(s.data_args) for s in specs]
         try:
             pending = list(range(len(specs)))
@@ -1035,7 +1094,7 @@ class Cluster:
                 threads = []
                 for wid, idxs in groups.items():
                     t = threading.Thread(
-                        target=self._call_batch_into,
+                        target=envelope,
                         args=(results, wid, idxs, specs, staged, timeout,
                               futures, meta_sink),
                         name=f"raydp-batch-{wid}",
@@ -1139,7 +1198,10 @@ class Cluster:
                 tasks.append(task)
             payload = {"fns": fn_blobs, "tasks": tasks}
             try:
-                reply = client.call("RunTaskBatch", payload, timeout=timeout)
+                reply, envelope = call_envelope(
+                    client, "RunTaskBatch", payload, timeout, worker_id,
+                    len(tasks),
+                )
             except grpc.RpcError as exc:
                 code = exc.code()
                 if self._elastic_stop.is_set():
@@ -1173,7 +1235,8 @@ class Cluster:
                 if res.get("ok"):
                     if meta_sink is not None:
                         try:
-                            meta_sink(i, worker_id, res.get("exec_s", 0.0))
+                            meta_sink(i, worker_id, res.get("exec_s", 0.0),
+                                      task_stamps(envelope, res))
                         except Exception:
                             pass  # sink must never fail the batch
                     futures[i].set_result(res.get("value"))

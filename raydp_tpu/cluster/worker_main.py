@@ -232,6 +232,11 @@ class Worker:
         return reply
 
     def _execute_task(self, req: dict) -> dict:
+        # The worker's own stamps (``perf_counter``): handler entered,
+        # body start and end, handler about to return. They ride the
+        # reply beside ``exec_s`` so the driver partitions a stage's
+        # wall from inside (``_StageRecorder``) with no extra RPC.
+        recv = time.perf_counter()
         # Busy goes up FIRST: between this handler starting and fn
         # deserializing, the heartbeat thread must already see the task
         # — an exit decision in that setup window would cancel it.
@@ -242,46 +247,57 @@ class Worker:
                 raise RuntimeError(
                     "worker context not ready (registration hung)"
                 )
-            fn = cloudpickle.loads(req["fn"])
-            args = req.get("args", ())
-            kwargs = req.get("kwargs", {})
-            # data_args travel the data plane: the envelope carries refs,
-            # the tables are resolved here (zero-copy from local shm when
-            # co-located with the submitter, chunked agent fetch if not).
-            data = self._resolve_data_refs(req.get("data_refs", ()))
+            with span("worker/task_load", worker_id=self.worker_id):
+                fn = cloudpickle.loads(req["fn"])
+                args = req.get("args", ())
+                kwargs = req.get("kwargs", {})
+                # data_args travel the data plane: the envelope carries
+                # refs, the tables are resolved here (zero-copy from
+                # local shm when co-located with the submitter, chunked
+                # agent fetch if not).
+                data = self._resolve_data_refs(req.get("data_refs", ()))
             self._fault_task_hook()
             metrics.counter_add("worker/tasks")
             _flight.record("task", "start", worker_id=self.worker_id)
             # RpcServer already installed the caller's traceparent as
             # this handler thread's ambient context, so this span — and
             # any span the task body opens — lands in the driver's
-            # job trace, under the submitting stage span. The inflight
+            # job trace, under the submitting envelope span. The inflight
             # bracket is the watchdog's stall signal: a wedged task
             # body shows up as component "worker/task" — at the long-op
             # threshold, since a healthy task may run for minutes.
-            t0 = time.perf_counter()
+            start = time.perf_counter()
             with _watchdog.inflight(
                 "worker/task", worker_id=self.worker_id,
                 stall_after_s=_watchdog.long_stall_s(),
             ):
                 with span("worker/task", worker_id=self.worker_id):
-                    with metrics.timer("worker/task").time():
-                        result = fn(self.ctx, *args, *data, **kwargs)
+                    result = fn(self.ctx, *args, *data, **kwargs)
+            end = time.perf_counter()
             _flight.record("task", "end", worker_id=self.worker_id)
-            exec_s = time.perf_counter() - t0
-            # RpcServer._wrap installed the caller's job scope, so
-            # host-CPU task seconds bill to the job that submitted the
-            # task, not to this worker's own identity.
-            _acct.add_usage(_acct.TASK_SECONDS, exec_s)
-            # exec_s lets the driver split stage wall into queue vs
-            # execution (stage-stats attribution) with no extra RPC.
-            return {"result": result, "exec_s": exec_s}
+            exec_s = self._task_ran(start, end)
+            return {
+                "result": result, "exec_s": exec_s,
+                "start": start, "end": end,
+                "recv": recv, "ret": time.perf_counter(),
+            }
         except Exception:
             # Let RpcServer._wrap serialize the failure uniformly.
             raise
         finally:
             with self._busy_lock:
                 self._busy -= 1
+
+    def _task_ran(self, start: float, end: float) -> float:
+        """One body interval, measured once: it bills the submitting
+        job (RpcServer._wrap installed the caller's job scope, so
+        host-CPU task seconds go to the job that sent the task, not to
+        this worker's own identity) and feeds the ``worker/task`` timer
+        the master's straggler attribution reads."""
+        exec_s = end - start
+        _acct.add_usage(_acct.TASK_SECONDS, exec_s)
+        metrics.timer("worker/task").observe(exec_s)
+        return exec_s
 
     def _resolve_data_refs(self, refs):
         return [self.ctx.get_table(r) for r in refs]
@@ -311,8 +327,11 @@ class Worker:
         Each distinct fn arrives once in ``fns``; tasks reference it by
         slot. Tasks run concurrently on the worker task pool and each
         reports per-task ``{"ok": ...}`` so one bad partition fails only
-        its own future, not its siblings in the envelope.
+        its own future, not its siblings in the envelope. The reply
+        carries the envelope's ``recv``/``ret`` stamps, each task its
+        body's ``start``/``end``.
         """
+        recv = time.perf_counter()
         with self._busy_lock:
             self._busy += 1
         try:
@@ -320,7 +339,8 @@ class Worker:
                 raise RuntimeError(
                     "worker context not ready (registration hung)"
                 )
-            fns = [cloudpickle.loads(b) for b in req["fns"]]
+            with span("worker/task_load", worker_id=self.worker_id):
+                fns = [cloudpickle.loads(b) for b in req["fns"]]
             tasks = req.get("tasks", ())
             metrics.counter_add("worker/tasks", len(tasks))
             metrics.counter_add("worker/task_batches")
@@ -328,8 +348,8 @@ class Worker:
                            tasks=len(tasks))
             # Task-pool threads don't inherit this handler thread's
             # propagated traceparent — re-propagate it so per-task spans
-            # still parent under the driver's stage span. The job scope
-            # crosses the same thread boundary the same way.
+            # still parent under the driver's envelope span. The job
+            # scope crosses the same thread boundary the same way.
             batch_ctx = trace_prop.current_context()
             batch_job = _acct.current_job()
 
@@ -338,17 +358,23 @@ class Worker:
                     fn = fns[task["fn"]]
                     args = task.get("args", ())
                     kwargs = task.get("kwargs", {})
-                    data = self._resolve_data_refs(task.get("data_refs", ()))
-                    self._fault_task_hook()
-                    t0 = time.perf_counter()
                     with trace_prop.propagated(batch_ctx), \
                             _acct.job_scope(batch_job):
+                        data = ()
+                        if task.get("data_refs"):
+                            with span("worker/task_load",
+                                      worker_id=self.worker_id):
+                                data = self._resolve_data_refs(
+                                    task["data_refs"]
+                                )
+                        self._fault_task_hook()
+                        start = time.perf_counter()
                         with span("worker/task", worker_id=self.worker_id):
-                            with metrics.timer("worker/task").time():
-                                value = fn(self.ctx, *args, *data, **kwargs)
-                        exec_s = time.perf_counter() - t0
-                        _acct.add_usage(_acct.TASK_SECONDS, exec_s)
-                    return {"ok": True, "value": value, "exec_s": exec_s}
+                            value = fn(self.ctx, *args, *data, **kwargs)
+                        end = time.perf_counter()
+                        exec_s = self._task_ran(start, end)
+                    return {"ok": True, "value": value, "exec_s": exec_s,
+                            "start": start, "end": end}
                 except Exception as exc:
                     return {
                         "ok": False,
@@ -366,7 +392,8 @@ class Worker:
                     results = list(self._pool().map(run_one, tasks))
             _flight.record("task", "batch_end", worker_id=self.worker_id,
                            tasks=len(tasks))
-            return {"results": results}
+            return {"results": results, "recv": recv,
+                    "ret": time.perf_counter()}
         finally:
             with self._busy_lock:
                 self._busy -= 1

@@ -1016,7 +1016,8 @@ class DataFrame:
 
         ``analyze=True`` EXECUTES the plan first (EXPLAIN ANALYZE) and
         renders per-stage runtime stats under each node: rows and bytes
-        in/out, wall/dispatch/queue seconds, worker attribution, and the
+        in/out, the wall and (cluster stages) its partition into submit,
+        transit, load, exec and driver time, worker attribution, and the
         partition-skew ratio. Returns the rendered text (and prints it
         unless ``quiet``)."""
         df = self._flush() if analyze else self
@@ -1615,6 +1616,22 @@ def _fmt_bytes(n: int) -> str:
     return f"{int(n)}B"
 
 
+def _fmt_partition(s) -> str:
+    """A cluster stage's wall partitioned along its critical path
+    (``StageStats``: the five sum to the wall); nothing for a local
+    stage, whose wall is the driver's own work."""
+    if s.executor != "cluster":
+        return ""
+    text = (
+        f" (submit {s.submit_s * 1e3:.1f}ms, transit"
+        f" {s.transit_s * 1e3:.1f}ms, load {s.load_s * 1e3:.1f}ms,"
+        f" exec {s.exec_s * 1e3:.1f}ms, driver {s.driver_s * 1e3:.1f}ms"
+    )
+    if s.upstream_s >= 5e-4:
+        text += f", of it upstream wait {s.upstream_s * 1e3:.1f}ms"
+    return text + ")"
+
+
 def _render_plan(lineage: List[Dict[str, Any]], analyze: bool) -> str:
     """EXPLAIN [ANALYZE] text for a lineage list (see _node)."""
     lines = [
@@ -1655,9 +1672,8 @@ def _render_plan(lineage: List[Dict[str, Any]], analyze: bool) -> str:
                     f"  bytes {_fmt_bytes(s.bytes_in)} ->"
                     f" {_fmt_bytes(s.bytes_out)}"
                     f"  wall {s.wall_s:.3f}s"
-                    f" (dispatch {s.dispatch_s:.3f}s,"
-                    f" queue {s.queue_s:.3f}s)"
-                    f"  skew {s.skew:.2f}"
+                    + _fmt_partition(s)
+                    + f"  skew {s.skew:.2f}"
                     + (f"  workers={workers}" if workers else "")
                 )
     lines.append(

@@ -16,6 +16,7 @@ Two backends with one interface:
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import os
 import threading
@@ -117,14 +118,65 @@ def _part_meta(part: Any) -> "tuple[int, int]":
     return -1, 0
 
 
+#: Envelopes a ``stage/close`` span lists at most (its one string attr).
+_CLOSE_ENVELOPES = 32
+
+
+def _union_s(intervals, lo: float, hi: float) -> float:
+    """Length of the union of ``intervals`` inside ``[lo, hi]``."""
+    covered, cursor = 0.0, lo
+    for a, b in sorted(intervals):
+        a, b = max(a, cursor), min(b, hi)
+        if b > a:
+            covered += b - a
+            cursor = b
+    return covered
+
+
+def format_envelopes(envelopes) -> str:
+    """The ``envelopes`` attr of a ``stage/close`` span: for each envelope
+    ``<env>:<worker>:<ret - recv>:<start>-<end>+<start>-<end>...`` joined
+    by ``;`` — whole microseconds, the body intervals relative to
+    ``recv``. No ``,``, ``=`` or ``#``: a profiler annotation's attrs
+    travel inside its name. ``benchmark/stage_trace.py:parse_envelopes``
+    reads it back."""
+    out = []
+    for e in envelopes:
+        worker = "".join(
+            c if c.isalnum() or c in "_.-" else "_" for c in str(e["worker"])
+        )
+        bodies = "+".join(
+            f"{round((a - e['recv']) * 1e6)}-{round((b - e['recv']) * 1e6)}"
+            for a, b in sorted(e["bodies"])
+        )
+        out.append(
+            f"{e['env']}:{worker}:{round((e['ret'] - e['recv']) * 1e6)}"
+            f":{bodies}"
+        )
+    return ";".join(out)
+
+
 class _StageRecorder:
     """Accumulates one :class:`StageStats` while a stage runs.
 
     Cheap when disabled (``RAYDP_TPU_STAGE_STATS=0``): every method
-    no-ops after one boolean check. ``task_meta`` doubles as the
-    ``meta_sink`` callback of ``Cluster.submit_batch``/``submit_async``
-    so worker-side exec seconds and per-worker attribution ride the
-    existing task replies."""
+    no-ops after one boolean check. ``round()`` opens one round of
+    envelopes (one ``Cluster.submit_batch``/``submit_async`` call; an
+    exchange has two) and returns that call's ``meta_sink``, so worker
+    attribution and the stamps of both sides ride the existing task
+    replies.
+
+    At the close the stage's wall is partitioned along its critical
+    path: of each round, the envelope whose reply came last; of
+    overlapping rounds (a streaming stage pumps one per upstream
+    completion), the chain that ends last. Per round on the chain,
+    ``submit_s`` is round start → that envelope's send, ``transit_s``
+    ``(reply − send) − (ret − recv)`` (no common clock needed),
+    ``exec_s`` the union of the envelope's body intervals, ``load_s``
+    the rest of ``ret − recv``; ``driver_s`` is what is left of
+    ``wall_s`` (before the first round — for a streaming stage the wait
+    for upstream partitions, kept apart as ``upstream_s`` — between
+    rounds, and after the last reply). The five sum to ``wall_s``."""
 
     def __init__(self, op: str, parts_in: Sequence[Any], kind: str,
                  total_tasks: Optional[int] = None, streaming: bool = False):
@@ -136,8 +188,7 @@ class _StageRecorder:
         self.kind = kind
         self.streaming = bool(streaming)
         self._t0 = time.perf_counter()
-        self._dispatch_s = 0.0
-        self._exec_s = 0.0
+        self._rounds: List[dict] = []
         self._workers: dict = {}
         self._mu = threading.Lock()
         self._outs: Optional[List[Any]] = None
@@ -168,21 +219,35 @@ class _StageRecorder:
         total = total_tasks if total_tasks is not None else len(parts_in)
         progress.stage_begin(self.stage_id, self.op, total)
 
-    def dispatched(self) -> None:
-        """Mark the end of driver-side submission (dispatch time)."""
+    def round(self) -> Callable:
+        """Open one round of envelopes — call it where the round's
+        ``submit_batch``/``submit_async`` is called — and return that
+        call's ``meta_sink``."""
+        rnd = {"start": time.perf_counter(), "envelopes": {}}
         if self.enabled:
-            self._dispatch_s = time.perf_counter() - self._t0
+            with self._mu:
+                self._rounds.append(rnd)
+        return functools.partial(self._task_meta, rnd)
 
-    def task_meta(self, index: int, worker_id: Optional[str],
-                  exec_s: float) -> None:
-        """Per-task completion: worker attribution + measured exec
-        seconds (``meta_sink`` shape)."""
+    def _task_meta(self, rnd: dict, index: int, worker_id: Optional[str],
+                   exec_s: float, stamps: Optional[dict] = None) -> None:
+        """Per-task completion (``meta_sink`` shape): worker attribution
+        and, per envelope of the round, the stamps of both sides."""
         if not self.enabled:
             return
+        wid = worker_id or "?"
         with self._mu:
-            self._exec_s += float(exec_s or 0.0)
-            wid = worker_id or "?"
             self._workers[wid] = self._workers.get(wid, 0) + 1
+            if stamps is not None:
+                env = rnd["envelopes"].get(stamps["env"])
+                if env is None:
+                    env = rnd["envelopes"][stamps["env"]] = {
+                        "env": stamps["env"], "worker": wid,
+                        "send": stamps["send"], "reply": stamps["reply"],
+                        "recv": stamps["recv"], "ret": stamps["ret"],
+                        "bodies": [],
+                    }
+                env["bodies"].append((stamps["start"], stamps["end"]))
         progress.task_done(self.stage_id)
 
     def task_done(self, n: int = 1) -> None:
@@ -219,36 +284,107 @@ class _StageRecorder:
         with self._mu:
             self._out_meta[index] = meta
 
+    def _partition(self) -> dict:
+        """The four measured parts of the wall along the critical path,
+        ``upstream_s`` and the envelopes in reply order (see the class
+        docstring)."""
+        with self._mu:
+            rounds = [
+                (r["start"], list(r["envelopes"].values()))
+                for r in self._rounds if r["envelopes"]
+            ]
+        last = sorted(
+            ((start, max(envs, key=lambda e: e["reply"]))
+             for start, envs in rounds),
+            key=lambda sc: -sc[1]["reply"],
+        )
+        parts = {"submit_s": 0.0, "transit_s": 0.0, "load_s": 0.0,
+                 "exec_s": 0.0, "upstream_s": 0.0}
+        cursor = float("inf")
+        for start, crit in last:
+            if crit["reply"] > cursor:
+                continue  # overlaps the chain: off the critical path
+            cursor = start
+            worker = max(0.0, crit["ret"] - crit["recv"])
+            body = _union_s(crit["bodies"], crit["recv"], crit["ret"])
+            parts["submit_s"] += max(0.0, crit["send"] - start)
+            parts["transit_s"] += max(
+                0.0, crit["reply"] - crit["send"] - worker
+            )
+            parts["load_s"] += worker - body
+            parts["exec_s"] += body
+        if last:
+            parts["upstream_s"] = max(0.0, cursor - self._t0)
+        parts["envelopes"] = sorted(
+            (e for _, envs in rounds for e in envs),
+            key=lambda e: e["reply"],
+        )
+        return parts
+
+    @contextlib.contextmanager
+    def _closing(self):
+        """The ``stage/close`` span of a cluster stage, around the
+        close's own work (``_part_meta`` over the outputs, ``_emit``).
+        Its attrs are known when it opens, because the replies are in:
+        the parts in µs (``driver_us`` as of now: the close itself
+        comes on top) and each envelope's worker-side stamps — a
+        profiler annotation takes attrs at entry only, so they cannot
+        go on ``stage/envelope`` itself."""
+        if self.kind != "cluster":
+            yield None
+            return
+        parts = self._partition()
+        measured = sum(
+            parts[k] for k in ("submit_s", "transit_s", "load_s", "exec_s")
+        )
+        envelopes = parts.pop("envelopes")
+        with span(
+            "stage/close", stage=self.stage_id, op=self.op,
+            submit_us=round(parts["submit_s"] * 1e6),
+            transit_us=round(parts["transit_s"] * 1e6),
+            load_us=round(parts["load_s"] * 1e6),
+            exec_us=round(parts["exec_s"] * 1e6),
+            driver_us=round(
+                (time.perf_counter() - self._t0 - measured) * 1e6
+            ),
+            envelopes=format_envelopes(envelopes[-_CLOSE_ENVELOPES:]),
+        ):
+            yield parts
+
     def close_streaming(self) -> None:
         """Finalize a streaming stage: called by the scheduler after the
         last task lands, BEFORE the final output future resolves."""
         if not self.enabled:
             return
-        with self._mu:
-            meta = dict(self._out_meta)
-        part_rows = [meta[i][0] for i in sorted(meta)]
-        part_bytes = [meta[i][1] for i in sorted(meta)]
-        self._emit(part_rows, part_bytes, len(meta))
+        with self._closing() as parts:
+            with self._mu:
+                meta = dict(self._out_meta)
+            part_rows = [meta[i][0] for i in sorted(meta)]
+            part_bytes = [meta[i][1] for i in sorted(meta)]
+            self._emit(part_rows, part_bytes, len(meta), parts)
 
     def close(self) -> None:
         if not self.enabled:
             return
-        part_rows: List[int] = []
-        part_bytes: List[int] = []
-        for p in self._outs or ():
-            r, b = _part_meta(p)
-            part_rows.append(r)
-            part_bytes.append(b)
-        self._emit(part_rows, part_bytes, len(self._outs or ()))
+        with self._closing() as parts:
+            part_rows: List[int] = []
+            part_bytes: List[int] = []
+            for p in self._outs or ():
+                r, b = _part_meta(p)
+                part_rows.append(r)
+                part_bytes.append(b)
+            self._emit(part_rows, part_bytes, len(self._outs or ()), parts)
 
     def _emit(self, part_rows: List[int], part_bytes: List[int],
-              parts_out: int) -> None:
+              parts_out: int, parts: Optional[dict]) -> None:
         wall = time.perf_counter() - self._t0
         rows_out = sum(r for r in part_rows if r > 0)
-        bytes_out = sum(part_bytes)
-        # Queue time: stage wall minus driver dispatch minus measured
-        # worker execution — the time tasks sat waiting for a slot.
-        queue_s = max(0.0, wall - self._dispatch_s - self._exec_s)
+        parts = dict(parts or {})  # a local stage has no partition
+        if parts:
+            parts["driver_s"] = wall - sum(
+                parts[k]
+                for k in ("submit_s", "transit_s", "load_s", "exec_s")
+            )
         stats = StageStats(
             stage_id=self.stage_id,
             op=self.op,
@@ -256,24 +392,30 @@ class _StageRecorder:
             rows_in=self._rows_in,
             rows_out=rows_out,
             bytes_in=self._bytes_in,
-            bytes_out=bytes_out,
+            bytes_out=sum(part_bytes),
             parts_in=self._parts_in,
             parts_out=parts_out,
             wall_s=wall,
-            dispatch_s=self._dispatch_s,
-            queue_s=queue_s if self.kind == "cluster" else 0.0,
+            # The time the stage's critical tasks existed and were
+            # neither being submitted nor running — measured, not
+            # inferred from a sum of task seconds over all workers.
+            queue_s=parts.get("transit_s", 0.0) + parts.get("load_s", 0.0),
             workers=dict(self._workers),
             part_rows=part_rows,
             part_bytes=part_bytes,
+            **parts,
         )
         stage_store.record(stats)
         progress.stage_end(self.stage_id)
         if self._ids_sink is not None and not self._ids_sunk:
             self._ids_sink.append(self.stage_id)
+        # Read by name: ``export.py`` folds them into the families
+        # ``raydp_stage_{rows,bytes,seconds}_total``, the dashboard reads
+        # ``stage/rows_out/``.
         metrics.counter_add(f"stage/rows_in/{self.op}", self._rows_in)
         metrics.counter_add(f"stage/rows_out/{self.op}", rows_out)
         metrics.counter_add(f"stage/bytes_in/{self.op}", self._bytes_in)
-        metrics.counter_add(f"stage/bytes_out/{self.op}", bytes_out)
+        metrics.counter_add(f"stage/bytes_out/{self.op}", stats.bytes_out)
         metrics.counter_add(f"stage/seconds/{self.op}", wall)
 
 
@@ -508,7 +650,6 @@ class LocalExecutor(Executor):
                                on_close=rec.close_streaming, op=op)
         with _stage_span(op, len(deps), "local", streaming=True):
             outs = stage.start()
-            rec.dispatched()
         return outs
 
     def map_partitions(self, parts, fn):
@@ -573,7 +714,6 @@ class LocalExecutor(Executor):
             metrics.counter_add("shuffle/exchanges")
             chunked = list(self._pool.map(splitter, parts))
             rec.task_done(len(parts))
-            rec.dispatched()
             moved = sum(
                 c.nbytes for chunks in chunked for c in chunks
             )
@@ -701,13 +841,12 @@ class ClusterExecutor(Executor):
             for _i, vals in items:
                 rec.task_input(vals[:1])
             specs = [spec_of(i, vals) for i, vals in items]
-            return self.cluster.submit_batch(specs, meta_sink=rec.task_meta)
+            return self.cluster.submit_batch(specs, meta_sink=rec.round())
 
         stage = StreamingStage(deps, submit, on_output=rec.task_output,
                                on_close=rec.close_streaming, op=op)
         with _stage_span(op, len(deps), "cluster", streaming=True):
             outs = stage.start()
-            rec.dispatched()
         return outs
 
     def map_partitions(self, parts, fn):
@@ -731,8 +870,7 @@ class ClusterExecutor(Executor):
             futures = self.cluster.submit_batch([
                 TaskSpec(task, (ref,), worker_id=self._worker_for(i, ref))
                 for i, ref in enumerate(parts)
-            ], meta_sink=rec.task_meta)
-            rec.dispatched()
+            ], meta_sink=rec.round())
             outs = [f.result() for f in futures]
             rec.finish(outs)
             return outs
@@ -755,8 +893,7 @@ class ClusterExecutor(Executor):
             futures = self.cluster.submit_batch([
                 TaskSpec(task, (ref, i), worker_id=self._worker_for(i, ref))
                 for i, ref in enumerate(parts)
-            ], meta_sink=rec.task_meta)
-            rec.dispatched()
+            ], meta_sink=rec.round())
             outs = [f.result() for f in futures]
             rec.finish(outs)
             return outs
@@ -821,9 +958,8 @@ class ClusterExecutor(Executor):
         with _stage("run_coalesced", parts, "cluster",
                     total_tasks=1) as rec:
             fut = self.cluster.submit_async(
-                task, parts, worker_id=worker_id, meta_sink=rec.task_meta
+                task, parts, worker_id=worker_id, meta_sink=rec.round()
             )
-            rec.dispatched()
             out = fut.result()
             rec.finish([out])
             return out
@@ -849,8 +985,7 @@ class ClusterExecutor(Executor):
             futures = self.cluster.submit_batch([
                 TaskSpec(task, (ra, rb), worker_id=self._worker_for(i, ra))
                 for i, (ra, rb) in enumerate(zip(parts_a, parts_b))
-            ], meta_sink=rec.task_meta)
-            rec.dispatched()
+            ], meta_sink=rec.round())
             outs = [f.result() for f in futures]
             rec.finish(outs)
             return outs
@@ -931,7 +1066,7 @@ class ClusterExecutor(Executor):
                 TaskSpec(split_task, (ref,),
                          worker_id=self._worker_for(i, ref))
                 for i, ref in enumerate(parts)
-            ], meta_sink=rec.task_meta)
+            ], meta_sink=rec.round())
             # Stream split completions (one envelope per worker resolves
             # independently) instead of gathering in submission order:
             # merge planning starts the moment the last chunk EXISTS,
@@ -1027,9 +1162,8 @@ class ClusterExecutor(Executor):
             metrics.counter_add("shuffle/local_bytes", local_b)
             _acct.add_usage(_acct.SHUFFLE_BYTES, total_b)
             merge_futures = self.cluster.submit_batch(
-                specs, meta_sink=rec.task_meta
+                specs, meta_sink=rec.round()
             )
-            rec.dispatched()
             # Merge i consumes exactly its input refs, so they are dead
             # the moment that merge lands — free them then, instead of
             # holding the whole shuffle's intermediates until the full

@@ -8,7 +8,9 @@ families, `/debug/progress`, and the cost-based adaptive planner
 * :data:`stage_store` — a :class:`StageStatsStore` of
   :class:`StageStats` records, one per executed DataFrame stage
   (map / exchange / coalesce), carrying rows and bytes in/out,
-  wall/dispatch/queue seconds, per-worker task attribution, and the
+  the wall and its partition into submit, transit, load, exec and
+  driver seconds (``queue_s`` is transit + load), per-worker task
+  attribution, and the
   per-partition output layout the skew ratio (max/mean rows) is
   computed from. Executors record into it as stages complete;
   materialized ``DataFrame``s keep the ids of the stages that built
@@ -87,8 +89,22 @@ class StageStats:
     parts_in: int = 0
     parts_out: int = 0
     wall_s: float = 0.0
-    dispatch_s: float = 0.0       # driver-side submit time
-    queue_s: float = 0.0          # wall - worker exec, cluster stages
+    # transit_s + load_s: the time the stage's critical tasks existed
+    # and were neither being submitted nor running (cluster stages). A
+    # task's wait for a slot of the worker's pool is not in it: other
+    # bodies run meanwhile, which is exec_s.
+    queue_s: float = 0.0
+    # The wall of a cluster stage partitioned along its critical path
+    # (per round of envelopes the one whose reply came last; measured
+    # from the stamps both sides put on the task reply, see
+    # ``_StageRecorder``): the five sum to ``wall_s``. All 0 for a
+    # local stage.
+    submit_s: float = 0.0         # round start -> the envelope's send
+    transit_s: float = 0.0        # (reply - send) - (ret - recv)
+    load_s: float = 0.0           # (ret - recv) - the bodies' union
+    exec_s: float = 0.0           # union of that envelope's bodies
+    driver_s: float = 0.0         # the rest: before, between, after
+    upstream_s: float = 0.0       # of driver_s: start -> first round
     workers: Dict[str, int] = field(default_factory=dict)  # wid -> tasks
     part_rows: List[int] = field(default_factory=list)     # output layout
     part_bytes: List[int] = field(default_factory=list)
@@ -115,8 +131,13 @@ class StageStats:
             "parts_in": self.parts_in,
             "parts_out": self.parts_out,
             "wall_s": round(self.wall_s, 6),
-            "dispatch_s": round(self.dispatch_s, 6),
             "queue_s": round(self.queue_s, 6),
+            "submit_s": round(self.submit_s, 6),
+            "transit_s": round(self.transit_s, 6),
+            "load_s": round(self.load_s, 6),
+            "exec_s": round(self.exec_s, 6),
+            "driver_s": round(self.driver_s, 6),
+            "upstream_s": round(self.upstream_s, 6),
             "workers": dict(self.workers),
             "part_rows": list(self.part_rows),
             "part_bytes": list(self.part_bytes),

@@ -202,6 +202,36 @@ def test_serve_group_queue_feeds_pressure():
     assert "serve_queue_depth" not in sc.sample_pressure()
 
 
+def test_stage_queue_signal_reads_the_measured_queue():
+    """``stage_queue`` is the largest ``queue_s`` of the stages recorded
+    since the last sample, and ``queue_s`` is ``transit_s + load_s`` of
+    the stage's critical envelopes (measured from the stamps on the task
+    replies). A stage whose tasks waited for a slot of their worker's
+    pool under OTHER bodies has that time in ``exec_s`` — it does not
+    trip the signal."""
+    from raydp_tpu.telemetry import StageStats, stage_store
+
+    def cluster_stage(wall, transit, load, exec_):
+        return StageStats(
+            stage_id=0, op="map", executor="cluster", wall_s=wall,
+            transit_s=transit, load_s=load, exec_s=exec_,
+            queue_s=transit + load, driver_s=wall - transit - load - exec_,
+        )
+
+    sc = Autoscaler(FakeProvisioner(), AutoscalerConfig())
+    sc.sample_pressure()  # stages recorded before this sample are old
+    # Sixteen 0.5 s bodies through a two-slot pool: 4 s of wall, all of
+    # it under some body.
+    stage_store.record(cluster_stage(4.1, 0.004, 0.001, 4.0))
+    assert sc.sample_pressure()["stage_queue"] == pytest.approx(0.005)
+    # A slow data plane: the critical envelope spent 1.5 s fetching its
+    # data refs and 0.5 s in transit before a 0.1 s body.
+    stage_store.record(cluster_stage(2.2, 0.5, 1.5, 0.1))
+    stage_store.record(cluster_stage(0.3, 0.01, 0.01, 0.2))
+    assert sc.sample_pressure()["stage_queue"] == pytest.approx(2.0)
+    assert "stage_queue" not in sc.sample_pressure()  # nothing new
+
+
 def test_decision_events_reconstruct_the_timeline():
     prov = FakeProvisioner(initial=1)
     sc, cell = _scaler(prov, {"stage_queue": 2.0})
