@@ -22,6 +22,25 @@ per-query-head dk/dv summed by XLA afterwards, writes and re-reads
 ``group`` times the bytes for nothing. With ``group == 1`` every grid and
 index map is the one it was before grouping existed.
 
+What a kernel does per score element is what its tile needs. Under a
+causal mask a [block_q, block_kv] tile is one of three kinds, told apart
+from the grid indices alone: DEAD (no key at or before any of its queries:
+skipped), WHOLE (its first query row already sees its last key column, so
+no entry is masked) or CROSSED by the diagonal. Only the crossed tiles'
+body builds the positions, compares and selects; the whole tiles' body is
+the same function with ``masked=False`` (at S = 8,192 in 1024² tiles: 36
+live, 8 crossed). The softmax scale multiplies the [block_q, d] ``q`` tile
+when it is a power of two (``64 ** -0.5``, ``1/64``): exact in any float
+dtype, and 1/16 or less of the elements of the score tile. Any other
+scale (``128 ** -0.5``) stays a float32 multiply of the score tile: a bf16
+``q·scale`` would round, and that is another result. The dk/dv kernel asks
+the MXU for its tiles TRANSPOSED (``k·qᵀ``, ``v·gᵀ``: keys down the rows,
+``lse`` and ``delta`` as rows), so ``pᵀ·g`` and ``dsᵀ·q`` are plain
+products and no [block_kv, block_q] tile is ever transposed (Mosaic turns
+a ``dot_general`` that contracts the left operand's first axis back into
+that transpose; ``k.T`` on the RIGHT it folds into the product itself).
+:func:`tile_counts` and :func:`report` say what a step's shapes come to.
+
 The kernels are compiled by Mosaic and run on a TPU only; on any other
 backend the call raises. ``interpret=True`` (pallas guide: Debugging)
 runs the same kernel bodies in the Pallas interpreter — the tests pass
@@ -30,14 +49,19 @@ it explicitly to check the math on the CPU mesh; no model code does.
 from __future__ import annotations
 
 import functools
+import logging
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
+
+from raydp_tpu.ops.attention import _scale
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 
@@ -49,26 +73,121 @@ def _tile_live(qi, ki, causal: bool, q_block: int, block_kv: int):
     return (qi + 1) * q_block - 1 >= ki * block_kv
 
 
-def _masked_scores(q_ref, k_ref, qi, ki, *, scale: float, causal: bool,
-                   q_block: int, block_kv: int):
+def _tile_whole(qi, ki, q_block: int, block_kv: int):
+    """Whether NO entry of causal tile (qi, ki) is masked: its first query
+    row already sees its last key column. A whole tile is live."""
+    return qi * q_block >= (ki + 1) * block_kv - 1
+
+
+def _on_live_tile(qi, ki, body, *, causal: bool, q_block: int,
+                  block_kv: int) -> None:
+    """Run ``body(masked)`` on tile (qi, ki) by its kind: not at all on a
+    dead tile, with ``masked=False`` on a whole one, with ``masked=True``
+    on one the diagonal crosses. ``masked`` is static: the whole-tile body
+    holds no iota, compare or select. Without ``causal`` every tile is
+    whole and only that body is built."""
+    if not causal:
+        body(False)
+        return
+    whole = _tile_whole(qi, ki, q_block, block_kv)
+    crossed = jnp.logical_and(
+        _tile_live(qi, ki, True, q_block, block_kv), jnp.logical_not(whole)
+    )
+    pl.when(whole)(functools.partial(body, False))
+    pl.when(crossed)(functools.partial(body, True))
+
+
+def tile_counts(s: int, block_q: Optional[int] = None,
+                block_kv: Optional[int] = None,
+                causal: bool = True) -> Tuple[int, int]:
+    """(live, masked) tiles of one head in one call: the tiles a kernel
+    computes, and those of them whose body applies the causal mask. The
+    kernels' own predicates over every (qi, ki), on plain ints."""
+    block_q, block_kv = _block(block_q, s), _block(block_kv, s)
+    live = [
+        (qi, ki)
+        for qi in range(s // block_q) for ki in range(s // block_kv)
+        if _tile_live(qi, ki, causal, block_q, block_kv)
+    ]
+    masked = sum(
+        1 for qi, ki in live
+        if causal and not _tile_whole(qi, ki, block_q, block_kv)
+    )
+    return len(live), masked
+
+
+def report(cfg, seq_len: int) -> None:
+    """Static for a compiled step: two gauges and one log line where the
+    step is built (as ``models/mamba.report``). Per head and call; zero for
+    a model that never calls the kernel."""
+    from raydp_tpu.utils.profiling import metrics
+
+    layers = live = masked = 0
+    if getattr(cfg, "attention_impl", None) == "flash":
+        layers = cfg.kinds.count("attention")
+    if layers:
+        live, masked = tile_counts(seq_len, causal=cfg.causal)
+    metrics.gauge_set("attention/flash_live_tiles", live)
+    metrics.gauge_set("attention/flash_masked_tiles", masked)
+    if layers:
+        scale = _scale(cfg.attention_scale, cfg.head_dim)
+        block = _block(None, seq_len)
+        logger.info(
+            "flash attention: %d layers, S = %d in %d x %d tiles, %d live a "
+            "head and call, %d of them masked; softmax scale %g on the %s",
+            layers, seq_len, block, block, live, masked, scale,
+            "q tile" if scale_rides_on_q(scale) else "float32 score tile",
+        )
+
+
+def scale_rides_on_q(scale: float) -> bool:
+    """Whether the softmax scale multiplies the ``q`` tile, not the score
+    tile: only a power of two, which a floating-point ``q`` takes without
+    rounding, so the scores keep their bits."""
+    return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+# a [m, d] x b [n, d] -> [m, n]: the contraction named on the operands' own
+# axes, which Mosaic feeds the MXU without a transposed copy of ``b``.
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32
+    )
+
+
+def _scores(q_ref, k_ref, qi, ki, *, scale: float, masked: bool,
+            q_block: int, block_kv: int, transposed: bool = False):
     """Shared tile math for ALL kernels (forward, dq, dkv): load raw
-    q/k tiles and compute the scaled, causally-masked score tile — one
-    definition, so forward and backward masking can never diverge.
+    q/k tiles and compute the scaled score tile, causally masked where
+    ``masked`` — one definition, so forward and backward masking can
+    never diverge. ``transposed`` gives the tile as [block_kv, q_block]
+    (keys down the rows): the same products, contracted over the same
+    ``d``, asked of the MXU the other way round.
 
     Tiles stay in their INPUT dtype through the MXU (a bf16 model feeds
     the systolic array bf16 operands at full rate — force-upcasting to
     fp32 halves matmul throughput, the r4 verdict's Weak #3) with fp32
-    accumulation via ``preferred_element_type``; scaling and masking
-    happen on the fp32 product."""
+    accumulation via ``preferred_element_type``. A power-of-two scale
+    multiplies the [q_block, d] ``q`` tile (exact in any float dtype);
+    any other multiplies the fp32 score tile, where a scaled bf16 ``q``
+    would round differently."""
     q = q_ref[0, 0]
     k = k_ref[0, 0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if causal:
+    on_q = scale_rides_on_q(scale)
+    q_in = q * scale if on_q else q
+    s = _dot(k, q_in, _NT) if transposed else _dot(q_in, k, _NT)
+    if not on_q:
+        s = s * scale
+    if masked:
+        q_axis = 1 if transposed else 0
         q_pos = qi * q_block + jax.lax.broadcasted_iota(
-            jnp.int32, (q_block, block_kv), 0
+            jnp.int32, s.shape, q_axis
         )
         k_pos = ki * block_kv + jax.lax.broadcasted_iota(
-            jnp.int32, (q_block, block_kv), 1
+            jnp.int32, s.shape, 1 - q_axis
         )
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
     return q, k, s
@@ -90,11 +209,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Causal: blocks strictly above the diagonal contribute nothing.
-    @pl.when(_tile_live(qi, ki, causal, q_block, block_kv))
-    def _attend():
-        _, _, s = _masked_scores(
-            q_ref, k_ref, qi, ki, scale=scale, causal=causal,
+    def _attend(masked: bool):
+        _, _, s = _scores(
+            q_ref, k_ref, qi, ki, scale=scale, masked=masked,
             q_block=q_block, block_kv=block_kv,
         )
         v = v_ref[0, 0]
@@ -107,9 +224,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         # p downcast to the value dtype for the MXU; the accumulator
         # stays fp32 (standard flash practice — the softmax weights carry
         # at most ~1 ulp of bf16 error into an fp32 sum).
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
+        acc_ref[...] = acc_ref[...] * corr + _dot(p.astype(v.dtype), v)
+
+    # Causal: blocks strictly above the diagonal contribute nothing.
+    _on_live_tile(qi, ki, _attend, causal=causal, q_block=q_block,
+                  block_kv=block_kv)
 
     @pl.when(ki == n_kv - 1)
     def _finish():
@@ -135,20 +254,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(_tile_live(qi, ki, causal, q_block, block_kv))
-    def _accumulate():
-        _, k, s = _masked_scores(
-            q_ref, k_ref, qi, ki, scale=scale, causal=causal,
+    def _accumulate(masked: bool):
+        _, k, s = _scores(
+            q_ref, k_ref, qi, ki, scale=scale, masked=masked,
             q_block=q_block, block_kv=block_kv,
         )
-        v = v_ref[0, 0]
-        g = g_ref[0, 0]
         p = jnp.exp(s - lse_ref[0, 0])          # [q_block, block_kv] f32
-        dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
+        dp = _dot(g_ref[0, 0], v_ref[0, 0], _NT)
         ds = p * (dp - delta_ref[0, 0])
-        acc_ref[...] += jnp.dot(
-            ds.astype(k.dtype), k, preferred_element_type=jnp.float32
-        ) * scale
+        acc_ref[...] += _dot(ds.astype(k.dtype), k) * scale
+
+    _on_live_tile(qi, ki, _accumulate, causal=causal, q_block=q_block,
+                  block_kv=block_kv)
 
     @pl.when(ki == n_kv - 1)
     def _finish():
@@ -176,23 +293,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_tile_live(qi, ki, causal, q_block, block_kv))
-    def _accumulate():
-        q, _, s = _masked_scores(
-            q_ref, k_ref, qi, ki, scale=scale, causal=causal,
-            q_block=q_block, block_kv=block_kv,
+    def _accumulate(masked: bool):
+        # Keys down the rows: p and ds come out as the [block_kv, q_block]
+        # tiles both products contract over, so neither is transposed
+        # (lse and delta arrive as [1, q_block] rows for the same reason).
+        q, _, s = _scores(
+            q_ref, k_ref, qi, ki, scale=scale, masked=masked,
+            q_block=q_block, block_kv=block_kv, transposed=True,
         )
-        v = v_ref[0, 0]
         g = g_ref[0, 0]
-        p = jnp.exp(s - lse_ref[0, 0])
-        dv_acc[...] += jnp.dot(
-            p.astype(g.dtype).T, g, preferred_element_type=jnp.float32
-        )
-        dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
+        p = jnp.exp(s - lse_ref[0, 0])          # [block_kv, q_block] f32
+        dv_acc[...] += _dot(p.astype(g.dtype), g)
+        dp = _dot(v_ref[0, 0], g, _NT)
         ds = p * (dp - delta_ref[0, 0])
-        dk_acc[...] += jnp.dot(
-            ds.astype(q.dtype).T, q, preferred_element_type=jnp.float32
-        ) * scale
+        dk_acc[...] += _dot(ds.astype(q.dtype), q) * scale
+
+    _on_live_tile(qi, ki, _accumulate, causal=causal, q_block=q_block,
+                  block_kv=block_kv)
 
     @pl.when(step == n_steps - 1)
     def _finish():
@@ -229,7 +346,7 @@ def flash_attention(
         )
     return _flash_vjp(
         q, k, v, causal, _block(block_q, s), _block(block_kv, s), interpret,
-        1.0 / math.sqrt(d) if scale is None else scale,
+        _scale(scale, d),
     )
 
 
@@ -239,7 +356,7 @@ def _block(block: Optional[int], s: int) -> int:
     with 128 x 128 tiles, 17.0 with 256, 8.26 with 512 and 6.35 with 1024
     (dense attention 14.7; PERF.md §6, PR 26)."""
     if block is not None:
-        return block
+        return min(block, s)
     return next((b for b in (1024, 512, 256, 128) if s % b == 0), s)
 
 
@@ -308,8 +425,6 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
     h_kv = kt.shape[1]
     group = h // h_kv
     kv_of = _kv_head(group)
-    block_q = min(block_q, s)
-    block_kv = min(block_kv, s)
 
     gt = jnp.einsum("bshd->bhsd", g)
     # delta_i = Σ_d dO_i · O_i — the softmax-jacobian row term.
@@ -354,7 +469,13 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
     kv_spec_t = pl.BlockSpec(
         (1, 1, block_kv, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)
     )
-    row_spec_t = pl.BlockSpec((1, 1, block_q, 1), q_at)
+    # lse and delta as rows [B, H, 1, S] for the dk/dv kernel's transposed
+    # tiles (1 MB a call to lay out again; the tiles are 4 MB each).
+    def row_at(*at):
+        bi, hi, qi, _ = q_at(*at)
+        return bi, hi, 0, qi
+
+    row_spec_t = pl.BlockSpec((1, 1, 1, block_q), row_at)
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, block_kv=block_kv, causal=causal, scale=scale,
@@ -375,7 +496,7 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
             pltpu.VMEM((block_kv, d), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt, gt, lse, delta)
+    )(qt, kt, vt, gt, jnp.swapaxes(lse, 2, 3), jnp.swapaxes(delta, 2, 3))
 
     to_bshd = lambda x: jnp.einsum("bhsd->bshd", x)  # noqa: E731
     return to_bshd(dq), to_bshd(dk), to_bshd(dv)
@@ -396,8 +517,6 @@ def _flash_forward(
 ):
     b, s, h, d = q.shape
     kv_of = _kv_head(h // k.shape[2])
-    block_q = min(block_q, s)
-    block_kv = min(block_kv, s)
     if s % block_q or s % block_kv:
         raise ValueError(f"seq len {s} not divisible by blocks "
                          f"({block_q}, {block_kv})")

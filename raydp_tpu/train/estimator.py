@@ -29,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from raydp_tpu import fault as _fault
 from raydp_tpu.data.ml_dataset import MLDataset
 from raydp_tpu.models import dropout, mamba, moe, shortconv
+from raydp_tpu.ops.flash_attention import report as report_flash_tiles
 from raydp_tpu.parallel.mesh import MeshSpec
 from raydp_tpu.telemetry import accounting as _acct
 from raydp_tpu.telemetry import events as _events
@@ -481,6 +482,10 @@ class JAXEstimator:
             getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
         )
         shortconv.report(getattr(self._model, "cfg", None))
+        report_flash_tiles(
+            getattr(self._model, "cfg", None),
+            seq_len=self._sample_batch.shape[-1],
+        )
         moe.report(self._model, tokens_per_step=tokens_per_step)
 
         use_aux = self.aux_losses
