@@ -11,7 +11,10 @@ from raydp_tpu.models.transformer import (
     olmoe,
     param_shardings,
     tiny_transformer,
+    xing4_0,
 )
+from raydp_tpu.models.hyperconn import HyperConfig
+from raydp_tpu.models.latent import LatentConfig
 
 from raydp_tpu.models.dlrm import (
     DLRM,
@@ -56,6 +59,9 @@ __all__ = [
     "granite_h_micro",
     "lfm2_8b_a1b",
     "olmoe",
+    "xing4_0",
+    "HyperConfig",
+    "LatentConfig",
     "tiny_transformer",
     "param_shardings",
 ]

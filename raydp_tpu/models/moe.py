@@ -50,9 +50,10 @@ the published OLMoE block (Muennighoff et al. 2024, arXiv:2409.02060):
   the ``'losses'`` collection as ``moe_aux`` (nothing where both weights
   are 0); pull them with :func:`moe_aux_loss`. The tokens each expert
   received (and, for a share, each held expert, and whether the held
-  pairs exceeded ``C``) are sown into :data:`STATS`; ``JAXEstimator`` sums
-  them over an epoch on the device (:func:`step_stats`,
-  :func:`report_epoch`).
+  pairs exceeded ``C``) are sown into the step's statistics
+  (``models/stats.py``); ``JAXEstimator`` sums them over an epoch on the
+  device, the step's auxiliary loss beside them (:func:`with_aux_loss`),
+  and :func:`report_epoch` reads them.
 """
 from __future__ import annotations
 
@@ -65,6 +66,8 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from raydp_tpu.models import stats
+from raydp_tpu.models.stats import STATS  # noqa: F401  (callers' mutable=)
 from raydp_tpu.ops.grouped_matmul import (
     IMPLEMENTATION,
     TILING,
@@ -83,7 +86,10 @@ __all__ = [
 
 logger = logging.getLogger(__name__)
 
-STATS = "moe_stats"
+# What the routed layers sow about a step, each summed over layers and
+# steps (``models/stats.py``).
+for _name in ("aux_loss", "expert_tokens", "held_tokens", "overflow"):
+    stats.declare(_name)
 # Collection of what a layer reads and no gradient step may change: the
 # router's selection bias. ``JAXEstimator``'s step hands it on as it was.
 BUFFERS = "buffers"
@@ -133,6 +139,10 @@ class MoEConfig:
     # The share held here: experts [first_expert, first_expert + held).
     first_expert: int = 0
     held_experts: Optional[int] = None   # None = all n_experts
+    # Experts every token goes through, beside the routed ones: one dense
+    # SwiGLU of width ``shared_experts * d_ff``, ungated, whole on every
+    # share of an expert-parallel deployment.
+    shared_experts: int = 0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -355,6 +365,30 @@ def _add(a, b):
     return a + b
 
 
+class SharedExpert(nn.Module):
+    """The experts no router chooses: ``down(silu(gate(x)) * up(x))`` for
+    every token, gate and up as one fused projection (scope
+    ``moe/shared``)."""
+
+    cfg: MoEConfig
+
+    @nn.compact
+    def __call__(self, tokens):
+        cfg = self.cfg
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+        )
+        width = cfg.shared_experts * cfg.d_ff
+        gate, up = jnp.split(dense(
+            2 * width, kernel_init=_expert_init("embed", "mlp"), name="in",
+        )(tokens), 2, axis=-1)
+        return dense(
+            cfg.d_model, kernel_init=_expert_init("mlp", "embed"),
+            name="out",
+        )(jax.nn.silu(gate) * up)
+
+
 class MoELayer(nn.Module):
     """Top-k routed SwiGLU experts over the trailing feature axis.
 
@@ -426,16 +460,10 @@ class MoELayer(nn.Module):
                     reduce_fn=_add,
                     init_fn=lambda: jnp.zeros((), jnp.float32),
                 )
-            self.sow(
-                STATS, "expert_tokens", counts, reduce_fn=_add,
-                init_fn=lambda: jnp.zeros((e,), jnp.float32),
-            )
+            stats.sow(self, "expert_tokens", counts)
             if share:
                 counts = counts[first:first + held]
-                self.sow(
-                    STATS, "held_tokens", counts, reduce_fn=_add,
-                    init_fn=lambda: jnp.zeros((held,), jnp.float32),
-                )
+                stats.sow(self, "held_tokens", counts)
 
         w_gate = self.param(
             "w_gate", _expert_init("expert", "embed", "mlp"),
@@ -476,11 +504,7 @@ class MoELayer(nn.Module):
         else:
             with jax.named_scope("permute"):
                 overflow = counts.sum() > rows
-                self.sow(
-                    STATS, "overflow", overflow.astype(jnp.float32),
-                    reduce_fn=_add,
-                    init_fn=lambda: jnp.zeros((), jnp.float32),
-                )
+                stats.sow(self, "overflow", overflow.astype(jnp.float32))
             with jax.named_scope("experts"):
                 # In the compute dtype before the guard, so that what the
                 # guard hands on is the kernels' own weight gradient.
@@ -491,6 +515,12 @@ class MoELayer(nn.Module):
             out = _hand_out(
                 _experts(operands, routing, rows), wire, operands, routing,
                 overflow,
+            )
+        if cfg.shared_experts:
+            # The same on every share: when the shares' parts are summed
+            # it counts once.
+            out = out + SharedExpert(cfg, name="shared")(
+                tokens.astype(cfg.dtype)
             )
         return out.reshape(*lead_shape, d)
 
@@ -536,24 +566,19 @@ def moe_aux_loss(variables) -> jnp.ndarray:
     return total
 
 
-def step_stats(variables) -> dict:
-    """What one step's ``mutable=['losses', STATS]`` state says about its
-    routing, as device values: ``{}`` for a model with no routed layer,
-    else the auxiliary loss and the tokens each expert received (a share's
-    layers also: each held expert), summed over the layers."""
-    from flax.traverse_util import flatten_dict
-
-    sown: dict = {}
-    for path, counts in flatten_dict(dict(variables.get(STATS, {}))).items():
-        sown[path[-1]] = sown.get(path[-1], 0) + counts
-    if not sown:
-        return {}
-    return {"aux_loss": moe_aux_loss(variables), **sown}
+def with_aux_loss(sown: dict, variables) -> dict:
+    """One step's statistics (``models/stats.step_stats``) with the
+    auxiliary loss of its ``'losses'`` state beside the routed layers'
+    counts; as they are for a model without a routed layer."""
+    if "expert_tokens" not in sown:
+        return sown
+    return {**sown, "aux_loss": moe_aux_loss(variables)}
 
 
-def report_epoch(stats: dict, n_steps: int) -> None:
-    """Gauges from an epoch's summed :func:`step_stats`, fetched with the
-    epoch's loss: the mean auxiliary loss a step, the (token, expert) pairs
+def report_epoch(sown: dict, n_steps: int) -> None:
+    """Gauges from the routed layers' part of an epoch's statistics
+    (``models/stats.step_stats`` summed over the steps), fetched with the
+    epoch's loss; nothing for a model without a routed layer: the mean auxiliary loss a step, the (token, expert) pairs
     a step routes and how many of them landed on experts held here (all of
     them unless the layers are a share: a quarter at uniform routing over
     four shares), the fullest held expert's tokens over the mean held
@@ -566,15 +591,17 @@ def report_epoch(stats: dict, n_steps: int) -> None:
 
     from raydp_tpu.utils.profiling import metrics
 
-    tokens = np.asarray(stats["expert_tokens"], np.float64)
-    held = np.asarray(stats.get("held_tokens", tokens), np.float64)
-    metrics.gauge_set("moe/aux_loss", float(stats["aux_loss"]) / n_steps)
+    if "expert_tokens" not in sown:
+        return
+    tokens = np.asarray(sown["expert_tokens"], np.float64)
+    held = np.asarray(sown.get("held_tokens", tokens), np.float64)
+    metrics.gauge_set("moe/aux_loss", float(sown["aux_loss"]) / n_steps)
     metrics.gauge_set("moe/load_max_over_mean", held.max() / held.mean())
     metrics.gauge_set("moe/expert_tokens_per_step", tokens.sum() / n_steps)
     metrics.gauge_set("moe/held_pairs_per_step", held.sum() / n_steps)
     metrics.gauge_set("moe/held_pair_share", held.sum() / tokens.sum())
     metrics.gauge_set(
-        "moe/overflow_layer_steps", float(stats.get("overflow", 0.0))
+        "moe/overflow_layer_steps", float(sown.get("overflow", 0.0))
     )
 
 
@@ -595,6 +622,9 @@ def report(model, tokens_per_step: int) -> None:
     metrics.gauge_set("moe/experts_routed", routed)
     metrics.gauge_set("moe/experts_held", held)
     metrics.gauge_set("moe/compact_rows", rows)
+    metrics.gauge_set(
+        "moe/shared_experts", moe.shared_experts if moe is not None else 0
+    )
     if held < routed:
         logger.info(
             "routed layers: a share of an expert-parallel deployment, "

@@ -23,11 +23,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
+import numpy as np
 
 from raydp_tpu.models.dropout import Dropout
 from raydp_tpu.ops.attention import (
@@ -50,6 +52,51 @@ LOGICAL_RULES: Tuple[Tuple[str, Optional[str]], ...] = (
     ("stage", "pp"),  # stacked pipeline-stage axis (models/pipelined.py)
     ("expert", "dp"),  # MoE expert axis shards over dp (models/moe.py)
 )
+
+
+MIXERS = frozenset({"attention", "mamba", "conv", "latent"})
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN (Peng et al. 2023, arXiv:2309.00071) as the published configs'
+    ``rope_scaling`` of ``type`` yarn gives it."""
+
+    factor: float = 1.0
+    original_max_len: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(half: int, theta: float, yarn: YarnScaling) -> np.ndarray:
+    """The ``half`` rotary frequencies under YaRN: frequency i is
+    ``theta^(-i/half)`` where it turns more than ``beta_fast`` times over
+    the original context, that over ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear ramp between the two in between."""
+    dim = 2 * half
+    plain = theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def index_of(turns: float) -> float:
+        return dim * math.log(
+            yarn.original_max_len / (turns * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(index_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(index_of(yarn.beta_slow)), dim - 1)
+    ramp = np.clip(
+        (np.arange(half, dtype=np.float64) - low)
+        / (high - low if high > low else 0.001), 0.0, 1.0,
+    )
+    return (plain / yarn.factor * ramp + plain * (1.0 - ramp)).astype(
+        np.float32
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,14 +133,23 @@ class TransformerConfig:
     first_expert: int = 0            # experts [first, first + held) are here
     experts_held: Optional[int] = None      # None = all n_experts
     moe_loss_weights: Tuple[float, float] = (1e-2, 1e-3)   # balance, z
+    shared_experts: int = 0          # of d_expert each, beside the routed
     # Layer i as "<mixer>" or "<mixer>:<ffn>": the mixer is "attention" |
-    # "mamba" | "conv", the FFN kind one of ``ffn``'s and ``ffn`` itself
-    # where the entry names none. None = attention and ``ffn`` everywhere.
+    # "mamba" | "conv" | "latent", the FFN kind one of ``ffn``'s and ``ffn``
+    # itself where the entry names none. None = attention and ``ffn``
+    # everywhere.
     layer_types: Optional[Tuple[str, ...]] = None
+    # A mixer's or the residual path's own sizes, a record each:
+    # ``models/latent.LatentConfig`` for the "latent" mixer,
+    # ``models/hyperconn.HyperConfig`` for more than one residual stream
+    # (None = the plain ``x + F(norm(x))``).
+    latent: Any = None
+    hyper: Any = None
     conv_taps: int = 3               # the "conv" mixer (models/shortconv.py)
     n_kv_heads: Optional[int] = None       # None = n_heads (no grouping)
     attention_scale: Optional[float] = None    # None = head_dim ** -0.5
     tie_head: bool = False           # CausalLM's logits from tok_embed
+    embed_init_std: float = 0.02     # of tok_embed's table at init
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0      # logits are DIVIDED by it
@@ -128,7 +184,7 @@ class TransformerConfig:
             tuple((entry.split(":", 1) + [self.ffn])[:2]) for entry in entries
         )
         if (len(layers) != self.n_layers
-                or {m for m, _ in layers} - {"attention", "mamba", "conv"}
+                or {m for m, _ in layers} - MIXERS
                 or {f for _, f in layers} - {"gelu", "swiglu", "moe"}):
             raise ValueError(
                 f"layer_types {entries!r} does not name the mixers (and FFN "
@@ -165,6 +221,7 @@ class TransformerConfig:
             scoring=self.router_scoring, selection_bias=self.router_bias,
             normalize_gates=self.norm_top_k, gate_scale=self.routed_scaling,
             first_expert=self.first_expert, held_experts=self.experts_held,
+            shared_experts=self.shared_experts,
             dtype=self.dtype, param_dtype=self.param_dtype,
         )
 
@@ -175,9 +232,9 @@ def _dense_init(*logical_axes: str):
     )
 
 
-def _embed_init(*logical_axes: str):
+def _embed_init(*logical_axes: str, std: float = 0.02):
     return nn.with_logical_partitioning(
-        nn.initializers.normal(stddev=0.02), logical_axes
+        nn.initializers.normal(stddev=std), logical_axes
     )
 
 
@@ -194,14 +251,25 @@ def _norm(cfg: TransformerConfig, name: str, dtype=None) -> nn.Module:
     )
 
 
-def rotary(x, positions, theta: float):
+def rotary(x, positions, theta: float, yarn: Optional[YarnScaling] = None):
     """Rotary position embedding (Su et al. 2021) in the half-split form
     of the published OLMoE/NeoX code: feature i pairs with i + D/2.
-    ``x`` [B, S, H, D], ``positions`` [B or 1, S]; float32 inside."""
+    ``x`` [B, S, H, D], ``positions`` [B or 1, S]; float32 inside. With
+    ``yarn`` the frequencies are YaRN's blend (:func:`yarn_inv_freq`) and
+    the rotation is scaled by ``m(mscale) / m(mscale_all_dim)``."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv_freq = jnp.asarray(yarn_inv_freq(half, theta, yarn))
     angle = positions.astype(jnp.float32)[..., None] * inv_freq
     cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    if yarn is not None:
+        stretch = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
+            yarn.factor, yarn.mscale_all_dim
+        )
+        if stretch != 1.0:
+            cos, sin = cos * stretch, sin * stretch
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
         jnp.float32
     )
@@ -381,11 +449,14 @@ class TransformerBlock(nn.Module):
     positions, QK-norm and biases come from the configuration, the mixer
     and the kind of FFN from the stack's per-layer pattern: BERT's encoder
     block, a routed decoder block, both layers of a hybrid state-space
-    stack and the dense and routed layers of a short-convolution hybrid
-    are the same code."""
+    stack, the dense and routed layers of a short-convolution hybrid and
+    those of a latent-attention stack are the same code. With
+    ``cfg.hyper`` the block carries ``[B, n, S, D]`` streams and each of
+    its two sublayers reads and writes them through its own mappings
+    (``models/hyperconn.py``; scopes ``hc_attn`` and ``hc_ffn``)."""
 
     cfg: TransformerConfig
-    mixer: str = "attention"         # attention | mamba | conv
+    mixer: str = "attention"         # attention | mamba | conv | latent
     ffn: Optional[str] = None        # None = cfg.ffn
 
     @nn.compact
@@ -405,76 +476,100 @@ class TransformerBlock(nn.Module):
                 return branch
             return branch * cfg.residual_multiplier
 
-        if self.mixer == "mamba":
-            from raydp_tpu.models.mamba import Mamba2Mixer
-
-            if cache_mode is not None:
-                raise NotImplementedError(
-                    "no decode cache for a state-space layer's state"
+        def mix(h):
+            """The layer's mixer on its own norm of ``h``."""
+            if self.mixer == "attention":
+                return MultiHeadAttention(cfg, name="attn")(
+                    _norm(cfg, "ln_attn")(h),
+                    deterministic,
+                    cache_mode=cache_mode,
+                    cache_positions=cache_positions,
+                    kv_len=kv_len,
                 )
-            x = x + scaled(
-                Mamba2Mixer(cfg, name="mamba")(_norm(cfg, "ln_mamba")(x))
-            )
-        elif self.mixer == "conv":
-            from raydp_tpu.models.shortconv import ShortConv
-
             if cache_mode is not None:
-                raise NotImplementedError(
-                    "no decode cache for a short convolution's last tokens"
-                )
-            x = x + scaled(
-                ShortConv(cfg, name="conv")(_norm(cfg, "ln_conv")(x))
-            )
-        else:
-            x = x + scaled(MultiHeadAttention(cfg, name="attn")(
-                _norm(cfg, "ln_attn")(x),
-                deterministic,
-                cache_mode=cache_mode,
-                cache_positions=cache_positions,
-                kv_len=kv_len,
-            ))
+                raise NotImplementedError({
+                    "mamba": "no decode cache for a state-space layer's state",
+                    "conv": "no decode cache for a short convolution's last "
+                            "tokens",
+                    "latent": "no decode cache for the key-value latent "
+                              "(ROADMAP R3)",
+                }[self.mixer])
+            if self.mixer == "mamba":
+                from raydp_tpu.models.mamba import Mamba2Mixer
 
-        dense = functools.partial(
-            nn.Dense, use_bias=cfg.use_bias, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
+                return Mamba2Mixer(cfg, name="mamba")(
+                    _norm(cfg, "ln_mamba")(h)
+                )
+            if self.mixer == "conv":
+                from raydp_tpu.models.shortconv import ShortConv
+
+                return ShortConv(cfg, name="conv")(_norm(cfg, "ln_conv")(h))
+            from raydp_tpu.models.latent import LatentAttention
+
+            return LatentAttention(cfg, name="attn")(_norm(cfg, "ln_attn")(h))
+
+        def feed(h):
+            """The layer's FFN on its own norm of ``h``."""
+            dense = functools.partial(
+                nn.Dense, use_bias=cfg.use_bias, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+            )
+            ffn = self.ffn or cfg.ffn
+            if ffn == "moe":
+                from raydp_tpu.models.moe import MoELayer
+
+                # The norm's output stays float32 for the router: one bf16
+                # rounding less between near-equal experts. The experts get
+                # it in the compute dtype.
+                y = _norm(cfg, "ln_mlp", jnp.float32)(h)
+                y = MoELayer(cfg.moe_config(), name="moe")(y)
+            elif ffn == "gelu":
+                y = _norm(cfg, "ln_mlp")(h)
+                y = dense(
+                    cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
+                    name="mlp_up",
+                )(y)
+                y = nn.gelu(y)
+                y = dense(
+                    cfg.d_model, kernel_init=_dense_init("mlp", "embed"),
+                    name="mlp_down",
+                )(y)
+            elif ffn == "swiglu":
+                # Dense gated MLP, one fused input projection: [gate, up].
+                y = _norm(cfg, "ln_mlp")(h)
+                gate, up = jnp.split(dense(
+                    2 * cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
+                    name="mlp_in",
+                )(y), 2, axis=-1)
+                y = dense(
+                    cfg.d_model, kernel_init=_dense_init("mlp", "embed"),
+                    name="mlp_out",
+                )(nn.silu(gate) * up)
+            else:
+                raise ValueError(f"unknown ffn {ffn!r}")
+            if cfg.dropout_rate > 0:
+                y = Dropout(cfg.dropout_rate)(y, deterministic)
+            return y
+
+        if cfg.hyper is None:
+            x = x + scaled(mix(x))
+            x = x + scaled(feed(x))
+            return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+
+        from raydp_tpu.models import hyperconn
+
+        for name, sublayer in (("hc_attn", mix), ("hc_ffn", feed)):
+            maps = hyperconn.HyperMaps(
+                cfg.hyper, cfg.norm_eps, cfg.param_dtype, name=name
+            )(x)
+            with jax.named_scope(name), jax.named_scope("pre"):
+                h = hyperconn.read(x, maps)
+            y = scaled(sublayer(h))
+            with jax.named_scope(name), jax.named_scope("post"):
+                x = hyperconn.write(x, y, maps)
+        return nn.with_logical_constraint(
+            x, ("batch", None, "seq", "embed")
         )
-        ffn = self.ffn or cfg.ffn
-        if ffn == "moe":
-            from raydp_tpu.models.moe import MoELayer
-
-            # The norm's output stays float32 for the router: one bf16
-            # rounding less between near-equal experts. The experts get
-            # it in the compute dtype.
-            y = _norm(cfg, "ln_mlp", jnp.float32)(x)
-            y = MoELayer(cfg.moe_config(), name="moe")(y)
-        elif ffn == "gelu":
-            y = _norm(cfg, "ln_mlp")(x)
-            y = dense(
-                cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
-                name="mlp_up",
-            )(y)
-            y = nn.gelu(y)
-            y = dense(
-                cfg.d_model, kernel_init=_dense_init("mlp", "embed"),
-                name="mlp_down",
-            )(y)
-        elif ffn == "swiglu":
-            # Dense gated MLP, one fused input projection: [gate, up].
-            y = _norm(cfg, "ln_mlp")(x)
-            gate, up = jnp.split(dense(
-                2 * cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
-                name="mlp_in",
-            )(y), 2, axis=-1)
-            y = dense(
-                cfg.d_model, kernel_init=_dense_init("mlp", "embed"),
-                name="mlp_out",
-            )(nn.silu(gate) * up)
-        else:
-            raise ValueError(f"unknown ffn {ffn!r}")
-        if cfg.dropout_rate > 0:
-            y = Dropout(cfg.dropout_rate)(y, deterministic)
-        x = x + scaled(y)
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
 
 class TransformerEncoder(nn.Module):
@@ -501,7 +596,9 @@ class TransformerEncoder(nn.Module):
         cfg = self.cfg
         x = nn.Embed(
             cfg.vocab_size, cfg.d_model,
-            embedding_init=_embed_init("vocab", "embed"),
+            embedding_init=_embed_init(
+                "vocab", "embed", std=cfg.embed_init_std
+            ),
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="tok_embed",
         )(input_ids)
         if cfg.embedding_multiplier != 1.0:
@@ -542,6 +639,11 @@ class TransformerEncoder(nn.Module):
             if cfg.remat
             else TransformerBlock
         )
+        if cfg.hyper is not None:
+            from raydp_tpu.models import hyperconn
+
+            with jax.named_scope("hc_expand"):
+                x = hyperconn.expand(x, cfg.hyper.streams)
         for i, (mixer, ffn) in enumerate(cfg.layers):
             x = block_cls(cfg, mixer, ffn, name=f"block_{i}")(
                 x,
@@ -550,6 +652,9 @@ class TransformerEncoder(nn.Module):
                 cache_positions=cache_positions,
                 kv_len=kv_len,
             )
+        if cfg.hyper is not None:
+            with jax.named_scope("hc_reduce"):
+                x = hyperconn.reduce(x)
         return _norm(cfg, "ln_final")(x)
 
 
@@ -807,6 +912,54 @@ def lfm2_8b_a1b(**overrides) -> TransformerConfig:
             ("attention" if i in attention else "conv")
             + (":swiglu" if i < 2 else ":moe") for i in range(n_layers)
         ),
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def xing4_0(**overrides) -> TransformerConfig:
+    """Xing4.0-29B-A4B (29.5B parameters, about 4B active; ``config.json``
+    of XingChen-AGI/Xing4.0-29B-A4B, ``model_type`` xing4_0): 40 pre-norm
+    layers of width 3584 whose residual path is four streams mixed by
+    Sinkhorn-projected mappings (``hc_mult`` 4, 20 rounds); latent
+    attention in every layer (32 heads of 128 + 64 for q and k and 128 for
+    v from latents of 768 and 512, one shared rotary key, YaRN x 64 over
+    4,096); a dense SwiGLU FFN of width 9216 in the first two layers and
+    64 SwiGLU experts of width 1024 beside one shared expert in the
+    others, 4 a token by sigmoid score + ``e_score_correction_bias``,
+    their scores divided by their sum and times 2; RMSNorm, no biases, no
+    auxiliary loss; vocabulary 131072, untied head. The multi-token
+    prediction module is not built (ROADMAP R10). ``experts_held`` /
+    ``first_expert`` give a layer the share of an expert-parallel
+    deployment; ``n_layers`` and ``dense_layers`` keep the model's own
+    first layers."""
+    from raydp_tpu.models.hyperconn import HyperConfig
+    from raydp_tpu.models.latent import LatentConfig
+
+    overrides = dict(overrides)
+    n_layers = overrides.get("n_layers", 40)
+    dense = overrides.pop("dense_layers", 2)
+    defaults = dict(
+        vocab_size=131072, d_model=3584, n_heads=32, n_layers=n_layers,
+        d_ff=9216, max_len=262144, dropout_rate=0.0, causal=True,
+        norm="rmsnorm", norm_eps=1e-6, positions="rotary",
+        rope_theta=10000.0, use_bias=False, ffn="moe", n_experts=64,
+        top_k=4, d_expert=1024, shared_experts=1, router_scoring="sigmoid",
+        router_bias=True, norm_top_k=True, routed_scaling=2.0,
+        moe_loss_weights=(0.0, 0.0), tie_head=False,
+        layer_types=tuple(
+            "latent" + (":swiglu" if i < dense else ":moe")
+            for i in range(n_layers)
+        ),
+        latent=LatentConfig(
+            q_rank=768, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+            yarn=YarnScaling(
+                factor=64.0, original_max_len=4096, beta_fast=32.0,
+                beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0,
+            ),
+        ),
+        hyper=HyperConfig(streams=4, sinkhorn_iters=20, eps=1e-6,
+                          clamp=(-30.0, 30.0)),
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

@@ -49,7 +49,7 @@ def reference_attention(
         out = _grouped_attention(
             q.reshape(b, s_q, h_kv, h // h_kv, d), k, v, causal, scale
         )
-        return out.reshape(b, s_q, h, d)
+        return out.reshape(b, s_q, h, v.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         scores = jnp.where(_causal_mask(scores), scores, -jnp.inf)

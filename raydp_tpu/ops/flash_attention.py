@@ -22,6 +22,11 @@ per-query-head dk/dv summed by XLA afterwards, writes and re-reads
 ``group`` times the bytes for nothing. With ``group == 1`` every grid and
 index map is the one it was before grouping existed.
 
+Two head widths (latent attention: ``q`` and ``k`` 192 wide, ``v`` 128):
+the q, k, dq and dk tiles and accumulators are as wide as ``q``, the v,
+output, cotangent and dv ones as wide as ``v``; nothing else knows. With
+one width every BlockSpec and scratch shape is the one it was.
+
 What a kernel does per score element is what its tile needs. Under a
 causal mask a [block_q, block_kv] tile is one of three kinds, told apart
 from the grid indices alone: DEAD (no key at or before any of its queries:
@@ -122,15 +127,18 @@ def report(cfg, seq_len: int) -> None:
     a model that never calls the kernel."""
     from raydp_tpu.utils.profiling import metrics
 
-    layers = live = masked = 0
+    layers = live = masked = latent = 0
     if getattr(cfg, "attention_impl", None) == "flash":
-        layers = cfg.kinds.count("attention")
+        latent = cfg.kinds.count("latent")
+        layers = cfg.kinds.count("attention") + latent
     if layers:
         live, masked = tile_counts(seq_len, causal=cfg.causal)
     metrics.gauge_set("attention/flash_live_tiles", live)
     metrics.gauge_set("attention/flash_masked_tiles", masked)
     if layers:
-        scale = _scale(cfg.attention_scale, cfg.head_dim)
+        scale = cfg.latent.softmax_scale if latent else _scale(
+            cfg.attention_scale, cfg.head_dim
+        )
         block = _block(None, seq_len)
         logger.info(
             "flash attention: %d layers, S = %d in %d x %d tiles, %d live a "
@@ -331,15 +339,19 @@ def flash_attention(
     interpret: bool = False,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """``q`` [B, S, H, D], ``k`` and ``v`` [B, S, Hkv, D] with H a multiple
-    of Hkv → [B, S, H, D]. S must divide by the blocks; a block left out
-    is the largest of 1024, 512, 256, 128 that divides S. ``scale`` is the
-    softmax scale, ``D ** -0.5`` when left out.
+    """``q`` [B, S, H, D], ``k`` [B, S, Hkv, D] and ``v`` [B, S, Hkv, Dv]
+    with H a multiple of Hkv → [B, S, H, Dv]. ``Dv`` need not be ``D``
+    (latent attention: 192-wide q and k, 128-wide v): the q, k, dq and dk
+    tiles are ``D`` wide, the v, output, cotangent and dv tiles ``Dv``;
+    with ``Dv == D`` every tile is the one it was. S must divide by the
+    blocks; a block left out is the largest of 1024, 512, 256, 128 that
+    divides S. ``scale`` is the softmax scale, ``D ** -0.5`` when left out.
 
     Differentiable via custom_vjp; forward AND backward are blockwise
     pallas kernels (no S×S materialization anywhere)."""
     s, d = q.shape[1], q.shape[3]
-    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+    if (q.shape[2] % k.shape[2] or k.shape[:3] != v.shape[:3]
+            or k.shape[3] != d):
         raise ValueError(
             f"{q.shape[2]} query heads over key-value shapes {k.shape}, "
             f"{v.shape}"
@@ -422,7 +434,7 @@ def _kv_head(group: int):
 def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
     qt, kt, vt, out_t, lse = res
     b, h, s, d = qt.shape
-    h_kv = kt.shape[1]
+    h_kv, d_v = kt.shape[1], vt.shape[3]
     group = h // h_kv
     kv_of = _kv_head(group)
 
@@ -435,8 +447,14 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
     q_spec = pl.BlockSpec(
         (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
     )
-    kv_spec = pl.BlockSpec(
+    g_spec = pl.BlockSpec(
+        (1, 1, block_q, d_v), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+    )
+    k_spec = pl.BlockSpec(
         (1, 1, block_kv, d), lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0)
+    )
+    v_spec = pl.BlockSpec(
+        (1, 1, block_kv, d_v), lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0)
     )
     row_spec = pl.BlockSpec(
         (1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
@@ -448,7 +466,7 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), qt.dtype),
         grid=(b, h, s // block_q, s // block_kv),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
@@ -466,9 +484,10 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
             bi, hi * group + step // q_tiles, step % q_tiles, 0
         )
     q_spec_t = pl.BlockSpec((1, 1, block_q, d), q_at)
-    kv_spec_t = pl.BlockSpec(
-        (1, 1, block_kv, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)
-    )
+    g_spec_t = pl.BlockSpec((1, 1, block_q, d_v), q_at)
+    kv_at = lambda bi, hi, ki, qi: (bi, hi, ki, 0)  # noqa: E731
+    k_spec_t = pl.BlockSpec((1, 1, block_kv, d), kv_at)
+    v_spec_t = pl.BlockSpec((1, 1, block_kv, d_v), kv_at)
     # lse and delta as rows [B, H, 1, S] for the dk/dv kernel's transposed
     # tiles (1 MB a call to lay out again; the tiles are 4 MB each).
     def row_at(*at):
@@ -483,17 +502,17 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, h_kv, s, d), kt.dtype),
-            jax.ShapeDtypeStruct((b, h_kv, s, d), vt.dtype),
+            jax.ShapeDtypeStruct((b, h_kv, s, d_v), vt.dtype),
         ),
         grid=(b, h_kv, s // block_kv, group * q_tiles),
         in_specs=[
-            q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
+            q_spec_t, k_spec_t, v_spec_t, g_spec_t, row_spec_t,
             row_spec_t,
         ],
-        out_specs=(kv_spec_t, kv_spec_t),
+        out_specs=(k_spec_t, v_spec_t),
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
+            pltpu.VMEM((block_kv, d_v), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt, gt, jnp.swapaxes(lse, 2, 3), jnp.swapaxes(delta, 2, 3))
@@ -516,6 +535,7 @@ def _flash_forward(
     scale: float,
 ):
     b, s, h, d = q.shape
+    d_v = v.shape[3]
     kv_of = _kv_head(h // k.shape[2])
     if s % block_q or s % block_kv:
         raise ValueError(f"seq len {s} not divisible by blocks "
@@ -537,7 +557,7 @@ def _flash_forward(
     out, lse = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ),
         grid=grid,
@@ -550,13 +570,13 @@ def _flash_forward(
                 lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_kv, d),
+                (1, 1, block_kv, d_v),
                 lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0),
             ),
         ],
         out_specs=(
             pl.BlockSpec(
-                (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+                (1, 1, block_q, d_v), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
             ),
             pl.BlockSpec(
                 (1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
@@ -565,7 +585,7 @@ def _flash_forward(
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt)

@@ -28,7 +28,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raydp_tpu import fault as _fault
 from raydp_tpu.data.ml_dataset import MLDataset
-from raydp_tpu.models import dropout, mamba, moe, shortconv
+from raydp_tpu.models import (
+    dropout,
+    hyperconn,
+    latent,
+    mamba,
+    moe,
+    shortconv,
+    stats,
+)
 from raydp_tpu.ops.flash_attention import report as report_flash_tiles
 from raydp_tpu.parallel.mesh import MeshSpec
 from raydp_tpu.telemetry import accounting as _acct
@@ -322,7 +330,7 @@ class JAXEstimator:
                 variables = {
                     k: v
                     for k, v in variables.items()
-                    if k not in ("losses", "intermediates", moe.STATS)
+                    if k not in ("losses", "intermediates", stats.STATS)
                 }
             return TrainState.create(
                 apply_fn=model.apply, params=variables, tx=tx
@@ -353,8 +361,8 @@ class JAXEstimator:
     def _make_train_step(self):
         """The (state, x, y, rng) → (state, loss, grad norm, stats) step
         shared by the stream and scan paths. ``stats`` is what the model
-        sowed about the step besides its loss (``models/moe.step_stats``:
-        the tokens each expert received), ``{}`` for most models; the
+        sowed about the step besides its loss (``models/stats.py``: the
+        tokens each expert received, say), ``{}`` for most models; the
         stream loop sums it on the device and fetches it with the epoch's
         loss."""
         loss_fn = self._loss_fn
@@ -376,17 +384,17 @@ class JAXEstimator:
             kwargs = apply_kwargs(rng)
             if use_aux:
                 preds, mut = state.apply_fn(
-                    variables, x, mutable=["losses", moe.STATS], **kwargs
+                    variables, x, mutable=["losses", stats.STATS], **kwargs
                 )
                 with jax.named_scope("part:loss"):
                     loss = loss_fn(preds, target) + moe.moe_aux_loss(mut)
-                return loss, moe.step_stats(mut)
+                return loss, moe.with_aux_loss(stats.step_stats(mut), mut)
             preds = state.apply_fn(variables, x, **kwargs)
             with jax.named_scope("part:loss"):
                 return loss_fn(preds, target), {}
 
         def train_step(state: TrainState, x, y, rng):
-            (loss_val, stats), grads = jax.value_and_grad(
+            (loss_val, sown), grads = jax.value_and_grad(
                 lambda params: loss_of(state, params, x, y, rng),
                 has_aux=True,
             )(state.params)
@@ -408,7 +416,7 @@ class JAXEstimator:
                 new = new.replace(params={
                     **new.params, moe.BUFFERS: state.params[moe.BUFFERS]
                 })
-            return new, loss_val, gnorm, stats
+            return new, loss_val, gnorm, sown
 
         choose = self._row_path()
         if choose is None:
@@ -482,6 +490,8 @@ class JAXEstimator:
             getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
         )
         shortconv.report(getattr(self._model, "cfg", None))
+        latent.report(getattr(self._model, "cfg", None))
+        hyperconn.report(getattr(self._model, "cfg", None))
         report_flash_tiles(
             getattr(self._model, "cfg", None),
             seq_len=self._sample_batch.shape[-1],
@@ -874,7 +884,7 @@ class JAXEstimator:
                             try:
                                 (
                                     self._state, loss_val, grad_norm,
-                                    stats,
+                                    sown,
                                 ) = self._train_step(
                                     self._state, xd, yd, step_rng
                                 )
@@ -917,9 +927,9 @@ class JAXEstimator:
                     loss_sum = (
                         loss_val if loss_sum is None else loss_sum + loss_val
                     )
-                    if stats:
-                        stats_sum = stats if stats_sum is None else (
-                            jax.tree_util.tree_map(jnp.add, stats_sum, stats)
+                    if sown:
+                        stats_sum = sown if stats_sum is None else (
+                            stats.merge(stats_sum, sown)
                         )
                     n_batches += 1
                     b_idx += 1
@@ -955,7 +965,9 @@ class JAXEstimator:
                     loss_sum is not None
                 ) else 0.0
                 if stats_sum is not None:
-                    moe.report_epoch(jax.device_get(stats_sum), n_batches)
+                    stats_sum = jax.device_get(stats_sum)
+                    moe.report_epoch(stats_sum, n_batches)
+                    hyperconn.report_epoch(stats_sum)
             # Epoch boundary always checks (the sampled cadence may
             # never have landed on a NaN step in a short epoch).
             sentinel.check_loss(train_loss, b_idx, epoch=epoch)

@@ -340,6 +340,67 @@ def test_flash_causal_tiles_of_every_kind_match_reference(blocks, group,
         )
 
 
+# -- q and k of one width, v of another (latent attention, PR 36) -----------
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("group", [1, 2])
+@pytest.mark.parametrize("widths", [(192, 128), (24, 16), (16, 48)],
+                         ids=lambda w: f"qk{w[0]}_v{w[1]}")
+def test_flash_with_two_head_widths_matches_reference(widths, group, causal):
+    """``q`` and ``k`` ``d_qk`` wide, ``v`` and the output ``d_v``: forward
+    and all three gradients against dense attention, with dead, whole and
+    crossed tiles (S = 128 in 32-wide tiles)."""
+    d_qk, d_v = widths
+    rng = np.random.default_rng(36)
+    mk = lambda h, d: jnp.asarray(  # noqa: E731
+        rng.standard_normal((1, 128, h, d)), jnp.float32)
+    q, k, v, w = mk(2, d_qk), mk(2 // group, d_qk), mk(2 // group, d_v), mk(
+        2, d_v)
+    scale = d_qk ** -0.5 * 1.4159 ** 2
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               block_q=32, block_kv=32, interpret=True)
+
+    def plain(q, k, v):
+        return reference_attention(q, k, v, causal=causal, scale=scale)
+
+    out = flash(q, k, v)
+    assert out.shape == (1, 128, 2, d_v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(plain(q, k, v)), rtol=1e-4, atol=1e-5)
+    grads = lambda fn: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    for got, want, name in zip(grads(flash), grads(plain), "qkv"):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-3, atol=1e-4,
+            err_msg=f"d{name} mismatch")
+
+
+def test_flash_with_equal_widths_is_the_call_it_was():
+    """``d_qk = d_v``: the jaxpr of forward and backward is the one a call
+    with no notion of a second width traces (every tile ``d`` wide)."""
+    q = jnp.zeros((1, 128, 2, 16), jnp.float32)
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal=True, block_q=32, block_kv=32,
+                        interpret=True)), argnums=(0, 1, 2)))(q, q, q))
+    assert "f32[1,2,128,16]" in text and ",24]" not in text
+    assert text.count("pallas_call") == 3       # forward, dq, dk/dv
+
+
+@pytest.mark.parametrize("q_shape,k_shape,v_shape", [
+    ((1, 64, 2, 24), (1, 64, 2, 16), (1, 64, 2, 16)),   # k not as wide as q
+    ((1, 64, 2, 24), (1, 64, 2, 24), (1, 64, 1, 16)),   # v of other heads
+    ((1, 64, 3, 24), (1, 64, 2, 24), (1, 64, 2, 16)),   # heads do not group
+])
+def test_flash_refuses_shapes_that_do_not_belong_together(q_shape, k_shape,
+                                                          v_shape):
+    with pytest.raises(ValueError, match="query heads"):
+        flash_attention(jnp.zeros(q_shape), jnp.zeros(k_shape),
+                        jnp.zeros(v_shape), interpret=True)
+
+
 @pytest.mark.parametrize("s,block_q,block_kv", [
     (256, 32, 32), (256, 32, 64), (256, 64, 32), (384, 128, 32),
     (512, 64, 256), (96, 32, 96), (128, 128, 128),

@@ -31,6 +31,7 @@ from raydp_tpu.models import (
     olmoe,
 )
 from raydp_tpu.models import moe as moe_module
+from raydp_tpu.models import stats as stats_module
 from raydp_tpu.models.mamba import CausalConv1d, causal_depthwise_conv
 from raydp_tpu.models.shortconv import ShortConv
 from raydp_tpu.models.transformer import MultiHeadAttention, rotary
@@ -576,8 +577,11 @@ def test_olmoes_layer_is_the_layer_it_was():
     variables = layer.init(jax.random.PRNGKey(8), x)
     assert set(variables) == {"params", "losses", moe_module.STATS}
     assert set(variables[moe_module.STATS]) == {"expert_tokens"}
-    stats = moe_module.step_stats(variables)
-    assert set(stats) == {"aux_loss", "expert_tokens"}
+    sown = stats_module.step_stats(variables)
+    assert set(sown) == {"expert_tokens"}
+    assert set(moe_module.with_aux_loss(sown, variables)) == {
+        "aux_loss", "expert_tokens"}
+    assert moe_module.with_aux_loss({}, variables) == {}
 
 
 # ------------------------------------------------- the per-layer pattern
