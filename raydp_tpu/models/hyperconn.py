@@ -21,8 +21,9 @@ the signal up nor kill it. Per sublayer, for ``x`` ``[T, n, D]``:
 ``SK`` is ``iters`` rounds of "each row over (its sum + eps), then each
 column over (its sum + eps)". The three ``phi`` are ONE ``[n, D, 2n + n²]``
 parameter (columns pre, post, res; the same mathematics, one pass over
-``x``), ``alpha`` ``[3]`` and ``bias`` ``[2n + n²]``; everything here is
-float32 whatever the trunk's dtype, and the streams travel in the trunk's.
+``x``), ``alpha`` ``[3]`` and ``bias`` ``[2n + n²]``; the parameters and
+the mappings are float32 whatever the trunk's dtype, and the streams travel
+in the trunk's.
 
 Layout: the streams are ``[B, n, S, D]`` (streams before the sequence) and
 the mappings ``[2n + n², B, S]`` (a mapping's entries before the tokens): a
@@ -31,7 +32,17 @@ the chip's memory, 4 to 16 sublanes in bfloat16.
 
 :class:`HyperMaps` computes the mappings (scopes ``<name>/maps`` and
 ``<name>/sinkhorn``); :func:`read` and :func:`write` are the two mixings
-(``<name>/pre``, ``<name>/post``). How far ``H_res`` is from doubly
+(``<name>/pre``, ``<name>/post``). Each mixing is one differentiable
+operation whose forward and whose backward pass ONCE over the streams in
+their own dtype (``ops/stream_mix.py``: Pallas kernels over blocks of
+tokens): products and sums are float32 inside a block's registers, an
+output is rounded once where it is stored, and no float32 array of a
+stream's size, of a cotangent's or of an accumulator's reaches HBM. That
+path is taken where the kernels can tile what they are given
+(:func:`one_pass`: a floating carrier, a feature width that is a multiple of
+128); anything else runs :func:`plain_read` and :func:`plain_write`, the
+formula above written out in ``jax.numpy``, which are also what the tests
+hold the kernels to. How far ``H_res`` is from doubly
 stochastic after its rounds is sown as ``hc_res_err_max`` into the step's
 statistics (``models/stats.py``; the largest over sublayers and steps) and
 reaches the gauge ``hc/res_row_sum_err_max`` with the epoch's loss
@@ -48,8 +59,10 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from raydp_tpu.models import stats
+from raydp_tpu.ops import stream_mix
 
 logger = logging.getLogger(__name__)
 
@@ -196,22 +209,59 @@ def _per_stream(m):
     return jnp.moveaxis(m, 0, 1)[..., None]
 
 
-def read(x, maps: Maps):
-    """``h = sum_i H_pre[i] x[:, i]``: ``[B, n, S, D]`` to ``[B, S, D]``
-    in ``x``'s dtype, float32 inside."""
+def plain_read(x, maps: Maps):
+    """:func:`read` as the formula is written, float32 arrays throughout:
+    the reference, and the path of streams the kernels cannot tile."""
     h = jnp.sum(x.astype(jnp.float32) * _per_stream(maps.pre), axis=1)
     return h.astype(x.dtype)
 
 
-def write(x, y, maps: Maps):
-    """``x'[:, i] = sum_j H_res[i, j] x[:, j] + H_post[i] y`` in ``x``'s
-    dtype, float32 inside; one term a source stream, so that no
-    ``[B, n, n, S, D]`` array exists."""
+def plain_write(x, y, maps: Maps):
+    """:func:`write` as the formula is written (one term a source stream,
+    so that no ``[B, n, n, S, D]`` array exists), float32 arrays
+    throughout: the reference, and the path of streams the kernels cannot
+    tile."""
     x32 = x.astype(jnp.float32)
     out = _per_stream(maps.post) * y.astype(jnp.float32)[:, None]
     for j in range(x.shape[1]):
         out = out + _per_stream(maps.res[:, j]) * x32[:, j][:, None]
     return out.astype(x.dtype)
+
+
+def one_pass(dtype, width: int) -> bool:
+    """Whether streams of this dtype and feature width are mixed by the
+    one-pass kernels (``ops/stream_mix.tileable``)."""
+    return stream_mix.tileable(dtype, width)
+
+
+def _columns(*mappings):
+    """Mappings ``[..., B, S]`` side by side as the kernels' per-token
+    columns ``[B, S, k]``, in the order given (a matrix row by row)."""
+    stacked = jnp.concatenate([
+        m.reshape((-1,) + m.shape[-2:]).astype(jnp.float32)
+        for m in mappings
+    ])
+    # The transposition happens HERE: left free, the compiler gives the
+    # stacked mappings the columns' layout and pays for it with a copy in
+    # every Sinkhorn round that made them (PERF.md §6, PR 37).
+    stacked = with_layout_constraint(stacked, Layout((0, 1, 2)))
+    return jnp.moveaxis(stacked, 0, -1)
+
+
+def read(x, maps: Maps):
+    """``h = sum_i H_pre[i] x[:, i]``: ``[B, n, S, D]`` to ``[B, S, D]``
+    in ``x``'s dtype; float32 products and sums, one rounding."""
+    if not one_pass(x.dtype, x.shape[-1]):
+        return plain_read(x, maps)
+    return stream_mix.read(x, _columns(maps.pre))
+
+
+def write(x, y, maps: Maps):
+    """``x'[:, i] = sum_j H_res[i, j] x[:, j] + H_post[i] y`` in ``x``'s
+    dtype; float32 products and sums, one rounding."""
+    if not one_pass(x.dtype, x.shape[-1]):
+        return plain_write(x, y, maps)
+    return stream_mix.write(x, y, _columns(maps.post, maps.res))
 
 
 def expand(x, streams: int):
@@ -225,25 +275,35 @@ def reduce(x):
 
 
 def report(cfg) -> None:
-    """Static for a compiled step: three gauges and one log line where the
+    """Static for a compiled step: four gauges and one log line where the
     step is built (as ``models/mamba.report``). Zero for a stack with one
-    residual stream."""
+    residual stream; ``hc/one_pass_sublayers`` counts the sublayers whose
+    mixings the kernels take (all of them or none: the carrier's dtype and
+    width are the stack's)."""
     from raydp_tpu.utils.profiling import metrics
 
     hyper = getattr(cfg, "hyper", None)
+    sublayers = SUBLAYERS_PER_LAYER * cfg.n_layers if hyper else 0
+    kernels = bool(hyper) and one_pass(cfg.dtype, cfg.d_model)
     metrics.gauge_set("hc/streams", hyper.streams if hyper else 0)
     metrics.gauge_set(
         "hc/sinkhorn_iters", hyper.sinkhorn_iters if hyper else 0
     )
-    metrics.gauge_set(
-        "hc/sublayers", SUBLAYERS_PER_LAYER * cfg.n_layers if hyper else 0
-    )
+    metrics.gauge_set("hc/sublayers", sublayers)
+    metrics.gauge_set("hc/one_pass_sublayers", sublayers if kernels else 0)
     if hyper:
+        carrier = jnp.dtype(cfg.dtype).name
+        path = (
+            f"in one pass over the {carrier} streams, forward and backward "
+            "(Pallas kernels)" if kernels else
+            f"as float32 arrays (the plain formula: no kernel tiles {carrier} "
+            f"streams of width {cfg.d_model})"
+        )
         logger.info(
             "residual path: %d streams around each of %d sublayers, mixed "
-            "by per-token mappings in float32; H_res through %d "
+            "by per-token float32 mappings %s; H_res through %d "
             "Sinkhorn-Knopp rounds (eps %g, clamp %s)",
-            hyper.streams, SUBLAYERS_PER_LAYER * cfg.n_layers,
+            hyper.streams, sublayers, path,
             hyper.sinkhorn_iters, hyper.eps, list(hyper.clamp),
         )
 

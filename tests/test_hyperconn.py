@@ -2,7 +2,12 @@
 sizes on the CPU, float32: Sinkhorn-Knopp's result is doubly stochastic
 and the clamp holds, the mappings and both mixings against plain numpy
 written per token, the streams' start and end, a bfloat16 carrier against
-float32, the gauges, and the older families' blocks untouched."""
+float32, the gauges, and the older families' blocks untouched; the one-pass
+kernels of both mixings (``ops/stream_mix.py``, in the Pallas interpreter)
+against the plain formula and its gradients, and a compiled step that holds
+no float32 array of a stream's size under the mixings' scopes."""
+import re
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -14,6 +19,7 @@ from raydp_tpu.models import hyperconn, stats
 from raydp_tpu.models.hyperconn import HyperConfig, HyperMaps, Maps
 from raydp_tpu.models.latent import LatentConfig
 from raydp_tpu.models.transformer import TransformerBlock, xing4_0
+from raydp_tpu.ops import stream_mix
 
 N, D, B, S = 4, 16, 2, 6
 # The draws the benchmark's configuration widens the mappings' init to
@@ -230,6 +236,129 @@ def test_mappings_of_bfloat16_streams_are_those_of_their_values():
                                rtol=1e-6)
 
 
+# ------------------------------------------------------- one-pass mixing
+
+def _operands(n, tokens, width, dtype, key=20):
+    """Streams, a sublayer's output, random mappings and two cotangents."""
+    k = jax.random.split(jax.random.PRNGKey(key), 7)
+    x = jax.random.normal(k[0], (B, n, tokens, width), dtype)
+    y = jax.random.normal(k[1], (B, tokens, width), dtype)
+    maps = Maps(
+        jax.random.uniform(k[2], (n, B, tokens)),
+        2 * jax.random.uniform(k[3], (n, B, tokens)),
+        jax.random.uniform(k[4], (n, n, B, tokens)),
+    )
+    gh = jax.random.normal(k[5], (B, tokens, width))
+    gx = jax.random.normal(k[6], (B, n, tokens, width))
+    return x, y, maps, gh, gx
+
+
+def _close(got, want, dtype):
+    """Float32: the sums' order only. bfloat16: one rounding of an output
+    (2^-8 of its size) and of the cotangent that went in."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol * np.abs(want).max())
+
+
+@pytest.mark.parametrize("tokens", [64, 40])     # 40: a ragged last block
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_one_pass_read_and_its_gradients(dtype, n, tokens):
+    x, _, maps, gh, _ = _operands(n, tokens, 128, dtype)
+    assert hyperconn.one_pass(dtype, 128)
+    assert tokens % stream_mix.token_tile(tokens, 128, dtype) == tokens % 32
+    got = hyperconn.read(x, maps)
+    assert got.dtype == dtype
+    x32 = x.astype(jnp.float32)
+    _close(got, hyperconn.plain_read(x32, maps), dtype)
+
+    def loss(fn, x, pre):
+        h = fn(x, Maps(pre, maps.post, maps.res))
+        return jnp.sum(h.astype(jnp.float32) * gh)
+
+    gx, gpre = jax.grad(loss, (1, 2))(hyperconn.read, x, maps.pre)
+    wx, wpre = jax.grad(loss, (1, 2))(hyperconn.plain_read, x32, maps.pre)
+    assert gx.dtype == dtype and gpre.dtype == jnp.float32
+    _close(gx, wx, dtype)
+    _close(gpre, wpre, dtype)
+
+
+@pytest.mark.parametrize("tokens", [64, 40])
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_one_pass_write_and_its_gradients(dtype, n, tokens):
+    x, y, maps, _, g = _operands(n, tokens, 128, dtype)
+    got = hyperconn.write(x, y, maps)
+    assert got.dtype == dtype and got.shape == x.shape
+    x32, y32 = x.astype(jnp.float32), y.astype(jnp.float32)
+    _close(got, hyperconn.plain_write(x32, y32, maps), dtype)
+
+    def loss(fn, x, y, post, res):
+        out = fn(x, y, Maps(maps.pre, post, res))
+        return jnp.sum(out.astype(jnp.float32) * g)
+
+    got = jax.grad(loss, (1, 2, 3, 4))(
+        hyperconn.write, x, y, maps.post, maps.res)
+    want = jax.grad(loss, (1, 2, 3, 4))(
+        hyperconn.plain_write, x32, y32, maps.post, maps.res)
+    assert [a.dtype for a in got] == [dtype, dtype, jnp.float32, jnp.float32]
+    assert [a.shape for a in got] == [a.shape for a in want]
+    for a, b in zip(got, want):
+        _close(a, b, dtype)
+
+
+@pytest.mark.parametrize("fn", ["read", "write"])
+def test_a_bfloat16_block_is_rounded_once(fn):
+    """The kernels' forward is the float32 result rounded once, as the
+    plain formula's: bit for bit but where the float32 sums, added in
+    another order, fall on the other side of a rounding boundary."""
+    x, y, maps, _, _ = _operands(4, 48, 256, jnp.bfloat16, key=21)
+    args = (x, maps) if fn == "read" else (x, y, maps)
+    got = np.asarray(getattr(hyperconn, fn)(*args), np.float32)
+    want = np.asarray(getattr(hyperconn, "plain_" + fn)(*args), np.float32)
+    assert np.mean(got == want) > 0.999
+    np.testing.assert_allclose(got, want, rtol=2.0 ** -7)
+
+
+def test_the_mixings_differentiate_under_a_checkpoint():
+    """As the blocks run them: both mixings around a sublayer inside
+    ``jax.checkpoint``, every gradient that of the plain formula."""
+    x, _, maps, _, g = _operands(4, 40, 128, jnp.float32, key=22)
+
+    def sublayer(read, write, x, pre, post, res):
+        m = Maps(pre, post, res)
+        return jnp.sum(write(x, jnp.tanh(read(x, m)), m) * g)
+
+    args = (x, maps.pre, maps.post, maps.res)
+    got = jax.grad(jax.checkpoint(
+        lambda *a: sublayer(hyperconn.read, hyperconn.write, *a)
+    ), (0, 1, 2, 3))(*args)
+    want = jax.grad(
+        lambda *a: sublayer(hyperconn.plain_read, hyperconn.plain_write, *a),
+        (0, 1, 2, 3))(*args)
+    for a, b in zip(got, want):
+        _close(a, b, jnp.float32)
+
+
+@pytest.mark.parametrize("width,kernels", [(128, True), (96, False)])
+def test_the_plain_formula_is_taken_for_a_width_no_kernel_tiles(
+        width, kernels):
+    x, y, maps, _, _ = _operands(4, 32, width, jnp.bfloat16, key=23)
+    assert hyperconn.one_pass(x.dtype, width) is kernels
+    assert not hyperconn.one_pass(jnp.int32, 128)
+    text = str(jax.make_jaxpr(
+        lambda x, y: hyperconn.write(x, y + hyperconn.read(x, maps), maps)
+    )(x, y))
+    assert ("pallas_call" in text) is kernels
+    got, want = hyperconn.read(x, maps), hyperconn.plain_read(x, maps)
+    if kernels:
+        _close(got, want, x.dtype)
+    else:
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
 # ---------------------------------------------------------- in the stack
 
 def test_a_hyper_connected_block_carries_streams():
@@ -255,6 +384,70 @@ def test_the_older_families_have_one_stream(factory):
     assert "latent" not in cfg.kinds
 
 
+def _tiny_stack(width, n_layers=2):
+    return xing4_0(
+        vocab_size=64, d_model=width, n_heads=2, n_layers=n_layers,
+        dense_layers=n_layers, d_ff=64, max_len=256, attention_impl="dense",
+        hyper=WIDE,
+        latent=LatentConfig(
+            q_rank=8, kv_rank=8, nope_dim=8, rope_dim=4, v_dim=8),
+    )
+
+
+def _float32_under_the_mixings(cfg, tokens=256):
+    """Element counts of every float32 tensor an operation under a
+    ``hc_*/pre|post`` scope reads or writes in the lowered gradient step of
+    ``CausalLM(cfg)`` (bfloat16 carrier; StableHLO with its locations),
+    and how many operations carry such a scope."""
+    model = CausalLM(cfg)
+    ids = jnp.zeros((1, tokens), jnp.int32)
+    params = nn.unbox(model.init(jax.random.PRNGKey(0), ids))["params"]
+
+    def loss(params):
+        logits, _ = model.apply(
+            {"params": params}, ids, mutable=["losses", stats.STATS])
+        return jnp.mean(logits.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss)).lower(params).as_text(debug_info=True)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+
+    def name(ref, depth=0):
+        body = locs.get(ref, "")
+        quoted = re.match(r'"([^"]*)"', body)
+        if quoted:
+            return quoted.group(1)
+        inner = re.findall(r"#loc\d+", body)
+        return name(inner[0], depth + 1) if inner and depth < 8 else ""
+
+    scope = re.compile(r"block_\d+/hc_(attn|ffn)/(pre|post)/")
+    sizes, scoped = [], 0
+    for line in text.splitlines():
+        ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if ref is None or not scope.search(name(ref.group(1))):
+            continue
+        scoped += 1
+        sizes += [
+            int(np.prod([int(v) for v in dims.split("x") if v] or [1]))
+            for dims in re.findall(r"tensor<([\dx]*)xf32>", line)
+        ]
+    return sizes, scoped
+
+
+def test_a_step_holds_no_float32_stream_under_the_mixings():
+    """What the one-pass path is for: in the gradient step of a small
+    stack at a width the kernels tile, no operation under ``hc_*/pre`` or
+    ``hc_*/post`` touches a float32 tensor of a stream's size, forward,
+    recomputed or backward (the largest is a block's coefficient columns).
+    At a width they do not tile the same scan finds the plain formula's
+    float32 streams, so it can tell."""
+    tokens = 256
+    sizes, scoped = _float32_under_the_mixings(_tiny_stack(128), tokens)
+    assert scoped > 100 and sizes
+    assert max(sizes) <= tokens * (N + N * N) < tokens * 128
+    plain, _ = _float32_under_the_mixings(_tiny_stack(96), tokens)
+    assert max(plain) == N * tokens * 96
+
+
 def test_report_sets_the_gauges():
     from raydp_tpu.utils.profiling import metrics
 
@@ -262,13 +455,35 @@ def test_report_sets_the_gauges():
     assert metrics.gauge_value("hc/streams") == 4
     assert metrics.gauge_value("hc/sinkhorn_iters") == 20
     assert metrics.gauge_value("hc/sublayers") == 10
+    assert metrics.gauge_value("hc/one_pass_sublayers") == 10
     hyperconn.report(olmoe())
     assert metrics.gauge_value("hc/streams") == 0
     assert metrics.gauge_value("hc/sublayers") == 0
+    assert metrics.gauge_value("hc/one_pass_sublayers") == 0
     hyperconn.report_epoch({"hc_res_err_max": np.float32(0.25)})
     assert metrics.gauge_value("hc/res_row_sum_err_max") == 0.25
     hyperconn.report_epoch({"expert_tokens": np.ones(4)})
     assert metrics.gauge_value("hc/res_row_sum_err_max") == 0.25
+
+
+@pytest.mark.parametrize("cfg,sublayers,one_pass", [
+    (_tiny_stack(128, n_layers=3), 6, 6),
+    (_tiny_stack(96), 4, 0),             # a width no kernel tiles
+    (olmoe(), 0, 0), (bert_base(), 0, 0),
+], ids=["tileable", "odd_width", "olmoe", "bert_base"])
+def test_the_gauge_counts_the_sublayers_the_kernels_take(
+        cfg, sublayers, one_pass, caplog):
+    from raydp_tpu.utils.profiling import metrics
+
+    with caplog.at_level("INFO", logger="raydp_tpu.models.hyperconn"):
+        hyperconn.report(cfg)
+    assert metrics.gauge_value("hc/sublayers") == sublayers
+    assert metrics.gauge_value("hc/one_pass_sublayers") == one_pass
+    if sublayers:
+        path = "in one pass over the bfloat16" if one_pass else "plain formula"
+        assert path in caplog.text
+    else:
+        assert "residual path" not in caplog.text
 
 
 # ------------------------------------------------------ step statistics

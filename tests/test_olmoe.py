@@ -600,3 +600,56 @@ def test_short_convolution_compiles_to_few_passes_at_lfm2s_widths(one_chip):
         _on(one_chip, params), _on(one_chip, u)
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def _float32_results(hlo: str, elements: int):
+    """Results of at least ``elements`` float32 values that an instruction
+    outside every fused computation writes: arrays in the chip's memory,
+    not values inside a fusion."""
+    found, inside = [], None
+    for line in hlo.splitlines():
+        opened = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if opened:
+            inside = opened.group(2)
+        elif line.startswith("}"):
+            inside = None
+        elif inside and "fused_computation" not in inside and " = " in line:
+            head = line.split(" = ", 1)[1]
+            result = (head[:head.index(")") + 1] if head.startswith("(")
+                      else head.split(" ", 1)[0])
+            found += [
+                dims for dims in re.findall(r"\bf32\[([\d,]+)\]", result)
+                if np.prod([int(d) for d in dims.split(",")]) >= elements
+            ]
+    return found
+
+
+@pytest.mark.parametrize("tokens", [4096, 4104])    # 4104: a ragged block
+def test_stream_mixing_is_one_pass_at_xing4s_widths(
+    one_chip, monkeypatch, tokens
+):
+    """Four bfloat16 streams of [tokens, 3584] read and written around a
+    sublayer: Mosaic accepts the four kernels of ``ops/stream_mix.py``
+    (aligned slices, the scoped VMEM), and forward and backward leave no
+    float32 array of a stream's size in the chip's memory."""
+    from raydp_tpu.models import hyperconn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, d = 4, 3584
+    x = jax.ShapeDtypeStruct((1, n, tokens, d), jnp.bfloat16)
+    maps = hyperconn.Maps(*(
+        jax.ShapeDtypeStruct(lead + (1, tokens), jnp.float32)
+        for lead in ((n,), (n,), (n, n))
+    ))
+
+    def loss(x, maps):
+        h = hyperconn.read(x, maps)
+        return jnp.sum(hyperconn.write(x, jnp.tanh(h), maps).astype(
+            jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *_on(one_chip, (x, maps))
+    ).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 4
+    assert _float32_results(hlo, tokens * d) == []
