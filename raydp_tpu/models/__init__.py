@@ -11,6 +11,7 @@ from raydp_tpu.models.transformer import (
     olmoe,
     param_shardings,
     tiny_transformer,
+    laguna_xs_2,
     xing4_0,
 )
 from raydp_tpu.models.hyperconn import HyperConfig
@@ -59,6 +60,7 @@ __all__ = [
     "granite_h_micro",
     "lfm2_8b_a1b",
     "olmoe",
+    "laguna_xs_2",
     "xing4_0",
     "HyperConfig",
     "LatentConfig",

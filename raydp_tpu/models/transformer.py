@@ -32,6 +32,7 @@ import flax.linen as nn
 import numpy as np
 
 from raydp_tpu.models.dropout import Dropout
+from raydp_tpu.models.window import WindowConfig
 from raydp_tpu.ops.attention import (
     cached_decode_attention,
     reference_attention,
@@ -54,7 +55,7 @@ LOGICAL_RULES: Tuple[Tuple[str, Optional[str]], ...] = (
 )
 
 
-MIXERS = frozenset({"attention", "mamba", "conv", "latent"})
+MIXERS = frozenset({"attention", "window", "mamba", "conv", "latent"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +69,17 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    # What the rotation is scaled by where the config states it outright
+    # (``attention_factor``); None = m(mscale) / m(mscale_all_dim).
+    attention_factor: Optional[float] = None
+
+    @property
+    def stretch(self) -> float:
+        if self.attention_factor is not None:
+            return self.attention_factor
+        return yarn_mscale(self.factor, self.mscale) / yarn_mscale(
+            self.factor, self.mscale_all_dim
+        )
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -145,6 +157,16 @@ class TransformerConfig:
     # (None = the plain ``x + F(norm(x))``).
     latent: Any = None
     hyper: Any = None
+    # :class:`WindowConfig` for the "window" mixer; the four fields after
+    # it describe the "attention" mixer beside it (and a window layer's
+    # head size and gate): a head of its own size (None = d_model //
+    # n_heads), how many of its leading features the positions rotate
+    # (None = all), YaRN for them, a per-head sigmoid gate on the output.
+    window: Any = None
+    head_size: Optional[int] = None
+    rotary_dim: Optional[int] = None
+    rope_yarn: Any = None
+    head_gate: bool = False
     conv_taps: int = 3               # the "conv" mixer (models/shortconv.py)
     n_kv_heads: Optional[int] = None       # None = n_heads (no grouping)
     attention_scale: Optional[float] = None    # None = head_dim ** -0.5
@@ -169,6 +191,8 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.head_size is not None:
+            return self.head_size
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
@@ -251,12 +275,19 @@ def _norm(cfg: TransformerConfig, name: str, dtype=None) -> nn.Module:
     )
 
 
-def rotary(x, positions, theta: float, yarn: Optional[YarnScaling] = None):
+def rotary(x, positions, theta: float, yarn: Optional[YarnScaling] = None,
+           dims: Optional[int] = None):
     """Rotary position embedding (Su et al. 2021) in the half-split form
     of the published OLMoE/NeoX code: feature i pairs with i + D/2.
     ``x`` [B, S, H, D], ``positions`` [B or 1, S]; float32 inside. With
     ``yarn`` the frequencies are YaRN's blend (:func:`yarn_inv_freq`) and
-    the rotation is scaled by ``m(mscale) / m(mscale_all_dim)``."""
+    the rotation is scaled by ``yarn.stretch``. ``dims`` rotates the
+    first ``dims`` features of a head (pairs i, i + dims/2) and passes
+    the others through."""
+    if dims is not None and dims != x.shape[-1]:
+        return jnp.concatenate([
+            rotary(x[..., :dims], positions, theta, yarn), x[..., dims:]
+        ], axis=-1)
     half = x.shape[-1] // 2
     if yarn is None:
         inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
@@ -264,12 +295,8 @@ def rotary(x, positions, theta: float, yarn: Optional[YarnScaling] = None):
         inv_freq = jnp.asarray(yarn_inv_freq(half, theta, yarn))
     angle = positions.astype(jnp.float32)[..., None] * inv_freq
     cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
-    if yarn is not None:
-        stretch = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(
-            yarn.factor, yarn.mscale_all_dim
-        )
-        if stretch != 1.0:
-            cos, sin = cos * stretch, sin * stretch
+    if yarn is not None and yarn.stretch != 1.0:
+        cos, sin = cos * yarn.stretch, sin * yarn.stretch
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
         jnp.float32
     )
@@ -279,7 +306,14 @@ def rotary(x, positions, theta: float, yarn: Optional[YarnScaling] = None):
 
 
 class MultiHeadAttention(nn.Module):
+    """Softmax attention over all earlier positions (or all positions
+    without ``cfg.causal``) from the configuration's own sizes; with
+    ``window`` (a :class:`WindowConfig`, the "window" mixer) over the last
+    ``window.window`` of them, with that record's head count and rotary
+    positions."""
+
     cfg: TransformerConfig
+    window: Any = None
 
     @nn.compact
     def __call__(
@@ -291,17 +325,27 @@ class MultiHeadAttention(nn.Module):
         cache_positions=None,
         kv_len: Optional[int] = None,
     ):
-        cfg = self.cfg
+        cfg, win = self.cfg, self.window
         scale = cfg.attention_scale
+        n_heads, theta, rotary_dim, yarn, span = (
+            cfg.n_heads, cfg.rope_theta, cfg.rotary_dim, cfg.rope_yarn, None
+        ) if win is None else (
+            win.n_heads, win.rope_theta, win.rotary_dim, None, win.window
+        )
+        if span is not None and (not cfg.causal or cache_mode is not None):
+            raise NotImplementedError(
+                "a window layer trains causally; no decode cache holds a "
+                "window's positions (ROADMAP R2)"
+            )
         project = functools.partial(
             nn.DenseGeneral, axis=-1, use_bias=cfg.use_bias, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
         )
-        if cfg.kv_heads == cfg.n_heads:
+        if cfg.kv_heads == n_heads:
             # One fused projection where the head counts are equal (the
             # layout every checkpoint so far was written with).
             qkv = project(
-                features=(3, cfg.n_heads, cfg.head_dim),
+                features=(3, n_heads, cfg.head_dim),
                 kernel_init=_dense_init("embed", "qkv", "heads", "kv"),
                 name="qkv",
             )(x)
@@ -314,7 +358,7 @@ class MultiHeadAttention(nn.Module):
                     "the decode cache holds one key-value head a query head"
                 )
             q = project(
-                features=(cfg.n_heads, cfg.head_dim),
+                features=(n_heads, cfg.head_dim),
                 kernel_init=_dense_init("embed", "heads", "kv"), name="q",
             )(x)
             kv = project(
@@ -341,8 +385,8 @@ class MultiHeadAttention(nn.Module):
                 pos = cache_positions[:, None]
             else:
                 pos = jnp.arange(x.shape[-2])[None, :]
-            q = rotary(q, pos, cfg.rope_theta)
-            k = rotary(k, pos, cfg.rope_theta)
+            q = rotary(q, pos, theta, yarn, rotary_dim)
+            k = rotary(k, pos, theta, yarn, rotary_dim)
 
         if cache_mode is not None:
             # Per-slot KV cache rows (serve-plane autoregressive decode).
@@ -351,7 +395,7 @@ class MultiHeadAttention(nn.Module):
             # masking by cache length in cached_decode_attention is what
             # keeps stale pages invisible.
             b = x.shape[0]
-            cache_shape = (b, cfg.max_len, cfg.n_heads, cfg.head_dim)
+            cache_shape = (b, cfg.max_len, n_heads, cfg.head_dim)
             ck = self.variable(
                 "cache", "cached_key",
                 lambda: jnp.zeros(cache_shape, cfg.dtype),
@@ -395,12 +439,16 @@ class MultiHeadAttention(nn.Module):
                 raise ValueError(f"unknown cache_mode {cache_mode!r}")
         elif cfg.attention_impl == "dense":
             out = reference_attention(
-                q, k, v, causal=cfg.causal, scale=scale
+                q, k, v, causal=cfg.causal, scale=scale, window=span
             )
         elif cfg.attention_impl in ("ring", "ulysses"):
+            if span is not None:
+                raise NotImplementedError(
+                    f"a window layer through {cfg.attention_impl!r}"
+                )
             # Both move K and V a query head at a time: grouped heads are
             # repeated first.
-            group = cfg.n_heads // cfg.kv_heads
+            group = n_heads // cfg.kv_heads
             if group > 1:
                 k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
             attend = (ring_attention if cfg.attention_impl == "ring"
@@ -419,17 +467,25 @@ class MultiHeadAttention(nn.Module):
             # more than one device the config has to carry the mesh.
             if cfg.mesh is not None:
                 out = sharded_flash_attention(
-                    q, k, v, mesh=cfg.mesh, causal=cfg.causal, scale=scale
+                    q, k, v, mesh=cfg.mesh, causal=cfg.causal, scale=scale,
+                    window=span,
                 )
             else:
                 out = flash_attention(
-                    q, k, v, causal=cfg.causal, scale=scale
+                    q, k, v, causal=cfg.causal, scale=scale, window=span
                 )
         else:
             raise ValueError(
                 f"unknown attention_impl {cfg.attention_impl!r}"
             )
 
+        if cfg.head_gate:
+            # One sigmoid gate a head and token, from the layer's input.
+            gate = nn.sigmoid(project(
+                features=n_heads, kernel_init=_dense_init("embed", "heads"),
+                name="gate",
+            )(x).astype(jnp.float32))
+            out = out * gate[..., None].astype(out.dtype)
         out = nn.DenseGeneral(
             features=cfg.d_model,
             axis=(-2, -1),
@@ -456,7 +512,7 @@ class TransformerBlock(nn.Module):
     (``models/hyperconn.py``; scopes ``hc_attn`` and ``hc_ffn``)."""
 
     cfg: TransformerConfig
-    mixer: str = "attention"         # attention | mamba | conv | latent
+    mixer: str = "attention"  # attention | window | mamba | conv | latent
     ffn: Optional[str] = None        # None = cfg.ffn
 
     @nn.compact
@@ -478,8 +534,15 @@ class TransformerBlock(nn.Module):
 
         def mix(h):
             """The layer's mixer on its own norm of ``h``."""
-            if self.mixer == "attention":
-                return MultiHeadAttention(cfg, name="attn")(
+            if self.mixer in ("attention", "window"):
+                # A window layer's module has a name of its own, so that a
+                # trace tells the two kinds of layer apart.
+                attend = MultiHeadAttention(cfg, name="attn") if (
+                    self.mixer == "attention"
+                ) else MultiHeadAttention(
+                    cfg, cfg.window, name="attn_window"
+                )
+                return attend(
                     _norm(cfg, "ln_attn")(h),
                     deterministic,
                     cache_mode=cache_mode,
@@ -960,6 +1023,48 @@ def xing4_0(**overrides) -> TransformerConfig:
         ),
         hyper=HyperConfig(streams=4, sinkhorn_iters=20, eps=1e-6,
                           clamp=(-30.0, 30.0)),
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def laguna_xs_2(**overrides) -> TransformerConfig:
+    """poolside Laguna-XS.2 (33.4B parameters, about 3B active;
+    ``config.json`` of poolside/Laguna-XS.2, ``model_type`` laguna): 40
+    pre-norm layers of width 2048 with heads of 128 over 8 key-value
+    heads; every fourth layer from layer 0 attends over all earlier
+    positions with 48 query heads, half of each head rotated at theta
+    500,000 under YaRN x 64 over 4,096 (beta 64 / 1, the rotation times
+    1.41589), the other three over the last 512 with 64 query heads, the
+    whole head rotated at theta 10,000; a sigmoid gate a head on
+    attention's output; a dense SwiGLU FFN of width 8192 in layer 0 and
+    256 SwiGLU experts of width 512 beside one shared expert in the
+    others, 8 a token by sigmoid score, their scores divided by their sum
+    and times 2.5; RMSNorm 1e-6, no biases, no auxiliary loss; vocabulary
+    100352, untied head. ``experts_held`` / ``first_expert`` give a layer
+    the share of an expert-parallel deployment; ``n_layers`` and
+    ``dense_layers`` keep the model's own first layers."""
+    overrides = dict(overrides)
+    n_layers = overrides.get("n_layers", 40)
+    dense = overrides.pop("dense_layers", 1)
+    defaults = dict(
+        vocab_size=100352, d_model=2048, n_heads=48, n_kv_heads=8,
+        head_size=128, n_layers=n_layers, d_ff=8192, max_len=262144,
+        dropout_rate=0.0, causal=True, norm="rmsnorm", norm_eps=1e-6,
+        positions="rotary", rope_theta=500000.0, rotary_dim=64,
+        rope_yarn=YarnScaling(
+            factor=64.0, original_max_len=4096, beta_fast=64.0,
+            beta_slow=1.0, attention_factor=1.4158883083359672,
+        ),
+        head_gate=True,
+        window=WindowConfig(window=512, n_heads=64, rope_theta=10000.0),
+        use_bias=False, ffn="moe", n_experts=256, top_k=8, d_expert=512,
+        shared_experts=1, router_scoring="sigmoid", norm_top_k=True,
+        routed_scaling=2.5, moe_loss_weights=(0.0, 0.0), tie_head=False,
+        layer_types=tuple(
+            ("attention" if i % 4 == 0 else "window")
+            + (":swiglu" if i < dense else ":moe") for i in range(n_layers)
+        ),
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

@@ -38,36 +38,45 @@ def reference_attention(
     v: jnp.ndarray,
     causal: bool = False,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Plain softmax attention. Shapes: q [B, S, H, D], k and v [B, S,
     Hkv, D] with H a multiple of Hkv (each key-value head serves H / Hkv
-    consecutive query heads) → [B, S, H, D]."""
+    consecutive query heads) → [B, S, H, D]. ``window`` (causal only)
+    keeps the last ``window`` keys of each query, its own among them."""
     scale = _scale(scale, q.shape[-1])
+    if window is not None and not causal:
+        raise ValueError("a window is over the keys before a query")
     h, h_kv = q.shape[2], k.shape[2]
     if h != h_kv:
         b, s_q, _, d = q.shape
         out = _grouped_attention(
-            q.reshape(b, s_q, h_kv, h // h_kv, d), k, v, causal, scale
+            q.reshape(b, s_q, h_kv, h // h_kv, d), k, v, causal, scale,
+            window,
         )
         return out.reshape(b, s_q, h, v.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
-        scores = jnp.where(_causal_mask(scores), scores, -jnp.inf)
+        scores = jnp.where(_causal_mask(scores, window), scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
-def _causal_mask(scores):
+def _causal_mask(scores, window: Optional[int] = None):
     s_q, s_k = scores.shape[-2], scores.shape[-1]
-    return jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+    mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+    if window is not None:
+        mask = jnp.triu(mask, k=s_k - s_q - window + 1)
+    return mask
 
 
-def _grouped_attention(q, k, v, causal: bool, scale: float):
+def _grouped_attention(q, k, v, causal: bool, scale: float,
+                       window: Optional[int] = None):
     """``q`` [B, S, Hkv, G, D] against ``k``, ``v`` [B, S, Hkv, D]: K and
     V are read once a group, not repeated."""
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) * scale
     if causal:
-        scores = jnp.where(_causal_mask(scores), scores, -jnp.inf)
+        scores = jnp.where(_causal_mask(scores, window), scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhgqk,bkhd->bqhgd", weights, v)
 

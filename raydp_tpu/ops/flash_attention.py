@@ -46,6 +46,19 @@ a ``dot_general`` that contracts the left operand's first axis back into
 that transpose; ``k.T`` on the RIGHT it folds into the product itself).
 :func:`tile_counts` and :func:`report` say what a step's shapes come to.
 
+A WINDOW (``window=w``, causal only: query i sees keys ``i - w < j <= i``,
+``w`` of them with its own) is a second edge on the same predicates: a
+tile no query of which reaches back to any of its keys is dead, one whose
+last query row still sees its first key column is whole on that side too.
+Dead tiles are not only skipped but never fetched: the innermost grid
+dimension spans the BAND (the kv tiles a q tile's window can reach, the q
+tiles a kv tile's keys can be seen from) and the index maps add the
+band's first tile, so at S = 16,384 and ``w`` = 512 in 512² tiles a q
+tile takes 2 steps and not 32. The window's tiles default to the power of
+two at or under the window (:func:`_window_block`). With ``window=None``
+(or ``w >= S``, which is plain causal attention and is run as it) every
+grid, index map and body is the one it was.
+
 The kernels are compiled by Mosaic and run on a TPU only; on any other
 backend the call raises. ``interpret=True`` (pallas guide: Debugging)
 runs the same kernel bodies in the Pallas interpreter — the tests pass
@@ -71,80 +84,160 @@ logger = logging.getLogger(__name__)
 NEG_INF = -1e30
 
 
-def _tile_live(qi, ki, causal: bool, q_block: int, block_kv: int):
-    """Whether tile (qi, ki) has any unmasked entries (causal skip)."""
+def _tile_live(qi, ki, causal: bool, q_block: int, block_kv: int,
+               window: Optional[int] = None):
+    """Whether tile (qi, ki) has any unmasked entries (causal skip; under
+    a window also: its first query row reaches back to its last key)."""
     if not causal:
         return True
-    return (qi + 1) * q_block - 1 >= ki * block_kv
+    live = (qi + 1) * q_block - 1 >= ki * block_kv
+    if window is not None:
+        live = live & ((ki + 1) * block_kv - 1 > qi * q_block - window)
+    return live
 
 
-def _tile_whole(qi, ki, q_block: int, block_kv: int):
+def _tile_whole(qi, ki, q_block: int, block_kv: int,
+                window: Optional[int] = None):
     """Whether NO entry of causal tile (qi, ki) is masked: its first query
-    row already sees its last key column. A whole tile is live."""
-    return qi * q_block >= (ki + 1) * block_kv - 1
+    row already sees its last key column (and, under a window, its last
+    query row still sees its first). A whole tile is live."""
+    whole = qi * q_block >= (ki + 1) * block_kv - 1
+    if window is not None:
+        whole = whole & (ki * block_kv > (qi + 1) * q_block - 1 - window)
+    return whole
+
+
+def _first_kv(qi, q_block: int, block_kv: int, window: int):
+    """The first kv tile the window of q tile ``qi`` reaches."""
+    return jnp.maximum(qi * q_block - window + 1, 0) // block_kv
+
+
+def _last_kv(qi, q_block: int, block_kv: int):
+    """The last kv tile q tile ``qi`` sees (the one its last row is in)."""
+    return ((qi + 1) * q_block - 1) // block_kv
+
+
+def _first_q(ki, q_block: int, block_kv: int):
+    """The first q tile that sees kv tile ``ki``."""
+    return ki * block_kv // q_block
+
+
+def _last_q(ki, q_block: int, block_kv: int, window: int, q_tiles: int):
+    """The last q tile whose window still reaches kv tile ``ki``."""
+    return jnp.minimum(
+        ((ki + 1) * block_kv + window - 2) // q_block, q_tiles - 1
+    )
+
+
+def band_tiles(s: int, block_q: int, block_kv: int,
+               window: int) -> Tuple[int, int]:
+    """(kv tiles a q tile's band spans at most, q tiles a kv tile's): the
+    innermost grid dimensions of the windowed kernels. A band's tiles are
+    the live ones of a row or column of tiles, which lie side by side."""
+    live = [
+        [_tile_live(qi, ki, True, block_q, block_kv, window)
+         for ki in range(s // block_kv)]
+        for qi in range(s // block_q)
+    ]
+    return max(map(sum, live)), max(map(sum, zip(*live)))
 
 
 def _on_live_tile(qi, ki, body, *, causal: bool, q_block: int,
-                  block_kv: int) -> None:
+                  block_kv: int, window: Optional[int] = None,
+                  inside=None) -> None:
     """Run ``body(masked)`` on tile (qi, ki) by its kind: not at all on a
     dead tile, with ``masked=False`` on a whole one, with ``masked=True``
     on one the diagonal crosses. ``masked`` is static: the whole-tile body
     holds no iota, compare or select. Without ``causal`` every tile is
-    whole and only that body is built."""
+    whole and only that body is built. ``inside`` (a band's step past the
+    sequence's last tile is not) is a further condition on both."""
     if not causal:
         body(False)
         return
-    whole = _tile_whole(qi, ki, q_block, block_kv)
+    whole = _tile_whole(qi, ki, q_block, block_kv, window)
     crossed = jnp.logical_and(
-        _tile_live(qi, ki, True, q_block, block_kv), jnp.logical_not(whole)
+        _tile_live(qi, ki, True, q_block, block_kv, window),
+        jnp.logical_not(whole),
     )
+    if inside is not None:
+        whole = jnp.logical_and(whole, inside)
+        crossed = jnp.logical_and(crossed, inside)
     pl.when(whole)(functools.partial(body, False))
     pl.when(crossed)(functools.partial(body, True))
 
 
 def tile_counts(s: int, block_q: Optional[int] = None,
-                block_kv: Optional[int] = None,
-                causal: bool = True) -> Tuple[int, int]:
+                block_kv: Optional[int] = None, causal: bool = True,
+                window: Optional[int] = None) -> Tuple[int, int]:
     """(live, masked) tiles of one head in one call: the tiles a kernel
-    computes, and those of them whose body applies the causal mask. The
-    kernels' own predicates over every (qi, ki), on plain ints."""
-    block_q, block_kv = _block(block_q, s), _block(block_kv, s)
+    computes, and those of them whose body applies the mask. The kernels'
+    own predicates over every (qi, ki), on plain ints; the blocks left out
+    are the ones the call would take."""
+    window = _window(window, causal, s)
+    block_q = _block(block_q, s, window)
+    block_kv = _block(block_kv, s, window)
     live = [
         (qi, ki)
         for qi in range(s // block_q) for ki in range(s // block_kv)
-        if _tile_live(qi, ki, causal, block_q, block_kv)
+        if _tile_live(qi, ki, causal, block_q, block_kv, window)
     ]
     masked = sum(
         1 for qi, ki in live
-        if causal and not _tile_whole(qi, ki, block_q, block_kv)
+        if causal and not _tile_whole(qi, ki, block_q, block_kv, window)
     )
     return len(live), masked
 
 
 def report(cfg, seq_len: int) -> None:
-    """Static for a compiled step: two gauges and one log line where the
-    step is built (as ``models/mamba.report``). Per head and call; zero for
-    a model that never calls the kernel."""
+    """Static for a compiled step: four gauges and a log line a kind of
+    layer where the step is built (as ``models/mamba.report``). Per head
+    and call, the layers over all positions and the window layers apart;
+    zero for a model that never calls the kernel."""
     from raydp_tpu.utils.profiling import metrics
 
-    layers = live = masked = latent = 0
+    layers = latent = windowed = 0
     if getattr(cfg, "attention_impl", None) == "flash":
         latent = cfg.kinds.count("latent")
         layers = cfg.kinds.count("attention") + latent
-    if layers:
-        live, masked = tile_counts(seq_len, causal=cfg.causal)
+        windowed = cfg.kinds.count("window")
+    live, masked = tile_counts(seq_len, causal=cfg.causal) if layers else (
+        0, 0
+    )
+    span = _window(cfg.window.window, cfg.causal, seq_len) if windowed else (
+        None
+    )
+    band_live, band_masked = tile_counts(
+        seq_len, causal=cfg.causal, window=span
+    ) if windowed else (0, 0)
     metrics.gauge_set("attention/flash_live_tiles", live)
     metrics.gauge_set("attention/flash_masked_tiles", masked)
+    metrics.gauge_set("attention/flash_window_live_tiles", band_live)
+    metrics.gauge_set("attention/flash_window_masked_tiles", band_masked)
+    if not layers and not windowed:
+        return
+    scale = cfg.latent.softmax_scale if latent else _scale(
+        cfg.attention_scale, cfg.head_dim
+    )
+    rides = "q tile" if scale_rides_on_q(scale) else "float32 score tile"
     if layers:
-        scale = cfg.latent.softmax_scale if latent else _scale(
-            cfg.attention_scale, cfg.head_dim
-        )
         block = _block(None, seq_len)
         logger.info(
             "flash attention: %d layers, S = %d in %d x %d tiles, %d live a "
             "head and call, %d of them masked; softmax scale %g on the %s",
-            layers, seq_len, block, block, live, masked, scale,
-            "q tile" if scale_rides_on_q(scale) else "float32 score tile",
+            layers, seq_len, block, block, live, masked, scale, rides,
+        )
+    if windowed:
+        block = _block(None, seq_len, span)
+        steps = (seq_len // block,) * 2 if span is None else band_tiles(
+            seq_len, block, block, span
+        )
+        logger.info(
+            "flash attention under a window of %d: %d layers, S = %d in %d "
+            "x %d tiles, %d live a head and call, %d of them masked, no "
+            "other fetched (%d kv steps a q tile, %d q steps a kv tile); "
+            "softmax scale %g on the %s",
+            cfg.window.window, windowed, seq_len, block, block, band_live,
+            band_masked, steps[0], steps[1], scale, rides,
         )
 
 
@@ -167,7 +260,8 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
 
 
 def _scores(q_ref, k_ref, qi, ki, *, scale: float, masked: bool,
-            q_block: int, block_kv: int, transposed: bool = False):
+            q_block: int, block_kv: int, transposed: bool = False,
+            window: Optional[int] = None):
     """Shared tile math for ALL kernels (forward, dq, dkv): load raw
     q/k tiles and compute the scaled score tile, causally masked where
     ``masked`` — one definition, so forward and backward masking can
@@ -197,21 +291,29 @@ def _scores(q_ref, k_ref, qi, ki, *, scale: float, masked: bool,
         k_pos = ki * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1 - q_axis
         )
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        keep = q_pos >= k_pos
+        if window is not None:
+            keep = jnp.logical_and(keep, k_pos > q_pos - window)
+        s = jnp.where(keep, s, NEG_INF)
     return q, k, s
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                   acc_ref, *, block_kv: int, causal: bool, scale: float,
-                  q_block: int):
+                  q_block: int, window: Optional[int] = None):
     """Grid (b, h, q_blocks, kv_blocks); kv is the innermost sequential
     dimension, so only one [block_kv, d] K/V tile is VMEM-resident at a
-    time and the (m, l, acc) scratch carries across kv steps."""
+    time and the (m, l, acc) scratch carries across kv steps. Under a
+    window the innermost dimension is the band's steps and the kv tile is
+    the band's first plus the step."""
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
     n_kv = pl.num_programs(3)
+    ki = step if window is None else (
+        _first_kv(qi, q_block, block_kv, window) + step
+    )
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -220,7 +322,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     def _attend(masked: bool):
         _, _, s = _scores(
             q_ref, k_ref, qi, ki, scale=scale, masked=masked,
-            q_block=q_block, block_kv=block_kv,
+            q_block=q_block, block_kv=block_kv, window=window,
         )
         v = v_ref[0, 0]
         m_prev = m_ref[...]
@@ -236,9 +338,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
     # Causal: blocks strictly above the diagonal contribute nothing.
     _on_live_tile(qi, ki, _attend, causal=causal, q_block=q_block,
-                  block_kv=block_kv)
+                  block_kv=block_kv, window=window)
 
-    @pl.when(ki == n_kv - 1)
+    @pl.when(step == n_kv - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -249,23 +351,27 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
                    acc_ref, *, block_kv: int, causal: bool, scale: float,
-                   q_block: int):
-    """dq for one q tile, accumulated over kv tiles (innermost grid dim).
+                   q_block: int, window: Optional[int] = None):
+    """dq for one q tile, accumulated over kv tiles (innermost grid dim;
+    under a window, over the band's, as the forward kernel).
 
     ds = p ⊙ (g·vᵀ − delta);  dq = scale · ds · k   — all tile-shaped.
     """
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
     n_kv = pl.num_programs(3)
+    ki = step if window is None else (
+        _first_kv(qi, q_block, block_kv, window) + step
+    )
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _accumulate(masked: bool):
         _, k, s = _scores(
             q_ref, k_ref, qi, ki, scale=scale, masked=masked,
-            q_block=q_block, block_kv=block_kv,
+            q_block=q_block, block_kv=block_kv, window=window,
         )
         p = jnp.exp(s - lse_ref[0, 0])          # [q_block, block_kv] f32
         dp = _dot(g_ref[0, 0], v_ref[0, 0], _NT)
@@ -273,9 +379,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
         acc_ref[...] += _dot(ds.astype(k.dtype), k) * scale
 
     _on_live_tile(qi, ki, _accumulate, causal=causal, q_block=q_block,
-                  block_kv=block_kv)
+                  block_kv=block_kv, window=window)
 
-    @pl.when(ki == n_kv - 1)
+    @pl.when(step == n_kv - 1)
     def _finish():
         dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
 
@@ -283,18 +389,26 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_kv: int,
                     causal: bool, scale: float, q_block: int,
-                    q_tiles: Optional[int] = None):
+                    q_tiles: Optional[int] = None,
+                    window: Optional[int] = None, seq_q_tiles: int = 0):
     """dk/dv for one kv tile, accumulated over q tiles (innermost).
 
     dv = pᵀ · g;  dk = scale · dsᵀ · q.
 
     With grouped key-value heads the innermost dimension runs over the
     group's query heads too, ``q_tiles`` tiles each (``None``: no groups).
+    Under a window ``q_tiles`` is the band's steps a head (never None),
+    the q tile is the band's first plus the step in the head, and a step
+    past the sequence's ``seq_q_tiles`` tiles computes nothing.
     """
     ki = pl.program_id(2)   # kv tile is the OUTER tile here
     step = pl.program_id(3)
     n_steps = pl.num_programs(3)
     qi = step if q_tiles is None else step % q_tiles
+    inside = None
+    if window is not None:
+        qi = _first_q(ki, q_block, block_kv) + qi
+        inside = qi < seq_q_tiles
 
     @pl.when(step == 0)
     def _init():
@@ -308,6 +422,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         q, _, s = _scores(
             q_ref, k_ref, qi, ki, scale=scale, masked=masked,
             q_block=q_block, block_kv=block_kv, transposed=True,
+            window=window,
         )
         g = g_ref[0, 0]
         p = jnp.exp(s - lse_ref[0, 0])          # [block_kv, q_block] f32
@@ -317,7 +432,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_acc[...] += _dot(ds.astype(q.dtype), q) * scale
 
     _on_live_tile(qi, ki, _accumulate, causal=causal, q_block=q_block,
-                  block_kv=block_kv)
+                  block_kv=block_kv, window=window, inside=inside)
 
     @pl.when(step == n_steps - 1)
     def _finish():
@@ -327,7 +442,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_kv", "interpret", "scale"),
+    static_argnames=("causal", "block_q", "block_kv", "interpret", "scale",
+                     "window"),
 )
 def flash_attention(
     q: jnp.ndarray,
@@ -338,6 +454,7 @@ def flash_attention(
     block_kv: Optional[int] = None,
     interpret: bool = False,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """``q`` [B, S, H, D], ``k`` [B, S, Hkv, D] and ``v`` [B, S, Hkv, Dv]
     with H a multiple of Hkv → [B, S, H, Dv]. ``Dv`` need not be ``D``
@@ -346,6 +463,8 @@ def flash_attention(
     with ``Dv == D`` every tile is the one it was. S must divide by the
     blocks; a block left out is the largest of 1024, 512, 256, 128 that
     divides S. ``scale`` is the softmax scale, ``D ** -0.5`` when left out.
+    ``window`` (causal only) keeps the last ``window`` keys of each query,
+    its own among them; one of S or more is plain causal attention.
 
     Differentiable via custom_vjp; forward AND backward are blockwise
     pallas kernels (no S×S materialization anywhere)."""
@@ -356,20 +475,36 @@ def flash_attention(
             f"{q.shape[2]} query heads over key-value shapes {k.shape}, "
             f"{v.shape}"
         )
+    window = _window(window, causal, s)
     return _flash_vjp(
-        q, k, v, causal, _block(block_q, s), _block(block_kv, s), interpret,
-        _scale(scale, d),
+        q, k, v, causal, _block(block_q, s, window),
+        _block(block_kv, s, window), interpret, _scale(scale, d), window,
     )
 
 
-def _block(block: Optional[int], s: int) -> int:
+def _window(window: Optional[int], causal: bool, s: int) -> Optional[int]:
+    """The window a call runs under: None where it excludes nothing."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"a window of {window} keys, causal={causal}")
+    return None if window >= s else window
+
+
+def _block(block: Optional[int], s: int,
+           window: Optional[int] = None) -> int:
     """A tile edge. Large tiles pay on the chip: causal, S = 4096, 16
     heads of 128, bf16, forward and backward on a TPU v5 lite took 39.2 ms
     with 128 x 128 tiles, 17.0 with 256, 8.26 with 512 and 6.35 with 1024
-    (dense attention 14.7; PERF.md §6, PR 26)."""
+    (dense attention 14.7; PERF.md §6, PR 26). Under a window a tile wider
+    than the window is mostly mask, so the edge is the largest of these at
+    or under the window (128 at least)."""
     if block is not None:
         return min(block, s)
-    return next((b for b in (1024, 512, 256, 128) if s % b == 0), s)
+    edges = (1024, 512, 256, 128)
+    if window is not None:
+        edges = tuple(b for b in edges if b <= max(window, 128))
+    return next((b for b in edges if s % b == 0), s)
 
 
 def sharded_flash_attention(
@@ -380,6 +515,7 @@ def sharded_flash_attention(
     causal: bool = False,
     interpret: bool = False,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """:func:`flash_attention` on a device mesh.
 
@@ -399,7 +535,8 @@ def sharded_flash_attention(
     spec = P(axis("dp", q.shape[0]), None, axis("tp", k.shape[2]), None)
     return jax.shard_map(
         functools.partial(
-            flash_attention, causal=causal, interpret=interpret, scale=scale
+            flash_attention, causal=causal, interpret=interpret, scale=scale,
+            window=window,
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -409,17 +546,19 @@ def sharded_flash_attention(
     )(q, k, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_vjp(q, k, v, causal, block_q, block_kv, interpret, scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_vjp(q, k, v, causal, block_q, block_kv, interpret, scale,
+               window=None):
     out_t, _, _, _, _ = _flash_forward(
-        q, k, v, causal, block_q, block_kv, interpret, scale
+        q, k, v, causal, block_q, block_kv, interpret, scale, window
     )
     return jnp.einsum("bhsd->bshd", out_t)
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_kv, interpret, scale):
+def _flash_fwd_rule(q, k, v, causal, block_q, block_kv, interpret, scale,
+                    window):
     out_t, lse, qt, kt, vt = _flash_forward(
-        q, k, v, causal, block_q, block_kv, interpret, scale
+        q, k, v, causal, block_q, block_kv, interpret, scale, window
     )
     # Residuals stay in the kernels' [B,H,S,D] layout — the backward
     # would otherwise re-transpose q/k/v/out all over again.
@@ -431,12 +570,32 @@ def _kv_head(group: int):
     return (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
 
 
-def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
+def _kv_tile_of(window: Optional[int], block_q: int, block_kv: int):
+    """The kv tile of step ``ki`` of q tile ``qi``'s innermost dimension:
+    the step itself, or under a window the band's first tile plus the
+    step, held at the last tile the q tile sees (a dead step then names
+    the block the step before fetched, and fetches nothing)."""
+    if window is None:
+        return lambda qi, ki: ki
+    return lambda qi, ki: jnp.minimum(
+        _first_kv(qi, block_q, block_kv, window) + ki,
+        _last_kv(qi, block_q, block_kv),
+    )
+
+
+def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, window,
+                    res, g):
     qt, kt, vt, out_t, lse = res
     b, h, s, d = qt.shape
     h_kv, d_v = kt.shape[1], vt.shape[3]
     group = h // h_kv
     kv_of = _kv_head(group)
+    kv_tile = _kv_tile_of(window, block_q, block_kv)
+    # The innermost grid dimensions: every tile of the other kind, or
+    # under a window the band's.
+    kv_steps, q_tiles = s // block_kv, s // block_q
+    if window is not None:
+        kv_steps, q_tiles = band_tiles(s, block_q, block_kv, window)
 
     gt = jnp.einsum("bshd->bhsd", g)
     # delta_i = Σ_d dO_i · O_i — the softmax-jacobian row term.
@@ -451,10 +610,12 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
         (1, 1, block_q, d_v), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
     )
     k_spec = pl.BlockSpec(
-        (1, 1, block_kv, d), lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0)
+        (1, 1, block_kv, d),
+        lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0),
     )
     v_spec = pl.BlockSpec(
-        (1, 1, block_kv, d_v), lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0)
+        (1, 1, block_kv, d_v),
+        lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0),
     )
     row_spec = pl.BlockSpec(
         (1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
@@ -462,10 +623,10 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, block_kv=block_kv, causal=causal, scale=scale,
-            q_block=block_q,
+            q_block=block_q, window=window,
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), qt.dtype),
-        grid=(b, h, s // block_q, s // block_kv),
+        grid=(b, h, s // block_q, kv_steps),
         in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -476,8 +637,16 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
     # are the key-value heads, and a group's query heads share the
     # innermost dimension with the q tiles (step = head in group × q
     # tiles + q tile).
-    q_tiles = s // block_q
-    if group == 1:
+    if window is not None:
+        # The band's q tiles of kv tile ``ki``, held at the last one that
+        # still sees it (a dead step fetches nothing, as ``_kv_tile_of``).
+        def q_at(bi, hi, ki, step):
+            qi = jnp.minimum(
+                _first_q(ki, block_q, block_kv) + step % q_tiles,
+                _last_q(ki, block_q, block_kv, window, s // block_q),
+            )
+            return bi, hi * group + step // q_tiles, qi, 0
+    elif group == 1:
         q_at = lambda bi, hi, ki, qi: (bi, hi, qi, 0)  # noqa: E731
     else:
         q_at = lambda bi, hi, ki, step: (  # noqa: E731
@@ -498,7 +667,9 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, res, g):
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, block_kv=block_kv, causal=causal, scale=scale,
-            q_block=block_q, q_tiles=None if group == 1 else q_tiles,
+            q_block=block_q,
+            q_tiles=None if group == 1 and window is None else q_tiles,
+            window=window, seq_q_tiles=s // block_q,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, h_kv, s, d), kt.dtype),
@@ -533,10 +704,12 @@ def _flash_forward(
     block_kv: int,
     interpret: bool,
     scale: float,
+    window: Optional[int] = None,
 ):
     b, s, h, d = q.shape
     d_v = v.shape[3]
     kv_of = _kv_head(h // k.shape[2])
+    kv_tile = _kv_tile_of(window, block_q, block_kv)
     if s % block_q or s % block_kv:
         raise ValueError(f"seq len {s} not divisible by blocks "
                          f"({block_q}, {block_kv})")
@@ -546,13 +719,17 @@ def _flash_forward(
     kt = jnp.einsum("bshd->bhsd", k)
     vt = jnp.einsum("bshd->bhsd", v)
 
-    grid = (b, h, s // block_q, s // block_kv)
+    kv_steps = s // block_kv
+    if window is not None:
+        kv_steps, _ = band_tiles(s, block_q, block_kv, window)
+    grid = (b, h, s // block_q, kv_steps)
     kernel = functools.partial(
         _flash_kernel,
         block_kv=block_kv,
         causal=causal,
         scale=scale,
         q_block=block_q,
+        window=window,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -567,11 +744,11 @@ def _flash_forward(
             ),
             pl.BlockSpec(
                 (1, 1, block_kv, d),
-                lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0),
+                lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0),
             ),
             pl.BlockSpec(
                 (1, 1, block_kv, d_v),
-                lambda bi, hi, qi, ki: (bi, kv_of(hi), ki, 0),
+                lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0),
             ),
         ],
         out_specs=(

@@ -37,6 +37,7 @@ from raydp_tpu.models import (
     shortconv,
     stats,
 )
+from raydp_tpu.models import window as window_mixer
 from raydp_tpu.ops.flash_attention import report as report_flash_tiles
 from raydp_tpu.parallel.mesh import MeshSpec
 from raydp_tpu.telemetry import accounting as _acct
@@ -491,6 +492,7 @@ class JAXEstimator:
         )
         shortconv.report(getattr(self._model, "cfg", None))
         latent.report(getattr(self._model, "cfg", None))
+        window_mixer.report(getattr(self._model, "cfg", None))
         hyperconn.report(getattr(self._model, "cfg", None))
         report_flash_tiles(
             getattr(self._model, "cfg", None),

@@ -400,7 +400,7 @@ def test_layer_pattern_is_checked_and_the_factory_is_the_published_one():
     with pytest.raises(ValueError, match="layer_types"):
         dataclasses.replace(cfg, layer_types=("mamba",)).kinds
     with pytest.raises(ValueError, match="layer_types"):
-        dataclasses.replace(cfg, n_layers=1, layer_types=("window",)).kinds
+        dataclasses.replace(cfg, n_layers=1, layer_types=("linear",)).kinds
 
 
 # ---------------------------------------- the old families stay as they were

@@ -602,7 +602,7 @@ def test_layer_types_name_mixer_and_ffn_kind():
     assert bert_base().layers == (("attention", "gelu"),) * 12
     assert granite_h_micro().ffn_kinds == ("swiglu",) * 40
     assert olmoe().layers == (("attention", "moe"),) * 16
-    for bad in (("conv:relu",), ("window:moe",), ("conv:moe:x",), ("conv",) * 2):
+    for bad in (("conv:relu",), ("linear:moe",), ("conv:moe:x",), ("conv",) * 2):
         with pytest.raises(ValueError, match="layer_types"):
             dataclasses.replace(cfg, n_layers=1, layer_types=bad).layers
     assert not cfg.serves_from_kv_cache
