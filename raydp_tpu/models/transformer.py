@@ -184,7 +184,10 @@ class TransformerConfig:
     ssm_chunk: int = 256
     # How to run it.
     attention_impl: str = "dense"    # dense | ring | ulysses | flash
-    remat: bool = False              # checkpoint blocks (memory-bound fits)
+    # Checkpoint the blocks (memory-bound fits): a block's backward starts
+    # from its input, and from the flash kernels' output and dense lse
+    # where it calls them (``ops/flash_attention.KEPT``).
+    remat: bool = False
     dtype: Any = jnp.bfloat16        # compute dtype (MXU-friendly)
     param_dtype: Any = jnp.float32
     mesh: Any = None                 # ring/ulysses; flash on >1 device
@@ -694,14 +697,26 @@ class TransformerEncoder(nn.Module):
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
         # remat: recompute block activations in the backward instead of
         # storing them — the standard FLOPs-for-HBM trade that unlocks
-        # bigger batches/sequences when training is memory-bound.
+        # bigger batches/sequences when training is memory-bound. What a
+        # block keeps besides its input are the two residuals only the
+        # flash forward kernel can make: its output ([B, H, S, D_v], the
+        # size of one q projection's result) and lse, named where they are
+        # made. Recomputing them is a second run of the whole kernel, the
+        # dearest item of a block per byte kept; lse is kept as a dense
+        # [B, H, S] array because the kernel's [B, H, S, 1] columns are
+        # 128 times their bytes in HBM's tiling. A block that calls no
+        # flash kernel (dense, ring, ulysses) holds no such name and
+        # keeps its input alone.
         if cache_mode is not None and cfg.remat:
             raise ValueError("decode cache is incompatible with remat")
-        block_cls = (
-            nn.remat(TransformerBlock, static_argnums=(2,))
-            if cfg.remat
-            else TransformerBlock
-        )
+        block_cls = TransformerBlock
+        if cfg.remat:
+            from raydp_tpu.ops.flash_attention import KEPT
+
+            block_cls = nn.remat(
+                TransformerBlock, static_argnums=(2,),
+                policy=jax.checkpoint_policies.save_only_these_names(*KEPT),
+            )
         if cfg.hyper is not None:
             from raydp_tpu.models import hyperconn
 

@@ -59,6 +59,22 @@ two at or under the window (:func:`_window_block`). With ``window=None``
 (or ``w >= S``, which is plain causal attention and is run as it) every
 grid, index map and body is the one it was.
 
+RESIDUALS. The backward rule reads five arrays of the forward: ``q``,
+``k``, ``v`` and the output in the kernels' [B, H, S, D] layout, and the
+row logsumexp. Two of them only the forward kernel can make, and they
+carry a name each (:data:`KEPT`, ``jax.ad_checkpoint.checkpoint_name``):
+the output as the kernel wrote it, and ``lse`` as a LANE-DENSE [B, H, S]
+float32 array. A checkpoint whose policy keeps those names
+(``models/transformer.py`` wraps a checkpointed block so) runs the forward
+kernel once a step and not a second time in its backward; ``q``, ``k``,
+``v`` are a projection and a transpose away from the block's input and
+are rebuilt. The kernel WRITES ``lse`` as [B, H, S, 1] columns, 128 times
+its bytes in HBM's (8, 128) tiling (0.5 GB a 64-head call at S = 16,384):
+kept in that shape over five layers it would not fit, so the residual is
+the dense form and the backward rule lays it out again as the dq kernel's
+column and the dk/dv kernel's row. Inside no checkpoint a name is the
+identity.
+
 The kernels are compiled by Mosaic and run on a TPU only; on any other
 backend the call raises. ``interpret=True`` (pallas guide: Debugging)
 runs the same kernel bodies in the Pallas interpreter — the tests pass
@@ -73,6 +89,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -82,6 +99,11 @@ from raydp_tpu.ops.attention import _scale
 logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
+
+# The names of the two residuals only the forward kernel can make: its
+# output in [B, H, S, D_v] and the dense [B, H, S] float32 ``lse``. What a
+# checkpoint around the call should keep (the module docstring).
+KEPT = ("flash_attention_out", "flash_attention_lse")
 
 
 def _tile_live(qi, ki, causal: bool, q_block: int, block_kv: int,
@@ -188,11 +210,13 @@ def tile_counts(s: int, block_q: Optional[int] = None,
     return len(live), masked
 
 
-def report(cfg, seq_len: int) -> None:
-    """Static for a compiled step: four gauges and a log line a kind of
+def report(cfg, seq_len: int, batch: int = 1) -> None:
+    """Static for a compiled step: six gauges and a log line a kind of
     layer where the step is built (as ``models/mamba.report``). Per head
     and call, the layers over all positions and the window layers apart;
-    zero for a model that never calls the kernel."""
+    then the layers whose two named residuals (:data:`KEPT`) a block
+    checkpoint keeps, and their size at the step's shapes; zero for a
+    model that never calls the kernel."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = latent = windowed = 0
@@ -209,22 +233,43 @@ def report(cfg, seq_len: int) -> None:
     band_live, band_masked = tile_counts(
         seq_len, causal=cfg.causal, window=span
     ) if windowed else (0, 0)
+    # What the block checkpoint (``cfg.remat``) keeps of every call it
+    # wraps: a head's output row in the compute dtype and its float32 lse.
+    kept = layers + windowed if getattr(cfg, "remat", False) else 0
+    kept_bytes = 0
+    if kept:
+        heads = {"attention": (cfg.n_heads, cfg.head_dim)}
+        if latent:
+            heads["latent"] = (cfg.n_heads, cfg.latent.v_dim)
+        if windowed:
+            heads["window"] = (cfg.window.n_heads, cfg.head_dim)
+        kept_bytes = batch * seq_len * sum(
+            h * (width * jnp.dtype(cfg.dtype).itemsize + 4)
+            for h, width in (heads[k] for k in cfg.kinds if k in heads)
+        )
     metrics.gauge_set("attention/flash_live_tiles", live)
     metrics.gauge_set("attention/flash_masked_tiles", masked)
     metrics.gauge_set("attention/flash_window_live_tiles", band_live)
     metrics.gauge_set("attention/flash_window_masked_tiles", band_masked)
+    metrics.gauge_set("attention/flash_kept_layers", kept)
+    metrics.gauge_set("attention/flash_kept_mib", kept_bytes / 2 ** 20)
     if not layers and not windowed:
         return
     scale = cfg.latent.softmax_scale if latent else _scale(
         cfg.attention_scale, cfg.head_dim
     )
     rides = "q tile" if scale_rides_on_q(scale) else "float32 score tile"
+    keeps = (
+        f"the block checkpoint keeps the output and lse of {kept} layers' "
+        f"calls ({kept_bytes / 2 ** 20:.0f} MiB)"
+    ) if kept else "no checkpoint around the calls"
     if layers:
         block = _block(None, seq_len)
         logger.info(
             "flash attention: %d layers, S = %d in %d x %d tiles, %d live a "
-            "head and call, %d of them masked; softmax scale %g on the %s",
-            layers, seq_len, block, block, live, masked, scale, rides,
+            "head and call, %d of them masked; softmax scale %g on the %s; "
+            "%s",
+            layers, seq_len, block, block, live, masked, scale, rides, keeps,
         )
     if windowed:
         block = _block(None, seq_len, span)
@@ -235,9 +280,9 @@ def report(cfg, seq_len: int) -> None:
             "flash attention under a window of %d: %d layers, S = %d in %d "
             "x %d tiles, %d live a head and call, %d of them masked, no "
             "other fetched (%d kv steps a q tile, %d q steps a kv tile); "
-            "softmax scale %g on the %s",
+            "softmax scale %g on the %s; %s",
             cfg.window.window, windowed, seq_len, block, block, band_live,
-            band_masked, steps[0], steps[1], scale, rides,
+            band_masked, steps[0], steps[1], scale, rides, keeps,
         )
 
 
@@ -561,7 +606,11 @@ def _flash_fwd_rule(q, k, v, causal, block_q, block_kv, interpret, scale,
         q, k, v, causal, block_q, block_kv, interpret, scale, window
     )
     # Residuals stay in the kernels' [B,H,S,D] layout — the backward
-    # would otherwise re-transpose q/k/v/out all over again.
+    # would otherwise re-transpose q/k/v/out all over again. The two a
+    # checkpoint cannot rebuild without the kernel are named; ``lse``
+    # leaves as [B, H, S], not as the kernel's 128-times-padded column.
+    out_t = checkpoint_name(out_t, KEPT[0])
+    lse = checkpoint_name(lse[..., 0], KEPT[1])
     return jnp.einsum("bhsd->bshd", out_t), (qt, kt, vt, out_t, lse)
 
 
@@ -631,7 +680,7 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, window,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(qt, kt, vt, gt, lse, delta)
+    )(qt, kt, vt, gt, lse[..., None], delta)
 
     # dk/dv iterate kv as the outer tile, q innermost; the grid's heads
     # are the key-value heads, and a group's query heads share the
@@ -658,7 +707,8 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, window,
     k_spec_t = pl.BlockSpec((1, 1, block_kv, d), kv_at)
     v_spec_t = pl.BlockSpec((1, 1, block_kv, d_v), kv_at)
     # lse and delta as rows [B, H, 1, S] for the dk/dv kernel's transposed
-    # tiles (1 MB a call to lay out again; the tiles are 4 MB each).
+    # tiles (1 MB a call to lay out again; the tiles are 4 MB each); the
+    # residual ``lse`` is [B, H, S], a column above and a row here.
     def row_at(*at):
         bi, hi, qi, _ = q_at(*at)
         return bi, hi, 0, qi
@@ -686,7 +736,7 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, window,
             pltpu.VMEM((block_kv, d_v), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt, gt, jnp.swapaxes(lse, 2, 3), jnp.swapaxes(delta, 2, 3))
+    )(qt, kt, vt, gt, lse[:, :, None, :], jnp.swapaxes(delta, 2, 3))
 
     to_bshd = lambda x: jnp.einsum("bhsd->bshd", x)  # noqa: E731
     return to_bshd(dq), to_bshd(dk), to_bshd(dv)

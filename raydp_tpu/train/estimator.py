@@ -497,6 +497,7 @@ class JAXEstimator:
         report_flash_tiles(
             getattr(self._model, "cfg", None),
             seq_len=self._sample_batch.shape[-1],
+            batch=self._sample_batch.shape[0],
         )
         moe.report(self._model, tokens_per_step=tokens_per_step)
 
