@@ -323,8 +323,8 @@ def check_flash_kernels() -> list:
         bwd_c = bwd.lower(q, k, v).compile()
         compile_s = time.perf_counter() - t0
         assert "tpu_custom_call" in fwd_c.as_text(), "forward not Mosaic"
-        assert bwd_c.as_text().count("tpu_custom_call") >= 3, (
-            "backward is not the forward + dq + dk/dv Mosaic kernels"
+        assert bwd_c.as_text().count("tpu_custom_call") >= 2, (
+            "backward is not the forward + backward Mosaic kernels"
         )
         got = (fwd_c(q, k, v),) + tuple(bwd_c(q, k, v))
         want = (reference_attention(f32(q), f32(k), f32(v), causal=causal),)

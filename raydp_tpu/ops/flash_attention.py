@@ -6,21 +6,41 @@ q@k^T and p@v per tile; HBM traffic is O(S·D) instead of O(S²). Grid is
 grid dimension — each step gets one K/V tile via BlockSpec DMA while the
 running (max, sum, acc) live in scratch across kv steps.
 
-The BACKWARD pass is blockwise too (two kernels: dq over kv tiles, and
-dk/dv over q tiles, both re-computing p from the forward's saved row
-logsumexp) — so training never materializes the S×S score matrix either,
-which is the whole long-context point (a dense-recompute backward would
-put an O(S²) cliff right back at seq 8k–16k).
+The BACKWARD pass is blockwise too, re-computing p from the forward's
+saved row logsumexp — so training never materializes the S×S score matrix
+either, which is the whole long-context point (a dense-recompute backward
+would put an O(S²) cliff right back at seq 8k–16k). It is ONE kernel
+(:func:`_bwd_fused_kernel`): for each live [block_kv, block_q] tile the
+transposed scores, ``p`` and ``ds`` are built once and feed all three
+gradients, ``dv += p·g``, ``dk += ds·q`` and ``dqᵀ += kᵀ·ds`` — five
+products, one ``exp`` pass and one fetch of ``q``, ``k``, ``v``, ``g`` a
+tile, where a dq kernel and a dk/dv kernel run seven, two and two. Its
+grid is (batch, kv head, head in group, kv tile, q step), so dq
+accumulates over the OUTER tile dimension: a head's float32 dq, and the
+key-value head's dk and dv, stay RESIDENT in VMEM (``S·(2d + d_v)·4``
+bytes: 24 MiB at S = 16,384 and d = 128) and each is written to HBM once.
+No [block, block] tile is transposed for dq either: the key tile comes in
+a second time laid out [d, block_kv] (a transpose of ``k`` by XLA, once a
+call), dq leaves as [d, block_q] tiles, and their way back to [B, S, H, D]
+is the einsum the rule ends with anyway. A dead step of that grid (a q
+tile before the kv tile's first live one; a band's step past its last)
+names the nearest live block and fetches nothing. Which calls take it is
+:func:`backward_is_fused`, a rule on the call's own shapes against the
+chip's VMEM: one whose resident blocks do not fit (S = 32,768 at d = 128)
+runs the two kernels the one replaced (:func:`_flash_bwd_pair`: dq over
+kv tiles, dk/dv over q tiles, each building the score tile for itself).
 
 Grouped key-value heads (``k``, ``v`` with fewer heads than ``q``): query
 head ``hi`` reads key-value head ``hi // group`` through the BlockSpec
-index maps, so K and V are never repeated in HBM. The dk/dv kernel's grid
-runs over the KEY-VALUE heads with the group's query heads folded into
-its innermost (sequential) dimension beside the q tiles: one accumulator
-pass writes each dk/dv tile once, in [B, Hkv, S, D]. The alternative, a
-per-query-head dk/dv summed by XLA afterwards, writes and re-reads
-``group`` times the bytes for nothing. With ``group == 1`` every grid and
-index map is the one it was before grouping existed.
+index maps, so K and V are never repeated in HBM. The backward's grid
+runs over the KEY-VALUE heads with the group's query heads as a
+sequential dimension inside (the pair's dk/dv kernel folds them into its
+innermost dimension beside the q tiles): one accumulation over the
+group's heads and q tiles writes each dk/dv tile once, in [B, Hkv, S, D].
+The alternative, a per-query-head dk/dv summed by XLA afterwards, writes
+and re-reads ``group`` times the bytes for nothing. With ``group == 1``
+the forward's and the pair's grids and index maps are the ones they were
+before grouping existed.
 
 Two head widths (latent attention: ``q`` and ``k`` 192 wide, ``v`` 128):
 the q, k, dq and dk tiles and accumulators are as wide as ``q``, the v,
@@ -38,12 +58,13 @@ live, 8 crossed). The softmax scale multiplies the [block_q, d] ``q`` tile
 when it is a power of two (``64 ** -0.5``, ``1/64``): exact in any float
 dtype, and 1/16 or less of the elements of the score tile. Any other
 scale (``128 ** -0.5``) stays a float32 multiply of the score tile: a bf16
-``q·scale`` would round, and that is another result. The dk/dv kernel asks
-the MXU for its tiles TRANSPOSED (``k·qᵀ``, ``v·gᵀ``: keys down the rows,
-``lse`` and ``delta`` as rows), so ``pᵀ·g`` and ``dsᵀ·q`` are plain
-products and no [block_kv, block_q] tile is ever transposed (Mosaic turns
-a ``dot_general`` that contracts the left operand's first axis back into
-that transpose; ``k.T`` on the RIGHT it folds into the product itself).
+``q·scale`` would round, and that is another result. The backward kernel
+(and the pair's dk/dv kernel) asks the MXU for its tiles TRANSPOSED
+(``k·qᵀ``, ``v·gᵀ``: keys down the rows, ``lse`` and ``delta`` as rows),
+so ``pᵀ·g`` and ``dsᵀ·q`` are plain products and no [block_kv, block_q]
+tile is ever transposed (Mosaic turns a ``dot_general`` that contracts
+the left operand's first axis back into that transpose; ``k.T`` on the
+RIGHT it folds into the product itself).
 :func:`tile_counts` and :func:`report` say what a step's shapes come to.
 
 A WINDOW (``window=w``, causal only: query i sees keys ``i - w < j <= i``,
@@ -71,9 +92,10 @@ kernel once a step and not a second time in its backward; ``q``, ``k``,
 are rebuilt. The kernel WRITES ``lse`` as [B, H, S, 1] columns, 128 times
 its bytes in HBM's (8, 128) tiling (0.5 GB a 64-head call at S = 16,384):
 kept in that shape over five layers it would not fit, so the residual is
-the dense form and the backward rule lays it out again as the dq kernel's
-column and the dk/dv kernel's row. Inside no checkpoint a name is the
-identity.
+the dense form, and the backward rule lays it out again as the [B, H, 1,
+S] ROWS the one kernel reads, ``delta`` beside it: no padded column is
+built in the backward (the pair's dq kernel alone still takes ``lse`` and
+``delta`` as columns). Inside no checkpoint a name is the identity.
 
 The kernels are compiled by Mosaic and run on a TPU only; on any other
 backend the call raises. ``interpret=True`` (pallas guide: Debugging)
@@ -211,12 +233,14 @@ def tile_counts(s: int, block_q: Optional[int] = None,
 
 
 def report(cfg, seq_len: int, batch: int = 1) -> None:
-    """Static for a compiled step: six gauges and a log line a kind of
+    """Static for a compiled step: eight gauges and a log line a kind of
     layer where the step is built (as ``models/mamba.report``). Per head
     and call, the layers over all positions and the window layers apart;
     then the layers whose two named residuals (:data:`KEPT`) a block
-    checkpoint keeps, and their size at the step's shapes; zero for a
-    model that never calls the kernel."""
+    checkpoint keeps, and their size at the step's shapes; then the
+    layers whose backward is the one kernel (:func:`backward_is_fused`,
+    the rule the call itself takes) and the largest call's resident
+    accumulators; zero for a model that never calls the kernel."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = latent = windowed = 0
@@ -233,26 +257,43 @@ def report(cfg, seq_len: int, batch: int = 1) -> None:
     band_live, band_masked = tile_counts(
         seq_len, causal=cfg.causal, window=span
     ) if windowed else (0, 0)
+    # A kind's calls: (query heads, width of q and k, width of v).
+    calls = {}
+    if layers > latent:
+        calls["attention"] = (cfg.n_heads, cfg.head_dim, cfg.head_dim)
+    if latent:
+        calls["latent"] = (cfg.n_heads, cfg.latent.qk_dim, cfg.latent.v_dim)
+    if windowed:
+        calls["window"] = (cfg.window.n_heads, cfg.head_dim, cfg.head_dim)
+    itemsize = jnp.dtype(cfg.dtype).itemsize if calls else 0
     # What the block checkpoint (``cfg.remat``) keeps of every call it
     # wraps: a head's output row in the compute dtype and its float32 lse.
     kept = layers + windowed if getattr(cfg, "remat", False) else 0
     kept_bytes = 0
     if kept:
-        heads = {"attention": (cfg.n_heads, cfg.head_dim)}
-        if latent:
-            heads["latent"] = (cfg.n_heads, cfg.latent.v_dim)
-        if windowed:
-            heads["window"] = (cfg.window.n_heads, cfg.head_dim)
         kept_bytes = batch * seq_len * sum(
-            h * (width * jnp.dtype(cfg.dtype).itemsize + 4)
-            for h, width in (heads[k] for k in cfg.kinds if k in heads)
+            cfg.kinds.count(kind) * h * (d_v * itemsize + 4)
+            for kind, (h, _, d_v) in calls.items()
         )
+    # Which backward each kind's calls take, by the call's own rule.
+    fused = {
+        kind: backward_is_fused(seq_len, d, d_v, itemsize)
+        for kind, (_, d, d_v) in calls.items()
+    }
+    fused_layers = sum(cfg.kinds.count(kind) for kind in calls if fused[kind])
+    resident = max(
+        (fused_backward_vmem(seq_len, d, d_v, itemsize)[0]
+         for kind, (_, d, d_v) in calls.items() if fused[kind]),
+        default=0,
+    )
     metrics.gauge_set("attention/flash_live_tiles", live)
     metrics.gauge_set("attention/flash_masked_tiles", masked)
     metrics.gauge_set("attention/flash_window_live_tiles", band_live)
     metrics.gauge_set("attention/flash_window_masked_tiles", band_masked)
     metrics.gauge_set("attention/flash_kept_layers", kept)
     metrics.gauge_set("attention/flash_kept_mib", kept_bytes / 2 ** 20)
+    metrics.gauge_set("attention/flash_fused_bwd_layers", fused_layers)
+    metrics.gauge_set("attention/flash_bwd_resident_mib", resident / 2 ** 20)
     if not layers and not windowed:
         return
     scale = cfg.latent.softmax_scale if latent else _scale(
@@ -263,13 +304,24 @@ def report(cfg, seq_len: int, batch: int = 1) -> None:
         f"the block checkpoint keeps the output and lse of {kept} layers' "
         f"calls ({kept_bytes / 2 ** 20:.0f} MiB)"
     ) if kept else "no checkpoint around the calls"
+
+    def backward(kinds):
+        paths = sorted({fused[kind] for kind in kinds if kind in fused})
+        said = {
+            True: "one kernel (dq, dk and dv from a tile's one ds)",
+            False: "the dq and dk/dv kernels (the one kernel's resident "
+                   "accumulators do not fit VMEM)",
+        }
+        return "the backward is " + " or ".join(said[p] for p in paths)
+
     if layers:
         block = _block(None, seq_len)
         logger.info(
             "flash attention: %d layers, S = %d in %d x %d tiles, %d live a "
             "head and call, %d of them masked; softmax scale %g on the %s; "
-            "%s",
+            "%s; %s",
             layers, seq_len, block, block, live, masked, scale, rides, keeps,
+            backward(("attention", "latent")),
         )
     if windowed:
         block = _block(None, seq_len, span)
@@ -280,9 +332,10 @@ def report(cfg, seq_len: int, batch: int = 1) -> None:
             "flash attention under a window of %d: %d layers, S = %d in %d "
             "x %d tiles, %d live a head and call, %d of them masked, no "
             "other fetched (%d kv steps a q tile, %d q steps a kv tile); "
-            "softmax scale %g on the %s; %s",
+            "softmax scale %g on the %s; %s; %s",
             cfg.window.window, windowed, seq_len, block, block, band_live,
             band_masked, steps[0], steps[1], scale, rides, keeps,
+            backward(("window",)),
         )
 
 
@@ -485,6 +538,90 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, kt_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      block_kv: int, causal: bool, scale: float,
+                      q_block: int, window: Optional[int] = None,
+                      seq_q_tiles: int = 0):
+    """dq, dk and dv of one query head from ONE pass over its live tiles.
+
+    Grid (b, kv head, head in group, kv tile, q step): q innermost, under
+    a window the band's steps as in the dk/dv kernel. A live tile's
+    transposed scores, ``p`` and ``ds`` ([block_kv, q_block], keys down
+    the rows) are built once and feed all three gradients:
+
+    dv += p · g;  dk += scale · ds · q;  dqᵀ += scale · kᵀ · ds.
+
+    ``kt_ref`` is the key tile laid out [d, block_kv], so dq's product is
+    a plain one as well and comes out TRANSPOSED, [d, q_block]. The
+    float32 accumulators are RESIDENT: ``dq_acc`` [q tiles, d, q_block]
+    of the current query head over its kv tiles (the OUTER tile
+    dimension), ``dk_acc`` [S, d] and ``dv_acc`` [S, d_v] of the key-value
+    head over the group's query heads too. A tile adds into its q tile
+    and its ``block_kv`` rows; each is zeroed at its first tile and
+    written to its (equally resident) output block after its last.
+    """
+    gi = pl.program_id(2)
+    ki = pl.program_id(3)
+    step = pl.program_id(4)
+    n_steps = pl.num_programs(4)
+    qi = step
+    inside = None
+    if window is not None:
+        qi = _first_q(ki, q_block, block_kv) + step
+        inside = qi < seq_q_tiles
+        qi = jnp.minimum(qi, seq_q_tiles - 1)
+    kv_rows = pl.ds(pl.multiple_of(ki * block_kv, block_kv), block_kv)
+    # The first and the last kv tile that add into this q tile's dq.
+    first_kv = 0 if window is None else _first_kv(
+        qi, q_block, block_kv, window
+    )
+    last_kv = _last_kv(qi, q_block, block_kv) if causal else (
+        pl.num_programs(3) - 1
+    )
+
+    def in_sequence(cond):
+        return cond if inside is None else jnp.logical_and(cond, inside)
+
+    @pl.when(in_sequence(ki == first_kv))
+    def _init_dq():
+        dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+    @pl.when(jnp.logical_and(gi == 0, step == 0))
+    def _init_dkv():
+        dk_acc[kv_rows, :] = jnp.zeros((block_kv, dk_acc.shape[1]),
+                                       dk_acc.dtype)
+        dv_acc[kv_rows, :] = jnp.zeros((block_kv, dv_acc.shape[1]),
+                                       dv_acc.dtype)
+
+    def _accumulate(masked: bool):
+        q, _, s = _scores(
+            q_ref, k_ref, qi, ki, scale=scale, masked=masked,
+            q_block=q_block, block_kv=block_kv, transposed=True,
+            window=window,
+        )
+        g = g_ref[0, 0]
+        p = jnp.exp(s - lse_ref[0, 0])          # [block_kv, q_block] f32
+        dv_acc[kv_rows, :] += _dot(p.astype(g.dtype), g)
+        dp = _dot(v_ref[0, 0], g, _NT)
+        ds = (p * (dp - delta_ref[0, 0])).astype(q.dtype)
+        dk_acc[kv_rows, :] += _dot(ds, q) * scale
+        dq_acc[qi] += _dot(kt_ref[0, 0], ds) * scale
+
+    _on_live_tile(qi, ki, _accumulate, causal=causal, q_block=q_block,
+                  block_kv=block_kv, window=window, inside=inside)
+
+    @pl.when(in_sequence(ki == last_kv))
+    def _finish_dq():
+        dq_ref[0, 0, qi] = dq_acc[qi].astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(gi == pl.num_programs(2) - 1,
+                             step == n_steps - 1))
+    def _finish_dkv():
+        dk_ref[0, 0, kv_rows, :] = dk_acc[kv_rows, :].astype(dk_ref.dtype)
+        dv_ref[0, 0, kv_rows, :] = dv_acc[kv_rows, :].astype(dv_ref.dtype)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_kv", "interpret", "scale",
@@ -632,8 +769,163 @@ def _kv_tile_of(window: Optional[int], block_q: int, block_kv: int):
     )
 
 
+# A TPU v5e core's VMEM: what the interpreter, and a compile for a chip
+# that is described and not attached, take the chip to have.
+_VMEM_BYTES = 128 * 2 ** 20
+# What a [1024, 1024] tile's scores, p, dp and ds and the pipeline's input
+# tiles take beside the resident blocks (today's kernels fit the default
+# scoped limit of 16 MiB with them).
+_TILE_WORK_BYTES = 32 * 2 ** 20
+
+
+def _vmem_bytes() -> int:
+    if jax.default_backend() == "tpu":
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    return _VMEM_BYTES
+
+
+def fused_backward_vmem(s: int, d: int, d_v: int,
+                        itemsize: int) -> Tuple[int, int]:
+    """(resident, needed) bytes of VMEM of the one-kernel backward at a
+    call's shapes: the float32 accumulators of dq, dk ([S, d]) and dv
+    ([S, d_v]) that stay resident over a head's tiles, and those with
+    their output blocks in the call's dtype (two buffers each, the
+    pipeline's) and a tile's work."""
+    resident = 4 * s * (2 * d + d_v)
+    return resident, resident + 2 * itemsize * s * (2 * d + d_v) + (
+        _TILE_WORK_BYTES
+    )
+
+
+def backward_is_fused(s: int, d: int, d_v: int, itemsize: int) -> bool:
+    """Whether a call's backward is the one kernel: its resident blocks
+    and a tile's work within three quarters of the chip's VMEM (at
+    d = d_v = 128 in bf16: S = 16,384 needs 80 MiB of 128, S = 32,768
+    128). What does not fit runs the dq and dk/dv kernels."""
+    return 4 * fused_backward_vmem(s, d, d_v, itemsize)[1] <= (
+        3 * _vmem_bytes()
+    )
+
+
 def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, window,
                     res, g):
+    qt, _, vt, _, _ = res
+    s, d, d_v = qt.shape[2], qt.shape[3], vt.shape[3]
+    rule = _flash_bwd_fused if backward_is_fused(
+        s, d, d_v, qt.dtype.itemsize
+    ) else _flash_bwd_pair
+    return rule(causal, block_q, block_kv, interpret, scale, window, res, g)
+
+
+def _cotangent_and_delta(g, out_t):
+    """The cotangent in the kernels' [B, H, S, D_v] layout and
+    delta_i = Σ_d dO_i · O_i, the softmax-jacobian row term, [B, H, S]."""
+    gt = jnp.einsum("bshd->bhsd", g)
+    return gt, jnp.einsum(
+        "bhsd,bhsd->bhs", gt.astype(jnp.float32), out_t.astype(jnp.float32)
+    )
+
+
+def _flash_bwd_fused(causal, block_q, block_kv, interpret, scale, window,
+                     res, g):
+    """The backward as ONE kernel (:func:`_bwd_fused_kernel`): rows of
+    ``lse`` and ``delta`` and the keys laid out a second time as
+    [B, Hkv, D, S] go in, dq comes out a q tile at a time as [d, block_q]
+    and takes its way back to [B, S, H, D] in the einsum every gradient
+    ends with."""
+    qt, kt, vt, out_t, lse = res
+    b, h, s, d = qt.shape
+    h_kv, d_v = kt.shape[1], vt.shape[3]
+    group = h // h_kv
+    n_q = s // block_q
+    q_steps = n_q
+    if window is not None:
+        _, q_steps = band_tiles(s, block_q, block_kv, window)
+    gt, delta = _cotangent_and_delta(g, out_t)
+
+    # The q tile of a step, held at the nearest live one where the step is
+    # dead (before the kv tile's first under a causal mask, past the
+    # band's last under a window): a dead step names the block its
+    # neighbour fetched, and fetches nothing.
+    if window is not None:
+        def q_tile(ki, step):
+            return jnp.minimum(
+                _first_q(ki, block_q, block_kv) + step,
+                _last_q(ki, block_q, block_kv, window, n_q),
+            )
+    elif causal:
+        def q_tile(ki, step):
+            return jnp.maximum(step, _first_q(ki, block_q, block_kv))
+    else:
+        def q_tile(ki, step):
+            return step
+
+    def q_at(bi, hk, gi, ki, step):
+        return bi, hk * group + gi, q_tile(ki, step), 0
+
+    def row_at(bi, hk, gi, ki, step):
+        return bi, hk * group + gi, 0, q_tile(ki, step)
+
+    def kv_at(bi, hk, gi, ki, step):
+        return bi, hk, ki, 0
+
+    def kv_head_at(bi, hk, gi, ki, step):
+        return bi, hk, 0, 0
+
+    row_spec = pl.BlockSpec((1, 1, 1, block_q), row_at)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_fused_kernel, block_kv=block_kv, causal=causal, scale=scale,
+            q_block=block_q, window=window, seq_q_tiles=n_q,
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((b, h, n_q, d, block_q), qt.dtype),
+            jax.ShapeDtypeStruct((b, h_kv, s, d), kt.dtype),
+            jax.ShapeDtypeStruct((b, h_kv, s, d_v), vt.dtype),
+        ),
+        grid=(b, h_kv, group, s // block_kv, q_steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, d), q_at),
+            pl.BlockSpec((1, 1, block_kv, d), kv_at),
+            pl.BlockSpec((1, 1, block_kv, d_v), kv_at),
+            pl.BlockSpec((1, 1, block_q, d_v), q_at),
+            row_spec, row_spec,
+            pl.BlockSpec(
+                (1, 1, d, block_kv),
+                lambda bi, hk, gi, ki, step: (bi, hk, 0, ki),
+            ),
+        ],
+        out_specs=(
+            pl.BlockSpec(
+                (1, 1, n_q, d, block_q),
+                lambda bi, hk, gi, ki, step: (bi, hk * group + gi, 0, 0, 0),
+            ),
+            pl.BlockSpec((1, 1, s, d), kv_head_at),
+            pl.BlockSpec((1, 1, s, d_v), kv_head_at),
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((n_q, d, block_q), jnp.float32),
+            pltpu.VMEM((s, d), jnp.float32),
+            pltpu.VMEM((s, d_v), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=fused_backward_vmem(
+                s, d, d_v, qt.dtype.itemsize
+            )[1],
+        ),
+        interpret=interpret,
+    )(qt, kt, vt, gt, lse[:, :, None, :], delta[:, :, None, :],
+      jnp.swapaxes(kt, 2, 3))
+
+    dq = jnp.einsum("bhndq->bnqhd", dq).reshape(b, s, h, d)
+    return dq, jnp.einsum("bhsd->bshd", dk), jnp.einsum("bhsd->bshd", dv)
+
+
+def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
+                    res, g):
+    """The backward as two kernels, dq and dk/dv, each building the score
+    tile for itself: what a call too long for :func:`_flash_bwd_fused`'s
+    resident blocks runs."""
     qt, kt, vt, out_t, lse = res
     b, h, s, d = qt.shape
     h_kv, d_v = kt.shape[1], vt.shape[3]
@@ -646,11 +938,8 @@ def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, window,
     if window is not None:
         kv_steps, q_tiles = band_tiles(s, block_q, block_kv, window)
 
-    gt = jnp.einsum("bshd->bhsd", g)
-    # delta_i = Σ_d dO_i · O_i — the softmax-jacobian row term.
-    delta = jnp.einsum(
-        "bhsd,bhsd->bhs", gt.astype(jnp.float32), out_t.astype(jnp.float32)
-    )[..., None]
+    gt, delta = _cotangent_and_delta(g, out_t)
+    delta = delta[..., None]
 
     q_spec = pl.BlockSpec(
         (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)

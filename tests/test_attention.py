@@ -14,8 +14,14 @@ from raydp_tpu.ops import (
     ulysses_attention,
 )
 from raydp_tpu.ops.flash_attention import (
+    _flash_bwd_fused,
+    _flash_bwd_pair,
+    _flash_fwd_rule,
+    _flash_vjp,
     _tile_live,
     _tile_whole,
+    backward_is_fused,
+    fused_backward_vmem,
     scale_rides_on_q,
     tile_counts,
 )
@@ -386,7 +392,7 @@ def test_flash_with_equal_widths_is_the_call_it_was():
         flash_attention(q, k, v, causal=True, block_q=32, block_kv=32,
                         interpret=True)), argnums=(0, 1, 2)))(q, q, q))
     assert "f32[1,2,128,16]" in text and ",24]" not in text
-    assert text.count("pallas_call") == 3       # forward, dq, dk/dv
+    assert text.count("pallas_call") == 2       # forward, backward
 
 
 @pytest.mark.parametrize("q_shape,k_shape,v_shape", [
@@ -447,10 +453,10 @@ def test_whole_tiles_build_no_mask_and_a_riding_scale_no_tile_multiply():
                                 q, q, q))
 
     assert "iota" not in text(causal=False)
-    # Three kernels, one masked body each: a row and a column iota.
-    assert text(causal=True).count(" iota[") == 6
+    # Two kernels, one masked body each: a row and a column iota.
+    assert text(causal=True).count(" iota[") == 4
     tile_mul = re.compile(r":f32\[32,32\] = mul \w+ 0\.\d+:f32\[\]")
-    assert len(tile_mul.findall(text(causal=True, scale=0.3))) == 6
+    assert len(tile_mul.findall(text(causal=True, scale=0.3))) == 4
     assert not tile_mul.search(text(causal=True, scale=0.25))
 
 
@@ -637,20 +643,129 @@ def test_window_without_causal_is_refused():
 
 
 def test_windowed_kernels_fetch_only_the_band():
-    """The grids' innermost dimensions span the band: 3 pallas calls whose
-    grids are (1, h, 8, 2) forward and dq and (1, h_kv, 8, group x 2) for
-    dk/dv at S = 256, 32-wide tiles, a window of 32; without a window the
+    """The grids' innermost dimensions span the band: the forward's grid is
+    (1, h, 8, 2) and the one backward kernel's (1, h_kv, group, 8, 2) at
+    S = 256, 32-wide tiles, a window of 32; the pair's are (1, h, 8, 2)
+    for dq and (1, h_kv, 8, group x 2) for dk/dv; without a window the
     same shapes take 8 steps."""
     q = jnp.zeros((1, 256, 4, 16), jnp.float32)
     kv = jnp.zeros((1, 256, 2, 16), jnp.float32)
 
-    def grids(**kw):
-        text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
-            flash_attention(q, k, v, causal=True, block_q=32, block_kv=32,
-                            interpret=True, **kw)), argnums=(0, 1, 2)))(
-                                q, kv, kv))
-        return sorted(re.findall(r"grid=\(([\d, ]+)\)", text))
+    def found(fn, *args):
+        return sorted(re.findall(
+            r"grid=\(([\d, ]+)\)", str(jax.make_jaxpr(fn)(*args))))
 
-    assert grids(window=32) == sorted(["1, 4, 8, 2", "1, 4, 8, 2",
-                                       "1, 2, 8, 4"])
-    assert grids() == sorted(["1, 4, 8, 8", "1, 4, 8, 8", "1, 2, 8, 16"])
+    def grids(**kw):
+        return found(jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, causal=True, block_q=32, block_kv=32,
+                            interpret=True, **kw)), argnums=(0, 1, 2)),
+                     q, kv, kv)
+
+    def pair(window):
+        def both(q, k, v):
+            _, res = _flash_fwd_rule(q, k, v, True, 32, 32, True, 0.25,
+                                     window)
+            return _flash_bwd_pair(True, 32, 32, True, 0.25, window, res, q)
+        return found(both, q, kv, kv)
+
+    assert grids(window=32) == sorted(["1, 4, 8, 2", "1, 2, 2, 8, 2"])
+    assert grids() == sorted(["1, 4, 8, 8", "1, 2, 2, 8, 8"])
+    assert pair(32) == sorted(["1, 4, 8, 2", "1, 4, 8, 2", "1, 2, 8, 4"])
+    assert pair(None) == sorted(["1, 4, 8, 8", "1, 4, 8, 8", "1, 2, 8, 16"])
+
+
+# -- the backward as one kernel, and as the pair it replaced (PR 40) ---------
+
+@pytest.mark.parametrize("scale", [2.0 ** -3, 128 ** -0.5],
+                         ids=["scale_on_q", "scale_on_scores"])
+@pytest.mark.parametrize("mask", [(False, None), (True, None), (True, 48)],
+                         ids=["all_pairs", "causal", "window48"])
+@pytest.mark.parametrize("blocks", [(32, 32), (32, 64), (64, 32)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("widths", [(16, 16), (24, 16)],
+                         ids=lambda w: f"qk{w[0]}_v{w[1]}")
+@pytest.mark.parametrize("group", [1, 4])
+def test_both_backward_paths_match_reference_and_each_other(
+        group, widths, blocks, mask, scale):
+    """The one kernel through ``flash_attention`` (these shapes fit any
+    VMEM) and the dq + dk/dv pair by its rule function, on the same
+    residuals: each against dense attention's gradients, and the two
+    against each other (dk and dv accumulate in the same order over the
+    same tiles; dq's tile product is asked of the MXU the other way
+    round)."""
+    causal, window = mask
+    d_qk, d_v = widths
+    rng = np.random.default_rng(40)
+    mk = lambda h, d: jnp.asarray(  # noqa: E731
+        rng.standard_normal((1, 128, h, d)), jnp.float32)
+    q, k, v, w = mk(4, d_qk), mk(4 // group, d_qk), mk(4 // group, d_v), mk(
+        4, d_v)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               window=window, block_q=blocks[0],
+                               block_kv=blocks[1], interpret=True)
+
+    def plain(q, k, v):
+        return reference_attention(q, k, v, causal=causal, scale=scale,
+                                   window=window)
+
+    grads = lambda fn: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    _, res = _flash_fwd_rule(q, k, v, causal, *blocks, True, scale, window)
+    pair = _flash_bwd_pair(causal, *blocks, True, scale, window, res, w)
+    for one, two, want, name in zip(grads(flash), pair, grads(plain), "qkv"):
+        assert one.shape == two.shape == want.shape
+        for got in (one, two):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), rtol=1e-3, atol=1e-4,
+                err_msg=f"d{name} mismatch")
+        np.testing.assert_allclose(
+            np.asarray(one), np.asarray(two), rtol=1e-6, atol=1e-6,
+            err_msg=f"d{name}: one kernel against the pair")
+
+
+@pytest.mark.parametrize("cell,s,d,d_v,fused,resident_mib", [
+    ("laguna_xs_2, full and window layers", 16384, 128, 128, True, 24),
+    ("lfm2_8b_a1b", 8192, 64, 64, True, 6),
+    ("xing4_0_29b_a4b", 4096, 192, 128, True, 8),
+    ("olmoe_1b_7b", 4096, 128, 128, True, 6),
+    ("granite_4_0_h_micro", 4096, 64, 64, True, 3),
+    ("twice Laguna's sequence", 32768, 128, 128, False, 48),
+])
+def test_which_backward_a_call_takes_follows_from_its_shapes(
+        cell, s, d, d_v, fused, resident_mib):
+    """Plain ints in, the chip's VMEM (here the stated constant) the
+    measure: the five LM cells' calls run the one kernel in bf16, a
+    32,768-token call at d = 128 the pair."""
+    resident, needed = fused_backward_vmem(s, d, d_v, 2)
+    assert resident == resident_mib * 2 ** 20 == 4 * s * (2 * d + d_v)
+    assert needed > 2 * resident
+    assert backward_is_fused(s, d, d_v, 2) is fused
+
+
+def test_a_call_too_long_for_vmem_runs_the_pair(monkeypatch):
+    """With a VMEM the accumulators do not fit, the same call holds the dq
+    and dk/dv kernels (three Pallas calls) and gives the pair's gradients;
+    nothing but the shapes and the chip chooses."""
+    import sys
+
+    module = sys.modules["raydp_tpu.ops.flash_attention"]
+    q, k, v = _qkv(b=1, s=96, h=2, d=16, seed=40)
+    args = (True, 32, 32, True, 0.25, None)
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(_flash_vjp(*a, *args) ** 2),
+                        argnums=(0, 1, 2))
+
+    fused = grads()(q, k, v)
+    assert str(jax.make_jaxpr(grads())(q, k, v)).count("pallas_call") == 2
+    monkeypatch.setattr(module, "_VMEM_BYTES", 2 ** 20)
+    assert not backward_is_fused(96, 16, 16, 4)
+    assert str(jax.make_jaxpr(grads())(q, k, v)).count("pallas_call") == 3
+    out, res = _flash_fwd_rule(q, k, v, *args)
+    pair = _flash_bwd_pair(*args, res, 2 * out)
+    for got, same, near in zip(grads()(q, k, v), pair, fused):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(near),
+                                   rtol=1e-6, atol=1e-6)

@@ -22,7 +22,12 @@ import pytest
 from raydp_tpu.models.latent import LatentConfig
 from raydp_tpu.models.transformer import CausalLM, tiny_transformer
 from raydp_tpu.models.window import WindowConfig
-from raydp_tpu.ops.flash_attention import KEPT, flash_attention
+from raydp_tpu.ops.flash_attention import (
+    KEPT,
+    _flash_bwd_pair,
+    _flash_fwd_rule,
+    flash_attention,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 64
@@ -92,7 +97,8 @@ def _eqns(jaxpr, name):
 
 def _forward_calls(jaxpr):
     """The forward kernel is the call whose second result is the
-    [B, H, S, 1] column of ``lse``; dq has one result, dk/dv two wide."""
+    [B, H, S, 1] column of ``lse``; the backward kernel has three results,
+    all of them wide."""
     return [e for e in _eqns(jaxpr, "pallas_call")
             if len(e.params["out_avals"]) == 2
             and e.params["out_avals"][1].shape[-1] == 1]
@@ -114,12 +120,12 @@ def test_the_backward_of_a_checkpointed_block_runs_no_second_forward(
         mixer, interpreted, plain_checkpoint):
     loss, variables = _model(mixer)
     kept = jax.make_jaxpr(jax.grad(loss))(variables).jaxpr
-    assert len(_eqns(kept, "pallas_call")) == 6      # 3 a layer
+    assert len(_eqns(kept, "pallas_call")) == 4      # 2 a layer
     assert len(_forward_calls(kept)) == 2
     plain_checkpoint()
     loss, variables = _model(mixer)
     plain = jax.make_jaxpr(jax.grad(loss))(variables).jaxpr
-    assert len(_eqns(plain, "pallas_call")) == 8
+    assert len(_eqns(plain, "pallas_call")) == 6
     assert len(_forward_calls(plain)) == 4
 
 
@@ -136,7 +142,7 @@ def test_on_a_mesh_the_names_are_seen_through_the_shard_map(
     monkeypatch.setitem(MIXERS, "on_a_mesh", (dict(n_heads=2, mesh=mesh), 2, 16))
     loss, variables = _model("on_a_mesh")
     grad = jax.make_jaxpr(jax.grad(loss))(variables).jaxpr
-    assert len(_eqns(grad, "pallas_call")) == 6
+    assert len(_eqns(grad, "pallas_call")) == 4
     assert len(_forward_calls(grad)) == 2
 
 
@@ -217,8 +223,11 @@ def _inputs(d, d_v):
 def test_outside_a_checkpoint_the_call_gives_the_parents_bits(
         case, d, d_v, kw):
     """``tests/data/flash_attention_parent_pr38.npz``: output and three
-    gradients of these calls at the parent commit (24ab4f4), where the
-    residual ``lse`` was the kernel's [B, H, S, 1] column."""
+    gradients of these calls at PR 39's parent commit (24ab4f4), where the
+    residual ``lse`` was the kernel's [B, H, S, 1] column and the backward
+    the dq and dk/dv kernels. The pair's rule still gives those bits; the
+    one kernel gives dk's and dv's (the same tiles in the same order) and
+    dq to a rounding of float32 (its tile products run transposed)."""
     recorded = np.load(os.path.join(
         REPO, "tests", "data", "flash_attention_parent_pr38.npz"))
     q, k, v, w = _inputs(d, d_v)
@@ -228,20 +237,40 @@ def test_outside_a_checkpoint_the_call_gives_the_parents_bits(
         np.asarray(call(q, k, v)), recorded[f"{case}.out"])
     grads = jax.grad(
         lambda *a: jnp.sum(call(*a) * w), argnums=(0, 1, 2))(q, k, v)
-    for got, name in zip(grads, "qkv"):
-        np.testing.assert_array_equal(
-            np.asarray(got), recorded[f"{case}.d{name}"], err_msg=name)
+    window = kw.get("window")
+    _, res = _flash_fwd_rule(q, k, v, True, 32, 32, True, d ** -0.5, window)
+    pair = _flash_bwd_pair(True, 32, 32, True, d ** -0.5, window, res, w)
+    for got, two, name in zip(grads, pair, "qkv"):
+        want = recorded[f"{case}.d{name}"]
+        np.testing.assert_array_equal(np.asarray(two), want, err_msg=name)
+        if name == "q":
+            np.testing.assert_allclose(
+                np.asarray(got), want, rtol=1e-6, atol=1e-6, err_msg=name)
+        else:
+            np.testing.assert_array_equal(np.asarray(got), want, err_msg=name)
 
 
 def test_the_kernels_take_lse_in_the_shapes_they_took():
-    """A column for dq, a row for dk/dv, rebuilt from the dense residual;
+    """Rows for the one backward kernel, [B, H, 1, S], laid out from the
+    dense residual, and no [B, H, S, 1] float32 column into any backward
+    call (the pair's dq kernel takes one still, its dk/dv kernel rows);
     the names sit in the forward rule and nowhere in the primal call."""
     q, k, v, _ = _inputs(16, 16)
     call = functools.partial(flash_attention, causal=True, block_q=32,
                              block_kv=32, interpret=True)
     grad = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(call(*a)), argnums=(0, 1, 2)))(q, k, v).jaxpr
-    _, dq, dkv = _eqns(grad, "pallas_call")
+    _, backward = _eqns(grad, "pallas_call")
+    assert len(backward.params["out_avals"]) == 3
+    assert [tuple(v.aval.shape) for v in backward.invars[4:6]] == [
+        (1, 2, 1, SEQ)] * 2
+    assert not [v for v in backward.invars
+                if tuple(v.aval.shape) == (1, 2, SEQ, 1)]
+
+    def pair(q, k, v):
+        out, res = _flash_fwd_rule(q, k, v, True, 32, 32, True, 0.25, None)
+        return _flash_bwd_pair(True, 32, 32, True, 0.25, None, res, out)
+    _, dq, dkv = _eqns(jax.make_jaxpr(pair)(q, k, v).jaxpr, "pallas_call")
     assert tuple(dq.invars[4].aval.shape) == (1, 2, SEQ, 1)
     assert tuple(dkv.invars[4].aval.shape) == (1, 2, 1, SEQ)
     assert sorted(e.params["name"] for e in _eqns(grad, "name")) == sorted(
@@ -249,7 +278,8 @@ def test_the_kernels_take_lse_in_the_shapes_they_took():
     assert not _eqns(jax.make_jaxpr(call)(q, k, v).jaxpr, "name")
 
 
-# (f) the two gauges, where the step is built.
+# (f) the gauges, where the step is built: what is kept (PR 39) and which
+# backward the calls take (PR 40).
 
 def _published(config):
     """The cell's configuration as its builder makes it (no kernel runs:
@@ -266,15 +296,15 @@ def _published(config):
     return module.model_config(sizes)
 
 
-@pytest.mark.parametrize("config,seq,batch,layers,mib", [
-    ("laguna_xs_2", 16384, 1, 5, 1170.0),
-    ("lfm2_8b_a1b", 8192, 1, 2, 66.0),
-    ("xing4_0_29b_a4b", 4096, 1, 5, 162.5),
-    ("granite_4_0_h_micro", 4096, 1, 1, 16.5),
-    ("olmoe_1b_7b", 4096, 2, 0, 0.0),     # flash, but no checkpoint
+@pytest.mark.parametrize("config,seq,batch,layers,mib,fused,resident", [
+    ("laguna_xs_2", 16384, 1, 5, 1170.0, 5, 24.0),
+    ("lfm2_8b_a1b", 8192, 1, 2, 66.0, 2, 6.0),
+    ("xing4_0_29b_a4b", 4096, 1, 5, 162.5, 5, 8.0),
+    ("granite_4_0_h_micro", 4096, 1, 1, 16.5, 1, 3.0),
+    ("olmoe_1b_7b", 4096, 2, 0, 0.0, 1, 6.0),     # flash, but no checkpoint
 ])
 def test_the_step_reports_the_layers_it_keeps_and_their_size(
-        config, seq, batch, layers, mib, caplog):
+        config, seq, batch, layers, mib, fused, resident, caplog):
     from raydp_tpu.ops.flash_attention import report
     from raydp_tpu.utils.profiling import metrics
 
@@ -285,11 +315,15 @@ def test_the_step_reports_the_layers_it_keeps_and_their_size(
     assert metrics.gauge_value("attention/flash_kept_layers") == layers
     assert metrics.gauge_value("attention/flash_kept_mib") == pytest.approx(
         mib, abs=0.3)
+    assert metrics.gauge_value("attention/flash_fused_bwd_layers") == fused
+    assert metrics.gauge_value(
+        "attention/flash_bwd_resident_mib") == resident
     lines = [r.getMessage() for r in caplog.records]
     said = (f"the block checkpoint keeps the output and lse of {layers} "
             f"layers' calls ({mib:.0f} MiB)") if layers else (
         "no checkpoint around the calls")
     assert lines and all(said in line for line in lines), lines
+    assert all("the backward is one kernel" in line for line in lines)
 
 
 def test_a_fit_sets_the_gauges_and_a_dense_model_reads_zero(
@@ -317,8 +351,15 @@ def test_a_fit_sets_the_gauges_and_a_dense_model_reads_zero(
     assert metrics.gauge_value("attention/flash_kept_mib") == (
         2 * 4 * 3 * SEQ * (16 * 4 + 4) / 2 ** 20)
     assert "lse of 2 layers' calls" in caplog.records[-1].getMessage()
+    assert metrics.gauge_value("attention/flash_fused_bwd_layers") == 2
+    # dq, dk and dv of one head in float32: 64 positions of 3 x 16.
+    assert metrics.gauge_value("attention/flash_bwd_resident_mib") == (
+        4 * SEQ * 3 * 16 / 2 ** 20)
     build("flash", False)
     assert metrics.gauge_value("attention/flash_kept_layers") == 0
+    assert metrics.gauge_value("attention/flash_fused_bwd_layers") == 2
     build("dense", True)
     assert metrics.gauge_value("attention/flash_kept_layers") == 0
     assert metrics.gauge_value("attention/flash_kept_mib") == 0
+    assert metrics.gauge_value("attention/flash_fused_bwd_layers") == 0
+    assert metrics.gauge_value("attention/flash_bwd_resident_mib") == 0

@@ -293,9 +293,13 @@ def test_grouped_attention_is_attention_with_k_and_v_repeated(causal):
 
 
 def test_flash_attention_without_groups_lowers_as_it_did():
-    """Equal head counts take the dk/dv kernel that was there before
+    """Equal head counts take the pair's dk/dv kernel that was there before
     grouping existed: its innermost grid index IS the q tile, no remainder
-    by the tiles of a head."""
+    by the tiles of a head. The one backward kernel (PR 40) has the head
+    in the group as a grid dimension of its own: groups add no index
+    arithmetic to it."""
+    from raydp_tpu.ops.flash_attention import _flash_bwd_pair, _flash_fwd_rule
+
     q = jnp.zeros((1, 256, 4, 16), jnp.float32)
     kv = jnp.zeros((1, 256, 2, 16), jnp.float32)
 
@@ -304,9 +308,20 @@ def test_flash_attention_without_groups_lowers_as_it_did():
             flash_attention(q, k, v, causal=True, block_q=128, block_kv=128,
                             interpret=True)), argnums=(0, 1, 2)))(q, k, k))
 
+    def pair(k):
+        def both(q, k, v):
+            out, res = _flash_fwd_rule(q, k, v, True, 128, 128, True, 0.25,
+                                       None)
+            return _flash_bwd_pair(True, 128, 128, True, 0.25, None, res, out)
+        return str(jax.make_jaxpr(both)(q, k, k))
+
     index_math = re.compile(r":i32\[\] = rem ")
-    assert not index_math.search(text(q))
-    assert index_math.search(text(kv))
+    assert not index_math.search(pair(q))
+    assert index_math.search(pair(kv))
+    # The one kernel finds a tile's first and last neighbour by floor
+    # divisions of its own; a group adds none to them.
+    assert len(index_math.findall(text(q))) == len(
+        index_math.findall(text(kv)))
 
 
 def test_attention_module_groups_scales_and_takes_no_positions(tiny):
