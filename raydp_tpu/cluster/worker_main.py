@@ -155,6 +155,9 @@ class Worker:
         )
 
     def register(self) -> None:
+        # A host pod's worker may be up before the driver pod's master
+        # listens (the store agent waits the same way).
+        self.master.wait_ready(timeout=30.0)
         last_exc = None
         for attempt in range(REGISTER_RETRIES):
             try:

@@ -3,10 +3,10 @@
 The fault plane that makes every recovery path in this repo testable:
 a seeded, env-driven plan (``RAYDP_TPU_FAULT_PLAN``) describes exactly
 which process dies, stalls, or loses an RPC, and when — so tier-1 tests
-and the ``fault_tolerance`` bench section exercise rank death, host
-preemption, dropped control-plane traffic, and heartbeat stalls
-deterministically instead of by hope. See ``doc/fault_tolerance.md``
-for the grammar and the supervisor semantics built on top.
+exercise rank death, host preemption, dropped control-plane traffic,
+and heartbeat stalls deterministically instead of by hope. See
+``doc/fault_tolerance.md`` for the grammar and the supervisor semantics
+built on top.
 
 Hook surface (all no-ops when no plan is configured):
 

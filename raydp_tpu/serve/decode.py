@@ -616,7 +616,7 @@ class DecodeLoop:
 
     def run_until_idle(self, max_rounds: int = 10000) -> int:
         """Drive rounds until no live or pending work remains (in-
-        process harness for tests and the bench). Returns rounds run."""
+        process harness for tests). Returns rounds run."""
         ran = 0
         while ran < max_rounds:
             stats = self.run_round()

@@ -83,7 +83,8 @@ class StoreAgent:
         if namespace is None:
             # Remote pods don't know the session namespace up front —
             # learn it from the master (Ping carries it).
-            namespace = self.master.call("Ping", {}, timeout=30.0)["namespace"]
+            namespace = self._call_master(
+                "Ping", {}, "learn its namespace")["namespace"]
         self.store = ObjectStore(namespace=namespace, node_id=node_id)
         self._stop_event = threading.Event()
         handlers = agent_handlers(self.store)
@@ -95,25 +96,34 @@ class StoreAgent:
         self._stop_event.set()
         return {"stopping": True}
 
-    def register(self) -> None:
+    def _call_master(self, method: str, request: dict, what: str) -> dict:
+        """A start-up call to the master. A host pod's agent may be up
+        before the driver pod's master listens: wait for the channel
+        (a sleep would not do: after a refused connection the channel
+        is in gRPC's own reconnect back-off, where calls fail at once),
+        then retry through blips."""
+        self.master.wait_ready(timeout=30.0)
         last_exc = None
         for attempt in range(REGISTER_RETRIES):
             try:
-                self.master.call(
-                    "RegisterAgent",
-                    {
-                        "node_id": self.node_id,
-                        "address": self._server.address,
-                        "service": AGENT_SERVICE,
-                        "pid": os.getpid(),
-                    },
-                )
-                return
+                return self.master.call(method, request)
             except Exception as exc:
                 last_exc = exc
                 time.sleep(0.5 * (attempt + 1))
         raise RuntimeError(
-            f"store agent {self.node_id} failed to register: {last_exc}"
+            f"store agent {self.node_id} failed to {what}: {last_exc}"
+        )
+
+    def register(self) -> None:
+        self._call_master(
+            "RegisterAgent",
+            {
+                "node_id": self.node_id,
+                "address": self._server.address,
+                "service": AGENT_SERVICE,
+                "pid": os.getpid(),
+            },
+            "register",
         )
 
     def run(self) -> None:

@@ -77,8 +77,7 @@ JOB_ENV = "RAYDP_TPU_JOB"
 
 #: Kill switch: ``RAYDP_TPU_JOB_ACCOUNTING=0`` disables ledger billing
 #: and event-timeline emits (propagation itself stays on — it is just
-#: an env var and a dict key). The ``bench.py`` ``job_accounting``
-#: section uses this as its off-arm; budget <5% overhead.
+#: an env var and a dict key).
 ACCOUNTING_ENV = "RAYDP_TPU_JOB_ACCOUNTING"
 
 

@@ -78,8 +78,10 @@ _prev_root_level: Optional[int] = None
 
 def install(directory: Optional[str] = None,
             level: int = logging.INFO) -> Optional[JsonLogHandler]:
-    """Attach the JSONL handler to the root logger. Idempotent; returns
-    the handler, or None when no telemetry directory is configured.
+    """Attach the JSONL handler to the root logger. Idempotent for one
+    directory; returns the handler, or None when no telemetry directory
+    is configured. The handler outlives a session, so a later session
+    with another directory moves it there.
 
     Handler levels filter *after* the logger's own level: in a process
     that never configured logging, the root logger's default WARNING
@@ -94,15 +96,17 @@ def install(directory: Optional[str] = None,
     if not directory:
         return None
     with _mu:
+        path = os.path.join(directory, f"logs-{os.getpid()}.jsonl")
+        root = logging.getLogger()
         if _handler is not None:
-            return _handler
+            if _handler.path == path:
+                return _handler
+            root.removeHandler(_handler)
         from raydp_tpu.telemetry.export import prune_shards_once
 
         prune_shards_once(directory, "logs")
-        path = os.path.join(directory, f"logs-{os.getpid()}.jsonl")
         handler = JsonLogHandler(path)
         handler.setLevel(level)
-        root = logging.getLogger()
         root.addHandler(handler)
         if root.getEffectiveLevel() > level:
             _prev_root_level = root.level

@@ -9,9 +9,7 @@ The RSS helpers feed the resource-accounting gauges of the query
 profiling plane (``raydp_host_rss_bytes``): :func:`host_rss_bytes`
 reads the current and peak resident set from ``/proc/self/status``
 (``VmRSS`` / ``VmHWM``), falling back to ``resource.getrusage`` where
-procfs is unavailable; :func:`reset_peak_rss` arms a fresh peak window
-via ``/proc/self/clear_refs`` so per-section watermarks (bench configs)
-don't inherit an earlier section's high-water mark.
+procfs is unavailable.
 """
 from __future__ import annotations
 
@@ -64,8 +62,7 @@ def format_memory_size(num_bytes: int) -> str:
 def host_rss_bytes() -> "tuple[int, int]":
     """Return ``(rss_bytes, peak_rss_bytes)`` for this process.
 
-    Prefers ``/proc/self/status`` (``VmRSS``/``VmHWM``) so the peak is
-    resettable via :func:`reset_peak_rss`; falls back to
+    Prefers ``/proc/self/status`` (``VmRSS``/``VmHWM``); falls back to
     ``resource.getrusage`` (``ru_maxrss`` is the lifetime peak and
     stands in for both values) where procfs is missing."""
     try:
@@ -88,15 +85,3 @@ def host_rss_bytes() -> "tuple[int, int]":
     except Exception:
         return 0, 0
 
-
-def reset_peak_rss() -> bool:
-    """Reset the kernel's peak-RSS watermark (``VmHWM``) for this
-    process so the next :func:`host_rss_bytes` peak covers a fresh
-    window. Returns False where unsupported (non-Linux, no write
-    permission) — callers then get the lifetime peak instead."""
-    try:
-        with open("/proc/self/clear_refs", "w") as f:
-            f.write("5")
-        return True
-    except OSError:
-        return False

@@ -20,13 +20,10 @@ export RAYDP_TPU_POSTMORTEM_DIR="${RAYDP_TPU_POSTMORTEM_DIR:-/tmp/raydp_tpu_post
 export RAYDP_TPU_STATS_DIR="${RAYDP_TPU_STATS_DIR:-/tmp/raydp_tpu_stats.$$}"
 # Machine-readable smoke-gate metrics (preempt MTTR, serve fill,
 # time-to-grow, SLO breach-detect/MTTR): each gate below stamps its
-# numbers here via scripts/verify_metrics.py; the advisory step at the
-# bottom diffs them against the previous run's stamp with the same
-# bench_compare rules that gate the BENCH leaves.
+# numbers here via scripts/verify_metrics.py, and the sim gate reads the
+# load gate's knee from it. Every run starts the file anew.
 export VERIFY_METRICS_PATH="${VERIFY_METRICS_PATH:-$PWD/VERIFY_METRICS.json}"
-if [ -f "$VERIFY_METRICS_PATH" ]; then
-  mv -f "$VERIFY_METRICS_PATH" "${VERIFY_METRICS_PATH%.json}.prev.json"
-fi
+rm -f "$VERIFY_METRICS_PATH"
 # On any gate failure, ship the unified dashboard with the black box:
 # the same document /debug/dashboard serves, rebuilt offline from the
 # gate's telemetry dir (or the local registry when the gate kept none).
@@ -1161,24 +1158,5 @@ stamp("sim_smoke", {
 })
 PYEOF
   rm -rf "$sim_dir"
-fi
-# Bench regression gate (ADVISORY): when two result files exist, diff
-# the newest pair; a >10% throughput/MFU regression prints loudly but
-# never fails the tier-1 gate (bench noise on shared CI boxes is real
-# — promote by dropping the `|| true` once runs are on quiet hardware).
-if [ "$rc" -eq 0 ]; then
-  mapfile -t bench_files < <(ls -t BENCH_r*.json BENCH_partial.json 2>/dev/null | head -2)
-  if [ "${#bench_files[@]}" -eq 2 ]; then
-    echo "--- bench regression check (advisory) ---"
-    python scripts/bench_compare.py "${bench_files[1]}" "${bench_files[0]}" || true
-  fi
-  # Smoke-gate metrics drift (ADVISORY): same rules, over the
-  # VERIFY_METRICS.json the gates above just stamped vs the previous
-  # run's stamp (preempt MTTR, serve fill, time-to-grow, SLO MTTR).
-  prev_metrics="${VERIFY_METRICS_PATH%.json}.prev.json"
-  if [ -f "$prev_metrics" ] && [ -f "$VERIFY_METRICS_PATH" ]; then
-    echo "--- smoke-metrics drift check (advisory) ---"
-    python scripts/bench_compare.py "$prev_metrics" "$VERIFY_METRICS_PATH" || true
-  fi
 fi
 exit $rc

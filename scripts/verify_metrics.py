@@ -5,10 +5,8 @@ Each verify.sh smoke gate loads this file inside its heredoc
 to the repo root) and calls ``stamp("<gate>_smoke", {...})`` with the
 numbers its assertions already computed: preempt MTTR, serve fill and
 reply rate, autoscaler time-to-grow, SLO breach-detect latency and
-MTTR. The leaves live under a top-level ``configs`` section so
-``scripts/bench_compare.py`` diffs them with the same extraction rules
-it applies to BENCH files — ``*per_sec*`` / ``batch_fill`` leaves are
-higher-is-better, ``*mttr_s`` / ``time_to_*`` leaves lower-is-better.
+MTTR. The leaves live under a top-level ``configs`` section, one entry
+per gate; the sim gate reads ``load_smoke.knee_rps`` from it.
 
 No-op when ``VERIFY_METRICS_PATH`` is unset (gates run standalone).
 """

@@ -6,7 +6,7 @@ The reference tests against a real local Ray cluster in two client modes
 multi-chip collectives (psum over dp, ring attention over sp, tensor-parallel
 matmuls over tp) execute for real in every test, without TPU hardware.
 
-bench.py and production code never import this — only pytest does.
+Production code never imports this — only pytest does.
 """
 import os
 

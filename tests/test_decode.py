@@ -33,6 +33,10 @@ def _fresh_metrics():
     metrics.reset()
 
 
+def _counter(name):
+    return metrics.snapshot()["counters"].get(name, 0)
+
+
 # ---------------------------------------------------------------------
 # kv buckets
 # ---------------------------------------------------------------------
@@ -296,6 +300,24 @@ def test_decode_group_streams_and_phases():
         assert stats["decode"]["ttft_p50_s"] is not None
 
 
+def _paced_toy_engine(round_s):
+    # Nested so cloudpickle ships it by value — a replica subprocess
+    # cannot import this test module by name.
+    def factory():
+        import time as _time
+
+        from raydp_tpu.serve.decode import ToyDecodeEngine
+
+        class Paced(ToyDecodeEngine):
+            def step(self, *args):
+                _time.sleep(round_s)
+                return super().step(*args)
+
+        return Paced()
+
+    return factory
+
+
 def test_decode_replica_kill_requeues_as_prefills(monkeypatch):
     """serve_kill lands at a LATER admission (request index 4), so the
     first wave is already streaming tokens when the replica dies. The
@@ -308,9 +330,15 @@ def test_decode_replica_kill_requeues_as_prefills(monkeypatch):
     )
     with ReplicaGroup(
         replicas=1, label="t-deckill", mode="decode",
+        # 64 rounds at 20 ms keep the first wave in flight for over a
+        # second, however slowly a loaded host gets the fifth admission
+        # to the replica.
+        model_fn=_paced_toy_engine(0.02),
         restart_backoff_s=0.1, max_restarts=3,
     ).start() as group:
         prompts = [[i + 1, i + 2, i + 3] for i in range(4)]
+        # the counter is the process's, not this group's: read a delta
+        before = _counter("decode/tokens")
         reqs = [
             group.submit_generate(p, max_new=64, timeout_s=60.0)
             for p in prompts
@@ -318,7 +346,7 @@ def test_decode_replica_kill_requeues_as_prefills(monkeypatch):
         # wait until the first wave is actually mid-decode
         deadline = time.monotonic() + 20.0
         while time.monotonic() < deadline:
-            if metrics.snapshot()["counters"].get("decode/tokens", 0) >= 4:
+            if _counter("decode/tokens") - before >= 4:
                 break
             time.sleep(0.005)
         # the 5th admission trips the kill clause on incarnation 0

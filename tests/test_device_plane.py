@@ -2,7 +2,6 @@
 loader's wait counter, and the gang-coordinated trace capture."""
 import gzip
 import json
-import os
 import threading
 import time
 import urllib.request
@@ -341,43 +340,3 @@ def test_debug_profile_endpoint():
         assert err.value.code == 400
     finally:
         server.close()
-
-
-# -- bench_compare -----------------------------------------------------------
-
-def _bench_doc(rate, mfu=0.4):
-    return {
-        "metric": "m", "value": rate, "unit": "x/s",
-        "configs": {"cfg": {"samples_per_sec": rate, "mfu": mfu}},
-        "cpu_matrix": {"cfg": {"samples_per_sec": rate}},
-    }
-
-
-def test_bench_compare_exit_codes(tmp_path):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_compare",
-        os.path.join(os.path.dirname(__file__), "..", "scripts",
-                     "bench_compare.py"),
-    )
-    bc = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bc)
-
-    old = tmp_path / "old.json"
-    same = tmp_path / "same.json"
-    slow = tmp_path / "slow.json"
-    junk = tmp_path / "junk.json"
-    old.write_text(json.dumps(_bench_doc(100.0)))
-    same.write_text(json.dumps(_bench_doc(95.0)))  # -5%: within threshold
-    slow.write_text(json.dumps(_bench_doc(50.0, mfu=0.1)))
-    junk.write_text(json.dumps({"n": 1, "cmd": "x", "rc": 1,
-                                "tail": "...", "parsed": None}))
-    assert bc.main([str(old), str(same)]) == 0
-    assert bc.main([str(old), str(slow)]) == 1
-    assert bc.main([str(old), str(junk)]) == 2
-    assert bc.main([str(old), str(tmp_path / "missing.json")]) == 2
-    # MFU regressions are caught independently of rates.
-    mfu_only = tmp_path / "mfu.json"
-    mfu_only.write_text(json.dumps(_bench_doc(100.0, mfu=0.1)))
-    assert bc.main([str(old), str(mfu_only)]) == 1

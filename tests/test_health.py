@@ -412,6 +412,29 @@ def test_logs_install_is_idempotent_and_noop_without_dir(tmp_path, monkeypatch):
     assert h1 not in logging.getLogger().handlers
 
 
+def test_logs_install_follows_a_new_directory(tmp_path):
+    """The handler outlives the session that installed it
+    (``Cluster.start``); the next session's records belong in ITS
+    telemetry directory, not in the first one's."""
+    first, second = tmp_path / "first", tmp_path / "second"
+    log = logging.getLogger("raydp_tpu.tests.redirect")
+    log.setLevel(logging.INFO)
+    h1 = logs.install(directory=str(first))
+    try:
+        log.info("to the first")
+        h2 = logs.install(directory=str(second))
+        assert h2 is not h1
+        log.info("to the second")
+        handlers = logging.getLogger().handlers
+        assert h1 not in handlers and handlers.count(h2) == 1
+    finally:
+        logs.uninstall()
+    assert [r["message"] for r in logs.read_records(str(first))] == \
+        ["to the first"]
+    assert [r["message"] for r in logs.read_records(str(second))] == \
+        ["to the second"]
+
+
 # ---------------------------------------------------------------------
 # Export surface
 
@@ -538,8 +561,17 @@ def test_acceptance_wedged_worker_health_report_healthz_and_postmortem(
         # arrived on a live beat, far inside the death-detection window.
         assert victim_info["heartbeat_age_s"] < HEARTBEAT_TIMEOUT_S / 2
         assert victim not in report["dead_workers"]
+        # The peer may still be inside the warm-up task every worker
+        # gets at start (pandas' import, over the 1 s threshold on a
+        # loaded host): that one ends, the wedge does not.
         healthy_peer = workers[1]
+        deadline = time.monotonic() + 30.0
+        while (report["workers"][healthy_peer]["stalls"]
+               and time.monotonic() < deadline):
+            time.sleep(0.5)
+            report = cl.health_report()
         assert not report["workers"][healthy_peer]["stalls"]
+        assert victim in report["stalled_workers"], report
 
         # (b) the wedged process's own endpoint: /healthz 503 while
         # /metrics keeps serving. Port comes from the worker's log line.

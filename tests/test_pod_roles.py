@@ -4,6 +4,7 @@ over the network (what deploy/k8s/raydp-tpu-pod.yaml runs)."""
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import pytest
@@ -48,3 +49,36 @@ def test_driver_and_host_roles_cross_process():
             host.wait(timeout=10)
         except subprocess.TimeoutExpired:
             host.kill()
+
+
+def test_agent_waits_for_a_master_that_listens_late():
+    """A host pod's agent may be up before the driver pod's master:
+    it waits for the master to listen, where one refused Ping was fatal."""
+    from raydp_tpu.cluster.rpc import RpcServer
+    from raydp_tpu.store.agent import StoreAgent
+
+    port = find_free_port()
+    namespace = f"late-master-{port}"
+    started = []
+
+    def listen():
+        started.append(RpcServer(
+            "raydp.AppMaster",
+            {"Ping": lambda req: {"pong": True, "namespace": namespace}},
+            host="127.0.0.1", port=port,
+        ))
+
+    # well after the agent's first connection is refused
+    late = threading.Timer(1.5, listen)
+    late.start()
+    agent = None
+    try:
+        agent = StoreAgent(None, "pod-late", f"127.0.0.1:{port}")
+        assert agent.store.namespace == namespace
+    finally:
+        late.join()
+        if agent is not None:
+            agent._server.stop()
+            agent.store.destroy()
+        for server in started:
+            server.stop()

@@ -37,6 +37,10 @@ def _fresh_metrics():
     metrics.reset()
 
 
+def _counter(name):
+    return metrics.snapshot()["counters"].get(name, 0)
+
+
 # ---------------------------------------------------------------------
 # RequestQueue: buckets, shedding, continuous assembly, at-most-once
 # ---------------------------------------------------------------------
@@ -286,6 +290,16 @@ def _make_model(delay_s=0.0):
     return model
 
 
+def _wait_all_alive(group, timeout_s=60.0):
+    """``start()`` returns while the replicas are still coming up, and
+    the first to register can serve a whole test alone: a test that
+    counts on every replica (or kills a particular one) waits here."""
+    deadline = time.monotonic() + timeout_s
+    while group.stats()["replicas_alive"] < group.replicas:
+        assert time.monotonic() < deadline, group.stats()
+        time.sleep(0.05)
+
+
 def _submit_and_wait_all(group, n, length=3):
     reqs = [group.submit([i] * length) for i in range(n)]
     return [r.wait(timeout=60.0) for r in reqs]
@@ -296,6 +310,7 @@ def test_group_end_to_end_batches_and_stats():
         replicas=2, model_fn=_make_model(), label="t-serve",
         max_batch=4, slo_ms=25, restart_backoff_s=0.1,
     ).start() as group:
+        _wait_all_alive(group)
         results = _submit_and_wait_all(group, 24)
         assert results == [float(i * 3) for i in range(24)]
         stats = group.stats()
@@ -316,6 +331,7 @@ def test_serve_kill_failover_drops_nothing(monkeypatch):
         replicas=2, model_fn=_make_model(), label="t-kill",
         max_batch=4, slo_ms=25, restart_backoff_s=0.1, max_restarts=3,
     ).start() as group:
+        _wait_all_alive(group)
         results = _submit_and_wait_all(group, 40)
         # zero drops: every accepted request got exactly one reply
         assert results == [float(i * 3) for i in range(40)]
@@ -357,13 +373,15 @@ def test_sigterm_drains_in_flight_batch():
         replicas=2, model_fn=_make_model(delay_s=0.3), label="t-drain",
         max_batch=4, slo_ms=25, restart_backoff_s=0.1,
     ).start() as group:
+        _wait_all_alive(group)
+        # the counter is the process's, not this group's: read a delta
+        before = _counter("serve/batches")
         reqs = [group.submit([i]) for i in range(12)]
         # wait until a replica is actually mid-batch, then SIGTERM it
         slot = group._slots[0]
         deadline = time.monotonic() + 10.0
         while time.monotonic() < deadline:
-            snap = metrics.snapshot()["counters"]
-            if snap.get("serve/batches", 0) >= 1:
+            if _counter("serve/batches") - before >= 1:
                 break
             time.sleep(0.02)
         victim = slot.proc
