@@ -7,6 +7,7 @@ from raydp_tpu.models.transformer import (
     TransformerEncoder,
     bert_base,
     granite_h_micro,
+    kimi_linear_48b_a3b,
     lfm2_8b_a1b,
     olmoe,
     param_shardings,
@@ -15,6 +16,7 @@ from raydp_tpu.models.transformer import (
     xing4_0,
 )
 from raydp_tpu.models.hyperconn import HyperConfig
+from raydp_tpu.models.kda import KDAConfig
 from raydp_tpu.models.latent import LatentConfig
 
 from raydp_tpu.models.dlrm import (
@@ -58,11 +60,13 @@ __all__ = [
     "CausalLM",
     "bert_base",
     "granite_h_micro",
+    "kimi_linear_48b_a3b",
     "lfm2_8b_a1b",
     "olmoe",
     "laguna_xs_2",
     "xing4_0",
     "HyperConfig",
+    "KDAConfig",
     "LatentConfig",
     "tiny_transformer",
     "param_shardings",

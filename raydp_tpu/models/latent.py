@@ -26,6 +26,12 @@ absorbed form, in which the up-projections fold into ``q`` and the output
 and the cache holds ``c_kv`` and ``k_r`` alone, is the serving plane's
 (ROADMAP R3); nothing here keeps a cache.
 
+With ``q_rank`` None the queries come from ONE full-rank projection of
+``h`` (no ``q_down``, no ``q_norm``; the projection keeps the name
+``q_up``), and in a stack without positions (``positions="none"``: another
+layer kind carries them) the ``rope`` features of ``q`` and the shared key
+stay and nothing is rotated.
+
 Scopes under the module (``attn`` in a block): ``q_down``, ``q_norm``,
 ``q_up``, ``kv_down``, ``kv_norm``, ``kv_up``, ``rope``, the kernel's, ``out``.
 """
@@ -48,7 +54,7 @@ class LatentConfig:
     """The latent mixer's own sizes (the head count, ``rope_theta`` and
     the norm's epsilon are the stack's)."""
 
-    q_rank: int = 768
+    q_rank: Optional[int] = 768      # None = one full-rank q projection
     kv_rank: int = 512
     nope_dim: int = 128
     rope_dim: int = 64
@@ -89,21 +95,30 @@ class LatentAttention(nn.Module):
         from raydp_tpu.ops.attention import reference_attention
 
         cfg, lat = self.cfg, self.cfg.latent
-        if not cfg.causal or cfg.positions != "rotary":
-            raise ValueError("latent attention: a causal rotary stack")
+        if not cfg.causal or cfg.positions not in ("rotary", "none"):
+            raise ValueError(
+                "latent attention: a causal stack, rotary or without "
+                "positions"
+            )
         h, nope = cfg.n_heads, lat.nope_dim
         project = functools.partial(
             nn.DenseGeneral, axis=-1, use_bias=cfg.use_bias, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
         )
-        c_q = project(
-            features=lat.q_rank, kernel_init=_dense_init("embed", None),
-            name="q_down",
-        )(x)
-        q = project(
-            features=(h, lat.qk_dim),
-            kernel_init=_dense_init(None, "heads", "kv"), name="q_up",
-        )(_norm(cfg, "q_norm")(c_q))
+        if lat.q_rank is None:
+            q = project(
+                features=(h, lat.qk_dim),
+                kernel_init=_dense_init("embed", "heads", "kv"), name="q_up",
+            )(x)
+        else:
+            c_q = project(
+                features=lat.q_rank, kernel_init=_dense_init("embed", None),
+                name="q_down",
+            )(x)
+            q = project(
+                features=(h, lat.qk_dim),
+                kernel_init=_dense_init(None, "heads", "kv"), name="q_up",
+            )(_norm(cfg, "q_norm")(c_q))
         down = project(
             features=lat.kv_rank + lat.rope_dim,
             kernel_init=_dense_init("embed", None), name="kv_down",
@@ -115,13 +130,17 @@ class LatentAttention(nn.Module):
         )(_norm(cfg, "kv_norm")(c_kv))
         k_nope, v = kv[..., :nope], kv[..., nope:]
         with jax.named_scope("rope"):
-            pos = jnp.arange(x.shape[-2])[None, :]
-            turn = functools.partial(
-                rotary, positions=pos, theta=cfg.rope_theta, yarn=lat.yarn
-            )
-            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], -1)
-            # One rotary key head, the same for every query head.
-            k_rope = turn(k_rope[..., None, :])
+            if cfg.positions == "rotary":
+                pos = jnp.arange(x.shape[-2])[None, :]
+                turn = functools.partial(
+                    rotary, positions=pos, theta=cfg.rope_theta,
+                    yarn=lat.yarn,
+                )
+                q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], -1)
+                k_rope = turn(k_rope[..., None, :])
+            else:
+                k_rope = k_rope[..., None, :]
+            # One such key head, the same for every query head.
             k = jnp.concatenate([
                 k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1] + (
                     lat.rope_dim,
@@ -159,9 +178,9 @@ def layers_of(cfg) -> int:
 
 
 def report(cfg) -> None:
-    """Static for a compiled step: three gauges and one log line where the
+    """Static for a compiled step: four gauges and one log line where the
     step is built (as ``models/mamba.report``). Zero for a stack without
-    latent layers."""
+    latent layers; ``latent/rotary_dims`` also where nothing is rotated."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
@@ -172,13 +191,16 @@ def report(cfg) -> None:
         "attention/latent_cache_bytes_per_token",
         lat.cache_bytes_per_token(layers) if lat else 0,
     )
+    rotated = lat.rope_dim if lat and cfg.positions == "rotary" else 0
+    metrics.gauge_set("latent/rotary_dims", rotated)
     if lat:
         logger.info(
             "latent attention: %d layers, %d heads of %d + %d (q, k) and %d "
-            "(v) from latents of %d (q) and %d (kv); one shared rotary key "
-            "of %d; softmax scale %g; expanded form, %d B a token in a "
-            "latent cache",
+            "(v) from latents of %s (q) and %d (kv); one shared key of %d, "
+            "%d of them rotated; softmax scale %g; expanded form, %d B a "
+            "token in a latent cache",
             layers, cfg.n_heads, lat.nope_dim, lat.rope_dim, lat.v_dim,
-            lat.q_rank, lat.kv_rank, lat.rope_dim, lat.softmax_scale,
+            "no rank" if lat.q_rank is None else lat.q_rank, lat.kv_rank,
+            lat.rope_dim, rotated, lat.softmax_scale,
             lat.cache_bytes_per_token(layers),
         )

@@ -78,13 +78,15 @@ def causal_depthwise_conv(x, kernel, bias=None):
 
 
 class CausalConv1d(nn.Module):
-    """Depthwise causal convolution over the sequence with a bias, and the
-    SiLU that follows it: ``silu(b + Σ_j w_j · in_{t-(k-1)+j})``, zeros
-    before the first token (``kernel`` [k, channels])."""
+    """Depthwise causal convolution over the sequence with a bias (none
+    with ``use_bias`` off), and the SiLU that follows it: ``silu(b + Σ_j
+    w_j · in_{t-(k-1)+j})``, zeros before the first token (``kernel``
+    [k, channels])."""
 
     taps: int
     dtype: jnp.dtype
     param_dtype: jnp.dtype
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -96,7 +98,7 @@ class CausalConv1d(nn.Module):
         )
         bias = self.param(
             "bias", _replicated(init), (channels,), self.param_dtype,
-        )
+        ) if self.use_bias else None
         return jax.nn.silu(
             causal_depthwise_conv(x, kernel, bias)
         ).astype(self.dtype)

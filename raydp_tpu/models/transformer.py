@@ -55,7 +55,9 @@ LOGICAL_RULES: Tuple[Tuple[str, Optional[str]], ...] = (
 )
 
 
-MIXERS = frozenset({"attention", "window", "mamba", "conv", "latent"})
+MIXERS = frozenset(
+    {"attention", "window", "mamba", "conv", "latent", "kda"}
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,15 +149,17 @@ class TransformerConfig:
     moe_loss_weights: Tuple[float, float] = (1e-2, 1e-3)   # balance, z
     shared_experts: int = 0          # of d_expert each, beside the routed
     # Layer i as "<mixer>" or "<mixer>:<ffn>": the mixer is "attention" |
-    # "mamba" | "conv" | "latent", the FFN kind one of ``ffn``'s and ``ffn``
-    # itself where the entry names none. None = attention and ``ffn``
-    # everywhere.
+    # "window" | "mamba" | "conv" | "latent" | "kda", the FFN kind one of
+    # ``ffn``'s and ``ffn`` itself where the entry names none. None =
+    # attention and ``ffn`` everywhere.
     layer_types: Optional[Tuple[str, ...]] = None
     # A mixer's or the residual path's own sizes, a record each:
     # ``models/latent.LatentConfig`` for the "latent" mixer,
+    # ``models/kda.KDAConfig`` for the "kda" mixer,
     # ``models/hyperconn.HyperConfig`` for more than one residual stream
     # (None = the plain ``x + F(norm(x))``).
     latent: Any = None
+    kda: Any = None
     hyper: Any = None
     # :class:`WindowConfig` for the "window" mixer; the four fields after
     # it describe the "attention" mixer beside it (and a window layer's
@@ -515,7 +519,7 @@ class TransformerBlock(nn.Module):
     (``models/hyperconn.py``; scopes ``hc_attn`` and ``hc_ffn``)."""
 
     cfg: TransformerConfig
-    mixer: str = "attention"  # attention | window | mamba | conv | latent
+    mixer: str = "attention"         # one of ``MIXERS``
     ffn: Optional[str] = None        # None = cfg.ffn
 
     @nn.compact
@@ -559,6 +563,8 @@ class TransformerBlock(nn.Module):
                             "tokens",
                     "latent": "no decode cache for the key-value latent "
                               "(ROADMAP R3)",
+                    "kda": "no decode cache for a delta-rule layer's state "
+                           "(ROADMAP R11)",
                 }[self.mixer])
             if self.mixer == "mamba":
                 from raydp_tpu.models.mamba import Mamba2Mixer
@@ -570,6 +576,12 @@ class TransformerBlock(nn.Module):
                 from raydp_tpu.models.shortconv import ShortConv
 
                 return ShortConv(cfg, name="conv")(_norm(cfg, "ln_conv")(h))
+            if self.mixer == "kda":
+                from raydp_tpu.models.kda import KimiDeltaMixer
+
+                return KimiDeltaMixer(cfg, name="kda")(
+                    _norm(cfg, "ln_kda")(h)
+                )
             from raydp_tpu.models.latent import LatentAttention
 
             return LatentAttention(cfg, name="attn")(_norm(cfg, "ln_attn")(h))
@@ -706,16 +718,21 @@ class TransformerEncoder(nn.Module):
         # [B, H, S] array because the kernel's [B, H, S, 1] columns are
         # 128 times their bytes in HBM's tiling. A block that calls no
         # flash kernel (dense, ring, ulysses) holds no such name and
-        # keeps its input alone.
+        # keeps its input alone. A delta-rule layer's scan names its output
+        # and the few states its segments were entered with for the same
+        # reason (``ops/kda.KEPT``).
         if cache_mode is not None and cfg.remat:
             raise ValueError("decode cache is incompatible with remat")
         block_cls = TransformerBlock
         if cfg.remat:
             from raydp_tpu.ops.flash_attention import KEPT
+            from raydp_tpu.ops.kda import KEPT as KDA_KEPT
 
             block_cls = nn.remat(
                 TransformerBlock, static_argnums=(2,),
-                policy=jax.checkpoint_policies.save_only_these_names(*KEPT),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *KEPT, *KDA_KEPT
+                ),
             )
         if cfg.hyper is not None:
             from raydp_tpu.models import hyperconn
@@ -1080,6 +1097,51 @@ def laguna_xs_2(**overrides) -> TransformerConfig:
             ("attention" if i % 4 == 0 else "window")
             + (":swiglu" if i < dense else ":moe") for i in range(n_layers)
         ),
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def kimi_linear_48b_a3b(**overrides) -> TransformerConfig:
+    """Moonshot AI Kimi-Linear-48B-A3B (49.1B parameters, about 3B active;
+    ``config.json`` of moonshotai/Kimi-Linear-48B-A3B-Instruct,
+    ``model_type`` kimi_linear; arXiv:2510.26692): 27 pre-norm layers of
+    width 2304 and NO positions anywhere; Kimi Delta Attention (32 heads
+    with keys and values of 128, 4-tap convolutions, a decay per channel;
+    ``models/kda.py``) in three layers of four and latent attention
+    without a query latent or a rotation (32 heads of 128 + 64 for q and
+    k and 128 for v from a key-value latent of 512) in layers 4, 8, …, 24
+    and 27 (1-indexed); a dense SwiGLU FFN of width 9216 in the first
+    layer and 256 SwiGLU experts of width 1024 beside one shared expert in
+    the others, 8 a token by sigmoid score + a selection bias, their
+    scores divided by their sum and times 2.446; RMSNorm 1e-5, no biases,
+    no auxiliary loss; vocabulary 163840, untied head. ``experts_held`` /
+    ``first_expert`` give a layer the share of an expert-parallel
+    deployment; ``n_layers`` and ``dense_layers`` keep the model's own
+    first layers."""
+    from raydp_tpu.models.kda import KDAConfig
+    from raydp_tpu.models.latent import LatentConfig
+
+    overrides = dict(overrides)
+    n_layers = overrides.get("n_layers", 27)
+    dense = overrides.pop("dense_layers", 1)
+    latent_layers = {3, 7, 11, 15, 19, 23, 26}
+    defaults = dict(
+        vocab_size=163840, d_model=2304, n_heads=32, n_layers=n_layers,
+        d_ff=9216, max_len=1048576, dropout_rate=0.0, causal=True,
+        norm="rmsnorm", norm_eps=1e-5, positions="none", use_bias=False,
+        ffn="moe", n_experts=256, top_k=8, d_expert=1024, shared_experts=1,
+        router_scoring="sigmoid", router_bias=True, norm_top_k=True,
+        routed_scaling=2.446, moe_loss_weights=(0.0, 0.0), tie_head=False,
+        layer_types=tuple(
+            ("latent" if i in latent_layers else "kda")
+            + (":swiglu" if i < dense else ":moe") for i in range(n_layers)
+        ),
+        latent=LatentConfig(
+            q_rank=None, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        ),
+        kda=KDAConfig(heads=32, key_dim=128, value_dim=128, conv_taps=4,
+                      gate_rank=128, chunk=64),
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

@@ -31,6 +31,7 @@ from raydp_tpu.data.ml_dataset import MLDataset
 from raydp_tpu.models import (
     dropout,
     hyperconn,
+    kda,
     latent,
     mamba,
     moe,
@@ -488,6 +489,9 @@ class JAXEstimator:
         dropout.report(sites, words)
         tokens_per_step = int(np.prod(self._sample_batch.shape))
         mamba.report(
+            getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
+        )
+        kda.report(
             getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
         )
         shortconv.report(getattr(self._model, "cfg", None))

@@ -1,0 +1,171 @@
+"""``ops/kda.py``: the chunked gated delta rule with a decay per channel
+against the token-by-token scan — output and the gradients of ``q``,
+``k``, ``v``, ``g`` and ``beta`` — over chunk sizes, head widths and a
+decay strong enough to overflow a factored form; the shapes it refuses;
+the recurrence's two limits against closed forms (``beta -> 0``: a gated
+linear attention; ``g = 0`` and ``beta = 1``: the plain delta rule); the
+triangular inverse against ``numpy``; and the residuals a checkpoint's
+policy keeps. Tiny sizes, float32, the CPU."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from raydp_tpu.ops import kda as kda_ops
+from raydp_tpu.ops.kda import kda_chunked, kda_recurrent, unit_lower_inverse
+
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+def _inputs(seed=0, b=2, s=64, h=2, d_k=16, d_v=8, strength=1.0):
+    """Unit ``q`` and ``k``, log-decays log-uniform in [-3, -1e-3] a token
+    times ``strength``, ``beta`` in (0.05, 0.95)."""
+    rng = np.random.default_rng(seed)
+    q = rng.standard_normal((b, s, h, d_k))
+    k = rng.standard_normal((b, s, h, d_k))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.standard_normal((b, s, h, d_v))
+    g = -np.exp(rng.uniform(np.log(1e-3), np.log(3.0), (b, s, h, d_k)))
+    beta = rng.uniform(0.05, 0.95, (b, s, h))
+    return tuple(jnp.asarray(a, jnp.float32)
+                 for a in (q, k, v, g * strength, beta))
+
+
+def _rel(got, want):
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
+def _grads(fn, args):
+    weights = jnp.cos(jnp.arange(args[2].shape[-1], dtype=jnp.float32))
+    return jax.jit(jax.grad(
+        lambda *a: jnp.sum(fn(*a) * weights), argnums=(0, 1, 2, 3, 4)
+    ))(*args)
+
+
+@pytest.mark.parametrize("chunk,s,d_k,d_v,strength,tol", [
+    (16, 16, 16, 8, 1.0, 5e-6),      # one chunk
+    (16, 48, 16, 8, 1.0, 5e-6),      # several: three, not a power of two
+    (64, 128, 8, 16, 1.0, 5e-6),     # d_k != d_v, the cell's chunk
+    # A chunk's cumulative log-decay passes -1000: exp(-G) of a factored
+    # form is infinite in float32 from -88 on. The cumulative sums' own
+    # rounding is what is left.
+    (64, 128, 16, 8, 40.0, 2e-4),
+])
+def test_chunked_is_the_token_by_token_scan(chunk, s, d_k, d_v, strength,
+                                            tol):
+    args = _inputs(s=s, d_k=d_k, d_v=d_v, strength=strength)
+    if strength > 1:
+        per_chunk = args[3].reshape(2, s // chunk, chunk, 2, d_k).sum(2)
+        assert float(per_chunk.min()) < -1000
+    want = jax.jit(kda_recurrent)(*args)
+    got = jax.jit(lambda *x: kda_chunked(*x, chunk))(*args)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert _rel(got, want) < tol
+    for name, a, b in zip(NAMES, _grads(lambda *x: kda_chunked(*x, chunk),
+                                        args), _grads(kda_recurrent, args)):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert _rel(a, b) < 3 * tol, name
+
+
+def test_a_sequence_runs_in_segments(monkeypatch):
+    """Four segments of two chunks give what one segment of eight gives,
+    up to float32 rounding, and the gradients handed back from one to the
+    one before it are the scan's."""
+    args = _inputs(s=64)
+    whole = jax.jit(lambda *x: kda_chunked(*x, 8))(*args)
+    monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 2)
+    cut = jax.jit(lambda *x: kda_chunked(*x, 8))(*args)
+    assert _rel(cut, whole) < 2e-6
+    for a, b in zip(_grads(lambda *x: kda_chunked(*x, 8), args),
+                    _grads(kda_recurrent, args)):
+        assert _rel(a, b) < 2e-5
+
+
+@pytest.mark.parametrize("s,chunk,message", [
+    (40, 16, "sequence 40 is not a multiple of chunk 16"),
+    (48, 12, "chunk 12 is not a power of two"),
+])
+def test_shapes_it_cannot_cut_are_refused(s, chunk, message):
+    with pytest.raises(ValueError, match=message):
+        kda_chunked(*_inputs(s=s), chunk)
+
+
+def _pairwise_decay(g):
+    """``exp(G_t - G_j)`` for j <= t, 0 above: [b, h, t, j, d] float64."""
+    G = np.cumsum(np.asarray(g, np.float64), axis=1).transpose(0, 2, 1, 3)
+    diff = G[:, :, :, None] - G[:, :, None, :]
+    s = G.shape[2]
+    return np.where(np.tril(np.ones((s, s), bool))[..., None],
+                    np.exp(np.minimum(diff, 0.0)), 0.0)
+
+
+def test_beta_zero_writes_nothing_and_its_limit_is_gated_linear_attention():
+    q, k, v, g, beta = _inputs(s=32)
+    assert float(jnp.abs(kda_chunked(q, k, v, g, 0 * beta, 16)).max()) == 0.0
+    # To first order in beta the correction -beta k k^T S drops out:
+    # o_t = sum_{j<=t} beta_j (sum_d q_td k_jd e^{G_td - G_jd}) v_j.
+    small = 1e-4 * beta
+    got = np.asarray(kda_chunked(q, k, v, g, small, 16), np.float64)
+    weights = np.einsum(
+        "bthd,bjhd,bhtjd->bhtj", *(np.asarray(a, np.float64) for a in (q, k)),
+        _pairwise_decay(g),
+    )
+    want = np.einsum(
+        "bhtj,bjh,bjhv->bthv", weights, np.asarray(small, np.float64),
+        np.asarray(v, np.float64),
+    )
+    assert np.abs(got - want).max() / np.abs(want).max() < 1e-3
+
+
+def test_no_decay_and_beta_one_is_the_plain_delta_rule():
+    """``S_t = (I - k k^T) S_{t-1} + k v^T`` by a loop in float64; a key
+    met a second time reads back the value last written for it."""
+    q, k, v, g, beta = _inputs(s=32, b=1, h=1)
+    k = k.at[0, 20, 0].set(k[0, 3, 0])
+    got = np.asarray(kda_chunked(q, k, v, 0 * g, 0 * beta + 1, 16))
+    K, V, Q = (np.asarray(a[0, :, 0], np.float64) for a in (k, v, q))
+    state, want = np.zeros((K.shape[1], V.shape[1])), []
+    for k_t, v_t, q_t in zip(K, V, Q):
+        state = state - np.outer(k_t, k_t @ state) + np.outer(k_t, v_t)
+        want.append(q_t @ state)
+        np.testing.assert_allclose(k_t @ state, v_t, atol=1e-6)
+    np.testing.assert_allclose(got[0, :, 0], np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("n", [16, 64])
+def test_the_triangular_inverse_and_its_backward(n):
+    rng = np.random.default_rng(n)
+    a = jnp.asarray(np.tril(rng.standard_normal((3, n, n)), -1) * 0.5,
+                    jnp.float32)
+    got = unit_lower_inverse(a)
+    want = np.linalg.inv(np.eye(n) + np.asarray(a, np.float64))
+    assert np.abs(np.asarray(got) - want).max() / np.abs(want).max() < 1e-4
+    # What lies on or above the diagonal is not read.
+    noisy = a + jnp.triu(jnp.ones((n, n)))
+    np.testing.assert_array_equal(unit_lower_inverse(noisy), got)
+    weights = jnp.asarray(rng.standard_normal((3, n, n)), jnp.float32)
+    d_got = jax.grad(lambda m: jnp.sum(unit_lower_inverse(m) * weights))(a)
+    d_want = jax.grad(lambda m: jnp.sum(jnp.linalg.inv(
+        jnp.eye(n) + jnp.tril(m, -1)) * weights))(a)
+    assert _rel(d_got, d_want) < 1e-4
+
+
+def test_the_forward_names_what_a_checkpoint_keeps(monkeypatch):
+    """Under a checkpoint whose policy keeps ``KEPT`` the gradient runs
+    the chunk quantities as often as without a checkpoint (forward, and
+    the backward's own rebuild); a bare checkpoint runs them once more.
+    Each run of a segment holds one cumulative sum of ``g``."""
+    monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 1)
+    args = _inputs(s=32)
+
+    def loss(*a):
+        return jnp.sum(kda_chunked(*a, 16))
+
+    policy = jax.checkpoint_policies.save_only_these_names(*kda_ops.KEPT)
+    kept = jax.checkpoint(loss, policy=policy)
+
+    def sums(fn):
+        return str(jax.make_jaxpr(jax.grad(fn))(*args)).count("cumsum")
+
+    assert sums(kept) == sums(loss) < sums(jax.checkpoint(loss))

@@ -9,6 +9,7 @@ pair, the per-layer pattern of mixers and FFN kinds, per-head QK-norm, a
 selection bias that training leaves as it was, the old families untouched,
 and no serving from a K/V cache the stack does not have."""
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -236,24 +237,31 @@ def _held_by(variables, first, held=2):
            for w in ("w_gate", "w_up", "w_down")}))
 
 
-def _plain_routed(variables, x, experts, scale=1.0):
-    """``sum_j g_j E_j(x)`` over ``experts`` by a loop over tokens."""
+def _plain_routed(variables, x, experts, scale=1.0, top_k=2, shared=False):
+    """``sum_j g_j E_j(x)`` over ``experts`` by a loop over tokens (plus
+    the shared expert's output with ``shared``)."""
     p = jax.tree_util.tree_map(
         lambda a: np.asarray(a, np.float64), variables["params"])
     bias = np.asarray(variables[moe_module.BUFFERS]["expert_bias"], np.float64)
     tokens = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
     out, chosen, unbiased = np.zeros_like(tokens), [], []
+
+    def swiglu(h, up):
+        return h / (1.0 + np.exp(-h)) * up
+
     for t, y in enumerate(tokens):
         s = 1.0 / (1.0 + np.exp(-(y @ p["router"]["kernel"])))
-        picked = np.argsort(-(s + bias), kind="stable")[:2]
+        picked = np.argsort(-(s + bias), kind="stable")[:top_k]
         chosen.append(set(picked))
-        unbiased.append(set(np.argsort(-s, kind="stable")[:2]))
+        unbiased.append(set(np.argsort(-s, kind="stable")[:top_k]))
         gates = s[picked] / (s[picked].sum() + 1e-6) * scale
         for e, g in zip(picked, gates):
             if e in experts:
-                h = y @ p["w_gate"][e]
-                h = h / (1.0 + np.exp(-h)) * (y @ p["w_up"][e])
+                h = swiglu(y @ p["w_gate"][e], y @ p["w_up"][e])
                 out[t] += g * (h @ p["w_down"][e])
+        if shared:
+            gate, up = np.split(y @ p["shared"]["in"]["kernel"], 2)
+            out[t] += swiglu(gate, up) @ p["shared"]["out"]["kernel"]
     return out.reshape(x.shape), chosen, unbiased
 
 
@@ -271,30 +279,49 @@ def test_sigmoid_bias_normalised_routing_against_a_plain_loop(uncut):
         np.asarray(scaled), 2.5 * want, rtol=2e-4, atol=2e-5)
 
 
-def test_the_four_shares_add_up_to_the_uncut_layer(uncut):
-    """The share test: each of four chips holds 2 of the 8 experts, routes
-    over all 8, and returns its own experts' part; the parts sum to what
-    the uncut layer, and the plain loop, give."""
-    layer, variables, x = uncut
+@pytest.mark.parametrize("experts,held,top_k,shared,scale", [
+    (8, 2, 2, 0, 1.0),          # LFM2's four shares (PR 32)
+    (256, 8, 8, 1, 2.446),      # Kimi Linear's thirty-two (PR 44)
+], ids=["four_of_8", "thirty_two_of_256_and_a_shared_expert"])
+def test_the_shares_add_up_to_the_uncut_layer(experts, held, top_k, shared,
+                                              scale):
+    """The share test: each chip holds ``held`` of the experts, routes
+    over all of them, and returns its own experts' part (plus the shared
+    expert's output, which every chip computes alike); the routed parts
+    and the shared expert counted ONCE sum to what the uncut layer, and
+    the plain loop, give."""
+    kw = dict(n_experts=experts, top_k=top_k, gate_scale=scale,
+              shared_experts=shared)
+    layer = _layer(**kw)
+    x = jax.random.normal(jax.random.PRNGKey(5), (3, 7, 16))
+    variables = _init(layer, x)
+    variables[moe_module.BUFFERS]["expert_bias"] = 0.5 * jax.random.normal(
+        jax.random.PRNGKey(6), (experts,))
+    plain = functools.partial(
+        _plain_routed, variables, x, scale=scale, top_k=top_k)
     whole = layer.apply(variables, x, mutable=[moe_module.STATS])[0]
-    plain, _, _ = _plain_routed(variables, x, set(range(8)))
+    alike = plain(set(), shared=True)[0] if shared else 0.0
     total, pairs = jnp.zeros_like(whole), 0.0
-    for share in range(4):
-        first = 2 * share
-        part, sown = _layer(first, 2).apply(
-            _held_by(variables, first), x, mutable=[moe_module.STATS])
-        want, _, _ = _plain_routed(variables, x, {first, first + 1})
+    for first in range(0, experts, held):
+        part, sown = _layer(first, held, **kw).apply(
+            _held_by(variables, first, held), x, mutable=[moe_module.STATS])
+        want, _, _ = plain(set(range(first, first + held)),
+                           shared=bool(shared))
         np.testing.assert_allclose(
             np.asarray(part), want, rtol=2e-4, atol=2e-5)
         stats = sown[moe_module.STATS]
         np.testing.assert_array_equal(
-            stats["held_tokens"], stats["expert_tokens"][first:first + 2])
+            stats["held_tokens"],
+            stats["expert_tokens"][first:first + held])
         pairs += float(stats["held_tokens"].sum())
-        total = total + part
-    assert pairs == 3 * 7 * 2                   # every pair on one share
+        total = total + (part - alike)
+    assert pairs == 3 * 7 * top_k               # every pair on one share
+    total = total + alike
     np.testing.assert_allclose(
-        np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(total), plain, rtol=2e-4, atol=2e-5)
+        np.asarray(total), np.asarray(whole), rtol=2e-4, atol=5e-5)
+    np.testing.assert_allclose(
+        np.asarray(total), plain(set(range(experts)), shared=bool(shared))[0],
+        rtol=2e-4, atol=5e-5)
 
 
 def test_a_shares_gradients_are_the_plain_formulas(uncut):
