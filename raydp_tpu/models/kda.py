@@ -35,7 +35,8 @@ from raydp_tpu.models.mamba import (
     _step_bias_init,
 )
 from raydp_tpu.ops.kda import IMPLEMENTATION as SCAN_IMPLEMENTATION
-from raydp_tpu.ops.kda import kda_chunked
+from raydp_tpu.ops.kda import PATHS as SCAN_PATHS
+from raydp_tpu.ops.kda import kda_chunked, uses_kernels
 
 logger = logging.getLogger(__name__)
 
@@ -56,6 +57,20 @@ class KDAConfig:
     def state_bytes(self, layers: int) -> int:
         """What a sequence's float32 states hold, all layers."""
         return 4 * layers * self.heads * self.key_dim * self.value_dim
+
+    def scan_chunk(self, sequence: int) -> int:
+        """The chunk a sequence runs in: one that is no multiple of
+        ``chunk`` (a test's) takes the largest power of two that divides
+        both."""
+        return math.gcd(self.chunk, sequence)
+
+    def scan_runs_kernels(self, sequence: int) -> bool:
+        """Whether the scan's chunk-local step takes its Pallas kernels
+        at this sequence length: ``ops/kda.uses_kernels``, what
+        ``kda_chunked`` itself asks, of the shapes the mixer hands it."""
+        return uses_kernels(
+            self.key_dim, self.value_dim, self.scan_chunk(sequence)
+        )
 
 
 class QKVConv(nn.Module):
@@ -172,11 +187,7 @@ class KimiDeltaMixer(nn.Module):
         with jax.named_scope("beta"):
             beta = jax.nn.sigmoid(beta.astype(jnp.float32))
         with jax.named_scope("scan"):
-            # A sequence that is no multiple of the chunk (a test's) runs
-            # in the largest power of two that divides both.
-            o = kda_chunked(
-                q, k, v, g, beta, math.gcd(kda.chunk, x.shape[-2])
-            )
+            o = kda_chunked(q, k, v, g, beta, kda.scan_chunk(x.shape[-2]))
         z = dense(values, "g_up", (None, "heads"), use_bias=True)(
             dense(kda.gate_rank, "g_down", narrow)(x)
         )
@@ -192,15 +203,18 @@ def layers_of(cfg) -> int:
     return sum(1 for kind in getattr(cfg, "kinds", ()) if kind == "kda")
 
 
-def report(cfg, tokens_per_step: int) -> None:
-    """Static for a compiled step: five gauges and one log line where the
+def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
+    """Static for a compiled step: six gauges and one log line where the
     step is built (as ``models/mamba.report``). All zero for a stack
-    without such layers."""
+    without such layers. ``sequence`` is a sequence's tokens (all of a
+    step's where left out)."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
     kda = cfg.kda if layers else None
     chunks = layers * -(-tokens_per_step // kda.chunk) if kda else 0
+    kernels = bool(kda) and kda.scan_runs_kernels(sequence or tokens_per_step)
+    metrics.gauge_set("kda/scan_kernel_layers", layers if kernels else 0)
     metrics.gauge_set("kda/layers", layers)
     metrics.gauge_set("kda/heads", kda.heads if kda else 0)
     metrics.gauge_set("kda/chunk", kda.chunk if kda else 0)
@@ -212,8 +226,9 @@ def report(cfg, tokens_per_step: int) -> None:
         logger.info(
             "delta-rule stack: layers %s; %d heads of %d (q, k) and %d (v), "
             "a decay per channel, %d-tap convolutions as %s, gates of rank "
-            "%d; chunk %d (%d chunks a step); scan: %s",
+            "%d; chunk %d (%d chunks a step); scan: %s; a chunk's work at "
+            "these shapes: %s",
             " ".join(cfg.kinds), kda.heads, kda.key_dim, kda.value_dim,
             kda.conv_taps, CONV_IMPLEMENTATION, kda.gate_rank, kda.chunk,
-            chunks, SCAN_IMPLEMENTATION,
+            chunks, SCAN_IMPLEMENTATION, SCAN_PATHS[kernels],
         )

@@ -1,6 +1,9 @@
 """Kimi Delta Attention's recurrence (Kimi Linear, arXiv:2510.26692): a
 gated delta rule whose decay is a vector over the key dimension, in a
-chunked form with no clamp and no dropped term, plain ``jax.numpy``.
+chunked form with no clamp and no dropped term. What a chunk needs of
+itself alone runs in two Pallas kernels where the shapes fill the chip's
+tiles and in plain ``jax.numpy`` elsewhere; the recurrence over chunk
+states is ``jax.numpy`` in both.
 
 Per head, with a state ``S`` of keys × values that starts at zero,
 
@@ -46,6 +49,29 @@ copies than the zero rows do: PERF.md §6, PR 44). The triangular inverse
 is block forward substitution over the same levels, ``T ← T − T a_h T``
 with ``a_h`` the part of ``A`` a level holds: exact, no Neumann series.
 
+**Two implementations of the chunk-local step, chosen by the shapes.**
+``U = T(βv)``, ``W = T(β e^G k)``, ``P``, ``q e^G``, ``k e^{G_C − G}`` and
+``e^{G_C}`` read one chunk's ``q``, ``k``, ``v``, ``g``, ``β`` and nothing
+else. :func:`_chunk_local_jnp` is the form above over all chunks at once:
+its level operands and ``[chunk, chunk]`` intermediates are arrays in HBM,
+about 40 times the bytes the rule needs (PERF.md §6, PR 44). Where
+:func:`uses_kernels` holds (``d_k`` and ``d_v`` multiples of 128, the
+chunk a multiple of 64: the published layer's [64, 128] tiles),
+:func:`chunk_local` runs the same mathematics (:func:`_chunk_math`) a
+chunk and head at a time on tiles in VMEM: ``kda_chunk_forward`` reads the
+five inputs and writes the six results, ``kda_chunk_backward`` reads the
+inputs, the chunk's ``T`` and the results' cotangents and writes the five
+gradients, evaluating ``jax.vjp`` of :func:`_chunk_math` in the kernel
+body; nothing with a level axis and nothing ``[chunk, chunk]`` but ``P``
+(and, between a segment's rebuild and its gradient, ``T``) is written to
+HBM. In a tile a level's factor is ONE ``[chunk, d_k]`` array, ``e^{G_i −
+G_r}`` on late rows and ``e^{G_r − G_j}`` on early ones, made from
+sublane rotations of the cumulative sums; the level's pairs select their
+entries from one ``[2·chunk, d_k] × [d_k, chunk]`` product of ``q`` over
+``k`` stacked. No option, field or name chooses: ``kda_chunked`` reads the
+shapes (a test may ask for either path by argument), and on a backend
+that is no TPU the kernel bodies run in the Pallas interpreter.
+
 **Precision.** ``g``, its cumulative sums, ``β``, ``A``'s inverse and the
 products with it, the chunk states and their recurrence are float32 (the
 state's products at ``Precision.HIGHEST``); the operands of the pairwise
@@ -61,7 +87,9 @@ the last to the first, rebuilds one segment's chunk quantities from its
 inputs and its entering state, and differentiates that segment as
 written (the triangular inverse by ``−Tᵀ dT Tᵀ``), handing the state's
 cotangent on. So a backward holds one segment's intermediates — a few
-``[segment, heads, d]`` float32 arrays — and never a sequence's.
+``[segment, heads, d]`` float32 arrays — and never a sequence's. By the
+kernels a segment's rebuild is the forward kernel once more (keeping each
+chunk's ``T``) and its gradient the backward kernel.
 """
 from __future__ import annotations
 
@@ -72,14 +100,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 IMPLEMENTATION = (
-    "chunked WY form in jax.numpy, pairwise decay by dyadic levels, "
-    "chunk states one after the other (ops/kda.py)"
+    "chunked WY form, pairwise decay by dyadic levels: a chunk's work in "
+    "Pallas kernels (forward and backward) where d_k and d_v are "
+    "multiples of 128 and the chunk of 64, in jax.numpy otherwise; chunk "
+    "states one after the other (ops/kda.py)"
 )
+# What makes a chunk's quantities, by whether :func:`uses_kernels` says so.
+PATHS = {
+    True: "Pallas kernels kda_chunk_forward and kda_chunk_backward",
+    False: "jax.numpy",
+}
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
-# Chunks a segment: what a backward holds at once (the module docstring).
+# Chunks a segment: what a backward holds at once. By the kernels that is
+# the six results of the chunk-local step, their cotangents and T, about
+# 0.35 MB a chunk and head (0.36 GB at 32 heads) where the jax.numpy
+# form's level operands alone were 2.5 GiB; 64 and 128 chunks a segment
+# were 3% and 8% slower on the chip (PERF.md §6, PR 45).
 SEGMENT_CHUNKS = 32
 # What the forward keeps besides its inputs: the output and the states the
 # segments were entered with, [segments, b, h, d_k, d_v] float32.
@@ -212,7 +253,348 @@ def _inverse_bwd(inv, d_inv):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-def _segment(q, k, v, g, beta, state, chunk: int):
+def _chunk_local_jnp(q, k, v, g, beta):
+    """The chunk-local step in plain ``jax.numpy``: ``q``, ``k`` [..., c,
+    d_k], ``v`` [..., c, d_v] (the chunk axis second to last), ``g`` [...,
+    c, d_k] float32 log-decays, ``beta`` [..., c] float32. Returns what the
+    recurrence over chunk states needs: ``U = T(βv)`` and ``W = T(β e^G
+    k)`` float32, ``P`` [..., c, c] and ``q e^G`` in ``v``'s dtype, ``k
+    e^{G_C − G}`` and ``e^{G_C}`` [..., d_k] float32."""
+    dtype = v.dtype
+    qf, kf, vf = (a.astype(_F32) for a in (q, k, v))
+    G = jnp.cumsum(g.astype(_F32), axis=-2)
+    bt = beta.astype(_F32)[..., None]                         # [..., c, 1]
+    P, own = _pairwise(qf, kf, G, dtype)
+    T = unit_lower_inverse(bt * own)
+    decay = jnp.exp(G)
+    U = jnp.einsum("...ij,...jv->...iv", T, bt * vf, precision=_HIGHEST)
+    W = jnp.einsum(
+        "...ij,...jk->...ik", T, bt * decay * kf, precision=_HIGHEST
+    )
+    to_end = kf * jnp.exp(G[..., -1:, :] - G)
+    return (U, W, P.astype(dtype), (qf * decay).astype(dtype), to_end,
+            decay[..., -1, :])
+
+
+# --------------------------------------------------------------------------
+# The chunk-local step as Pallas kernels. One function, :func:`_chunk_math`,
+# holds the mathematics for ONE chunk of one head on [c, d] tiles in VMEM;
+# the forward kernel evaluates it and the backward kernel evaluates its
+# ``jax.vjp`` in the kernel body, so G, the levels' factors and A are
+# rebuilt there and no per-level array leaves the chip's VMEM. Only
+# operations Mosaic lowers on (8, 128) tiles: sublane rotations, selects
+# by iota masks, [c, d] × [d, c] and [c, c] × [c, d] products.
+
+# Chunk-heads a grid step: enough that a step's 0.35 µs is small beside
+# its work (2 µs a chunk-head), few enough that both buffers of every
+# block fit the default scoped VMEM (5 MB in the backward). 16 a step, and
+# 2 or 4 of them unrolled into one straight line, were within 1.5% on the
+# chip (PERF.md §6, PR 45).
+CHUNKS_A_STEP = 8
+
+
+def uses_kernels(d_k: int, d_v: int, chunk: int) -> bool:
+    """Whether :func:`kda_chunked` takes the Pallas kernels at these
+    shapes, read from the shapes alone: a head's keys and values fill
+    whole 128-lane registers, and a chunk whole sublane tiles in either
+    dtype with [chunk, chunk] tiles of at least half a register's lanes.
+    Smaller chunks keep the ``jax.numpy`` form, which batches them all
+    into one product."""
+    return d_k % 128 == 0 and d_v % 128 == 0 and chunk % 64 == 0
+
+
+def _interpret() -> bool:
+    # Mosaic compiles the kernels for a TPU; elsewhere the same bodies run
+    # in the Pallas interpreter (as ``ops/stream_mix.py``).
+    return jax.default_backend() != "tpu"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _shift(x, by: int):
+    """Row ``i`` of the result is row ``i − by`` of ``x`` [c, d], around
+    the end (a sublane rotation)."""
+    return pltpu.roll(x, by % x.shape[0], 0)
+
+
+_shift.defvjp(
+    lambda x, by: (_shift(x, by), None),
+    lambda by, _, d: (_shift(d, -by),),
+)
+
+
+def _dot(a, b, contract):
+    """A product with float32 accumulation; float32 operands multiply to
+    float32's accuracy."""
+    return jax.lax.dot_general(
+        a, b, ((contract[:1], contract[1:]), ((), ())),
+        precision=_HIGHEST if a.dtype == _F32 else None,
+        preferred_element_type=_F32,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _pairs(x, y, dtype):
+    """``x yᵀ`` and ``y yᵀ`` for float32 ``x``, ``y`` [c, d], the operands
+    rounded to ``dtype``: ONE product of ``x`` over ``y`` stacked, so
+    ``yᵀ`` is loaded once. The cotangents come back float32, unrounded."""
+    return _pairs_fwd(x, y, dtype)[0]
+
+
+def _pairs_fwd(x, y, dtype):
+    c = x.shape[0]
+    y = y.astype(dtype)
+    stacked = jnp.concatenate([x.astype(dtype), y], axis=0)
+    both = _dot(stacked, y, (1, 1))
+    return (both[:c], both[c:]), (stacked, y)
+
+
+def _pairs_bwd(dtype, res, d):
+    stacked, y = res
+    c = y.shape[0]
+    d = jnp.concatenate(d, axis=0).astype(dtype)
+    d_stacked = _dot(d, y, (1, 0))
+    return d_stacked[:c], d_stacked[c:] + _dot(d, stacked, (0, 0))
+
+
+_pairs.defvjp(_pairs_fwd, _pairs_bwd)
+
+
+def _level_masks(c: int):
+    """For each half-block size ``h``: the pairs (i, j) [c, c] of one
+    block of ``2h`` with ``i`` in its second half and ``j`` in its first
+    (:func:`_levels`' ``pairs``, from iotas: the highest bit in which i
+    and j differ is ``h``, and i has it)."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    apart = jnp.bitwise_xor(i, j)
+    return [
+        (1 << n, (jnp.right_shift(apart, n) == 1) & (i > j))
+        for n in range(c.bit_length() - 1)
+    ]
+
+
+@jax.custom_vjp
+def _inverse_tile(a):
+    """:func:`_inverse` on one [c, c] tile. Level 1 needs no product:
+    ``I − I a_1 I``."""
+    c = a.shape[-1]
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    inv = jnp.where(i == j, 1.0, 0.0).astype(_F32)
+    for h, pairs in _level_masks(c):
+        under = jnp.where(pairs, a, 0.0)
+        inv = inv - (
+            under if h == 1
+            else _dot(_dot(inv, under, (1, 0)), inv, (1, 0))
+        )
+    return inv
+
+
+def _inverse_tile_fwd(a):
+    inv = _inverse_tile(a)
+    return inv, inv
+
+
+def _inverse_tile_bwd(inv, d_inv):
+    c = inv.shape[-1]
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    d_a = _dot(_dot(inv, d_inv, (0, 0)), inv, (1, 1))
+    return (jnp.where(i > j, -d_a, 0.0),)
+
+
+_inverse_tile.defvjp(_inverse_tile_fwd, _inverse_tile_bwd)
+
+
+@jax.custom_vjp
+def _kept_inverse(a, inv):
+    """``inv``, which IS ``(I + a)^-1`` (the forward kernel's, kept for
+    the backward): differentiated as :func:`_inverse_tile` of ``a``."""
+    return inv
+
+
+_kept_inverse.defvjp(
+    lambda a, inv: (inv, inv),
+    lambda inv, d: (*_inverse_tile_bwd(inv, d), jnp.zeros_like(inv)),
+)
+
+
+def _chunk_math(q, k, v, g, beta, dtype, inverse=None):
+    """One chunk of one head: ``q``, ``k`` [c, d_k] and ``v`` [c, d_v]
+    float32 (values of ``dtype``), ``g`` [c, d_k] float32, ``beta`` [1, c]
+    float32 → :func:`_chunk_local_jnp`'s six results (``e^{G_C}`` as a
+    [1, d_k] row) and ``T``, which a caller that kept it hands back as
+    ``inverse``. The pairwise factor at level ``h`` is ONE array: with
+    ``B`` the cumulative sum at the first token of a token's own block of
+    ``h``, a late token carries ``e^{G_i − B_i}`` and an early one
+    ``e^{B_{j+h} − G_j}`` (``B`` of the next block), both exponents ≤ 0;
+    what a row carries at a level where it is neither is masked out of
+    the product by the level's pairs."""
+    c, d_k = k.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, d_k), 0)
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    levels = _level_masks(c)
+
+    G = g                                    # the inclusive cumulative sum
+    for h, _ in levels:
+        G = G + jnp.where(row >= h, _shift(G, h), 0.0)
+
+    P = own = jnp.zeros((c, c), _F32)
+    start = G
+    for h, pairs in levels:
+        late = jnp.bitwise_and(row, h) != 0
+        # The last block of h is a late one: no row reads around the end.
+        factor = jnp.exp(jnp.where(late, G - start, _shift(start, -h) - G))
+        of_q, of_k = _pairs(q * factor, k * factor, dtype)
+        P, own = jnp.where(pairs, of_q, P), jnp.where(pairs, of_k, own)
+        start = jnp.where(late, _shift(start, h), start)
+    P = jnp.where(i == j, jnp.sum(q * k, axis=1, keepdims=True), P)
+
+    bt = jnp.sum(jnp.where(i == j, beta, 0.0), axis=1, keepdims=True)
+    T = (_inverse_tile(bt * own) if inverse is None
+         else _kept_inverse(bt * own, inverse))
+    decay = jnp.exp(G)
+    U = _dot(T, bt * v, (1, 0))
+    W = _dot(T, bt * decay * k, (1, 0))
+    last = jnp.sum(jnp.where(row == c - 1, G, 0.0), axis=0, keepdims=True)
+    return (U, W, P.astype(dtype), (q * decay).astype(dtype),
+            k * jnp.exp(last - G), jnp.exp(last), T)
+
+
+def _tiles(refs, at):
+    """A chunk-head's operands from the blocks of a grid step: ``q``,
+    ``k``, ``v`` float32, ``g``, and ``beta`` as a [1, c] row."""
+    q, k, v, g, beta = refs
+    return (*(r[at].astype(_F32) for r in (q, k, v)), g[at],
+            beta[0, pl.ds(at, 1), :])
+
+
+def _forward_kernel(*refs):
+    """q, k, v, g, beta → U, W, P, q e^G, k e^{G_C − G}, e^{G_C} and,
+    where the call has a block for it, T."""
+    ins, outs = refs[:5], refs[5:]
+    dtype = ins[2].dtype
+
+    def chunk(at, _):
+        *full, end, inverse = _chunk_math(*_tiles(ins, at), dtype)
+        for ref, a in zip(outs, full):
+            ref[at] = a
+        outs[5][0, pl.ds(at, 1), :] = end
+        if len(outs) > 6:
+            outs[6][at] = inverse
+        return 0
+
+    jax.lax.fori_loop(0, ins[0].shape[0], chunk, 0)
+
+
+def _backward_kernel(*refs):
+    """q, k, v, g, beta, T and the six results' cotangents → dq, dk, dv,
+    dg, dbeta: the ``jax.vjp`` of :func:`_chunk_math` on a chunk's tiles."""
+    ins, kept, cots, outs = refs[:5], refs[5], refs[6:12], refs[12:]
+    dtype = ins[2].dtype
+
+    def chunk(at, _):
+        _, vjp = jax.vjp(
+            lambda *a: _chunk_math(*a, dtype, inverse=kept[at])[:6],
+            *_tiles(ins, at),
+        )
+        *d_full, d_beta = vjp((
+            *(ref[at] for ref in cots[:5]), cots[5][0, pl.ds(at, 1), :]
+        ))
+        for ref, a in zip(outs, d_full):
+            ref[at] = a.astype(ref.dtype)
+        outs[4][0, pl.ds(at, 1), :] = d_beta
+        return 0
+
+    jax.lax.fori_loop(0, ins[0].shape[0], chunk, 0)
+
+
+def _call(kernel, name, operands, outputs, interpret: bool):
+    """``kernel`` over the chunk-heads of ``operands`` ([..., c, d]
+    tiles; [..., d] rows go as [steps, chunk-heads a step, d]), every
+    grid step independent."""
+    lead = operands[0].shape[:-2]
+    count = math.prod(lead)
+    step = math.gcd(count, CHUNKS_A_STEP)
+
+    def flat(a):
+        if len(a.shape) == len(lead) + 2:
+            return (count, *a.shape[-2:])
+        return (count // step, step, a.shape[-1])
+
+    def spec(a):
+        first, *rest = flat(a)
+        return pl.BlockSpec(
+            (first * step // count, *rest), lambda at: (at, 0, 0)
+        )
+
+    def run(*operands):
+        return pl.pallas_call(
+            kernel,
+            out_shape=[
+                jax.ShapeDtypeStruct(flat(a), a.dtype) for a in outputs
+            ],
+            grid=(count // step,),
+            in_specs=[spec(a) for a in operands],
+            out_specs=[spec(a) for a in outputs],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+            ),
+            interpret=interpret,
+            name=name,
+        )(*(a.reshape(flat(a)) for a in operands))
+
+    run.__name__ = name
+    results = (run if interpret else jax.jit(run))(*operands)
+    return tuple(r.reshape(a.shape) for r, a in zip(results, outputs))
+
+
+def _forward_call(q, k, v, g, beta, interpret: bool, keep: bool):
+    like = jax.ShapeDtypeStruct
+    square = (*k.shape[:-1], k.shape[-2])
+    results = (
+        like(v.shape, _F32), like(k.shape, _F32), like(square, v.dtype),
+        like(q.shape, v.dtype), like(k.shape, _F32),
+        like((*k.shape[:-2], k.shape[-1]), _F32),
+    )
+    return _call(
+        _forward_kernel, "kda_chunk_forward", (q, k, v, g, beta),
+        results + ((like(square, _F32),) if keep else ()), interpret,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunk_local(q, k, v, g, beta, interpret: bool):
+    return _forward_call(q, k, v, g, beta, interpret, keep=False)
+
+
+def _chunk_local_fwd(q, k, v, g, beta, interpret):
+    # Differentiated, the forward keeps a chunk's T beside its inputs: the
+    # backward kernel then runs no inverse.
+    *results, inverse = _forward_call(q, k, v, g, beta, interpret, keep=True)
+    return tuple(results), (q, k, v, g, beta, inverse)
+
+
+def _chunk_local_bwd(interpret, kept, cotangents):
+    like = jax.ShapeDtypeStruct
+    return _call(
+        _backward_kernel, "kda_chunk_backward", (*kept, *cotangents),
+        tuple(like(a.shape, a.dtype) for a in kept[:5]), interpret,
+    )
+
+
+_chunk_local.defvjp(_chunk_local_fwd, _chunk_local_bwd)
+
+
+def chunk_local(q, k, v, g, beta):
+    """:func:`_chunk_local_jnp` by the Pallas kernels: the same arguments
+    and results, one ``custom_vjp``. Compiled by Mosaic on a TPU, run in
+    the Pallas interpreter anywhere else."""
+    return _chunk_local(q, k, v, g, beta, _interpret())
+
+
+def _segment(q, k, v, g, beta, state, chunk: int, kernels: bool):
     """Some whole chunks of a sequence, entered with ``state`` [b, h, d_k,
     d_v] float32: the shapes of :func:`kda_chunked`. Returns ``o`` in
     ``v``'s dtype and the state left."""
@@ -222,19 +604,10 @@ def _segment(q, k, v, g, beta, state, chunk: int):
     def chunks(a):                           # [b, s, h, ...] -> [b, n, h, c, ...]
         return jnp.moveaxis(a.reshape(b, n, chunk, *a.shape[2:]), 2, 3)
 
-    qf, kf, vf = (chunks(a).astype(_F32) for a in (q, k, v))
-    G = jnp.cumsum(chunks(g.astype(_F32)), axis=-2)
-    bt = chunks(beta.astype(_F32))[..., None]                 # [b, n, h, c, 1]
-
-    P, own = _pairwise(qf, kf, G, dtype)
-    T = unit_lower_inverse(bt * own)
-    decay = jnp.exp(G)
-    U = jnp.einsum("...ij,...jv->...iv", T, bt * vf, precision=_HIGHEST)
-    W = jnp.einsum(
-        "...ij,...jk->...ik", T, bt * decay * kf, precision=_HIGHEST
-    )
-    to_end = kf * jnp.exp(G[..., -1:, :] - G)
-    end = decay[..., -1, :]                                   # [b, n, h, d_k]
+    U, W, P, q_decayed, to_end, end = (
+        chunk_local if kernels else _chunk_local_jnp
+    )(chunks(q), chunks(k), chunks(v), chunks(g.astype(_F32)),
+      chunks(beta.astype(_F32)))
 
     # The recurrence over the chunks, float32: the state each chunk is
     # entered with.
@@ -254,10 +627,10 @@ def _segment(q, k, v, g, beta, state, chunk: int):
     )
     entered, w = jnp.moveaxis(entered, 0, 1), jnp.moveaxis(w, 0, 1)
     out = jnp.einsum(
-        "...ik,...kv->...iv", (qf * decay).astype(dtype),
-        entered.astype(dtype), preferred_element_type=_F32,
+        "...ik,...kv->...iv", q_decayed, entered.astype(dtype),
+        preferred_element_type=_F32,
     ) + jnp.einsum(
-        "...ij,...jv->...iv", P.astype(dtype), w.astype(dtype),
+        "...ij,...jv->...iv", P, w.astype(dtype),
         preferred_element_type=_F32,
     )
     out = jnp.moveaxis(out, 3, 2).reshape(b, s, h, d_v).astype(dtype)
@@ -281,17 +654,26 @@ def _whole(a):
     return a.reshape(a.shape[0], a.shape[1] * a.shape[2], *a.shape[3:])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def kda_chunked(q, k, v, g, beta, chunk: int = 64):
+def kda_chunked(q, k, v, g, beta, chunk: int = 64, kernels=None):
     """``q``, ``k`` [b, s, h, d_k], ``v`` [b, s, h, d_v] (``q`` already
     scaled), ``g`` [b, s, h, d_k] float32 log-decays (≤ 0, unbounded
     below), ``beta`` [b, s, h] float32; ``s`` a multiple of ``chunk``, a
     power of two. Returns ``o`` [b, s, h, d_v] in ``v``'s dtype. The state
-    before the first token is zero."""
-    return _kda_fwd(q, k, v, g, beta, chunk)[0]
+    before the first token is zero. ``kernels`` asks for the Pallas
+    kernels (true) or the ``jax.numpy`` form (false) of the chunk-local
+    step whatever the shapes, as a test does; left out,
+    :func:`uses_kernels` reads it from the shapes."""
+    if kernels is None:
+        kernels = uses_kernels(k.shape[-1], v.shape[-1], chunk)
+    return _kda(q, k, v, g, beta, chunk, bool(kernels))
 
 
-def _kda_fwd(q, k, v, g, beta, chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kda(q, k, v, g, beta, chunk, kernels):
+    return _kda_fwd(q, k, v, g, beta, chunk, kernels)[0]
+
+
+def _kda_fwd(q, k, v, g, beta, chunk, kernels):
     b, s, h, d_k = k.shape
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"chunk {chunk} is not a power of two")
@@ -299,7 +681,7 @@ def _kda_fwd(q, k, v, g, beta, chunk):
         raise ValueError(f"sequence {s} is not a multiple of chunk {chunk}")
 
     def step(state, xs):
-        out, left = _segment(*xs, state, chunk)
+        out, left = _segment(*xs, state, chunk, kernels)
         return left, (out, state)
 
     _, (out, entered) = jax.lax.scan(
@@ -311,13 +693,13 @@ def _kda_fwd(q, k, v, g, beta, chunk):
     return out, (q, k, v, g, beta, entered)
 
 
-def _kda_bwd(chunk, residuals, d_out):
+def _kda_bwd(chunk, kernels, residuals, d_out):
     *inputs, entered = residuals
 
     def step(d_state, xs):
         *xs, state, d_o = xs
         _, vjp = jax.vjp(
-            lambda *a: _segment(*a, chunk), *xs, state
+            lambda *a: _segment(*a, chunk, kernels), *xs, state
         )
         *d_xs, d_state = vjp((d_o, d_state))
         return d_state, tuple(d_xs)
@@ -330,4 +712,4 @@ def _kda_bwd(chunk, residuals, d_out):
     return tuple(_whole(a) for a in grads)
 
 
-kda_chunked.defvjp(_kda_fwd, _kda_bwd)
+_kda.defvjp(_kda_fwd, _kda_bwd)

@@ -492,7 +492,8 @@ class JAXEstimator:
             getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
         )
         kda.report(
-            getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
+            getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step,
+            sequence=int(self._sample_batch.shape[-1]),
         )
         shortconv.report(getattr(self._model, "cfg", None))
         latent.report(getattr(self._model, "cfg", None))

@@ -5,7 +5,9 @@ decay strong enough to overflow a factored form; the shapes it refuses;
 the recurrence's two limits against closed forms (``beta -> 0``: a gated
 linear attention; ``g = 0`` and ``beta = 1``: the plain delta rule); the
 triangular inverse against ``numpy``; and the residuals a checkpoint's
-policy keeps. Tiny sizes, float32, the CPU."""
+policy keeps. The chunk-local step runs by both of its paths, the
+``jax.numpy`` form and the Pallas kernels (in the interpreter here), and
+the shapes decide which. Tiny sizes, float32, the CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,6 +45,7 @@ def _grads(fn, args):
     ))(*args)
 
 
+@pytest.mark.parametrize("kernels", [False, True], ids=["jnp", "kernels"])
 @pytest.mark.parametrize("chunk,s,d_k,d_v,strength,tol", [
     (16, 16, 16, 8, 1.0, 5e-6),      # one chunk
     (16, 48, 16, 8, 1.0, 5e-6),      # several: three, not a power of two
@@ -53,7 +56,10 @@ def _grads(fn, args):
     (64, 128, 16, 8, 40.0, 2e-4),
 ])
 def test_chunked_is_the_token_by_token_scan(chunk, s, d_k, d_v, strength,
-                                            tol):
+                                            tol, kernels):
+    def kda_chunked(*a):             # the path asked for, whatever the shapes
+        return kda_ops.kda_chunked(*a, kernels=kernels)
+
     args = _inputs(s=s, d_k=d_k, d_v=d_v, strength=strength)
     if strength > 1:
         per_chunk = args[3].reshape(2, s // chunk, chunk, 2, d_k).sum(2)
@@ -66,6 +72,81 @@ def test_chunked_is_the_token_by_token_scan(chunk, s, d_k, d_v, strength,
                                         args), _grads(kda_recurrent, args)):
         assert bool(jnp.all(jnp.isfinite(a))), name
         assert _rel(a, b) < 3 * tol, name
+
+
+def _chunked(args, chunk):
+    """[b, s, h, ...] arrays as the chunk-local step takes them:
+    [b, chunks, h, chunk, ...]."""
+    return tuple(
+        jnp.moveaxis(a.reshape(a.shape[0], -1, chunk, *a.shape[2:]), 2, 3)
+        for a in args
+    )
+
+
+def test_the_kernels_vjp_is_the_jnp_chunk_local_forms():
+    """One chunk of the cell's tile, [64, 128] float32, two heads: the six
+    results, and the five gradients of the kernels' ``custom_vjp`` against
+    ``jax.vjp`` of the plain form under the same cotangents."""
+    args = _chunked(_inputs(b=1, s=64, d_k=128, d_v=128), 64)
+    want, vjp = jax.vjp(kda_ops._chunk_local_jnp, *args)
+    got, kernel_vjp = jax.vjp(kda_ops.chunk_local, *args)
+    rng = np.random.default_rng(1)
+    cotangents = tuple(
+        jnp.asarray(rng.standard_normal(a.shape), a.dtype) for a in want
+    )
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert _rel(a, b) < 5e-6
+    for name, a, b in zip(NAMES, kernel_vjp(cotangents), vjp(cotangents)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert _rel(a, b) < 1e-5, name
+
+
+def test_the_kernels_take_bfloat16_as_the_jnp_form_does():
+    """``q``, ``k``, ``v`` in bfloat16 (the cell's), decays and ``beta``
+    float32: output and gradients by the kernels against the plain form,
+    both rounding their products' operands to bfloat16."""
+    q, k, v, g, beta = _inputs(b=1, s=128, d_k=128, d_v=128)
+    args = (*(a.astype(jnp.bfloat16) for a in (q, k, v)), g, beta)
+
+    def run(kernels):
+        fn = lambda *a: kda_chunked(*a, 64, kernels).astype(jnp.float32)
+        return jax.jit(fn)(*args), _grads(fn, args)
+
+    (got, d_got), (want, d_want) = run(True), run(False)
+    assert got.dtype == want.dtype
+    assert _rel(got, want) < 2e-2
+    for name, a, b in zip(NAMES, d_got, d_want):
+        assert a.dtype == b.dtype, name
+        assert _rel(a.astype(jnp.float32), b.astype(jnp.float32)) < 3e-2, name
+
+
+@pytest.mark.parametrize("d_k,d_v,chunk,kernels", [
+    (128, 128, 64, True),            # the cell's: [1, 16384, 32, 128]
+    (256, 128, 128, True),
+    (16, 128, 64, False),
+    (128, 16, 64, False),
+    (128, 128, 16, False),
+    (192, 128, 64, False),           # half a register left over
+])
+def test_the_shapes_choose_the_path(d_k, d_v, chunk, kernels, monkeypatch):
+    """The predicate alone, and that ``kda_chunked`` asks it: nothing
+    runs."""
+    assert kda_ops.uses_kernels(d_k, d_v, chunk) is kernels
+    seen = []
+    monkeypatch.setattr(
+        kda_ops, "_kda", lambda *a: seen.append(a[-1]) or a[2]
+    )
+    like = jax.ShapeDtypeStruct
+    jax.eval_shape(
+        lambda *a: kda_chunked(*a, chunk),
+        like((1, 16384, 32, d_k), jnp.bfloat16),
+        like((1, 16384, 32, d_k), jnp.bfloat16),
+        like((1, 16384, 32, d_v), jnp.bfloat16),
+        like((1, 16384, 32, d_k), jnp.float32),
+        like((1, 16384, 32), jnp.float32),
+    )
+    assert seen == [kernels]
 
 
 def test_a_sequence_runs_in_segments(monkeypatch):

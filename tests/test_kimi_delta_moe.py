@@ -415,6 +415,7 @@ def test_the_layers_norm_and_the_latent_layer_keep_their_scopes(lowered):
     ("kda/layers", 4), ("kda/heads", 32), ("kda/chunk", 64),
     ("kda/chunks_per_step", 1024),
     ("kda/state_bytes_per_sequence", 4 * 32 * 128 * 128 * 4),
+    ("kda/scan_kernel_layers", 4),
     ("latent/rotary_dims", 0),
 ])
 def test_the_reports_of_the_published_stack(gauge, value, caplog):
@@ -432,6 +433,28 @@ def test_the_reports_of_the_published_stack(gauge, value, caplog):
     assert "32 heads of 128 (q, k) and 128 (v)" in line
     assert "chunk 64 (1024 chunks a step)" in line
     assert kda_module.SCAN_IMPLEMENTATION in line and "ops/kda.py" in line
+    # ... and which path a chunk's work takes at these shapes.
+    assert line.endswith(kda_module.SCAN_PATHS[True])
+    assert "kda_chunk_forward" in line
+
+
+@pytest.mark.parametrize("sequence,chunk,layers", [
+    (16384, 64, 4), (8192, 64, 4), (48, 16, 0), (100, 4, 0),
+])
+def test_the_kernel_gauge_follows_the_sequences_chunk(sequence, chunk, layers,
+                                                      caplog):
+    """A sequence that is no multiple of 64 runs in a smaller chunk, and
+    that by the ``jax.numpy`` form: the gauge and the line say what
+    ``kda_chunked`` will be asked with."""
+    cfg = kimi_linear_48b_a3b(n_layers=5)
+    assert cfg.kda.scan_chunk(sequence) == chunk
+    assert cfg.kda.scan_runs_kernels(sequence) is bool(layers)
+    with caplog.at_level(logging.INFO, logger="raydp_tpu.models.kda"):
+        kda_module.report(cfg, tokens_per_step=2 * sequence, sequence=sequence)
+    assert metrics.gauge_value("kda/scan_kernel_layers") == layers
+    (record,) = [r for r in caplog.records
+                 if r.name == "raydp_tpu.models.kda"]
+    assert record.getMessage().endswith(kda_module.SCAN_PATHS[bool(layers)])
 
 
 def test_the_reports_read_zero_for_the_other_stacks(caplog):
@@ -439,7 +462,7 @@ def test_the_reports_read_zero_for_the_other_stacks(caplog):
         for cfg in (xing4_0(n_layers=2), granite_h_micro(n_layers=2), None):
             kda_module.report(cfg, tokens_per_step=4096)
             for gauge in ("kda/layers", "kda/heads", "kda/chunk",
-                          "kda/chunks_per_step",
+                          "kda/chunks_per_step", "kda/scan_kernel_layers",
                           "kda/state_bytes_per_sequence"):
                 assert metrics.gauge_value(gauge) == 0
     assert not caplog.records

@@ -511,6 +511,44 @@ def test_chunked_scan_compiles_for_the_chip_at_published_widths(one_chip):
     assert temp < 0.25e9, temp
 
 
+def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
+        one_chip, monkeypatch):
+    """One sequence of 16,384 tokens, 32 heads of 128 / 128 in chunks of
+    64, forward and backward: Mosaic accepts both chunk kernels (three
+    calls: forward, the backward's rebuild, the gradient), and what the
+    ``jax.numpy`` form wrote for every segment is in no buffer: nothing
+    with the six levels' axis, and of float32 [chunk, chunk] arrays the
+    rebuild's kept inverse alone (the other, inside a fusion, is the
+    output product's gradient of ``P`` before it is rounded and stored)."""
+    import re
+
+    from raydp_tpu.ops import kda
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kda.uses_kernels(128, 128, 64)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    wide = jax.ShapeDtypeStruct((1, 16384, 32, 128), bf16)
+    args = (wide, wide, wide,
+            jax.ShapeDtypeStruct((1, 16384, 32, 128), f32),
+            jax.ShapeDtypeStruct((1, 16384, 32), f32))
+
+    def loss(*a):
+        return jnp.sum(kda.kda_chunked(*a, 64).astype(f32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+        *_on(one_chip, args)
+    ).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 3
+    assert not re.search(r"\[[\d,]*,6,64,128\]", hlo)
+    squares = set(re.findall(r"f32\[[\d,]*64,64\]", hlo))
+    assert squares == {"f32[1024,64,64]", "f32[32,32,64,64]"}, squares
+    assert "bf16[32,32,64,64]" in hlo and hlo.count("f32[32,32,64,64]") == 1
+    # 0.69 GB; the jax.numpy form's temporaries were 2.00 GiB (PR 44).
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.0e9, temp
+
+
 def test_a_share_of_the_routed_layer_compiles_at_lfm2s_widths(
     one_chip, monkeypatch
 ):
