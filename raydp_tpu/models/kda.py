@@ -72,6 +72,14 @@ class KDAConfig:
             self.key_dim, self.value_dim, self.scan_chunk(sequence)
         )
 
+    def kept_inverse_bytes(self, layers: int, sequence: int) -> int:
+        """What the chunks' float32 triangular inverses of one sequence
+        hold, all layers: the residual the kernels' forward keeps for the
+        backward (``ops/kda.KEPT``); none by the ``jax.numpy`` form."""
+        if not self.scan_runs_kernels(sequence):
+            return 0
+        return 4 * layers * self.heads * sequence * self.scan_chunk(sequence)
+
 
 class QKVConv(nn.Module):
     """The three causal depthwise convolutions (no bias) with their SiLU,
@@ -204,7 +212,7 @@ def layers_of(cfg) -> int:
 
 
 def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
-    """Static for a compiled step: six gauges and one log line where the
+    """Static for a compiled step: seven gauges and one log line where the
     step is built (as ``models/mamba.report``). All zero for a stack
     without such layers. ``sequence`` is a sequence's tokens (all of a
     step's where left out)."""
@@ -214,7 +222,10 @@ def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
     kda = cfg.kda if layers else None
     chunks = layers * -(-tokens_per_step // kda.chunk) if kda else 0
     kernels = bool(kda) and kda.scan_runs_kernels(sequence or tokens_per_step)
+    kept = kda.kept_inverse_bytes(
+        layers, sequence or tokens_per_step) >> 20 if kda else 0
     metrics.gauge_set("kda/scan_kernel_layers", layers if kernels else 0)
+    metrics.gauge_set("kda/kept_inverse_mib", kept)
     metrics.gauge_set("kda/layers", layers)
     metrics.gauge_set("kda/heads", kda.heads if kda else 0)
     metrics.gauge_set("kda/chunk", kda.chunk if kda else 0)
@@ -226,9 +237,10 @@ def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
         logger.info(
             "delta-rule stack: layers %s; %d heads of %d (q, k) and %d (v), "
             "a decay per channel, %d-tap convolutions as %s, gates of rank "
-            "%d; chunk %d (%d chunks a step); scan: %s; a chunk's work at "
-            "these shapes: %s",
+            "%d; chunk %d (%d chunks a step); scan: %s; the forward keeps "
+            "%d MiB of chunk inverses a sequence; a chunk's work at these "
+            "shapes: %s",
             " ".join(cfg.kinds), kda.heads, kda.key_dim, kda.value_dim,
             kda.conv_taps, CONV_IMPLEMENTATION, kda.gate_rank, kda.chunk,
-            chunks, SCAN_IMPLEMENTATION, SCAN_PATHS[kernels],
+            chunks, SCAN_IMPLEMENTATION, kept, SCAN_PATHS[kernels],
         )

@@ -1,7 +1,7 @@
 """Kimi Delta Attention's recurrence (Kimi Linear, arXiv:2510.26692): a
 gated delta rule whose decay is a vector over the key dimension, in a
 chunked form with no clamp and no dropped term. What a chunk needs of
-itself alone runs in two Pallas kernels where the shapes fill the chip's
+itself alone runs in Pallas kernels where the shapes fill the chip's
 tiles and in plain ``jax.numpy`` elsewhere; the recurrence over chunk
 states is ``jax.numpy`` in both.
 
@@ -59,12 +59,13 @@ about 40 times the bytes the rule needs (PERF.md §6, PR 44). Where
 chunk a multiple of 64: the published layer's [64, 128] tiles),
 :func:`chunk_local` runs the same mathematics (:func:`_chunk_math`) a
 chunk and head at a time on tiles in VMEM: ``kda_chunk_forward`` reads the
-five inputs and writes the six results, ``kda_chunk_backward`` reads the
-inputs, the chunk's ``T`` and the results' cotangents and writes the five
-gradients, evaluating ``jax.vjp`` of :func:`_chunk_math` in the kernel
-body; nothing with a level axis and nothing ``[chunk, chunk]`` but ``P``
-(and, between a segment's rebuild and its gradient, ``T``) is written to
-HBM. In a tile a level's factor is ONE ``[chunk, d_k]`` array, ``e^{G_i −
+five inputs and writes the six results and, differentiated, the chunk's
+``T``; ``kda_chunk_rebuild`` is the same body given ``T``, with no inverse
+and no ``A``; ``kda_chunk_backward`` reads the inputs, ``T`` and the
+results' cotangents and writes the five gradients, evaluating ``jax.vjp``
+of :func:`_chunk_math` in the kernel body. Nothing with a level axis and
+nothing ``[chunk, chunk]`` but ``P`` and the kept ``T`` is written to HBM.
+In a tile a level's factor is ONE ``[chunk, d_k]`` array, ``e^{G_i −
 G_r}`` on late rows and ``e^{G_r − G_j}`` on early ones, made from
 sublane rotations of the cumulative sums; the level's pairs select their
 entries from one ``[2·chunk, d_k] × [d_k, chunk]`` product of ``q`` over
@@ -79,17 +80,27 @@ products and of the two products that make ``o`` are in ``q``'s dtype with
 float32 accumulation.
 
 **The backward.** A sequence runs in segments of :data:`SEGMENT_CHUNKS`
-chunks, one after the other. The forward keeps its inputs, its output and
-the state each segment was entered with (:data:`KEPT`, named for a
-checkpoint's policy as ``ops/flash_attention.KEPT`` are: a checkpointed
-block then runs no second forward); the backward walks the segments from
-the last to the first, rebuilds one segment's chunk quantities from its
-inputs and its entering state, and differentiates that segment as
-written (the triangular inverse by ``−Tᵀ dT Tᵀ``), handing the state's
-cotangent on. So a backward holds one segment's intermediates — a few
-``[segment, heads, d]`` float32 arrays — and never a sequence's. By the
-kernels a segment's rebuild is the forward kernel once more (keeping each
-chunk's ``T``) and its gradient the backward kernel.
+chunks, one after the other. The forward keeps its inputs, its output,
+the state each segment was entered with and, by the kernels, every
+chunk's ``T`` (:data:`KEPT`, named for a checkpoint's policy as
+``ops/flash_attention.KEPT`` are: a checkpointed block then runs no second
+forward); the backward walks the segments from the last to the first,
+rebuilds one segment's chunk quantities from its inputs and its entering
+state, and differentiates that segment as written (the triangular inverse
+by ``−Tᵀ dT Tᵀ``), handing the state's cotangent on. So a backward holds
+one segment's intermediates — a few ``[segment, heads, d]`` float32 arrays
+— and never a sequence's.
+
+By the kernels ``T`` is a function of a chunk's ``k``, ``g`` and ``β`` that
+costs 60 of the forward kernel's 84 MXU passes (ten six-pass float32
+products), so it is computed ONCE: the forward pass writes it, float32, a
+[64, 64] tile's two row blocks side by side in 128 lanes
+(:func:`_packed`: 16 KiB a chunk and head, 134 MB a layer at 16k tokens
+and 32 heads, half of what a float32 [..., 64, 64] array takes in HBM),
+and a segment's rebuild (cumulative sums, the levels' factors, ``q``'s
+level products for ``P``, ``U = T(βv)``, ``W = T(β e^G k)``, the three
+decayed arrays: 18 passes) and its gradient kernel both read that array.
+The ``jax.numpy`` form keeps no ``T`` and inverts again in its rebuild.
 """
 from __future__ import annotations
 
@@ -105,26 +116,30 @@ from jax.experimental.pallas import tpu as pltpu
 
 IMPLEMENTATION = (
     "chunked WY form, pairwise decay by dyadic levels: a chunk's work in "
-    "Pallas kernels (forward and backward) where d_k and d_v are "
+    "Pallas kernels (forward and backward, each chunk's triangular "
+    "inverse kept from the one for the other) where d_k and d_v are "
     "multiples of 128 and the chunk of 64, in jax.numpy otherwise; chunk "
     "states one after the other (ops/kda.py)"
 )
 # What makes a chunk's quantities, by whether :func:`uses_kernels` says so.
 PATHS = {
-    True: "Pallas kernels kda_chunk_forward and kda_chunk_backward",
+    True: "Pallas kernels kda_chunk_forward (kda_chunk_rebuild where the "
+          "backward reads the kept inverses) and kda_chunk_backward",
     False: "jax.numpy",
 }
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
 # Chunks a segment: what a backward holds at once. By the kernels that is
-# the six results of the chunk-local step, their cotangents and T, about
+# the six results of the chunk-local step and their cotangents, about
 # 0.35 MB a chunk and head (0.36 GB at 32 heads) where the jax.numpy
 # form's level operands alone were 2.5 GiB; 64 and 128 chunks a segment
 # were 3% and 8% slower on the chip (PERF.md §6, PR 45).
 SEGMENT_CHUNKS = 32
-# What the forward keeps besides its inputs: the output and the states the
-# segments were entered with, [segments, b, h, d_k, d_v] float32.
-KEPT = ("kda_out", "kda_segment_states")
+# What the forward keeps besides its inputs: the output, the states the
+# segments were entered with, [segments, b, h, d_k, d_v] float32, and, where
+# the kernels run, every chunk's triangular inverse T, float32 with no
+# padded lane (:func:`_packed`): 16 KiB a chunk and head at a chunk of 64.
+KEPT = ("kda_out", "kda_segment_states", "kda_chunk_inverses")
 
 
 def kda_recurrent(q, k, v, g, beta):
@@ -419,12 +434,14 @@ _kept_inverse.defvjp(
 )
 
 
-def _chunk_math(q, k, v, g, beta, dtype, inverse=None):
+def _chunk_math(q, k, v, g, beta, dtype, inverse=None, values_only=False):
     """One chunk of one head: ``q``, ``k`` [c, d_k] and ``v`` [c, d_v]
     float32 (values of ``dtype``), ``g`` [c, d_k] float32, ``beta`` [1, c]
     float32 → :func:`_chunk_local_jnp`'s six results (``e^{G_C}`` as a
     [1, d_k] row) and ``T``, which a caller that kept it hands back as
-    ``inverse``. The pairwise factor at level ``h`` is ONE array: with
+    ``inverse``: ``A`` is then read by the derivative alone, and a caller
+    that takes ``values_only`` leaves its half of the level products out.
+    The pairwise factor at level ``h`` is ONE array: with
     ``B`` the cumulative sum at the first token of a token's own block of
     ``h``, a late token carries ``e^{G_i − B_i}`` and an early one
     ``e^{B_{j+h} − G_j}`` (``B`` of the next block), both exponents ≤ 0;
@@ -446,14 +463,21 @@ def _chunk_math(q, k, v, g, beta, dtype, inverse=None):
         late = jnp.bitwise_and(row, h) != 0
         # The last block of h is a late one: no row reads around the end.
         factor = jnp.exp(jnp.where(late, G - start, _shift(start, -h) - G))
-        of_q, of_k = _pairs(q * factor, k * factor, dtype)
-        P, own = jnp.where(pairs, of_q, P), jnp.where(pairs, of_k, own)
+        if values_only:
+            of_q = _dot((q * factor).astype(dtype),
+                        (k * factor).astype(dtype), (1, 1))
+        else:
+            of_q, of_k = _pairs(q * factor, k * factor, dtype)
+            own = jnp.where(pairs, of_k, own)
+        P = jnp.where(pairs, of_q, P)
         start = jnp.where(late, _shift(start, h), start)
     P = jnp.where(i == j, jnp.sum(q * k, axis=1, keepdims=True), P)
 
     bt = jnp.sum(jnp.where(i == j, beta, 0.0), axis=1, keepdims=True)
-    T = (_inverse_tile(bt * own) if inverse is None
-         else _kept_inverse(bt * own, inverse))
+    if inverse is None:
+        T = _inverse_tile(bt * own)
+    else:
+        T = inverse if values_only else _kept_inverse(bt * own, inverse)
     decay = jnp.exp(G)
     U = _dot(T, bt * v, (1, 0))
     W = _dot(T, bt * decay * k, (1, 0))
@@ -470,19 +494,49 @@ def _tiles(refs, at):
             beta[0, pl.ds(at, 1), :])
 
 
-def _forward_kernel(*refs):
-    """q, k, v, g, beta → U, W, P, q e^G, k e^{G_C − G}, e^{G_C} and,
-    where the call has a block for it, T."""
-    ins, outs = refs[:5], refs[5:]
+def _fold(c: int) -> int:
+    """Row blocks of a [c, c] float32 tile that lie side by side in one
+    row of 128 lanes: 2 at a chunk of 64, 1 from 128 on."""
+    return min(c, max(1, 128 // c))
+
+
+def _packed(T):
+    """``T`` [c, c] as it is kept in HBM, [c / fold, c · fold]: its row
+    blocks side by side, so that a chunk of 64 fills 128-lane registers
+    (a [64, 64] float32 array there takes the room of [64, 128])."""
+    rows = T.shape[0] // _fold(T.shape[0])
+    return jnp.concatenate(
+        [T[at:at + rows] for at in range(0, T.shape[0], rows)], axis=1
+    )
+
+
+def _unpacked(kept):
+    """The inverse of :func:`_packed`."""
+    rows, wide = kept.shape
+    c = math.isqrt(rows * wide)
+    return jnp.concatenate(
+        [kept[:, at:at + c] for at in range(0, wide, c)], axis=0
+    )
+
+
+def _forward_kernel(*refs, reads: bool):
+    """q, k, v, g, beta → U, W, P, q e^G, k e^{G_C − G}, e^{G_C}. The
+    chunk's T is read (``reads``: the block after beta's) and not
+    inverted again, or written where the call has a block for it."""
+    ins, kept, outs = refs[:5], refs[5:5 + reads], refs[5 + reads:]
     dtype = ins[2].dtype
 
     def chunk(at, _):
-        *full, end, inverse = _chunk_math(*_tiles(ins, at), dtype)
+        *full, end, inverse = _chunk_math(
+            *_tiles(ins, at), dtype,
+            inverse=_unpacked(kept[0][at]) if reads else None,
+            values_only=reads,
+        )
         for ref, a in zip(outs, full):
             ref[at] = a
         outs[5][0, pl.ds(at, 1), :] = end
         if len(outs) > 6:
-            outs[6][at] = inverse
+            outs[6][at] = _packed(inverse)
         return 0
 
     jax.lax.fori_loop(0, ins[0].shape[0], chunk, 0)
@@ -495,8 +549,9 @@ def _backward_kernel(*refs):
     dtype = ins[2].dtype
 
     def chunk(at, _):
+        inverse = _unpacked(kept[at])
         _, vjp = jax.vjp(
-            lambda *a: _chunk_math(*a, dtype, inverse=kept[at])[:6],
+            lambda *a: _chunk_math(*a, dtype, inverse=inverse)[:6],
             *_tiles(ins, at),
         )
         *d_full, d_beta = vjp((
@@ -550,64 +605,82 @@ def _call(kernel, name, operands, outputs, interpret: bool):
     return tuple(r.reshape(a.shape) for r, a in zip(results, outputs))
 
 
-def _forward_call(q, k, v, g, beta, interpret: bool, keep: bool):
+def inverses_shape(lead, chunk: int):
+    """The kept T of chunk-heads ``lead``: [*lead, c / fold, c · fold]."""
+    fold = _fold(chunk)
+    return (*lead, chunk // fold, chunk * fold)
+
+
+def _forward_call(q, k, v, g, beta, interpret: bool, keep: bool = False,
+                  inverses=None):
+    """The chunk-local step's six results by the forward kernel: with
+    ``keep`` the chunks' T as a seventh, with ``inverses`` (a ``keep``
+    call's seventh) T read in place of the inverse's products."""
     like = jax.ShapeDtypeStruct
-    square = (*k.shape[:-1], k.shape[-2])
+    lead, c = k.shape[:-2], k.shape[-2]
     results = (
-        like(v.shape, _F32), like(k.shape, _F32), like(square, v.dtype),
+        like(v.shape, _F32), like(k.shape, _F32), like((*lead, c, c), v.dtype),
         like(q.shape, v.dtype), like(k.shape, _F32),
-        like((*k.shape[:-2], k.shape[-1]), _F32),
+        like((*lead, k.shape[-1]), _F32),
     )
+    if keep:
+        results += (like(inverses_shape(lead, c), _F32),)
+    reads = inverses is not None
     return _call(
-        _forward_kernel, "kda_chunk_forward", (q, k, v, g, beta),
-        results + ((like(square, _F32),) if keep else ()), interpret,
+        functools.partial(_forward_kernel, reads=reads),
+        "kda_chunk_rebuild" if reads else "kda_chunk_forward",
+        (q, k, v, g, beta) + ((inverses,) if reads else ()), results,
+        interpret,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _chunk_local(q, k, v, g, beta, interpret: bool):
-    return _forward_call(q, k, v, g, beta, interpret, keep=False)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _chunk_local(q, k, v, g, beta, inverses, interpret: bool):
+    return _forward_call(q, k, v, g, beta, interpret, inverses=inverses)
 
 
-def _chunk_local_fwd(q, k, v, g, beta, interpret):
-    # Differentiated, the forward keeps a chunk's T beside its inputs: the
-    # backward kernel then runs no inverse.
-    *results, inverse = _forward_call(q, k, v, g, beta, interpret, keep=True)
-    return tuple(results), (q, k, v, g, beta, inverse)
+def _chunk_local_fwd(q, k, v, g, beta, inverses, interpret):
+    results = _forward_call(q, k, v, g, beta, interpret, inverses=inverses)
+    return results, (q, k, v, g, beta, inverses)
 
 
 def _chunk_local_bwd(interpret, kept, cotangents):
     like = jax.ShapeDtypeStruct
-    return _call(
+    grads = _call(
         _backward_kernel, "kda_chunk_backward", (*kept, *cotangents),
         tuple(like(a.shape, a.dtype) for a in kept[:5]), interpret,
     )
+    return (*grads, jnp.zeros_like(kept[5]))
 
 
 _chunk_local.defvjp(_chunk_local_fwd, _chunk_local_bwd)
 
 
-def chunk_local(q, k, v, g, beta):
-    """:func:`_chunk_local_jnp` by the Pallas kernels: the same arguments
-    and results, one ``custom_vjp``. Compiled by Mosaic on a TPU, run in
-    the Pallas interpreter anywhere else."""
-    return _chunk_local(q, k, v, g, beta, _interpret())
+def chunk_local(q, k, v, g, beta, inverses):
+    """:func:`_chunk_local_jnp` by the Pallas kernels, given the chunks'
+    ``T`` as the forward pass kept it (:func:`_forward_call`'s seventh
+    result): the same results, one ``custom_vjp`` that inverts nothing,
+    forward or backward. Compiled by Mosaic on a TPU, run in the Pallas
+    interpreter anywhere else."""
+    return _chunk_local(q, k, v, g, beta, inverses, _interpret())
 
 
-def _segment(q, k, v, g, beta, state, chunk: int, kernels: bool):
-    """Some whole chunks of a sequence, entered with ``state`` [b, h, d_k,
-    d_v] float32: the shapes of :func:`kda_chunked`. Returns ``o`` in
-    ``v``'s dtype and the state left."""
-    b, s, h, d_k = k.shape
-    d_v, dtype, n = v.shape[-1], v.dtype, s // chunk
+def _chunks(chunk: int, q, k, v, g, beta):
+    """[b, s, h, ...] arrays as the chunk-local step takes them, [b, n, h,
+    c, ...], ``g`` and ``beta`` float32."""
+    return tuple(
+        jnp.moveaxis(
+            a.reshape(a.shape[0], -1, chunk, *a.shape[2:]), 2, 3)
+        for a in (q, k, v, g.astype(_F32), beta.astype(_F32))
+    )
 
-    def chunks(a):                           # [b, s, h, ...] -> [b, n, h, c, ...]
-        return jnp.moveaxis(a.reshape(b, n, chunk, *a.shape[2:]), 2, 3)
 
-    U, W, P, q_decayed, to_end, end = (
-        chunk_local if kernels else _chunk_local_jnp
-    )(chunks(q), chunks(k), chunks(v), chunks(g.astype(_F32)),
-      chunks(beta.astype(_F32)))
+def _across(local, state, dtype):
+    """Some whole chunks of a sequence from their chunk-local step's six
+    results ``local`` [b, n, h, ...], entered with ``state`` [b, h, d_k,
+    d_v] float32: ``o`` [b, n · c, h, d_v] in ``dtype`` and the state
+    left."""
+    U, W, P, q_decayed, to_end, end = local
 
     # The recurrence over the chunks, float32: the state each chunk is
     # entered with.
@@ -633,7 +706,8 @@ def _segment(q, k, v, g, beta, state, chunk: int, kernels: bool):
         "...ij,...jv->...iv", P, w.astype(dtype),
         preferred_element_type=_F32,
     )
-    out = jnp.moveaxis(out, 3, 2).reshape(b, s, h, d_v).astype(dtype)
+    b, n, h, c, d_v = out.shape
+    out = jnp.moveaxis(out, 3, 2).reshape(b, n * c, h, d_v).astype(dtype)
     return out, state
 
 
@@ -670,10 +744,12 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, kernels=None):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _kda(q, k, v, g, beta, chunk, kernels):
-    return _kda_fwd(q, k, v, g, beta, chunk, kernels)[0]
+    return _forward(q, k, v, g, beta, chunk, kernels, keep=False)[0]
 
 
-def _kda_fwd(q, k, v, g, beta, chunk, kernels):
+def _forward(q, k, v, g, beta, chunk, kernels, keep: bool):
+    """``o``, the states the segments were entered with and, by the
+    kernels with ``keep``, every segment's chunks' T (else nothing)."""
     b, s, h, d_k = k.shape
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"chunk {chunk} is not a power of two")
@@ -681,32 +757,47 @@ def _kda_fwd(q, k, v, g, beta, chunk, kernels):
         raise ValueError(f"sequence {s} is not a multiple of chunk {chunk}")
 
     def step(state, xs):
-        out, left = _segment(*xs, state, chunk, kernels)
-        return left, (out, state)
+        xs = _chunks(chunk, *xs)
+        local = (_forward_call(*xs, _interpret(), keep) if kernels
+                 else _chunk_local_jnp(*xs))
+        out, left = _across(local[:6], state, v.dtype)
+        return left, (out, state, *local[6:])
 
-    _, (out, entered) = jax.lax.scan(
+    _, (out, *kept) = jax.lax.scan(
         step, jnp.zeros((b, h, d_k, v.shape[-1]), _F32),
         _segments(chunk, q, k, v, g, beta),
     )
-    out = checkpoint_name(_whole(out), KEPT[0])
-    entered = checkpoint_name(entered, KEPT[1])
-    return out, (q, k, v, g, beta, entered)
+    return _whole(out), *kept
+
+
+def _kda_fwd(q, k, v, g, beta, chunk, kernels):
+    out, *kept = (
+        checkpoint_name(a, name) for a, name in zip(
+            _forward(q, k, v, g, beta, chunk, kernels, keep=True), KEPT)
+    )
+    return out, (q, k, v, g, beta, *kept)
 
 
 def _kda_bwd(chunk, kernels, residuals, d_out):
-    *inputs, entered = residuals
+    q, k, v, g, beta, entered, *kept = residuals
 
     def step(d_state, xs):
-        *xs, state, d_o = xs
-        _, vjp = jax.vjp(
-            lambda *a: _segment(*a, chunk, kernels), *xs, state
-        )
+        (*xs, d_o), (state, *inverses) = xs[:6], xs[6:]
+
+        def segment(*a):
+            *a, state = a
+            a = _chunks(chunk, *a)
+            local = (chunk_local(*a, *inverses) if kernels
+                     else _chunk_local_jnp(*a))
+            return _across(local, state, v.dtype)
+
+        _, vjp = jax.vjp(segment, *xs, state)
         *d_xs, d_state = vjp((d_o, d_state))
         return d_state, tuple(d_xs)
 
-    *cut, d_cut = _segments(chunk, *inputs, d_out)
     _, grads = jax.lax.scan(
-        step, jnp.zeros(entered.shape[1:], _F32), (*cut, entered, d_cut),
+        step, jnp.zeros(entered.shape[1:], _F32),
+        (*_segments(chunk, q, k, v, g, beta, d_out), entered, *kept),
         reverse=True,
     )
     return tuple(_whole(a) for a in grads)

@@ -15,6 +15,7 @@ import pytest
 
 from raydp_tpu.ops import kda as kda_ops
 from raydp_tpu.ops.kda import kda_chunked, kda_recurrent, unit_lower_inverse
+from tests.test_checkpoint_keeps import _eqns
 
 NAMES = ("q", "k", "v", "g", "beta")
 
@@ -86,10 +87,21 @@ def _chunked(args, chunk):
 def test_the_kernels_vjp_is_the_jnp_chunk_local_forms():
     """One chunk of the cell's tile, [64, 128] float32, two heads: the six
     results, and the five gradients of the kernels' ``custom_vjp`` against
-    ``jax.vjp`` of the plain form under the same cotangents."""
+    ``jax.vjp`` of the plain form under the same cotangents. The kernels
+    read the chunks' ``T`` as the forward pass keeps it: the inverse of
+    ``I + A``, two row blocks of 32 side by side in 128 lanes."""
     args = _chunked(_inputs(b=1, s=64, d_k=128, d_v=128), 64)
     want, vjp = jax.vjp(kda_ops._chunk_local_jnp, *args)
-    got, kernel_vjp = jax.vjp(kda_ops.chunk_local, *args)
+    inverses = kda_ops._forward_call(*args, interpret=True, keep=True)[6]
+    assert inverses.shape == (1, 1, 2, 32, 128)
+    _, own = kda_ops._pairwise(*args[:2], jnp.cumsum(args[3], -2), jnp.float32)
+    system = jnp.eye(64) + args[4][..., None] * own
+    T = jnp.concatenate([inverses[..., :64], inverses[..., 64:]], axis=-2)
+    np.testing.assert_allclose(
+        jnp.einsum("...ij,...jk->...ik", T, system, precision="highest"),
+        jnp.broadcast_to(jnp.eye(64), T.shape), atol=2e-6)
+    got, kernel_vjp = jax.vjp(
+        lambda *a: kda_ops.chunk_local(*a, inverses), *args)
     rng = np.random.default_rng(1)
     cotangents = tuple(
         jnp.asarray(rng.standard_normal(a.shape), a.dtype) for a in want
@@ -243,6 +255,8 @@ def test_the_forward_names_what_a_checkpoint_keeps(monkeypatch):
     def loss(*a):
         return jnp.sum(kda_chunked(*a, 16))
 
+    assert kda_ops.KEPT == (
+        "kda_out", "kda_segment_states", "kda_chunk_inverses")
     policy = jax.checkpoint_policies.save_only_these_names(*kda_ops.KEPT)
     kept = jax.checkpoint(loss, policy=policy)
 
@@ -250,3 +264,94 @@ def test_the_forward_names_what_a_checkpoint_keeps(monkeypatch):
         return str(jax.make_jaxpr(jax.grad(fn))(*args)).count("cumsum")
 
     assert sums(kept) == sums(loss) < sums(jax.checkpoint(loss))
+
+
+def _kernel_calls(jaxpr):
+    """{kernel's name: (operand shapes, result shapes)} of a jaxpr's
+    Pallas calls."""
+    return {
+        e.params["name"]: ([tuple(v.aval.shape) for v in e.invars],
+                           [tuple(v.aval.shape) for v in e.outvars])
+        for e in _eqns(jaxpr, "pallas_call")
+    }
+
+
+def test_under_the_policy_the_kernels_inverses_are_kept_with_no_padded_lane(
+        monkeypatch):
+    """The kernel path at the cell's chunk, two segments of one chunk and
+    two heads: what enters the checkpoint's backward holds the chunks'
+    ``T`` as [segments, b, chunks, h, 32, 128] float32 (a [64, 64] float32
+    array's last dimension is padded to 128 lanes in HBM), nothing float32
+    [64, 64], and the backward runs the rebuild and the gradient kernels
+    and no forward kernel."""
+    monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 1)
+    args = _inputs(b=1, s=128, h=2, d_k=8, d_v=8)
+
+    def loss(*a):
+        return jnp.sum(kda_chunked(*a, 64, kernels=True))
+
+    policy = jax.checkpoint_policies.save_only_these_names(*kda_ops.KEPT)
+    grad = jax.make_jaxpr(jax.grad(jax.checkpoint(loss, policy=policy)))(
+        *args).jaxpr
+    (backward,) = [
+        e for e in _eqns(grad, "remat") + _eqns(grad, "checkpoint")
+        if "kda_chunk_backward" in _kernel_calls(e.params["jaxpr"])
+    ]
+    assert set(_kernel_calls(backward.params["jaxpr"])) == {
+        "kda_chunk_rebuild", "kda_chunk_backward"}
+    kept = [tuple(v.aval.shape) for v in backward.invars
+            if v.aval.dtype == jnp.float32]
+    assert (2, 1, 1, 2, 32, 128) in kept
+    assert all(shape[-1] % 128 == 0 for shape in kept if len(shape) == 6)
+    assert not [shape for shape in kept if shape[-2:] == (64, 64)]
+    assert kda_ops.inverses_shape((1, 256, 32), 64) == (1, 256, 32, 32, 128)
+    assert kda_ops.inverses_shape((3,), 128) == (3, 128, 128)
+
+
+@pytest.mark.parametrize("case,h,chunks,segment,count", [
+    ("one segment, an even count a grid step", 2, 2, 32, 4),
+    ("one segment, an odd count", 3, 1, 32, 3),
+    ("several segments, an even count", 2, 4, 2, 4),
+    ("several segments, an odd count", 1, 3, 32, 1),
+])
+def test_the_backward_by_the_kernels_inverts_nothing(case, h, chunks, segment,
+                                                     count, monkeypatch):
+    """Under ``jax.grad`` of ``kda_chunked`` on the kernel path the
+    triangular inverse is traced ONCE, in the forward pass's kernel, which
+    writes every chunk's ``T``; the backward's rebuild of a segment has
+    ``T`` among its operands and not among its results, the gradient
+    kernel reads the same array, and the gradients are the ``jax.numpy``
+    path's."""
+    chunk, d = 16, 16
+    monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", segment)
+    traced = []
+    inverse = kda_ops._inverse_tile
+    monkeypatch.setattr(
+        kda_ops, "_inverse_tile", lambda a: traced.append(a) or inverse(a))
+    args = _inputs(b=1, s=chunk * chunks, h=h, d_k=d, d_v=d)
+
+    def grads(kernels):
+        return jax.grad(
+            lambda *a: jnp.sum(jnp.sin(kda_chunked(*a, chunk, kernels))),
+            argnums=(0, 1, 2, 3, 4))
+
+    calls = _kernel_calls(jax.make_jaxpr(grads(True))(*args).jaxpr)
+    assert len(traced) == 1
+    assert set(calls) == {
+        "kda_chunk_forward", "kda_chunk_rebuild", "kda_chunk_backward"}
+    kept = (count, *kda_ops.inverses_shape((), chunk))
+    assert kept[-1] == 128
+    steps = count // np.gcd(count, kda_ops.CHUNKS_A_STEP)
+    for name, n_in, n_out, reads in [
+        ("kda_chunk_forward", 5, 7, False),
+        ("kda_chunk_rebuild", 6, 6, True),
+        ("kda_chunk_backward", 12, 5, True),
+    ]:
+        operands, results = calls[name]
+        assert (len(operands), len(results)) == (n_in, n_out), name
+        assert (operands[5] == kept) if reads else (results[6] == kept), name
+        assert kept not in (results if reads else operands), name
+        assert operands[4] == (steps, count // steps, chunk), name
+    for name, a, b in zip(NAMES, jax.jit(grads(True))(*args),
+                          jax.jit(grads(False))(*args)):
+        assert _rel(a, b) < 2e-5, name

@@ -416,6 +416,8 @@ def test_the_layers_norm_and_the_latent_layer_keep_their_scopes(lowered):
     ("kda/chunks_per_step", 1024),
     ("kda/state_bytes_per_sequence", 4 * 32 * 128 * 128 * 4),
     ("kda/scan_kernel_layers", 4),
+    # 4 layers x 256 chunks x 32 heads x a float32 [64, 64] inverse.
+    ("kda/kept_inverse_mib", 512),
     ("latent/rotary_dims", 0),
 ])
 def test_the_reports_of_the_published_stack(gauge, value, caplog):
@@ -435,26 +437,32 @@ def test_the_reports_of_the_published_stack(gauge, value, caplog):
     assert kda_module.SCAN_IMPLEMENTATION in line and "ops/kda.py" in line
     # ... and which path a chunk's work takes at these shapes.
     assert line.endswith(kda_module.SCAN_PATHS[True])
-    assert "kda_chunk_forward" in line
+    assert "kda_chunk_forward" in line and "kda_chunk_rebuild" in line
+    # ... and what its forward keeps for the backward.
+    assert "the forward keeps 512 MiB of chunk inverses a sequence" in line
 
 
-@pytest.mark.parametrize("sequence,chunk,layers", [
-    (16384, 64, 4), (8192, 64, 4), (48, 16, 0), (100, 4, 0),
+@pytest.mark.parametrize("sequence,chunk,layers,kept_mib", [
+    (16384, 64, 4, 512), (8192, 64, 4, 256), (48, 16, 0, 0), (100, 4, 0, 0),
 ])
 def test_the_kernel_gauge_follows_the_sequences_chunk(sequence, chunk, layers,
-                                                      caplog):
+                                                      kept_mib, caplog):
     """A sequence that is no multiple of 64 runs in a smaller chunk, and
-    that by the ``jax.numpy`` form: the gauge and the line say what
-    ``kda_chunked`` will be asked with."""
+    that by the ``jax.numpy`` form, which keeps no inverse: the gauges and
+    the line say what ``kda_chunked`` will be asked with. The kept
+    inverses are ONE sequence's, whatever the step's batch."""
     cfg = kimi_linear_48b_a3b(n_layers=5)
     assert cfg.kda.scan_chunk(sequence) == chunk
     assert cfg.kda.scan_runs_kernels(sequence) is bool(layers)
     with caplog.at_level(logging.INFO, logger="raydp_tpu.models.kda"):
         kda_module.report(cfg, tokens_per_step=2 * sequence, sequence=sequence)
     assert metrics.gauge_value("kda/scan_kernel_layers") == layers
+    assert metrics.gauge_value("kda/kept_inverse_mib") == kept_mib
+    assert cfg.kda.kept_inverse_bytes(4, sequence) == kept_mib << 20
     (record,) = [r for r in caplog.records
                  if r.name == "raydp_tpu.models.kda"]
     assert record.getMessage().endswith(kda_module.SCAN_PATHS[bool(layers)])
+    assert f"keeps {kept_mib} MiB of chunk inverses" in record.getMessage()
 
 
 def test_the_reports_read_zero_for_the_other_stacks(caplog):
@@ -463,6 +471,7 @@ def test_the_reports_read_zero_for_the_other_stacks(caplog):
             kda_module.report(cfg, tokens_per_step=4096)
             for gauge in ("kda/layers", "kda/heads", "kda/chunk",
                           "kda/chunks_per_step", "kda/scan_kernel_layers",
+                          "kda/kept_inverse_mib",
                           "kda/state_bytes_per_sequence"):
                 assert metrics.gauge_value(gauge) == 0
     assert not caplog.records

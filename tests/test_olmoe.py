@@ -514,12 +514,16 @@ def test_chunked_scan_compiles_for_the_chip_at_published_widths(one_chip):
 def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
         one_chip, monkeypatch):
     """One sequence of 16,384 tokens, 32 heads of 128 / 128 in chunks of
-    64, forward and backward: Mosaic accepts both chunk kernels (three
-    calls: forward, the backward's rebuild, the gradient), and what the
-    ``jax.numpy`` form wrote for every segment is in no buffer: nothing
-    with the six levels' axis, and of float32 [chunk, chunk] arrays the
-    rebuild's kept inverse alone (the other, inside a fusion, is the
-    output product's gradient of ``P`` before it is rounded and stored)."""
+    64, forward and backward: Mosaic accepts the chunk kernels (three
+    calls: forward, which writes every chunk's inverse ``T``; the
+    backward's rebuild, which reads it and inverts nothing; the gradient),
+    and what the ``jax.numpy`` form wrote for every segment is in no
+    buffer: nothing with the six levels' axis, and of float32 [chunk,
+    chunk] arrays one alone, inside a fusion (the output product's
+    gradient of ``P`` before it is rounded and stored). ``T`` is kept as
+    [segments, 1, 32 chunks, 32 heads, 32, 128] float32, a [64, 64] tile's
+    two row blocks side by side: 134 MB a layer, where a float32
+    [..., 64, 64] array, its last dimension padded to 128 lanes, is 268."""
     import re
 
     from raydp_tpu.ops import kda
@@ -542,9 +546,27 @@ def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
     assert hlo.count("tpu_custom_call") == 3
     assert not re.search(r"\[[\d,]*,6,64,128\]", hlo)
     squares = set(re.findall(r"f32\[[\d,]*64,64\]", hlo))
-    assert squares == {"f32[1024,64,64]", "f32[32,32,64,64]"}, squares
+    assert squares == {"f32[32,32,64,64]"}, squares
     assert "bf16[32,32,64,64]" in hlo and hlo.count("f32[32,32,64,64]") == 1
-    # 0.69 GB; the jax.numpy form's temporaries were 2.00 GiB (PR 44).
+    # The kept inverses: one array of all eight segments', written a
+    # segment at a time by the forward kernel and read by the rebuild and
+    # the gradient kernels, in tiles with no padded lane.
+    kept = kda.inverses_shape((8, 1, 32, 32), 64)
+    assert kept == (8, 1, 32, 32, 32, 128)
+    (layout,) = set(re.findall(
+        r"f32\[8,1,32,32,32,128\]\{[^}]*\}", hlo))
+    assert layout.endswith("{5,4,3,2,1,0:T(8,128)}"), layout
+    assert 4 * int(np.prod(kept)) == 134_217_728
+    a_segment = "f32[1024,32,128]{2,1,0}"
+    for name, reads in (("forward", False), ("rebuild", True),
+                        ("backward", True)):
+        (call,) = [line for line in hlo.splitlines()
+                   if f"%kda_chunk_{name}" in line
+                   and "tpu_custom_call" in line]
+        operands = call.split("operand_layout_constraints=")[1]
+        assert (a_segment in operands) is reads, name
+    # 0.94 GB with the 134 MB of T (0.69 at PR 45, which kept none); the
+    # jax.numpy form's temporaries were 2.00 GiB (PR 44).
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.0e9, temp
 
