@@ -13,8 +13,10 @@ from raydp_tpu.models.transformer import (
     param_shardings,
     tiny_transformer,
     laguna_xs_2,
+    sdar_30b_a3b,
     xing4_0,
 )
+from raydp_tpu.models.blockdiff import BlockDiffusionConfig, BlockDiffusionLM
 from raydp_tpu.models.hyperconn import HyperConfig
 from raydp_tpu.models.kda import KDAConfig
 from raydp_tpu.models.latent import LatentConfig
@@ -64,7 +66,10 @@ __all__ = [
     "lfm2_8b_a1b",
     "olmoe",
     "laguna_xs_2",
+    "sdar_30b_a3b",
     "xing4_0",
+    "BlockDiffusionConfig",
+    "BlockDiffusionLM",
     "HyperConfig",
     "KDAConfig",
     "LatentConfig",

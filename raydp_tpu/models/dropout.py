@@ -61,11 +61,13 @@ def key_for(step_key):
     )
 
 
-def census(apply_fn, variables, sample_batch):
+def census(apply_fn, variables, sample_batch, also=()):
     """``(sites, mask words)`` of one training-mode step: the dropout
     modules (this one or flax's) that draw a mask when the model is
     applied to a batch, and the 32-bit words those masks are drawn from.
-    From one abstract apply; nothing runs."""
+    From one abstract apply; nothing runs. ``also`` names the further rng
+    collections the model's step draws from (the apply needs a key for
+    each)."""
     sites = words = 0
 
     def count(next_fun, args, kwargs, context):
@@ -88,7 +90,8 @@ def census(apply_fn, variables, sample_batch):
         jax.eval_shape(
             lambda v, x: apply_fn(
                 v, x, deterministic=False,
-                rngs={"dropout": key_for(jax.random.PRNGKey(0))},
+                rngs={name: key_for(jax.random.PRNGKey(0))
+                      for name in ("dropout",) + tuple(also)},
             ),
             variables, sample_batch,
         )

@@ -157,10 +157,14 @@ class TransformerConfig:
     # ``models/latent.LatentConfig`` for the "latent" mixer,
     # ``models/kda.KDAConfig`` for the "kda" mixer,
     # ``models/hyperconn.HyperConfig`` for more than one residual stream
-    # (None = the plain ``x + F(norm(x))``).
+    # (None = the plain ``x + F(norm(x))``);
+    # ``models/blockdiff.BlockDiffusionConfig`` for a stack that runs on
+    # block diffusion's training PAIRS (a noised and a clean copy of every
+    # sequence side by side, the "attention" mixer under the pair mask).
     latent: Any = None
     kda: Any = None
     hyper: Any = None
+    diffusion: Any = None
     # :class:`WindowConfig` for the "window" mixer; the four fields after
     # it describe the "attention" mixer beside it (and a window layer's
     # head size and gate): a head of its own size (None = d_model //
@@ -317,7 +321,11 @@ class MultiHeadAttention(nn.Module):
     without ``cfg.causal``) from the configuration's own sizes; with
     ``window`` (a :class:`WindowConfig`, the "window" mixer) over the last
     ``window.window`` of them, with that record's head count and rotary
-    positions."""
+    positions. ``positions`` ([B or 1, S]) are what rotary positions
+    rotate by where they are not ``0 … S-1``. With ``cfg.diffusion`` the S
+    positions are a noised and a clean copy of a sequence side by side and
+    the mask is the pair's (``ops/attention.pair_mask``), in blocks of
+    that record's length."""
 
     cfg: TransformerConfig
     window: Any = None
@@ -331,9 +339,19 @@ class MultiHeadAttention(nn.Module):
         cache_mode: Optional[str] = None,
         cache_positions=None,
         kv_len: Optional[int] = None,
+        positions=None,
     ):
         cfg, win = self.cfg, self.window
         scale = cfg.attention_scale
+        pair = cfg.diffusion.block_length if cfg.diffusion else None
+        if pair is not None and (
+                win is not None or cache_mode is not None or not cfg.causal
+                or cfg.attention_impl not in ("dense", "flash")):
+            raise NotImplementedError(
+                "the pair mask runs in the causal 'attention' mixer, dense "
+                "or flash, in training and evaluation; a decode loop that "
+                "finishes a block a step is ROADMAP R10"
+            )
         n_heads, theta, rotary_dim, yarn, span = (
             cfg.n_heads, cfg.rope_theta, cfg.rotary_dim, cfg.rope_yarn, None
         ) if win is None else (
@@ -390,6 +408,8 @@ class MultiHeadAttention(nn.Module):
         if cfg.positions == "rotary":
             if cache_mode == "step":
                 pos = cache_positions[:, None]
+            elif positions is not None:
+                pos = positions
             else:
                 pos = jnp.arange(x.shape[-2])[None, :]
             q = rotary(q, pos, theta, yarn, rotary_dim)
@@ -444,6 +464,26 @@ class MultiHeadAttention(nn.Module):
                 )
             else:
                 raise ValueError(f"unknown cache_mode {cache_mode!r}")
+        elif pair is not None:
+            # Everything between the rotated q, k, v and the attention's
+            # output under the pair mask carries the scope ``attn/pair``
+            # or, the kernels and the layout moves around them,
+            # ``attn/jit(flash_attention)``.
+            if cfg.attention_impl == "dense":
+                with jax.named_scope("pair"):
+                    out = reference_attention(
+                        q, k, v, causal=True, scale=scale, pair=pair
+                    )
+            elif cfg.mesh is not None:
+                raise NotImplementedError(
+                    "the pair mask through the flash kernels on a mesh"
+                )
+            else:
+                from raydp_tpu.ops.flash_attention import (
+                    flash_pair_attention,
+                )
+
+                out = flash_pair_attention(q, k, v, pair, scale=scale)
         elif cfg.attention_impl == "dense":
             out = reference_attention(
                 q, k, v, causal=cfg.causal, scale=scale, window=span
@@ -531,8 +571,14 @@ class TransformerBlock(nn.Module):
         cache_mode: Optional[str] = None,
         cache_positions=None,
         kv_len: Optional[int] = None,
+        positions=None,
     ):
         cfg = self.cfg
+        if cfg.diffusion is not None and self.mixer != "attention":
+            raise NotImplementedError(
+                f"a {self.mixer!r} layer over a pair of copies: only the "
+                "'attention' mixer knows the pair mask"
+            )
 
         def scaled(branch):
             if cfg.residual_multiplier == 1.0:
@@ -555,6 +601,7 @@ class TransformerBlock(nn.Module):
                     cache_mode=cache_mode,
                     cache_positions=cache_positions,
                     kv_len=kv_len,
+                    positions=positions,
                 )
             if cache_mode is not None:
                 raise NotImplementedError({
@@ -654,7 +701,8 @@ class TransformerEncoder(nn.Module):
     """Token + position (+ optional segment) embeddings, N blocks (layer
     i's mixer and FFN kind from ``cfg.layer_types``), final LN.
 
-    Input: int32 token ids [B, S] (+ optional segment ids). Output:
+    Input: int32 token ids [B, S] (+ optional segment ids; ``positions``
+    [B or 1, S] where rotary positions are not ``0 … S-1``). Output:
     [B, S, d_model] hidden states.
     """
 
@@ -670,8 +718,13 @@ class TransformerEncoder(nn.Module):
         cache_mode: Optional[str] = None,
         cache_positions=None,
         kv_len: Optional[int] = None,
+        positions=None,
     ):
         cfg = self.cfg
+        if positions is not None and cfg.positions != "rotary":
+            raise NotImplementedError(
+                f"given positions with cfg.positions={cfg.positions!r}"
+            )
         x = nn.Embed(
             cfg.vocab_size, cfg.d_model,
             embedding_init=_embed_init(
@@ -739,6 +792,9 @@ class TransformerEncoder(nn.Module):
 
             with jax.named_scope("hc_expand"):
                 x = hyperconn.expand(x, cfg.hyper.streams)
+        # Given positions go to the blocks that rotate by them; a call
+        # without them is the call it was.
+        given = {} if positions is None else {"positions": positions}
         for i, (mixer, ffn) in enumerate(cfg.layers):
             x = block_cls(cfg, mixer, ffn, name=f"block_{i}")(
                 x,
@@ -746,6 +802,7 @@ class TransformerEncoder(nn.Module):
                 cache_mode=cache_mode,
                 cache_positions=cache_positions,
                 kv_len=kv_len,
+                **given,
             )
         if cfg.hyper is not None:
             with jax.named_scope("hc_reduce"):
@@ -1142,6 +1199,42 @@ def kimi_linear_48b_a3b(**overrides) -> TransformerConfig:
         ),
         kda=KDAConfig(heads=32, key_dim=128, value_dim=128, conv_taps=4,
                       gate_rank=128, chunk=64),
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def sdar_30b_a3b(**overrides) -> TransformerConfig:
+    """JetLM SDAR-30B-A3B-Chat (30.5B parameters, about 3B active;
+    ``config.json`` of JetLM/SDAR-30B-A3B-Chat, ``model_type`` sdar_moe;
+    arXiv:2510.06303): 48 identical pre-norm layers of width 2048;
+    grouped-query attention, 32 query heads over 4 key-value heads of 128
+    (a head size of its own: 32 x 128 = 4096 over a hidden of 2048), an
+    RMSNorm over each head's q and k, the whole head rotated at theta
+    1e6; 128 SwiGLU experts of width 768, 8 a token by softmax
+    probability, renormalised, no shared expert, no auxiliary loss;
+    RMSNorm 1e-6, no biases; vocabulary 151936, untied head. It is
+    trained and sampled by DIFFUSION OVER BLOCKS: ``diffusion`` is the
+    objective's record (``models/blockdiff.py``; the config gives neither
+    the block length nor the noise schedule: 4, the released chat
+    model's generation block, and the linear schedule of arXiv:2503.09573
+    are assumed), and the model class is ``BlockDiffusionLM``.
+    ``experts_held`` / ``first_expert`` give a layer the share of an
+    expert-parallel deployment; ``n_layers`` keeps the model's own first
+    layers. The published block has no selection bias and neither has
+    this one."""
+    from raydp_tpu.models.blockdiff import BlockDiffusionConfig
+
+    defaults = dict(
+        vocab_size=151936, d_model=2048, n_heads=32, n_kv_heads=4,
+        head_size=128, n_layers=48, max_len=32768, dropout_rate=0.0,
+        causal=True, norm="rmsnorm", norm_eps=1e-6, positions="rotary",
+        rope_theta=1e6, qk_norm="head", use_bias=False, ffn="moe",
+        n_experts=128, top_k=8, d_expert=768, router_scoring="softmax",
+        norm_top_k=True, moe_loss_weights=(0.0, 0.0), tie_head=False,
+        diffusion=BlockDiffusionConfig(
+            block_length=4, mask_id=151669, t_min=1e-3
+        ),
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

@@ -23,6 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -39,25 +40,32 @@ def reference_attention(
     causal: bool = False,
     scale: Optional[float] = None,
     window: Optional[int] = None,
+    pair: Optional[int] = None,
 ) -> jnp.ndarray:
     """Plain softmax attention. Shapes: q [B, S, H, D], k and v [B, S,
     Hkv, D] with H a multiple of Hkv (each key-value head serves H / Hkv
     consecutive query heads) → [B, S, H, D]. ``window`` (causal only)
-    keeps the last ``window`` keys of each query, its own among them."""
+    keeps the last ``window`` keys of each query, its own among them.
+    ``pair`` (a block length, causal only, no window) reads the S
+    positions as a noised and a clean copy of S/2 each under
+    :func:`pair_mask`."""
     scale = _scale(scale, q.shape[-1])
-    if window is not None and not causal:
-        raise ValueError("a window is over the keys before a query")
+    if (window is not None or pair is not None) and not causal:
+        raise ValueError("a window, or a pair's blocks, are over the keys "
+                         "before a query")
+    if pair is not None and window is not None:
+        raise ValueError("no window under the pair mask")
     h, h_kv = q.shape[2], k.shape[2]
     if h != h_kv:
         b, s_q, _, d = q.shape
         out = _grouped_attention(
             q.reshape(b, s_q, h_kv, h // h_kv, d), k, v, causal, scale,
-            window,
+            window, pair,
         )
         return out.reshape(b, s_q, h, v.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
-        scores = jnp.where(_causal_mask(scores, window), scores, -jnp.inf)
+        scores = jnp.where(_mask(scores, window, pair), scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
@@ -70,13 +78,44 @@ def _causal_mask(scores, window: Optional[int] = None):
     return mask
 
 
+def pair_mask(s: int, block_length: int) -> np.ndarray:
+    """The attention mask of block diffusion's training pair, [2s, 2s]
+    bool, query down the rows: positions ``[0, s)`` are a NOISED copy of a
+    sequence and ``[s, 2s)`` its CLEAN copy, in blocks of ``block_length``
+    (``b(i) = (i mod s) // block_length``). A noised query sees the noised
+    keys of its own block (both directions) and the clean keys of the
+    blocks strictly before it; a clean query sees the clean keys of its
+    own and earlier blocks; no clean query sees a noised key."""
+    if s % block_length:
+        raise ValueError(
+            f"{s} positions do not divide into blocks of {block_length}"
+        )
+    block = np.arange(2 * s) % s // block_length
+    clean = np.arange(2 * s) >= s
+    q_b, k_b = block[:, None], block[None, :]
+    q_c, k_c = clean[:, None], clean[None, :]
+    return np.where(
+        k_c, np.where(q_c, k_b <= q_b, k_b < q_b), ~q_c & (k_b == q_b)
+    )
+
+
+def _mask(scores, window: Optional[int], pair: Optional[int]):
+    if pair is None:
+        return _causal_mask(scores, window)
+    s_q, s_k = scores.shape[-2], scores.shape[-1]
+    if s_q != s_k or s_q % 2:
+        raise ValueError(f"a pair's scores are square, not [{s_q}, {s_k}]")
+    return jnp.asarray(pair_mask(s_q // 2, pair))
+
+
 def _grouped_attention(q, k, v, causal: bool, scale: float,
-                       window: Optional[int] = None):
+                       window: Optional[int] = None,
+                       pair: Optional[int] = None):
     """``q`` [B, S, Hkv, G, D] against ``k``, ``v`` [B, S, Hkv, D]: K and
     V are read once a group, not repeated."""
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) * scale
     if causal:
-        scores = jnp.where(_causal_mask(scores, window), scores, -jnp.inf)
+        scores = jnp.where(_mask(scores, window, pair), scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhgqk,bkhd->bqhgd", weights, v)
 

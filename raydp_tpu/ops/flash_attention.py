@@ -173,6 +173,55 @@ def _last_q(ki, q_block: int, block_kv: int, window: int, q_tiles: int):
     )
 
 
+def _block_start(pos, block_length: int):
+    """The first position of ``pos``'s block of ``block_length``: a mask
+    of the bits where the length is a power of two (Mosaic has no vector
+    division to spare on a score tile), the division otherwise."""
+    if block_length & (block_length - 1) == 0:
+        return pos & -block_length
+    return pos // block_length * block_length
+
+
+def _pair_limit(q_pos, block_length: int, copy):
+    """Under the PAIR mask (the module docstring): one past the last clean
+    key that the query at ``q_pos`` of copy ``copy`` sees. The noised copy
+    (0) sees the blocks strictly before its own, the clean copy (1) its
+    own block too; a query sees the keys ``j < limit``."""
+    return _block_start(q_pos, block_length) + copy * block_length
+
+
+def _pair_live(qi, ki, q_block: int, block_kv: int, block_length: int, copy):
+    """Whether the pair mask leaves tile (qi, ki) any entry: its LAST
+    query row (the limit grows with the row) sees its first key."""
+    return _pair_limit(
+        (qi + 1) * q_block - 1, block_length, copy
+    ) > ki * block_kv
+
+
+def _pair_whole(qi, ki, q_block: int, block_kv: int, block_length: int, copy):
+    """Whether the pair mask leaves tile (qi, ki) every entry: its FIRST
+    query row already sees its last key. A whole tile is live."""
+    return _pair_limit(qi * q_block, block_length, copy) >= (ki + 1) * block_kv
+
+
+def _pair_last_kv(qi, q_block: int, block_kv: int, block_length: int, copy):
+    """The last kv tile q tile ``qi`` of copy ``copy`` sees under the pair
+    mask; tile 0 where it sees none (the noised copy's first block)."""
+    limit = _pair_limit((qi + 1) * q_block - 1, block_length, copy)
+    return jnp.maximum(limit - 1, 0) // block_kv
+
+
+def _pair_first_q(ki, q_block: int, block_kv: int, block_length: int, copy,
+                  q_tiles: int):
+    """The first q tile of copy ``copy`` that sees kv tile ``ki`` under
+    the pair mask: the tile of the first row of block ``b(k) + 1 - copy``,
+    ``k`` the tile's first key; the last tile where no row sees it."""
+    first_row = (
+        ki * block_kv // block_length + 1 - copy
+    ) * block_length
+    return jnp.minimum(first_row // q_block, q_tiles - 1)
+
+
 def band_tiles(s: int, block_q: int, block_kv: int,
                window: int) -> Tuple[int, int]:
     """(kv tiles a q tile's band spans at most, q tiles a kv tile's): the
@@ -188,13 +237,24 @@ def band_tiles(s: int, block_q: int, block_kv: int,
 
 def _on_live_tile(qi, ki, body, *, causal: bool, q_block: int,
                   block_kv: int, window: Optional[int] = None,
-                  inside=None) -> None:
+                  inside=None, pair=None) -> None:
     """Run ``body(masked)`` on tile (qi, ki) by its kind: not at all on a
     dead tile, with ``masked=False`` on a whole one, with ``masked=True``
     on one the diagonal crosses. ``masked`` is static: the whole-tile body
     holds no iota, compare or select. Without ``causal`` every tile is
     whole and only that body is built. ``inside`` (a band's step past the
-    sequence's last tile is not) is a further condition on both."""
+    sequence's last tile is not) is a further condition on both. ``pair``
+    ((block length, copy), the pair mask) takes the same three kinds from
+    its own two predicates."""
+    if pair is not None:
+        whole = _pair_whole(qi, ki, q_block, block_kv, *pair)
+        crossed = jnp.logical_and(
+            _pair_live(qi, ki, q_block, block_kv, *pair),
+            jnp.logical_not(whole),
+        )
+        pl.when(whole)(functools.partial(body, False))
+        pl.when(crossed)(functools.partial(body, True))
+        return
     if not causal:
         body(False)
         return
@@ -233,14 +293,17 @@ def tile_counts(s: int, block_q: Optional[int] = None,
 
 
 def report(cfg, seq_len: int, batch: int = 1) -> None:
-    """Static for a compiled step: eight gauges and a log line a kind of
+    """Static for a compiled step: eleven gauges and a log line a kind of
     layer where the step is built (as ``models/mamba.report``). Per head
-    and call, the layers over all positions and the window layers apart;
-    then the layers whose two named residuals (:data:`KEPT`) a block
-    checkpoint keeps, and their size at the step's shapes; then the
-    layers whose backward is the one kernel (:func:`backward_is_fused`,
-    the rule the call itself takes) and the largest call's resident
-    accumulators; zero for a model that never calls the kernel."""
+    and call, the layers over all positions and the window layers apart,
+    and per head and PAIR of copies the layers under the pair mask
+    (``cfg.diffusion``; ``seq_len`` is one copy's and a call runs two
+    rows a sequence); then the layers whose two named residuals
+    (:data:`KEPT`) a block checkpoint keeps, and their size at the step's
+    shapes; then the layers whose backward is the one kernel
+    (:func:`backward_is_fused`, the rule the call itself takes) and the
+    largest call's resident accumulators; zero for a model that never
+    calls the kernel."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = latent = windowed = 0
@@ -248,9 +311,14 @@ def report(cfg, seq_len: int, batch: int = 1) -> None:
         latent = cfg.kinds.count("latent")
         layers = cfg.kinds.count("attention") + latent
         windowed = cfg.kinds.count("window")
-    live, masked = tile_counts(seq_len, causal=cfg.causal) if layers else (
-        0, 0
-    )
+    pair = getattr(getattr(cfg, "diffusion", None), "block_length", None)
+    pair_tiles = (0, 0, 0)
+    if layers and pair is not None:
+        pair_tiles = pair_tile_counts(seq_len, pair)
+        batch = 2 * batch
+    live, masked = tile_counts(seq_len, causal=cfg.causal) if (
+        layers and pair is None
+    ) else (0, 0)
     span = _window(cfg.window.window, cfg.causal, seq_len) if windowed else (
         None
     )
@@ -290,6 +358,9 @@ def report(cfg, seq_len: int, batch: int = 1) -> None:
     metrics.gauge_set("attention/flash_masked_tiles", masked)
     metrics.gauge_set("attention/flash_window_live_tiles", band_live)
     metrics.gauge_set("attention/flash_window_masked_tiles", band_masked)
+    metrics.gauge_set("attention/flash_pair_live_tiles", pair_tiles[0])
+    metrics.gauge_set("attention/flash_pair_crossed_tiles", pair_tiles[1])
+    metrics.gauge_set("attention/flash_pair_own_block_tiles", pair_tiles[2])
     metrics.gauge_set("attention/flash_kept_layers", kept)
     metrics.gauge_set("attention/flash_kept_mib", kept_bytes / 2 ** 20)
     metrics.gauge_set("attention/flash_fused_bwd_layers", fused_layers)
@@ -314,7 +385,22 @@ def report(cfg, seq_len: int, batch: int = 1) -> None:
         }
         return "the backward is " + " or ".join(said[p] for p in paths)
 
-    if layers:
+    if layers and pair is not None:
+        block = _block(None, seq_len)
+        logger.info(
+            "flash attention under the pair mask in blocks of %d: %d layers, "
+            "two copies of S = %d in %d x %d tiles, %d live a head and pair "
+            "over the clean keys (a causal call over 2S would compute %d), "
+            "%d of them masked, no other fetched and no noised key read; the "
+            "noised copy's own-block term runs beside the kernels on [%d, "
+            "%d, %d] blocks a head and is merged through the rows' lse; "
+            "softmax scale %g on the %s; %s; %s",
+            pair, layers, seq_len, block, block, pair_tiles[0],
+            tile_counts(2 * seq_len, block, block)[0], pair_tiles[1],
+            seq_len // pair, pair, pair, scale, rides, keeps,
+            backward(("attention",)),
+        )
+    elif layers:
         block = _block(None, seq_len)
         logger.info(
             "flash attention: %d layers, S = %d in %d x %d tiles, %d live a "
@@ -359,7 +445,7 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
 
 def _scores(q_ref, k_ref, qi, ki, *, scale: float, masked: bool,
             q_block: int, block_kv: int, transposed: bool = False,
-            window: Optional[int] = None):
+            window: Optional[int] = None, pair=None):
     """Shared tile math for ALL kernels (forward, dq, dkv): load raw
     q/k tiles and compute the scaled score tile, causally masked where
     ``masked`` — one definition, so forward and backward masking can
@@ -389,24 +475,38 @@ def _scores(q_ref, k_ref, qi, ki, *, scale: float, masked: bool,
         k_pos = ki * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1 - q_axis
         )
-        keep = q_pos >= k_pos
+        if pair is not None:
+            keep = k_pos < _pair_limit(q_pos, *pair)
+        else:
+            keep = q_pos >= k_pos
         if window is not None:
             keep = jnp.logical_and(keep, k_pos > q_pos - window)
         s = jnp.where(keep, s, NEG_INF)
     return q, k, s
 
 
+def _pair_at(pair: Optional[int]):
+    """(block length, copy) of the grid step a kernel body runs in, None
+    without the pair mask: every kernel's first grid dimension is the
+    batch row, and the rows of a pair call alternate noised (0) and clean
+    (1) copies."""
+    return None if pair is None else (pair, pl.program_id(0) % 2)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                   acc_ref, *, block_kv: int, causal: bool, scale: float,
-                  q_block: int, window: Optional[int] = None):
+                  q_block: int, window: Optional[int] = None,
+                  pair: Optional[int] = None):
     """Grid (b, h, q_blocks, kv_blocks); kv is the innermost sequential
     dimension, so only one [block_kv, d] K/V tile is VMEM-resident at a
     time and the (m, l, acc) scratch carries across kv steps. Under a
     window the innermost dimension is the band's steps and the kv tile is
-    the band's first plus the step."""
+    the band's first plus the step. Under the pair mask (``pair``, the
+    block length) the batch row's parity says which copy the queries are."""
     qi = pl.program_id(2)
     step = pl.program_id(3)
     n_kv = pl.num_programs(3)
+    pair_at = _pair_at(pair)
     ki = step if window is None else (
         _first_kv(qi, q_block, block_kv, window) + step
     )
@@ -420,7 +520,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     def _attend(masked: bool):
         _, _, s = _scores(
             q_ref, k_ref, qi, ki, scale=scale, masked=masked,
-            q_block=q_block, block_kv=block_kv, window=window,
+            q_block=q_block, block_kv=block_kv, window=window, pair=pair_at,
         )
         v = v_ref[0, 0]
         m_prev = m_ref[...]
@@ -436,7 +536,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
     # Causal: blocks strictly above the diagonal contribute nothing.
     _on_live_tile(qi, ki, _attend, causal=causal, q_block=q_block,
-                  block_kv=block_kv, window=window)
+                  block_kv=block_kv, window=window, pair=pair_at)
 
     @pl.when(step == n_kv - 1)
     def _finish():
@@ -449,7 +549,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
                    acc_ref, *, block_kv: int, causal: bool, scale: float,
-                   q_block: int, window: Optional[int] = None):
+                   q_block: int, window: Optional[int] = None,
+                   pair: Optional[int] = None):
     """dq for one q tile, accumulated over kv tiles (innermost grid dim;
     under a window, over the band's, as the forward kernel).
 
@@ -458,6 +559,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
     qi = pl.program_id(2)
     step = pl.program_id(3)
     n_kv = pl.num_programs(3)
+    pair_at = _pair_at(pair)
     ki = step if window is None else (
         _first_kv(qi, q_block, block_kv, window) + step
     )
@@ -469,7 +571,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
     def _accumulate(masked: bool):
         _, k, s = _scores(
             q_ref, k_ref, qi, ki, scale=scale, masked=masked,
-            q_block=q_block, block_kv=block_kv, window=window,
+            q_block=q_block, block_kv=block_kv, window=window, pair=pair_at,
         )
         p = jnp.exp(s - lse_ref[0, 0])          # [q_block, block_kv] f32
         dp = _dot(g_ref[0, 0], v_ref[0, 0], _NT)
@@ -477,7 +579,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
         acc_ref[...] += _dot(ds.astype(k.dtype), k) * scale
 
     _on_live_tile(qi, ki, _accumulate, causal=causal, q_block=q_block,
-                  block_kv=block_kv, window=window)
+                  block_kv=block_kv, window=window, pair=pair_at)
 
     @pl.when(step == n_kv - 1)
     def _finish():
@@ -488,7 +590,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_kv: int,
                     causal: bool, scale: float, q_block: int,
                     q_tiles: Optional[int] = None,
-                    window: Optional[int] = None, seq_q_tiles: int = 0):
+                    window: Optional[int] = None, seq_q_tiles: int = 0,
+                    pair: Optional[int] = None):
     """dk/dv for one kv tile, accumulated over q tiles (innermost).
 
     dv = pᵀ · g;  dk = scale · dsᵀ · q.
@@ -503,6 +606,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     step = pl.program_id(3)
     n_steps = pl.num_programs(3)
     qi = step if q_tiles is None else step % q_tiles
+    pair_at = _pair_at(pair)
     inside = None
     if window is not None:
         qi = _first_q(ki, q_block, block_kv) + qi
@@ -520,7 +624,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         q, _, s = _scores(
             q_ref, k_ref, qi, ki, scale=scale, masked=masked,
             q_block=q_block, block_kv=block_kv, transposed=True,
-            window=window,
+            window=window, pair=pair_at,
         )
         g = g_ref[0, 0]
         p = jnp.exp(s - lse_ref[0, 0])          # [block_kv, q_block] f32
@@ -530,7 +634,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_acc[...] += _dot(ds.astype(q.dtype), q) * scale
 
     _on_live_tile(qi, ki, _accumulate, causal=causal, q_block=q_block,
-                  block_kv=block_kv, window=window, inside=inside)
+                  block_kv=block_kv, window=window, inside=inside,
+                  pair=pair_at)
 
     @pl.when(step == n_steps - 1)
     def _finish():
@@ -542,7 +647,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, kt_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       block_kv: int, causal: bool, scale: float,
                       q_block: int, window: Optional[int] = None,
-                      seq_q_tiles: int = 0):
+                      seq_q_tiles: int = 0, pair: Optional[int] = None):
     """dq, dk and dv of one query head from ONE pass over its live tiles.
 
     Grid (b, kv head, head in group, kv tile, q step): q innermost, under
@@ -566,6 +671,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, kt_ref,
     step = pl.program_id(4)
     n_steps = pl.num_programs(4)
     qi = step
+    pair_at = _pair_at(pair)
     inside = None
     if window is not None:
         qi = _first_q(ki, q_block, block_kv) + step
@@ -576,9 +682,12 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, kt_ref,
     first_kv = 0 if window is None else _first_kv(
         qi, q_block, block_kv, window
     )
-    last_kv = _last_kv(qi, q_block, block_kv) if causal else (
-        pl.num_programs(3) - 1
-    )
+    if pair_at is not None:
+        last_kv = _pair_last_kv(qi, q_block, block_kv, *pair_at)
+    else:
+        last_kv = _last_kv(qi, q_block, block_kv) if causal else (
+            pl.num_programs(3) - 1
+        )
 
     def in_sequence(cond):
         return cond if inside is None else jnp.logical_and(cond, inside)
@@ -598,7 +707,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, kt_ref,
         q, _, s = _scores(
             q_ref, k_ref, qi, ki, scale=scale, masked=masked,
             q_block=q_block, block_kv=block_kv, transposed=True,
-            window=window,
+            window=window, pair=pair_at,
         )
         g = g_ref[0, 0]
         p = jnp.exp(s - lse_ref[0, 0])          # [block_kv, q_block] f32
@@ -609,7 +718,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, kt_ref,
         dq_acc[qi] += _dot(kt_ref[0, 0], ds) * scale
 
     _on_live_tile(qi, ki, _accumulate, causal=causal, q_block=q_block,
-                  block_kv=block_kv, window=window, inside=inside)
+                  block_kv=block_kv, window=window, inside=inside,
+                  pair=pair_at)
 
     @pl.when(in_sequence(ki == last_kv))
     def _finish_dq():
@@ -625,7 +735,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, kt_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_kv", "interpret", "scale",
-                     "window"),
+                     "window", "pair"),
 )
 def flash_attention(
     q: jnp.ndarray,
@@ -637,7 +747,8 @@ def flash_attention(
     interpret: bool = False,
     scale: Optional[float] = None,
     window: Optional[int] = None,
-) -> jnp.ndarray:
+    pair: Optional[int] = None,
+):
     """``q`` [B, S, H, D], ``k`` [B, S, Hkv, D] and ``v`` [B, S, Hkv, Dv]
     with H a multiple of Hkv → [B, S, H, Dv]. ``Dv`` need not be ``D``
     (latent attention: 192-wide q and k, 128-wide v): the q, k, dq and dk
@@ -648,6 +759,16 @@ def flash_attention(
     ``window`` (causal only) keeps the last ``window`` keys of each query,
     its own among them; one of S or more is plain causal attention.
 
+    ``pair`` (a block length L; causal, no window) is the PAIR mask's part
+    over the clean keys: the batch rows alternate the noised copy (even)
+    and the clean copy (odd) of a sequence, every row reads the CLEAN
+    row's keys and values, a noised query sees the keys of the blocks
+    before its own and a clean query those of its own block too. The
+    noised rows' keys are never read, so their own-block term and the
+    merge are the caller's (:func:`flash_pair_attention`), and the call
+    returns the row logsumexp beside the output for it: ``(out [B, S, H,
+    Dv], lse [B, H, S] float32)``, both differentiable.
+
     Differentiable via custom_vjp; forward AND backward are blockwise
     pallas kernels (no S×S materialization anywhere)."""
     s, d = q.shape[1], q.shape[3]
@@ -657,11 +778,123 @@ def flash_attention(
             f"{q.shape[2]} query heads over key-value shapes {k.shape}, "
             f"{v.shape}"
         )
+    if pair is not None:
+        if not causal or window is not None or q.shape[0] % 2 or (
+                pair < 1 or s % pair):
+            raise ValueError(
+                f"the pair mask in blocks of {pair} over {q.shape[0]} rows of "
+                f"{s} positions (causal={causal}, window={window}): a pair "
+                "call is causal, without a window, over an even number of "
+                "rows of a whole number of blocks"
+            )
+        return _flash_pair_vjp(
+            q, k, v, _block(block_q, s), _block(block_kv, s), interpret,
+            _scale(scale, d), pair,
+        )
     window = _window(window, causal, s)
     return _flash_vjp(
         q, k, v, causal, _block(block_q, s, window),
         _block(block_kv, s, window), interpret, _scale(scale, d), window,
     )
+
+
+def flash_pair_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    block_length: int,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+    block_q: Optional[int] = None,
+    block_kv: Optional[int] = None,
+) -> jnp.ndarray:
+    """Attention under the PAIR mask of block diffusion (the module
+    docstring; ``ops/attention.pair_mask`` is its definition): ``q`` [B,
+    2S, H, D], ``k`` [B, 2S, Hkv, D], ``v`` [B, 2S, Hkv, Dv] hold a noised
+    copy of each sequence in positions ``[0, S)`` and the clean copy in
+    ``[S, 2S)`` → [B, 2S, H, Dv].
+
+    Everything over the CLEAN keys is one :func:`flash_attention` call
+    with ``pair=block_length`` on the 2B rows of S the pair folds into (a
+    reshape). The noised queries' OWN-BLOCK term, ``L`` noised keys a
+    query, is computed beside it on ``[B, S/L, L, ...]`` blocks (scope
+    ``pair``: ``S·L`` scores a head, 1M of the step's 67M at S = 8,192 and
+    L = 4, where eight more 1024² tiles a head would run 8.4M through the
+    kernel for them) and merged through the rows' logsumexp."""
+    b, s2, h, d = q.shape
+    s, h_kv, d_v = s2 // 2, k.shape[2], v.shape[3]
+    if s2 % 2 or s % block_length:
+        raise ValueError(
+            f"a pair of {s2} positions in blocks of {block_length}: the two "
+            "copies are S positions each, a whole number of blocks"
+        )
+    scale = _scale(scale, d)
+
+    def fold(t):
+        return t.reshape((2 * b, s) + t.shape[2:])
+
+    out, lse = flash_attention(
+        fold(q), fold(k), fold(v), causal=True, block_q=block_q,
+        block_kv=block_kv, interpret=interpret, scale=scale,
+        pair=block_length,
+    )
+    with jax.named_scope("pair"):
+        out = out.reshape(b, 2, s, h, d_v)
+        lse = lse.reshape(b, 2, h, s)
+        blocks = (b, s // block_length, block_length)
+        merged = merge_own_block(
+            q[:, :s].reshape(blocks + (h_kv, h // h_kv, d)),
+            k[:, :s].reshape(blocks + (h_kv, d)),
+            v[:, :s].reshape(blocks + (h_kv, d_v)),
+            out[:, 0].reshape(blocks + (h_kv, h // h_kv, d_v)),
+            jnp.einsum("bhs->bsh", lse[:, 0]).reshape(
+                blocks + (h_kv, h // h_kv)
+            ),
+            scale,
+        ).reshape(b, s, h, d_v)
+        return jnp.concatenate([merged, out[:, 1]], axis=1)
+
+
+def merge_own_block(q, k, v, out, lse, scale: float):
+    """A query's softmax over the keys of ITS OWN block of L, merged into
+    what it already has over other keys: ``q`` [B, N, L, Hkv, G, D], ``k``
+    [B, N, L, Hkv, D], ``v`` [B, N, L, Hkv, Dv]; ``out`` [B, N, L, Hkv, G,
+    Dv] and ``lse`` [B, N, L, Hkv, G] the attention over those other keys
+    and its row logsumexp (``-1e30``, or less, where there were none) →
+    the attention over both. float32 inside."""
+    own = jnp.einsum(
+        "bnlkgd,bnmkd->bnlkgm", q, k, preferred_element_type=jnp.float32
+    ) * scale
+    total = jnp.logaddexp(lse, jax.nn.logsumexp(own, axis=-1))
+    probs = jnp.exp(own - total[..., None])
+    mixed = jnp.einsum(
+        "bnlkgm,bnmkd->bnlkgd", probs.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    )
+    kept = jnp.exp(lse - total)[..., None] * out.astype(jnp.float32)
+    return (kept + mixed).astype(out.dtype)
+
+
+def pair_tile_counts(s: int, block_length: int,
+                     block_q: Optional[int] = None,
+                     block_kv: Optional[int] = None) -> Tuple[int, int, int]:
+    """(live, crossed, own-block) tiles of one head and one PAIR of
+    sequences of ``s`` positions each: the tiles the pair call computes
+    over both copies' queries, those of them whose body applies the mask,
+    and the tiles that hold the noised copy's own-block entries (none:
+    that term runs beside the kernel, :func:`flash_pair_attention`). The
+    kernels' own predicates on plain ints."""
+    block_q, block_kv = _block(block_q, s), _block(block_kv, s)
+    tiles = [
+        (qi, ki, copy) for copy in (0, 1)
+        for qi in range(s // block_q) for ki in range(s // block_kv)
+        if _pair_live(qi, ki, block_q, block_kv, block_length, copy)
+    ]
+    crossed = sum(
+        1 for qi, ki, copy in tiles
+        if not _pair_whole(qi, ki, block_q, block_kv, block_length, copy)
+    )
+    return len(tiles), crossed, 0
 
 
 def _window(window: Optional[int], causal: bool, s: int) -> Optional[int]:
@@ -751,6 +984,24 @@ def _flash_fwd_rule(q, k, v, causal, block_q, block_kv, interpret, scale,
     return jnp.einsum("bhsd->bshd", out_t), (qt, kt, vt, out_t, lse)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_pair_vjp(q, k, v, block_q, block_kv, interpret, scale, pair):
+    out_t, lse, _, _, _ = _flash_forward(
+        q, k, v, True, block_q, block_kv, interpret, scale, None, pair
+    )
+    return jnp.einsum("bhsd->bshd", out_t), lse[..., 0]
+
+
+def _flash_pair_fwd_rule(q, k, v, block_q, block_kv, interpret, scale, pair):
+    out_t, lse, qt, kt, vt = _flash_forward(
+        q, k, v, True, block_q, block_kv, interpret, scale, None, pair
+    )
+    # The residuals and their names are :func:`_flash_fwd_rule`'s.
+    out_t = checkpoint_name(out_t, KEPT[0])
+    lse = checkpoint_name(lse[..., 0], KEPT[1])
+    return (jnp.einsum("bhsd->bshd", out_t), lse), (qt, kt, vt, out_t, lse)
+
+
 def _kv_head(group: int):
     """Key-value head of query head ``hi``; the identity without groups."""
     return (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
@@ -766,6 +1017,23 @@ def _kv_tile_of(window: Optional[int], block_q: int, block_kv: int):
     return lambda qi, ki: jnp.minimum(
         _first_kv(qi, block_q, block_kv, window) + ki,
         _last_kv(qi, block_q, block_kv),
+    )
+
+
+def _kv_block_of(window: Optional[int], block_q: int, block_kv: int, kv_of,
+                 pair: Optional[int] = None):
+    """The index map of a key or value tile of the grids whose innermost
+    dimension runs over kv steps, (batch row, query head, q tile, step):
+    the row itself, the head's key-value head and :func:`_kv_tile_of`'s
+    tile; under the pair mask the CLEAN row of the pair (the odd one) and
+    the step held at the last tile the q tile's copy sees."""
+    kv_tile = _kv_tile_of(window, block_q, block_kv)
+    if pair is None:
+        return lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0)
+    return lambda bi, hi, qi, ki: (
+        bi | 1, kv_of(hi),
+        jnp.minimum(ki, _pair_last_kv(qi, block_q, block_kv, pair, bi % 2)),
+        0,
     )
 
 
@@ -807,27 +1075,50 @@ def backward_is_fused(s: int, d: int, d_v: int, itemsize: int) -> bool:
     )
 
 
-def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, window,
-                    res, g):
-    qt, _, vt, _, _ = res
+def _bwd_rule_for(qt, vt):
     s, d, d_v = qt.shape[2], qt.shape[3], vt.shape[3]
-    rule = _flash_bwd_fused if backward_is_fused(
+    return _flash_bwd_fused if backward_is_fused(
         s, d, d_v, qt.dtype.itemsize
     ) else _flash_bwd_pair
+
+
+def _flash_bwd_rule(causal, block_q, block_kv, interpret, scale, window,
+                    res, g):
+    rule = _bwd_rule_for(res[0], res[2])
     return rule(causal, block_q, block_kv, interpret, scale, window, res, g)
 
 
-def _cotangent_and_delta(g, out_t):
+def _flash_pair_bwd_rule(block_q, block_kv, interpret, scale, pair, res, g):
+    """The same kernels under the pair mask. The cotangent of ``lse``
+    rides in ``delta``: ``d lse_i / d s_ij = p_ij``, so ``ds = p ⊙ (dp −
+    (delta − g_lse))``."""
+    g_out, g_lse = g
+    rule = _bwd_rule_for(res[0], res[2])
+    return rule(True, block_q, block_kv, interpret, scale, None, res, g_out,
+                pair=pair, g_lse=g_lse)
+
+
+def _cotangent_and_delta(g, out_t, g_lse=None):
     """The cotangent in the kernels' [B, H, S, D_v] layout and
-    delta_i = Σ_d dO_i · O_i, the softmax-jacobian row term, [B, H, S]."""
+    delta_i = Σ_d dO_i · O_i, the softmax-jacobian row term, [B, H, S]
+    (less the cotangent of ``lse`` where the call returned it)."""
     gt = jnp.einsum("bshd->bhsd", g)
-    return gt, jnp.einsum(
+    delta = jnp.einsum(
         "bhsd,bhsd->bhs", gt.astype(jnp.float32), out_t.astype(jnp.float32)
     )
+    return gt, delta if g_lse is None else delta - g_lse
+
+
+def _clean_rows(t):
+    """The gradient of a pair call's ``k`` or ``v`` from the kernel's
+    [2B, Hkv, S, D] (one a query copy): both copies read the CLEAN row, so
+    it takes their sum and the noised row nothing."""
+    both = t.reshape((t.shape[0] // 2, 2) + t.shape[1:]).sum(axis=1)
+    return jnp.stack([jnp.zeros_like(both), both], axis=1).reshape(t.shape)
 
 
 def _flash_bwd_fused(causal, block_q, block_kv, interpret, scale, window,
-                     res, g):
+                     res, g, pair=None, g_lse=None):
     """The backward as ONE kernel (:func:`_bwd_fused_kernel`): rows of
     ``lse`` and ``delta`` and the keys laid out a second time as
     [B, Hkv, D, S] go in, dq comes out a q tile at a time as [d, block_q]
@@ -841,33 +1132,39 @@ def _flash_bwd_fused(causal, block_q, block_kv, interpret, scale, window,
     q_steps = n_q
     if window is not None:
         _, q_steps = band_tiles(s, block_q, block_kv, window)
-    gt, delta = _cotangent_and_delta(g, out_t)
+    gt, delta = _cotangent_and_delta(g, out_t, g_lse)
+    kv_row = (lambda bi: bi) if pair is None else (lambda bi: bi | 1)
 
     # The q tile of a step, held at the nearest live one where the step is
-    # dead (before the kv tile's first under a causal mask, past the
-    # band's last under a window): a dead step names the block its
-    # neighbour fetched, and fetches nothing.
-    if window is not None:
-        def q_tile(ki, step):
+    # dead (before the kv tile's first under a causal mask or the pair's,
+    # past the band's last under a window): a dead step names the block
+    # its neighbour fetched, and fetches nothing.
+    if pair is not None:
+        def q_tile(bi, ki, step):
+            return jnp.maximum(step, _pair_first_q(
+                ki, block_q, block_kv, pair, bi % 2, n_q
+            ))
+    elif window is not None:
+        def q_tile(bi, ki, step):
             return jnp.minimum(
                 _first_q(ki, block_q, block_kv) + step,
                 _last_q(ki, block_q, block_kv, window, n_q),
             )
     elif causal:
-        def q_tile(ki, step):
+        def q_tile(bi, ki, step):
             return jnp.maximum(step, _first_q(ki, block_q, block_kv))
     else:
-        def q_tile(ki, step):
+        def q_tile(bi, ki, step):
             return step
 
     def q_at(bi, hk, gi, ki, step):
-        return bi, hk * group + gi, q_tile(ki, step), 0
+        return bi, hk * group + gi, q_tile(bi, ki, step), 0
 
     def row_at(bi, hk, gi, ki, step):
-        return bi, hk * group + gi, 0, q_tile(ki, step)
+        return bi, hk * group + gi, 0, q_tile(bi, ki, step)
 
     def kv_at(bi, hk, gi, ki, step):
-        return bi, hk, ki, 0
+        return kv_row(bi), hk, ki, 0
 
     def kv_head_at(bi, hk, gi, ki, step):
         return bi, hk, 0, 0
@@ -876,7 +1173,7 @@ def _flash_bwd_fused(causal, block_q, block_kv, interpret, scale, window,
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, block_kv=block_kv, causal=causal, scale=scale,
-            q_block=block_q, window=window, seq_q_tiles=n_q,
+            q_block=block_q, window=window, seq_q_tiles=n_q, pair=pair,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, h, n_q, d, block_q), qt.dtype),
@@ -892,7 +1189,7 @@ def _flash_bwd_fused(causal, block_q, block_kv, interpret, scale, window,
             row_spec, row_spec,
             pl.BlockSpec(
                 (1, 1, d, block_kv),
-                lambda bi, hk, gi, ki, step: (bi, hk, 0, ki),
+                lambda bi, hk, gi, ki, step: (kv_row(bi), hk, 0, ki),
             ),
         ],
         out_specs=(
@@ -918,11 +1215,13 @@ def _flash_bwd_fused(causal, block_q, block_kv, interpret, scale, window,
       jnp.swapaxes(kt, 2, 3))
 
     dq = jnp.einsum("bhndq->bnqhd", dq).reshape(b, s, h, d)
+    if pair is not None:
+        dk, dv = _clean_rows(dk), _clean_rows(dv)
     return dq, jnp.einsum("bhsd->bshd", dk), jnp.einsum("bhsd->bshd", dv)
 
 
 def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
-                    res, g):
+                    res, g, pair=None, g_lse=None):
     """The backward as two kernels, dq and dk/dv, each building the score
     tile for itself: what a call too long for :func:`_flash_bwd_fused`'s
     resident blocks runs."""
@@ -931,14 +1230,14 @@ def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
     h_kv, d_v = kt.shape[1], vt.shape[3]
     group = h // h_kv
     kv_of = _kv_head(group)
-    kv_tile = _kv_tile_of(window, block_q, block_kv)
+    kv_at = _kv_block_of(window, block_q, block_kv, kv_of, pair)
     # The innermost grid dimensions: every tile of the other kind, or
     # under a window the band's.
     kv_steps, q_tiles = s // block_kv, s // block_q
     if window is not None:
         kv_steps, q_tiles = band_tiles(s, block_q, block_kv, window)
 
-    gt, delta = _cotangent_and_delta(g, out_t)
+    gt, delta = _cotangent_and_delta(g, out_t, g_lse)
     delta = delta[..., None]
 
     q_spec = pl.BlockSpec(
@@ -947,21 +1246,15 @@ def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
     g_spec = pl.BlockSpec(
         (1, 1, block_q, d_v), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
     )
-    k_spec = pl.BlockSpec(
-        (1, 1, block_kv, d),
-        lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0),
-    )
-    v_spec = pl.BlockSpec(
-        (1, 1, block_kv, d_v),
-        lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0),
-    )
+    k_spec = pl.BlockSpec((1, 1, block_kv, d), kv_at)
+    v_spec = pl.BlockSpec((1, 1, block_kv, d_v), kv_at)
     row_spec = pl.BlockSpec(
         (1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
     )
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, block_kv=block_kv, causal=causal, scale=scale,
-            q_block=block_q, window=window,
+            q_block=block_q, window=window, pair=pair,
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), qt.dtype),
         grid=(b, h, s // block_q, kv_steps),
@@ -984,6 +1277,14 @@ def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
                 _last_q(ki, block_q, block_kv, window, s // block_q),
             )
             return bi, hi * group + step // q_tiles, qi, 0
+    elif pair is not None:
+        # A step before the kv tile's first live q tile names that tile.
+        def q_at(bi, hi, ki, step):
+            first = _pair_first_q(
+                ki, block_q, block_kv, pair, bi % 2, s // block_q
+            )
+            return (bi, hi * group + step // q_tiles,
+                    jnp.maximum(step % q_tiles, first), 0)
     elif group == 1:
         q_at = lambda bi, hi, ki, qi: (bi, hi, qi, 0)  # noqa: E731
     else:
@@ -992,9 +1293,12 @@ def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
         )
     q_spec_t = pl.BlockSpec((1, 1, block_q, d), q_at)
     g_spec_t = pl.BlockSpec((1, 1, block_q, d_v), q_at)
-    kv_at = lambda bi, hi, ki, qi: (bi, hi, ki, 0)  # noqa: E731
-    k_spec_t = pl.BlockSpec((1, 1, block_kv, d), kv_at)
-    v_spec_t = pl.BlockSpec((1, 1, block_kv, d_v), kv_at)
+    kv_out = lambda bi, hi, ki, qi: (bi, hi, ki, 0)  # noqa: E731
+    kv_in = kv_out if pair is None else (
+        lambda bi, hi, ki, qi: (bi | 1, hi, ki, 0)
+    )
+    k_spec_t = pl.BlockSpec((1, 1, block_kv, d), kv_in)
+    v_spec_t = pl.BlockSpec((1, 1, block_kv, d_v), kv_in)
     # lse and delta as rows [B, H, 1, S] for the dk/dv kernel's transposed
     # tiles (1 MB a call to lay out again; the tiles are 4 MB each); the
     # residual ``lse`` is [B, H, S], a column above and a row here.
@@ -1007,8 +1311,10 @@ def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
         functools.partial(
             _bwd_dkv_kernel, block_kv=block_kv, causal=causal, scale=scale,
             q_block=block_q,
-            q_tiles=None if group == 1 and window is None else q_tiles,
-            window=window, seq_q_tiles=s // block_q,
+            q_tiles=None if (
+                group == 1 and window is None and pair is None
+            ) else q_tiles,
+            window=window, seq_q_tiles=s // block_q, pair=pair,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, h_kv, s, d), kt.dtype),
@@ -1019,7 +1325,10 @@ def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
             q_spec_t, k_spec_t, v_spec_t, g_spec_t, row_spec_t,
             row_spec_t,
         ],
-        out_specs=(k_spec_t, v_spec_t),
+        out_specs=(
+            pl.BlockSpec((1, 1, block_kv, d), kv_out),
+            pl.BlockSpec((1, 1, block_kv, d_v), kv_out),
+        ),
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d_v), jnp.float32),
@@ -1027,11 +1336,14 @@ def _flash_bwd_pair(causal, block_q, block_kv, interpret, scale, window,
         interpret=interpret,
     )(qt, kt, vt, gt, lse[:, :, None, :], jnp.swapaxes(delta, 2, 3))
 
+    if pair is not None:
+        dk, dv = _clean_rows(dk), _clean_rows(dv)
     to_bshd = lambda x: jnp.einsum("bhsd->bshd", x)  # noqa: E731
     return to_bshd(dq), to_bshd(dk), to_bshd(dv)
 
 
 _flash_vjp.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash_pair_vjp.defvjp(_flash_pair_fwd_rule, _flash_pair_bwd_rule)
 
 
 def _flash_forward(
@@ -1044,11 +1356,13 @@ def _flash_forward(
     interpret: bool,
     scale: float,
     window: Optional[int] = None,
+    pair: Optional[int] = None,
 ):
     b, s, h, d = q.shape
     d_v = v.shape[3]
-    kv_of = _kv_head(h // k.shape[2])
-    kv_tile = _kv_tile_of(window, block_q, block_kv)
+    kv_at = _kv_block_of(
+        window, block_q, block_kv, _kv_head(h // k.shape[2]), pair
+    )
     if s % block_q or s % block_kv:
         raise ValueError(f"seq len {s} not divisible by blocks "
                          f"({block_q}, {block_kv})")
@@ -1069,6 +1383,7 @@ def _flash_forward(
         scale=scale,
         q_block=block_q,
         window=window,
+        pair=pair,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -1081,14 +1396,8 @@ def _flash_forward(
             pl.BlockSpec(
                 (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
             ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d),
-                lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, d_v),
-                lambda bi, hi, qi, ki: (bi, kv_of(hi), kv_tile(qi, ki), 0),
-            ),
+            pl.BlockSpec((1, 1, block_kv, d), kv_at),
+            pl.BlockSpec((1, 1, block_kv, d_v), kv_at),
         ],
         out_specs=(
             pl.BlockSpec(

@@ -29,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from raydp_tpu import fault as _fault
 from raydp_tpu.data.ml_dataset import MLDataset
 from raydp_tpu.models import (
+    blockdiff,
     dropout,
     hyperconn,
     kda,
@@ -371,15 +372,20 @@ class JAXEstimator:
         takes_deterministic = self._model_takes_deterministic()
         use_aux = self.aux_losses
 
+        step_rngs = self._step_rngs()
+
         def apply_kwargs(rng):
             # ``rng`` is the step's key of the threefry chain; the masks
             # come from the chip's bit generator (``models/dropout.py``).
-            return (
-                dict(deterministic=False,
-                     rngs={"dropout": dropout.key_for(rng)})
-                if takes_deterministic
-                else {}
-            )
+            # A model that draws more than dropout's masks in its step
+            # names the collections (``step_rngs``: block diffusion's
+            # ``noise``) and gets a key each, a function of ``rng`` too.
+            if not takes_deterministic:
+                return {}
+            rngs = {"dropout": dropout.key_for(rng)}
+            for i, name in enumerate(step_rngs):
+                rngs[name] = dropout.key_for(jax.random.fold_in(rng, i + 1))
+            return dict(deterministic=False, rngs=rngs)
 
         def loss_of(state: TrainState, variables, x, y, rng):
             target = y if y is not None else x  # self-supervised: x IS y
@@ -482,12 +488,18 @@ class JAXEstimator:
         train_step = self._make_train_step()
         sites, words = (
             dropout.census(
-                self._model.apply, self._state.params, self._sample_batch
+                self._model.apply, self._state.params, self._sample_batch,
+                also=self._step_rngs(),
             )
             if self._model_takes_deterministic() else (0, 0)
         )
         dropout.report(sites, words)
-        tokens_per_step = int(np.prod(self._sample_batch.shape))
+        # The rows a step sends through every layer: the batch's tokens,
+        # or more of them where the model lays copies side by side (block
+        # diffusion's pair).
+        tokens_per_step = int(np.prod(self._sample_batch.shape)) * getattr(
+            self._model, "positions_per_token", 1
+        )
         mamba.report(
             getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
         )
@@ -498,6 +510,10 @@ class JAXEstimator:
         shortconv.report(getattr(self._model, "cfg", None))
         latent.report(getattr(self._model, "cfg", None))
         window_mixer.report(getattr(self._model, "cfg", None))
+        blockdiff.report(
+            self._model, batch=self._sample_batch.shape[0],
+            seq_len=int(self._sample_batch.shape[-1]),
+        )
         hyperconn.report(getattr(self._model, "cfg", None))
         report_flash_tiles(
             getattr(self._model, "cfg", None),
@@ -553,6 +569,11 @@ class JAXEstimator:
         if self.label_column:
             return loader
         return ((x, None) for x in loader)
+
+    def _step_rngs(self) -> tuple:
+        """The rng collections, beside ``dropout``, that the model draws
+        from in a training step (its ``step_rngs``; none for most)."""
+        return tuple(getattr(self._model, "step_rngs", ()))
 
     def _model_takes_deterministic(self) -> bool:
         import inspect
@@ -976,6 +997,7 @@ class JAXEstimator:
                     stats_sum = jax.device_get(stats_sum)
                     moe.report_epoch(stats_sum, n_batches)
                     hyperconn.report_epoch(stats_sum)
+                    blockdiff.report_epoch(stats_sum)
             # Epoch boundary always checks (the sampled cadence may
             # never have landed on a NaN step in a short epoch).
             sentinel.check_loss(train_loss, b_idx, epoch=epoch)

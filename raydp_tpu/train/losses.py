@@ -3,6 +3,7 @@ string-configured losses — reference: tf/estimator.py:87-132 serializes
 keras losses by name; torch estimator takes loss instances)."""
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Union
 
 import jax
@@ -64,15 +65,33 @@ def lm_crossentropy(logits, tokens):
     saves a second copy of the logits and scatter-adds the label's
     gradient into a zero array. float32 throughout."""
     targets = jnp.roll(tokens.astype(jnp.int32), -1, axis=1)
-    return _shifted_ce(logits, targets)
-
-
-def _weight_and_count(logits):
-    """``[1, S]`` weight that leaves the last position out, and the
-    number of terms of the mean."""
     b, s = logits.shape[:2]
-    weight = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
-    return weight, b * (s - 1)
+    # ``[1, S]``: every position but the last.
+    weights = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
+    return _weighted_ce(b * (s - 1), logits, targets, weights)
+
+
+def weighted_crossentropy(logits, targets, weights):
+    """``Σ_i w_i · CE(logits_i, targets_i) / (B·S)`` over ``logits`` [B, S,
+    V], ``targets`` and ``weights`` [B, S]: position i predicts
+    ``targets_i`` (NO shift) and the mean runs over every position, the
+    unweighted ones too. :func:`lm_crossentropy`'s written-out backward
+    pass (it is this loss with a ``[1, S]`` weight); no gradient reaches
+    the weights. float32 throughout."""
+    return _weighted_ce(
+        logits.shape[0] * logits.shape[1], logits, targets.astype(jnp.int32),
+        jax.lax.stop_gradient(weights.astype(jnp.float32)),
+    )
+
+
+def blockdiff_crossentropy(preds, tokens):
+    """Block diffusion's loss (``models/blockdiff.py``): ``preds`` is what
+    a ``BlockDiffusionLM`` returns in training, the logits of the noised
+    copy and a weight a token (``1/t`` of its block where the token was
+    masked, 0 elsewhere); ``tokens`` are the clean ids, each position's own
+    target."""
+    logits, weights = preds
+    return weighted_crossentropy(logits, tokens, weights)
 
 
 def _is_target(logits, targets):
@@ -80,26 +99,25 @@ def _is_target(logits, targets):
     return vocab == targets[..., None]
 
 
-def _shifted_ce_fwd(logits, targets):
+def _weighted_ce_fwd(count, logits, targets, weights):
     x = logits.astype(jnp.float32)
     top = jnp.max(x, axis=-1)
     lse = top + jnp.log(jnp.sum(jnp.exp(x - top[..., None]), axis=-1))
     label = jnp.sum(jnp.where(_is_target(x, targets), x, 0.0), axis=-1)
-    weight, count = _weight_and_count(x)
-    loss = jnp.sum((lse - label) * weight) / count
-    return loss, (logits, lse, targets)
+    loss = jnp.sum((lse - label) * weights) / count
+    return loss, (logits, lse, targets, weights)
 
 
-@jax.custom_vjp
-def _shifted_ce(logits, targets):
-    return _shifted_ce_fwd(logits, targets)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _weighted_ce(count, logits, targets, weights):
+    """``Σ w · CE / count``: ``weights`` broadcasts against ``[B, S]``."""
+    return _weighted_ce_fwd(count, logits, targets, weights)[0]
 
 
-def _shifted_ce_bwd(residuals, g):
-    logits, lse, targets = residuals
+def _weighted_ce_bwd(count, residuals, g):
+    logits, lse, targets, weights = residuals
     x = logits.astype(jnp.float32)
-    weight, count = _weight_and_count(x)
-    scale = (weight * (g / count))[..., None]
+    scale = (weights * (g / count))[..., None]
     grad = (
         jnp.exp(x - lse[..., None])
         - _is_target(x, targets).astype(jnp.float32)
@@ -109,10 +127,10 @@ def _shifted_ce_bwd(residuals, g):
     # each computes it again for every tile of its output: 7.5 ms of the
     # step above, 4.7 at ``[2, 4096, 50304]`` (PERF.md §6, PR 31).
     grad = jax.lax.optimization_barrier(grad.astype(logits.dtype))
-    return grad, None
+    return grad, None, jnp.zeros_like(weights)
 
 
-_shifted_ce.defvjp(_shifted_ce_fwd, _shifted_ce_bwd)
+_weighted_ce.defvjp(_weighted_ce_fwd, _weighted_ce_bwd)
 
 
 LOSSES: Dict[str, Callable] = {
@@ -125,6 +143,7 @@ LOSSES: Dict[str, Callable] = {
     "softmax_ce": softmax_crossentropy,
     "sparse_categorical_crossentropy": softmax_crossentropy,
     "lm_ce": lm_crossentropy,
+    "blockdiff_ce": blockdiff_crossentropy,
 }
 
 

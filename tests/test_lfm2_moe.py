@@ -237,12 +237,15 @@ def _held_by(variables, first, held=2):
            for w in ("w_gate", "w_up", "w_down")}))
 
 
-def _plain_routed(variables, x, experts, scale=1.0, top_k=2, shared=False):
+def _plain_routed(variables, x, experts, scale=1.0, top_k=2, shared=False,
+                  scoring="sigmoid"):
     """``sum_j g_j E_j(x)`` over ``experts`` by a loop over tokens (plus
-    the shared expert's output with ``shared``)."""
+    the shared expert's output with ``shared``). ``scoring`` softmax has
+    no selection bias."""
     p = jax.tree_util.tree_map(
         lambda a: np.asarray(a, np.float64), variables["params"])
-    bias = np.asarray(variables[moe_module.BUFFERS]["expert_bias"], np.float64)
+    bias = 0.0 if scoring == "softmax" else np.asarray(
+        variables[moe_module.BUFFERS]["expert_bias"], np.float64)
     tokens = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
     out, chosen, unbiased = np.zeros_like(tokens), [], []
 
@@ -250,7 +253,12 @@ def _plain_routed(variables, x, experts, scale=1.0, top_k=2, shared=False):
         return h / (1.0 + np.exp(-h)) * up
 
     for t, y in enumerate(tokens):
-        s = 1.0 / (1.0 + np.exp(-(y @ p["router"]["kernel"])))
+        s = y @ p["router"]["kernel"]
+        if scoring == "softmax":
+            s = np.exp(s - s.max())
+            s = s / s.sum()
+        else:
+            s = 1.0 / (1.0 + np.exp(-s))
         picked = np.argsort(-(s + bias), kind="stable")[:top_k]
         chosen.append(set(picked))
         unbiased.append(set(np.argsort(-s, kind="stable")[:top_k]))
@@ -279,26 +287,35 @@ def test_sigmoid_bias_normalised_routing_against_a_plain_loop(uncut):
         np.asarray(scaled), 2.5 * want, rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("experts,held,top_k,shared,scale", [
-    (8, 2, 2, 0, 1.0),          # LFM2's four shares (PR 32)
-    (256, 8, 8, 1, 2.446),      # Kimi Linear's thirty-two (PR 44)
-], ids=["four_of_8", "thirty_two_of_256_and_a_shared_expert"])
+@pytest.mark.parametrize("experts,held,top_k,shared,scale,scoring", [
+    (8, 2, 2, 0, 1.0, "sigmoid"),          # LFM2's four shares (PR 32)
+    (256, 8, 8, 1, 2.446, "sigmoid"),      # Kimi Linear's thirty-two (PR 44)
+    (128, 16, 8, 0, 1.0, "softmax"),       # SDAR's eight (PR 47)
+], ids=["four_of_8", "thirty_two_of_256_and_a_shared_expert",
+        "eight_of_128_by_renormalised_softmax"])
 def test_the_shares_add_up_to_the_uncut_layer(experts, held, top_k, shared,
-                                              scale):
+                                              scale, scoring):
     """The share test: each chip holds ``held`` of the experts, routes
     over all of them, and returns its own experts' part (plus the shared
     expert's output, which every chip computes alike); the routed parts
     and the shared expert counted ONCE sum to what the uncut layer, and
     the plain loop, give."""
     kw = dict(n_experts=experts, top_k=top_k, gate_scale=scale,
-              shared_experts=shared)
+              shared_experts=shared, scoring=scoring,
+              selection_bias=scoring == "sigmoid")
     layer = _layer(**kw)
     x = jax.random.normal(jax.random.PRNGKey(5), (3, 7, 16))
     variables = _init(layer, x)
-    variables[moe_module.BUFFERS]["expert_bias"] = 0.5 * jax.random.normal(
-        jax.random.PRNGKey(6), (experts,))
+    if scoring == "sigmoid":
+        variables[moe_module.BUFFERS]["expert_bias"] = (
+            0.5 * jax.random.normal(jax.random.PRNGKey(6), (experts,)))
+    else:
+        # The published block, a renormalised softmax of 128 logits
+        # (``norm_topk_prob``): no bias, no buffer beside the parameters.
+        assert moe_module.BUFFERS not in variables
     plain = functools.partial(
-        _plain_routed, variables, x, scale=scale, top_k=top_k)
+        _plain_routed, variables, x, scale=scale, top_k=top_k,
+        scoring=scoring)
     whole = layer.apply(variables, x, mutable=[moe_module.STATS])[0]
     alike = plain(set(), shared=True)[0] if shared else 0.0
     total, pairs = jnp.zeros_like(whole), 0.0
