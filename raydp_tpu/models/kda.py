@@ -65,9 +65,10 @@ class KDAConfig:
         return math.gcd(self.chunk, sequence)
 
     def scan_runs_kernels(self, sequence: int) -> bool:
-        """Whether the scan's chunk-local step takes its Pallas kernels
-        at this sequence length: ``ops/kda.uses_kernels``, what
-        ``kda_chunked`` itself asks, of the shapes the mixer hands it."""
+        """Whether the scan takes its Pallas kernels at this sequence
+        length, for the chunk-local step and for the recurrence over
+        chunk states alike: ``ops/kda.uses_kernels``, what ``kda_chunked``
+        itself asks, of the shapes the mixer hands it."""
         return uses_kernels(
             self.key_dim, self.value_dim, self.scan_chunk(sequence)
         )
@@ -212,7 +213,7 @@ def layers_of(cfg) -> int:
 
 
 def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
-    """Static for a compiled step: seven gauges and one log line where the
+    """Static for a compiled step: eight gauges and one log line where the
     step is built (as ``models/mamba.report``). All zero for a stack
     without such layers. ``sequence`` is a sequence's tokens (all of a
     step's where left out)."""
@@ -225,6 +226,7 @@ def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
     kept = kda.kept_inverse_bytes(
         layers, sequence or tokens_per_step) >> 20 if kda else 0
     metrics.gauge_set("kda/scan_kernel_layers", layers if kernels else 0)
+    metrics.gauge_set("kda/state_kernel_layers", layers if kernels else 0)
     metrics.gauge_set("kda/kept_inverse_mib", kept)
     metrics.gauge_set("kda/layers", layers)
     metrics.gauge_set("kda/heads", kda.heads if kda else 0)
@@ -238,8 +240,8 @@ def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
             "delta-rule stack: layers %s; %d heads of %d (q, k) and %d (v), "
             "a decay per channel, %d-tap convolutions as %s, gates of rank "
             "%d; chunk %d (%d chunks a step); scan: %s; the forward keeps "
-            "%d MiB of chunk inverses a sequence; a chunk's work at these "
-            "shapes: %s",
+            "%d MiB of chunk inverses a sequence; a chunk's work and the "
+            "walk over the chunks at these shapes: %s",
             " ".join(cfg.kinds), kda.heads, kda.key_dim, kda.value_dim,
             kda.conv_taps, CONV_IMPLEMENTATION, kda.gate_rank, kda.chunk,
             chunks, SCAN_IMPLEMENTATION, kept, SCAN_PATHS[kernels],

@@ -1,9 +1,9 @@
 """Kimi Delta Attention's recurrence (Kimi Linear, arXiv:2510.26692): a
 gated delta rule whose decay is a vector over the key dimension, in a
 chunked form with no clamp and no dropped term. What a chunk needs of
-itself alone runs in Pallas kernels where the shapes fill the chip's
-tiles and in plain ``jax.numpy`` elsewhere; the recurrence over chunk
-states is ``jax.numpy`` in both.
+itself alone, and the recurrence that carries the state from chunk to
+chunk, run in Pallas kernels where the shapes fill the chip's tiles and
+in plain ``jax.numpy`` elsewhere.
 
 Per head, with a state ``S`` of keys × values that starts at zero,
 
@@ -69,9 +69,29 @@ In a tile a level's factor is ONE ``[chunk, d_k]`` array, ``e^{G_i −
 G_r}`` on late rows and ``e^{G_r − G_j}`` on early ones, made from
 sublane rotations of the cumulative sums; the level's pairs select their
 entries from one ``[2·chunk, d_k] × [d_k, chunk]`` product of ``q`` over
-``k`` stacked. No option, field or name chooses: ``kda_chunked`` reads the
-shapes (a test may ask for either path by argument), and on a backend
-that is no TPU the kernel bodies run in the Pallas interpreter.
+``k`` stacked.
+
+**Two implementations of the recurrence over chunk states, chosen with
+it.** :func:`_across` is a ``lax.scan`` over a segment's chunks: two
+float32 products a step, every chunk's entering state stacked in HBM for
+one batched product to read back. Where :func:`uses_kernels` holds,
+:func:`across` runs it in two more kernels over the grid (sequences,
+groups of :data:`HEADS_A_STEP` heads, chunks), the chunks one after the
+other: ``kda_state_forward`` loads a group's states into a VMEM scratch at
+a segment's first chunk, reads the six chunk-local results as the chunk
+kernels wrote them ([b, n, h, c, d]: no transposed copy), computes ``w =
+U − W S``, ``o = (q e^G) S + P w`` and ``S ← e^{G_C} ⊙ S + (k e^{G_C −
+G})ᵀ w`` a chunk, and writes ``o`` and, at the last chunk, the state left;
+under differentiation (the backward's rebuild of ONE segment) it also
+writes that segment's chunk states and ``w``, float32, which
+``kda_state_backward`` reads as it walks the chunks from the last to the
+first with the state's cotangent in the scratch. One ``custom_vjp`` holds
+the pair, so nothing of the recurrence is differentiated by tracing and no
+loop over chunks is left to XLA.
+
+No option, field or name chooses: ``kda_chunked`` reads the shapes once
+for both halves (a test may ask for either path by argument), and on a
+backend that is no TPU the kernel bodies run in the Pallas interpreter.
 
 **Precision.** ``g``, its cumulative sums, ``β``, ``A``'s inverse and the
 products with it, the chunk states and their recurrence are float32 (the
@@ -117,15 +137,18 @@ from jax.experimental.pallas import tpu as pltpu
 IMPLEMENTATION = (
     "chunked WY form, pairwise decay by dyadic levels: a chunk's work in "
     "Pallas kernels (forward and backward, each chunk's triangular "
-    "inverse kept from the one for the other) where d_k and d_v are "
-    "multiples of 128 and the chunk of 64, in jax.numpy otherwise; chunk "
-    "states one after the other (ops/kda.py)"
+    "inverse kept from the one for the other) and the chunk states one "
+    "after the other in two more that hold the state in VMEM, where d_k "
+    "and d_v are multiples of 128 and the chunk of 64; both in jax.numpy "
+    "otherwise (ops/kda.py)"
 )
-# What makes a chunk's quantities, by whether :func:`uses_kernels` says so.
+# What makes a chunk's quantities and what carries the state over the
+# chunks, by whether :func:`uses_kernels` says so.
 PATHS = {
     True: "Pallas kernels kda_chunk_forward (kda_chunk_rebuild where the "
-          "backward reads the kept inverses) and kda_chunk_backward",
-    False: "jax.numpy",
+          "backward reads the kept inverses) and kda_chunk_backward; the "
+          "chunk states by kda_state_forward and kda_state_backward",
+    False: "jax.numpy, the chunk states by a lax.scan",
 }
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -711,6 +734,205 @@ def _across(local, state, dtype):
     return out, state
 
 
+# --------------------------------------------------------------------------
+# The recurrence over chunk states as Pallas kernels. A grid step holds ONE
+# chunk of :data:`HEADS_A_STEP` heads; the chunk axis is the grid's last and
+# runs in order, so a head's state stays in a VMEM scratch from a segment's
+# first chunk to its last, and no chunk's state is written to HBM but where
+# the backward reads it. The state lies TRANSPOSED there, [d_v, d_k]: a
+# chunk's decay is then a [1, d_k] row over its sublanes, the decay's
+# cotangent a sum over them, and no [1, d] row has to become a column.
+
+# Heads a grid step: blocks of 0.13 MB a head in the forward pass and 0.34
+# in the backward, both buffers of each, and 64 KiB a head of scratch.
+HEADS_A_STEP = 8
+
+
+def _state_forward_kernel(*refs, keeps: bool):
+    """U, W, P, q e^G, k e^{G_C − G}, e^{G_C} of a chunk and the state the
+    segment is entered with → o, where the call ``keeps`` them for the
+    backward the state the chunk is entered with (transposed) and its w,
+    and the state the segment leaves."""
+    U, W, P, q_decayed, to_end, end, entering = refs[:7]
+    (out, *kept), left, state = refs[7:-2], refs[-2], refs[-1]
+    dtype, heads = out.dtype, range(state.shape[0])
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        for at in heads:
+            state[at] = entering[at].T
+
+    # Every head's W·S first, then every o, then every update: a group's
+    # heads are independent, and side by side their six-pass products keep
+    # the MXUs fed where one head's chain W·S → w → state leaves them
+    # waiting (PERF.md §6, PR 48).
+    ws = [U[at] - _dot(W[at], state[at], (1, 1)) for at in heads]
+    for at in heads:
+        out[at] = (
+            _dot(q_decayed[at], state[at].astype(dtype), (1, 1))
+            + _dot(P[at], ws[at].astype(dtype), (1, 0))
+        ).astype(dtype)
+        if keeps:
+            kept[0][at], kept[1][at] = state[at], ws[at]
+    for at in heads:
+        state[at] = (end[at:at + 1, :] * state[at]
+                     + _dot(ws[at], to_end[at], (0, 0)))
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _():
+        for at in heads:
+            left[at] = state[at].T
+
+
+def _state_backward_kernel(*refs):
+    """A chunk's W, P, q e^G, k e^{G_C − G}, e^{G_C}, the state it was
+    entered with and its w as the forward kept them, o's cotangent and the
+    cotangent of the state the segment leaves → the six results'
+    cotangents and the entering state's; the grid walks the chunks from
+    the last to the first."""
+    W, P, q_decayed, to_end, end, entered, w, d_out, d_left = refs[:9]
+    dU, dW, dP, dq_decayed, dto_end, dend, d_entering, d_state = refs[9:]
+    dtype, heads = d_out.dtype, range(d_state.shape[0])
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        for at in heads:
+            d_state[at] = d_left[at].T
+
+    for at in heads:
+        dU[at] = (_dot(P[at], d_out[at], (0, 0))
+                  + _dot(to_end[at], d_state[at], (1, 1)))
+    for at in heads:
+        S, dS, d_o = entered[at], d_state[at], d_out[at]
+        dW[at] = -_dot(dU[at], S, (1, 0))
+        dq_decayed[at] = _dot(d_o, S.astype(dtype), (1, 0)).astype(dtype)
+        dP[at] = _dot(d_o, w[at].astype(dtype), (1, 1)).astype(dtype)
+        dto_end[at] = _dot(w[at], dS, (1, 0))
+        dend[at:at + 1, :] = jnp.sum(S * dS, axis=0, keepdims=True)
+    for at in heads:
+        d_state[at] = (
+            end[at:at + 1, :] * d_state[at]
+            + _dot(d_out[at], q_decayed[at], (0, 0))
+            - _dot(dU[at], W[at], (0, 0))
+        )
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _():
+        for at in heads:
+            d_entering[at] = d_state[at].T
+
+
+def state_heads(h: int) -> int:
+    """Heads a grid step of the state kernels: :data:`HEADS_A_STEP` or
+    the largest divisor of ``h`` in it and, where that is no whole tile
+    of 8 sublanes (the block of a group's [heads, d_k] decay rows), all
+    ``h``."""
+    heads = math.gcd(h, HEADS_A_STEP)
+    return heads if heads % 8 == 0 else h
+
+
+def _state_call(kernel, name, operands, outputs, reverse: bool,
+                interpret: bool):
+    """``kernel`` over a segment, grid (sequences, groups of heads,
+    chunks), the chunks one after the other (from the last with
+    ``reverse``). Of ``operands`` and of ``outputs`` the last is a state,
+    [b, h, d_k, d_v], whose block stays for a group's whole walk; the
+    others are [b, n, h, ...] as the chunk kernels write them and go a
+    chunk of the group's heads a step. The scratch is the group's states,
+    float32, transposed."""
+    b, n, h = operands[0].shape[:3]
+    heads = state_heads(h)
+    d_k, d_v = operands[-1].shape[2:]
+
+    def specs(arrays):
+        *walked, state = arrays
+        return [
+            pl.BlockSpec(
+                (None, None, heads, *a.shape[3:]),
+                lambda i, j, t, zeros=(0,) * (len(a.shape) - 3): (
+                    i, n - 1 - t if reverse else t, j, *zeros),
+            ) for a in walked
+        ] + [pl.BlockSpec(
+            (None, heads, *state.shape[2:]), lambda i, j, t: (i, j, 0, 0)
+        )]
+
+    def run(*operands):
+        return pl.pallas_call(
+            kernel,
+            out_shape=list(outputs),
+            grid=(b, h // heads, n),
+            in_specs=specs(operands),
+            out_specs=specs(outputs),
+            scratch_shapes=[pltpu.VMEM((heads, d_v, d_k), _F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+            name=name,
+        )(*operands)
+
+    run.__name__ = name
+    return tuple((run if interpret else jax.jit(run))(*operands))
+
+
+def _state_forward_call(local, state, interpret: bool, keeps: bool):
+    """``o`` [b, n, h, c, d_v] in ``P``'s dtype, with ``keeps`` every
+    chunk's entering state [b, n, h, d_v, d_k] and w [b, n, h, c, d_v],
+    float32, and the state left."""
+    like = jax.ShapeDtypeStruct
+    U, _, P = local[:3]
+    d_k, d_v = state.shape[2:]
+    kept = (
+        like((*U.shape[:3], d_v, d_k), _F32), like(U.shape, _F32)
+    ) if keeps else ()
+    return _state_call(
+        functools.partial(_state_forward_kernel, keeps=keeps),
+        "kda_state_forward", (*local, state),
+        (like(U.shape, P.dtype), *kept, like(state.shape, _F32)),
+        False, interpret,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _states(U, W, P, q_decayed, to_end, end, state, interpret: bool):
+    return _state_forward_call(
+        (U, W, P, q_decayed, to_end, end), state, interpret, keeps=False)
+
+
+def _states_fwd(U, W, P, q_decayed, to_end, end, state, interpret):
+    out, entered, w, left = _state_forward_call(
+        (U, W, P, q_decayed, to_end, end), state, interpret, keeps=True)
+    return (out, left), (W, P, q_decayed, to_end, end, entered, w)
+
+
+def _states_bwd(interpret, kept, cotangents):
+    like = jax.ShapeDtypeStruct
+    W, P, q_decayed, to_end, end, _, w = kept
+    d_out, d_left = cotangents
+    return _state_call(
+        _state_backward_kernel, "kda_state_backward",
+        (*kept, d_out, d_left),
+        tuple(like(a.shape, a.dtype)
+              for a in (w, W, P, q_decayed, to_end, end, d_left)),
+        True, interpret,
+    )
+
+
+_states.defvjp(_states_fwd, _states_bwd)
+
+
+def across(local, state):
+    """:func:`_across` by the Pallas kernels: the same two results, the
+    state in VMEM from a segment's first chunk to its last, and one
+    ``custom_vjp`` whose forward keeps a segment's chunk states and w and
+    whose backward is the second kernel, so nothing of the recurrence is
+    differentiated by tracing. Compiled by Mosaic on a TPU, run in the
+    Pallas interpreter anywhere else."""
+    out, left = _states(*local, state, _interpret())
+    b, n, h, c, d_v = out.shape
+    return jnp.moveaxis(out, 3, 2).reshape(b, n * c, h, d_v), left
+
+
 def _segments(chunk: int, *arrays):
     """[b, s, ...] arrays as [segments, b, s / segments, ...]."""
     s = arrays[0].shape[1]
@@ -760,7 +982,8 @@ def _forward(q, k, v, g, beta, chunk, kernels, keep: bool):
         xs = _chunks(chunk, *xs)
         local = (_forward_call(*xs, _interpret(), keep) if kernels
                  else _chunk_local_jnp(*xs))
-        out, left = _across(local[:6], state, v.dtype)
+        out, left = (across(local[:6], state) if kernels
+                     else _across(local[:6], state, v.dtype))
         return left, (out, state, *local[6:])
 
     _, (out, *kept) = jax.lax.scan(
@@ -787,9 +1010,9 @@ def _kda_bwd(chunk, kernels, residuals, d_out):
         def segment(*a):
             *a, state = a
             a = _chunks(chunk, *a)
-            local = (chunk_local(*a, *inverses) if kernels
-                     else _chunk_local_jnp(*a))
-            return _across(local, state, v.dtype)
+            if kernels:
+                return across(chunk_local(*a, *inverses), state)
+            return _across(_chunk_local_jnp(*a), state, v.dtype)
 
         _, vjp = jax.vjp(segment, *xs, state)
         *d_xs, d_state = vjp((d_o, d_state))

@@ -7,7 +7,9 @@ linear attention; ``g = 0`` and ``beta = 1``: the plain delta rule); the
 triangular inverse against ``numpy``; and the residuals a checkpoint's
 policy keeps. The chunk-local step runs by both of its paths, the
 ``jax.numpy`` form and the Pallas kernels (in the interpreter here), and
-the shapes decide which. Tiny sizes, float32, the CPU."""
+so does the recurrence over chunk states (``_across`` in ``jax.numpy``,
+``across`` by the two state kernels); the shapes decide which, for both
+at once. Tiny sizes, float32, the CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -143,22 +145,40 @@ def test_the_kernels_take_bfloat16_as_the_jnp_form_does():
 ])
 def test_the_shapes_choose_the_path(d_k, d_v, chunk, kernels, monkeypatch):
     """The predicate alone, and that ``kda_chunked`` asks it: nothing
-    runs."""
+    runs. The one answer decides both halves: the chunk-local step and
+    the recurrence over chunk states are the kernels' or ``jax.numpy``'s
+    together, forward and backward."""
     assert kda_ops.uses_kernels(d_k, d_v, chunk) is kernels
-    seen = []
-    monkeypatch.setattr(
-        kda_ops, "_kda", lambda *a: seen.append(a[-1]) or a[2]
-    )
     like = jax.ShapeDtypeStruct
-    jax.eval_shape(
-        lambda *a: kda_chunked(*a, chunk),
-        like((1, 16384, 32, d_k), jnp.bfloat16),
-        like((1, 16384, 32, d_k), jnp.bfloat16),
-        like((1, 16384, 32, d_v), jnp.bfloat16),
-        like((1, 16384, 32, d_k), jnp.float32),
-        like((1, 16384, 32), jnp.float32),
-    )
+
+    def shapes(s):
+        return (like((1, s, 32, d_k), jnp.bfloat16),
+                like((1, s, 32, d_k), jnp.bfloat16),
+                like((1, s, 32, d_v), jnp.bfloat16),
+                like((1, s, 32, d_k), jnp.float32),
+                like((1, s, 32), jnp.float32))
+
+    seen = []
+    with monkeypatch.context() as patch:
+        patch.setattr(
+            kda_ops, "_kda", lambda *a: seen.append(a[-1]) or a[2]
+        )
+        jax.eval_shape(lambda *a: kda_chunked(*a, chunk), *shapes(16384))
     assert seen == [kernels]
+    halves = []
+    for name in ("_chunk_local_jnp", "_across", "_forward_call",
+                 "chunk_local", "across"):
+        def recorded(*a, _name=name, _fn=getattr(kda_ops, name), **kw):
+            halves.append(_name)
+            return _fn(*a, **kw)
+        monkeypatch.setattr(kda_ops, name, recorded)
+    jax.eval_shape(
+        jax.grad(lambda *a: jnp.sum(
+            kda_chunked(*a, chunk).astype(jnp.float32)), argnums=(0, 1, 2)),
+        *shapes(2 * chunk))
+    assert set(halves) == (
+        {"_forward_call", "chunk_local", "across"} if kernels
+        else {"_chunk_local_jnp", "_across"})
 
 
 def test_a_sequence_runs_in_segments(monkeypatch):
@@ -283,7 +303,8 @@ def test_under_the_policy_the_kernels_inverses_are_kept_with_no_padded_lane(
     ``T`` as [segments, b, chunks, h, 32, 128] float32 (a [64, 64] float32
     array's last dimension is padded to 128 lanes in HBM), nothing float32
     [64, 64], and the backward runs the rebuild and the gradient kernels
-    and no forward kernel."""
+    of the chunk-local step, the two state kernels, and no forward chunk
+    kernel."""
     monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 1)
     args = _inputs(b=1, s=128, h=2, d_k=8, d_v=8)
 
@@ -298,7 +319,8 @@ def test_under_the_policy_the_kernels_inverses_are_kept_with_no_padded_lane(
         if "kda_chunk_backward" in _kernel_calls(e.params["jaxpr"])
     ]
     assert set(_kernel_calls(backward.params["jaxpr"])) == {
-        "kda_chunk_rebuild", "kda_chunk_backward"}
+        "kda_chunk_rebuild", "kda_chunk_backward",
+        "kda_state_forward", "kda_state_backward"}
     kept = [tuple(v.aval.shape) for v in backward.invars
             if v.aval.dtype == jnp.float32]
     assert (2, 1, 1, 2, 32, 128) in kept
@@ -338,7 +360,8 @@ def test_the_backward_by_the_kernels_inverts_nothing(case, h, chunks, segment,
     calls = _kernel_calls(jax.make_jaxpr(grads(True))(*args).jaxpr)
     assert len(traced) == 1
     assert set(calls) == {
-        "kda_chunk_forward", "kda_chunk_rebuild", "kda_chunk_backward"}
+        "kda_chunk_forward", "kda_chunk_rebuild", "kda_chunk_backward",
+        "kda_state_forward", "kda_state_backward"}
     kept = (count, *kda_ops.inverses_shape((), chunk))
     assert kept[-1] == 128
     steps = count // np.gcd(count, kda_ops.CHUNKS_A_STEP)
@@ -355,3 +378,162 @@ def test_the_backward_by_the_kernels_inverts_nothing(case, h, chunks, segment,
     for name, a, b in zip(NAMES, jax.jit(grads(True))(*args),
                           jax.jit(grads(False))(*args)):
         assert _rel(a, b) < 2e-5, name
+
+
+# --------------------------------------------------------------------------
+# The recurrence over chunk states by its two kernels.
+
+LOCAL = ("U", "W", "P", "q_decayed", "to_end", "end", "state")
+
+
+def _local(dtype, h=2, chunks=3, d_k=128, d_v=128, seed=0):
+    """A segment's six chunk-local results, [1, chunks, h, ...], from the
+    ``jax.numpy`` form at the cell's tile, and a state to enter it with
+    that is not zero."""
+    q, k, v, g, beta = _inputs(seed, b=1, s=64 * chunks, h=h, d_k=d_k,
+                               d_v=d_v)
+    args = _chunked((*(a.astype(dtype) for a in (q, k, v)), g, beta), 64)
+    rng = np.random.default_rng(seed + 1)
+    state = jnp.asarray(rng.standard_normal((1, h, d_k, d_v)), jnp.float32)
+    return kda_ops._chunk_local_jnp(*args), state
+
+
+@pytest.mark.parametrize("dtype,tol,d_tol", [
+    (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 2e-2, 3e-2),
+], ids=["float32", "bfloat16"])
+def test_the_state_kernels_are_the_jnp_recurrence(dtype, tol, d_tol):
+    """``across`` against ``_across`` on the same six chunk-local results
+    and a non-zero entering state: ``o``, the state left and all seven
+    cotangents, the bfloat16 case at the tolerance
+    ``test_the_kernels_take_bfloat16_as_the_jnp_form_does`` uses."""
+    local, state = _local(dtype)
+    want, vjp = jax.vjp(
+        lambda *a: kda_ops._across(a[:6], a[6], dtype), *local, state)
+    got, kernel_vjp = jax.vjp(
+        lambda *a: kda_ops.across(a[:6], a[6]), *local, state)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert _rel(a.astype(jnp.float32), b.astype(jnp.float32)) < tol
+    rng = np.random.default_rng(2)
+    cotangents = tuple(
+        jnp.asarray(rng.standard_normal(a.shape), a.dtype) for a in want
+    )
+    for name, a, b in zip(LOCAL, kernel_vjp(cotangents), vjp(cotangents)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert _rel(a.astype(jnp.float32), b.astype(jnp.float32)) < d_tol, name
+
+
+@pytest.mark.parametrize("strength,tol", [(1.0, 5e-6), (40.0, 2e-4)],
+                         ids=["weak decay", "strong decay"])
+def test_the_kernels_carry_a_state_from_segment_to_segment(strength, tol,
+                                                           monkeypatch):
+    """Four segments of two chunks, three heads (no whole group of eight):
+    every segment but the first is entered with the state the kernel
+    before it left, and the state's cotangent comes back the same way;
+    output and gradients against the token-by-token scan."""
+    monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 2)
+    args = _inputs(b=2, s=128, h=3, d_k=16, d_v=8, strength=strength)
+    fn = lambda *a: kda_chunked(*a, 16, kernels=True)
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(fn(*a))))(*args).jaxpr)
+    assert calls["kda_state_backward"][0][-1] == (2, 3, 16, 8)
+    assert _rel(jax.jit(fn)(*args), jax.jit(kda_recurrent)(*args)) < tol
+    for name, a, b in zip(NAMES, _grads(fn, args),
+                          _grads(kda_recurrent, args)):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert _rel(a, b) < 3 * tol, name
+
+
+@pytest.mark.parametrize("h,heads", [
+    (32, 8), (64, 8), (16, 8), (24, 8),
+    # A group's decay rows are a [heads, d_k] block: whole tiles of eight
+    # sublanes or the whole array.
+    (12, 12), (4, 4), (3, 3),
+])
+def test_heads_a_grid_step_divide_the_head_count(h, heads):
+    """``HEADS_A_STEP`` or a divisor of the head count (as ``_call``'s
+    ``gcd``), and the grid the kernels run on says so: (sequences, groups
+    of heads, chunks), the state's block a group's."""
+    assert kda_ops.HEADS_A_STEP == 8
+    assert kda_ops.state_heads(h) == heads and h % heads == 0
+    like = jax.ShapeDtypeStruct
+    b, n, c, d = 2, 5, 16, 8
+    local = (like((b, n, h, c, d), jnp.float32),) * 2 + (
+        like((b, n, h, c, c), jnp.float32), like((b, n, h, c, d), jnp.float32),
+        like((b, n, h, c, d), jnp.float32), like((b, n, h, d), jnp.float32))
+    jaxpr = jax.make_jaxpr(lambda *a: kda_ops.across(a[:6], a[6]))(
+        *local, like((b, h, d, d), jnp.float32)).jaxpr
+    (call,) = _eqns(jaxpr, "pallas_call")
+    assert call.params["grid_mapping"].grid == (b, h // heads, n)
+    blocks = [tuple(m.block_shape) for m in
+              call.params["grid_mapping"].block_mappings]
+    assert [len(shape) for shape in blocks] == [5, 5, 5, 5, 5, 4, 4, 5, 4]
+    assert all(heads in [getattr(size, "block_size", size) for size in shape]
+               for shape in blocks)
+
+
+def test_only_the_rebuild_keeps_a_segments_states():
+    """The forward pass's call of ``kda_state_forward`` writes ``o`` and
+    the state left; under differentiation (the backward's rebuild of ONE
+    segment) it also writes that segment's entering states, transposed,
+    and ``w``, float32: at the cell's shape 67 MB and 34 MB a segment."""
+    like = jax.ShapeDtypeStruct
+    b, n, h, c, d = 1, 32, 32, 64, 128
+    tile = like((b, n, h, c, d), jnp.float32)
+    local = (tile, tile, like((b, n, h, c, c), jnp.bfloat16),
+             like((b, n, h, c, d), jnp.bfloat16), tile,
+             like((b, n, h, d), jnp.float32))
+    state = like((b, h, d, d), jnp.float32)
+    shapes = {
+        keeps: [(a.shape, a.dtype) for a in jax.eval_shape(
+            lambda *a: kda_ops._state_forward_call(a[:6], a[6], True, keeps),
+            *local, state)]
+        for keeps in (False, True)
+    }
+    out, left = ((b, n, h, c, d), jnp.bfloat16), ((b, h, d, d), jnp.float32)
+    assert shapes[False] == [out, left]
+    assert shapes[True] == [
+        out, ((b, n, h, d, d), jnp.float32), ((b, n, h, c, d), jnp.float32),
+        left]
+    assert 4 * b * n * h * d * d == 67_108_864
+    forward, vjp = (
+        _kernel_calls(jax.make_jaxpr(fn)(*local, state).jaxpr)
+        for fn in (lambda *a: kda_ops.across(a[:6], a[6]),
+                   lambda *a: jax.vjp(kda_ops.across, a[:6], a[6])[0])
+    )
+    assert len(forward["kda_state_forward"][1]) == 2
+    assert len(vjp["kda_state_forward"][1]) == 4
+
+
+@pytest.mark.parametrize("kernel,float32_dots,rounded_dots", [
+    ("kda_state_forward", 2, 2), ("kda_state_backward", 4, 4),
+])
+def test_the_states_products_are_float32_at_the_highest_precision(
+        kernel, float32_dots, rounded_dots):
+    """In the kernels' jaxprs, ``q``, ``k``, ``v`` bfloat16: every product
+    that touches the state's float32 values (W·S, the state's update, and
+    their four transposes) is float32 × float32 at ``Precision.HIGHEST``;
+    the output products and their transposes multiply bfloat16 operands
+    and accumulate in float32."""
+    local, state = _local(jnp.bfloat16, chunks=1)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: jax.vjp(kda_ops.across, a[:6], a[6])[1](
+            (jnp.ones((1, 64, 2, 128), jnp.bfloat16), a[6]))
+    )(*local, state).jaxpr
+    (call,) = [e for e in _eqns(jaxpr, "pallas_call")
+               if e.params["name"] == kernel]
+    dots = _eqns(call.params["jaxpr"], "dot_general")
+    per_head = len(dots) // kda_ops.state_heads(2)
+    assert per_head == float32_dots + rounded_dots
+    kinds = []
+    for e in dots:
+        dtypes = {v.aval.dtype for v in e.invars}
+        assert len(dtypes) == 1 and e.outvars[0].aval.dtype == jnp.float32
+        if dtypes == {jnp.dtype(jnp.float32)}:
+            assert e.params["precision"] == (
+                jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+        else:
+            assert dtypes == {jnp.dtype(jnp.bfloat16)}
+            assert e.params["preferred_element_type"] == jnp.float32
+        kinds.append(dtypes.pop())
+    assert kinds.count(jnp.float32) == float32_dots * kda_ops.state_heads(2)

@@ -416,6 +416,9 @@ def test_the_layers_norm_and_the_latent_layer_keep_their_scopes(lowered):
     ("kda/chunks_per_step", 1024),
     ("kda/state_bytes_per_sequence", 4 * 32 * 128 * 128 * 4),
     ("kda/scan_kernel_layers", 4),
+    # The layers whose chunk states the two state kernels carry: the same
+    # predicate, so the same count.
+    ("kda/state_kernel_layers", 4),
     # 4 layers x 256 chunks x 32 heads x a float32 [64, 64] inverse.
     ("kda/kept_inverse_mib", 512),
     ("latent/rotary_dims", 0),
@@ -438,6 +441,8 @@ def test_the_reports_of_the_published_stack(gauge, value, caplog):
     # ... and which path a chunk's work takes at these shapes.
     assert line.endswith(kda_module.SCAN_PATHS[True])
     assert "kda_chunk_forward" in line and "kda_chunk_rebuild" in line
+    # ... and what carries the state from chunk to chunk.
+    assert "kda_state_forward" in line and "kda_state_backward" in line
     # ... and what its forward keeps for the backward.
     assert "the forward keeps 512 MiB of chunk inverses a sequence" in line
 
@@ -457,6 +462,7 @@ def test_the_kernel_gauge_follows_the_sequences_chunk(sequence, chunk, layers,
     with caplog.at_level(logging.INFO, logger="raydp_tpu.models.kda"):
         kda_module.report(cfg, tokens_per_step=2 * sequence, sequence=sequence)
     assert metrics.gauge_value("kda/scan_kernel_layers") == layers
+    assert metrics.gauge_value("kda/state_kernel_layers") == layers
     assert metrics.gauge_value("kda/kept_inverse_mib") == kept_mib
     assert cfg.kda.kept_inverse_bytes(4, sequence) == kept_mib << 20
     (record,) = [r for r in caplog.records
@@ -471,6 +477,7 @@ def test_the_reports_read_zero_for_the_other_stacks(caplog):
             kda_module.report(cfg, tokens_per_step=4096)
             for gauge in ("kda/layers", "kda/heads", "kda/chunk",
                           "kda/chunks_per_step", "kda/scan_kernel_layers",
+                          "kda/state_kernel_layers",
                           "kda/kept_inverse_mib",
                           "kda/state_bytes_per_sequence"):
                 assert metrics.gauge_value(gauge) == 0

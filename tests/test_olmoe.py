@@ -514,13 +514,16 @@ def test_chunked_scan_compiles_for_the_chip_at_published_widths(one_chip):
 def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
         one_chip, monkeypatch):
     """One sequence of 16,384 tokens, 32 heads of 128 / 128 in chunks of
-    64, forward and backward: Mosaic accepts the chunk kernels (three
-    calls: forward, which writes every chunk's inverse ``T``; the
-    backward's rebuild, which reads it and inverts nothing; the gradient),
-    and what the ``jax.numpy`` form wrote for every segment is in no
-    buffer: nothing with the six levels' axis, and of float32 [chunk,
-    chunk] arrays one alone, inside a fusion (the output product's
-    gradient of ``P`` before it is rounded and stored). ``T`` is kept as
+    64, forward and backward: Mosaic accepts the kernels (six calls: the
+    chunk-local step's forward, which writes every chunk's inverse ``T``,
+    the backward's rebuild, which reads it and inverts nothing, and the
+    gradient; the walk over the chunk states in the forward pass, again
+    in the rebuild, where it also writes a segment's states and ``w``,
+    and backwards), the only loops left are the two walks over the
+    segments, and what the ``jax.numpy`` form wrote for every segment is
+    in no buffer: nothing with the six levels' axis, and of float32
+    [chunk, chunk] arrays none (the output product's gradient of ``P`` is
+    the state kernel's, rounded in VMEM). ``T`` is kept as
     [segments, 1, 32 chunks, 32 heads, 32, 128] float32, a [64, 64] tile's
     two row blocks side by side: 134 MB a layer, where a float32
     [..., 64, 64] array, its last dimension padded to 128 lanes, is 268."""
@@ -543,11 +546,35 @@ def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
         *_on(one_chip, args)
     ).compile()
     hlo = compiled.as_text()
-    assert hlo.count("tpu_custom_call") == 3
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == hlo.count("tpu_custom_call") == 6
+    named = [re.search(r"%(kda_[a-z_]+)", line).group(1) for line in calls]
+    assert sorted(named) == [
+        "kda_chunk_backward", "kda_chunk_forward", "kda_chunk_rebuild",
+        "kda_state_backward", "kda_state_forward", "kda_state_forward"]
+    # The forward pass's walk writes o and the state left; the rebuild's
+    # also a segment's entering states and w, which the backward reads.
+    a_segments_states = "f32[1,32,32,128,128]"
+    walks = [line.split(" custom-call(")[0] for line in calls
+             if "%kda_state_forward" in line]
+    assert sorted(a_segments_states in line for line in walks) == [False, True]
+    # Two loops, the walks over the segments, and nothing loops inside
+    # them: no product on the [..., 128, 128] state is left to XLA.
+    loops = re.findall(r"= \([^\n]*\) while\(", hlo)
+    assert len(loops) == 2, len(loops)
+    bodies = re.findall(r"while\([^\n]*body=%([\w.]+)", hlo)
+    assert len(bodies) == 2
+    for body in bodies:
+        text = hlo.split(f"\n%{body} (")[1].split("\n}\n")[0]
+        assert " while(" not in text and "kda_state_" in text, body
+    for line in hlo.splitlines():
+        if re.search(r" (dot|convolution)\(", line):
+            assert not re.search(r"f32\[[\d,]*128,128\]", line), line
     assert not re.search(r"\[[\d,]*,6,64,128\]", hlo)
     squares = set(re.findall(r"f32\[[\d,]*64,64\]", hlo))
-    assert squares == {"f32[32,32,64,64]"}, squares
-    assert "bf16[32,32,64,64]" in hlo and hlo.count("f32[32,32,64,64]") == 1
+    assert squares == set(), squares
+    assert "bf16[1,32,32,64,64]" in hlo
     # The kept inverses: one array of all eight segments', written a
     # segment at a time by the forward kernel and read by the rebuild and
     # the gradient kernels, in tiles with no padded lane.
@@ -565,8 +592,10 @@ def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
                    and "tpu_custom_call" in line]
         operands = call.split("operand_layout_constraints=")[1]
         assert (a_segment in operands) is reads, name
-    # 0.94 GB with the 134 MB of T (0.69 at PR 45, which kept none); the
-    # jax.numpy form's temporaries were 2.00 GiB (PR 44).
+    # 0.79 GB with the 134 MB of T and a segment's states and w from the
+    # state kernel (0.94 with the loop's stacked residuals at PR 46, 0.69
+    # at PR 45, which kept no T); the jax.numpy form's temporaries were
+    # 2.00 GiB (PR 44).
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.0e9, temp
 
