@@ -15,7 +15,14 @@ with RayDP (reference mounted at /root/reference) with a TPU-first design:
   * ``raydp_tpu.parallel`` — dp/pp/sp/tp device meshes, ring attention
   * ``raydp_tpu.spmd`` — SPMD host-process job runner (reference: MPI-on-Ray)
 """
-from raydp_tpu.version import __version__
+import time as _time
+
+#: ``perf_counter`` at the first import of this package, taken before the
+#: imports below (jax alone is seconds): the origin of the process's
+#: start-up record (``utils/profiling.mark_ready``).
+IMPORTED_AT = _time.perf_counter()
+
+from raydp_tpu.version import __version__  # noqa: E402
 
 from raydp_tpu.context import connect, init, stop  # noqa: E402
 

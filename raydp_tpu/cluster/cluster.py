@@ -181,28 +181,35 @@ class Cluster:
             if self.config.num_virtual_nodes
             else None
         )
-        self.master = AppMaster(
-            self.namespace,
-            nodes=nodes,
-            bind_host=self.config.bind_host,
-            advertise_host=self.config.advertise_host,
-            port=self.config.master_port,
-        )
-        try:
-            self._place_group()
-            self._spawn_agents()
-            self.master.expect_workers(self.config.num_workers)
-            for _ in range(self.config.num_workers):
-                self._spawn_worker()
-            if self.config.num_workers and not self.master.wait_for_workers(60.0):
-                raise ClusterError(
-                    f"workers failed to register within 60s "
-                    f"(logs: {self._log_dir})"
-                )
-        except BaseException:
-            # Partial start must not leak the master server/monitor thread.
-            self.shutdown(del_obj_holder=True)
-            raise
+        # Master and workers up and registered: a start-up phase of the
+        # driver, under the job's trace (one of the recorder's retained
+        # names).
+        with span("cluster/start", workers=self.config.num_workers):
+            self.master = AppMaster(
+                self.namespace,
+                nodes=nodes,
+                bind_host=self.config.bind_host,
+                advertise_host=self.config.advertise_host,
+                port=self.config.master_port,
+            )
+            try:
+                self._place_group()
+                self._spawn_agents()
+                self.master.expect_workers(self.config.num_workers)
+                for _ in range(self.config.num_workers):
+                    self._spawn_worker()
+                if self.config.num_workers and not (
+                    self.master.wait_for_workers(60.0)
+                ):
+                    raise ClusterError(
+                        f"workers failed to register within 60s "
+                        f"(logs: {self._log_dir})"
+                    )
+            except BaseException:
+                # Partial start must not leak the master server/monitor
+                # thread.
+                self.shutdown(del_obj_holder=True)
+                raise
         logger.info(
             "cluster %s up: %d workers, master @ %s",
             self.namespace,

@@ -491,7 +491,6 @@ class JaxShardLoader:
                                         rank=self._rank), \
                      span("ingest/device_put", rank=self._rank):
                     buf = jax.device_put(chunk.buf, device)
-                batch_counter("ingest/device_puts")
                 return self._unpack_device(buf, chunk.rows)
             x, y = chunk
             if device is not None:
@@ -501,9 +500,6 @@ class JaxShardLoader:
                      span("ingest/device_put", rank=self._rank):
                     x = jax.device_put(x, device)
                     y = jax.device_put(y, device) if y is not None else None
-                batch_counter(
-                    "ingest/device_puts", 1 if y is None else 2
-                )
             return x, y
 
         def batches_of(chunk):

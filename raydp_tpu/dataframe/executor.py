@@ -60,7 +60,7 @@ def _ensure_etl_job() -> None:
 
 @contextlib.contextmanager
 def _stage_span(op: str, n_parts: int, executor: str, **attrs):
-    """Span + counter around one stage execution (driver side: covers
+    """Span around one stage execution (driver side: covers
     submit AND result gather on the cluster backend, so the duration is
     the stage's wall time as the query planner experiences it). Under
     streaming dispatch the span covers scheduling only — completion
@@ -74,7 +74,6 @@ def _stage_span(op: str, n_parts: int, executor: str, **attrs):
     from raydp_tpu.control import stage_gate
 
     _ensure_etl_job()
-    metrics.counter_add("df/stages")
     with stage_gate(label=op), span(
         "df/stage", op=op, parts=n_parts, executor=executor, **attrs
     ):

@@ -357,9 +357,29 @@ def render_prometheus(view: Dict[str, Any]) -> str:
     )
     compile_seconds = _Family(
         "raydp_compile_seconds_total", "counter",
-        "Cumulative XLA compile seconds (all compile-phase duration "
-        "events summed).",
+        "Seconds spent building programs: Python's trace, the lowering, "
+        "the backend's compile and the persistent cache's loads, every "
+        "second once.",
     )
+    # By kind, keyed by the registry's counter name.
+    compile_kinds = {
+        f"compile/{stem}": _Family(
+            f"raydp_compile_{stem}_total", "counter", help_text,
+        )
+        for stem, help_text in (
+            ("trace_seconds", "Seconds of Python tracing functions to "
+             "jaxprs."),
+            ("lower_seconds", "Seconds of lowering jaxprs to MLIR modules."),
+            ("backend_seconds", "Seconds of backend compiles of programs "
+             "the persistent cache did not hold."),
+            ("cache_load_seconds", "Seconds of reading and loading "
+             "programs the persistent cache held."),
+            ("cache_hits", "Programs found in the persistent compilation "
+             "cache."),
+            ("cache_misses", "Programs compiled and written to the "
+             "persistent compilation cache."),
+        )
+    }
     compile_failures = _Family(
         "raydp_compile_failures_total", "counter",
         "First dispatches of a jitted step that raised while compiling.",
@@ -945,6 +965,11 @@ def render_prometheus(view: Dict[str, Any]) -> str:
                             {"worker": worker_id}, section[name]
                         )
                         continue
+                    if name in compile_kinds:
+                        compile_kinds[name].add(
+                            {"worker": worker_id}, section[name]
+                        )
+                        continue
                     if name.startswith("sched/preemptions/"):
                         sched_preemptions.add(
                             {"worker": worker_id,
@@ -1205,7 +1230,8 @@ def render_prometheus(view: Dict[str, Any]) -> str:
                    shuffles_elided, pipeline_overlap,
                    aqe_replans, aqe_coalesced, aqe_salted, aqe_bytes_saved,
                    stage_rows, stage_bytes, stage_seconds,
-                   compiles, compile_seconds, compile_failures,
+                   compiles, compile_seconds, *compile_kinds.values(),
+                   compile_failures,
                    restarts, preemptions, replay_steps, worker_restarts,
                    usage_total, job_chip_seconds, job_task_seconds,
                    job_bytes, job_hbm_byte_seconds, job_compile_seconds,

@@ -50,6 +50,16 @@ __all__ = [
 # bounded so an unflushed long job cannot grow without limit.
 _CAPACITY = int(os.environ.get("RAYDP_TPU_SPAN_BUFFER", "4096"))
 
+# Start-up is what a process does first and reads last: one epoch of a few
+# hundred steps turns the ring over. The first finished spans of these
+# names are kept beside it for the life of the process
+# (:meth:`SpanRecorder.retained`).
+RETAINED_NAMES = frozenset({
+    "cluster/start", "mesh/build", "train/init_state", "train/build_steps",
+    "train/first_dispatch", "train/fit",
+})
+RETAINED_MAX = 256
+
 
 @dataclass(frozen=True)
 class TraceContext:
@@ -130,6 +140,7 @@ class SpanRecorder:
 
     def __init__(self, capacity: int = _CAPACITY):
         self._buf: "deque[Span]" = deque(maxlen=capacity)
+        self._retained: List[Span] = []
         self._mu = threading.Lock()
         self._tls = threading.local()
         self._seq = itertools.count(1)
@@ -164,6 +175,12 @@ class SpanRecorder:
         if stack:
             return stack[-1].context()
         return self._ambient()
+
+    def current_span(self) -> Optional[Span]:
+        """The innermost span open on this thread, None when there is
+        none (the compile listener names a program's owner by it)."""
+        stack = self._stack()
+        return stack[-1] if stack else None
 
     @contextlib.contextmanager
     def propagated(self, ctx: Optional[TraceContext]) -> Iterator[None]:
@@ -270,12 +287,17 @@ class SpanRecorder:
 
     # -- buffer access --------------------------------------------------
     def _append(self, sp: Span) -> None:
-        evicted = False
+        evicted = 0
         with self._mu:
             if self._buf.maxlen is not None and len(self._buf) == self._buf.maxlen:
-                evicted = True
-                self._dropped += 1
+                evicted += 1
             self._buf.append(sp)
+            if sp.name in RETAINED_NAMES:
+                if len(self._retained) < RETAINED_MAX:
+                    self._retained.append(sp)
+                else:
+                    evicted += 1
+            self._dropped += evicted
         if evicted:
             # Count outside the recorder lock; the metrics counter ships
             # on heartbeats (raydp_spans_dropped_total per worker), so
@@ -283,7 +305,7 @@ class SpanRecorder:
             try:
                 from raydp_tpu.utils.profiling import metrics
 
-                metrics.counter_add("spans/dropped")
+                metrics.counter_add("spans/dropped", evicted)
             except Exception:  # pragma: no cover - accounting best-effort
                 pass
 
@@ -305,9 +327,19 @@ class SpanRecorder:
         with self._mu:
             return list(self._buf)
 
+    def retained(self) -> List[Span]:
+        """The first ``RETAINED_MAX`` finished spans named in
+        ``RETAINED_NAMES``, oldest first: what start-up was made of. A
+        flush does not take them and the ring's turnover does not reach
+        them; one past the limit is counted in ``spans/dropped``."""
+        with self._mu:
+            return list(self._retained)
+
     def clear(self) -> None:
+        """Forget every finished span, the retained ones too (tests)."""
         with self._mu:
             self._buf.clear()
+            self._retained.clear()
 
 
 #: Process-wide recorder — the instrumented hot paths all record here.

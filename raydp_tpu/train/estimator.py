@@ -50,6 +50,7 @@ from raydp_tpu.telemetry import flight_recorder as _flight
 from raydp_tpu.telemetry import overlap as _overlap
 from raydp_tpu.telemetry import watchdog as _watchdog
 from raydp_tpu.train.losses import resolve_loss, resolve_metric
+from raydp_tpu.utils import profiling as _profiling
 
 #: Retention cap for step-encoded checkpoints (``step_mid_<N>`` /
 #: ``step_emergency_<N>``). Long preemption-heavy runs accumulate one
@@ -68,34 +69,35 @@ def _guard_compile(jitted: Callable, label: str) -> Callable:
     """Surface first-dispatch (compile-time) failures with XLA detail.
 
     The first call of a jit'd step is where tracing + backend compile
-    happen; a failure there would otherwise reach the user with no hint
-    of which step it was or how long the compile ran. Later calls pass
-    through untouched — runtime errors are not compile errors and must
-    not be relabelled as such.
+    happen: it runs under the span ``train/first_dispatch`` (one of the
+    recorder's retained names, so a process's start-up can be read after
+    the ring has turned over), and a failure there, which would
+    otherwise reach the user with no hint of which step it was or how
+    long the compile ran, is enriched. Later calls pass through
+    untouched — runtime errors are not compile errors and must not be
+    relabelled as such.
     """
     state = {"first": True}
 
     def wrapped(*args, **kwargs):
         if not state["first"]:
             return jitted(*args, **kwargs)
-        from raydp_tpu.utils.profiling import enrich_compile_error
-
-        start = time.monotonic()
+        sp = None
         try:
-            out = jitted(*args, **kwargs)
+            with span("train/first_dispatch", label=label) as sp:
+                out = jitted(*args, **kwargs)
         except Exception as exc:
             payload = sum(
                 getattr(leaf, "nbytes", 0) or 0
                 for leaf in jax.tree_util.tree_leaves((args, kwargs))
             )
-            raise enrich_compile_error(
-                exc, time.monotonic() - start, label,
-                payload_bytes=payload,
+            raise _profiling.enrich_compile_error(
+                exc, sp.duration_s, label, payload_bytes=payload,
             ) from exc
         # First dispatch ≈ trace + backend compile: bill it to the job
         # ledger so usage_report shows compile cost per job, not just
         # per process.
-        _acct.add_usage(_acct.COMPILE_SECONDS, time.monotonic() - start)
+        _acct.add_usage(_acct.COMPILE_SECONDS, sp.duration_s)
         state["first"] = False
         return out
 
@@ -270,6 +272,11 @@ class JAXEstimator:
             logical_rules = LOGICAL_RULES
         self.logical_rules = list(logical_rules)
 
+        # Compile accounting from the first program on (the init program
+        # is the dearest of a warm start): every trace, lowering and
+        # backend compile lands in the compile/* counters and in one
+        # record per program (utils/profiling.compile_records).
+        _profiling.install_compile_listener()
         self._mesh = None
         # Set by fit(): which epoch path actually ran ('scan'/'stream').
         self.effective_epoch_mode: Optional[str] = None
@@ -293,16 +300,19 @@ class JAXEstimator:
     # -- mesh / state setup ---------------------------------------------
     def _ensure_mesh(self):
         if self._mesh is None:
-            if self.mesh_spec.size > len(jax.devices()):
-                # An explicitly requested mesh that doesn't fit is a
-                # misconfiguration — fail loudly instead of silently
-                # training at a fraction of the requested scale.
-                raise ValueError(
-                    f"mesh {self.mesh_spec.axis_sizes} needs "
-                    f"{self.mesh_spec.size} devices but only "
-                    f"{len(jax.devices())} are visible"
-                )
-            self._mesh = self.mesh_spec.build()
+            # The first build is the backend's first touch where the
+            # caller has not made it: the client's creation is in here.
+            with span("mesh/build", devices=self.mesh_spec.size):
+                if self.mesh_spec.size > len(jax.devices()):
+                    # An explicitly requested mesh that doesn't fit is a
+                    # misconfiguration — fail loudly instead of silently
+                    # training at a fraction of the requested scale.
+                    raise ValueError(
+                        f"mesh {self.mesh_spec.axis_sizes} needs "
+                        f"{self.mesh_spec.size} devices but only "
+                        f"{len(jax.devices())} are visible"
+                    )
+                self._mesh = self.mesh_spec.build()
         return self._mesh
 
     @property
@@ -320,46 +330,62 @@ class JAXEstimator:
         import flax.linen as nn
 
         mesh = self._ensure_mesh()
-        rng = jax.random.PRNGKey(self.seed)
-        sample = jnp.asarray(sample_x[:1])
         model, tx = self._model, self._tx
+        # The init program traced (twice where the state is sharded: once
+        # abstractly for its shardings), compiled or loaded, and run: the
+        # dearest program of a warm start, because it closes over the
+        # seed. Its record names this span as the one that paid.
+        with span("train/init_state", seed=self.seed,
+                  sharded=bool(self.shard_params)) as sp:
+            rng = jax.random.PRNGKey(self.seed)
+            sample = jnp.asarray(sample_x[:1])
 
-        def create():
-            variables = model.init(rng, sample)
-            # Output collections sown during init (MoE aux losses,
-            # intermediates) are NOT parameters — keeping them would feed
-            # them to the optimizer as trainables.
-            if isinstance(variables, dict):
-                variables = {
-                    k: v
-                    for k, v in variables.items()
-                    if k not in ("losses", "intermediates", stats.STATS)
-                }
-            return TrainState.create(
-                apply_fn=model.apply, params=variables, tx=tx
-            )
+            def create():
+                variables = model.init(rng, sample)
+                # Output collections sown during init (MoE aux losses,
+                # intermediates) are NOT parameters — keeping them would
+                # feed them to the optimizer as trainables.
+                if isinstance(variables, dict):
+                    variables = {
+                        k: v
+                        for k, v in variables.items()
+                        if k not in ("losses", "intermediates", stats.STATS)
+                    }
+                return TrainState.create(
+                    apply_fn=model.apply, params=variables, tx=tx
+                )
 
-        if self.shard_params:
-            # The flax SPMD recipe: logical metadata → PartitionSpecs →
-            # mesh shardings for the WHOLE TrainState (optimizer moments
-            # mirror the param tree through optax's tree_map), then a
-            # jitted init materializes each shard directly on its devices
-            # — no full replica ever exists in HBM.
-            abstract = jax.eval_shape(create)
-            logical = nn.get_partition_spec(abstract)
-            shardings = nn.logical_to_mesh_sharding(
-                logical, mesh, self.logical_rules
+            if self.shard_params:
+                # The flax SPMD recipe: logical metadata → PartitionSpecs
+                # → mesh shardings for the WHOLE TrainState (optimizer
+                # moments mirror the param tree through optax's
+                # tree_map), then a jitted init materializes each shard
+                # directly on its devices — no full replica ever exists
+                # in HBM.
+                abstract = jax.eval_shape(create)
+                logical = nn.get_partition_spec(abstract)
+                shardings = nn.logical_to_mesh_sharding(
+                    logical, mesh, self.logical_rules
+                )
+            else:
+                shardings = self.replicated
+            init = jax.jit(
+                lambda: nn.unbox(create()), out_shardings=shardings
             )
-        else:
-            shardings = self.replicated
-        self._state = _guard_compile(jax.jit(
-            lambda: nn.unbox(create()), out_shardings=shardings
-        ), "init_state")()
+            try:
+                self._state = init()
+            except Exception as exc:
+                raise _profiling.enrich_compile_error(
+                    exc, time.perf_counter() - sp.start_mono, "init_state",
+                ) from exc
+        # Billed like a step's first dispatch: the span's duration.
+        _acct.add_usage(_acct.COMPILE_SECONDS, sp.duration_s)
         self._state_shardings = shardings
         self._sample_batch = jax.ShapeDtypeStruct(
             (self.batch_size,) + tuple(sample_x.shape[1:]), sample.dtype
         )
-        self._build_steps()
+        with span("train/build_steps"):
+            self._build_steps()
 
     def _make_train_step(self):
         """The (state, x, y, rng) → (state, loss, grad norm, stats) step
@@ -549,12 +575,6 @@ class JAXEstimator:
                 preds = state.apply_fn(state.params, x)
             return preds
 
-        # Compile accounting: every backend compile these steps trigger
-        # lands in compile/count + compile/seconds (shipped on
-        # heartbeats, exported as raydp_compile_* families).
-        from raydp_tpu.utils.profiling import install_compile_listener
-
-        install_compile_listener()
         self._train_step = _guard_compile(jax.jit(
             train_step, donate_argnums=(0,) if self.donate_state else ()
         ), "train_step")
@@ -656,8 +676,14 @@ class JAXEstimator:
         ``train/epoch_end``: what the host does between the loss fetch
         and the next epoch. It closes before the flush, which drains
         finished spans only."""
-        with span("train/epoch_end", epoch=epoch):
+        with span("train/epoch_end", epoch=epoch) as sp:
             metrics = self._epoch_tail(epoch, *tail)
+        built = _profiling.programs_built()
+        if built != self._programs_seen:
+            # This epoch paid for a program (traced, compiled or loaded
+            # one): start-up was not over before its end.
+            self._programs_seen = built
+            _profiling.mark_ready(sp.end_mono)
         # Epoch boundary = natural flush point for the span ring buffer
         # (no-op unless RAYDP_TPU_TELEMETRY_DIR is configured).
         flush_spans()
@@ -776,6 +802,10 @@ class JAXEstimator:
                 "(label_column may be omitted with self_supervised=True)"
             )
         epochs = num_epochs if num_epochs is not None else self.num_epochs
+        # Programs the process had built when this fit began or its
+        # previous epoch ended: an epoch that ends with more has paid for
+        # one, and moves train/ready_seconds.
+        self._programs_seen = _profiling.programs_built()
         # One root span per fit: everything below — epoch/step spans on
         # this thread, ingest spans on producer threads, worker-side
         # task spans — parents under it (directly or via propagation),

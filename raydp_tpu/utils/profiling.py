@@ -34,6 +34,10 @@ __all__ = [
     "trace",
     "annotate",
     "install_compile_listener",
+    "compile_records",
+    "programs_built",
+    "mark_ready",
+    "ready_stamp",
     "enrich_compile_error",
     "local_devices_if_initialized",
     "sample_resource_gauges",
@@ -338,44 +342,207 @@ def annotate(name: str) -> Iterator[None]:
 
 
 # -- XLA compile accounting ------------------------------------------------
+#
+# jax reports what it does to build a program through ``jax.monitoring``:
+# a scalar when Python's trace of a function, its lowering to MLIR or the
+# backend's compile STARTS, a duration when it ends, and plain events from
+# the persistent cache. The listener below keeps one record per program
+# the process built and counters by kind; ``train/estimator.py`` marks
+# where start-up ended. Together with the recorder's retained spans
+# (``telemetry/spans.py``) that is the process's start-up record.
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: Program records kept; later ones are counted in
+#: ``compile/records_dropped``.
+MAX_COMPILE_RECORDS = 512
+
+_PHASES = {TRACE_EVENT: "trace", LOWER_EVENT: "lower", BACKEND_EVENT: "backend"}
 _COMPILE_LISTENER_INSTALLED = False
 
 
-def install_compile_listener() -> bool:
-    """Feed XLA compile durations into ``compile/count`` +
-    ``compile/seconds`` via ``jax.monitoring``.
+class _CompileLog:
+    """What the listener keeps. Per thread: the phases jax has entered and
+    not left (a phase inside another adds its seconds to its own kind and
+    takes them off the outer one's, so each second is counted once), the
+    trace and lowering seconds no program record has taken yet, and what
+    the cache said since the backend phase began."""
 
-    Every backend-compile jax performs (jit tracing-triggered, AOT
-    ``.compile()``) emits a ``*compile*`` duration
-    event; counting them here gives compile-time accounting on every
-    process — driver, SPMD ranks, cluster workers — without wrapping
-    individual ``jax.jit`` sites. Idempotent; returns False when the
-    running jax has no monitoring hooks."""
+    def __init__(self):
+        self._mu = threading.Lock()
+        self._tls = threading.local()
+        self.records: List[Dict[str, object]] = []
+        self.built = 0
+        self.ready_stamp: Optional[float] = None
+
+    def clear(self) -> None:
+        """Forget the records and the ready stamp (tests)."""
+        with self._mu:
+            self.records = []
+            self.ready_stamp = None
+
+    def _thread(self):
+        tls = self._tls
+        if not hasattr(tls, "open"):
+            tls.open = []  # [event, seconds of the phases inside it]
+            tls.trace_s = tls.lower_s = tls.retrieval_s = 0.0
+            tls.cache = "uncached"
+        return tls
+
+    def entered(self, event: str) -> None:
+        tls = self._thread()
+        tls.open.append([event, 0.0])
+        if event == BACKEND_EVENT:
+            tls.cache, tls.retrieval_s = "uncached", 0.0
+
+    def left(self, event: str, duration: float, fun_name: str) -> None:
+        tls = self._thread()
+        # No entry for an event fed without its start (tests do).
+        inside = 0.0
+        if tls.open and tls.open[-1][0] == event:
+            inside = tls.open.pop()[1]
+        if tls.open:
+            tls.open[-1][1] += duration
+        own = max(0.0, duration - inside)
+        kind = _PHASES[event]
+        if kind == "trace":
+            tls.trace_s += own
+        elif kind == "lower":
+            tls.lower_s += own
+        else:
+            kind = "cache_load" if tls.cache == "hit" else "backend"
+            metrics.counter_add("compile/count")
+            self._record(tls, fun_name, own)
+        metrics.counter_add(f"compile/{kind}_seconds", own)
+        metrics.counter_add("compile/seconds", own)
+
+    def _record(self, tls, fun_name: str, backend_s: float) -> None:
+        from raydp_tpu.telemetry.spans import recorder
+
+        # Who paid: the innermost span open on the building thread.
+        owner = recorder.current_span()
+        record = {
+            "fun_name": fun_name, "trace_s": tls.trace_s,
+            "lower_s": tls.lower_s, "backend_s": backend_s,
+            "cache": tls.cache, "retrieval_s": tls.retrieval_s,
+            "t_end": time.perf_counter(),
+            "owner": owner.name if owner is not None else None,
+        }
+        tls.trace_s = tls.lower_s = tls.retrieval_s = 0.0
+        tls.cache = "uncached"
+        with self._mu:
+            self.built += 1
+            kept = len(self.records) < MAX_COMPILE_RECORDS
+            if kept:
+                self.records.append(record)
+        if not kept:
+            metrics.counter_add("compile/records_dropped")
+
+    def cache_said(self, event: str) -> None:
+        hit = event == CACHE_HIT_EVENT
+        self._thread().cache = "hit" if hit else "miss"
+        metrics.counter_add(
+            "compile/cache_hits" if hit else "compile/cache_misses"
+        )
+
+    def retrieved(self, seconds: float) -> None:
+        self._thread().retrieval_s += seconds
+
+
+_compile_log = _CompileLog()
+
+
+def install_compile_listener() -> bool:
+    """Hear what jax does to build programs (``jax.monitoring``) on every
+    process that calls this — driver, SPMD ranks — without wrapping a
+    ``jax.jit`` site. Idempotent; False when the running jax has no
+    monitoring hooks.
+
+    Counters, in seconds of the phase itself (a trace inside a trace or a
+    compile inside a trace is counted once, under its own kind):
+    ``compile/trace_seconds`` (Python tracing the function),
+    ``compile/lower_seconds`` (jaxpr to MLIR), ``compile/backend_seconds``
+    (XLA's and Mosaic's compile of a program the persistent cache did not
+    hold), ``compile/cache_load_seconds`` (the same event where the cache
+    held it: the read and the load), and ``compile/seconds``, their sum.
+    ``compile/count`` counts backend events, ``compile/cache_hits`` and
+    ``compile/cache_misses`` the cache's own. What the cache SAVED
+    (``compile_time_saved_sec``) is not time spent and is not heard.
+
+    One record per program: see :func:`compile_records`."""
     global _COMPILE_LISTENER_INSTALLED
     if _COMPILE_LISTENER_INSTALLED:
         return True
     try:
         from jax import monitoring as _mon
 
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            # The persistent cache's own bookkeeping is not time spent:
-            # on a hit it reports compile_time_saved_sec, the time the
-            # cache SAVED, which would otherwise be added as if spent.
-            if "compile" not in event or "/compilation_cache/" in event:
-                return
-            # Count the top-level backend_compile events once; finer
-            # sub-phase events still add their seconds to the total.
-            if "backend_compile" in event or event.endswith(
-                "compile_duration_sec"
-            ):
-                metrics.counter_add("compile/count")
-            metrics.counter_add("compile/seconds", float(duration))
+        def _on_scalar(event: str, value: float, **kw) -> None:
+            if event in _PHASES:
+                _compile_log.entered(event)
 
+        def _on_duration(event: str, duration: float, **kw) -> None:
+            if event in _PHASES:
+                _compile_log.left(
+                    event, float(duration), str(kw.get("fun_name", "?"))
+                )
+            elif event == CACHE_RETRIEVAL_EVENT:
+                _compile_log.retrieved(float(duration))
+
+        def _on_event(event: str, **kw) -> None:
+            if event in (CACHE_HIT_EVENT, CACHE_MISS_EVENT):
+                _compile_log.cache_said(event)
+
+        _mon.register_scalar_listener(_on_scalar)
         _mon.register_event_duration_secs_listener(_on_duration)
+        _mon.register_event_listener(_on_event)
     except Exception:
         return False
     _COMPILE_LISTENER_INSTALLED = True
     return True
+
+
+def compile_records() -> List[Dict[str, object]]:
+    """The programs this process built since the listener was installed,
+    oldest first, at most ``MAX_COMPILE_RECORDS``. Each: ``fun_name``
+    (jax's name of the program), ``trace_s`` and ``lower_s`` (what the
+    thread traced and lowered since its previous program: an abstract
+    trace, ``jax.eval_shape``, is billed to the next program built),
+    ``backend_s`` (the backend event: a compile, or on a cache hit the read
+    and load), ``cache`` (``hit``, ``miss``: compiled and written, or
+    ``uncached``: the cache is off or did not take the program),
+    ``retrieval_s`` (the cache's read, part of ``backend_s``), ``t_end``
+    (``perf_counter``) and ``owner``, the innermost span open on the
+    building thread when the backend event arrived, or None."""
+    with _compile_log._mu:
+        return [dict(r) for r in _compile_log.records]
+
+
+def programs_built() -> int:
+    """How many programs the listener has heard, dropped records too."""
+    with _compile_log._mu:
+        return _compile_log.built
+
+
+def mark_ready(stamp: float) -> None:
+    """An epoch that paid for a program ended at ``stamp``
+    (``perf_counter``): the gauge ``train/ready_seconds`` is that moment
+    counted from the first import of ``raydp_tpu``
+    (``raydp_tpu.IMPORTED_AT``). Its last value is where start-up ended."""
+    from raydp_tpu import IMPORTED_AT
+
+    with _compile_log._mu:
+        _compile_log.ready_stamp = stamp
+    metrics.gauge_set("train/ready_seconds", stamp - IMPORTED_AT)
+
+
+def ready_stamp() -> Optional[float]:
+    """``perf_counter`` of the last :func:`mark_ready`, None before it."""
+    with _compile_log._mu:
+        return _compile_log.ready_stamp
 
 
 class CompileError(RuntimeError):
@@ -432,7 +599,6 @@ def enrich_compile_error(
         xla_detail=detail,
     )
     metrics.counter_add("compile/failures")
-    metrics.counter_add("compile/seconds", duration_s)
     # Timeline correlation: the failure lands in /debug/events next to
     # whatever gang churn it caused (lazy import — telemetry.events
     # imports this module's registry).

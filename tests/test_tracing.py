@@ -625,7 +625,8 @@ def test_spans_without_a_profile_are_the_seeds():
     main = threading.get_ident()
     mine = [s for s in recorder.spans() if s.tid == main and s.kind == "span"]
     new = {"infeed/put", "ingest/wait", "train/loss_fetch", "train/epoch_end",
-           "df/action", "df/from_pandas"}
+           "df/action", "df/from_pandas", "mesh/build", "train/init_state",
+           "train/build_steps", "train/first_dispatch"}
     seeds = [(s.name, s.attrs) for s in mine if s.name not in new]
     assert seeds == [
         ("train/step", {"epoch": 0, "step": 0}),
