@@ -324,56 +324,68 @@ class JAXEstimator:
     def replicated(self) -> NamedSharding:
         return NamedSharding(self._ensure_mesh(), P())
 
+    def _init_program(self, rng, sample):
+        """``(init, shardings)``: the jitted ``(rng, sample) → TrainState``
+        and the shardings of the state it returns. The key and the sample
+        row are its ARGUMENTS, so its text depends on nothing a run
+        chooses: a new seed or a new dataset of the same shape and dtype
+        finds it in the compile cache."""
+        import flax.linen as nn
+
+        model, tx = self._model, self._tx
+
+        def create(rng, sample):
+            variables = model.init(rng, sample)
+            # Output collections sown during init (MoE aux losses,
+            # intermediates) are NOT parameters — keeping them would
+            # feed them to the optimizer as trainables.
+            if isinstance(variables, dict):
+                variables = {
+                    k: v
+                    for k, v in variables.items()
+                    if k not in ("losses", "intermediates", stats.STATS)
+                }
+            return TrainState.create(
+                apply_fn=model.apply, params=variables, tx=tx
+            )
+
+        if self.shard_params:
+            # The flax SPMD recipe: logical metadata → PartitionSpecs
+            # → mesh shardings for the WHOLE TrainState (optimizer
+            # moments mirror the param tree through optax's
+            # tree_map), then a jitted init materializes each shard
+            # directly on its devices — no full replica ever exists
+            # in HBM.
+            abstract = jax.eval_shape(create, rng, sample)
+            logical = nn.get_partition_spec(abstract)
+            shardings = nn.logical_to_mesh_sharding(
+                logical, self._ensure_mesh(), self.logical_rules
+            )
+        else:
+            shardings = self.replicated
+        # Both inputs are replicated over the mesh, whatever device the
+        # two small arrays were made on.
+        init = jax.jit(
+            lambda rng, sample: nn.unbox(create(rng, sample)),
+            in_shardings=self.replicated, out_shardings=shardings,
+        )
+        return init, shardings
+
     def _init_state(self, sample_x: np.ndarray) -> None:
         if self._state is not None:
             return
-        import flax.linen as nn
-
-        mesh = self._ensure_mesh()
-        model, tx = self._model, self._tx
+        self._ensure_mesh()  # outside the span: `mesh/build` is a phase
         # The init program traced (twice where the state is sharded: once
-        # abstractly for its shardings), compiled or loaded, and run: the
-        # dearest program of a warm start, because it closes over the
-        # seed. Its record names this span as the one that paid.
+        # abstractly for its shardings), compiled or loaded, and run. A
+        # miss under this span means a changed tree, a new shape or an
+        # evicted entry: no seed and no dataset changes the program.
         with span("train/init_state", seed=self.seed,
                   sharded=bool(self.shard_params)) as sp:
             rng = jax.random.PRNGKey(self.seed)
             sample = jnp.asarray(sample_x[:1])
-
-            def create():
-                variables = model.init(rng, sample)
-                # Output collections sown during init (MoE aux losses,
-                # intermediates) are NOT parameters — keeping them would
-                # feed them to the optimizer as trainables.
-                if isinstance(variables, dict):
-                    variables = {
-                        k: v
-                        for k, v in variables.items()
-                        if k not in ("losses", "intermediates", stats.STATS)
-                    }
-                return TrainState.create(
-                    apply_fn=model.apply, params=variables, tx=tx
-                )
-
-            if self.shard_params:
-                # The flax SPMD recipe: logical metadata → PartitionSpecs
-                # → mesh shardings for the WHOLE TrainState (optimizer
-                # moments mirror the param tree through optax's
-                # tree_map), then a jitted init materializes each shard
-                # directly on its devices — no full replica ever exists
-                # in HBM.
-                abstract = jax.eval_shape(create)
-                logical = nn.get_partition_spec(abstract)
-                shardings = nn.logical_to_mesh_sharding(
-                    logical, mesh, self.logical_rules
-                )
-            else:
-                shardings = self.replicated
-            init = jax.jit(
-                lambda: nn.unbox(create()), out_shardings=shardings
-            )
+            init, shardings = self._init_program(rng, sample)
             try:
-                self._state = init()
+                self._state = init(rng, sample)
             except Exception as exc:
                 raise _profiling.enrich_compile_error(
                     exc, time.perf_counter() - sp.start_mono, "init_state",
