@@ -14,9 +14,11 @@ from raydp_tpu.models.transformer import (
     tiny_transformer,
     laguna_xs_2,
     sdar_30b_a3b,
+    keye_vl_2_0_30b_a3b,
     xing4_0,
 )
 from raydp_tpu.models.blockdiff import BlockDiffusionConfig, BlockDiffusionLM
+from raydp_tpu.models.sparse_index import SparseIndexConfig
 from raydp_tpu.models.hyperconn import HyperConfig
 from raydp_tpu.models.kda import KDAConfig
 from raydp_tpu.models.latent import LatentConfig
@@ -67,6 +69,8 @@ __all__ = [
     "olmoe",
     "laguna_xs_2",
     "sdar_30b_a3b",
+    "keye_vl_2_0_30b_a3b",
+    "SparseIndexConfig",
     "xing4_0",
     "BlockDiffusionConfig",
     "BlockDiffusionLM",

@@ -56,7 +56,7 @@ LOGICAL_RULES: Tuple[Tuple[str, Optional[str]], ...] = (
 
 
 MIXERS = frozenset(
-    {"attention", "window", "mamba", "conv", "latent", "kda"}
+    {"attention", "window", "mamba", "conv", "latent", "kda", "sparse"}
 )
 
 
@@ -127,8 +127,11 @@ class TransformerConfig:
     # What describes an architecture (the defaults are BERT's block).
     norm: str = "layernorm"          # layernorm | rmsnorm
     norm_eps: float = 1e-6
-    positions: str = "learned"       # learned (a table) | rotary | none
+    # learned (a table) | rotary | mrope (rotary by three ids a token,
+    # ``mrope_section`` frequencies each) | none
+    positions: str = "learned"
     rope_theta: float = 10000.0
+    mrope_section: Optional[Tuple[int, ...]] = None
     # Norm of q and k before the positions: False | True or "projection"
     # (over the whole projection) | "head" (over each head's values, one
     # learned weight of head_dim).
@@ -149,7 +152,7 @@ class TransformerConfig:
     moe_loss_weights: Tuple[float, float] = (1e-2, 1e-3)   # balance, z
     shared_experts: int = 0          # of d_expert each, beside the routed
     # Layer i as "<mixer>" or "<mixer>:<ffn>": the mixer is "attention" |
-    # "window" | "mamba" | "conv" | "latent" | "kda", the FFN kind one of
+    # "window" | "mamba" | "conv" | "latent" | "kda" | "sparse", the FFN kind one of
     # ``ffn``'s and ``ffn`` itself where the entry names none. None =
     # attention and ``ffn`` everywhere.
     layer_types: Optional[Tuple[str, ...]] = None
@@ -160,11 +163,14 @@ class TransformerConfig:
     # (None = the plain ``x + F(norm(x))``);
     # ``models/blockdiff.BlockDiffusionConfig`` for a stack that runs on
     # block diffusion's training PAIRS (a noised and a clean copy of every
-    # sequence side by side, the "attention" mixer under the pair mask).
+    # sequence side by side, the "attention" mixer under the pair mask);
+    # ``models/sparse_index.SparseIndexConfig`` for the "sparse" mixer
+    # (the "attention" mixer over the keys a learned index branch selects).
     latent: Any = None
     kda: Any = None
     hyper: Any = None
     diffusion: Any = None
+    sparse: Any = None
     # :class:`WindowConfig` for the "window" mixer; the four fields after
     # it describe the "attention" mixer beside it (and a window layer's
     # head size and gate): a head of its own size (None = d_model //
@@ -286,25 +292,51 @@ def _norm(cfg: TransformerConfig, name: str, dtype=None) -> nn.Module:
     )
 
 
+def rotary_angles(positions, half: int, theta: float,
+                  yarn: Optional[YarnScaling] = None,
+                  sections: Optional[Tuple[int, ...]] = None):
+    """Ids into angles, [B or 1, S, half] float32: frequency i is
+    ``theta^(-i/half)`` (YaRN's blend with ``yarn``,
+    :func:`yarn_inv_freq`) and turns by a token's id. ``positions`` is [B
+    or 1, S], one id a token; with ``sections`` (M-RoPE, ``mrope_section``
+    of the published configs: contiguous bands that sum to ``half``) it is
+    [len(sections), B or 1, S] and band b's frequencies turn by
+    ``positions[b]``. Equal ids make the bands one rotation."""
+    if yarn is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv_freq = jnp.asarray(yarn_inv_freq(half, theta, yarn))
+    if sections is None:
+        return positions.astype(jnp.float32)[..., None] * inv_freq
+    if sum(sections) != half or positions.shape[0] != len(sections):
+        raise ValueError(
+            f"sections {sections!r} over {half} frequencies and ids of "
+            f"shape {positions.shape}"
+        )
+    band = np.repeat(np.arange(len(sections)), sections)
+    return jnp.moveaxis(
+        positions.astype(jnp.float32)[band], 0, -1
+    ) * inv_freq
+
+
 def rotary(x, positions, theta: float, yarn: Optional[YarnScaling] = None,
-           dims: Optional[int] = None):
+           dims: Optional[int] = None,
+           sections: Optional[Tuple[int, ...]] = None):
     """Rotary position embedding (Su et al. 2021) in the half-split form
     of the published OLMoE/NeoX code: feature i pairs with i + D/2.
     ``x`` [B, S, H, D], ``positions`` [B or 1, S]; float32 inside. With
     ``yarn`` the frequencies are YaRN's blend (:func:`yarn_inv_freq`) and
     the rotation is scaled by ``yarn.stretch``. ``dims`` rotates the
     first ``dims`` features of a head (pairs i, i + dims/2) and passes
-    the others through."""
+    the others through. With ``sections`` the ids are three a token
+    (:func:`rotary_angles`, which every form goes through)."""
     if dims is not None and dims != x.shape[-1]:
         return jnp.concatenate([
-            rotary(x[..., :dims], positions, theta, yarn), x[..., dims:]
+            rotary(x[..., :dims], positions, theta, yarn, None, sections),
+            x[..., dims:],
         ], axis=-1)
     half = x.shape[-1] // 2
-    if yarn is None:
-        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    else:
-        inv_freq = jnp.asarray(yarn_inv_freq(half, theta, yarn))
-    angle = positions.astype(jnp.float32)[..., None] * inv_freq
+    angle = rotary_angles(positions, half, theta, yarn, sections)
     cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
     if yarn is not None and yarn.stretch != 1.0:
         cos, sin = cos * yarn.stretch, sin * yarn.stretch
@@ -325,10 +357,15 @@ class MultiHeadAttention(nn.Module):
     rotate by where they are not ``0 … S-1``. With ``cfg.diffusion`` the S
     positions are a noised and a clean copy of a sequence side by side and
     the mask is the pair's (``ops/attention.pair_mask``), in blocks of
-    that record's length."""
+    that record's length. With ``sparse`` (a ``SparseIndexConfig``, the
+    "sparse" mixer) attention runs over the keys the layer's index branch
+    selects (``models/sparse_index.py``). Under ``positions="mrope"``
+    ``positions`` may be [3, B or 1, S]; a call without them is a text's,
+    whose three ids are equal."""
 
     cfg: TransformerConfig
     window: Any = None
+    sparse: Any = None
 
     @nn.compact
     def __call__(
@@ -341,8 +378,16 @@ class MultiHeadAttention(nn.Module):
         kv_len: Optional[int] = None,
         positions=None,
     ):
-        cfg, win = self.cfg, self.window
+        cfg, win, sparse = self.cfg, self.window, self.sparse
         scale = cfg.attention_scale
+        if sparse is not None and (
+                win is not None or cache_mode is not None or not cfg.causal
+                or cfg.diffusion is not None):
+            raise NotImplementedError(
+                "a sparse layer trains and evaluates causally over whole "
+                "sequences; an index-key cache and the selection inside "
+                "decode attention are ROADMAP R13"
+            )
         pair = cfg.diffusion.block_length if cfg.diffusion else None
         if pair is not None and (
                 win is not None or cache_mode is not None or not cfg.causal
@@ -405,15 +450,21 @@ class MultiHeadAttention(nn.Module):
             ).reshape(k.shape)
         elif cfg.qk_norm:
             raise ValueError(f"unknown qk_norm {cfg.qk_norm!r}")
-        if cfg.positions == "rotary":
+        pos = None
+        if cfg.positions in ("rotary", "mrope"):
             if cache_mode == "step":
                 pos = cache_positions[:, None]
             elif positions is not None:
                 pos = positions
             else:
                 pos = jnp.arange(x.shape[-2])[None, :]
-            q = rotary(q, pos, theta, yarn, rotary_dim)
-            k = rotary(k, pos, theta, yarn, rotary_dim)
+            # Three ids a token only where they were given: a text's are
+            # equal, and equal ids make the bands one rotation.
+            bands = cfg.mrope_section if pos.ndim == 3 else None
+            if (cfg.positions == "mrope") != (cfg.mrope_section is not None):
+                raise ValueError("positions='mrope' takes mrope_section")
+            q = rotary(q, pos, theta, yarn, rotary_dim, bands)
+            k = rotary(k, pos, theta, yarn, rotary_dim, bands)
 
         if cache_mode is not None:
             # Per-slot KV cache rows (serve-plane autoregressive decode).
@@ -464,6 +515,18 @@ class MultiHeadAttention(nn.Module):
                 )
             else:
                 raise ValueError(f"unknown cache_mode {cache_mode!r}")
+        elif sparse is not None:
+            from raydp_tpu.models import sparse_index
+
+            if pos is None:
+                raise ValueError("a sparse layer's index head is rotated")
+            # The index head turns by a token's temporal id alone.
+            q_idx, k_idx, w = sparse_index.IndexBranch(
+                cfg, sparse, name="index"
+            )(x, pos[0] if pos.ndim == 3 else pos)
+            out = sparse_index.attend(
+                self, sparse, q, k, v, q_idx, k_idx, w, scale
+            )
         elif pair is not None:
             # Everything between the rotated q, k, v and the attention's
             # output under the pair mask carries the scope ``attn/pair``
@@ -587,13 +650,14 @@ class TransformerBlock(nn.Module):
 
         def mix(h):
             """The layer's mixer on its own norm of ``h``."""
-            if self.mixer in ("attention", "window"):
+            if self.mixer in ("attention", "window", "sparse"):
                 # A window layer's module has a name of its own, so that a
                 # trace tells the two kinds of layer apart.
-                attend = MultiHeadAttention(cfg, name="attn") if (
-                    self.mixer == "attention"
-                ) else MultiHeadAttention(
+                attend = MultiHeadAttention(
                     cfg, cfg.window, name="attn_window"
+                ) if self.mixer == "window" else MultiHeadAttention(
+                    cfg, sparse=cfg.sparse if self.mixer == "sparse" else None,
+                    name="attn",
                 )
                 return attend(
                     _norm(cfg, "ln_attn")(h),
@@ -721,7 +785,7 @@ class TransformerEncoder(nn.Module):
         positions=None,
     ):
         cfg = self.cfg
-        if positions is not None and cfg.positions != "rotary":
+        if positions is not None and cfg.positions not in ("rotary", "mrope"):
             raise NotImplementedError(
                 f"given positions with cfg.positions={cfg.positions!r}"
             )
@@ -780,11 +844,12 @@ class TransformerEncoder(nn.Module):
         if cfg.remat:
             from raydp_tpu.ops.flash_attention import KEPT
             from raydp_tpu.ops.kda import KEPT as KDA_KEPT
+            from raydp_tpu.ops.sparse_attention import KEPT as SPARSE_KEPT
 
             block_cls = nn.remat(
                 TransformerBlock, static_argnums=(2,),
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    *KEPT, *KDA_KEPT
+                    *KEPT, *KDA_KEPT, *SPARSE_KEPT
                 ),
             )
         if cfg.hyper is not None:
@@ -918,8 +983,10 @@ class CausalLM(nn.Module):
                 param_dtype=self.cfg.param_dtype,
             )
 
-    def __call__(self, input_ids, deterministic: bool = True):
-        h = self.encoder(input_ids, None, deterministic)
+    def __call__(self, input_ids, deterministic: bool = True, *,
+                 positions=None):
+        given = {} if positions is None else {"positions": positions}
+        h = self.encoder(input_ids, None, deterministic, **given)
         # The final norm's output is written once. Fused into the head's
         # products instead, the norm is computed again inside the weight
         # gradient, whose tiling gets worse for it (PERF.md §6, PR 31:
@@ -1234,6 +1301,43 @@ def sdar_30b_a3b(**overrides) -> TransformerConfig:
         norm_top_k=True, moe_loss_weights=(0.0, 0.0), tie_head=False,
         diffusion=BlockDiffusionConfig(
             block_length=4, mask_id=151669, t_min=1e-3
+        ),
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def keye_vl_2_0_30b_a3b(**overrides) -> TransformerConfig:
+    """Kwai Keye-VL-2.0-30B-A3B's LANGUAGE MODEL (30.6B parameters, about
+    3B active; ``config.json`` of Kwai-Keye/Keye-VL-2.0-30B-A3B,
+    ``model_type`` KeyeVL2): 48 identical pre-norm layers of width 2048;
+    grouped-query attention, 32 query heads over 4 key-value heads of 128,
+    an RMSNorm over each head's q and k, the head rotated by three ids a
+    token (M-RoPE, bands of 16, 24 and 24 frequencies at theta 1e7; a
+    text's ids are equal), OVER THE 2,048 KEYS A LEARNED INDEX BRANCH
+    SELECTS for each query (``sa_config``: 16 index heads of 64 over one
+    index key head; ``models/sparse_index.py``); 128 SwiGLU experts of
+    width 768, 8 a token by softmax probability, renormalised, no shared
+    expert; RMSNorm 1e-6, no biases; vocabulary 151936, untied head. The
+    vision tower is not built (the config gives none of its widths).
+    ``experts_held`` / ``first_expert`` give a layer the share of an
+    expert-parallel deployment; ``n_layers`` keeps the model's own first
+    layers."""
+    from raydp_tpu.models.sparse_index import SparseIndexConfig
+
+    n_layers = overrides.get("n_layers", 48)
+    defaults = dict(
+        vocab_size=151936, d_model=2048, n_heads=32, n_kv_heads=4,
+        head_size=128, n_layers=n_layers, max_len=262144, dropout_rate=0.0,
+        causal=True, norm="rmsnorm", norm_eps=1e-6, positions="mrope",
+        mrope_section=(16, 24, 24), rope_theta=1e7, qk_norm="head",
+        use_bias=False, ffn="moe", n_experts=128, top_k=8, d_expert=768,
+        router_scoring="softmax", norm_top_k=True,
+        moe_loss_weights=(0.0, 0.0), tie_head=False,
+        layer_types=("sparse",) * n_layers,
+        sparse=SparseIndexConfig(
+            index_heads=16, index_head_dim=64, index_kv_heads=1, topk=2048,
+            q_chunk=512, kv_chunk=512,
         ),
     )
     defaults.update(overrides)

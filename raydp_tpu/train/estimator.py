@@ -37,6 +37,7 @@ from raydp_tpu.models import (
     mamba,
     moe,
     shortconv,
+    sparse_index,
     stats,
 )
 from raydp_tpu.models import window as window_mixer
@@ -548,6 +549,7 @@ class JAXEstimator:
         shortconv.report(getattr(self._model, "cfg", None))
         latent.report(getattr(self._model, "cfg", None))
         window_mixer.report(getattr(self._model, "cfg", None))
+        sparse_index.report(getattr(self._model, "cfg", None))
         blockdiff.report(
             self._model, batch=self._sample_batch.shape[0],
             seq_len=int(self._sample_batch.shape[-1]),
@@ -1040,6 +1042,7 @@ class JAXEstimator:
                     moe.report_epoch(stats_sum, n_batches)
                     hyperconn.report_epoch(stats_sum)
                     blockdiff.report_epoch(stats_sum)
+                    sparse_index.report_epoch(stats_sum)
             # Epoch boundary always checks (the sampled cadence may
             # never have landed on a NaN step in a short epoch).
             sentinel.check_loss(train_loss, b_idx, epoch=epoch)

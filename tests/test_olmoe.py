@@ -776,3 +776,45 @@ def test_stream_mixing_is_one_pass_at_xing4s_widths(
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") == 4
     assert _float32_results(hlo, tokens * d) == []
+
+
+def test_sparse_attention_kernels_compile_for_the_chip_at_keyes_shape(
+        one_chip, monkeypatch):
+    """One sequence of 16,384 tokens, 32 query heads over 4 key-value heads
+    of 128, 16 index heads of 64 over one index key head, 2,048 keys a
+    query, forward and backward: Mosaic accepts the five kernels of
+    ``ops/sparse_attention.py`` (all 32 heads of a [256, 512] tile in one
+    grid step, the select's [128, 16384] rows in VMEM), the scores are in
+    HBM a block of 512 query rows at a time and no [S, S] array is, of any
+    type."""
+    import re
+
+    from raydp_tpu.ops import sparse_attention as sa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bf16, s = jnp.bfloat16, 16384
+    like = jax.ShapeDtypeStruct
+    args = (like((1, s, 32, 128), bf16), like((1, s, 4, 128), bf16),
+            like((1, s, 4, 128), bf16), like((1, s, 16, 64), bf16),
+            like((1, s, 64), bf16), like((1, s, 16), jnp.float32))
+
+    def loss(*a):
+        out, kl, _ = sa.sparse_attention(*a, 2048)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.mean(kl)
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *_on(one_chip, args)
+    ).compile()
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    named = sorted(
+        re.search(r"%(sparse_[a-z_]+)", line).group(1) for line in calls)
+    assert named == [
+        "sparse_attention_dkv", "sparse_attention_dq",
+        "sparse_attention_forward", "sparse_index_scores", "sparse_select"]
+    assert f"[{s},{s}]" not in hlo and f"[1,{s},{s}]" not in hlo
+    assert "f32[512,16384]" in hlo
+    # The padded [.., S, 1] columns of lse and delta are the largest
+    # temporaries: 1.1 GB here, where one [S, S] float32 array is as much.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
