@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -153,28 +153,55 @@ def layers_of(cfg) -> int:
     return sum(1 for kind in getattr(cfg, "kinds", ()) if kind == "sparse")
 
 
-def report(cfg) -> None:
-    """Static for a compiled step: three gauges and one log line where the
-    step is built (as ``models/window.report``). Zero for a stack without
-    sparse layers."""
+def report(cfg, seq_len: Optional[int] = None) -> None:
+    """Static for a compiled step: gauges and one log line where the step
+    is built (as ``models/window.report``): the layers, the selection's
+    size, the index heads; and, given the step's sequence length (the
+    estimator gives it), the layers whose backward is the one kernel
+    (``ops/sparse_attention.backward_is_fused``, the rule the call itself
+    takes) and what that kernel keeps resident. Without a length the rule
+    cannot be asked and those two gauges are left as they are. Zero for a
+    stack without sparse layers, which never imports the kernels."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
     sp = cfg.sparse if layers else None
+    # (the one kernel?, its resident MiB); None where the rule is not asked.
+    rule = None if sp else (False, 0)
+    backward = "chosen by the call's length"
+    if sp and seq_len is not None:
+        from raydp_tpu.ops import sparse_attention as op
+
+        call = (seq_len, cfg.n_heads, cfg.kv_heads, cfg.head_dim,
+                sp.index_heads, sp.index_head_dim, cfg.dtype)
+        fused = op.backward_is_fused(*call)
+        rule = fused, fused * op.fused_backward_vmem(*call)[0] / 2 ** 20
+        backward = (
+            f"one kernel (a tile's mask, P and dP feed all six gradients; "
+            f"dk, dv and dkI of the {seq_len} positions resident, "
+            f"{rule[1]:.0f} MiB)" if fused else
+            "the dq and dk/dv kernels (the one kernel's resident gradients "
+            "do not fit VMEM)")
     metrics.gauge_set("attention/sparse_layers", layers)
     metrics.gauge_set("attention/index_topk", sp.topk if sp else 0)
     metrics.gauge_set("attention/index_heads", sp.index_heads if sp else 0)
+    if rule is not None:
+        metrics.gauge_set(
+            "attention/sparse_fused_bwd_layers", layers * rule[0])
+        metrics.gauge_set("attention/sparse_bwd_resident_mib", rule[1])
     if sp:
         logger.info(
             "sparse attention: %d layers, %d index heads of %d over %d index "
             "key head score every causal pair, a query keeps its %d best "
             "keys (all tied ones at the threshold), %d query heads over %d "
             "key-value heads of %d attend over them; the layers' index "
-            "losses go into the step's loss; positions %s",
+            "losses go into the step's loss; positions %s; the backward is "
+            "%s",
             layers, sp.index_heads, sp.index_head_dim, sp.index_kv_heads,
             sp.topk, cfg.n_heads, cfg.kv_heads, cfg.head_dim,
             cfg.positions if cfg.positions != "mrope" else
             f"mrope {tuple(cfg.mrope_section)} (text ids where none given)",
+            backward,
         )
 
 

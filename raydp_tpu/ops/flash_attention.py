@@ -1046,7 +1046,7 @@ _VMEM_BYTES = 128 * 2 ** 20
 _TILE_WORK_BYTES = 32 * 2 ** 20
 
 
-def _vmem_bytes() -> int:
+def vmem_bytes() -> int:
     if jax.default_backend() == "tpu":
         return pltpu.get_tpu_info().vmem_capacity_bytes
     return _VMEM_BYTES
@@ -1071,7 +1071,7 @@ def backward_is_fused(s: int, d: int, d_v: int, itemsize: int) -> bool:
     d = d_v = 128 in bf16: S = 16,384 needs 80 MiB of 128, S = 32,768
     128). What does not fit runs the dq and dk/dv kernels."""
     return 4 * fused_backward_vmem(s, d, d_v, itemsize)[1] <= (
-        3 * _vmem_bytes()
+        3 * vmem_bytes()
     )
 
 

@@ -27,9 +27,18 @@ beyond causality, and it is never an array in HBM: every kernel rebuilds a
 tile's scores ``I`` from ``qI``, ``kI``, ``w`` (one product of ``Hi · Di``
 a pair where the tile's own are ``2 · H · D``) and compares them with the
 kept ``τ``. ALL query heads of a tile run in one grid step, so the mask is
-made once a tile and the head mean ``p`` is a sum in registers.
+made once a tile and the head mean ``p`` is a sum in registers. The key at
+a query's threshold EQUALS ``τ``, so every kernel makes ``I`` by the one
+:func:`_index_tile`, in ONE tile shape. On a TPU its bits are then the
+selection's in every kernel (bf16 products are exact in float32, a pair's
+64 of them are summed in one pass of the MXU and the heads in one order;
+measured, PERF.md §6, and checked again by
+``scripts/sparse_attention_on_chip.py``); the interpreter's float32
+products do not promise that between two programs (XLA's CPU backend emits
+a small product by what surrounds it), so off the chip the loops over
+heads run one head a trip (:func:`_heads_a_trip`).
 
-Five kernels, all in [tq, tk] tiles with float32 accumulation and the
+The kernels, all in [tq, tk] tiles with float32 accumulation and the
 operands in their input dtype:
 
 * ``index`` (:func:`_index_kernel`): ``I`` for one block of
@@ -43,15 +52,25 @@ operands in their input dtype:
 * forward (:func:`_forward_kernel`): two passes over a query tile's key
   tiles, the rows' logsumexp first, then ``P`` normalised as it is made:
   ``o``, ``p`` and ``kl`` need no rescaling and no third pass.
-* backward, ``dq`` with ``dqI`` and ``dw`` (key tiles innermost) and
-  ``dk``, ``dv`` with ``dkI`` (query tiles innermost, tiles transposed
-  as ``ops/flash_attention.py``'s): each rebuilds mask and probabilities,
-  and the index branch's backward rides on the ``p`` they hold.
+* backward, ONE kernel (:func:`_backward_kernel`) where its resident
+  gradients fit the chip's VMEM (:func:`backward_is_fused`, a rule on the
+  call's own shapes: 16,384 tokens over 4 key-value heads of 128 and an
+  index key of 64 keep 68 MiB of 128): a tile's index scores, mask, ``P``,
+  ``dP`` and the index branch's ``dI`` are built once and feed all six
+  gradients; ``dq``, ``dqI`` and ``dw`` accumulate over a query tile's key
+  tiles, ``dk``, ``dv`` and ``dkI`` stay in VMEM for a batch row's whole
+  walk and leave once. A longer call (32,768 tokens: 136 MiB) runs the
+  PAIR: ``dq`` with ``dqI`` and ``dw`` (:func:`_dq_kernel`, key tiles
+  innermost) and ``dk``, ``dv`` with ``dkI`` (:func:`_dkv_kernel`, query
+  tiles innermost, tiles transposed as ``ops/flash_attention.py``'s), each
+  rebuilding mask and probabilities for itself.
 
 RESIDUALS (:data:`KEPT`, named for a checkpoint's policy as
 ``ops/flash_attention.KEPT`` are): the output in [B, H, S, D], the rows'
 logsumexp as a dense [B, H, S], and THE SELECTION as ``τ`` [B, S] with the
-selection's logsumexp of ``I`` [B, S]: the backward never ranks again.
+selection's logsumexp of ``I`` [B, S]: the backward never ranks again, and
+the one kernel takes all four as lane-dense rows (no padded ``[.., S, 1]``
+column is built).
 
 Compiled by Mosaic on a TPU; on any other backend the same kernel bodies
 run in the Pallas interpreter (as ``ops/kda.py``).
@@ -59,6 +78,7 @@ run in the Pallas interpreter (as ``ops/kda.py``).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -67,6 +87,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from raydp_tpu.ops.flash_attention import vmem_bytes
 
 NEG_INF = -1e30
 _F32 = jnp.float32
@@ -77,10 +99,14 @@ KEPT = (
     "sparse_attention_index_lse",
 )
 
-# Tiles of the attention kernels, the query rows whose scores are in HBM at
-# a time, and the rows and columns the selection walks at a time in VMEM.
-BLOCK_Q = 256
-BLOCK_KV = 512
+# The [query rows, keys] tile of every kernel, and how many heads of a
+# key-value group run in one trip of the attention kernels' loops over
+# them: what measured fastest on a TPU v5e at 16,384 tokens, 32 heads over 4
+# of 128, 16 index heads of 64 (PERF.md §6, PR 52).
+BLOCK_Q, BLOCK_KV = 256, 1024
+HEAD_UNROLL = 8
+# The query rows whose scores are in HBM at a time, and the rows and columns
+# the selection walks at a time in VMEM.
 SELECT_ROWS = 512
 SELECT_TILE_ROWS = 128
 SELECT_CHUNK = 1024
@@ -118,16 +144,47 @@ def _params(*semantics: str):
 
 # ------------------------------------------------------------ the tile's mask
 
-def _index_tile(qi, ki, w):
+def _index_tile(qi, ki, w, relu_ref=None):
     """``I`` [tq, tk] of one tile from ``qi`` (a ref whose first axis is
-    the index head, [Hi, tq, Di]), ``ki`` [tk, Di] and ``w`` [tq, Hi]. ONE definition for every
-    kernel: the heads are summed in the same order over the same products,
-    so a tile's mask is the selection's to the bit."""
+    the index head, [Hi, tq, Di]), ``ki`` [tk, Di] and ``w`` [tq, Hi]. ONE
+    definition for every kernel: the heads are summed in the same order
+    over the same products, so a tile's mask is the selection's to the
+    bit. ``relu_ref`` [Hi, tq, tk], where given, keeps every head's
+    ``ReLU(qI_j · kI)`` for the branch's backward."""
     total = None
     for j in range(qi.shape[0]):
-        term = w[:, j:j + 1] * jnp.maximum(_dot(qi[j], ki, _NT), 0.0)
+        relu = jnp.maximum(_dot(qi[j], ki, _NT), 0.0)
+        if relu_ref is not None:
+            relu_ref[j] = relu
+        term = w[:, j:j + 1] * relu
         total = term if total is None else total + term
     return total
+
+
+def _heads_a_trip(group: int) -> int:
+    """How many of a group's heads run straight-line in one trip of the
+    loop over them: :data:`HEAD_UNROLL` on a TPU, ONE in the interpreter.
+    There XLA's CPU backend emits the float32 score product by what
+    surrounds it, and with a second head's work beside it the last bit of
+    ``I`` is no longer the selection's: a key AT its query's threshold
+    drops out of a kernel's mask. The MXU's pass does not depend on its
+    neighbours (module docstring)."""
+    return 1 if _interpret() else math.gcd(group, HEAD_UNROLL)
+
+
+def _over_heads(group: int, body, carry):
+    """``body(i, carry)`` over a group's heads ``i``,
+    :func:`_heads_a_trip` of them a trip of the loop (Mosaic unrolls a
+    ``fori_loop`` wholly or not at all): within a trip one head's vector
+    work runs beside the next one's products."""
+    unroll = _heads_a_trip(group)
+
+    def trip(n, carry):
+        for u in range(unroll):
+            carry = body(n * unroll + u, carry)
+        return carry
+
+    return jax.lax.fori_loop(0, group // unroll, trip, carry)
 
 
 class _Heads:
@@ -153,6 +210,16 @@ def _selected(qi, ki, w, tau, q0, k0):
     ``I ≥ τ`` of its query (``tau`` a [tq, 1] column)."""
     scores = _index_tile(qi, ki, w)
     q_pos, k_pos = _positions(q0, k0, scores.shape)
+    return scores, jnp.logical_and(q_pos >= k_pos, scores >= tau)
+
+
+def _selected_turned(qi, ki, w, tau, q0, k0, relu_ref=None):
+    """:func:`_selected` with the keys down the rows ([tk, tq]; ``tau`` a
+    [1, tq] row). The scores are made as every other kernel makes them and
+    then TURNED: the key at the threshold EQUALS it, and the other
+    contraction's last bit is not promised to."""
+    scores = _index_tile(qi, ki, w, relu_ref).T
+    q_pos, k_pos = _positions(q0, k0, scores.shape, transposed=True)
     return scores, jnp.logical_and(q_pos >= k_pos, scores >= tau)
 
 
@@ -360,7 +427,8 @@ def _forward_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref,
     @pl.when(jnp.logical_and(phase == 0, live))
     def _sums():
         _, keep = tile()
-        for g in range(kv_heads):
+
+        def of_group(g, carry):
             k = k_ref[0, g]
 
             def head(i, carry):
@@ -376,13 +444,15 @@ def _forward_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref,
                 m_ref[h] = m_new
                 return carry
 
-            jax.lax.fori_loop(0, group, head, 0)
+            return _over_heads(group, head, carry)
+
+        jax.lax.fori_loop(0, kv_heads, of_group, 0)
 
     @pl.when(jnp.logical_and(phase == 1, live))
     def _attend():
         scores, keep = tile()
-        mean = jnp.zeros(scores.shape, _F32)
-        for g in range(kv_heads):
+
+        def of_group(g, mean):
             k, v = k_ref[0, g], v_ref[0, g]
 
             def head(i, mean):
@@ -392,8 +462,11 @@ def _forward_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref,
                 acc_ref[h] += _dot(p.astype(v.dtype), v)
                 return mean + p
 
-            mean = jax.lax.fori_loop(0, group, head, mean)
-        mean = mean * (1.0 / heads)
+            return _over_heads(group, head, mean)
+
+        mean = jax.lax.fori_loop(
+            0, kv_heads, of_group, jnp.zeros(scores.shape, _F32)
+        ) * (1.0 / heads)
         log_r = scores - ilse_ref[0]
         kl_acc[...] += jnp.where(
             mean > 0.0,
@@ -498,12 +571,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, wt_ref, tau_ref,
     @pl.when(k0 <= q0 + tq - 1)
     def _accumulate():
         ki, w = ki_ref[0], wt_ref[0]
-        # The scores as every other kernel makes them, then turned: the
-        # key at the threshold EQUALS it, and the other contraction's
-        # last bit is not promised to.
-        scores = _index_tile(_Heads(qi_ref), ki, w_ref[0]).T
-        q_pos, k_pos = _positions(q0, k0, scores.shape, transposed=True)
-        keep = jnp.logical_and(q_pos >= k_pos, scores >= tau_ref[0])
+        scores, keep = _selected_turned(
+            _Heads(qi_ref), ki, w_ref[0], tau_ref[0], q0, k0
+        )
         mean = jnp.zeros(scores.shape, _F32)
         for g in range(kv_heads):
             k, v = k_ref[0, g], v_ref[0, g]
@@ -539,32 +609,135 @@ def _dkv_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, wt_ref, tau_ref,
         dki_ref[0] = dki_acc[...].astype(dki_ref.dtype)
 
 
+def _backward_kernel(q_ref, k_ref, v_ref, kt_ref, qi_ref, qit_ref, ki_ref,
+                     w_ref, tau_ref, ilse_ref, g_ref, lse_ref, delta_ref,
+                     gkl_ref, dq_ref, dqi_ref, dw_ref, dk_ref, dv_ref,
+                     dki_ref, dq_acc, dqi_acc, dw_acc, relu_ref, *, tq: int,
+                     tk: int, scale: float):
+    """All six gradients from ONE pass over a batch row's live tiles.
+
+    Grid (b, query tiles, key tiles). A tile's quantities are built once:
+    the index heads' ``ReLU(z_j)`` (kept in ``relu_ref`` for the branch's
+    backward) and ``I`` by :func:`_index_tile`, queries down the rows as
+    the selection made them; ``I`` turned, and from there on the keys down
+    the rows ([tk, tq]: ``τ``, the logsumexps, ``delta`` and ``g_kl``
+    arrive as rows): the mask, and per head ``p`` and ``ds``, which feed
+
+    dv += p · g;  dk += scale · ds · q;  dqᵀ += scale · kᵀ · ds
+
+    (``kt_ref`` the key tile laid out [d, tk], so dq's product is a plain
+    one and comes out TRANSPOSED, [d, tq]); then ``dI`` from the heads'
+    mean, turned back, and per index head
+
+    dw_j += Σ_s dI · ReLU(z_j);  dqI_j += dz_j · kI;  dkIᵀ += qI_jᵀ · dz_j
+
+    (``qit_ref`` the index queries laid out [Di, tq]). ``dq``, ``dqI`` and
+    ``dw`` accumulate in scratch over the key tiles; ``dk``, ``dv`` and
+    ``dkIᵀ`` ARE their float32 output blocks, a batch row's whole
+    sequence resident in VMEM in ONE buffer: zeroed at the row's first
+    step, written to HBM once after its last."""
+    qi, kj = pl.program_id(1), pl.program_id(2)
+    heads, kv_heads = q_ref.shape[1], k_ref.shape[1]
+    group = heads // kv_heads
+    q0, k0 = qi * tq, kj * tk
+
+    @pl.when(jnp.logical_and(qi == 0, kj == 0))
+    def _start_row():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+        dki_ref[...] = jnp.zeros_like(dki_ref)
+
+    @pl.when(kj == 0)
+    def _start():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dqi_acc[...] = jnp.zeros_like(dqi_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when(k0 <= q0 + tq - 1)
+    def _accumulate():
+        ki, w = ki_ref[0], w_ref[0]
+        scores, keep = _selected_turned(
+            _Heads(qi_ref), ki, w, tau_ref[0], q0, k0, relu_ref
+        )
+
+        def of_group(g, mean):
+            k, v, kt = k_ref[0, g], v_ref[0, g], kt_ref[0, g]
+
+            def head(i, carry):
+                mean, dk, dv = carry
+                h = g * group + i
+                q, go = q_ref[0, h], g_ref[0, h]
+                s = _dot(k, q, _NT) * scale - lse_ref[0, h]
+                p = jnp.exp(jnp.where(keep, s, NEG_INF))
+                dv = dv + _dot(p.astype(go.dtype), go)
+                ds = (p * (_dot(v, go, _NT) - delta_ref[0, h])).astype(q.dtype)
+                dk = dk + _dot(ds, q)
+                dq_acc[h] += _dot(kt, ds)
+                return mean + p, dk, dv
+
+            zero = jnp.zeros(k.shape, _F32)
+            mean, dk, dv = _over_heads(group, head, (mean, zero, zero))
+            dk_ref[0, g, kj] += dk * scale
+            dv_ref[0, g, kj] += dv
+            return mean
+
+        mean = jax.lax.fori_loop(
+            0, kv_heads, of_group, jnp.zeros(scores.shape, _F32)
+        ) * (1.0 / heads)
+        r = jnp.exp(jnp.where(keep, scores - ilse_ref[0], NEG_INF))
+        d_scores = ((r - mean) * gkl_ref[0]).T
+        lane = jax.lax.broadcasted_iota(jnp.int32, dw_acc.shape, 1)
+        d_w = jnp.zeros(dw_acc.shape, _F32)
+        d_ki = jnp.zeros(dki_ref.shape[2:], _F32)
+        for j in range(relu_ref.shape[0]):
+            relu = relu_ref[j]
+            d_w = d_w + jnp.where(
+                lane == j, (d_scores * relu).sum(axis=1, keepdims=True), 0.0
+            )
+            dz = jnp.where(
+                relu > 0.0, d_scores * w[:, j:j + 1], 0.0
+            ).astype(ki.dtype)
+            dqi_acc[j] += _dot(dz, ki)
+            d_ki = d_ki + _dot(qit_ref[0, j], dz)
+        dw_acc[...] += d_w
+        dki_ref[0, kj] += d_ki
+
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[0, :, 0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+        dqi_ref[0] = dqi_acc[...].astype(dqi_ref.dtype)
+        dw_ref[0] = dw_acc[:, :dw_ref.shape[2]]
+
+
 # ------------------------------------------------------------------ the calls
 
-def _specs(shapes, tq: int, tk: int, q_at, k_at):
+def _specs(shapes, tq: int, tk: int, q_at, k_at, q_buffers=None):
     """Block specs of the kernels' shared operands, the query side's
     placed by ``q_at(*grid) -> (b, query tile)`` and the key side's by
-    ``k_at``."""
+    ``k_at``; ``q_buffers`` is the query side's ``pipeline_mode``."""
     (b, h, s, d), h_kv, h_i, d_i = shapes
 
-    def at(side, form):
-        return lambda *grid: form(*side(*grid))
+    def q_side(block, form):
+        return pl.BlockSpec(
+            block, lambda *grid: form(*q_at(*grid)), pipeline_mode=q_buffers
+        )
+
+    def k_side(block, form):
+        return pl.BlockSpec(block, lambda *grid: form(*k_at(*grid)))
 
     return {
-        "q": pl.BlockSpec((1, h, tq, d), at(q_at, lambda b, i: (b, 0, i, 0))),
-        "kv": pl.BlockSpec(
-            (1, h_kv, tk, d), at(k_at, lambda b, j: (b, 0, j, 0))),
-        "qi": pl.BlockSpec(
-            (1, h_i, tq, d_i), at(q_at, lambda b, i: (b, 0, i, 0))),
-        "ki": pl.BlockSpec((1, tk, d_i), at(k_at, lambda b, j: (b, j, 0))),
-        "w": pl.BlockSpec((1, tq, h_i), at(q_at, lambda b, i: (b, i, 0))),
-        "column": pl.BlockSpec((1, tq, 1), at(q_at, lambda b, i: (b, i, 0))),
-        "columns": pl.BlockSpec(
-            (1, h, tq, 1), at(q_at, lambda b, i: (b, 0, i, 0))),
-        "w_t": pl.BlockSpec((1, h_i, tq), at(q_at, lambda b, i: (b, 0, i))),
-        "row": pl.BlockSpec((1, 1, tq), at(q_at, lambda b, i: (b, 0, i))),
-        "rows": pl.BlockSpec(
-            (1, h, 1, tq), at(q_at, lambda b, i: (b, 0, 0, i))),
+        "q": q_side((1, h, tq, d), lambda b, i: (b, 0, i, 0)),
+        "kv": k_side((1, h_kv, tk, d), lambda b, j: (b, 0, j, 0)),
+        "qi": q_side((1, h_i, tq, d_i), lambda b, i: (b, 0, i, 0)),
+        "ki": k_side((1, tk, d_i), lambda b, j: (b, j, 0)),
+        "w": q_side((1, tq, h_i), lambda b, i: (b, i, 0)),
+        "column": q_side((1, tq, 1), lambda b, i: (b, i, 0)),
+        "columns": q_side((1, h, tq, 1), lambda b, i: (b, 0, i, 0)),
+        "w_t": q_side((1, h_i, tq), lambda b, i: (b, 0, i)),
+        "kv_t": k_side((1, h_kv, d, tk), lambda b, j: (b, 0, 0, j)),
+        "qi_t": q_side((1, h_i, d_i, tq), lambda b, i: (b, 0, 0, i)),
+        "row": q_side((1, 1, tq), lambda b, i: (b, 0, i)),
+        "rows": q_side((1, h, 1, tq), lambda b, i: (b, 0, 0, i)),
     }
 
 
@@ -601,17 +774,180 @@ def _forward(qt, kt, vt, qi_t, k_idx, w, tau, ilse, scale: float):
     return out, lse[..., 0], kl[..., 0]
 
 
+def _fused_vmem_limit() -> int:
+    # What the one-kernel backward may take: Mosaic sizes its own scratch
+    # by what it is allowed, so the call is given all but a sixteenth of the
+    # chip's VMEM, and the rule below compares with what the call is given.
+    return 15 * vmem_bytes() // 16
+
+
+# Mosaic's own temporaries are in no spec: room for this many [tk, tq]
+# float32 tiles beside the blocks (a trip's scores, p, dp, ds, the heads'
+# mean, I, r, dI and their turned copies). An allowance, not a count: the
+# cell's call compiles and runs within it (PERF.md §6, PR 52).
+_TILE_TEMPORARIES = 12
+
+
+def _fused_layout(shapes, dtype, index_dtype):
+    """The one-kernel backward's blocks at a call's shapes, the ONE place
+    that knows them: ``((tq, tk), inputs, tiled, resident, scratch)``, an
+    input ``(spec, dtype)`` in the order :func:`_backward_kernel` takes
+    them, an output ``(spec, array)`` (the ``tiled`` ones, then the
+    ``resident``), ``scratch`` arrays of VMEM. The call is built from it
+    and :func:`fused_backward_vmem` adds it up.
+
+    One buffer for what changes with the query tile alone (its fetch and
+    write-back are a query row's, not a step's) and for ``dk``, ``dv``
+    and ``dkIᵀ``, whose blocks are a batch row's whole sequence and ARE
+    the accumulators; the pipeline's two for the keys."""
+    (b, h, s, d), h_kv, h_i, d_i = shapes
+    tq, tk = _block(BLOCK_Q, s), _block(BLOCK_KV, s)
+    n_q, n_k = s // tq, s // tk
+    like = jax.ShapeDtypeStruct
+    once = pl.Buffered(1)
+    spec = _specs(
+        shapes, tq, tk, lambda b, i, j: (b, i),
+        lambda b, i, j: (b, jnp.minimum(j, (i * tq + tq - 1) // tk)), once,
+    )
+
+    def whole_row(*block):
+        return pl.BlockSpec(
+            (1,) + block, lambda b, i, j: (b,) + (0,) * len(block),
+            pipeline_mode=once,
+        ), like((b,) + block, _F32)
+
+    inputs = [(spec[name], of) for name, of in (
+        ("q", dtype), ("kv", dtype), ("kv", dtype), ("kv_t", dtype),
+        ("qi", index_dtype), ("qi_t", index_dtype), ("ki", index_dtype),
+        ("w", _F32), ("row", _F32), ("row", _F32), ("q", dtype),
+        ("rows", _F32), ("rows", _F32), ("row", _F32))]
+    tiled = [
+        (pl.BlockSpec((1, h, 1, d, tq), lambda b, i, j: (b, 0, i, 0, 0),
+                      pipeline_mode=once), like((b, h, n_q, d, tq), dtype)),
+        (spec["qi"], like((b, h_i, s, d_i), index_dtype)),
+        (spec["w"], like((b, s, h_i), _F32)),
+    ]
+    resident = [
+        whole_row(h_kv, n_k, tk, d), whole_row(h_kv, n_k, tk, d),
+        whole_row(n_k, d_i, tk),
+    ]
+    scratch = [
+        like((h, d, tq), _F32), like((h_i, tq, d_i), _F32),
+        like((tq, max(128, h_i)), _F32), like((h_i, tq, tk), _F32),
+    ]
+    return (tq, tk), inputs, tiled, resident, scratch
+
+
+def _padded(shape, dtype) -> int:
+    """Bytes in VMEM of an array whose last two axes lie in (8 · 4 /
+    itemsize, 128) tiles."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *leading, rows, lanes = shape
+    sublanes = 32 // itemsize
+    return math.prod(leading) * (-(-rows // sublanes) * sublanes) * (
+        -(-lanes // 128) * 128) * itemsize
+
+
+def fused_backward_vmem(s: int, h: int, h_kv: int, d: int, h_i: int,
+                        d_i: int, dtype) -> tuple:
+    """(resident, needed) bytes of the one-kernel backward at a call's
+    shapes, from the blocks the call is built with (:func:`_fused_layout`):
+    ``resident`` a batch row's float32 ``dk``, ``dv`` [Hkv, S, d] and
+    ``dkIᵀ`` [Di, S], which stay over all of the row's tiles; ``needed``
+    every block in VMEM's tiles times its buffers, the scratch, and
+    :data:`_TILE_TEMPORARIES`."""
+    (tq, tk), inputs, tiled, resident, scratch = _fused_layout(
+        ((1, h, s, d), h_kv, h_i, d_i), dtype, dtype
+    )
+
+    def held(spec, of):
+        mode = spec.pipeline_mode
+        return (2 if mode is None else mode.buffer_count) * _padded(
+            spec.block_shape, of)
+
+    kept = sum(math.prod(a.shape) * a.dtype.itemsize for _, a in resident)
+    blocks = sum(held(spec, of) for spec, of in inputs) + sum(
+        held(spec, a.dtype) for spec, a in tiled + resident)
+    work = sum(_padded(a.shape, a.dtype) for a in scratch) + (
+        _TILE_TEMPORARIES * _padded((tk, tq), _F32))
+    return kept, blocks + work
+
+
+def backward_is_fused(s: int, h: int, h_kv: int, d: int, h_i: int, d_i: int,
+                      dtype) -> bool:
+    """Whether a call's backward is the one kernel: the resident
+    gradients and a step's work within what the call is given of the
+    chip's VMEM (the cell's S = 16,384, Hkv = 4, d = 128, Di = 64 in bf16:
+    68 MiB resident; S = 32,768: 136). What does not fit runs the dq and
+    dk/dv kernels."""
+    needed = fused_backward_vmem(s, h, h_kv, d, h_i, d_i, dtype)[1]
+    return needed <= _fused_vmem_limit()
+
+
 def _backward(qt, kt, vt, qi_t, k_idx, w, tau, ilse, out_t, lse, g_out,
               g_kl, scale: float):
-    """Gradients in the kernels' layouts: ``(dq, dk, dv, dqI, dkI, dw)``."""
-    b, h, s, d = qt.shape
+    """``(dq, dk, dv, dqI, dkI, dw)`` in the operation's layouts, by the
+    one kernel where :func:`backward_is_fused` says its resident blocks
+    fit, else by the pair."""
+    _, h, s, d = qt.shape
     h_kv, h_i, d_i = kt.shape[1], qi_t.shape[1], qi_t.shape[3]
-    tq, tk = _block(BLOCK_Q, s), _block(BLOCK_KV, s)
-    shapes = (qt.shape, h_kv, h_i, d_i)
     delta = jnp.einsum(
         "bhsd,bhsd->bhs", g_out.astype(_F32), out_t.astype(_F32)
     )
-    g_kl = g_kl.astype(_F32)
+    rule = _backward_fused if backward_is_fused(
+        s, h, h_kv, d, h_i, d_i, qt.dtype
+    ) else _backward_pair
+    return rule((qt.shape, h_kv, h_i, d_i), qt, kt, vt, qi_t, k_idx, w, tau,
+                ilse, lse, g_out, delta, g_kl.astype(_F32), scale)
+
+
+def _backward_fused(shapes, qt, kt, vt, qi_t, k_idx, w, tau, ilse, lse,
+                    g_out, delta, g_kl, scale: float):
+    """The backward as ONE kernel (:func:`_backward_kernel`). The keys
+    and the index queries go in a second time with the sequence last;
+    ``dq`` comes out a query tile at a time as [d, tq] and ``dk``,
+    ``dv``, ``dkIᵀ`` in float32, a key tile at a time: each takes its way
+    to the operation's layout and dtype in the one einsum every gradient
+    ends with."""
+    (b, h, s, d), h_kv, h_i, d_i = shapes
+    (tq, tk), inputs, tiled, resident, scratch = _fused_layout(
+        shapes, qt.dtype, qi_t.dtype
+    )
+    outputs = tiled + resident
+    dq, dqi, dw, dk, dv, dki = pl.pallas_call(
+        functools.partial(_backward_kernel, tq=tq, tk=tk, scale=scale),
+        out_shape=tuple(array for _, array in outputs),
+        grid=(b, s // tq, s // tk),
+        in_specs=[spec for spec, _ in inputs],
+        out_specs=tuple(spec for spec, _ in outputs),
+        scratch_shapes=[pltpu.VMEM(a.shape, a.dtype) for a in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_fused_vmem_limit(),
+        ),
+        interpret=_interpret(),
+        name="sparse_attention_backward",
+    )(qt, kt, vt, jnp.swapaxes(kt, 2, 3), qi_t, jnp.swapaxes(qi_t, 2, 3),
+      k_idx, w, tau[:, None, :], ilse[:, None, :], g_out,
+      lse[:, :, None, :], delta[:, :, None, :], g_kl[:, None, :])
+    heads_last = lambda x: jnp.einsum(  # noqa: E731
+        "bhnkd->bnkhd", x).reshape(b, s, h_kv, d).astype(kt.dtype)
+    return (
+        jnp.einsum("bhndq->bnqhd", dq).reshape(b, s, h, d), heads_last(dk),
+        heads_last(dv), jnp.einsum("bhsd->bshd", dqi),
+        jnp.einsum("bndk->bnkd", dki).reshape(b, s, d_i).astype(k_idx.dtype),
+        dw,
+    )
+
+
+def _backward_pair(shapes, qt, kt, vt, qi_t, k_idx, w, tau, ilse, lse, g_out,
+                   delta, g_kl, scale: float):
+    """The backward as two kernels, ``dq`` with ``dqI`` and ``dw`` and
+    ``dk``, ``dv`` with ``dkI``, each building a tile's mask and
+    probabilities for itself: what a call too long for
+    :func:`_backward_fused`'s resident blocks runs."""
+    (b, h, s, d), h_kv, h_i, d_i = shapes
+    tq, tk = _block(BLOCK_Q, s), _block(BLOCK_KV, s)
     like = jax.ShapeDtypeStruct
 
     spec = _specs(
@@ -662,7 +998,8 @@ def _backward(qt, kt, vt, qi_t, k_idx, w, tau, ilse, out_t, lse, g_out,
     )(qt, kt, vt, qi_t, k_idx, w, jnp.swapaxes(w, 1, 2), tau[:, None, :],
       ilse[:, None, :], g_out, lse[:, :, None, :], delta[:, :, None, :],
       g_kl[:, None, :])
-    return dq, dk, dv, dqi, dki, dw
+    back = lambda x: jnp.einsum("bhsd->bshd", x)  # noqa: E731
+    return back(dq), back(dk), back(dv), back(dqi), dki, dw
 
 
 # ----------------------------------------------------------- the operation
@@ -700,12 +1037,10 @@ def _sparse_attention_bwd(topk, scale, residuals, cotangents):
     (qt, kt, vt, qi_t, w), k_idx, (out_t, lse, tau, ilse) = residuals
     g_out, g_kl, _ = cotangents
     with jax.named_scope("sparse"):
-        dq, dk, dv, dqi, dki, dw = _backward(
+        return _backward(
             qt, kt, vt, qi_t, k_idx, w, tau, ilse, out_t, lse,
             _heads_first(g_out), g_kl, scale,
         )
-        back = lambda x: jnp.einsum("bhsd->bshd", x)  # noqa: E731
-        return back(dq), back(dk), back(dv), back(dqi), dki, dw
 
 
 _sparse_attention.defvjp(_sparse_attention_fwd, _sparse_attention_bwd)

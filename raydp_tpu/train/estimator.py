@@ -549,7 +549,10 @@ class JAXEstimator:
         shortconv.report(getattr(self._model, "cfg", None))
         latent.report(getattr(self._model, "cfg", None))
         window_mixer.report(getattr(self._model, "cfg", None))
-        sparse_index.report(getattr(self._model, "cfg", None))
+        sparse_index.report(
+            getattr(self._model, "cfg", None),
+            seq_len=int(self._sample_batch.shape[-1]),
+        )
         blockdiff.report(
             self._model, batch=self._sample_batch.shape[0],
             seq_len=int(self._sample_batch.shape[-1]),

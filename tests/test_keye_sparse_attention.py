@@ -55,13 +55,16 @@ TRAFFIC = {"seq_len": SEQ, "per_chip_batch": 2}
 @pytest.fixture(scope="module", autouse=True)
 def small_tiles():
     """Tiles of the tiny sequence: four query tiles, two key tiles, two
-    blocks of rows for the selection."""
-    saved = {n: getattr(sa, n) for n in (
-        "BLOCK_Q", "BLOCK_KV", "SELECT_ROWS", "SELECT_TILE_ROWS",
-        "SELECT_CHUNK")}
-    sa.BLOCK_Q, sa.BLOCK_KV, sa.SELECT_ROWS = 16, 32, 32
-    sa.SELECT_TILE_ROWS, sa.SELECT_CHUNK = 16, 32
-    yield
+    blocks of rows for the selection. The loops over heads run as the
+    module runs them off the chip."""
+    small = {
+        "BLOCK_Q": 16, "BLOCK_KV": 32, "SELECT_ROWS": 32,
+        "SELECT_TILE_ROWS": 16, "SELECT_CHUNK": 32,
+    }
+    saved = {name: getattr(sa, name) for name in small}
+    for name, value in small.items():
+        setattr(sa, name, value)
+    yield saved
     for name, value in saved.items():
         setattr(sa, name, value)
 
@@ -238,25 +241,123 @@ def test_the_index_loss_reaches_the_branch_alone(tiny):
 
 # --------------------------------------------------------- the operation
 
-@pytest.mark.parametrize("topk", [8, 24, 200])
-def test_the_kernels_match_the_dense_formula(topk):
-    args = _draws(topk)
+@pytest.fixture
+def backward(request, monkeypatch):
+    """The rule on the call's shapes forced each way: a chip with no VMEM
+    sends every call to the ``dq`` and ``dk/dv`` kernels."""
+    if request.param == "pair":
+        monkeypatch.setattr(sa, "vmem_bytes", lambda: 0)
+    return request.param
+
+
+# A ``topk`` under, at and over a tile at four query and two key tiles;
+# then eight query and four key tiles, so that every resident key tile is
+# reached from several query tiles and each of the two batch rows starts
+# from zero and is written once.
+@pytest.mark.parametrize("topk, seq, backward", [
+    (8, SEQ, "fused"), (24, SEQ, "fused"), (200, SEQ, "fused"),
+    (8, SEQ, "pair"), (24, SEQ, "pair"), (200, SEQ, "pair"),
+    (24, 2 * SEQ, "fused"),
+], indirect=["backward"])
+def test_the_kernels_match_the_dense_formula(topk, seq, backward):
+    args = _draws(topk, s=seq)
+    shapes = [a.shape[1:] for a in args]
+    assert sa.backward_is_fused(
+        seq, shapes[0][1], shapes[1][1], shapes[0][2], *shapes[3][1:],
+        jnp.float32,
+    ) == (backward == "fused")
     got = sa.sparse_attention(*args, topk)
     want = sa.reference_sparse_attention(*args, topk)
     for g, w in zip(got, want):
         assert _rel(g, w) < 1e-5
-    cot = _draws(topk + 1)[0], jnp.asarray(
-        np.random.default_rng(3).standard_normal((2, SEQ)), jnp.float32)
+    cot = _draws(topk + 1, s=seq)[0], jnp.asarray(
+        np.random.default_rng(3).standard_normal((2, seq)), jnp.float32)
 
     def loss(fn, *a):
         out, kl, _ = fn(*a, topk)
         return (out * cot[0]).sum() + (kl * cot[1]).sum()
 
-    got = jax.grad(lambda *a: loss(sa.sparse_attention, *a), range(6))(*args)
+    grads = jax.grad(lambda *a: loss(sa.sparse_attention, *a), range(6))
+    calls = str(jax.make_jaxpr(grads)(*args))
+    for name, there in (("sparse_attention_backward", backward == "fused"),
+                        ("sparse_attention_dq", backward == "pair"),
+                        ("sparse_attention_dkv", backward == "pair")):
+        assert (name in calls) == there, name
+    got = grads(*args)
     want = jax.grad(
         lambda *a: loss(sa.reference_sparse_attention, *a), range(6))(*args)
     for g, w in zip(got, want):
         assert _rel(g, w) < 1e-4
+
+
+def test_two_heads_a_trip_give_the_gradients_of_one(monkeypatch):
+    """The loops over a group's heads unrolled as the chip runs them
+    (off the chip they run one head a trip: ``_heads_a_trip``): with every
+    causal key selected (no threshold for the interpreter's last bit to
+    move) outputs and gradients are the dense formula's."""
+    assert sa._heads_a_trip(8) == 1
+    monkeypatch.setattr(sa, "_heads_a_trip", lambda group: 2)
+    args = _draws(21)
+
+    def loss(fn, *a):
+        out, kl, _ = fn(*a, 200)
+        return (out * args[0]).sum() + kl.sum()
+
+    got = jax.value_and_grad(
+        lambda *a: loss(sa.sparse_attention, *a), range(6))(*args)
+    want = jax.value_and_grad(
+        lambda *a: loss(sa.reference_sparse_attention, *a), range(6))(*args)
+    assert abs(float(got[0]) - float(want[0])) < 1e-5 * abs(float(want[0]))
+    for g, w in zip(got[1], want[1]):
+        assert _rel(g, w) < 1e-4
+
+
+def test_the_chips_checks_run_at_the_tests_tiles():
+    """``scripts/sparse_attention_on_chip.py`` is where the chip is asked
+    what the interpreter cannot promise; its parts run here so that they
+    stay runnable: the backward's mask, built as the kernel builds it, is
+    the selection's on every pair, and both backward paths give the
+    gradients of the dense formula taken a block of query rows at a
+    time."""
+    path = os.path.join(REPO, "scripts", "sparse_attention_on_chip.py")
+    spec = importlib.util.spec_from_file_location("sparse_on_chip", path)
+    on_chip = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(on_chip)
+    _, _, _, q_idx, k_idx, w = _draws(4, b=1)
+    (layer,) = on_chip.mask_agreement(
+        {"layer": (q_idx[0], k_idx[0], w[0])}, 8, rows=32).values()
+    assert layer["pairs_that_differ"] == 0
+    assert layer["selection_pairs"] == layer["backward_mask_pairs"] >= sum(
+        min(t + 1, 8) for t in range(SEQ))
+    assert layer["backward_holds_of_float32"] > 0.99
+    errors = on_chip.gradient_errors(
+        5, SEQ, 8, 16, h=4, h_kv=2, d=16, h_i=3, d_i=8, dtype=jnp.float32)
+    assert errors["rule"]["kernels"] == ["sparse_attention_backward"]
+    assert errors["pair"]["kernels"] == [
+        "sparse_attention_dkv", "sparse_attention_dq"]
+    for path in errors.values():
+        assert max(path["error"].values()) < 1e-4, path
+    assert on_chip.main(["--skip-model"]) == 3    # no TPU here
+
+
+def test_the_rule_is_the_calls_own_shapes_against_the_chips_vmem(
+        small_tiles, monkeypatch):
+    """At the module's own tiles: the cell's call (16,384 tokens, 32 heads
+    over 4 of 128, 16 index heads of 64, bf16) keeps 68 MiB resident and
+    takes the one kernel; 32,768 tokens would keep 136 of the chip's 128
+    and take the pair; no VMEM, no one kernel."""
+    for name, value in small_tiles.items():
+        monkeypatch.setattr(sa, name, value)
+    cell = (16384, 32, 4, 128, 16, 64, jnp.bfloat16)
+    resident, needed = sa.fused_backward_vmem(*cell)
+    assert resident == 68 * 2 ** 20 < needed <= 120 * 2 ** 20
+    assert sa.backward_is_fused(*cell)
+    long = (32768,) + cell[1:]
+    assert sa.fused_backward_vmem(*long)[0] == 136 * 2 ** 20
+    assert not sa.backward_is_fused(*long)
+    monkeypatch.setattr(sa, "vmem_bytes", lambda: 64 * 2 ** 20)
+    assert not sa.backward_is_fused(*cell)
+    assert sa.backward_is_fused(2048, *cell[1:])
 
 
 def test_queries_before_topk_attend_causally():
@@ -398,7 +499,7 @@ def test_the_mixer_refuses_what_it_does_not_run(tiny):
                     method=model.prefill, mutable=["cache"])
 
 
-def test_fit_reports_the_selection(builder):
+def test_fit_reports_the_selection(builder, small_tiles, monkeypatch):
     """One fit through ``JAXEstimator``: the step's loss carries the index
     losses, the epoch's gauges say what was selected, and the reports read
     zero for a model without the mixer."""
@@ -423,13 +524,31 @@ def test_fit_reports_the_selection(builder):
     assert metrics.gauge_value("attn/select_overfull_queries") >= 0.0
     assert metrics.gauge_value("attention/sparse_layers") == 2
     assert metrics.gauge_value("attention/index_topk") == 8
+    # Both layers' backward is the one kernel; a row's dk and dv of two
+    # key-value heads of 16 and dkI of 8, float32, stay resident.
+    assert metrics.gauge_value("attention/sparse_fused_bwd_layers") == 2
+    assert metrics.gauge_value("attention/sparse_bwd_resident_mib") == (
+        4 * SEQ * (2 * 2 * 16 + 8) / 2 ** 20)
     logits = est.predict(x[:2])
     assert logits.shape == (2, SEQ, 256)
     # Zero for every other model.
+    cell = keye_vl_2_0_30b_a3b(n_layers=5)
+    for seq, layers, mib in ((16384, 5, 68), (32768, 0, 0)):
+        with monkeypatch.context() as at_full_size:
+            for name, value in small_tiles.items():
+                at_full_size.setattr(sa, name, value)
+            sparse_index.report(cell, seq_len=seq)
+        assert metrics.gauge_value("attention/sparse_layers") == 5
+        assert metrics.gauge_value(
+            "attention/sparse_fused_bwd_layers") == layers
+        assert metrics.gauge_value(
+            "attention/sparse_bwd_resident_mib") == mib
     sparse_index.report(keye_vl_2_0_30b_a3b(
-        layer_types=("attention",) * 48, sparse=None))
+        layer_types=("attention",) * 48, sparse=None), seq_len=16384)
     sparse_index.report_epoch({"expert_tokens": np.ones(4)})
     for gauge in ("attn/selected_share", "attn/index_kl",
                   "attn/select_overfull_queries", "attention/sparse_layers",
-                  "attention/index_topk", "attention/index_heads"):
+                  "attention/index_topk", "attention/index_heads",
+                  "attention/sparse_fused_bwd_layers",
+                  "attention/sparse_bwd_resident_mib"):
         assert metrics.gauge_value(gauge) == 0.0, gauge

@@ -778,21 +778,29 @@ def test_stream_mixing_is_one_pass_at_xing4s_widths(
     assert _float32_results(hlo, tokens * d) == []
 
 
+@pytest.mark.parametrize("s, backward, temporaries", [
+    (16384, ["sparse_attention_backward"], 0.6e9),
+    (32768, ["sparse_attention_dkv", "sparse_attention_dq"], 2.5e9),
+])
 def test_sparse_attention_kernels_compile_for_the_chip_at_keyes_shape(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, s, backward, temporaries):
     """One sequence of 16,384 tokens, 32 query heads over 4 key-value heads
     of 128, 16 index heads of 64 over one index key head, 2,048 keys a
-    query, forward and backward: Mosaic accepts the five kernels of
-    ``ops/sparse_attention.py`` (all 32 heads of a [256, 512] tile in one
-    grid step, the select's [128, 16384] rows in VMEM), the scores are in
-    HBM a block of 512 query rows at a time and no [S, S] array is, of any
-    type."""
+    query, forward and backward: Mosaic accepts the kernels of
+    ``ops/sparse_attention.py`` at the tiles they are built with (all 32
+    heads of a tile in one grid step, the select's [128, S] rows in VMEM,
+    the one backward kernel's 68 MiB of resident gradients in single
+    buffers), the scores are in HBM a block of 512 query rows at a time
+    and no [S, S] array is, of any type. At 32,768 tokens the rule on the
+    call's shapes sends the backward to the ``dq`` and ``dk/dv`` kernels,
+    which compile too."""
     import re
 
     from raydp_tpu.ops import sparse_attention as sa
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    bf16, s = jnp.bfloat16, 16384
+    monkeypatch.setattr(sa, "vmem_bytes", lambda: 128 * 2 ** 20)
+    bf16 = jnp.bfloat16
     like = jax.ShapeDtypeStruct
     args = (like((1, s, 32, 128), bf16), like((1, s, 4, 128), bf16),
             like((1, s, 4, 128), bf16), like((1, s, 16, 64), bf16),
@@ -810,11 +818,14 @@ def test_sparse_attention_kernels_compile_for_the_chip_at_keyes_shape(
              if "tpu_custom_call" in line and " custom-call(" in line]
     named = sorted(
         re.search(r"%(sparse_[a-z_]+)", line).group(1) for line in calls)
-    assert named == [
-        "sparse_attention_dkv", "sparse_attention_dq",
+    assert named == backward + [
         "sparse_attention_forward", "sparse_index_scores", "sparse_select"]
     assert f"[{s},{s}]" not in hlo and f"[1,{s},{s}]" not in hlo
-    assert "f32[512,16384]" in hlo
-    # The padded [.., S, 1] columns of lse and delta are the largest
-    # temporaries: 1.1 GB here, where one [S, S] float32 array is as much.
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert f"f32[512,{s}]" in hlo
+    # The one kernel takes lse, delta and the thresholds as rows; the
+    # pair's dq kernel takes padded [.., S, 1] columns, its largest
+    # temporaries (2.2 GB at 32,768 tokens).
+    columns = [line for line in calls if f"f32[1,32,{s},1]" in
+               line.split(" custom-call(")[1]]
+    assert len(columns) == (0 if len(backward) == 1 else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries

@@ -53,6 +53,12 @@ def phase(event, seconds, inside=(), name="jit(f)"):
 def listener():
     assert profiling.install_compile_listener()
     profiling._compile_log.clear()
+    # A trace whose program an earlier file of this worker found compiled
+    # leaves its seconds on the thread for the next record: not this test's.
+    here = profiling._compile_log._thread()
+    here.open = []
+    here.trace_s = here.lower_s = here.retrieval_s = 0.0
+    here.cache = "uncached"
     return counters()
 
 
