@@ -15,6 +15,8 @@ from raydp_tpu.models.transformer import (
     laguna_xs_2,
     sdar_30b_a3b,
     keye_vl_2_0_30b_a3b,
+    mellum2_12b_a2_5b,
+    vocab_rules,
     xing4_0,
 )
 from raydp_tpu.models.blockdiff import BlockDiffusionConfig, BlockDiffusionLM
@@ -70,6 +72,8 @@ __all__ = [
     "laguna_xs_2",
     "sdar_30b_a3b",
     "keye_vl_2_0_30b_a3b",
+    "mellum2_12b_a2_5b",
+    "vocab_rules",
     "SparseIndexConfig",
     "xing4_0",
     "BlockDiffusionConfig",

@@ -18,7 +18,11 @@ the published OLMoE block (Muennighoff et al. 2024, arXiv:2409.02060):
   scatter of ``T·k`` rows (XLA's TPU scatter is a serial loop over its
   updates, 95 ns a row: PERF.md §6, PR 25).
 * Expert weights carry the logical axes ``('expert', 'embed', 'mlp')``
-  (experts over ``dp``, an expert's FFN over ``tp``); no biases.
+  (at rest: experts over ``dp``, an expert's FFN over ``tp``); no biases.
+  Sharded weights alone make no expert parallelism: XLA cannot partition
+  the Mosaic grouped matmul or the two sorts, and gathers every expert
+  onto every chip around them. The exchange below is what computes on the
+  shards.
 * What the router does is configuration of the one layer: ``scoring``
   ``softmax`` (OLMoE) or ``sigmoid``; a ``selection_bias`` added to the
   scores for the selection only (the buffer ``expert_bias`` in collection
@@ -43,8 +47,21 @@ the published OLMoE block (Muennighoff et al. 2024, arXiv:2409.02060):
   instead, forward and backward, inside a ``lax.cond`` that no other
   layer-step enters (:func:`_hand_in`; counted in ``overflow``). A pair
   sorted behind the ``C`` rows reads the last of them and is masked where
-  rows go back to tokens. On one chip the layer runs without its
+  rows go back to tokens. A share on one chip runs without its
   exchange: nothing stands in for the absent chips.
+* The exchange, where the whole group of chips is here
+  (``expert_axis`` names the axis of ``mesh`` the experts lie along, ``n``
+  chips, ``n_experts / n`` experts a chip, tokens sharded over the same
+  axis as the batch): inside a ``shard_map`` over that axis a chip
+  all-gathers the axis's tokens with their gates and choices
+  (``exchange/gather``), runs the share layer above over its own experts
+  (``first_expert`` from its place on the axis; the compact ``C``-row
+  path and its ``T·k``-row guard PER CHIP, so no pair is dropped under
+  any imbalance), and the parts are reduce-scattered to the chips that
+  own the tokens (``exchange/scatter``): :func:`_exchanged`, which also
+  says why a gather and not an all-to-all of pairs. The router and its
+  statistics stay outside, on the batch's own shards. Without an axis the
+  layer is the one-chip program, to the bit.
 * The load-balancing loss (``E · Σ_e f_e · p_e``, weight 0.01) and the
   router z-loss (``mean(logsumexp(logits)²)``, weight 0.001) are sown into
   the ``'losses'`` collection as ``moe_aux`` (nothing where both weights
@@ -88,7 +105,8 @@ logger = logging.getLogger(__name__)
 
 # What the routed layers sow about a step, each summed over layers and
 # steps (``models/stats.py``).
-for _name in ("aux_loss", "expert_tokens", "held_tokens", "overflow"):
+for _name in ("aux_loss", "expert_tokens", "held_tokens", "chip_tokens",
+              "overflow"):
     stats.declare(_name)
 # Collection of what a layer reads and no gradient step may change: the
 # router's selection bias. ``JAXEstimator``'s step hands it on as it was.
@@ -143,8 +161,29 @@ class MoEConfig:
     # SwiGLU of width ``shared_experts * d_ff``, ungated, whole on every
     # share of an expert-parallel deployment.
     shared_experts: int = 0
+    # Expert parallelism on a mesh: the axis of ``mesh`` (a
+    # ``jax.sharding.Mesh``) the experts lie along. Each of the axis's n
+    # chips holds ``n_experts / n`` experts and the layer runs its exchange
+    # (:func:`_exchanged`). None = every held expert on every chip, no
+    # collective: the layer as it was.
+    expert_axis: Optional[str] = None
+    mesh: Any = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+
+    @property
+    def exchange_chips(self) -> int:
+        """Chips the experts lie over (1 without an axis)."""
+        if self.expert_axis is None or self.mesh is None:
+            return 1
+        n = int(self.mesh.shape[self.expert_axis])
+        if n > 1 and (self.held != self.n_experts or self.n_experts % n):
+            raise ValueError(
+                f"{self.n_experts} experts ({self.held} held) over "
+                f"{n} chips of axis {self.expert_axis!r}: the exchange runs "
+                "over all the experts, the same number a chip"
+            )
+        return n
 
     @property
     def held(self) -> int:
@@ -349,6 +388,118 @@ def _hand_out_bwd(_, g):
 _hand_out.defvjp(_hand_out_fwd, _hand_out_bwd)
 
 
+def _sorted_experts(tokens, gate, expert, counts, weights, first, held: int,
+                    share: bool, rows: int, dtype, note_overflow):
+    """``sum_j gate_j E_j(token)`` over the experts ``[first, first +
+    held)`` for ``tokens`` ``[T, D]`` with their ``gate`` and ``expert``
+    ``[T, k]``: the pairs sorted by expert, the expert path over the first
+    ``rows`` places, the guard around it where those are fewer than all
+    ``T·k`` (``note_overflow`` is handed the layer-step's flag, float32).
+    ``counts`` ``[held]`` are the pairs on each held expert, ``first`` a
+    number or a traced value (a chip's place on the experts' axis);
+    ``share`` says that some of the routed experts are not held."""
+    n_tokens, k = gate.shape
+    with jax.named_scope("permute"):
+        # Pair p = t·k + j is token t's j-th expert. ``order`` lists
+        # the pairs by expert, ``inverse`` is each pair's place in
+        # that list: two sorts, no scatter.
+        pairs = jnp.arange(n_tokens * k, dtype=jnp.int32)
+        key, live = expert.reshape(-1).astype(jnp.int32), None
+        if share:
+            # Held experts are groups 0..held-1; a pair on an absent
+            # expert sorts behind them all, where no group reaches.
+            key = key - first
+            live = (key >= 0) & (key < held)
+            key = jnp.where(live, key, held)
+            live = live.reshape(n_tokens, k)
+        _, order = jax.lax.sort_key_val(key, pairs)
+        _, inverse = jax.lax.sort_key_val(order, pairs)
+        routing = (order, inverse, counts.astype(jnp.int32), live)
+        operands = (tokens.astype(dtype), gate) + tuple(weights)
+    if rows == n_tokens * k:
+        return _experts(operands, routing)
+    with jax.named_scope("permute"):
+        overflow = counts.sum() > rows
+        note_overflow(overflow.astype(jnp.float32))
+    with jax.named_scope("experts"):
+        # In the compute dtype before the guard, so that what the
+        # guard hands on is the kernels' own weight gradient.
+        operands = operands[:2] + tuple(
+            w.astype(dtype) for w in operands[2:]
+        )
+    operands, wire = _hand_in(operands, routing, overflow)
+    return _hand_out(
+        _experts(operands, routing, rows), wire, operands, routing,
+        overflow,
+    )
+
+
+def _exchanged(cfg: "MoEConfig", tokens, gate, expert, counts, weights):
+    """The routed experts' sum for ``tokens`` ``[T, D]`` (compute dtype)
+    whose rows, like ``gate``'s and ``expert``'s ``[T, k]``, lie over the
+    ``n`` chips of ``cfg.expert_axis`` as the batch does, with experts
+    ``c·E/n … (c+1)·E/n - 1`` of the stacked ``weights`` on chip ``c``.
+    Inside a ``shard_map`` over that axis a chip all-gathers the tokens
+    with their gates and choices (scope ``exchange/gather``), runs the
+    share layer over its own experts — the sort, the compact ``C``-row
+    expert path and its guard, per chip: no pair is dropped — and the
+    parts go back to the chips that own the tokens summed on the way
+    (``exchange/scatter``, a reduce-scatter in the compute dtype). Under
+    autodiff the two collectives are each other's transposes, so a
+    backward pass moves the same rows.
+
+    The gather form and not an all-to-all of pairs: with k = 8 experts a
+    token over 4 chips a token misses a given chip with probability about
+    0.75⁸ = 10%, so the gather and the reduce-scatter move 3·T/n rows a
+    chip each way where an all-to-all of (token, expert) pairs moves about
+    6·T/n and one of de-duplicated (token, chip) rows about 2.7·T/n; and
+    every shape here is static, where an all-to-all needs a capacity a
+    chip and a second guard for what exceeds it (PERF.md §6, PR 53).
+    Returns the sum ``[T, D]`` laid out as ``tokens`` was and each chip's
+    overflow flag ``[n]``. ``counts`` ``[E]`` int32 are the pairs on every
+    expert, the same on every chip."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh, axis, n = cfg.mesh, cfg.expert_axis, cfg.exchange_chips
+    held = cfg.n_experts // n
+    n_tokens = tokens.shape[0]
+    if n_tokens % n:
+        raise ValueError(
+            f"{n_tokens} tokens over the {n} chips of axis {axis!r}"
+        )
+    rows = compact_rows(
+        dataclasses.replace(cfg, held_experts=held, expert_axis=None), n_tokens
+    )
+
+    def chip(tokens, gate, expert, counts, *weights):
+        with jax.named_scope("exchange"), jax.named_scope("gather"):
+            tokens, gate, expert = (
+                jax.lax.all_gather(a, axis, axis=0, tiled=True)
+                for a in (tokens, gate, expert)
+            )
+        first = jax.lax.axis_index(axis) * held
+        mine = jax.lax.dynamic_slice(counts, (first,), (held,))
+        flags = [jnp.zeros((), jnp.float32)]
+        out = _sorted_experts(
+            tokens, gate, expert, mine, weights, first, held, True, rows,
+            cfg.dtype, flags.append,
+        )
+        with jax.named_scope("exchange"), jax.named_scope("scatter"):
+            out = jax.lax.psum_scatter(
+                out, axis, scatter_dimension=0, tiled=True
+            )
+        return out, flags[-1][None]
+
+    along = P(axis)
+    return jax.shard_map(
+        chip, mesh=mesh,
+        in_specs=(along, along, along, P()) + (along,) * len(weights),
+        out_specs=(along, along),
+        # pallas_call's out_shape carries no varying-axes annotation.
+        check_vma=False,
+    )(tokens, gate, expert, counts, *weights)
+
+
 @functools.lru_cache(maxsize=None)
 def _log_once(cfg: "MoEConfig") -> None:
     logger.info(
@@ -478,43 +629,29 @@ class MoELayer(nn.Module):
             (held, cfg.d_ff, d), cfg.param_dtype,
         )
 
-        with jax.named_scope("permute"):
-            # Pair p = t·k + j is token t's j-th expert. ``order`` lists
-            # the pairs by expert, ``inverse`` is each pair's place in
-            # that list: two sorts, no scatter.
-            pairs = jnp.arange(n_tokens * k, dtype=jnp.int32)
-            key, live = expert.reshape(-1).astype(jnp.int32), None
-            if share:
-                # Held experts are groups 0..held-1; a pair on an absent
-                # expert sorts behind them all, where no group reaches.
-                key = key - first
-                live = (key >= 0) & (key < held)
-                key = jnp.where(live, key, held)
-                live = live.reshape(n_tokens, k)
-            _, order = jax.lax.sort_key_val(key, pairs)
-            _, inverse = jax.lax.sort_key_val(order, pairs)
-            routing = (order, inverse, counts.astype(jnp.int32), live)
-            operands = (tokens.astype(cfg.dtype), gate, w_gate, w_up, w_down)
-        # Nothing reads what ``init`` computes: no guard to compile there.
-        rows = n_tokens * k if self.is_initializing() else (
-            compact_rows(cfg, n_tokens)
-        )
-        if rows == n_tokens * k:
-            out = _experts(operands, routing)
-        else:
+        weights = (w_gate, w_up, w_down)
+        if cfg.exchange_chips > 1 and not self.is_initializing():
+            out, overflow = _exchanged(
+                cfg, tokens.astype(cfg.dtype), gate, expert,
+                counts.astype(jnp.int32), weights,
+            )
             with jax.named_scope("permute"):
-                overflow = counts.sum() > rows
-                stats.sow(self, "overflow", overflow.astype(jnp.float32))
-            with jax.named_scope("experts"):
-                # In the compute dtype before the guard, so that what the
-                # guard hands on is the kernels' own weight gradient.
-                operands = operands[:2] + tuple(
-                    w.astype(cfg.dtype) for w in operands[2:]
-                )
-            operands, wire = _hand_in(operands, routing, overflow)
-            out = _hand_out(
-                _experts(operands, routing, rows), wire, operands, routing,
-                overflow,
+                # The pairs each chip's experts received, and the
+                # (chip, layer-step)s that took all the rows.
+                stats.sow(self, "chip_tokens", counts.reshape(
+                    cfg.exchange_chips, -1
+                ).sum(axis=1))
+                stats.sow(self, "overflow", overflow.sum())
+        else:
+            # Nothing reads what ``init`` computes: no guard and no
+            # exchange to compile there.
+            rows = n_tokens * k if self.is_initializing() else (
+                compact_rows(cfg, n_tokens)
+            )
+            out = _sorted_experts(
+                tokens, gate, expert, counts, weights, first, held, share,
+                rows, cfg.dtype,
+                lambda over: stats.sow(self, "overflow", over),
             )
         if cfg.shared_experts:
             # The same on every share: when the shares' parts are summed
@@ -586,7 +723,10 @@ def report_epoch(sown: dict, n_steps: int) -> None:
     epoch whose held pairs exceeded :func:`compact_rows` and took all
     ``T·k`` rows inside the guard (0 where loads are balanced, and always
     where every expert is held; a reader who sees it rise knows why the
-    step slowed)."""
+    step slowed); over a mesh axis the fullest chip's pairs over the mean
+    chip's and the pairs a step that reached the axis's FIRST chip
+    (``moe/first_chip_pairs_per_step``: the rows its grouped matmuls
+    ran)."""
     import numpy as np
 
     from raydp_tpu.utils.profiling import metrics
@@ -603,29 +743,79 @@ def report_epoch(sown: dict, n_steps: int) -> None:
     metrics.gauge_set(
         "moe/overflow_layer_steps", float(sown.get("overflow", 0.0))
     )
+    if "chip_tokens" in sown:
+        # The step waits for the fullest chip of the experts' axis; the
+        # axis's first chip is the mesh's first device, whose kernels a
+        # device trace of "chip 0" shows.
+        chips = np.asarray(sown["chip_tokens"], np.float64)
+        metrics.gauge_set(
+            "moe/chip_load_max_over_mean", chips.max() / chips.mean()
+        )
+        metrics.gauge_set("moe/first_chip_pairs_per_step", chips[0] / n_steps)
+
+
+def exchange_bytes(cfg: MoEConfig, n_tokens: int) -> int:
+    """Bytes one chip sends plus receives in ONE pass of one layer's
+    exchange over ``n_tokens`` tokens of the whole axis, from the shapes:
+    the gather brings the other chips' ``(n-1)/n · T`` rows in (a token's
+    ``D`` features in the compute dtype, its k gates and k choices, 4
+    bytes each) and sends this chip's ``T/n`` rows to ``n-1`` chips, the
+    reduce-scatter moves as many rows of ``D`` features the other way. A
+    backward pass transposes the two collectives and moves the same. 0
+    without an axis."""
+    n = cfg.exchange_chips
+    if n == 1:
+        return 0
+    rows = 2 * (n - 1) * n_tokens // n            # sent + received
+    row = cfg.d_model * jnp.dtype(cfg.dtype).itemsize
+    return rows * (2 * row + 8 * cfg.top_k)
 
 
 def report(model, tokens_per_step: int) -> None:
-    """Static for a compiled step: three gauges where the step is built
+    """Static for a compiled step: gauges where the step is built
     (as ``models/mamba.report``): the experts the routed layers route over,
-    how many of them this process holds, and ``moe/compact_rows``, the
+    how many of them a chip holds, ``moe/compact_rows``, the
     rows a layer's expert path runs over (:func:`compact_rows`: all
-    ``T·k`` pairs of the step's tokens unless the layers are a share).
-    Zero for a model without a routed layer."""
+    ``T·k`` pairs of the step's tokens unless the layers are a share or lie
+    over a mesh axis), ``moe/exchange_chips`` (the chips of that axis, 1
+    without one) and ``moe/exchange_bytes_per_step``
+    (:func:`exchange_bytes` over the routed layers and a step's passes:
+    forward, backward, and the forward again where blocks are
+    checkpointed). Zero for a model without a routed layer."""
     from raydp_tpu.utils.profiling import metrics
 
     cfg, moe = getattr(model, "cfg", None), getattr(model, "moe", None)
-    if moe is None and "moe" in getattr(cfg, "ffn_kinds", ()):
+    layers = getattr(cfg, "ffn_kinds", ()).count("moe")
+    if moe is None and layers:
         moe = cfg.moe_config()
-    routed, held = (moe.n_experts, moe.held) if moe is not None else (0, 0)
-    rows = compact_rows(moe, tokens_per_step) if moe is not None else 0
+    routed, held, chips, rows, moved = 0, 0, 1, 0, 0
+    if moe is not None:
+        routed, chips = moe.n_experts, moe.exchange_chips
+        held = moe.held // chips
+        # What ONE chip's expert path is: the share it holds.
+        share = dataclasses.replace(moe, held_experts=held, expert_axis=None)
+        rows = compact_rows(share, tokens_per_step)
+        passes = 3 if getattr(cfg, "remat", False) else 2
+        moved = layers * passes * exchange_bytes(moe, tokens_per_step)
     metrics.gauge_set("moe/experts_routed", routed)
     metrics.gauge_set("moe/experts_held", held)
     metrics.gauge_set("moe/compact_rows", rows)
     metrics.gauge_set(
         "moe/shared_experts", moe.shared_experts if moe is not None else 0
     )
-    if held < routed:
+    metrics.gauge_set("moe/exchange_chips", chips)
+    metrics.gauge_set("moe/exchange_bytes_per_step", moved)
+    if chips > 1:
+        logger.info(
+            "routed layers: %d experts over the %d chips of mesh axis %r, "
+            "%d a chip, top-%d; a chip gathers the axis's %d tokens, runs "
+            "its experts over %d of their %d pairs (all of them in a "
+            "layer-step with more on its experts) and the parts are "
+            "reduce-scattered to the tokens' chips",
+            routed, chips, moe.expert_axis, held, moe.top_k,
+            tokens_per_step, rows, tokens_per_step * moe.top_k,
+        )
+    elif held < routed:
         logger.info(
             "routed layers: a share of an expert-parallel deployment, "
             "experts [%d, %d) of %d held here, top-%d over all %d; pairs on "

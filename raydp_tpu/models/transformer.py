@@ -205,6 +205,16 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16        # compute dtype (MXU-friendly)
     param_dtype: Any = jnp.float32
     mesh: Any = None                 # ring/ulysses; flash on >1 device
+    # The axis of ``mesh`` that the model's STATE lies along beside the
+    # batch, ``1 / n`` of it a chip: the routed layers' experts, with the
+    # expert exchange around them (``models/moe.py``), and the rows of
+    # ``tok_embed`` and the columns of an untied ``lm_head``
+    # (:func:`embed_over`, :func:`_logits`). ``JAXEstimator`` reads it
+    # here and puts the logical axis ``vocab`` on it (:func:`vocab_rules`),
+    # which is what keeps the tables and their moments a share a chip at
+    # rest: the layout has no second place to be stated in.
+    # None = whole on every chip, no collective: the program as it was.
+    state_axis: Optional[str] = None
 
     @property
     def head_dim(self) -> int:
@@ -263,8 +273,16 @@ class TransformerConfig:
             normalize_gates=self.norm_top_k, gate_scale=self.routed_scaling,
             first_expert=self.first_expert, held_experts=self.experts_held,
             shared_experts=self.shared_experts,
+            expert_axis=self.state_axis,
+            mesh=self.mesh if self.state_axis is not None else None,
             dtype=self.dtype, param_dtype=self.param_dtype,
         )
+
+    def chips_along(self, axis: Optional[str]) -> int:
+        """Chips of ``mesh`` along ``axis``; 1 without either."""
+        if axis is None or self.mesh is None:
+            return 1
+        return int(self.mesh.shape[axis])
 
 
 def _dense_init(*logical_axes: str):
@@ -346,6 +364,61 @@ def rotary(x, positions, theta: float, yarn: Optional[YarnScaling] = None,
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     ).astype(x.dtype)
+
+
+def embed_over(table, ids, mesh, axis: str):
+    """``table[ids]`` for a ``table`` ``[V, D]`` whose rows lie over the
+    ``n`` chips of ``axis``, ``V / n`` a chip, and ``ids`` ``[B, ...]``
+    whose rows lie over the same chips: inside a ``shard_map`` a chip
+    gathers the ids (4 bytes a token), looks its own rows up for all of
+    them — zeros where a token's row is another chip's — and the parts
+    are reduce-scattered to the chips that own the tokens (scope
+    ``exchange``). The table is never whole on a chip; its gradient is a
+    scatter-add into the chip's own rows."""
+    from jax.sharding import PartitionSpec as P
+
+    n = int(mesh.shape[axis])
+    if table.shape[0] % n or ids.shape[0] % n:
+        raise ValueError(
+            f"{table.shape[0]} rows and {ids.shape[0]} sequences over the "
+            f"{n} chips of axis {axis!r}"
+        )
+    held = table.shape[0] // n
+
+    def chip(table, ids):
+        with jax.named_scope("exchange"):
+            ids = jax.lax.all_gather(ids, axis, axis=0, tiled=True)
+        local = ids - jax.lax.axis_index(axis) * held
+        mine = (local >= 0) & (local < held)
+        rows = jnp.where(
+            mine[..., None], table[jnp.where(mine, local, 0)], 0
+        )
+        with jax.named_scope("exchange"):
+            return jax.lax.psum_scatter(
+                rows, axis, scatter_dimension=0, tiled=True
+            )
+
+    return jax.shard_map(
+        chip, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(axis),
+        check_vma=False,
+    )(table, ids)
+
+
+class TokenEmbed(nn.Embed):
+    """``nn.Embed`` (same parameter, same scope) whose table may lie over
+    a mesh axis: with ``mesh`` the lookup is :func:`embed_over`. Nothing
+    reads what ``init`` computes, so the plain lookup stands there (its
+    one sample row does not divide over the chips)."""
+
+    mesh: Any = None
+    axis: Optional[str] = None
+
+    def __call__(self, inputs):
+        if self.mesh is None or self.is_initializing():
+            return super().__call__(inputs)
+        return embed_over(
+            self.embedding.astype(self.dtype), inputs, self.mesh, self.axis
+        )
 
 
 class MultiHeadAttention(nn.Module):
@@ -578,7 +651,7 @@ class MultiHeadAttention(nn.Module):
             if cfg.mesh is not None:
                 out = sharded_flash_attention(
                     q, k, v, mesh=cfg.mesh, causal=cfg.causal, scale=scale,
-                    window=span,
+                    window=span, scope=self.name,
                 )
             else:
                 out = flash_attention(
@@ -789,12 +862,14 @@ class TransformerEncoder(nn.Module):
             raise NotImplementedError(
                 f"given positions with cfg.positions={cfg.positions!r}"
             )
-        x = nn.Embed(
+        x = TokenEmbed(
             cfg.vocab_size, cfg.d_model,
             embedding_init=_embed_init(
                 "vocab", "embed", std=cfg.embed_init_std
             ),
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="tok_embed",
+            mesh=cfg.mesh if cfg.chips_along(cfg.state_axis) > 1 else None,
+            axis=cfg.state_axis,
         )(input_ids)
         if cfg.embedding_multiplier != 1.0:
             # Under the embedding's scope, for the same reason as the
@@ -929,6 +1004,28 @@ def _logits(lm: "CausalLM", h):
             lm.encoder.get_variable("params", "tok_embed")
         )["embedding"]
         logits = lm.lm_head(h, table)
+    elif cfg.chips_along(cfg.state_axis) > 1 and not lm.is_initializing():
+        # The head's columns lie over ``state_axis`` as the batch does: a
+        # chip gathers the axis's tokens ([B, S, D], under ``lm_head/
+        # gather``) and computes its own columns of every token's logits,
+        # which stay where they were computed. A cross-entropy that
+        # reduces over the vocabulary by max and sum
+        # (``train/losses.lm_crossentropy``) then needs two numbers a
+        # token from the other chips, the head's weight gradient none,
+        # and the tokens' gradient is reduce-scattered to their chips.
+        # Gathering the head instead would move ``D·V`` floats a step
+        # three times (forward, backward, the gradient's reduce-scatter)
+        # and hold a whole table and a whole gradient beside the logits.
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        with jax.named_scope("lm_head"), jax.named_scope("gather"):
+            h = jax.lax.with_sharding_constraint(
+                h, NamedSharding(cfg.mesh, P())
+            )
+        logits = jax.lax.with_sharding_constraint(
+            lm.lm_head(h),
+            NamedSharding(cfg.mesh, P(None, None, cfg.state_axis)),
+        )
     else:
         logits = lm.lm_head(h)
     if cfg.logits_scaling != 1.0:
@@ -1344,6 +1441,45 @@ def keye_vl_2_0_30b_a3b(**overrides) -> TransformerConfig:
     return TransformerConfig(**defaults)
 
 
+def mellum2_12b_a2_5b(**overrides) -> TransformerConfig:
+    """JetBrains Mellum2-12B-A2.5B-Instruct (12.15B parameters, about 2.5B
+    active; ``config.json`` of JetBrains/Mellum2-12B-A2.5B-Instruct,
+    ``model_type`` mellum): 28 pre-norm layers of width 2304; grouped-query
+    attention, 32 query heads over 4 key-value heads of 128, the whole head
+    rotated at theta 500,000, no QK-norm; every fourth layer from layer 3
+    attends over all earlier positions under YaRN x 16 over 8,192 (beta 32
+    / 1, the rotation times 1.27726), the other three over the last 1,024
+    positions with the plain frequencies; 64 SwiGLU experts of width 896
+    in every layer, 8 a token by softmax probability, renormalised, no
+    shared expert, no selection bias, no auxiliary loss; RMSNorm 1e-6, no
+    biases; vocabulary 98304, untied head. The model's stated deployment
+    shares a layer among four chips: ``mesh`` with ``state_axis`` lays the
+    64 experts over that axis, 16 a chip, with the exchange around them,
+    and the two vocabulary tables, a quarter a chip (``JAXEstimator``
+    keeps them there at rest); ``n_layers`` keeps the model's own first
+    layers."""
+    n_layers = overrides.get("n_layers", 28)
+    defaults = dict(
+        vocab_size=98304, d_model=2304, n_heads=32, n_kv_heads=4,
+        head_size=128, n_layers=n_layers, d_ff=7168, max_len=131072,
+        dropout_rate=0.0, causal=True, norm="rmsnorm", norm_eps=1e-6,
+        positions="rotary", rope_theta=500000.0,
+        rope_yarn=YarnScaling(
+            factor=16.0, original_max_len=8192, beta_fast=32.0,
+            beta_slow=1.0, attention_factor=1.2772588722239782,
+        ),
+        window=WindowConfig(window=1024, n_heads=32, rope_theta=500000.0),
+        qk_norm=False, use_bias=False, ffn="moe", n_experts=64, top_k=8,
+        d_expert=896, router_scoring="softmax", norm_top_k=True,
+        moe_loss_weights=(0.0, 0.0), tie_head=False,
+        layer_types=tuple(
+            "attention" if i % 4 == 3 else "window" for i in range(n_layers)
+        ),
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
 def tiny_transformer(**overrides) -> TransformerConfig:
     """Small MXU-aligned config for tests/dry runs (widths still /128)."""
     defaults = dict(
@@ -1386,3 +1522,15 @@ def effective_rules(mesh, rules=LOGICAL_RULES):
         (logical, axis if axis in mesh.axis_names else None)
         for logical, axis in rules
     ]
+
+
+def vocab_rules(axis: Optional[str], rules=LOGICAL_RULES):
+    """``rules`` with the logical axis ``vocab`` on mesh axis ``axis``, so
+    that ``tok_embed``'s rows, an untied ``lm_head``'s columns and their
+    optimizer moments lie over that axis at rest. ``JAXEstimator`` applies
+    it to its rules for a model whose configuration names a
+    ``state_axis`` (the model computes with the tables there)."""
+    return tuple(
+        (logical, axis if logical == "vocab" else mesh_axis)
+        for logical, mesh_axis in rules
+    )

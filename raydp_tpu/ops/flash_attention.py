@@ -104,6 +104,7 @@ it explicitly to check the math on the CPU mesh; no model code does.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import math
@@ -931,6 +932,7 @@ def sharded_flash_attention(
     interpret: bool = False,
     scale: Optional[float] = None,
     window: Optional[int] = None,
+    scope: Optional[str] = None,
 ) -> jnp.ndarray:
     """:func:`flash_attention` on a device mesh.
 
@@ -942,17 +944,27 @@ def sharded_flash_attention(
     on every device (sequence parallelism is ring attention's job). A
     dimension its mesh axis does not divide (the batch-1 sample of
     ``model.init``) stays whole as well; heads are split only where ``tp``
-    divides the key-value heads, so a group stays on one device."""
+    divides the key-value heads, so a group stays on one device.
+
+    ``scope`` (the calling module's name) is opened again inside the
+    ``shard_map``, whose own name would otherwise stand between the module
+    and the kernels in an operation's scope path
+    (``attn/shard_map/jit(flash_attention)``): a trace finds the kernels
+    under ``<scope>/jit(flash_attention)`` on a mesh as on one chip."""
     def axis(name, size):
         n = mesh.shape.get(name, 1)
         return name if n > 1 and size % n == 0 else None
 
+    def attend(q, k, v):
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            return flash_attention(
+                q, k, v, causal=causal, interpret=interpret, scale=scale,
+                window=window,
+            )
+
     spec = P(axis("dp", q.shape[0]), None, axis("tp", k.shape[2]), None)
     return jax.shard_map(
-        functools.partial(
-            flash_attention, causal=causal, interpret=interpret, scale=scale,
-            window=window,
-        ),
+        attend,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

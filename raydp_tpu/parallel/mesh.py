@@ -11,9 +11,20 @@ a virtual CPU mesh for tests:
   * ``sp`` — sequence/context parallel (ring attention over this axis)
   * ``tp`` — tensor parallel (weight shards; activations all-gather/psum)
 
-Expert parallelism (``ep``) reuses the ``dp`` axis: experts are sharded
-across data-parallel groups (see raydp_tpu/models/moe.py), the standard
-layout when expert count is a multiple of dp size.
+Expert parallelism has no axis of its own. The logical axis ``expert``
+lies on ``dp`` (``models/transformer.LOGICAL_RULES``), so on a ``dp`` mesh
+a routed layer's stacked expert weights and their optimizer moments are
+sharded over the data-parallel chips AT REST. What the layer computes with
+them is the model's choice: told the axis (``TransformerConfig.state_axis``,
+``MoEConfig.expert_axis`` with the mesh), the layer runs its expert
+exchange inside a ``shard_map`` over that axis and every chip works on its
+own ``n_experts / n`` experts (``models/moe.py:_exchanged``); told nothing,
+the layer is one chip's program and XLA gathers every expert onto every
+chip around the Mosaic grouped matmul, which it cannot partition. The
+vocabulary's tables lie over the same ``state_axis``: the model computes
+with them there, and ``JAXEstimator`` reads the axis from the model's
+configuration and keeps them there at rest
+(``models/transformer.vocab_rules``).
 """
 from __future__ import annotations
 

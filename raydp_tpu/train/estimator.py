@@ -267,10 +267,16 @@ class JAXEstimator:
         # reachable straight from fit() (VERDICT r1 weak-point 1). Models
         # without metadata replicate, exactly as before.
         self.shard_params = shard_params
-        if logical_rules is None:
-            from raydp_tpu.models.transformer import LOGICAL_RULES
+        from raydp_tpu.models.transformer import LOGICAL_RULES, vocab_rules
 
+        if logical_rules is None:
             logical_rules = LOGICAL_RULES
+        # A model that computes with its vocabulary tables over a mesh
+        # axis says so in its own configuration, and they lie there at
+        # rest: the one place the layout is stated in.
+        state_axis = getattr(getattr(model, "cfg", None), "state_axis", None)
+        if state_axis is not None:
+            logical_rules = vocab_rules(state_axis, logical_rules)
         self.logical_rules = list(logical_rules)
 
         # Compile accounting from the first program on (the init program

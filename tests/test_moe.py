@@ -232,3 +232,197 @@ def test_moe_classifier_through_estimator(eight_cpu_devices):
     # the sown collections were stripped from trainable state
     assert "losses" not in est._state.params
     assert STATS not in est._state.params
+
+
+# --------------------------------------------- the exchange on a mesh axis
+
+CHIPS = 4
+
+
+def _skewed(cfg, x, params):
+    """The layer's parameters with the router pushed toward chip 1's
+    experts (a quarter of them): most pairs land on one chip."""
+    held = cfg.n_experts // CHIPS
+    push = np.zeros((cfg.d_model, cfg.n_experts), np.float32)
+    push[:, held:2 * held] = np.abs(
+        np.asarray(x).mean(axis=tuple(range(x.ndim - 1)))
+    )[:, None] * 4.0
+    router = params["params"]["router"]["kernel"] + jnp.sign(
+        jnp.asarray(x).mean(axis=tuple(range(x.ndim - 1)))
+    )[:, None] * push
+    return {"params": {**params["params"], "router": {"kernel": router}}}
+
+
+def _loss_and_grads(layer, params, x):
+    def loss(p, x):
+        y, mut = layer.apply(p, x, mutable=[STATS])
+        weight = jnp.cos(jnp.arange(y.size, dtype=jnp.float32)).reshape(
+            y.shape
+        )
+        return jnp.sum(y * weight), (y, mut[STATS])
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(params, x)
+
+
+@pytest.fixture(scope="module")
+def exchanged(eight_cpu_devices):
+    """One layer of 16 experts, 4 a token, on one device and over the four
+    chips of ``dp`` (4 experts a chip), under a routing skewed enough that
+    chip 1's pairs exceed ``compact_rows`` (patched to 128 of 512 pairs,
+    the uniform share): outputs, gradients and statistics of both."""
+    import dataclasses
+    from unittest import mock
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from raydp_tpu.models import moe
+
+    mesh = MeshSpec(dp=CHIPS).build()
+    whole = tiny_moe(
+        n_experts=16, top_k=4, normalize_gates=True, aux_loss_weight=0.0,
+        z_loss_weight=0.0,
+    )
+    over = dataclasses.replace(whole, expert_axis="dp", mesh=mesh)
+    x = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (CHIPS, 32, whole.d_model)
+    ).astype(np.float32)) + 0.5
+    params = _skewed(whole, x, _init(MoELayer(whole), x))
+    want = _loss_and_grads(MoELayer(whole), params, x)
+    spread = {"params": {
+        name: jax.device_put(leaf, NamedSharding(
+            mesh, P("dp") if name.startswith("w_") else P()
+        )) for name, leaf in params["params"].items()
+    }}
+    xd = jax.device_put(x, NamedSharding(mesh, P("dp")))
+
+    def rows(cfg, n_tokens):
+        pairs = n_tokens * cfg.top_k
+        return pairs if cfg.held == cfg.n_experts else pairs // CHIPS
+
+    with mock.patch.object(moe, "compact_rows", rows):
+        got = _loss_and_grads(MoELayer(over), spread, xd)
+        # The guide's tie: each chip's share layer alone, on one device.
+        shares = [
+            MoELayer(dataclasses.replace(
+                whole, first_expert=c * 4, held_experts=4
+            )).apply(
+                {"params": {
+                    name: leaf[c * 4:(c + 1) * 4] if name.startswith("w_")
+                    else leaf for name, leaf in params["params"].items()
+                }}, x, mutable=[STATS],
+            )[0] for c in range(CHIPS)
+        ]
+    return {"want": want, "got": got, "shares": shares}
+
+
+def test_the_exchange_drops_no_pair_under_skew(exchanged):
+    """(ii) The layer over the mesh axis equals the one-device layer with
+    every expert, forward, while one chip's pairs exceed its compact rows:
+    the guard's branch is taken there (and only there) and no pair is
+    lost."""
+    (_, (want, _)), (_, (got, stats)) = exchanged["want"], exchanged["got"]
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
+    )
+    chips = np.asarray(stats["chip_tokens"])
+    assert chips.sum() == 4 * 32 * 4 and chips[1] > 128 > chips.max(
+        initial=0, where=np.arange(CHIPS) != 1
+    )
+    assert float(stats["overflow"]) == 1.0
+    np.testing.assert_array_equal(
+        chips, np.asarray(stats["expert_tokens"]).reshape(CHIPS, -1).sum(1)
+    )
+
+
+@pytest.mark.parametrize(
+    "leaf", ["router", "w_gate", "w_up", "w_down", "tokens"]
+)
+def test_the_exchange_gives_the_one_device_gradients(exchanged, leaf):
+    """(ii) Every gradient through the two collectives and the guard's
+    overflow branch equals the one-device layer's."""
+    (want_p, want_x), _ = exchanged["want"]
+    (got_p, got_x), _ = exchanged["got"]
+    want, got = (want_x, got_x) if leaf == "tokens" else (
+        jax.tree_util.tree_leaves(want_p["params"][leaf])[0],
+        jax.tree_util.tree_leaves(got_p["params"][leaf])[0],
+    )
+    assert float(jnp.max(jnp.abs(want))) > 1e-3
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-4
+    )
+
+
+def test_the_four_chips_parts_add_up_to_the_uncut_layer(exchanged):
+    """(iii) What each chip's share layer gives alone sums to the uncut
+    layer, which is what the reduce-scatter adds up."""
+    _, (want, _) = exchanged["want"]
+    np.testing.assert_allclose(
+        np.asarray(sum(exchanged["shares"])), np.asarray(want),
+        atol=2e-5, rtol=2e-5,
+    )
+    assert float(jnp.max(jnp.abs(exchanged["shares"][0] - want))) > 1e-2
+
+
+# sha256 and length of ``str(jax.make_jaxpr(grad of the layer's sum))`` as
+# the PARENT of PR 53 (commit e58c280) printed it for the two
+# configurations below, by this same function: with no axis named the
+# layer traces to the program it was.
+PARENT_JAXPRS = {
+    "whole": (188756, "ca557149f039b8c526c3f30843b797f349f9cac7bf62aa2c897fb7"
+                      "4dc8c374ee"),
+    "share": (382419, "94dd04298fd401dfc9b30541e44612229e4076fb07b511cac9071a"
+                      "b06d1e8633"),
+}
+
+
+@pytest.mark.parametrize("which", sorted(PARENT_JAXPRS))
+def test_without_an_axis_the_layer_traces_to_the_parents_program(which):
+    """(v) One whole configuration and one share (compact rows patched to
+    32 of its 128 pairs, so the guard is in the program)."""
+    import hashlib
+    from unittest import mock
+
+    from raydp_tpu.models import moe
+
+    cfg = {
+        "whole": tiny_moe(n_experts=8, top_k=2),
+        "share": tiny_moe(
+            n_experts=8, top_k=2, first_expert=2, held_experts=2,
+            normalize_gates=True, dtype=jnp.bfloat16,
+        ),
+    }[which]
+    layer = MoELayer(cfg)
+    x = jnp.zeros((2, 32, 32), jnp.float32)
+    variables = jax.eval_shape(
+        lambda: nn.unbox(layer.init(jax.random.PRNGKey(0), x))
+    )
+
+    def loss(v, x):
+        y, _ = layer.apply(v, x, mutable=[STATS, "losses"])
+        return jnp.sum(y.astype(jnp.float32))
+
+    def rows(cfg, n_tokens):
+        return n_tokens * cfg.top_k if cfg.held == cfg.n_experts else 32
+
+    with mock.patch.object(moe, "compact_rows", rows):
+        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+            variables, x
+        ))
+    assert (len(text), hashlib.sha256(text.encode()).hexdigest()) == (
+        PARENT_JAXPRS[which]
+    )
+
+
+def test_the_exchange_wants_every_expert_and_an_even_split(
+    eight_cpu_devices,
+):
+    import dataclasses
+
+    mesh = MeshSpec(dp=CHIPS).build()
+    for bad in (dict(held_experts=2), dict(n_experts=6, top_k=2)):
+        cfg = dataclasses.replace(
+            tiny_moe(n_experts=8, top_k=2, expert_axis="dp", mesh=mesh),
+            **bad,
+        )
+        with pytest.raises(ValueError, match="chips of axis"):
+            cfg.exchange_chips
+    assert tiny_moe(n_experts=8, top_k=2).exchange_chips == 1
