@@ -47,7 +47,15 @@ the published OLMoE block (Muennighoff et al. 2024, arXiv:2409.02060):
   instead, forward and backward, inside a ``lax.cond`` that no other
   layer-step enters (:func:`_hand_in`; counted in ``overflow``). A pair
   sorted behind the ``C`` rows reads the last of them and is masked where
-  rows go back to tokens. A share on one chip runs without its
+  rows go back to tokens. The compact path's two token-side sums — the
+  way back to tokens, forward, and the cotangent of the way there,
+  backward — do not gather ``T·k`` rows for the share that is live: the
+  sort is stable, so a held expert's rows of one token tile are one
+  contiguous run of the ``C`` rows, and ``ops/rows_to_tokens.py`` sums
+  the runs where they lie (the same float32 products in another order;
+  up to three held experts for each of a token's k, by the kernel's own
+  rule: its time goes with the experts held, the gather's with the
+  pairs). A share on one chip runs without its
   exchange: nothing stands in for the absent chips.
 * The exchange, where the whole group of chips is here
   (``expert_axis`` names the axis of ``mesh`` the experts lie along, ``n``
@@ -90,6 +98,7 @@ from raydp_tpu.ops.grouped_matmul import (
     TILING,
     grouped_matmul,
 )
+from raydp_tpu.ops.rows_to_tokens import pays, rows_to_tokens
 
 __all__ = [
     "MoEConfig",
@@ -206,7 +215,7 @@ def _expert_init(*logical_axes: str):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def take_rows(x, perm, inverse, fan: int = 1, live=None):
+def take_rows(x, perm, inverse, fan: int = 1, live=None, runs=None):
     """``x[perm // fan]`` for a permutation ``perm`` of ``range(len(x) *
     fan)`` with inverse ``inverse``: every row of ``x`` goes to ``fan``
     places. The transpose of a permutation is its inverse, so the
@@ -215,30 +224,35 @@ def take_rows(x, perm, inverse, fan: int = 1, live=None):
     ``live`` ``[len(x), fan]`` (a share's layer) says which copies anyone
     computed on: the cotangent of the others is not read. A share's
     ``perm`` is the first ``C`` places of the permutation and ``inverse``
-    never points behind them: ``C`` rows come out, and ``g`` has ``C``."""
+    never points behind them: ``C`` rows come out, and ``g`` has ``C``.
+    ``runs`` (:func:`_experts`, a share's compact path) has the
+    cotangent summed from ``g``'s own ``C`` rows, float32 inside, with no
+    gather of ``len(x) * fan`` rows."""
     return x[perm // fan] if fan > 1 else x[perm]
 
 
-def _take_rows_fwd(x, perm, inverse, fan, live):
-    return take_rows(x, perm, inverse, fan, live), (inverse, live)
+def _take_rows_fwd(x, perm, inverse, fan, live, runs):
+    return take_rows(x, perm, inverse, fan, live, runs), (inverse, live, runs)
 
 
 def _take_rows_bwd(fan, res, g):
-    inverse, live = res
+    inverse, live, runs = res
+    if runs is not None:
+        return rows_to_tokens(g, None, *runs), None, None, None, None
     gx = g[inverse]
     if fan > 1 or live is not None:
         gx = gx.reshape(-1, fan, gx.shape[-1])
         if live is not None:
             gx = jnp.where(live[..., None], gx, 0)
         gx = gx.sum(axis=1)
-    return gx, None, None, None
+    return gx, None, None, None, None
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 @jax.custom_vjp
-def combine_rows(rows, gate, order, inverse, live=None):
+def combine_rows(rows, gate, order, inverse, live=None, runs=None):
     """Expert-ordered ``rows`` ``[T·k, D]`` back to their tokens: token
     t's output is the sum over its k pairs of ``gate[t, j]`` times the
     pair's row, float32 inside. The cotangents need no row of a ``T·k``
@@ -247,7 +261,12 @@ def combine_rows(rows, gate, order, inverse, live=None):
     share's layer) says which pairs have a row that was computed: the
     others add nothing and their gates get no cotangent. A share hands
     ``rows`` ``[C, D]`` with the first ``C`` places of ``order`` and an
-    ``inverse`` that never points behind them."""
+    ``inverse`` that never points behind them; with ``runs``
+    (:func:`_experts`, a share's compact path) the same products are
+    summed from the ``C`` rows as they lie, and no ``[T·k, D]`` array is
+    formed."""
+    if runs is not None:
+        return rows_to_tokens(rows, gate, *runs)
     t, k = gate.shape
     pairs = rows[inverse].reshape(t, k, rows.shape[-1])
     if live is not None:
@@ -257,8 +276,8 @@ def combine_rows(rows, gate, order, inverse, live=None):
     ).astype(rows.dtype)
 
 
-def _combine_rows_fwd(rows, gate, order, inverse, live):
-    return combine_rows(rows, gate, order, inverse, live), (
+def _combine_rows_fwd(rows, gate, order, inverse, live, runs):
+    return combine_rows(rows, gate, order, inverse, live, runs), (
         rows, gate, order, inverse, live
     )
 
@@ -274,7 +293,7 @@ def _combine_rows_bwd(res, g):
         d_gate = jnp.where(live, d_gate, 0)
     return (
         d_rows.astype(rows.dtype), d_gate.astype(gate.dtype),
-        None, None, None,
+        None, None, None, None,
     )
 
 
@@ -305,13 +324,20 @@ def _experts(operands, routing, rows: Optional[int] = None):
     ``rows`` rows, and group sizes that sum to more are cut there."""
     tokens, gate, w_gate, w_up, w_down = operands
     order, inverse, group_sizes, live = routing
+    runs = None
     with jax.named_scope("permute"):
         if rows is not None and rows < order.shape[0]:
+            place = inverse
             order = order[:rows]
             inverse = jnp.minimum(inverse, rows - 1)
             ends = jnp.minimum(jnp.cumsum(group_sizes), rows)
             group_sizes = jnp.diff(ends, prepend=0)
-        x = take_rows(tokens, order, inverse, gate.shape[1], live)
+            if live is not None and pays(len(group_sizes), gate.shape[1]):
+                # The two token-side sums read the ``rows`` rows as they
+                # lie (``ops/rows_to_tokens.py``): each live pair's place,
+                # and where the held experts' groups end.
+                runs = (jnp.where(live, place.reshape(live.shape), -1), ends)
+        x = take_rows(tokens, order, inverse, gate.shape[1], live, runs)
     with jax.named_scope("experts"):
         w_gate, w_up, w_down = (
             w.astype(tokens.dtype) for w in (w_gate, w_up, w_down)
@@ -321,7 +347,7 @@ def _experts(operands, routing, rows: Optional[int] = None):
         ) * grouped_matmul(x, w_up, group_sizes)
         y = grouped_matmul(h, w_down, group_sizes)
     with jax.named_scope("unpermute"):
-        return combine_rows(y, gate, order, inverse, live)
+        return combine_rows(y, gate, order, inverse, live, runs)
 
 
 # A share's guard. The compact path ``_experts(..., rows=C)`` stands in the
@@ -778,7 +804,16 @@ def report(model, tokens_per_step: int) -> None:
     rows a layer's expert path runs over (:func:`compact_rows`: all
     ``T·k`` pairs of the step's tokens unless the layers are a share or lie
     over a mesh axis), ``moe/exchange_chips`` (the chips of that axis, 1
-    without one) and ``moe/exchange_bytes_per_step``
+    without one), ``moe/token_sum_rows``, the rows of the expert-ordered
+    array that a layer-pass's two token-side sums read (the way back to
+    tokens, forward; the cotangent of the way there, backward: the ``C``
+    compact rows where ``ops/rows_to_tokens.py`` runs them, all ``T·k``
+    pairs where a gather does), ``moe/token_sum_layers``, the routed layers
+    in which the kernel runs them (0 where every expert is held, and where
+    a chip holds more than three experts for each of a token's: the
+    kernel's time goes with the experts held,
+    ``ops/rows_to_tokens.pays``), and
+    ``moe/exchange_bytes_per_step``
     (:func:`exchange_bytes` over the routed layers and a step's passes:
     forward, backward, and the forward again where blocks are
     checkpointed). Zero for a model without a routed layer."""
@@ -788,7 +823,7 @@ def report(model, tokens_per_step: int) -> None:
     layers = getattr(cfg, "ffn_kinds", ()).count("moe")
     if moe is None and layers:
         moe = cfg.moe_config()
-    routed, held, chips, rows, moved = 0, 0, 1, 0, 0
+    routed, held, chips, rows, moved, summed, sum_rows = 0, 0, 1, 0, 0, 0, 0
     if moe is not None:
         routed, chips = moe.n_experts, moe.exchange_chips
         held = moe.held // chips
@@ -797,9 +832,16 @@ def report(model, tokens_per_step: int) -> None:
         rows = compact_rows(share, tokens_per_step)
         passes = 3 if getattr(cfg, "remat", False) else 2
         moved = layers * passes * exchange_bytes(moe, tokens_per_step)
+        # Where :func:`_experts` hands both sums ``runs``.
+        pairs = tokens_per_step * moe.top_k
+        summed, sum_rows = (layers, rows) if (
+            rows < pairs and pays(held, moe.top_k)
+        ) else (0, pairs)
     metrics.gauge_set("moe/experts_routed", routed)
     metrics.gauge_set("moe/experts_held", held)
     metrics.gauge_set("moe/compact_rows", rows)
+    metrics.gauge_set("moe/token_sum_rows", sum_rows)
+    metrics.gauge_set("moe/token_sum_layers", summed)
     metrics.gauge_set(
         "moe/shared_experts", moe.shared_experts if moe is not None else 0
     )

@@ -523,10 +523,14 @@ def test_the_compact_path_keeps_its_place_in_the_part_rules(
     assert 'loc("pallas_call"' in text
     names |= {n + "/pallas_call" for n in names
               if re.search(r"/jit\(t?gmm\)$", n)}
+    # ``ops/rows_to_tokens.py``'s kernel, inside its ``jit`` as the
+    # grouped matmuls are inside theirs.
+    sums = {n for n in names if n.endswith("/jit(_token_sums)")}
     routed = {n for n in names if re.search(r"/block_\d+/moe/", n)}
     guard = {n for n in routed if "/moe/cond/branch_" in n}
     path = routed - guard
     assert not [n for n in names - guard if "cond/branch_" in n]
+    assert sums and sums <= path
     # The guard holds a whole expert path of its own, forward and backward,
     # and none of it counts as a row move or a kernel of the step.
     for op in ("gather", "jit(gmm)/pallas_call", "jit(tgmm)/pallas_call"):
@@ -541,16 +545,74 @@ def test_the_compact_path_keeps_its_place_in_the_part_rules(
     # back to tokens is not needed again) and the backward.
     forward, again, backward = (
         "/encoder/block_", "/rematted_computation/block_", "/checkpoint/block_")
+    # Since PR 54 the way back to tokens (forward) and the cotangent of the
+    # way there (backward) are ``ops/rows_to_tokens.py``'s kernel, a row
+    # move like the gathers it took the place of.
+    assert {part_of(n) for n in sums} == {"moe_permute"}
     for found, op, passes in (
         (kernels, "jit(gmm)", (forward, again, backward)),
         (kernels, "jit(tgmm)", (backward,)),
-        (gathers, "/moe/permute/", (forward, again, backward)),
-        (gathers, "/moe/unpermute/", (forward, backward)),
+        (gathers, "/moe/permute/", (forward, again)),
+        (gathers, "/moe/unpermute/", (backward,)),
+        (sums, "/moe/permute/", (backward,)),
+        (sums, "/moe/unpermute/", (forward,)),
     ):
         assert {p for p in (forward, again, backward)
                 if [n for n in found if op in n and p in n]} == set(passes), op
     assert {part_of(n) for n in path} == {
         "moe_permute", "moe_gmm", "moe_rest"}
+
+
+def test_every_part_rule_counts_the_token_sums_as_row_moves(tiny, monkeypatch):
+    """The kernel's calls as a step names them (the forward, the forward
+    run again inside a checkpointed block, the backward; a chip's share
+    inside the exchange's ``shard_map`` too) against every
+    ``benchmark/parts/*.json`` that splits the routed layer, read as the
+    files are: wherever a file would count a grouped matmul of the same
+    layer and pass as ``moe_gmm`` it counts the token sum as
+    ``moe_permute``, so ``moe.permute_ms`` keeps reading the row moves and
+    ``moe.grouped_matmul_roofline`` its kernels alone."""
+    import glob
+
+    model, variables, ids = tiny
+    pairs = ids.size * SIZES["num_experts_per_tok"]
+    monkeypatch.setattr(moe_module, "compact_rows", lambda cfg, n: pairs // 2)
+    text = jax.jit(jax.grad(
+        lambda v: lm_crossentropy(_logits(model, v, ids), ids)
+    )).lower(variables).as_text(debug_info=True)
+    found = {n[:-len("/jit(_token_sums)")]
+             for n in re.findall(r'loc\("(jit\([^"]+)"', text)
+             if n.endswith("/jit(_token_sums)")}
+    forward = {n for n in found if "/checkpoint/" not in n}
+    assert {n.rsplit("/", 1)[1] for n in forward} == {"unpermute"}
+    assert {n.rsplit("/", 1)[1] for n in found - forward} == {"permute"}
+    again = {n.replace("/encoder/", "/encoder/checkpoint/"
+                       "rematted_computation/") for n in forward}
+    scopes = found | again
+    scopes |= {n.replace("/moe/", "/moe/shard_map/") for n in scopes}
+    files = 0
+    for path in sorted(glob.glob(
+            os.path.join(REPO, "benchmark", "parts", "*.json"))):
+        with open(path) as f:
+            rules = [(re.compile(a), b) for a, b in json.load(f)]
+        if not {"moe_permute", "moe_gmm"} <= {part for _, part in rules}:
+            continue
+        files += 1
+
+        def part_of(name):
+            return next((p for rule, p in rules if rule.search(name)), "rest")
+
+        seen = 0
+        for scope in scopes:
+            twin = scope.rsplit("/", 1)[0] + "/experts/jit(gmm)/pallas_call"
+            if part_of(twin) != "moe_gmm":
+                continue        # a pass or a wrapper this file's model lacks
+            seen += 1
+            for call in ("/pallas_call", "/rows_to_tokens"):
+                assert part_of(scope + "/jit(_token_sums)" + call) == (
+                    "moe_permute"), (path, scope)
+        assert seen, path
+    assert files >= 8
 
 
 def test_the_selection_bias_is_drawn_to_balance_the_init_sample():

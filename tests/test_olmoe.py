@@ -642,8 +642,38 @@ def test_a_share_of_the_routed_layer_compiles_at_lfm2s_widths(
     assert compact_rows(layer.cfg, 8192) == 12288
     kernels = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
     path = [k for k in kernels if "/cond/branch_" not in k]
-    assert len(path) == 9 and len(kernels) > len(path)
+    # ... and since PR 54 the two token-side sums read those rows too.
+    sums = [k for k in path if "rows_to_tokens" in k]
+    assert len(sums) == 2 and len(path) == 11 and len(kernels) > len(path)
     assert all("[12288," in k and "[32768," not in k for k in path)
+
+
+def test_a_shares_token_sums_compile_for_the_chip_at_mellum2s_shapes(
+    one_chip, monkeypatch
+):
+    """16,384 tokens of the group, 8 pairs a token, the 16 experts of 64
+    that a chip holds, 49,152 of 131,072 rows of width 2,304: Mosaic takes
+    the kernel (the runs' copies from HBM at a row offset read from SMEM,
+    a selection multiplied from its transposed side) with the gates and
+    without, at the tiles the shapes give."""
+    from raydp_tpu.ops import rows_to_tokens as op
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert op.tiles(16384, 49152, 16) == (512, 112)
+    assert op.tiles(16384, 24576, 16) == (512, 64)      # SDAR, Keye
+    assert op.pays(16, 8) and not op.pays(32, 8)        # Laguna: the gather
+    shapes = _on(one_chip, (
+        jax.ShapeDtypeStruct((49152, 2304), jnp.bfloat16),
+        jax.ShapeDtypeStruct((16384, 8), jnp.float32),
+        jax.ShapeDtypeStruct((16384, 8), jnp.int32),
+        jax.ShapeDtypeStruct((16,), jnp.int32),
+    ))
+    for gated in (True, False):
+        compiled = jax.jit(lambda src, gate, place, ends: op.rows_to_tokens(
+            src, gate if gated else None, place, ends)).lower(*shapes).compile()
+        hlo = compiled.as_text()
+        assert hlo.count("tpu_custom_call") == 1 and "gather(" not in hlo
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
 def test_grouped_flash_kernels_compile_at_lfm2s_sequence(one_chip):
