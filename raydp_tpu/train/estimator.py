@@ -28,20 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from raydp_tpu import fault as _fault
 from raydp_tpu.data.ml_dataset import MLDataset
-from raydp_tpu.models import (
-    blockdiff,
-    dropout,
-    hyperconn,
-    kda,
-    latent,
-    mamba,
-    moe,
-    shortconv,
-    sparse_index,
-    stats,
-)
-from raydp_tpu.models import window as window_mixer
-from raydp_tpu.ops.flash_attention import report as report_flash_tiles
+from raydp_tpu.models import step as model_step
 from raydp_tpu.parallel.mesh import MeshSpec
 from raydp_tpu.telemetry import accounting as _acct
 from raydp_tpu.telemetry import events as _events
@@ -50,6 +37,7 @@ from raydp_tpu.telemetry.device_profiler import AnomalySentinel
 from raydp_tpu.telemetry import flight_recorder as _flight
 from raydp_tpu.telemetry import overlap as _overlap
 from raydp_tpu.telemetry import watchdog as _watchdog
+from raydp_tpu.train import rowsparse
 from raydp_tpu.train.losses import resolve_loss, resolve_metric
 from raydp_tpu.utils import profiling as _profiling
 
@@ -267,17 +255,7 @@ class JAXEstimator:
         # reachable straight from fit() (VERDICT r1 weak-point 1). Models
         # without metadata replicate, exactly as before.
         self.shard_params = shard_params
-        from raydp_tpu.models.transformer import LOGICAL_RULES, vocab_rules
-
-        if logical_rules is None:
-            logical_rules = LOGICAL_RULES
-        # A model that computes with its vocabulary tables over a mesh
-        # axis says so in its own configuration, and they lie there at
-        # rest: the one place the layout is stated in.
-        state_axis = getattr(getattr(model, "cfg", None), "state_axis", None)
-        if state_axis is not None:
-            logical_rules = vocab_rules(state_axis, logical_rules)
-        self.logical_rules = list(logical_rules)
+        self.logical_rules = model_step.logical_rules(model, logical_rules)
 
         # Compile accounting from the first program on (the init program
         # is the dearest of a warm start): every trace, lowering and
@@ -342,16 +320,7 @@ class JAXEstimator:
         model, tx = self._model, self._tx
 
         def create(rng, sample):
-            variables = model.init(rng, sample)
-            # Output collections sown during init (MoE aux losses,
-            # intermediates) are NOT parameters — keeping them would
-            # feed them to the optimizer as trainables.
-            if isinstance(variables, dict):
-                variables = {
-                    k: v
-                    for k, v in variables.items()
-                    if k not in ("losses", "intermediates", stats.STATS)
-                }
+            variables = model_step.parameters(model.init(rng, sample))
             return TrainState.create(
                 apply_fn=model.apply, params=variables, tx=tx
             )
@@ -414,34 +383,19 @@ class JAXEstimator:
         stream loop sums it on the device and fetches it with the epoch's
         loss."""
         loss_fn = self._loss_fn
-        takes_deterministic = self._model_takes_deterministic()
         use_aux = self.aux_losses
-
-        step_rngs = self._step_rngs()
-
-        def apply_kwargs(rng):
-            # ``rng`` is the step's key of the threefry chain; the masks
-            # come from the chip's bit generator (``models/dropout.py``).
-            # A model that draws more than dropout's masks in its step
-            # names the collections (``step_rngs``: block diffusion's
-            # ``noise``) and gets a key each, a function of ``rng`` too.
-            if not takes_deterministic:
-                return {}
-            rngs = {"dropout": dropout.key_for(rng)}
-            for i, name in enumerate(step_rngs):
-                rngs[name] = dropout.key_for(jax.random.fold_in(rng, i + 1))
-            return dict(deterministic=False, rngs=rngs)
+        apply_kwargs = functools.partial(model_step.apply_kwargs, self._model)
 
         def loss_of(state: TrainState, variables, x, y, rng):
             target = y if y is not None else x  # self-supervised: x IS y
             kwargs = apply_kwargs(rng)
             if use_aux:
                 preds, mut = state.apply_fn(
-                    variables, x, mutable=["losses", stats.STATS], **kwargs
+                    variables, x, mutable=model_step.SOWN, **kwargs
                 )
                 with jax.named_scope("part:loss"):
-                    loss = loss_fn(preds, target) + moe.moe_aux_loss(mut)
-                return loss, moe.with_aux_loss(stats.step_stats(mut), mut)
+                    loss = loss_fn(preds, target) + model_step.aux_loss(mut)
+                return loss, model_step.step_stats(mut)
             preds = state.apply_fn(variables, x, **kwargs)
             with jax.named_scope("part:loss"):
                 return loss_fn(preds, target), {}
@@ -462,27 +416,22 @@ class JAXEstimator:
                 gnorm = optax.global_norm(grads)
             with jax.named_scope("part:update"):
                 new = state.apply_gradients(grads=grads)
-            if isinstance(state.params, dict) and moe.BUFFERS in state.params:
-                # What the model reads and no step may change (the
-                # router's selection bias): no gradient reaches it, and the
-                # optimizer's weight decay does not either.
-                new = new.replace(params={
-                    **new.params, moe.BUFFERS: state.params[moe.BUFFERS]
-                })
+            frozen = model_step.frozen(state.params)
+            if frozen:
+                new = new.replace(params={**new.params, **frozen})
             return new, loss_val, gnorm, sown
 
         choose = self._row_path()
         if choose is None:
             return train_step
-        from raydp_tpu.models.dlrm import ROW_IDS
-        from raydp_tpu.train import rowsparse
 
         def ids_of(state: TrainState, x, rng):
             # Dead code but for the ids: no other output is used.
             _, mut = state.apply_fn(
-                state.params, x, mutable=[ROW_IDS], **apply_kwargs(rng)
+                state.params, x, mutable=[rowsparse.ROW_IDS],
+                **apply_kwargs(rng)
             )
-            return mut.get(ROW_IDS, {})
+            return mut.get(rowsparse.ROW_IDS, {})
 
         return rowsparse.make_step(loss_of, ids_of, choose, train_step)
 
@@ -494,17 +443,16 @@ class JAXEstimator:
         two gauges and one log line."""
         if self._row_plan is not None:
             return self._row_plan or None
-        from raydp_tpu.models.dlrm import ROW_IDS
-        from raydp_tpu.train import rowsparse
 
         variables = self._state.params
         self._row_plan = False
         sown = {}
         if isinstance(variables, dict) and "params" in variables:
             sown = jax.eval_shape(
-                lambda v, x: self._model.apply(v, x, mutable=[ROW_IDS])[1],
+                lambda v, x: self._model.apply(
+                    v, x, mutable=[rowsparse.ROW_IDS])[1],
                 variables, self._sample_batch,
-            ).get(ROW_IDS, {})
+            ).get(rowsparse.ROW_IDS, {})
         ids = rowsparse.table_paths(sown)
         tables = {p: rowsparse.leaf_at(variables, p) for p in ids}
         mesh = self._ensure_mesh()
@@ -531,45 +479,7 @@ class JAXEstimator:
         loss_fn = self._loss_fn
         metric_fns = list(self._metrics)
         train_step = self._make_train_step()
-        sites, words = (
-            dropout.census(
-                self._model.apply, self._state.params, self._sample_batch,
-                also=self._step_rngs(),
-            )
-            if self._model_takes_deterministic() else (0, 0)
-        )
-        dropout.report(sites, words)
-        # The rows a step sends through every layer: the batch's tokens,
-        # or more of them where the model lays copies side by side (block
-        # diffusion's pair).
-        tokens_per_step = int(np.prod(self._sample_batch.shape)) * getattr(
-            self._model, "positions_per_token", 1
-        )
-        mamba.report(
-            getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step
-        )
-        kda.report(
-            getattr(self._model, "cfg", None), tokens_per_step=tokens_per_step,
-            sequence=int(self._sample_batch.shape[-1]),
-        )
-        shortconv.report(getattr(self._model, "cfg", None))
-        latent.report(getattr(self._model, "cfg", None))
-        window_mixer.report(getattr(self._model, "cfg", None))
-        sparse_index.report(
-            getattr(self._model, "cfg", None),
-            seq_len=int(self._sample_batch.shape[-1]),
-        )
-        blockdiff.report(
-            self._model, batch=self._sample_batch.shape[0],
-            seq_len=int(self._sample_batch.shape[-1]),
-        )
-        hyperconn.report(getattr(self._model, "cfg", None))
-        report_flash_tiles(
-            getattr(self._model, "cfg", None),
-            seq_len=self._sample_batch.shape[-1],
-            batch=self._sample_batch.shape[0],
-        )
-        moe.report(self._model, tokens_per_step=tokens_per_step)
+        model_step.report(self._model, self._state.params, self._sample_batch)
 
         use_aux = self.aux_losses
 
@@ -612,20 +522,6 @@ class JAXEstimator:
         if self.label_column:
             return loader
         return ((x, None) for x in loader)
-
-    def _step_rngs(self) -> tuple:
-        """The rng collections, beside ``dropout``, that the model draws
-        from in a training step (its ``step_rngs``; none for most)."""
-        return tuple(getattr(self._model, "step_rngs", ()))
-
-    def _model_takes_deterministic(self) -> bool:
-        import inspect
-
-        try:
-            sig = inspect.signature(type(self._model).__call__)
-            return "deterministic" in sig.parameters
-        except (TypeError, ValueError):
-            return False
 
     def _sharded_prefetch(self, host_iter, depth: Optional[int] = None):
         """Windowed sharded infeed: keep up to ``depth`` batches'
@@ -1011,7 +907,7 @@ class JAXEstimator:
                     )
                     if sown:
                         stats_sum = sown if stats_sum is None else (
-                            stats.merge(stats_sum, sown)
+                            model_step.merge(stats_sum, sown)
                         )
                     n_batches += 1
                     b_idx += 1
@@ -1048,10 +944,7 @@ class JAXEstimator:
                 ) else 0.0
                 if stats_sum is not None:
                     stats_sum = jax.device_get(stats_sum)
-                    moe.report_epoch(stats_sum, n_batches)
-                    hyperconn.report_epoch(stats_sum)
-                    blockdiff.report_epoch(stats_sum)
-                    sparse_index.report_epoch(stats_sum)
+                    model_step.report_epoch(stats_sum, n_batches)
             # Epoch boundary always checks (the sampled cadence may
             # never have landed on a NaN step in a short epoch).
             sentinel.check_loss(train_loss, b_idx, epoch=epoch)

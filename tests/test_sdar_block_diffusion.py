@@ -29,6 +29,7 @@ from raydp_tpu.models import (
 )
 from raydp_tpu.models import dropout
 from raydp_tpu.models import moe as moe_module
+from raydp_tpu.models import step as model_step
 from raydp_tpu.models.transformer import MultiHeadAttention, olmoe
 from raydp_tpu.train.losses import (
     blockdiff_crossentropy,
@@ -406,7 +407,7 @@ def test_only_a_model_that_names_them_gets_further_keys(builder):
     from raydp_tpu.train import JAXEstimator
 
     est = _tiny_estimator(builder)
-    assert est._step_rngs() == ("noise",)
+    assert model_step.step_rngs(est._model) == ("noise",)
     assert BlockDiffusionLM.positions_per_token == 2
     causal = JAXEstimator(
         model=CausalLM(olmoe(vocab_size=64, d_model=32, n_heads=2,
@@ -416,7 +417,7 @@ def test_only_a_model_that_names_them_gets_further_keys(builder):
         aux_losses=True, batch_size=2, seed=1, epoch_mode="stream",
         feature_columns=[f"t{i}" for i in range(16)], feature_dtype=np.int32,
     )
-    assert causal._step_rngs() == ()
+    assert model_step.step_rngs(causal._model) == ()
     x = np.zeros((2, 16), np.int32)
     causal._init_state(x)
     text = jax.jit(causal._make_train_step()).lower(
