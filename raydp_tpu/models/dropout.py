@@ -61,17 +61,14 @@ def key_for(step_key):
     )
 
 
-def census(apply_fn, variables, sample_batch, also=()):
-    """``(sites, mask words)`` of one training-mode step: the dropout
-    modules (this one or flax's) that draw a mask when the model is
-    applied to a batch, and the 32-bit words those masks are drawn from.
-    From one abstract apply; nothing runs. ``also`` names the further rng
-    collections the model's step draws from (the apply needs a key for
-    each)."""
-    sites = words = 0
+def counting():
+    """``(interceptor, read)``: a flax method interceptor that counts the
+    dropout modules (this one or flax's) that draw a mask under it and the
+    32-bit words those masks are drawn from, and the function that reads
+    ``(sites, mask words)`` afterwards."""
+    found = [0, 0]
 
     def count(next_fun, args, kwargs, context):
-        nonlocal sites, words
         out = next_fun(*args, **kwargs)
         module = context.module
         if (isinstance(module, (Dropout, nn.Dropout))
@@ -82,10 +79,21 @@ def census(apply_fn, variables, sample_batch, also=()):
                 shape = list(inputs.shape)
                 for dim in getattr(module, "broadcast_dims", ()):
                     shape[dim] = 1
-                sites += 1
-                words += math.prod(shape)
+                found[0] += 1
+                found[1] += math.prod(shape)
         return out
 
+    return count, lambda: tuple(found)
+
+
+def census(apply_fn, variables, sample_batch, also=()):
+    """``(sites, mask words)`` of one training-mode step: the dropout
+    modules that draw a mask when the model is applied to a batch, and
+    the 32-bit words those masks are drawn from (:func:`counting`). From
+    one abstract apply; nothing runs. ``also`` names the further rng
+    collections the model's step draws from (the apply needs a key for
+    each)."""
+    count, read = counting()
     with nn.intercept_methods(count):
         jax.eval_shape(
             lambda v, x: apply_fn(
@@ -95,7 +103,7 @@ def census(apply_fn, variables, sample_batch, also=()):
             ),
             variables, sample_batch,
         )
-    return sites, words
+    return read()
 
 
 def report(sites: int, words: int) -> None:

@@ -815,7 +815,7 @@ def report(model, tokens_per_step: int) -> None:
     ``ops/rows_to_tokens.pays``), and
     ``moe/exchange_bytes_per_step``
     (:func:`exchange_bytes` over the routed layers and a step's passes:
-    forward, backward, and the forward again where blocks are
+    forward, backward, and the forward again in the blocks that are
     checkpointed). Zero for a model without a routed layer."""
     from raydp_tpu.utils.profiling import metrics
 
@@ -830,8 +830,14 @@ def report(model, tokens_per_step: int) -> None:
         # What ONE chip's expert path is: the share it holds.
         share = dataclasses.replace(moe, held_experts=held, expert_axis=None)
         rows = compact_rows(share, tokens_per_step)
-        passes = 3 if getattr(cfg, "remat", False) else 2
-        moved = layers * passes * exchange_bytes(moe, tokens_per_step)
+        # Forward and backward a layer, and the forward again in a block
+        # that is checkpointed.
+        again = sum(
+            1 for ffn, held in zip(
+                getattr(cfg, "ffn_kinds", ()), getattr(cfg, "checkpointed", ())
+            ) if held and ffn == "moe"
+        )
+        moved = (2 * layers + again) * exchange_bytes(moe, tokens_per_step)
         # Where :func:`_experts` hands both sums ``runs``.
         pairs = tokens_per_step * moe.top_k
         summed, sum_rows = (layers, rows) if (

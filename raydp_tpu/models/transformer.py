@@ -198,10 +198,17 @@ class TransformerConfig:
     ssm_chunk: int = 256
     # How to run it.
     attention_impl: str = "dense"    # dense | ring | ulysses | flash
-    # Checkpoint the blocks (memory-bound fits): a block's backward starts
-    # from its input, and from the flash kernels' output and dense lse
-    # where it calls them (``ops/flash_attention.KEPT``).
+    # The blocks MAY be checkpointed (memory-bound fits): a checkpointed
+    # block's backward starts from its input, and from the flash kernels'
+    # output and dense lse where it calls them
+    # (``ops/flash_attention.KEPT``), and runs the rest of its forward
+    # again. Which blocks are is ``checkpointed`` below: all of them as a
+    # configuration is written, fewer once ``models/step.fit_checkpoint``
+    # has read the shapes and the device's memory and RELEASED the blocks
+    # whose residuals fit (``released``: the program's to set where the
+    # step is built, not a configuration's).
     remat: bool = False
+    released: Tuple[int, ...] = ()
     dtype: Any = jnp.bfloat16        # compute dtype (MXU-friendly)
     param_dtype: Any = jnp.float32
     mesh: Any = None                 # ring/ulysses; flash on >1 device
@@ -252,6 +259,15 @@ class TransformerConfig:
     def ffn_kinds(self) -> Tuple[str, ...]:
         """The FFN kind of every layer."""
         return tuple(ffn for _, ffn in self.layers)
+
+    @property
+    def checkpointed(self) -> Tuple[bool, ...]:
+        """Whether layer i's block is checkpointed in a gradient's
+        program: every block of a ``remat`` stack but the ``released``."""
+        return tuple(
+            self.remat and i not in self.released
+            for i in range(self.n_layers)
+        )
 
     @property
     def serves_from_kv_cache(self) -> bool:
@@ -834,6 +850,34 @@ class TransformerBlock(nn.Module):
         )
 
 
+def checkpointed_block():
+    """``TransformerBlock`` under the block checkpoint. What such a block
+    keeps besides its input are the residuals only a kernel's forward can
+    make, named where they are made: the flash forward kernel's output
+    ([B, H, S, D_v], the size of one q projection's result) and lse.
+    Recomputing them is a second run of the whole kernel, the dearest item
+    of a block per byte kept; lse is kept as a dense [B, H, S] array
+    because the kernel's [B, H, S, 1] columns are 128 times their bytes in
+    HBM's tiling. A block that calls no flash kernel (dense, ring,
+    ulysses) holds no such name and keeps its input alone. A delta-rule
+    layer's scan names its output and the few states its segments were
+    entered with for the same reason (``ops/kda.KEPT``), the attention
+    over a learned selection its kernels' (``ops/sparse_attention.KEPT``)."""
+    return nn.remat(
+        TransformerBlock, static_argnums=(2,),
+        policy=jax.checkpoint_policies.save_only_these_names(*kept_names()),
+    )
+
+
+def kept_names() -> Tuple[str, ...]:
+    """The names a checkpointed block keeps besides its input."""
+    from raydp_tpu.ops.flash_attention import KEPT
+    from raydp_tpu.ops.kda import KEPT as KDA_KEPT
+    from raydp_tpu.ops.sparse_attention import KEPT as SPARSE_KEPT
+
+    return (*KEPT, *KDA_KEPT, *SPARSE_KEPT)
+
+
 class TransformerEncoder(nn.Module):
     """Token + position (+ optional segment) embeddings, N blocks (layer
     i's mixer and FFN kind from ``cfg.layer_types``), final LN.
@@ -899,34 +943,18 @@ class TransformerEncoder(nn.Module):
         if cfg.dropout_rate > 0:
             x = Dropout(cfg.dropout_rate)(x, deterministic)
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
-        # remat: recompute block activations in the backward instead of
-        # storing them — the standard FLOPs-for-HBM trade that unlocks
-        # bigger batches/sequences when training is memory-bound. What a
-        # block keeps besides its input are the two residuals only the
-        # flash forward kernel can make: its output ([B, H, S, D_v], the
-        # size of one q projection's result) and lse, named where they are
-        # made. Recomputing them is a second run of the whole kernel, the
-        # dearest item of a block per byte kept; lse is kept as a dense
-        # [B, H, S] array because the kernel's [B, H, S, 1] columns are
-        # 128 times their bytes in HBM's tiling. A block that calls no
-        # flash kernel (dense, ring, ulysses) holds no such name and
-        # keeps its input alone. A delta-rule layer's scan names its output
-        # and the few states its segments were entered with for the same
-        # reason (``ops/kda.KEPT``).
+        # A checkpointed block's activations are recomputed in the backward
+        # instead of stored: the FLOPs-for-HBM trade that fits a bigger
+        # batch or sequence where training is memory-bound, and work the
+        # result does not need where the memory is there. So the unit is
+        # the block: ``cfg.checkpointed`` says which blocks of a ``remat``
+        # stack are (all of them until ``models/step.fit_checkpoint`` has
+        # released those whose residuals fit the device), and a released
+        # block is the plain ``TransformerBlock`` under the same name, with
+        # the same parameters.
         if cache_mode is not None and cfg.remat:
             raise ValueError("decode cache is incompatible with remat")
-        block_cls = TransformerBlock
-        if cfg.remat:
-            from raydp_tpu.ops.flash_attention import KEPT
-            from raydp_tpu.ops.kda import KEPT as KDA_KEPT
-            from raydp_tpu.ops.sparse_attention import KEPT as SPARSE_KEPT
-
-            block_cls = nn.remat(
-                TransformerBlock, static_argnums=(2,),
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *KEPT, *KDA_KEPT, *SPARSE_KEPT
-                ),
-            )
+        checkpointed = checkpointed_block() if any(cfg.checkpointed) else None
         if cfg.hyper is not None:
             from raydp_tpu.models import hyperconn
 
@@ -936,6 +964,9 @@ class TransformerEncoder(nn.Module):
         # without them is the call it was.
         given = {} if positions is None else {"positions": positions}
         for i, (mixer, ffn) in enumerate(cfg.layers):
+            block_cls = checkpointed if cfg.checkpointed[i] else (
+                TransformerBlock
+            )
             x = block_cls(cfg, mixer, ffn, name=f"block_{i}")(
                 x,
                 deterministic,

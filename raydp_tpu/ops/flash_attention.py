@@ -335,15 +335,21 @@ def report(cfg, seq_len: int, batch: int = 1) -> None:
     if windowed:
         calls["window"] = (cfg.window.n_heads, cfg.head_dim, cfg.head_dim)
     itemsize = jnp.dtype(cfg.dtype).itemsize if calls else 0
-    # What the block checkpoint (``cfg.remat``) keeps of every call it
-    # wraps: a head's output row in the compute dtype and its float32 lse.
-    kept = layers + windowed if getattr(cfg, "remat", False) else 0
-    kept_bytes = 0
-    if kept:
-        kept_bytes = batch * seq_len * sum(
-            cfg.kinds.count(kind) * h * (d_v * itemsize + 4)
-            for kind, (h, _, d_v) in calls.items()
-        )
+    # What the block checkpoint keeps of every call it wraps, in the
+    # blocks that are checkpointed (``cfg.checkpointed``; a released
+    # block's call keeps the same two among all its residuals): a head's
+    # output row in the compute dtype and its float32 lse.
+    wrapped = {
+        kind: sum(
+            1 for of, held in zip(cfg.kinds, cfg.checkpointed)
+            if held and of == kind
+        ) for kind in calls
+    }
+    kept = sum(wrapped.values())
+    kept_bytes = batch * seq_len * sum(
+        wrapped[kind] * h * (d_v * itemsize + 4)
+        for kind, (h, _, d_v) in calls.items()
+    )
     # Which backward each kind's calls take, by the call's own rule.
     fused = {
         kind: backward_is_fused(seq_len, d, d_v, itemsize)

@@ -277,6 +277,9 @@ class JAXEstimator:
         # Row path of the train step: None until the first step is
         # built, then its plan function, or False where nothing takes it.
         self._row_plan = None
+        # The model the train step differentiates: ``_model`` until
+        # ``_build_steps`` has fitted its block checkpoint to the device.
+        self._step_model = None
         self._sample_batch = None
         # Anomaly sentinel of the latest fit.
         self._sentinel = None
@@ -384,19 +387,23 @@ class JAXEstimator:
         loss."""
         loss_fn = self._loss_fn
         use_aux = self.aux_losses
+        # The model a gradient is taken through: ``self._model`` with the
+        # blocks that fit released from its checkpoint (the same
+        # parameters, the same values; ``models/step.fit_checkpoint``).
+        apply = (self._step_model or self._model).apply
         apply_kwargs = functools.partial(model_step.apply_kwargs, self._model)
 
         def loss_of(state: TrainState, variables, x, y, rng):
             target = y if y is not None else x  # self-supervised: x IS y
             kwargs = apply_kwargs(rng)
             if use_aux:
-                preds, mut = state.apply_fn(
+                preds, mut = apply(
                     variables, x, mutable=model_step.SOWN, **kwargs
                 )
                 with jax.named_scope("part:loss"):
                     loss = loss_fn(preds, target) + model_step.aux_loss(mut)
                 return loss, model_step.step_stats(mut)
-            preds = state.apply_fn(variables, x, **kwargs)
+            preds = apply(variables, x, **kwargs)
             with jax.named_scope("part:loss"):
                 return loss_fn(preds, target), {}
 
@@ -478,8 +485,18 @@ class JAXEstimator:
     def _build_steps(self) -> None:
         loss_fn = self._loss_fn
         metric_fns = list(self._metrics)
-        train_step = self._make_train_step()
-        model_step.report(self._model, self._state.params, self._sample_batch)
+        # One abstract training apply serves the decision and the report.
+        surveyed = model_step.survey(
+            self._model, self._state.params, self._sample_batch
+        )
+        self._step_model = model_step.fit_checkpoint(
+            self._model, self._state, self._sample_batch,
+            self._ensure_mesh(), surveyed,
+        )
+        model_step.report(
+            self._step_model, self._state.params, self._sample_batch,
+            surveyed,
+        )
 
         use_aux = self.aux_losses
 
@@ -508,13 +525,52 @@ class JAXEstimator:
                 preds = state.apply_fn(state.params, x)
             return preds
 
-        self._train_step = _guard_compile(jax.jit(
-            train_step, donate_argnums=(0,) if self.donate_state else ()
-        ), "train_step")
+        self._train_step = self._with_way_back(lambda: _guard_compile(jax.jit(
+            self._make_train_step(),
+            donate_argnums=(0,) if self.donate_state else (),
+        ), "train_step"))
         self._eval_step = _guard_compile(jax.jit(eval_step), "eval_step")
         self._predict_step = _guard_compile(
             jax.jit(predict_step), "predict_step"
         )
+
+    def _with_way_back(self, build: Callable[[], Callable]) -> Callable:
+        """``build()``'s guarded program. Where the step's model has
+        blocks released from its checkpoint and the program's FIRST call
+        fails for want of device memory (the estimate behind the release
+        was wrong for this shape), the program is built once more with
+        every block checkpointed, as the configuration wrote the model,
+        and the call made again: a fit that ran before this rule runs
+        with it. The failure is a compile's or an allocation's, before
+        anything ran, so the donated state is whole."""
+        program = build()
+        if (self._step_model or self._model) is self._model:
+            return program
+        state = {"program": program, "first": True}
+
+        def wrapped(*args, **kwargs):
+            first, state["first"] = state["first"], False
+            try:
+                return state["program"](*args, **kwargs)
+            except _profiling.CompileError as err:
+                donated = any(
+                    leaf.is_deleted()
+                    for leaf in jax.tree_util.tree_leaves((args, kwargs))
+                    if isinstance(leaf, jax.Array)
+                )
+                if (not first or donated
+                        or "RESOURCE_EXHAUSTED" not in err.xla_detail):
+                    raise
+                logger.warning(
+                    "%s does not fit the device with blocks released from "
+                    "the checkpoint; building it with every block "
+                    "checkpointed: %s", err.label, err.xla_detail[:400],
+                )
+            self._step_model = model_step.checkpoint_all(self._step_model)
+            state["program"] = build()
+            return state["program"](*args, **kwargs)
+
+        return wrapped
 
     def _pairs(self, loader):
         """``(x, y)`` host batches of a loader; a label-less loader
@@ -1028,6 +1084,11 @@ class JAXEstimator:
         return x, y
 
     def _build_epoch_fn(self, n_steps: int, batch: int):
+        return self._with_way_back(
+            lambda: self._epoch_program(n_steps, batch)
+        )
+
+    def _epoch_program(self, n_steps: int, batch: int):
         train_step = self._make_train_step()
         shuffle = self.shuffle
 
