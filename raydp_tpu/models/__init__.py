@@ -16,6 +16,7 @@ from raydp_tpu.models.transformer import (
     sdar_30b_a3b,
     keye_vl_2_0_30b_a3b,
     mellum2_12b_a2_5b,
+    nemotron_3_nano_30b_a3b,
     vocab_rules,
     xing4_0,
 )
@@ -73,6 +74,7 @@ __all__ = [
     "sdar_30b_a3b",
     "keye_vl_2_0_30b_a3b",
     "mellum2_12b_a2_5b",
+    "nemotron_3_nano_30b_a3b",
     "vocab_rules",
     "SparseIndexConfig",
     "xing4_0",

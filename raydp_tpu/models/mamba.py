@@ -9,9 +9,12 @@ state-space layer a hybrid stack puts where most of its attention was.
     h_t          = exp(dt_t A) h_{t-1} + dt_t x_t ⊗ B_t;  y_t = h_t C_t + D x_t
     out          = W_out (rms(y · silu(z)) · w)
 
-The gate goes in BEFORE the norm, which runs over all ``heads × head_dim``
-features. Module paths: ``mamba/{in_proj,conv,ssd,gate_norm,out_proj}``;
-the scan itself is ``ops/ssd.py``.
+The gate goes in BEFORE the norm, which runs over each of the
+``ssm_groups`` groups of ``heads × head_dim / ssm_groups`` features on its
+own (the released code's ``group_size``; all the features where the B and
+C of every head are one group). Module paths:
+``mamba/{in_proj,conv,ssd,gate_norm,out_proj}``; the scan itself is
+``ops/ssd.py``.
 """
 from __future__ import annotations
 
@@ -136,23 +139,35 @@ class SelectiveScan(nn.Module):
 
 
 class GatedRMSNorm(nn.Module):
-    """``rms(y · silu(z)) · w`` over the whole feature axis, float32
-    inside: the gate enters before the norm."""
+    """``rms(y · silu(z)) · w``, float32 inside: the gate enters before
+    the norm, whose mean square is taken over each of ``groups`` equal
+    groups of the feature axis on its own (the whole axis with one); one
+    learned weight a feature either way."""
 
     epsilon: float
     dtype: jnp.dtype
     param_dtype: jnp.dtype
+    groups: int = 1
 
     @nn.compact
     def __call__(self, y, z):
+        features = y.shape[-1]
+        if features % self.groups:
+            raise ValueError(
+                f"{features} features do not divide into {self.groups} groups"
+            )
         scale = self.param(
-            "scale", _replicated(nn.initializers.ones), (y.shape[-1],),
+            "scale", _replicated(nn.initializers.ones), (features,),
             self.param_dtype,
         )
         y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        if self.groups > 1:
+            y = y.reshape(*y.shape[:-1], self.groups, -1)
         y = y * jax.lax.rsqrt(
             jnp.mean(y * y, axis=-1, keepdims=True) + self.epsilon
         )
+        if self.groups > 1:
+            y = y.reshape(*y.shape[:-2], features)
         return (y * scale.astype(jnp.float32)).astype(self.dtype)
 
 
@@ -190,7 +205,8 @@ class Mamba2Mixer(nn.Module):
             B.reshape(*lead, g, n), C.reshape(*lead, g, n),
         )
         y = GatedRMSNorm(
-            cfg.norm_eps, cfg.dtype, cfg.param_dtype, name="gate_norm"
+            cfg.norm_eps, cfg.dtype, cfg.param_dtype, groups=g,
+            name="gate_norm",
         )(y.reshape(*lead, inner), z)
         return dense(
             cfg.d_model, name="out_proj",
@@ -203,9 +219,9 @@ def layers_of(cfg) -> int:
 
 
 def report(cfg, tokens_per_step: int) -> None:
-    """Static for a compiled step: three gauges and one log line where the
-    step is built (as ``models/dropout.report``), nothing per step. All
-    zero for a stack without state-space layers."""
+    """Static for a compiled step: the ``ssm/*`` gauges and one log line
+    where the step is built (as ``models/dropout.report``), nothing per
+    step. All zero for a stack without state-space layers."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
@@ -216,6 +232,14 @@ def report(cfg, tokens_per_step: int) -> None:
     metrics.gauge_set("ssm/layers", layers)
     metrics.gauge_set("ssm/chunks_per_step", chunks)
     metrics.gauge_set("ssm/state_bytes_per_sequence", state)
+    # The groups of B and C, and the features one mean square of the gated
+    # norm runs over (all of a layer's where the group is one).
+    groups = cfg.ssm_groups if layers else 0
+    metrics.gauge_set("ssm/groups", groups)
+    metrics.gauge_set(
+        "ssm/gate_norm_group_size",
+        cfg.ssm_heads * cfg.ssm_head_dim // groups if layers else 0,
+    )
     if layers:
         logger.info(
             "hybrid stack: %d mamba and %d attention layers; attention %d "

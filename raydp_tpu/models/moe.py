@@ -13,6 +13,10 @@ the published OLMoE block (Muennighoff et al. 2024, arXiv:2409.02060):
   ``down(silu(gate(x)) * up(x))`` as grouped matmuls over the stacked
   ``[E, D, F]`` weights (``ops/grouped_matmul.py``; group sizes from a
   count per expert), and brought back by a gather with the inverse permutation.
+  ``expert_form`` ``relu2`` makes every expert, routed and shared, the
+  ungated ``down(relu(up(x))²)``: two stacked matrices and no ``w_gate``
+  anywhere (:data:`EXPERT_FORMS`; stated once, read by the routed path,
+  a share's guard, the mesh path and the shared expert).
   A permutation's transpose is its inverse, so :func:`take_rows` gives the
   row gather a backward that is a gather too: neither direction is a
   scatter of ``T·k`` rows (XLA's TPU scatter is a serial loop over its
@@ -123,6 +127,12 @@ BUFFERS = "buffers"
 
 
 BALANCING_ROUNDS = 4
+# An expert's form -> its stacked matrices; the last one goes back to
+# ``d_model``, the others come from it.
+EXPERT_FORMS = {
+    "swiglu": ("w_gate", "w_up", "w_down"),
+    "relu2": ("w_up", "w_down"),
+}
 
 
 def balancing_bias(scores, top_k: int):
@@ -167,9 +177,14 @@ class MoEConfig:
     first_expert: int = 0
     held_experts: Optional[int] = None   # None = all n_experts
     # Experts every token goes through, beside the routed ones: one dense
-    # SwiGLU of width ``shared_experts * d_ff``, ungated, whole on every
-    # share of an expert-parallel deployment.
+    # expert of width ``shared_experts * d_ff``, no router gate on it, whole
+    # on every share of an expert-parallel deployment.
     shared_experts: int = 0
+    # What an expert computes, routed and shared alike (:data:`EXPERT_FORMS`):
+    # swiglu ``down(silu(gate x) * up x)``, three matrices | relu2
+    # ``down(relu(up x)²)``, two: no ``w_gate`` exists, in the parameters,
+    # the optimizer's state or the cotangents.
+    expert_form: str = "swiglu"
     # Expert parallelism on a mesh: the axis of ``mesh`` (a
     # ``jax.sharding.Mesh``) the experts lie along. Each of the axis's n
     # chips holds ``n_experts / n`` experts and the layer runs its exchange
@@ -193,6 +208,14 @@ class MoEConfig:
                 "over all the experts, the same number a chip"
             )
         return n
+
+    @property
+    def expert_weights(self) -> tuple:
+        """The names of an expert's stacked matrices, in the order the
+        expert path takes them."""
+        if self.expert_form not in EXPERT_FORMS:
+            raise ValueError(f"unknown expert_form {self.expert_form!r}")
+        return EXPERT_FORMS[self.expert_form]
 
     @property
     def held(self) -> int:
@@ -315,14 +338,27 @@ def compact_rows(cfg: "MoEConfig", n_tokens: int) -> int:
     return min(pairs, -(-rows // TILING[0]) * TILING[0])
 
 
-def _experts(operands, routing, rows: Optional[int] = None):
+def _hidden(form: str, x, weights, group_sizes):
+    """What an expert of ``form`` hands its last matrix, for rows ``x`` in
+    expert order: ``silu(x·w_gate) * (x·w_up)`` | ``relu(x·w_up)²``."""
+    if form == "swiglu":
+        w_gate, w_up = weights
+        return jax.nn.silu(
+            grouped_matmul(x, w_gate, group_sizes)
+        ) * grouped_matmul(x, w_up, group_sizes)
+    (w_up,) = weights
+    return jnp.square(jax.nn.relu(grouped_matmul(x, w_up, group_sizes)))
+
+
+def _experts(form: str, operands, routing, rows: Optional[int] = None):
     """The expert path over the first ``rows`` places of the sorted pairs
     (all ``T·k`` unless given): ``operands`` = tokens ``[T, D]``, gates
-    ``[T, k]`` and the three stacked weights, ``routing`` = ``order``,
+    ``[T, k]`` and the stacked weights of an expert of ``form`` (three,
+    or two: ``MoEConfig.expert_weights``), ``routing`` = ``order``,
     ``inverse``, the held experts' ``group_sizes`` and ``live``. With fewer
     rows than pairs every array between the two token-side gathers has
     ``rows`` rows, and group sizes that sum to more are cut there."""
-    tokens, gate, w_gate, w_up, w_down = operands
+    tokens, gate, *weights = operands
     order, inverse, group_sizes, live = routing
     runs = None
     with jax.named_scope("permute"):
@@ -339,12 +375,8 @@ def _experts(operands, routing, rows: Optional[int] = None):
                 runs = (jnp.where(live, place.reshape(live.shape), -1), ends)
         x = take_rows(tokens, order, inverse, gate.shape[1], live, runs)
     with jax.named_scope("experts"):
-        w_gate, w_up, w_down = (
-            w.astype(tokens.dtype) for w in (w_gate, w_up, w_down)
-        )
-        h = jax.nn.silu(
-            grouped_matmul(x, w_gate, group_sizes)
-        ) * grouped_matmul(x, w_up, group_sizes)
+        *w_in, w_down = (w.astype(tokens.dtype) for w in weights)
+        h = _hidden(form, x, w_in, group_sizes)
         y = grouped_matmul(h, w_down, group_sizes)
     with jax.named_scope("unpermute"):
         return combine_rows(y, gate, order, inverse, live, runs)
@@ -353,36 +385,37 @@ def _experts(operands, routing, rows: Optional[int] = None):
 # A share's guard. The compact path ``_experts(..., rows=C)`` stands in the
 # program as it would without a guard, under plain autodiff; around it:
 #
-#     operands, wire = _hand_in(operands, routing, overflow)
-#     out = _hand_out(_experts(operands, routing, C), wire, operands, ...)
+#     operands, wire = _hand_in(form, operands, routing, overflow)
+#     out = _hand_out(form, _experts(form, operands, routing, C), wire, ...)
 #
 # ``_hand_out`` gives the compact result unless the layer-step's held pairs
 # exceed ``C``: then, inside a ``lax.cond``, the ``T·k``-row path's. Its
 # cotangent goes to the compact path and, over ``wire`` (zeros nobody
 # reads, there to carry it), to ``_hand_in``'s backward, which by then also
-# holds the compact path's five cotangents and hands them on through a
+# holds the compact path's cotangents (tokens, gates and an expert's stacked
+# weights: five, four for an ungated expert) and hands them on through a
 # second ``cond`` — or, on overflow, the ``T·k``-row path's in their place.
 # A branch not taken computes nothing and writes nothing: no residual and
 # no zero gradient of a ``T·k``-row array or of a stacked weight exists for
 # the sake of the guard.
 
-@jax.custom_vjp
-def _hand_in(operands, routing, overflow):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _hand_in(form, operands, routing, overflow):
     return operands, jnp.zeros_like(operands[0])
 
 
-def _hand_in_fwd(operands, routing, overflow):
-    return _hand_in(operands, routing, overflow), (
+def _hand_in_fwd(form, operands, routing, overflow):
+    return _hand_in(form, operands, routing, overflow), (
         operands, routing, overflow
     )
 
 
-def _hand_in_bwd(res, cotangents):
+def _hand_in_bwd(form, res, cotangents):
     operands, routing, overflow = res
     compact, g = cotangents
 
     def whole(_):
-        _, pull = jax.vjp(lambda *o: _experts(o, routing), *operands)
+        _, pull = jax.vjp(lambda *o: _experts(form, o, routing), *operands)
         return pull(g)
 
     # Between barriers, or XLA moves the compact path's last ops and the
@@ -396,28 +429,29 @@ def _hand_in_bwd(res, cotangents):
 _hand_in.defvjp(_hand_in_fwd, _hand_in_bwd)
 
 
-@jax.custom_vjp
-def _hand_out(out, wire, operands, routing, overflow):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _hand_out(form, out, wire, operands, routing, overflow):
     return jax.lax.optimization_barrier(jax.lax.cond(
-        overflow, lambda: _experts(operands, routing), lambda: out
+        overflow, lambda: _experts(form, operands, routing), lambda: out
     ))
 
 
-def _hand_out_fwd(out, wire, operands, routing, overflow):
-    return _hand_out(out, wire, operands, routing, overflow), None
+def _hand_out_fwd(form, out, wire, operands, routing, overflow):
+    return _hand_out(form, out, wire, operands, routing, overflow), None
 
 
-def _hand_out_bwd(_, g):
+def _hand_out_bwd(form, _, g):
     return g, g, None, None, None
 
 
 _hand_out.defvjp(_hand_out_fwd, _hand_out_bwd)
 
 
-def _sorted_experts(tokens, gate, expert, counts, weights, first, held: int,
-                    share: bool, rows: int, dtype, note_overflow):
+def _sorted_experts(form: str, tokens, gate, expert, counts, weights, first,
+                    held: int, share: bool, rows: int, dtype, note_overflow):
     """``sum_j gate_j E_j(token)`` over the experts ``[first, first +
-    held)`` for ``tokens`` ``[T, D]`` with their ``gate`` and ``expert``
+    held)`` of ``form`` (``weights``: their stacked matrices) for
+    ``tokens`` ``[T, D]`` with their ``gate`` and ``expert``
     ``[T, k]``: the pairs sorted by expert, the expert path over the first
     ``rows`` places, the guard around it where those are fewer than all
     ``T·k`` (``note_overflow`` is handed the layer-step's flag, float32).
@@ -443,7 +477,7 @@ def _sorted_experts(tokens, gate, expert, counts, weights, first, held: int,
         routing = (order, inverse, counts.astype(jnp.int32), live)
         operands = (tokens.astype(dtype), gate) + tuple(weights)
     if rows == n_tokens * k:
-        return _experts(operands, routing)
+        return _experts(form, operands, routing)
     with jax.named_scope("permute"):
         overflow = counts.sum() > rows
         note_overflow(overflow.astype(jnp.float32))
@@ -453,10 +487,10 @@ def _sorted_experts(tokens, gate, expert, counts, weights, first, held: int,
         operands = operands[:2] + tuple(
             w.astype(dtype) for w in operands[2:]
         )
-    operands, wire = _hand_in(operands, routing, overflow)
+    operands, wire = _hand_in(form, operands, routing, overflow)
     return _hand_out(
-        _experts(operands, routing, rows), wire, operands, routing,
-        overflow,
+        form, _experts(form, operands, routing, rows), wire, operands,
+        routing, overflow,
     )
 
 
@@ -507,8 +541,8 @@ def _exchanged(cfg: "MoEConfig", tokens, gate, expert, counts, weights):
         mine = jax.lax.dynamic_slice(counts, (first,), (held,))
         flags = [jnp.zeros((), jnp.float32)]
         out = _sorted_experts(
-            tokens, gate, expert, mine, weights, first, held, True, rows,
-            cfg.dtype, flags.append,
+            cfg.expert_form, tokens, gate, expert, mine, weights, first,
+            held, True, rows, cfg.dtype, flags.append,
         )
         with jax.named_scope("exchange"), jax.named_scope("scatter"):
             out = jax.lax.psum_scatter(
@@ -543,9 +577,10 @@ def _add(a, b):
 
 
 class SharedExpert(nn.Module):
-    """The experts no router chooses: ``down(silu(gate(x)) * up(x))`` for
-    every token, gate and up as one fused projection (scope
-    ``moe/shared``)."""
+    """The experts no router chooses, of the routed experts' form:
+    ``down(silu(gate(x)) * up(x))`` for every token, gate and up as one
+    fused projection ``in``, or ``down(relu(up(x))²)`` with ``in`` the up
+    projection alone (scope ``moe/shared``)."""
 
     cfg: MoEConfig
 
@@ -557,17 +592,25 @@ class SharedExpert(nn.Module):
             param_dtype=cfg.param_dtype,
         )
         width = cfg.shared_experts * cfg.d_ff
-        gate, up = jnp.split(dense(
-            2 * width, kernel_init=_expert_init("embed", "mlp"), name="in",
-        )(tokens), 2, axis=-1)
+        gated = cfg.expert_form == "swiglu"
+        h = dense(
+            (2 if gated else 1) * width,
+            kernel_init=_expert_init("embed", "mlp"), name="in",
+        )(tokens)
+        if gated:
+            gate, up = jnp.split(h, 2, axis=-1)
+            h = jax.nn.silu(gate) * up
+        else:
+            h = jnp.square(jax.nn.relu(h))
         return dense(
             cfg.d_model, kernel_init=_expert_init("mlp", "embed"),
             name="out",
-        )(jax.nn.silu(gate) * up)
+        )(h)
 
 
 class MoELayer(nn.Module):
-    """Top-k routed SwiGLU experts over the trailing feature axis.
+    """Top-k routed experts (SwiGLU, or ungated relu²: ``cfg.expert_form``)
+    over the trailing feature axis.
 
     Input ``[..., D]`` → output ``[..., D]`` in the compute dtype; tokens
     are the flattened leading axes, and every token reaches all of its
@@ -642,20 +685,16 @@ class MoELayer(nn.Module):
                 counts = counts[first:first + held]
                 stats.sow(self, "held_tokens", counts)
 
-        w_gate = self.param(
-            "w_gate", _expert_init("expert", "embed", "mlp"),
-            (held, d, cfg.d_ff), cfg.param_dtype,
-        )
-        w_up = self.param(
-            "w_up", _expert_init("expert", "embed", "mlp"),
-            (held, d, cfg.d_ff), cfg.param_dtype,
-        )
-        w_down = self.param(
-            "w_down", _expert_init("expert", "mlp", "embed"),
+        *names_in, name_down = cfg.expert_weights
+        weights = tuple(
+            self.param(
+                name, _expert_init("expert", "embed", "mlp"),
+                (held, d, cfg.d_ff), cfg.param_dtype,
+            ) for name in names_in
+        ) + (self.param(
+            name_down, _expert_init("expert", "mlp", "embed"),
             (held, cfg.d_ff, d), cfg.param_dtype,
-        )
-
-        weights = (w_gate, w_up, w_down)
+        ),)
         if cfg.exchange_chips > 1 and not self.is_initializing():
             out, overflow = _exchanged(
                 cfg, tokens.astype(cfg.dtype), gate, expert,
@@ -675,8 +714,8 @@ class MoELayer(nn.Module):
                 compact_rows(cfg, n_tokens)
             )
             out = _sorted_experts(
-                tokens, gate, expert, counts, weights, first, held, share,
-                rows, cfg.dtype,
+                cfg.expert_form, tokens, gate, expert, counts, weights,
+                first, held, share, rows, cfg.dtype,
                 lambda over: stats.sow(self, "overflow", over),
             )
         if cfg.shared_experts:
@@ -816,7 +855,9 @@ def report(model, tokens_per_step: int) -> None:
     ``moe/exchange_bytes_per_step``
     (:func:`exchange_bytes` over the routed layers and a step's passes:
     forward, backward, and the forward again in the blocks that are
-    checkpointed). Zero for a model without a routed layer."""
+    checkpointed), and the expert's form as ``moe/expert_matrices`` (3
+    for SwiGLU, 2 for the ungated relu²). Zero for a model without a
+    routed layer."""
     from raydp_tpu.utils.profiling import metrics
 
     cfg, moe = getattr(model, "cfg", None), getattr(model, "moe", None)
@@ -850,6 +891,11 @@ def report(model, tokens_per_step: int) -> None:
     metrics.gauge_set("moe/token_sum_layers", summed)
     metrics.gauge_set(
         "moe/shared_experts", moe.shared_experts if moe is not None else 0
+    )
+    # An expert's form, as its stacked matrices: 3 gated (SwiGLU), 2 not.
+    metrics.gauge_set(
+        "moe/expert_matrices",
+        len(moe.expert_weights) if moe is not None else 0,
     )
     metrics.gauge_set("moe/exchange_chips", chips)
     metrics.gauge_set("moe/exchange_bytes_per_step", moved)

@@ -25,7 +25,8 @@ from raydp_tpu.models import (
 )
 from raydp_tpu.models.stats import merge  # noqa: F401  (two steps' statistics as one)
 from raydp_tpu.models.transformer import (
-    LOGICAL_RULES, TransformerBlock, kept_names, vocab_rules,
+    LOGICAL_RULES, TransformerBlock, kept_names,
+    report as report_stack, vocab_rules,
 )
 from raydp_tpu.ops.flash_attention import report as report_flash_tiles
 
@@ -160,6 +161,7 @@ def report(model, params, sample_batch, surveyed=None) -> None:
     tokens_per_step = int(np.prod(sample_batch.shape)) * getattr(
         model, "positions_per_token", 1
     )
+    report_stack(cfg)
     mamba.report(cfg, tokens_per_step=tokens_per_step)
     kda.report(cfg, tokens_per_step=tokens_per_step, sequence=seq_len)
     shortconv.report(cfg)

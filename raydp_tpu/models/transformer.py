@@ -58,6 +58,10 @@ LOGICAL_RULES: Tuple[Tuple[str, Optional[str]], ...] = (
 MIXERS = frozenset(
     {"attention", "window", "mamba", "conv", "latent", "kda", "sparse"}
 )
+FFNS = frozenset({"gelu", "swiglu", "moe"})
+# The sublayer a layer of ONE sublayer does not have: ``"<mixer>:none"`` is
+# ``x + mixer(norm(x))`` alone, ``"none:<ffn>"`` ``x + FFN(norm(x))`` alone.
+NONE = "none"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,10 +155,15 @@ class TransformerConfig:
     experts_held: Optional[int] = None      # None = all n_experts
     moe_loss_weights: Tuple[float, float] = (1e-2, 1e-3)   # balance, z
     shared_experts: int = 0          # of d_expert each, beside the routed
+    # An expert's form, routed and shared alike (``models/moe.py``):
+    # swiglu ``down(silu(gate x) * up x)`` | relu2 ``down(relu(up x)²)``.
+    expert_form: str = "swiglu"
     # Layer i as "<mixer>" or "<mixer>:<ffn>": the mixer is "attention" |
     # "window" | "mamba" | "conv" | "latent" | "kda" | "sparse", the FFN kind one of
-    # ``ffn``'s and ``ffn`` itself where the entry names none. None =
-    # attention and ``ffn`` everywhere.
+    # ``ffn``'s and ``ffn`` itself where the entry names none. A layer of
+    # ONE sublayer (one norm, one residual add) names "none" for the other:
+    # "<mixer>:none" or "none:<ffn>". None = attention and ``ffn``
+    # everywhere.
     layer_types: Optional[Tuple[str, ...]] = None
     # A mixer's or the residual path's own sizes, a record each:
     # ``models/latent.LatentConfig`` for the "latent" mixer,
@@ -236,14 +245,16 @@ class TransformerConfig:
 
     @property
     def layers(self) -> Tuple[Tuple[str, str], ...]:
-        """(mixer, FFN kind) of every layer, ``n_layers`` long."""
+        """(mixer, FFN kind) of every layer, ``n_layers`` long; ``NONE``
+        for the sublayer a layer of one sublayer does not have."""
         entries = self.layer_types or ("attention",) * self.n_layers
         layers = tuple(
             tuple((entry.split(":", 1) + [self.ffn])[:2]) for entry in entries
         )
         if (len(layers) != self.n_layers
-                or {m for m, _ in layers} - MIXERS
-                or {f for _, f in layers} - {"gelu", "swiglu", "moe"}):
+                or {m for m, _ in layers} - MIXERS - {NONE}
+                or {f for _, f in layers} - FFNS - {NONE}
+                or (NONE, NONE) in layers):
             raise ValueError(
                 f"layer_types {entries!r} does not name the mixers (and FFN "
                 f"kinds) of {self.n_layers} layers"
@@ -252,12 +263,14 @@ class TransformerConfig:
 
     @property
     def kinds(self) -> Tuple[str, ...]:
-        """The mixer of every layer."""
+        """The mixer of every layer (``NONE`` where a layer is an FFN
+        alone)."""
         return tuple(mixer for mixer, _ in self.layers)
 
     @property
     def ffn_kinds(self) -> Tuple[str, ...]:
-        """The FFN kind of every layer."""
+        """The FFN kind of every layer (``NONE`` where a layer is a mixer
+        alone)."""
         return tuple(ffn for _, ffn in self.layers)
 
     @property
@@ -289,6 +302,7 @@ class TransformerConfig:
             normalize_gates=self.norm_top_k, gate_scale=self.routed_scaling,
             first_expert=self.first_expert, held_experts=self.experts_held,
             shared_experts=self.shared_experts,
+            expert_form=self.expert_form,
             expert_axis=self.state_axis,
             mesh=self.mesh if self.state_axis is not None else None,
             dtype=self.dtype, param_dtype=self.param_dtype,
@@ -708,11 +722,14 @@ class TransformerBlock(nn.Module):
     those of a latent-attention stack are the same code. With
     ``cfg.hyper`` the block carries ``[B, n, S, D]`` streams and each of
     its two sublayers reads and writes them through its own mappings
-    (``models/hyperconn.py``; scopes ``hc_attn`` and ``hc_ffn``)."""
+    (``models/hyperconn.py``; scopes ``hc_attn`` and ``hc_ffn``). A layer
+    of one sublayer (``mixer`` or ``ffn`` is ``NONE``) is ``x + F(norm(x))``
+    with the one norm, the one module and the one add of the sublayer it
+    has, under the names they have in a block of two."""
 
     cfg: TransformerConfig
-    mixer: str = "attention"         # one of ``MIXERS``
-    ffn: Optional[str] = None        # None = cfg.ffn
+    mixer: str = "attention"         # one of ``MIXERS``, or ``NONE``
+    ffn: Optional[str] = None        # None = cfg.ffn; ``NONE`` = no FFN
 
     @nn.compact
     def __call__(
@@ -726,7 +743,8 @@ class TransformerBlock(nn.Module):
         positions=None,
     ):
         cfg = self.cfg
-        if cfg.diffusion is not None and self.mixer != "attention":
+        if cfg.diffusion is not None and self.mixer not in (
+                "attention", NONE):
             raise NotImplementedError(
                 f"a {self.mixer!r} layer over a pair of copies: only the "
                 "'attention' mixer knows the pair mask"
@@ -829,14 +847,21 @@ class TransformerBlock(nn.Module):
                 y = Dropout(cfg.dropout_rate)(y, deterministic)
             return y
 
+        # The sublayers the layer has, in order: two unless it names one.
+        sublayers = [
+            named for named, kind in (
+                (("hc_attn", mix), self.mixer),
+                (("hc_ffn", feed), self.ffn or cfg.ffn),
+            ) if kind != NONE
+        ]
         if cfg.hyper is None:
-            x = x + scaled(mix(x))
-            x = x + scaled(feed(x))
+            for _, sublayer in sublayers:
+                x = x + scaled(sublayer(x))
             return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
 
         from raydp_tpu.models import hyperconn
 
-        for name, sublayer in (("hc_attn", mix), ("hc_ffn", feed)):
+        for name, sublayer in sublayers:
             maps = hyperconn.HyperMaps(
                 cfg.hyper, cfg.norm_eps, cfg.param_dtype, name=name
             )(x)
@@ -1511,6 +1536,56 @@ def mellum2_12b_a2_5b(**overrides) -> TransformerConfig:
     return TransformerConfig(**defaults)
 
 
+NEMOTRON_H_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+def hybrid_pattern_layers(pattern: str) -> Tuple[str, ...]:
+    """``layer_types`` of a ``nemotron_h`` ``hybrid_override_pattern``:
+    one character a layer and ONE sublayer a layer, ``M`` a Mamba-2
+    mixer, ``*`` attention, ``E`` the routed feed-forward part (``-``, the
+    family's dense relu² part, is in no published pattern this stack
+    runs and is not built)."""
+    kinds = {"M": "mamba:none", "*": "attention:none", "E": "none:moe"}
+    if set(pattern) - set(kinds):
+        raise ValueError(f"hybrid pattern {pattern!r}: M, * or E a layer")
+    return tuple(kinds[c] for c in pattern)
+
+
+def nemotron_3_nano_30b_a3b(**overrides) -> TransformerConfig:
+    """NVIDIA Nemotron 3 Nano 30B-A3B (31.6B parameters, about 3.2B active;
+    ``config.json`` of nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16,
+    ``model_type`` nemotron_h): 52 pre-norm layers of width 2688 in the
+    pattern ``MEMEM*E…`` where EVERY LAYER IS ONE SUBLAYER, ``x +
+    F(rms(x))``: a Mamba-2 mixer in 23 (64 heads of 64, state 128, EIGHT
+    groups of B and C, 4-tap convolution with bias, chunks of 128, the
+    gated norm over each group's 512 features), the routed feed-forward
+    part in 23 (128 UNGATED relu² experts of width 1856,
+    ``down(relu(up x)²)``, 6 a token by sigmoid score + a selection bias,
+    their scores divided by their sum and times 2.5, beside one shared
+    expert of the same form at width 3712) and grouped-query attention in
+    6 (32 query heads over 2 key-value heads of 128, no bias, no
+    positions); RMSNorm 1e-5, no auxiliary loss; vocabulary 131072,
+    untied head. ``pattern`` keeps the model's own first layers;
+    ``experts_held`` / ``first_expert`` give a layer the share of an
+    expert-parallel deployment."""
+    overrides = dict(overrides)
+    pattern = overrides.pop("pattern", NEMOTRON_H_PATTERN)
+    defaults = dict(
+        vocab_size=131072, d_model=2688, n_heads=32, n_kv_heads=2,
+        head_size=128, n_layers=len(pattern), max_len=262144,
+        dropout_rate=0.0, causal=True, norm="rmsnorm", norm_eps=1e-5,
+        positions="none", use_bias=False, ffn="moe", n_experts=128,
+        top_k=6, d_expert=1856, shared_experts=2, expert_form="relu2",
+        router_scoring="sigmoid", router_bias=True, norm_top_k=True,
+        routed_scaling=2.5, moe_loss_weights=(0.0, 0.0), tie_head=False,
+        layer_types=hybrid_pattern_layers(pattern),
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+        ssm_conv=4, ssm_chunk=128,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
 def tiny_transformer(**overrides) -> TransformerConfig:
     """Small MXU-aligned config for tests/dry runs (widths still /128)."""
     defaults = dict(
@@ -1519,6 +1594,25 @@ def tiny_transformer(**overrides) -> TransformerConfig:
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)
+
+
+def report(cfg) -> None:
+    """Static for a compiled step: the stack's layers by what they hold,
+    gauges where the step is built (as ``models/mamba.report``):
+    ``stack/layers``, ``stack/sublayers`` (two a layer unless it names
+    one), ``stack/mixer_only_layers`` and ``stack/ffn_only_layers``. Zero
+    for a model that is no such stack."""
+    from raydp_tpu.utils.profiling import metrics
+
+    layers = getattr(cfg, "layers", ())
+    mixer_only = sum(1 for _, ffn in layers if ffn == NONE)
+    ffn_only = sum(1 for mixer, _ in layers if mixer == NONE)
+    metrics.gauge_set("stack/layers", len(layers))
+    metrics.gauge_set(
+        "stack/sublayers", 2 * len(layers) - mixer_only - ffn_only
+    )
+    metrics.gauge_set("stack/mixer_only_layers", mixer_only)
+    metrics.gauge_set("stack/ffn_only_layers", ffn_only)
 
 
 # ------------------------------------------------------------- shardings

@@ -530,3 +530,39 @@ def test_step_reports_the_stack_once_where_it_is_built(builder, caplog):
     )._init_state(np.zeros((4, 16), np.int32))
     assert metrics.gauge_value("ssm/layers") == 0
     assert metrics.gauge_value("ssm/chunks_per_step") == 0
+
+
+# sha256 and length of ``str(jax.make_jaxpr(grad of a tiny Granite step's
+# loss))`` with the memory addresses jax prints for a checkpoint's policy
+# taken out, as the PARENT of PR 57 (commit 8b8d49a) printed them by this
+# same function: the gated norm's groups (one here), the one-sublayer
+# layers the stack may now hold (none here) and the expert's form (no
+# expert here) leave a Granite step the program it was. Regenerate from a
+# parent tree if jax changes how it prints.
+PARENT_STEPS = {
+    False: (107291, "157043d9e11a6ed4376034a5b4a7a2ec57ea5d54086d2e663633b2"
+                    "dfc8d45be6"),
+    True: (163068, "0541f9056d45eea157aaad1a97ab9a676d3036d2908e84760e66e78"
+                   "540689e3c"),
+}
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_a_granite_step_traces_to_the_parents_program(remat):
+    import hashlib
+
+    cfg = granite_h_micro(
+        n_layers=3, d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
+        vocab_size=64, ssm_heads=4, ssm_head_dim=8, ssm_state=8, ssm_chunk=8,
+        max_len=64, layer_types=("mamba", "attention", "mamba"),
+        dtype=jnp.bfloat16, remat=remat,
+    )
+    model = CausalLM(cfg)
+    ids = jnp.zeros((2, 32), jnp.int32)
+    variables = jax.eval_shape(
+        lambda: nn.unbox(model.init(jax.random.PRNGKey(0), ids)))
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(jax.grad(
+        lambda v, ids: lm_crossentropy(model.apply(v, ids), ids)
+    ))(variables, ids)))
+    assert (len(text), hashlib.sha256(text.encode()).hexdigest()) == (
+        PARENT_STEPS[remat])

@@ -217,11 +217,15 @@ def _layer(first=0, held=None, **kw):
     return MoELayer(MoEConfig(**defaults))
 
 
-@pytest.fixture(scope="module")
-def uncut():
-    """One uncut routed layer with seeded weights and a selection bias
-    large enough to change what some tokens get."""
-    layer = _layer()
+FORMS = sorted(moe_module.EXPERT_FORMS)
+
+
+@pytest.fixture(scope="module", params=FORMS)
+def uncut(request):
+    """One uncut routed layer (SwiGLU experts, and ungated relu² ones: the
+    cases below run through both forms) with seeded weights and a
+    selection bias large enough to change what some tokens get."""
+    layer = _layer(expert_form=request.param)
     x = jax.random.normal(jax.random.PRNGKey(5), (3, 7, 16))
     variables = _init(layer, x)
     bias = 0.5 * jax.random.normal(jax.random.PRNGKey(6), (8,))
@@ -234,7 +238,12 @@ def _held_by(variables, first, held=2):
     return dict(variables, params=dict(
         variables["params"],
         **{w: variables["params"][w][first:first + held]
-           for w in ("w_gate", "w_up", "w_down")}))
+           for w in ("w_gate", "w_up", "w_down") if w in variables["params"]}))
+
+
+def _form_of(variables) -> str:
+    """The form of the experts whose stacked weights ``variables`` hold."""
+    return "swiglu" if "w_gate" in variables["params"] else "relu2"
 
 
 def _plain_routed(variables, x, experts, scale=1.0, top_k=2, shared=False,
@@ -252,6 +261,11 @@ def _plain_routed(variables, x, experts, scale=1.0, top_k=2, shared=False,
     def swiglu(h, up):
         return h / (1.0 + np.exp(-h)) * up
 
+    def hidden(y, e):
+        if "w_gate" in p:
+            return swiglu(y @ p["w_gate"][e], y @ p["w_up"][e])
+        return np.maximum(y @ p["w_up"][e], 0.0) ** 2
+
     for t, y in enumerate(tokens):
         s = y @ p["router"]["kernel"]
         if scoring == "softmax":
@@ -265,11 +279,12 @@ def _plain_routed(variables, x, experts, scale=1.0, top_k=2, shared=False,
         gates = s[picked] / (s[picked].sum() + 1e-6) * scale
         for e, g in zip(picked, gates):
             if e in experts:
-                h = swiglu(y @ p["w_gate"][e], y @ p["w_up"][e])
-                out[t] += g * (h @ p["w_down"][e])
+                out[t] += g * (hidden(y, e) @ p["w_down"][e])
         if shared:
-            gate, up = np.split(y @ p["shared"]["in"]["kernel"], 2)
-            out[t] += swiglu(gate, up) @ p["shared"]["out"]["kernel"]
+            h = y @ p["shared"]["in"]["kernel"]
+            h = swiglu(*np.split(h, 2)) if "w_gate" in p else (
+                np.maximum(h, 0.0) ** 2)
+            out[t] += h @ p["shared"]["out"]["kernel"]
     return out.reshape(x.shape), chosen, unbiased
 
 
@@ -281,7 +296,7 @@ def test_sigmoid_bias_normalised_routing_against_a_plain_loop(uncut):
     # The bias changed the selection of some token, and only the selection:
     # the gates above are the scores.
     assert any(c != u for c, u in zip(chosen, unbiased))
-    scaled = _layer(gate_scale=2.5).apply(
+    scaled = _layer(gate_scale=2.5, expert_form=_form_of(variables)).apply(
         variables, x, mutable=[moe_module.STATS])[0]
     np.testing.assert_allclose(
         np.asarray(scaled), 2.5 * want, rtol=2e-4, atol=2e-5)
@@ -293,8 +308,9 @@ def test_sigmoid_bias_normalised_routing_against_a_plain_loop(uncut):
     (128, 16, 8, 0, 1.0, "softmax"),       # SDAR's eight (PR 47)
 ], ids=["four_of_8", "thirty_two_of_256_and_a_shared_expert",
         "eight_of_128_by_renormalised_softmax"])
+@pytest.mark.parametrize("form", FORMS)
 def test_the_shares_add_up_to_the_uncut_layer(experts, held, top_k, shared,
-                                              scale, scoring):
+                                              scale, scoring, form):
     """The share test: each chip holds ``held`` of the experts, routes
     over all of them, and returns its own experts' part (plus the shared
     expert's output, which every chip computes alike); the routed parts
@@ -302,7 +318,7 @@ def test_the_shares_add_up_to_the_uncut_layer(experts, held, top_k, shared,
     the plain loop, give."""
     kw = dict(n_experts=experts, top_k=top_k, gate_scale=scale,
               shared_experts=shared, scoring=scoring,
-              selection_bias=scoring == "sigmoid")
+              selection_bias=scoring == "sigmoid", expert_form=form)
     layer = _layer(**kw)
     x = jax.random.normal(jax.random.PRNGKey(5), (3, 7, 16))
     variables = _init(layer, x)
@@ -352,7 +368,7 @@ def test_a_shares_gradients_are_the_plain_formulas(uncut):
     bias = variables[moe_module.BUFFERS]
 
     def program(params, x):
-        out, _ = _layer(first, 2).apply(
+        out, _ = _layer(first, 2, expert_form=_form_of(variables)).apply(
             {"params": params, moe_module.BUFFERS: bias}, x,
             mutable=[moe_module.STATS])
         return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
@@ -364,8 +380,12 @@ def test_a_shares_gradients_are_the_plain_formulas(uncut):
         mask = jnp.argsort(jnp.argsort(-ranked, -1, stable=True), -1) < 2
         g = jnp.where(mask, s, 0.0)
         g = (g / (g.sum(-1, keepdims=True) + 1e-6))[:, first:first + 2]
-        h = jax.nn.silu(jnp.einsum("td,edf->tef", tokens, params["w_gate"]))
-        h = h * jnp.einsum("td,edf->tef", tokens, params["w_up"])
+        h = jnp.einsum("td,edf->tef", tokens, params["w_up"])
+        if "w_gate" in params:
+            h = h * jax.nn.silu(
+                jnp.einsum("td,edf->tef", tokens, params["w_gate"]))
+        else:
+            h = jnp.square(jax.nn.relu(h))
         out = jnp.einsum("tef,efd,te->td", h, params["w_down"], g)
         out = out.reshape(x.shape)
         return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(out.shape)))
@@ -389,8 +409,11 @@ def test_an_empty_and_a_full_share_lose_nothing(uncut, case):
     biased = dict(variables, **{moe_module.BUFFERS: {"expert_bias": bias}})
     mine = _held_by(biased, first)
 
+    form = _form_of(variables)
+
     def part(v):
-        return _layer(first, 2).apply(v, x, mutable=[moe_module.STATS])
+        return _layer(first, 2, expert_form=form).apply(
+            v, x, mutable=[moe_module.STATS])
 
     out, sown = part(mine)
     held = sown[moe_module.STATS]["held_tokens"]
@@ -403,7 +426,8 @@ def test_an_empty_and_a_full_share_lose_nothing(uncut, case):
         assert float(jnp.abs(grads["params"]["w_down"]).max()) == 0.0
     else:
         assert float(held.sum()) == 3 * 7 * 2
-        whole = _layer().apply(biased, x, mutable=[moe_module.STATS])[0]
+        whole = _layer(expert_form=form).apply(
+            biased, x, mutable=[moe_module.STATS])[0]
         want, _, _ = _plain_routed(biased, x, {first, first + 1})
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(whole), rtol=2e-4, atol=2e-5)
@@ -412,8 +436,9 @@ def test_an_empty_and_a_full_share_lose_nothing(uncut, case):
 
 def _share_and_grads(variables, x, first=4):
     """A share's part, what it sowed, and the gradients of a weighted sum
-    of it with respect to its input, its router and its three weights."""
-    layer = _layer(first, 2)
+    of it with respect to its input, its router and its stacked weights
+    (three, or two for an ungated expert)."""
+    layer = _layer(first, 2, expert_form=_form_of(variables))
 
     def part(v, x):
         out, sown = layer.apply(v, x, mutable=[moe_module.STATS])
@@ -425,7 +450,8 @@ def _share_and_grads(variables, x, first=4):
         part, argnums=(0, 1), has_aux=True)(variables, x)
     p = d_v["params"]
     return out, stats, (
-        d_x, p["router"]["kernel"], p["w_gate"], p["w_up"], p["w_down"])
+        d_x, p["router"]["kernel"],
+        *(p[w] for w in moe_module.EXPERT_FORMS[_form_of(variables)]))
 
 
 @pytest.mark.parametrize("routing,rows", [
@@ -450,6 +476,7 @@ def test_a_compact_share_is_the_whole_share(uncut, monkeypatch, routing, rows):
     mine = _held_by(variables, first)
     assert moe_module.compact_rows(_layer(first, 2).cfg, 3 * 7) == pairs
     want_out, stats, want = _share_and_grads(mine, x)
+    assert len(want) == 2 + len(moe_module.EXPERT_FORMS[_form_of(mine)])
     assert "overflow" not in stats              # C = T·k: no guard
     held = int(stats["held_tokens"].sum())
     if routing == "as_drawn":
@@ -471,7 +498,8 @@ def test_a_compact_share_is_the_whole_share(uncut, monkeypatch, routing, rows):
     if routing == "no_pair":
         np.testing.assert_array_equal(np.asarray(got_out), 0.0)
     if routing == "every_pair":
-        whole = _layer().apply(variables, x, mutable=[moe_module.STATS])[0]
+        whole = _layer(expert_form=_form_of(variables)).apply(
+            variables, x, mutable=[moe_module.STATS])[0]
         np.testing.assert_allclose(
             np.asarray(got_out), np.asarray(whole), rtol=2e-4, atol=2e-5)
     if held:                    # what an epoch of one such step reports

@@ -19,6 +19,7 @@ from raydp_tpu.models import (
     shortconv, sparse_index, stats, window,
 )
 from raydp_tpu.models import step as model_step
+from raydp_tpu.models.transformer import report as report_stack
 from raydp_tpu.ops.flash_attention import report as report_flash_tiles
 from raydp_tpu.train import JAXEstimator
 from raydp_tpu.utils.profiling import metrics
@@ -68,6 +69,7 @@ def _eleven_reports_by_hand(model, params, sample):
         model, "positions_per_token", 1)
     dropout.report(*dropout.census(
         model.apply, params, sample, also=model_step.step_rngs(model)))
+    report_stack(cfg)       # PR 57: the stack's layers by what they hold
     mamba.report(cfg, tokens_per_step=tokens)
     kda.report(cfg, tokens_per_step=tokens, sequence=seq_len)
     shortconv.report(cfg)

@@ -1,6 +1,8 @@
 """MoE tests: routing conservation, single-expert equivalence to a dense
-SwiGLU FFN, no token lost under extreme imbalance, aux loss, expert-sharded
-execution on the mesh."""
+FFN, no token lost under extreme imbalance, aux loss, expert-sharded
+execution on the mesh. The cases that run the expert path run it for both
+forms of expert (``FORMS``: SwiGLU, and the ungated relu² that has no
+``w_gate``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,7 @@ import flax.linen as nn
 import pytest
 
 from raydp_tpu.models.moe import (
+    EXPERT_FORMS,
     STATS,
     MoEConfig,
     MoELayer,
@@ -27,6 +30,24 @@ def _init(layer, x):
     return {"params": nn.unbox(layer.init(jax.random.PRNGKey(0), x))["params"]}
 
 
+FORMS = sorted(EXPERT_FORMS)
+
+
+def _leaves(form, *more):
+    """The leaves a case compares: the router, the form's stacked
+    matrices, and what the case adds."""
+    return ["router", *EXPERT_FORMS[form], *more]
+
+
+def _hidden(p, x):
+    """``[T, E, F]``: every token through the first half of every expert
+    whose stacked weights ``p`` holds, whatever their form."""
+    up = jnp.einsum("td,edf->tef", x, p["w_up"])
+    if "w_gate" in p:
+        return jax.nn.silu(jnp.einsum("td,edf->tef", x, p["w_gate"])) * up
+    return jnp.square(jax.nn.relu(up))
+
+
 def _every_expert_masked(params, x, top_k):
     """The layer written the plain way: every token through every expert,
     times the router's probabilities where they are among the k largest."""
@@ -36,33 +57,35 @@ def _every_expert_masked(params, x, top_k):
     # Ties (a zero router) go to the lowest indices, as lax.top_k's do.
     rank = jnp.argsort(jnp.argsort(-probs, axis=-1, stable=True), axis=-1)
     weights = jnp.where((probs >= kth) & (rank < top_k), probs, 0.0)
-    h = jax.nn.silu(jnp.einsum("td,edf->tef", x, p["w_gate"])) * jnp.einsum(
-        "td,edf->tef", x, p["w_up"]
-    )
-    return jnp.einsum("tef,efd,te->td", h, p["w_down"], weights)
+    return jnp.einsum("tef,efd,te->td", _hidden(p, x), p["w_down"], weights)
 
 
-def test_single_expert_equals_dense_ffn():
-    """E=1, k=1: the MoE must reduce to a plain SwiGLU FFN with gate
-    weight exactly 1 (softmax over one expert)."""
-    cfg = tiny_moe(n_experts=1, top_k=1)
+@pytest.mark.parametrize("form", FORMS)
+def test_single_expert_equals_dense_ffn(form):
+    """E=1, k=1: the MoE must reduce to a plain FFN of the expert's form
+    with gate weight exactly 1 (softmax over one expert)."""
+    cfg = tiny_moe(n_experts=1, top_k=1, expert_form=form)
     x = _tokens(16, cfg.d_model)
     layer = MoELayer(cfg)
     params = _init(layer, x)
     out, _ = layer.apply(params, x, mutable=["losses", STATS])
 
     p = params["params"]
-    want = (jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_up"][0])) @ p[
-        "w_down"
-    ][0]
+    assert set(p) == {"router", *EXPERT_FORMS[form]}
+    if form == "swiglu":
+        h = jax.nn.silu(x @ p["w_gate"][0]) * (x @ p["w_up"][0])
+    else:
+        h = jnp.maximum(x @ p["w_up"][0], 0.0) ** 2
+    want = h @ p["w_down"][0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
 
-def test_topk_dispatch_conservation():
+@pytest.mark.parametrize("form", FORMS)
+def test_topk_dispatch_conservation(form):
     """Every token reaches exactly top_k experts, weighted by its k
     largest router probabilities as they are: the layer equals "every
     expert on every token, masked", and the expert counts sum to T·k."""
-    cfg = tiny_moe(n_experts=4, top_k=2)
+    cfg = tiny_moe(n_experts=4, top_k=2, expert_form=form)
     x = _tokens(24, cfg.d_model, seed=1)
     layer = MoELayer(cfg)
     params = _init(layer, x)
@@ -75,11 +98,12 @@ def test_topk_dispatch_conservation():
     assert counts.sum() == 24 * 2
 
 
-def test_no_token_is_lost_when_every_token_picks_the_same_experts():
+@pytest.mark.parametrize("form", FORMS)
+def test_no_token_is_lost_when_every_token_picks_the_same_experts(form):
     """A zero router sends every token to experts 0 and 1 (ties go to the
     lowest index): twelve times their fair share. No capacity, so every
     token still gets both experts' outputs."""
-    cfg = tiny_moe(n_experts=4, top_k=2)
+    cfg = tiny_moe(n_experts=4, top_k=2, expert_form=form)
     x = _tokens(48, cfg.d_model, seed=2)
     layer = MoELayer(cfg)
     params = _init(layer, x)
@@ -264,12 +288,13 @@ def _loss_and_grads(layer, params, x):
     return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(params, x)
 
 
-@pytest.fixture(scope="module")
-def exchanged(eight_cpu_devices):
-    """One layer of 16 experts, 4 a token, on one device and over the four
-    chips of ``dp`` (4 experts a chip), under a routing skewed enough that
-    chip 1's pairs exceed ``compact_rows`` (patched to 128 of 512 pairs,
-    the uniform share): outputs, gradients and statistics of both."""
+@pytest.fixture(scope="module", params=FORMS)
+def exchanged(request, eight_cpu_devices):
+    """One layer of 16 experts (of either form), 4 a token, on one device
+    and over the four chips of ``dp`` (4 experts a chip), under a routing
+    skewed enough that chip 1's pairs exceed ``compact_rows`` (patched to
+    128 of 512 pairs, the uniform share): outputs, gradients and
+    statistics of both."""
     import dataclasses
     from unittest import mock
 
@@ -279,7 +304,7 @@ def exchanged(eight_cpu_devices):
     mesh = MeshSpec(dp=CHIPS).build()
     whole = tiny_moe(
         n_experts=16, top_k=4, normalize_gates=True, aux_loss_weight=0.0,
-        z_loss_weight=0.0,
+        z_loss_weight=0.0, expert_form=request.param,
     )
     over = dataclasses.replace(whole, expert_axis="dp", mesh=mesh)
     x = jnp.asarray(np.random.default_rng(5).standard_normal(
@@ -311,7 +336,8 @@ def exchanged(eight_cpu_devices):
                 }}, x, mutable=[STATS],
             )[0] for c in range(CHIPS)
         ]
-    return {"want": want, "got": got, "shares": shares}
+    return {"want": want, "got": got, "shares": shares,
+            "form": request.param}
 
 
 def test_the_exchange_drops_no_pair_under_skew(exchanged):
@@ -333,13 +359,15 @@ def test_the_exchange_drops_no_pair_under_skew(exchanged):
     )
 
 
-@pytest.mark.parametrize(
-    "leaf", ["router", "w_gate", "w_up", "w_down", "tokens"]
-)
+@pytest.mark.parametrize("exchanged,leaf", [
+    (form, leaf) for form in FORMS for leaf in _leaves(form, "tokens")
+], indirect=["exchanged"])
 def test_the_exchange_gives_the_one_device_gradients(exchanged, leaf):
     """(ii) Every gradient through the two collectives and the guard's
-    overflow branch equals the one-device layer's."""
+    overflow branch equals the one-device layer's; an ungated expert has
+    no ``w_gate`` to have one."""
     (want_p, want_x), _ = exchanged["want"]
+    assert set(want_p["params"]) == set(_leaves(exchanged["form"]))
     (got_p, got_x), _ = exchanged["got"]
     want, got = (want_x, got_x) if leaf == "tokens" else (
         jax.tree_util.tree_leaves(want_p["params"][leaf])[0],
@@ -465,19 +493,19 @@ def test_a_shares_token_sums_are_the_parents_formula(case, gated, monkeypatch):
             pull(src)[0], op.rows_to_tokens(src, None, *runs))
 
 
-@pytest.fixture(scope="module")
-def compact_share():
-    """A share (experts 2 and 3 of 8, two a token) whose expert path runs
-    over 48 of its 128 pairs, so that both token-side sums are the
-    kernel's: the layer's output and gradients, and the same of the dense
-    formula restricted to the held experts."""
+@pytest.fixture(scope="module", params=FORMS)
+def compact_share(request):
+    """A share (experts 2 and 3 of 8, of either form, two a token) whose
+    expert path runs over 48 of its 128 pairs, so that both token-side
+    sums are the kernel's: the layer's output and gradients, and the same
+    of the dense formula restricted to the held experts."""
     from unittest import mock
 
     from raydp_tpu.models import moe
 
     cfg = tiny_moe(
         n_experts=8, top_k=2, first_expert=2, held_experts=2,
-        aux_loss_weight=0.0, z_loss_weight=0.0,
+        aux_loss_weight=0.0, z_loss_weight=0.0, expert_form=request.param,
     )
     layer = MoELayer(cfg)
     x = _tokens(t=64)
@@ -488,10 +516,9 @@ def compact_share():
         probs = jax.nn.softmax(x @ p["router"]["kernel"], axis=-1)
         _, chosen = jax.lax.top_k(probs, 2)
         picked = jax.nn.one_hot(chosen, 8).sum(axis=1)[:, 2:4]
-        h = jax.nn.silu(jnp.einsum("td,edf->tef", x, p["w_gate"])) * (
-            jnp.einsum("td,edf->tef", x, p["w_up"]))
         return jnp.einsum(
-            "tef,efd,te->td", h, p["w_down"], probs[:, 2:4] * picked)
+            "tef,efd,te->td", _hidden(p, x), p["w_down"],
+            probs[:, 2:4] * picked)
 
     def both(fn):
         def loss(p, x):
@@ -507,9 +534,10 @@ def compact_share():
     return {"got": got, "want": both(dense), "jaxpr": text}
 
 
-@pytest.mark.parametrize(
-    "leaf", ["output", "router", "w_gate", "w_up", "w_down", "tokens"]
-)
+@pytest.mark.parametrize("compact_share,leaf", [
+    (form, leaf) for form in FORMS
+    for leaf in _leaves(form, "output", "tokens")
+], indirect=["compact_share"])
 def test_a_compact_shares_gradients_are_the_dense_formulas(compact_share, leaf):
     """The share layer through both kernel calls (the way back to tokens,
     forward; the cotangent of the way there, backward) against
