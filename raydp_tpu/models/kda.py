@@ -22,13 +22,13 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from raydp_tpu.models.mamba import (
-    CONV_IMPLEMENTATION,
     CausalConv1d,
     _decay_rate_init,
     _replicated,
@@ -90,6 +90,7 @@ class QKVConv(nn.Module):
     kda: KDAConfig
     dtype: jnp.dtype
     param_dtype: jnp.dtype
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, q, k, v):
@@ -98,7 +99,7 @@ class QKVConv(nn.Module):
         def conv(x, name, width):
             y = CausalConv1d(
                 kda.conv_taps, jnp.float32, self.param_dtype, use_bias=False,
-                name=name,
+                mesh=self.mesh, name=name,
             )(x)
             return y.reshape(*y.shape[:-1], kda.heads, width)
 
@@ -183,7 +184,9 @@ class KimiDeltaMixer(nn.Module):
             )
 
         wide, narrow = ("embed", "heads"), ("embed", None)
-        q, k, v = QKVConv(kda, cfg.dtype, cfg.param_dtype, name="conv")(
+        q, k, v = QKVConv(
+            kda, cfg.dtype, cfg.param_dtype, mesh=cfg.mesh, name="conv",
+        )(
             dense(keys, "q_proj", wide)(x), dense(keys, "k_proj", wide)(x),
             dense(values, "v_proj", wide)(x),
         )
@@ -238,11 +241,11 @@ def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
     if kda:
         logger.info(
             "delta-rule stack: layers %s; %d heads of %d (q, k) and %d (v), "
-            "a decay per channel, %d-tap convolutions as %s, gates of rank "
+            "a decay per channel, %d-tap convolutions, gates of rank "
             "%d; chunk %d (%d chunks a step); scan: %s; the forward keeps "
             "%d MiB of chunk inverses a sequence; a chunk's work and the "
             "walk over the chunks at these shapes: %s",
             " ".join(cfg.kinds), kda.heads, kda.key_dim, kda.value_dim,
-            kda.conv_taps, CONV_IMPLEMENTATION, kda.gate_rank, kda.chunk,
-            chunks, SCAN_IMPLEMENTATION, kept, SCAN_PATHS[kernels],
+            kda.conv_taps, kda.gate_rank, kda.chunk, chunks,
+            SCAN_IMPLEMENTATION, kept, SCAN_PATHS[kernels],
         )

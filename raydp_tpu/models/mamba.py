@@ -21,11 +21,13 @@ from __future__ import annotations
 import functools
 import logging
 import math
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from raydp_tpu.ops import causal_conv
 from raydp_tpu.ops.ssd import ssd_chunked
 
 logger = logging.getLogger(__name__)
@@ -80,16 +82,50 @@ def causal_depthwise_conv(x, kernel, bias=None):
     return out
 
 
+def conv_takes_kernel(sequence: int, channels: int, taps: int, x_dtype,
+                      out_dtype, sequence_minor: bool = False,
+                      mesh=None) -> bool:
+    """Whether a :class:`CausalConv1d` call of these shapes runs as the
+    Pallas kernels of ``ops/causal_conv.py``: on a TPU, where
+    ``causal_conv.uses_kernel`` takes the shapes and the compiler is not
+    left to partition the call. XLA cannot partition a Mosaic kernel, so
+    the program has to be one device's (no ``mesh`` told and one device
+    here) or the model's ``mesh`` has to be told, over whose ``dp`` the
+    call is then laid by a ``shard_map`` (``causal_conv_silu(mesh=)``); a
+    mesh that splits the sequence (``sp`` > 1) would gather it whole for
+    the kernel. Everywhere else, and at a shape the kernels decline (a
+    decode step's single token among them), the call is
+    :func:`causal_depthwise_conv` and ``jax.nn.silu``, which the compiler
+    partitions as it did."""
+    if jax.default_backend() != "tpu":
+        return False
+    if mesh is None and jax.device_count() > 1:
+        return False
+    if mesh is not None and mesh.shape.get("sp", 1) > 1:
+        return False
+    return causal_conv.uses_kernel(
+        sequence, channels, taps, x_dtype, out_dtype, sequence_minor
+    )
+
+
 class CausalConv1d(nn.Module):
     """Depthwise causal convolution over the sequence with a bias (none
     with ``use_bias`` off), and the SiLU that follows it: ``silu(b + Σ_j
     w_j · in_{t-(k-1)+j})``, zeros before the first token (``kernel``
-    [k, channels])."""
+    [k, channels]). One Pallas kernel forward and one backward where
+    :func:`takes_kernel` says so, the ``jax.numpy`` form elsewhere: the
+    same float32 arithmetic either way. ``sequence_minor`` tells the
+    kernels which layout the compiler gives the arrays around the call
+    (``ops/causal_conv.py``): the caller's knowledge, not a choice of
+    result. ``mesh`` is the model's (``cfg.mesh``), for a step compiled
+    for more than one device."""
 
     taps: int
     dtype: jnp.dtype
     param_dtype: jnp.dtype
     use_bias: bool = True
+    sequence_minor: bool = False
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, x):
@@ -102,9 +138,42 @@ class CausalConv1d(nn.Module):
         bias = self.param(
             "bias", _replicated(init), (channels,), self.param_dtype,
         ) if self.use_bias else None
+        if takes_kernel(self, x):
+            return causal_conv.causal_conv_silu(
+                x, kernel, bias, dtype=self.dtype,
+                sequence_minor=self.sequence_minor, mesh=self.mesh,
+            )
         return jax.nn.silu(
             causal_depthwise_conv(x, kernel, bias)
         ).astype(self.dtype)
+
+
+def takes_kernel(conv: CausalConv1d, x) -> bool:
+    """The form ``conv`` runs as on ``x``: :func:`conv_takes_kernel` of
+    the call's shapes. The module's own decision and the census's
+    (:func:`counting_convs`) are this one function."""
+    return x.ndim == 3 and conv_takes_kernel(
+        x.shape[1], x.shape[-1], conv.taps, x.dtype, conv.dtype,
+        conv.sequence_minor, conv.mesh,
+    )
+
+
+def counting_convs():
+    """``(interceptor, read)``: a flax method interceptor that counts the
+    :class:`CausalConv1d` calls made under it, a Mamba-2 mixer's and a
+    delta-rule layer's alike, by the form each takes, and the function
+    that reads ``(kernel calls, jax.numpy calls)`` afterwards (as
+    ``models/dropout.counting``)."""
+    found = [0, 0]
+
+    def count(next_fun, args, kwargs, context):
+        if (isinstance(context.module, CausalConv1d)
+                and context.method_name == "__call__"):
+            x = args[0] if args else next(iter(kwargs.values()))
+            found[0 if takes_kernel(context.module, x) else 1] += 1
+        return next_fun(*args, **kwargs)
+
+    return count, lambda: tuple(found)
 
 
 class SelectiveScan(nn.Module):
@@ -195,8 +264,15 @@ class Mamba2Mixer(nn.Module):
         z, xbc, dt = jnp.split(
             zxbcdt, [inner, 2 * inner + 2 * g * n], axis=-1
         )
+        # The compiler lays this mixer's arrays out with the sequence on
+        # the lanes, in_proj's product and the convolution's result alike:
+        # the chunked scan that consumes them contracts over a chunk's
+        # positions. Read in the compiled mixer at heads of 64 and of 128,
+        # one group and eight (PERF.md §6, PR 59; pinned by
+        # tests/test_olmoe.py): the convolution's kernels take that.
         xbc = CausalConv1d(
-            cfg.ssm_conv, cfg.dtype, cfg.param_dtype, name="conv"
+            cfg.ssm_conv, cfg.dtype, cfg.param_dtype, sequence_minor=True,
+            mesh=cfg.mesh, name="conv",
         )(xbc)
         xs, B, C = jnp.split(xbc, [inner, inner + g * n], axis=-1)
         lead = x.shape[:-1]
@@ -218,13 +294,20 @@ def layers_of(cfg) -> int:
     return sum(1 for kind in getattr(cfg, "kinds", ()) if kind == "mamba")
 
 
-def report(cfg, tokens_per_step: int) -> None:
-    """Static for a compiled step: the ``ssm/*`` gauges and one log line
-    where the step is built (as ``models/dropout.report``), nothing per
-    step. All zero for a stack without state-space layers."""
+def report(cfg, tokens_per_step: int, convs=(0, 0)) -> None:
+    """Static for a compiled step: the ``ssm/*`` gauges, the two
+    ``conv/*_calls`` gauges and one log line where the step is built
+    (as ``models/dropout.report``), nothing per step. All zero for a
+    stack without state-space layers. ``convs`` is what
+    :func:`counting_convs` read off the step's abstract apply
+    (``models/step.survey``): the :class:`CausalConv1d` calls of the whole
+    model, the delta-rule layers' among them, by the form each took."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
+    kernel_calls, jnp_calls = convs
+    metrics.gauge_set("conv/kernel_calls", kernel_calls)
+    metrics.gauge_set("conv/jnp_calls", jnp_calls)
     chunks = state = 0
     if layers:
         chunks = layers * -(-tokens_per_step // cfg.ssm_chunk)
@@ -245,9 +328,12 @@ def report(cfg, tokens_per_step: int) -> None:
             "hybrid stack: %d mamba and %d attention layers; attention %d "
             "query / %d key-value heads of %d; scan %d heads of %d in %d "
             "group(s), state %d, chunk %d (%d chunks a step); scan: %s; "
-            "convolution: %d taps as %s",
+            "convolution: %d taps; the stack's causal convolutions: %d as "
+            "one Pallas kernel each way (ops/causal_conv.py), %d as %s in "
+            "jax.numpy",
             layers, cfg.kinds.count("attention"), cfg.n_heads,
             cfg.kv_heads, cfg.head_dim, cfg.ssm_heads, cfg.ssm_head_dim,
             cfg.ssm_groups, cfg.ssm_state, cfg.ssm_chunk, chunks,
-            SCAN_IMPLEMENTATION, cfg.ssm_conv, CONV_IMPLEMENTATION,
+            SCAN_IMPLEMENTATION, cfg.ssm_conv, kernel_calls, jnp_calls,
+            CONV_IMPLEMENTATION,
         )

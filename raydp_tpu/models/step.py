@@ -110,12 +110,15 @@ def frozen(params) -> dict:
 
 class Survey(NamedTuple):
     """What ONE abstract training-mode apply says about a step: the
-    dropout census ``(sites, mask words)``, the model's outputs and the
+    dropout census ``(sites, mask words)``, the model's outputs, the
     input of every ``TransformerBlock`` by its name (a block returns what
-    it is given, so its result's shape is its input's)."""
+    it is given, so its result's shape is its input's) and the causal
+    convolutions ``(kernel calls, jax.numpy calls)`` by the form each
+    takes (``models/mamba.counting_convs``)."""
     dropout: Tuple[int, int] = (0, 0)
     out: Any = None
     blocks: dict = {}
+    convs: Tuple[int, int] = (0, 0)
 
 
 def survey(model, params, sample_batch) -> Survey:
@@ -125,6 +128,7 @@ def survey(model, params, sample_batch) -> Survey:
     if not _takes_deterministic(model):
         return Survey()
     count, read = dropout.counting()
+    count_convs, read_convs = mamba.counting_convs()
 
     def apply(variables, x):
         out, sown = model.apply(
@@ -135,14 +139,14 @@ def survey(model, params, sample_batch) -> Survey:
         )
         return out, sown.get("intermediates", {})
 
-    with nn.intercept_methods(count):
+    with nn.intercept_methods(count), nn.intercept_methods(count_convs):
         out, captured = jax.eval_shape(apply, params, sample_batch)
     blocks = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(captured):
         keys = [getattr(key, "key", None) for key in path]
         if "__call__" in keys:      # <module path>/block_i/__call__/0
             blocks[keys[keys.index("__call__") - 1]] = leaf
-    return Survey(read(), out, blocks)
+    return Survey(read(), out, blocks, read_convs())
 
 
 def report(model, params, sample_batch, surveyed=None) -> None:
@@ -162,7 +166,9 @@ def report(model, params, sample_batch, surveyed=None) -> None:
         model, "positions_per_token", 1
     )
     report_stack(cfg)
-    mamba.report(cfg, tokens_per_step=tokens_per_step)
+    mamba.report(
+        cfg, tokens_per_step=tokens_per_step, convs=surveyed.convs
+    )
     kda.report(cfg, tokens_per_step=tokens_per_step, sequence=seq_len)
     shortconv.report(cfg)
     latent.report(cfg)

@@ -5,6 +5,7 @@ pieces of the block against a few lines of ``jax.numpy``, the permutation's
 backward, no scatter of ``T·k`` rows in the compiled step, BERT's parameter
 tree and checkpoints unchanged; and the routed layer and the flash kernels
 at the published widths compiled for a described TPU v5e."""
+import functools
 import importlib.util
 import os
 import re
@@ -390,11 +391,11 @@ def test_bert_parameter_tree_and_checkpoint_are_unchanged(tmp_path):
 # ------------------------------ published widths, compiled for the chip
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described TPU v5e (nothing runs on it). Only the process that is
-    given this file loads the TPU's library."""
+def four_chips():
+    """The devices of a described host of four TPU v5e chips (nothing runs
+    on them). Only the process that is given this file loads the TPU's
+    library."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -403,7 +404,15 @@ def one_chip():
         )
     except Exception as exc:  # noqa: BLE001
         pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    """One of them, as a sharding."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(four_chips[0])
 
 
 def _on(sharding, tree):
@@ -859,3 +868,111 @@ def test_sparse_attention_kernels_compile_for_the_chip_at_keyes_shape(
                line.split(" custom-call(")[1]]
     assert len(columns) == (0 if len(backward) == 1 else 1)
     assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+
+
+# (S, channels, bias, output dtype, sequence_minor) of the three cells'
+# causal convolutions, as their mixers call them.
+CONV_CELLS = {
+    "granite": (4096, 2 * 2048 + 2 * 128, True, jnp.bfloat16, True),
+    "kimi": (16384, 4096, False, jnp.float32, False),
+    "nemotron": (16384, 4096 + 2 * 8 * 128, True, jnp.bfloat16, True),
+}
+
+
+@pytest.mark.parametrize("cell", list(CONV_CELLS))
+def test_causal_convolution_kernels_compile_for_the_chip_at_the_cells_shapes(
+        one_chip, cell):
+    """Mosaic takes both kernels (``ops/causal_conv.py``) in both forms of
+    the body as the cells tile them, and a gradient through one call is
+    the two custom calls with, between them, the result and its cotangent
+    at most: no padded copy, no float32 copy of the input."""
+    from raydp_tpu.ops.causal_conv import causal_conv_silu
+
+    s, channels, bias, out, sequence_minor = CONV_CELLS[cell]
+    like, f32 = jax.ShapeDtypeStruct, jnp.float32
+    args = (like((1, s, channels), jnp.bfloat16), like((4, channels), f32),
+            like((channels,), f32) if bias else None)
+
+    def loss(x, kernel, b):
+        y = causal_conv_silu(
+            x, kernel, b, dtype=out, sequence_minor=sequence_minor)
+        return jnp.sum(y.astype(f32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2) if bias else (0, 1)))
+    compiled = compiled.lower(*_on(one_chip, args)).compile()
+    calls = re.findall(r"%(causal_conv_\w+?)[.\d]* = ", compiled.as_text())
+    assert sorted(calls) == ["causal_conv_backward", "causal_conv_forward"]
+    size = s * channels * jnp.dtype(out).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * size
+
+
+def _mixer_gradient(cfg, x, variables_on):
+    """The compiled gradient of one ``Mamba2Mixer``'s squared output, as
+    text; ``variables_on`` places the abstract variables."""
+    from raydp_tpu.models.mamba import Mamba2Mixer
+
+    mixer = Mamba2Mixer(cfg)
+    variables = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1,) + x.shape[1:], x.dtype)))
+
+    def loss(variables, x):
+        return jnp.sum(mixer.apply(variables, x).astype(jnp.float32) ** 2)
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        variables_on(variables), x).compile().as_text()
+
+
+def test_the_compiler_lays_a_mamba2_mixers_convolution_out_sequence_minor(
+        one_chip, head_dim=128, groups=1):
+    """What ``Mamba2Mixer`` tells its convolution's kernels
+    (``sequence_minor=True``) is what the compiler does on its own: in the
+    mixer's gradient with the convolution in ``jax.numpy``, ``in_proj``'s
+    product, which the convolution reads, and the convolution's result are
+    laid out ``{1,2,0}``, the sequence on the lanes, at heads of 128 (here)
+    as at the cells' 64 (read on the chip; ``head_dim=64, groups=8`` is
+    Nemotron's mixer). The chunked scan that consumes them is why, not the
+    heads' size; a kernel that asked for ``{2,1,0}`` there cost Nemotron
+    6.7% in transposing copies (PERF.md §6, PR 59)."""
+    from raydp_tpu.models.transformer import granite_h_micro
+
+    cfg = granite_h_micro(
+        n_layers=1, layer_types=("mamba",), ssm_heads=4096 // head_dim,
+        ssm_head_dim=head_dim, ssm_groups=groups)
+    tokens, conv = 1024, 4096 + 2 * groups * cfg.ssm_state
+    hlo = _mixer_gradient(
+        cfg, jax.ShapeDtypeStruct(
+            (1, tokens, cfg.d_model), jnp.bfloat16, sharding=one_chip),
+        functools.partial(_on, one_chip))
+    assert "tpu_custom_call" not in hlo     # the CPU's choice of form
+    layouts = {
+        width: set(re.findall(
+            rf"= bf16\[1,{tokens},{width}\](\{{[\d,]+)[^ ]* fusion\(", hlo))
+        for width in (4096 + conv + cfg.ssm_heads, conv)
+    }
+    assert all(found == {"{1,2,0"} for found in layouts.values()), layouts
+
+
+def test_a_mamba2_mixers_gradient_compiles_for_four_chips(
+        four_chips, monkeypatch):
+    """dp = 2 by tp = 2 with the model's mesh told: XLA is not asked to
+    partition the convolution's Mosaic calls (it cannot); each chip runs
+    them on its own two sequences inside a ``shard_map``, and the taps'
+    sums meet in an all-reduce."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from raydp_tpu.models.transformer import granite_h_micro
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(four_chips).reshape(2, 2), ("dp", "tp"))
+    cfg = granite_h_micro(n_layers=1, layer_types=("mamba",), mesh=mesh)
+    hlo = _mixer_gradient(
+        cfg, jax.ShapeDtypeStruct(
+            (4, 1024, cfg.d_model), jnp.bfloat16,
+            sharding=NamedSharding(mesh, P("dp"))),
+        functools.partial(_on, NamedSharding(mesh, P())))
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 2
+    assert all("bf16[2,4352,1024]" in line for line in calls)
+    assert "all-reduce" in hlo
