@@ -16,8 +16,9 @@ from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
+import jax.numpy as jnp
 import numpy as np
-from jax.extend.core import Literal
+from jax.extend.core import Jaxpr, Literal
 
 from raydp_tpu.models import (
     blockdiff, dropout, hyperconn, kda, latent, mamba, moe, shortconv,
@@ -195,29 +196,40 @@ def report_epoch(stats_sum: dict, n_batches: int) -> None:
 # shapes and the device's memory: :func:`fit_checkpoint` reads both where
 # the step is built and releases the blocks whose residuals fit.
 
-#: What a compiled step holds of the blocks over :func:`kept_bytes`'
-#: count of them (the operands XLA chooses to write where the count takes
-#: them to fuse, HBM tile padding), and the share of the device's limit
-#: the estimate leaves free. Both from one table (PR 56; PERF.md section 6
-#: has it whole): the step compiled for a TPU v5e, ``memory_analysis()``
-#: arguments + temporaries, and the chip's own peak, in GiB of 15.75 —
+#: What a compiled step holds over :func:`estimated_bytes`' count of it
+#: (HBM tile padding, the copies XLA writes to change a layout), and the
+#: share of the device's limit the estimate leaves free. The count is a
+#: walk: what is HELD for the backward (the blocks before the one at work,
+#: released or checkpointed; the gradients of those after it; the logits'
+#: gradient where the head shares the embedding's table) beside the
+#: WORKING set of the one block whose forward or backward runs, the most
+#: bytes live at once in program order. Both from one table (PR 60;
+#: PERF.md section 6 has every row): the step compiled for a TPU v5e,
+#: ``memory_analysis()`` arguments + temporaries, in GiB of 15.75 —
 #:
-#:   cell (blocks)     all checkpointed      all released       as run
-#:                     estimate  compiled    estimate compiled  released peak
-#:   Granite (6)         11.80    10.53       14.58    13.44     6   13.51
-#:   LFM2 (7)            10.37    10.29       14.00    13.53     7   13.69
-#:   Xing4.0 (5)         12.49    10.83       15.94    13.90     2   12.12
-#:   Laguna (5)          15.24    12.29       19.90    15.03     0   12.47
-#:   Kimi Linear (5)     16.85    13.07       22.16    17.92     0   12.77
-#:   SDAR (6)            13.12    11.91       18.62    15.81     1   12.31
-#:   Keye (5)            12.74    10.24         -        -       2   12.10
+#:   cell (blocks)    all checkpointed    as run                 one more
+#:                    estimate compiled   released  est.  comp.   est.  comp.
+#:   Granite (6)        11.04   10.57     6 of 6   14.02  13.45     -     -
+#:   LFM2 (7)           10.92   10.29     7 of 7   14.19  13.53     -     -
+#:   Xing4.0 (5)        11.51   10.83     5 of 5   14.59  13.90     -     -
+#:   Laguna (5)         13.70   12.29     3, 4     14.95  13.52   16.27 13.69
+#:   Kimi Linear (5)    14.65   13.07     4        14.65  14.41     -     -
+#:   SDAR (6)           12.95   11.91     4, 5     14.30  13.49   15.65 13.81
+#:   Keye (5)           11.68   10.24     2, 3, 4  14.15  13.07   15.38 13.82
+#:   Nemotron (9)       12.45   12.10     5, 7, 8  14.57  13.89   15.33 13.97
+#:   Mellum2 (4, a chip) 12.42   9.91     1, 2, 3  14.40  12.43   15.70 13.45
 #:
-#: 1.6 is the least slack (in tenths) at which the estimate is no lower
-#: than the compiled figure in any row (LFM2, all checkpointed, binds);
-#: 5% of 15.75 GiB leaves the estimate 14.96, which admits the two stacks
-#: measured whole (Granite 13.51 and LFM2 13.69 GiB on the chip) and keeps
-#: every peak 1.2 GiB or more under the limit. Constants, not parameters.
-SLACK = 1.6
+#: 1.2 is the least slack (in tenths) at which the estimate is no lower
+#: than the compiled figure in any row of the table (Kimi Linear
+#: with its last block released binds: 14.65 against 14.41; it was 1.6 on
+#: a count that took everything to be live at once); 5% of 15.75 GiB
+#: leaves the estimate 14.96, which admits the two stacks measured whole
+#: (Granite 13.45 and LFM2 13.53 GiB compiled) and leaves every compiled
+#: choice 1.3 GiB or more under the limit. Where sequences are long the
+#: count still reads 1-2 GiB over the compiler, which orders the inside
+#: of a routed layer's conditional better than its program order.
+#: Constants, not parameters.
+SLACK = 1.2
 MARGIN = 0.05
 
 # Primitives whose result XLA computes inside the fusion that reads it
@@ -233,6 +245,13 @@ sharding_constraint shift_left shift_right_arithmetic
 shift_right_logical sign sin slice split sqrt square squeeze
 stop_gradient sub tan tanh transpose xor
 """.split())
+# Of those, the ones whose result is its operand's bytes in place.
+_VIEWS = frozenset((
+    "copy", "copy_p", "expand_dims", "mesh_cast", "name", "pvary", "reshape",
+    "sharding_constraint", "squeeze", "stop_gradient",
+))
+# What a literal or a constant is made of: no array, and it stands.
+_NOTHING = (frozenset(), True)
 # Calls whose body is read through: the callee's arrays are the caller's.
 _INLINED = frozenset((
     "pjit", "jit", "closed_call", "core_call", "custom_jvp_call",
@@ -243,124 +262,281 @@ def _nbytes(aval) -> int:
     return math.prod(aval.shape) * np.dtype(aval.dtype).itemsize
 
 
-def _held(jaxpr, entering, scale, named):
-    """For each of ``jaxpr``'s results, the arrays it is made of that a
-    program holds: ``{var: bytes}`` of results of primitives that write
-    (products, reductions, kernels, scans, collectives) and of the arrays
-    ``entering`` (one such dict an input). A ``shard_map``'s body is read
-    with its per-chip shapes scaled to the whole mesh. ``named`` gathers
-    ``{name: {var: bytes}}`` of every array given a name
-    (``checkpoint_name``) on the way."""
+def _held(jaxpr, entering, scale, named, sizes, order):
+    """For each of ``jaxpr``'s results, ``(held, whole)``: the set of
+    arrays it is made of that a program holds, results of primitives that
+    write (products, reductions, kernels, scans, collectives) and the
+    arrays ``entering`` (one such pair an input), and whether it IS one
+    array as it stands (written so, or a view of one). An array is its
+    index in ``sizes``, its bytes, which gains every array written on the
+    way; ``order`` gains, for each primitive that writes,
+    in program order, ``(the arrays it reads, the arrays it writes, what
+    it has live inside)``. A primitive that runs programs of its own (a
+    kernel, a conditional, a loop) reads arrays: an operand that is not
+    whole is written for it, and is that array from there on. A
+    ``shard_map``'s body is read with its per-chip shapes scaled to the
+    whole mesh. ``named`` gathers ``{name: {var: bytes}}`` of every value
+    given a name (``checkpoint_name``): a checkpoint's policy writes it
+    whatever it is made of."""
     env = dict(zip(jaxpr.invars, entering))
 
     def read(var):
         # Literals (unhashable) and constants: nothing held.
-        return {} if isinstance(var, Literal) else env.get(var, {})
+        return _NOTHING if isinstance(var, Literal) else env.get(
+            var, _NOTHING)
+
+    def written(var):
+        sizes.append(scale * _nbytes(var.aval))
+        return frozenset((len(sizes) - 1,)), True
 
     for eqn in jaxpr.eqns:
         name, params = eqn.primitive.name, eqn.params
         inputs = [read(v) for v in eqn.invars]
         inner = params.get("jaxpr", params.get("call_jaxpr"))
         if name in _INLINED and inner is not None:
-            outs = _held(getattr(inner, "jaxpr", inner), inputs, scale, named)
+            outs = _held(getattr(inner, "jaxpr", inner), inputs, scale,
+                         named, sizes, order)
         elif name == "shard_map":
             chips = math.prod(params["mesh"].shape.values())
-            outs = _held(params["jaxpr"], inputs, scale * chips, named)
+            outs = _held(params["jaxpr"], inputs, scale * chips, named,
+                         sizes, order)
         elif name in _FUSED:
+            merged = frozenset().union(*(held for held, _ in inputs))
             if name == "name":
                 var = eqn.outvars[0]
                 named.setdefault(params["name"], {})[var] = (
                     scale * _nbytes(var.aval)
                 )
-            # The dicts are never written after they are made: one
-            # operand's is handed on as it is.
-            held = [h for h in inputs if h]
-            merged = held[0] if len(held) == 1 else {
-                var: size for h in held for var, size in h.items()
-            }
-            outs = [merged] * len(eqn.outvars)
+            whole = name in _VIEWS and inputs[0][1]
+            outs = [(merged, whole)] * len(eqn.outvars)
         else:
-            outs = [{v: scale * _nbytes(v.aval)} for v in eqn.outvars]
+            programs = _programs(eqn)
+            for var in eqn.invars if programs else ():
+                if not read(var)[1]:
+                    made_of = read(var)[0]
+                    env[var] = written(var)
+                    order.append((made_of, env[var][0], 0))
+            outs = [written(v) for v in eqn.outvars]
+            order.append((
+                frozenset().union(*(read(v)[0] for v in eqn.invars)),
+                frozenset().union(*(held for held, _ in outs)),
+                # A kernel's own program works in VMEM.
+                0 if name == "pallas_call" else _inside(programs, scale),
+            ))
         env.update(zip(eqn.outvars, outs))
     return [read(v) for v in jaxpr.outvars]
 
 
-def kept_bytes(fun, state, *inputs, names=()) -> Tuple[int, int]:
-    """``(held, under a checkpoint)``. The first: bytes a program holds
-    between the forward and the backward of ``fun(state, *inputs)``, the
-    residuals of ``jax.vjp`` from ONE abstract trace (nothing runs,
-    nothing compiles), less ``state`` itself (the parameters are the
-    step's state, counted there) and with every residual that elementwise
-    and shape ops make counted as the arrays it is made of, once
-    (:data:`_FUSED`: XLA makes such a residual again inside the fusion
-    that reads it; JAX's own list, unfused, reads five times what a
-    compiled Mamba-2 block holds). ``inputs`` that a residual reaches are
-    held, and counted. The second: what the same call holds under a
-    checkpoint whose policy keeps ``names`` — its inputs and the arrays
-    the forward gives one of those names."""
-    def residuals(state, *inputs):
-        return jax.tree_util.tree_leaves(jax.vjp(fun, state, *inputs)[1])
-
-    jaxpr = jax.make_jaxpr(residuals)(state, *inputs).jaxpr
-    n_state = len(jax.tree_util.tree_leaves(state))
-    entering = [{} for _ in jaxpr.invars[:n_state]] + [
-        {v: _nbytes(v.aval)} for v in jaxpr.invars[n_state:]
+def _programs(eqn) -> list:
+    """The programs a primitive runs of its own: the jaxprs among its
+    parameters (a conditional's branches, a loop's body, a kernel's)."""
+    inner = [
+        getattr(each, "jaxpr", each) for value in eqn.params.values()
+        for each in (value if isinstance(value, (tuple, list)) else (value,))
     ]
-    held, named = {}, {}
-    for result in _held(jaxpr, entering, 1, named):
-        held.update(result)
-    under = sum(sum(e.values()) for e in entering) + sum(
+    return [each for each in inner if isinstance(each, Jaxpr)]
+
+
+def _inside(programs, scale) -> int:
+    """The most that ``programs``, which one primitive runs (a
+    conditional's branches, the greatest; a loop's body, one turn), have
+    live at once of what they write themselves, beyond their results,
+    which the caller counts as the primitive's."""
+    most = 0
+    for inner in programs:
+        sizes, order = [], []
+        results = frozenset().union(*(held for held, _ in _held(
+            inner, [_NOTHING] * len(inner.invars), scale, {}, sizes, order
+        )))
+        order.append((results, (), 0))
+        most = max(most, _most_live(sizes, order) - sum(
+            sizes[array] for array in results
+        ))
+    return most
+
+
+class Counted(NamedTuple):
+    """What one abstract trace of a block's forward and backward counts
+    (:func:`kept_bytes`), in bytes."""
+    released: int       # held between the forward and the backward
+    checkpointed: int   # the same under a checkpoint
+    working: int        # the most live at once while either runs
+    gradients: int      # what the parameters' gradients hold afterwards
+
+
+def kept_bytes(fun, state, *inputs, names=()) -> Counted:
+    """:class:`Counted` of ``fun(state, *inputs)`` from ONE abstract trace
+    of ``jax.vjp`` and its pullback (nothing runs, nothing compiles).
+
+    ``released``: bytes a program holds between the forward and the
+    backward, the residuals of ``jax.vjp`` less ``state`` itself (the
+    parameters are the step's state, counted there) and with every
+    residual that elementwise and shape ops make counted as the arrays it
+    is made of, once (:data:`_FUSED`: XLA makes such a residual again
+    inside the fusion that reads it; JAX's own list, unfused, reads five
+    times what a compiled Mamba-2 block holds). ``inputs`` that a residual
+    reaches are held, and counted.
+
+    ``checkpointed``: what the same call holds under a checkpoint whose
+    policy keeps ``names`` — its inputs and the arrays the forward gives
+    one of those names.
+
+    ``working``: the greatest number of bytes live at once while the
+    forward and then the backward run, read in program order with the
+    same fusing: an array lives from the primitive that writes it to the
+    last primitive that writes from it (an input from the start, the
+    result's cotangent from the backward's first read of it, a gradient
+    to the end); a conditional or a loop adds, while it runs, the most
+    its own programs have live (:func:`_inside`); a kernel's temporaries
+    are in VMEM and not counted.
+
+    ``gradients``: what the gradients of ``state`` are made of, held
+    from this call's backward to the update."""
+    n_state = len(jax.tree_util.tree_leaves(state))
+    shape = {}
+
+    def both(state, inputs, one):
+        out, pullback = jax.vjp(fun, state, *inputs)
+        residuals = jax.tree_util.tree_leaves(pullback)
+        cotangent = jax.tree_util.tree_map(
+            lambda leaf: jnp.full_like(leaf, one)
+            if jnp.issubdtype(leaf.dtype, jnp.inexact)
+            else np.zeros(leaf.shape, jax.dtypes.float0), out,
+        )
+        shape.update(
+            residuals=len(residuals),
+            cotangent=sum(map(_nbytes, jax.tree_util.tree_leaves(out))),
+        )
+        return residuals, pullback(cotangent)
+
+    jaxpr = jax.make_jaxpr(both)(
+        state, inputs, jax.ShapeDtypeStruct((), np.float32)
+    ).jaxpr
+    sizes = [_nbytes(v.aval) for v in jaxpr.invars[n_state:-1]]
+    # The result's cotangent: one array, the next block's to write.
+    cotangent = len(sizes)
+    sizes.append(shape["cotangent"])
+    entering = [_NOTHING] * n_state + [
+        (frozenset((array,)), True) for array in range(len(sizes))
+    ]
+    named, order = {}, []
+    results = [
+        held for held, _ in _held(jaxpr, entering, 1, named, sizes, order)
+    ]
+    kept = frozenset().union(*results[:shape["residuals"]]) - {cotangent}
+    under = sum(sizes[:cotangent]) + sum(
         sum(named.get(name, {}).values()) for name in names
     )
-    return sum(held.values()), under
+    gradients = results[shape["residuals"]:]
+    order.append((frozenset().union(*gradients), (), 0))
+    return Counted(
+        sum(sizes[array] for array in kept), under,
+        _most_live(sizes, order, cotangent),
+        sum(sizes[array] for array in frozenset().union(
+            *gradients[:n_state])),
+    )
 
 
-def released_blocks(
-    released: Sequence[int], checkpointed: Sequence[int], fixed: int,
-    limit: Optional[int],
-) -> Tuple[int, ...]:
+def _most_live(sizes, order, cotangent=None) -> int:
+    """The greatest sum of ``sizes`` live at once over ``order``'s steps:
+    an array is live from the step that writes it (``cotangent`` from the
+    step that first reads it, any other that no step writes from the
+    start) to the last step that reads it, and a step's third entry is
+    live during that step alone."""
+    born, last = {}, {}
+    for at, (reads, writes, _) in enumerate(order):
+        for array in writes:
+            born[array] = last[array] = at
+        for array in reads:
+            if array == cotangent:
+                born.setdefault(array, at)
+            last[array] = at
+    change = [0] * (len(order) + 1)
+    for array, at in last.items():
+        change[born.get(array, 0)] += sizes[array]
+        change[at + 1] -= sizes[array]
+    most = live = 0
+    for delta, (_, _, inside) in zip(change, order):
+        live += delta
+        most = max(most, live + inside)
+    return most
+
+
+class Stack(NamedTuple):
+    """What :func:`estimated_bytes` reads of a step, in one chip's bytes:
+    a :class:`Counted` each block (``released[i]``, ``checkpointed[i]``,
+    ``working[i]``, ``gradients[i]``), ``fixed`` what the step holds from
+    end to end (the state, the batch), ``head`` the logits (their
+    gradient is as much again) and ``head_stays`` what of the head is
+    still held while the blocks' backward runs: the logits' gradient
+    where the head shares the embedding's table, whose update waits for
+    the lookup's gradient at the very end (the compiled Granite and LFM2
+    steps hold it until then), nothing otherwise."""
+    released: Sequence[int]
+    checkpointed: Sequence[int]
+    working: Sequence[int]
+    gradients: Sequence[int]
+    fixed: int
+    head: int
+    head_stays: int
+
+
+class Estimate(NamedTuple):
+    """:func:`estimated_bytes`' result: ``total`` and its two parts where
+    it is greatest, before the slack."""
+    total: int
+    held: int
+    working: int
+
+
+def released_blocks(stack: Stack, limit: Optional[int]) -> Tuple[int, ...]:
     """WHICH blocks of a stack that may be checkpointed are not: as many
-    as fit. ``released[i]`` is what block i holds for its backward as the
-    plain block, ``checkpointed[i]`` what it holds under the checkpoint
-    (its input and the kernels' named results), ``fixed`` what the step
-    holds whatever the blocks do, ``limit`` the device's memory (None
-    where the backend reports none: nothing is released). A choice's
-    estimate (:func:`estimated_bytes`) has to stay under
-    ``limit · (1 - MARGIN)``.
+    as fit ``limit``, the device's memory (None where the backend reports
+    none: nothing is released). A choice's estimate
+    (:func:`estimated_bytes`) has to stay under ``limit · (1 - MARGIN)``.
 
     Blocks are tried LAST FIRST, each released if the estimate with it
     still fits: the backward walks the stack from its end, so a released
     last block's arrays are the first to be freed and are gone when an
-    earlier, checkpointed block makes its own again (the estimate adds
-    them up as if they were not, which errs to the safe side most for the
-    first blocks); and the order is a function of the bytes alone, so two
-    runs of one shape on one device make one program. Forward time saved
-    per byte kept would be the better order where kinds differ much; the
-    stacks measured so far either release everything or have one kind of
-    block in all layers but one (PERF.md section 6, PR 56)."""
+    earlier block's backward runs; and the order is a function of the
+    bytes alone, so two runs of one shape on one device make one program.
+    Forward time saved per byte kept would be the better order where
+    kinds differ much (PERF.md section 7)."""
     if limit is None:
         return ()
     out = ()
-    for i in reversed(range(len(released))):
-        if estimated_bytes(
-            released, checkpointed, fixed, out + (i,)
-        ) <= limit * (1.0 - MARGIN):
+    for i in reversed(range(len(stack.released))):
+        if estimated_bytes(stack, out + (i,)).total <= limit * (1.0 - MARGIN):
             out += (i,)
     return tuple(sorted(out))
 
 
-def estimated_bytes(released, checkpointed, fixed, out) -> int:
-    """What a step holds with the blocks ``out`` released: ``fixed`` +
-    :data:`SLACK` x (every block's share as it is run + the largest
-    checkpointed block's ``released`` bytes, since its forward runs again
-    inside the backward and what it makes is live then + the largest
-    block's ``released`` bytes once more, the cotangents and temporaries
-    of the backward at work in it), all of it taken to be live at once."""
-    stay = [i for i in range(len(released)) if i not in out]
-    blocks = sum(released[i] for i in out) + sum(
-        checkpointed[i] for i in stay
-    ) + max((released[i] for i in stay), default=0) + max(released, default=0)
-    return fixed + int(SLACK * blocks)
+def estimated_bytes(stack: Stack, out) -> Estimate:
+    """What a step holds with the blocks ``out`` released, as the
+    greatest total over the walk a step makes. Block i's forward and,
+    from the last block to the first, its backward run beside what blocks
+    0..i-1 hold for theirs (``released`` or ``checkpointed`` bytes each):
+    later blocks' arrays are not yet made in the forward and are freed in
+    the backward, where their parameters' ``gradients`` stay in their
+    place until the update (a kernel's and a conditional's are written
+    whole; the compiled steps keep them to the end), and ``working[i]``
+    covers both passes of block i, so a checkpointed block's second
+    forward too. Between the two walks the head runs, logits and their
+    gradient, beside what EVERY block holds. Each total is ``fixed`` +
+    :data:`SLACK` x (held + working)."""
+    held = [
+        stack.released[i] if i in out else stack.checkpointed[i]
+        for i in range(len(stack.released))
+    ]
+    n = len(held)
+    parts = max(
+        [(sum(held), 2 * stack.head)] + [(
+            sum(held[:i]) + sum(stack.gradients[i + 1:]) + stack.head_stays,
+            stack.working[i],
+        ) for i in range(n)],
+        key=sum,
+    )
+    return Estimate(stack.fixed + int(SLACK * sum(parts)), *parts)
 
 
 def device_limit(mesh) -> Optional[int]:
@@ -392,10 +568,11 @@ def _under(tree, name: str):
     return None
 
 
-def block_bytes(cfg, mixer: str, ffn: str, variables, x) -> Tuple[int, int]:
-    """``(released, checkpointed)``: what one block of this kind, with
-    these variables, holds for its backward at the input ``x`` as the
-    plain block and under the checkpoint. One abstract trace."""
+def block_bytes(cfg, mixer: str, ffn: str, variables, x) -> Counted:
+    """:class:`Counted` of one block of this kind, with these variables,
+    at the input ``x``: what it holds for its backward as the plain block
+    and under the checkpoint, and the most its two passes have live at
+    once. One abstract trace."""
     rngs = {"dropout": dropout.key_for(jax.random.PRNGKey(0))}
     block = TransformerBlock(cfg, mixer, ffn)
     return kept_bytes(
@@ -409,7 +586,9 @@ def _report_checkpoint(blocks, checkpointed, estimate, limit, fell_back):
 
     metrics.gauge_set("checkpoint/blocks", blocks)
     metrics.gauge_set("checkpoint/blocks_checkpointed", checkpointed)
-    metrics.gauge_set("checkpoint/estimated_bytes", estimate)
+    metrics.gauge_set("checkpoint/estimated_bytes", estimate.total)
+    metrics.gauge_set("checkpoint/held_bytes", estimate.held)
+    metrics.gauge_set("checkpoint/working_bytes", estimate.working)
     metrics.gauge_set("checkpoint/limit_bytes", limit or 0)
     metrics.gauge_set("checkpoint/fell_back", fell_back)
 
@@ -427,45 +606,51 @@ def fit_checkpoint(model, state, sample_batch, mesh, surveyed=None):
     their gathered size). The gauges ``checkpoint/*`` say what was decided
     from what."""
     cfg = getattr(model, "cfg", None)
+    nothing = Estimate(0, 0, 0)
     if not getattr(cfg, "remat", False):
-        _report_checkpoint(0, 0, 0, None, 0)
+        _report_checkpoint(0, 0, nothing, None, 0)
         return model
     n = cfg.n_layers
     limit = device_limit(mesh)
     if limit is None or cfg.released:
-        _report_checkpoint(n, sum(cfg.checkpointed), 0, limit, 0)
+        _report_checkpoint(n, sum(cfg.checkpointed), nothing, limit, 0)
         return model
     batch_chips = mesh.shape.get("dp", 1)
     surveyed = surveyed or survey(model, state.params, sample_batch)
     inputs = [surveyed.blocks[f"block_{i}"] for i in range(n)]
     # One trace a KIND of block: mixer, FFN and the input's shape.
     kinds = [(*layer, x.shape) for layer, x in zip(cfg.layers, inputs)]
-    sizes = {}
+    counted = {}
     for i, (kind, x) in enumerate(zip(kinds, inputs)):
-        if kind not in sizes:
+        if kind not in counted:
             # Layer i's own variables, a collection each, out of the
             # model's: the block's scope is its name.
             variables = {
                 name: found for name, tree in state.params.items()
                 if (found := _under(tree, f"block_{i}")) is not None
             }
-            sizes[kind] = block_bytes(cfg, *kind[:2], variables, x)
-    released = [sizes[kind][0] // batch_chips for kind in kinds]
-    checkpointed = [sizes[kind][1] // batch_chips for kind in kinds]
+            counted[kind] = block_bytes(cfg, *kind[:2], variables, x)
     head = sum(map(_nbytes, jax.tree_util.tree_leaves(surveyed.out)))
-    fixed = _chip_bytes(state) + (
-        2 * head + _nbytes(sample_batch)
-    ) // batch_chips
-    free = released_blocks(released, checkpointed, fixed, limit)
-    estimate = estimated_bytes(released, checkpointed, fixed, free)
+    stack = Stack(
+        *([size // batch_chips for size in sizes]
+          for sizes in zip(*(counted[kind] for kind in kinds))),
+        fixed=_chip_bytes(state) + _nbytes(sample_batch) // batch_chips,
+        head=head // batch_chips,
+        head_stays=head // batch_chips if cfg.tie_head else 0,
+    )
+    free = released_blocks(stack, limit)
+    estimate = estimated_bytes(stack, free)
     _report_checkpoint(n, n - len(free), estimate, limit, 0)
     logger.info(
         "block checkpoint: %d of %d blocks released %s; the step is "
-        "estimated to hold %.2f GiB of the chip's %.2f (%.2f whatever the "
-        "blocks do; a block released holds %s MiB, checkpointed %s)",
-        len(free), n, list(free), estimate / 2 ** 30, limit / 2 ** 30,
-        fixed / 2 ** 30, [a >> 20 for a in released],
-        [b >> 20 for b in checkpointed],
+        "estimated to hold %.2f GiB of the chip's %.2f: %.2f from end to "
+        "end, and where it holds most %d MiB kept for the backward beside "
+        "%d MiB at work (a block released keeps %s MiB, checkpointed %s, "
+        "and has %s at work; the head %d; gradients %s)",
+        len(free), n, list(free), estimate.total / 2 ** 30, limit / 2 ** 30,
+        stack.fixed / 2 ** 30, estimate.held >> 20, estimate.working >> 20,
+        *([size >> 20 for size in sizes] for sizes in stack[:3]),
+        stack.head >> 20, [size >> 20 for size in stack.gradients],
     )
     if not free:
         return model

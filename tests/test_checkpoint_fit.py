@@ -1,8 +1,9 @@
 """How many blocks of a ``remat`` stack are checkpointed follows from the
-shapes and the device's memory (PR 56): the rule as a pure function on the
-calibration table's rows, the model it makes, what one abstract trace
-counts of a block, the way back, and the two reports that follow the
-decision (``models/step.py``)."""
+shapes and the device's memory (PR 56; the count that walks the step, PR
+60): the rule as a pure function on the calibration table's rows, the
+estimate against every compiled step of that table, the model it makes,
+what one abstract trace counts of a block, the way back, and the two
+reports that follow the decision (``models/step.py``)."""
 import dataclasses
 import importlib
 
@@ -27,25 +28,103 @@ from raydp_tpu.train import estimator as estimator_module
 from raydp_tpu.utils.profiling import metrics
 
 MIB, GIB = 2 ** 20, 2 ** 30
-# One chip of a TPU v5e as its backend reports it.
-V5E = int(15.75 * GIB)
-# The calibration table's rows (PERF.md section 6, PR 56), in MiB: what a
-# block holds released and checkpointed, and what the step holds whatever
-# the blocks do (state, the head's output and its gradient).
+# One chip of a TPU v5e as its backend reports it (``bytes_limit``; my chip
+# runs, PR 60): 2 MiB under 15.75 GiB.
+V5E = 16_909_336_064
+
+
+def _row(released, checkpointed, working, gradients, fixed, head, stays=0):
+    return model_step.Stack(
+        *([size * MIB for size in sizes]
+          for sizes in (released, checkpointed, working, gradients)),
+        fixed * MIB, head * MIB, stays * MIB)
+
+
+# The calibration table's rows (PERF.md section 6, PR 60), in MiB and one
+# chip's: what a block holds released and checkpointed, the most its two
+# passes have live at once, what its parameters' gradients hold; what the
+# step holds from end to end (state, batch), the logits, and what of the
+# head stays beside the blocks' backward (a shared table: Granite, LFM2).
 ROWS = {
-    "granite": ([423] * 5 + [200], [16] * 5 + [32], 10540),
-    "lfm2": ([384, 384, 453, 467, 467, 467, 453],
-             [32, 32, 65, 32, 32, 32, 65], 8660),
-    "xing4": ([643, 761, 761, 761, 761], [144] * 5, 9200),
-    "laguna": ([1092, 1168, 1168, 1168, 1039],
-               [259, 324, 324, 324, 259], 9492),
+    "granite": _row(
+        [492] * 5 + [201], [16] * 5 + [32], [875] * 5 + [393],
+        [147] * 5 + [116], 7407, 1568, 1568),
+    "lfm2": _row(
+        [384, 384, 744, 710, 710, 710, 744], [32, 32, 65, 32, 32, 32, 65],
+        [644, 644, 1588, 1554, 1554, 1554, 1588],
+        [116, 116, 188, 200, 200, 200, 188], 7636, 512, 512),
+    "xing4": _row(
+        [660] + [951] * 4, [144] * 5, [1318] + [1698] * 4,
+        [246] + [247] * 4, 8690, 256),
+    "laguna": _row(
+        [1093, 1450, 1450, 1450, 1320], [259, 324, 324, 324, 259],
+        [1573, 3936, 3936, 3936, 3806], [152, 272, 272, 272, 256], 7915, 784),
+    "kimi": _row(
+        [2415, 2289, 2289, 1254, 2289], [344, 344, 344, 202, 344],
+        [3335, 5520, 5520, 4485, 5520], [197, 199, 199, 179, 199],
+        6895, 1280),
+    "sdar": _row(
+        [1343] * 6, [194] * 6, [3926] * 6, [181] * 6, 7389, 594),
+    "keye": _row(
+        [1246] * 5, [194] * 5, [3829] * 5, [185] * 5, 6435, 1187),
+    "nemotron": _row(
+        [1883, 727, 1883, 727, 1883, 358, 727, 1883, 727],
+        [84, 84, 84, 84, 84, 214, 84, 84, 84],
+        [2960, 3330, 2960, 3330, 2960, 617, 3330, 2960, 3330],
+        [77, 192, 77, 192, 77, 45, 192, 77, 192], 7633, 1024),
+    # One chip of four: the state as it lies there, the exchange's
+    # gathered rows at their gathered size.
+    "mellum2": _row(
+        [1163] * 4, [50] * 4, [3754] * 4, [388] * 4, 6811, 1536),
 }
 
 
-def _row(name):
-    released, checkpointed, fixed = ROWS[name]
-    return ([a * MIB for a in released], [b * MIB for b in checkpointed],
-            fixed * MIB)
+def _last(name, k):
+    n = len(ROWS[name].released)
+    return tuple(range(n - k, n))
+
+
+# Every step of the table compiled for a described v5e (``memory_analysis()``
+# arguments + temporaries, GiB): ``(cell, blocks released, compiled)``.
+COMPILED = [
+    ("granite", _last("granite", 0), 10.57),
+    ("granite", _last("granite", 6), 13.45),
+    ("lfm2", _last("lfm2", 0), 10.29),
+    ("lfm2", _last("lfm2", 7), 13.53),
+    ("xing4", _last("xing4", 0), 10.83),
+    ("xing4", _last("xing4", 2), 11.65),
+    ("xing4", _last("xing4", 3), 12.41),
+    ("xing4", _last("xing4", 4), 13.18),
+    ("xing4", _last("xing4", 5), 13.90),
+    ("laguna", _last("laguna", 0), 12.29),
+    ("laguna", _last("laguna", 1), 12.51),
+    ("laguna", _last("laguna", 2), 13.52),
+    ("laguna", (0, 4), 13.47),
+    ("laguna", _last("laguna", 3), 13.69),
+    ("laguna", _last("laguna", 5), 15.03),
+    ("kimi", _last("kimi", 0), 13.07),
+    ("kimi", _last("kimi", 1), 14.41),
+    ("kimi", _last("kimi", 5), 17.92),         # refused: over the chip
+    ("sdar", _last("sdar", 0), 11.91),
+    ("sdar", _last("sdar", 1), 12.04),
+    ("sdar", _last("sdar", 2), 13.49),
+    ("sdar", _last("sdar", 3), 13.81),
+    ("sdar", _last("sdar", 4), 14.25),
+    ("sdar", _last("sdar", 6), 15.81),         # refused: over by 60 MB
+    ("keye", _last("keye", 0), 10.24),
+    ("keye", _last("keye", 2), 11.90),
+    ("keye", _last("keye", 3), 13.07),
+    ("keye", _last("keye", 4), 13.82),
+    ("nemotron", _last("nemotron", 0), 12.10),
+    ("nemotron", (5, 7, 8), 13.89),
+    ("nemotron", _last("nemotron", 4), 13.97),
+    ("nemotron", _last("nemotron", 5), 15.78),
+    ("mellum2", _last("mellum2", 0), 9.91),
+    ("mellum2", _last("mellum2", 1), 10.28),
+    ("mellum2", _last("mellum2", 2), 11.42),
+    ("mellum2", _last("mellum2", 3), 12.43),
+    ("mellum2", _last("mellum2", 4), 13.45),
+]
 
 
 # ------------------------------------------------------------- the rule
@@ -55,49 +134,100 @@ def _row(name):
     ("lfm2", None, ()),
     ("granite", V5E, (0, 1, 2, 3, 4, 5)),     # whole, 13.5 GiB on the chip
     ("lfm2", V5E, (0, 1, 2, 3, 4, 5, 6)),     # whole, under 14 GiB
-    ("xing4", V5E, (3, 4)),                   # some, from the last
-    ("laguna", V5E, ()),                      # none: 12.5 GiB as it is
+    ("xing4", V5E, (0, 1, 2, 3, 4)),          # whole: 13.90 GiB compiled
+    ("laguna", V5E, (3, 4)),                  # 13.52 compiled
     ("laguna", 2 * V5E, (0, 1, 2, 3, 4)),
     ("granite", 8 * GIB, ()),                 # the state alone is over
+    ("sdar", V5E, (4, 5)),                    # 13.49 compiled
+    ("keye", V5E, (2, 3, 4)),                 # 13.07
+    ("kimi", V5E, (4,)),                      # 14.41: the row that binds
+    ("nemotron", V5E, (5, 7, 8)),             # 13.89; block 6 does not fit
+    ("mellum2", V5E, (1, 2, 3)),              # 12.43 a chip
 ])
 def test_the_rule_on_the_calibration_rows(name, limit, want):
-    assert model_step.released_blocks(*_row(name), limit) == want
+    assert model_step.released_blocks(ROWS[name], limit) == want
+
+
+@pytest.mark.parametrize("name, out, compiled", COMPILED, ids=[
+    f"{name}-{'.'.join(map(str, out)) or 'none'}" for name, out, _ in COMPILED
+])
+def test_the_estimate_is_no_lower_than_any_compiled_step(name, out, compiled):
+    estimate = model_step.estimated_bytes(ROWS[name], out)
+    assert estimate.total >= compiled * GIB
+    assert estimate.total == ROWS[name].fixed + int(
+        model_step.SLACK * (estimate.held + estimate.working))
+
+
+def test_the_slack_is_the_least_tenth_that_bounds_the_table(monkeypatch):
+    assert model_step.SLACK <= 1.6 and model_step.MARGIN == 0.05
+    monkeypatch.setattr(
+        model_step, "SLACK", round(model_step.SLACK - 0.1, 1))
+    under = [
+        (name, out) for name, out, compiled in COMPILED
+        if model_step.estimated_bytes(ROWS[name], out).total < compiled * GIB
+    ]
+    assert ("kimi", (4,)) in under
 
 
 @pytest.mark.parametrize("name", list(ROWS))
 def test_the_rule_is_monotone_and_never_over(name):
-    released, checkpointed, fixed = _row(name)
-    counts = []
+    stack = ROWS[name]
+    counts, kept = [], []
     for limit in range(8 * GIB, 26 * GIB, GIB // 4):
-        out = model_step.released_blocks(released, checkpointed, fixed, limit)
+        out = model_step.released_blocks(stack, limit)
         counts.append(len(out))
+        kept.append(sum(stack.released[i] for i in out))
         room = limit * (1 - model_step.MARGIN)
-        estimate = model_step.estimated_bytes(
-            released, checkpointed, fixed, out)
         # Nothing released is what the configuration wrote: it ran before.
-        assert not out or estimate <= room
+        assert not out or model_step.estimated_bytes(stack, out).total <= room
         # No block left that would have fitted beside those released.
-        for i in set(range(len(released))) - set(out):
+        for i in set(range(len(stack.released))) - set(out):
             later = tuple(j for j in out if j > i)
             assert model_step.estimated_bytes(
-                released, checkpointed, fixed, later + (i,)) > room
-    assert counts == sorted(counts)
-    assert counts[0] == 0 and counts[-1] == len(released)
+                stack, later + (i,)).total > room
+    # More memory never releases less: fewer blocks only where kinds
+    # differ (Nemotron: two Mamba blocks in the room of three small ones).
+    assert kept == sorted(kept)
+    assert counts == sorted(counts) or name == "nemotron"
+    assert counts[0] == 0 and counts[-1] == len(stack.released)
 
 
 def test_the_estimate_counts_a_checkpointed_blocks_second_forward():
-    released, checkpointed = [100, 300, 200], [10, 10, 10]
-    both = model_step.SLACK
-    # All checkpointed: the inputs, the largest block made again, and the
-    # largest block's backward at work.
-    assert model_step.estimated_bytes(released, checkpointed, 1000, ()) == (
-        1000 + int(both * (30 + 300 + 300)))
-    # The largest released: what is made again is the largest that stays.
-    assert model_step.estimated_bytes(released, checkpointed, 1000, (1,)) == (
-        1000 + int(both * (300 + 20 + 200 + 300)))
-    assert model_step.estimated_bytes(
-        released, checkpointed, 1000, (0, 1, 2)) == (
-        1000 + int(both * (600 + 0 + 300)))
+    """The walk: block i's two passes run beside what blocks 0..i-1 hold
+    and the gradients of blocks i+1.. ; the head beside what all hold."""
+    slack = model_step.SLACK
+    stack = model_step.Stack(
+        released=[100, 300, 200], checkpointed=[10, 10, 10],
+        working=[400, 700, 900], gradients=[1, 2, 4], fixed=1000, head=50,
+        head_stays=0)
+
+    def total(held, working):
+        return model_step.Estimate(
+            1000 + int(slack * (held + working)), held, working)
+
+    # All checkpointed: the last block's second forward and backward
+    # (its working set) beside the two inputs before it.
+    assert model_step.estimated_bytes(stack, ()) == total(20, 900)
+    # The last block released changes nothing there: what it keeps is in
+    # its own working set, and it is NOT live while block 0's backward
+    # runs (400 beside the later blocks' gradients alone).
+    assert model_step.estimated_bytes(stack, (2,)) == total(20, 900)
+    first = model_step.Stack(
+        [100, 300, 200], [10, 10, 10], [2000, 700, 900], [1, 2, 4], 1000, 50,
+        0)
+    assert model_step.estimated_bytes(first, (2,)) == total(2 + 4, 2000)
+    assert model_step.estimated_bytes(first, ()) == total(2 + 4, 2000)
+    # A block released is held while every later block's backward runs.
+    assert model_step.estimated_bytes(stack, (0, 1)) == total(400, 900)
+    # The logits and their gradient are live beside what EVERY block
+    # holds, and with no block's backward ...
+    big = stack._replace(head=2000)
+    assert model_step.estimated_bytes(big, (0, 1, 2)) == total(600, 4000)
+    assert model_step.estimated_bytes(big, ()) == total(30, 4000)
+    # ... unless the head shares the embedding's table: the logits'
+    # gradient then stays beside every block's backward.
+    tied = stack._replace(head_stays=50)
+    assert model_step.estimated_bytes(tied, (0, 1)) == total(450, 900)
 
 
 # ------------------------------------------------- the model it makes
@@ -178,7 +308,7 @@ def test_a_dense_blocks_kept_bytes_are_a_hand_count_without_parameters():
     block = TransformerBlock(cfg, "attention", "gelu")
     variables = nn.unbox(jax.eval_shape(
         lambda x: block.init(jax.random.PRNGKey(0), x, False), x))
-    released, checkpointed = model_step.block_bytes(
+    released, checkpointed, working, gradients = model_step.block_bytes(
         cfg, "attention", "gelu", variables, x)
     floats = (
         b * s * d           # the block's input
@@ -202,6 +332,58 @@ def test_a_dense_blocks_kept_bytes_are_a_hand_count_without_parameters():
         4 * int(np.prod(leaf.shape))
         for leaf in jax.tree_util.tree_leaves(variables))
     assert parameters > released / 4
+    # Every parameter's gradient is a product's or a sum's own array.
+    assert gradients == parameters
+    assert working > released
+
+
+def test_a_dense_blocks_working_set_is_a_hand_count():
+    """One sublayer, norm -> up -> gelu -> down -> residual: the most is
+    live where the backward takes the gradient back through ``mlp_up``."""
+    b, s, d, f = 2, SEQ, 32, 64
+    cfg = tiny_transformer(
+        vocab_size=64, d_model=d, n_heads=2, d_ff=f, max_len=s, n_layers=1,
+        causal=True, dtype=jnp.float32, remat=True,
+    )
+    x = jax.ShapeDtypeStruct((b, s, d), jnp.float32)
+    block = TransformerBlock(cfg, "none", "gelu")
+    variables = nn.unbox(jax.eval_shape(
+        lambda x: block.init(jax.random.PRNGKey(0), x, False), x))
+    counted = model_step.block_bytes(cfg, "none", "gelu", variables, x)
+    kept = (
+        b * s * d           # the block's input
+        + 2 * b * s         # the norm's two sums a row
+        + b * s * f         # mlp_up's product
+    )
+    assert counted.released == 4 * kept
+    assert counted.checkpointed == 4 * b * s * d
+    assert counted.working == 4 * (
+        kept                # all of it still read: the normed input and
+                            # the gelu's slope are made of these
+        + b * s * d         # the result's cotangent, the next block's
+        + d + f * d         # mlp_down's bias and kernel gradients, written
+        + b * s * f         # the gradient at the gelu's result
+        + f + d * f         # mlp_up's bias and kernel gradients
+        + b * s * d         # the gradient at the normed input, just
+                            # written (the norm's own sums come after and
+                            # are a row each)
+    )
+    # mlp_down's product is written in the forward and read by nothing:
+    # the forward's own most (kept + b * s * d) is under the backward's.
+    assert counted.gradients == 4 * (2 * d * f + f + 3 * d)
+
+
+def test_two_traces_of_one_shape_give_one_count():
+    model, ids = _stack()
+    variables = nn.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids)))
+    x = jax.ShapeDtypeStruct((2, SEQ, 32), jnp.float32)
+    layer = {"params": variables["params"]["encoder"]["block_1"]}
+    counts = {
+        model_step.block_bytes(model.cfg, "attention", "gelu", layer, x)
+        for _ in range(2)
+    }
+    assert len(counts) == 1 and all(min(count) > 0 for count in counts)
 
 
 def test_kept_bytes_reads_through_calls_and_counts_an_array_once():
@@ -214,12 +396,17 @@ def test_kept_bytes_reads_through_calls_and_counts_an_array_once():
     # The input (the weight's gradient reads it) and the product; tanh,
     # silu, exp and their products are made of the product. Under a
     # checkpoint: the input, and what the forward names for its policy.
-    assert model_step.kept_bytes(fun, w, x) == (2 * 4 * 4 * 8, 4 * 4 * 8)
+    # At work: both beside the weight's gradient (8 x 8) and the scalar
+    # result's cotangent; the input is read last there, so its own
+    # gradient then takes no more than its place.
+    assert model_step.kept_bytes(fun, w, x) == (
+        2 * 4 * 4 * 8, 4 * 4 * 8, 4 * (2 * 4 * 8 + 8 * 8 + 1), 4 * 8 * 8)
 
     def named(w, x):
         return fun(w, checkpoint_name(jnp.sin(x), "kept"))
 
-    assert model_step.kept_bytes(named, w, x, names=("kept", "absent")) == (
+    assert model_step.kept_bytes(
+        named, w, x, names=("kept", "absent"))[:2] == (
         2 * 4 * 4 * 8, 2 * 4 * 4 * 8)
 
 
@@ -245,8 +432,8 @@ def _estimator(**overrides):
 def _gauges():
     return {
         name: metrics.gauge_value(f"checkpoint/{name}") for name in (
-            "blocks", "blocks_checkpointed", "estimated_bytes",
-            "limit_bytes", "fell_back")
+            "blocks", "blocks_checkpointed", "estimated_bytes", "held_bytes",
+            "working_bytes", "limit_bytes", "fell_back")
     }
 
 
@@ -255,8 +442,8 @@ def test_a_backend_that_reports_no_limit_keeps_every_block_checkpointed():
     est.fit_on_df(_frame(), num_epochs=1)
     assert est._step_model is est._model
     assert _gauges() == dict(
-        blocks=3, blocks_checkpointed=3, estimated_bytes=0, limit_bytes=0,
-        fell_back=0)
+        blocks=3, blocks_checkpointed=3, estimated_bytes=0, held_bytes=0,
+        working_bytes=0, limit_bytes=0, fell_back=0)
 
 
 @pytest.mark.parametrize("limit, released", [
@@ -276,6 +463,9 @@ def test_the_limit_decides_and_the_gauges_say_so(
     assert got["limit_bytes"] == limit and got["fell_back"] == 0
     assert 0 < got["estimated_bytes"]
     assert (got["estimated_bytes"] <= limit) == bool(released)
+    # The estimate's two parts where it is greatest, before the slack.
+    assert 0 < got["held_bytes"] and 0 < got["working_bytes"]
+    assert got["held_bytes"] + got["working_bytes"] < got["estimated_bytes"]
 
 
 def test_the_loss_is_the_same_whichever_blocks_are_released(monkeypatch):

@@ -494,9 +494,9 @@ def test_the_block_checkpoint_rule_reads_a_layer_of_one_sublayer(tiny):
         own = {name: model_step._under(tree, f"block_{i}")
                for name, tree in variables.items()
                if model_step._under(tree, f"block_{i}") is not None}
-        released, checkpointed = model_step.block_bytes(cfg, *layer, own, x)
-        assert checkpointed == x.size * 4
-        assert released > checkpointed
+        counted = model_step.block_bytes(cfg, *layer, own, x)
+        assert counted.checkpointed == x.size * 4
+        assert counted.working > counted.released > counted.checkpointed
 
 
 def test_a_config_field_hands_the_form_on():
