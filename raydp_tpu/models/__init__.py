@@ -17,10 +17,12 @@ from raydp_tpu.models.transformer import (
     keye_vl_2_0_30b_a3b,
     mellum2_12b_a2_5b,
     nemotron_3_nano_30b_a3b,
+    ouro_2_6b,
     vocab_rules,
     xing4_0,
 )
 from raydp_tpu.models.blockdiff import BlockDiffusionConfig, BlockDiffusionLM
+from raydp_tpu.models.loop import LoopLM
 from raydp_tpu.models.sparse_index import SparseIndexConfig
 from raydp_tpu.models.hyperconn import HyperConfig
 from raydp_tpu.models.kda import KDAConfig
@@ -75,6 +77,8 @@ __all__ = [
     "keye_vl_2_0_30b_a3b",
     "mellum2_12b_a2_5b",
     "nemotron_3_nano_30b_a3b",
+    "ouro_2_6b",
+    "LoopLM",
     "vocab_rules",
     "SparseIndexConfig",
     "xing4_0",

@@ -21,7 +21,7 @@ import numpy as np
 from jax.extend.core import Jaxpr, Literal
 
 from raydp_tpu.models import (
-    blockdiff, dropout, hyperconn, kda, latent, mamba, moe, shortconv,
+    blockdiff, dropout, hyperconn, kda, latent, loop, mamba, moe, shortconv,
     sparse_index, stats, window,
 )
 from raydp_tpu.models.stats import merge  # noqa: F401  (two steps' statistics as one)
@@ -113,7 +113,9 @@ class Survey(NamedTuple):
     """What ONE abstract training-mode apply says about a step: the
     dropout census ``(sites, mask words)``, the model's outputs, the
     input of every ``TransformerBlock`` by its name (a block returns what
-    it is given, so its result's shape is its input's) and the causal
+    it is given, so its result's shape is its input's; ONE input a name,
+    which is right for a stack run ``cfg.passes`` times too: every
+    application of a block has the same shapes) and the causal
     convolutions ``(kernel calls, jax.numpy calls)`` by the form each
     takes (``models/mamba.counting_convs``)."""
     dropout: Tuple[int, int] = (0, 0)
@@ -177,6 +179,7 @@ def report(model, params, sample_batch, surveyed=None) -> None:
     sparse_index.report(cfg, seq_len=seq_len)
     blockdiff.report(model, batch=batch, seq_len=seq_len)
     hyperconn.report(cfg)
+    loop.report(model)
     report_flash_tiles(cfg, seq_len=seq_len, batch=batch)
     moe.report(model, tokens_per_step=tokens_per_step)
 
@@ -188,6 +191,7 @@ def report_epoch(stats_sum: dict, n_batches: int) -> None:
     hyperconn.report_epoch(stats_sum)
     blockdiff.report_epoch(stats_sum)
     sparse_index.report_epoch(stats_sum)
+    loop.report_epoch(stats_sum)
 
 
 # ------------------------------------------------- the block checkpoint
@@ -218,7 +222,14 @@ def report_epoch(stats_sum: dict, n_batches: int) -> None:
 #:   Keye (5)           11.68   10.24     2, 3, 4  14.15  13.07   15.38 13.82
 #:   Nemotron (9)       12.45   12.10     5, 7, 8  14.57  13.89   15.33 13.97
 #:   Mellum2 (4, a chip) 12.42   9.91     1, 2, 3  14.40  12.43   15.70 13.45
+#:   Ouro (6 x 4 passes)  11.56  12.15     4, 5     14.71  14.56   16.29   -
 #:
+#: (Ouro, PR 61: a stack run four times, the walk over its 24 applications.
+#: Its all-checkpointed row is the one row where the estimate reads UNDER
+#: the compiler, which starts the last pass's second forward before the
+#: exits and holds it; the rule never stops there at a v5e's limit, and
+#: the choice it makes and its neighbour, block 5 alone 13.14 against
+#: 13.06, are bounded.)
 #: 1.2 is the least slack (in tenths) at which the estimate is no lower
 #: than the compiled figure in any row of the table (Kimi Linear
 #: with its last block released binds: 14.65 against 14.41; it was 1.6 on
@@ -471,7 +482,14 @@ class Stack(NamedTuple):
     still held while the blocks' backward runs: the logits' gradient
     where the head shares the embedding's table, whose update waits for
     the lookup's gradient at the very end (the compiled Granite and LFM2
-    steps hold it until then), nothing otherwise."""
+    steps hold it until then), nothing otherwise.
+
+    A stack run ``passes`` times over one set of weights (a looped LM)
+    holds a block's kept arrays once an APPLICATION and its parameters'
+    gradients once a block; ``exits`` of its passes end in a head
+    (``head`` is ONE exit's logits: each exit's are made and freed before
+    the next's), and with more than one ``head_stays`` is the head's own
+    gradient, which the exits add up and hold until the update."""
     released: Sequence[int]
     checkpointed: Sequence[int]
     working: Sequence[int]
@@ -479,6 +497,8 @@ class Stack(NamedTuple):
     fixed: int
     head: int
     head_stays: int
+    passes: int = 1
+    exits: int = 1
 
 
 class Estimate(NamedTuple):
@@ -523,19 +543,37 @@ def estimated_bytes(stack: Stack, out) -> Estimate:
     covers both passes of block i, so a checkpointed block's second
     forward too. Between the two walks the head runs, logits and their
     gradient, beside what EVERY block holds. Each total is ``fixed`` +
-    :data:`SLACK` x (held + working)."""
+    :data:`SLACK` x (held + working).
+
+    With ``stack.passes`` > 1 the walk is over APPLICATIONS: pass t's
+    block i runs beside what every application of passes 0..t-1 and
+    blocks 0..i-1 of pass t hold (a block is released in all its
+    applications or in none), and beside the gradients of the blocks whose
+    backward has run in ANY pass: once a block, so all the others' in
+    every pass but the last. The exits run between the two walks, one
+    after the other, one exit's logits and their gradient at a time beside
+    what EVERY application holds; their gradients are made there
+    (``train/losses._exits_ce``), so where there is more than one the
+    head's own (``head_stays``) is held from then on."""
     held = [
         stack.released[i] if i in out else stack.checkpointed[i]
         for i in range(len(stack.released))
     ]
-    n = len(held)
-    parts = max(
-        [(sum(held), 2 * stack.head)] + [(
-            sum(held[:i]) + sum(stack.gradients[i + 1:]) + stack.head_stays,
+    n, gradients = len(held), sum(stack.gradients)
+    walk = [(
+        stack.passes * sum(held) + (stack.head_stays if stack.exits > 1 else 0),
+        2 * stack.head,
+    )]
+    for t in range(stack.passes):
+        last = t + 1 == stack.passes
+        walk += [(
+            t * sum(held) + sum(held[:i]) + stack.head_stays + (
+                sum(stack.gradients[i + 1:]) if last
+                else gradients - stack.gradients[i]
+            ),
             stack.working[i],
-        ) for i in range(n)],
-        key=sum,
-    )
+        ) for i in range(n)]
+    parts = max(walk, key=sum)
     return Estimate(stack.fixed + int(SLACK * sum(parts)), *parts)
 
 
@@ -630,13 +668,17 @@ def fit_checkpoint(model, state, sample_batch, mesh, surveyed=None):
                 if (found := _under(tree, f"block_{i}")) is not None
             }
             counted[kind] = block_bytes(cfg, *kind[:2], variables, x)
-    head = sum(map(_nbytes, jax.tree_util.tree_leaves(surveyed.out)))
+    exits = loop.exit_bytes(model, surveyed.out)
+    if exits is None:       # one head: the model's output is its logits
+        head = sum(map(_nbytes, jax.tree_util.tree_leaves(surveyed.out)))
+        exits = (1, head, head if cfg.tie_head else 0)
     stack = Stack(
         *([size // batch_chips for size in sizes]
           for sizes in zip(*(counted[kind] for kind in kinds))),
         fixed=_chip_bytes(state) + _nbytes(sample_batch) // batch_chips,
-        head=head // batch_chips,
-        head_stays=head // batch_chips if cfg.tie_head else 0,
+        head=exits[1] // batch_chips,
+        head_stays=exits[2] // batch_chips,
+        passes=cfg.passes, exits=exits[0],
     )
     free = released_blocks(stack, limit)
     estimate = estimated_bytes(stack, free)

@@ -21,6 +21,7 @@ TPU-first design:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -218,6 +219,17 @@ class TransformerConfig:
     # step is built, not a configuration's).
     remat: bool = False
     released: Tuple[int, ...] = ()
+    # How many times the WHOLE stack runs over its one set of weights (a
+    # looped LM): pass t's blocks are pass 0's modules, the final norm
+    # closes every pass and its output enters the next, and the encoder
+    # can hand back every pass's normed state (``every_pass``; ``LoopLM``
+    # in ``models/loop.py`` puts an exit after each). 1 = the stack as it
+    # was, op for op.
+    passes: int = 1
+    # A norm on each sublayer's OUTPUT too, before the residual add:
+    # ``x + norm_out(F(norm_in(x)))``, under the input norm's name +
+    # ``_out`` (``ln_attn_out``, ``ln_mlp_out``, ...).
+    branch_norm: bool = False
     dtype: Any = jnp.bfloat16        # compute dtype (MXU-friendly)
     param_dtype: Any = jnp.float32
     mesh: Any = None                 # ring/ulysses; flash on >1 device
@@ -755,6 +767,10 @@ class TransformerBlock(nn.Module):
                 return branch
             return branch * cfg.residual_multiplier
 
+        # The mixer's input norm, by the name it has always had.
+        mixer_norm = {"mamba": "ln_mamba", "conv": "ln_conv",
+                      "kda": "ln_kda"}.get(self.mixer, "ln_attn")
+
         def mix(h):
             """The layer's mixer on its own norm of ``h``."""
             if self.mixer in ("attention", "window", "sparse"):
@@ -767,7 +783,7 @@ class TransformerBlock(nn.Module):
                     name="attn",
                 )
                 return attend(
-                    _norm(cfg, "ln_attn")(h),
+                    _norm(cfg, mixer_norm)(h),
                     deterministic,
                     cache_mode=cache_mode,
                     cache_positions=cache_positions,
@@ -788,21 +804,21 @@ class TransformerBlock(nn.Module):
                 from raydp_tpu.models.mamba import Mamba2Mixer
 
                 return Mamba2Mixer(cfg, name="mamba")(
-                    _norm(cfg, "ln_mamba")(h)
+                    _norm(cfg, mixer_norm)(h)
                 )
             if self.mixer == "conv":
                 from raydp_tpu.models.shortconv import ShortConv
 
-                return ShortConv(cfg, name="conv")(_norm(cfg, "ln_conv")(h))
+                return ShortConv(cfg, name="conv")(_norm(cfg, mixer_norm)(h))
             if self.mixer == "kda":
                 from raydp_tpu.models.kda import KimiDeltaMixer
 
                 return KimiDeltaMixer(cfg, name="kda")(
-                    _norm(cfg, "ln_kda")(h)
+                    _norm(cfg, mixer_norm)(h)
                 )
             from raydp_tpu.models.latent import LatentAttention
 
-            return LatentAttention(cfg, name="attn")(_norm(cfg, "ln_attn")(h))
+            return LatentAttention(cfg, name="attn")(_norm(cfg, mixer_norm)(h))
 
         def feed(h):
             """The layer's FFN on its own norm of ``h``."""
@@ -847,11 +863,19 @@ class TransformerBlock(nn.Module):
                 y = Dropout(cfg.dropout_rate)(y, deterministic)
             return y
 
+        def normed(sublayer, name):
+            """``sublayer`` with the configuration's norm on its output
+            (``cfg.branch_norm``), under the input norm's name + ``_out``."""
+            if not cfg.branch_norm:
+                return sublayer
+            return lambda h: _norm(cfg, name)(sublayer(h))
+
         # The sublayers the layer has, in order: two unless it names one.
         sublayers = [
-            named for named, kind in (
-                (("hc_attn", mix), self.mixer),
-                (("hc_ffn", feed), self.ffn or cfg.ffn),
+            (name, normed(sublayer, norm + "_out"))
+            for (name, sublayer, norm), kind in (
+                (("hc_attn", mix, mixer_norm), self.mixer),
+                (("hc_ffn", feed, "ln_mlp"), self.ffn or cfg.ffn),
             ) if kind != NONE
         ]
         if cfg.hyper is None:
@@ -910,6 +934,13 @@ class TransformerEncoder(nn.Module):
     Input: int32 token ids [B, S] (+ optional segment ids; ``positions``
     [B or 1, S] where rotary positions are not ``0 … S-1``). Output:
     [B, S, d_model] hidden states.
+
+    With ``cfg.passes`` = T > 1 the blocks run T times, the SAME modules
+    (one set of parameters, whose gradient is the sum over the
+    applications), ``ln_final`` closes every pass and its output enters
+    the next; pass t's ops lie under the scope ``pass_<t>``. The output is
+    the last pass's normed state, or with ``every_pass`` the tuple of all
+    T. Every application of a block has the same shapes.
     """
 
     cfg: TransformerConfig
@@ -925,8 +956,15 @@ class TransformerEncoder(nn.Module):
         cache_positions=None,
         kv_len: Optional[int] = None,
         positions=None,
+        every_pass: bool = False,
     ):
         cfg = self.cfg
+        if cfg.passes > 1 and (
+                cache_mode is not None or cfg.hyper is not None):
+            raise NotImplementedError(
+                "a stack run several times keeps no decode cache (one a "
+                "pass: ROADMAP Reach) and carries one residual stream"
+            )
         if positions is not None and cfg.positions not in ("rotary", "mrope"):
             raise NotImplementedError(
                 f"given positions with cfg.positions={cfg.positions!r}"
@@ -988,22 +1026,40 @@ class TransformerEncoder(nn.Module):
         # Given positions go to the blocks that rotate by them; a call
         # without them is the call it was.
         given = {} if positions is None else {"positions": positions}
-        for i, (mixer, ffn) in enumerate(cfg.layers):
-            block_cls = checkpointed if cfg.checkpointed[i] else (
-                TransformerBlock
-            )
-            x = block_cls(cfg, mixer, ffn, name=f"block_{i}")(
-                x,
-                deterministic,
-                cache_mode=cache_mode,
-                cache_positions=cache_positions,
-                kv_len=kv_len,
-                **given,
-            )
-        if cfg.hyper is not None:
-            with jax.named_scope("hc_reduce"):
-                x = hyperconn.reduce(x)
-        return _norm(cfg, "ln_final")(x)
+        # The modules are made once and called ``passes`` times: a Python
+        # loop, so that a trace tells the passes apart and the compiler
+        # orders 24 applications as it orders 6 (PERF.md section 6, PR 61).
+        # One pass adds no scope: the program it was.
+        blocks = [
+            (checkpointed if cfg.checkpointed[i] else TransformerBlock)(
+                cfg, mixer, ffn, name=f"block_{i}"
+            ) for i, (mixer, ffn) in enumerate(cfg.layers)
+        ]
+        ln_final = _norm(cfg, "ln_final")
+        pass_scope = (lambda t: jax.named_scope(f"pass_{t}")) if (
+            cfg.passes > 1) else (lambda t: contextlib.nullcontext())
+        states = []
+        for t in range(cfg.passes):
+            with pass_scope(t):
+                for block in blocks:
+                    x = block(
+                        x,
+                        deterministic,
+                        cache_mode=cache_mode,
+                        cache_positions=cache_positions,
+                        kv_len=kv_len,
+                        **given,
+                    )
+                if cfg.hyper is not None:
+                    with jax.named_scope("hc_reduce"):
+                        x = hyperconn.reduce(x)
+                x = ln_final(x)
+            if t + 1 < cfg.passes:
+                # Written once: the next pass and an exit both read it
+                # (as ``CausalLM`` writes the state its head reads).
+                x = jax.lax.optimization_barrier(x)
+            states.append(x)
+        return tuple(states) if every_pass else x
 
 
 class SequenceClassifier(nn.Module):
@@ -1581,6 +1637,26 @@ def nemotron_3_nano_30b_a3b(**overrides) -> TransformerConfig:
         layer_types=hybrid_pattern_layers(pattern),
         ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
         ssm_conv=4, ssm_chunk=128,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def ouro_2_6b(**overrides) -> TransformerConfig:
+    """Ouro-2.6B (ByteDance; ``config.json`` of ByteDance/Ouro-2.6B,
+    ``model_type`` ouro; "Scaling Latent Reasoning via Looped Language
+    Models", arXiv:2510.25741): 48 decoder layers of width 2048, 16 heads
+    of 128 (no grouping) with rotary positions at theta 1e6, a SwiGLU FFN
+    of width 5632, RMSNorm (eps 1e-6) on every sublayer's input AND
+    output, no biases; the whole stack run ``total_ut_steps`` = 4 times
+    over its one set of weights, the final norm closing every pass;
+    vocabulary 49,152, untied head. ``models/loop.LoopLM`` puts the exit
+    gate and the head after every pass."""
+    defaults = dict(
+        vocab_size=49152, d_model=2048, n_heads=16, n_layers=48, d_ff=5632,
+        max_len=65536, dropout_rate=0.0, causal=True, norm="rmsnorm",
+        norm_eps=1e-6, positions="rotary", rope_theta=1e6, use_bias=False,
+        ffn="swiglu", tie_head=False, passes=4, branch_norm=True,
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

@@ -68,7 +68,7 @@ def lm_crossentropy(logits, tokens):
     b, s = logits.shape[:2]
     # ``[1, S]``: every position but the last.
     weights = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
-    return _weighted_ce(b * (s - 1), logits, targets, weights)
+    return _weighted_ce((b * (s - 1), False), logits, targets, weights)
 
 
 def weighted_crossentropy(logits, targets, weights):
@@ -79,7 +79,8 @@ def weighted_crossentropy(logits, targets, weights):
     pass (it is this loss with a ``[1, S]`` weight); no gradient reaches
     the weights. float32 throughout."""
     return _weighted_ce(
-        logits.shape[0] * logits.shape[1], logits, targets.astype(jnp.int32),
+        (logits.shape[0] * logits.shape[1], False), logits,
+        targets.astype(jnp.int32),
         jax.lax.stop_gradient(weights.astype(jnp.float32)),
     )
 
@@ -94,28 +95,129 @@ def blockdiff_crossentropy(preds, tokens):
     return weighted_crossentropy(logits, tokens, weights)
 
 
+def loop_exit_crossentropy(preds, tokens):
+    """A looped LM's training loss (``models/loop.py``): ``preds`` is what
+    a ``LoopLM`` returns in training (``LoopExits``: the T passes' normed
+    states, the log of a token's exit distribution ``p`` [T, B, S], the
+    head's [D, V] matrix, β), ``tokens`` the ids, position s predicting
+    token s + 1 as :func:`lm_crossentropy` has it:
+
+        1/(B(S-1)) Σ_{b, s<S-1} [ Σ_t p_t · CE(head(h_t), token s+1)
+                                  - β · H(p) ],   H(p) = -Σ_t p_t log p_t
+
+    float32 throughout. The head runs HERE, one exit at a time
+    (:func:`_exits_ce`): an exit's logits [B, S, V] and their gradient
+    live while that exit runs and no longer. The gates that define ``p``
+    learn through the weights of the cross-entropies and the entropy.
+    Given an array (what the model returns in ``evaluate``: the last
+    exit's logits) this is :func:`lm_crossentropy` of it."""
+    if not isinstance(preds, tuple):
+        return lm_crossentropy(preds, tokens)
+    states, log_probs, head, beta = preds
+    targets = jnp.roll(tokens.astype(jnp.int32), -1, axis=1)
+    b, s = targets.shape
+    count = b * (s - 1)
+    valid = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
+    probs = jnp.exp(log_probs)
+    expected = _exits_ce(count, tuple(states), head, targets, probs * valid)
+    entropy = -jnp.sum(probs * log_probs, axis=0)
+    return expected - beta * (jnp.sum(entropy * valid) / count)
+
+
+def _head_product(h, head):
+    """``nn.Dense(dtype=float32)``'s product, under its scope."""
+    with jax.named_scope("lm_head"):
+        return jax.lax.dot_general(
+            h.astype(jnp.float32), head.astype(jnp.float32),
+            (((h.ndim - 1,), (0,)), ((), ())),
+        )
+
+
+def _exits_ce_fwd(count, states, head, targets, weights):
+    """The forward pass makes every gradient too. An exit's loss is linear
+    in what comes back to it, so its logits' gradient is made WHERE THE
+    LOGITS ARE MADE, by :func:`_weighted_ce`'s own backward expression at
+    a cotangent of 1, and goes at once through the head's two backward
+    products: what is kept for the backward pass are the states'
+    gradients ([B, S, D] each), ONE head-sized sum and the weights'
+    cotangents, and no exit's head runs a second time. An
+    ``optimization_barrier`` between exits holds exit t + 1's state back
+    until everything exit t's logits' gradient feeds is written: one
+    exit's logits and their gradient live at a time, by the program's
+    order and not by the scheduler's choice."""
+    how, one = (count, True), jnp.ones((), jnp.float32)
+    total = d_head = d_state = None
+    d_states, d_weights = [], []
+    for t, h in enumerate(states):
+        with jax.named_scope(f"exit_{t}"):
+            if t:
+                h, d_head, d_state = jax.lax.optimization_barrier(
+                    (h, d_head, d_state)
+                )
+                d_states[-1] = d_state
+            logits, back = jax.vjp(_head_product, h, head)
+            loss, kept = _weighted_ce_fwd(how, logits, targets, weights[t])
+            d_logits, _, d_weight = _weighted_ce_bwd(how, kept, one)
+            d_state, d_matrix = back(d_logits)
+        total = loss if total is None else total + loss
+        d_head = d_matrix if d_head is None else d_head + d_matrix
+        d_states.append(d_state)
+        d_weights.append(d_weight)
+    return total, (tuple(d_states), d_head, jnp.stack(d_weights))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _exits_ce(count, states, head, targets, weights):
+    """``Σ_t Σ w_t · CE(h_t @ head, targets) / count`` over the exits of a
+    looped LM: ``states`` a tuple of T arrays [B, S, D], ``head`` [D, V],
+    ``weights`` [T, B, S] (they learn: their cotangent is a token's
+    cross-entropy at that exit)."""
+    return _exits_ce_fwd(count, states, head, targets, weights)[0]
+
+
+def _exits_ce_bwd(count, gradients, g):
+    d_states, d_head, d_weights = gradients
+    return (
+        tuple(d * g.astype(d.dtype) for d in d_states), d_head * g, None,
+        d_weights * g,
+    )
+
+
+_exits_ce.defvjp(_exits_ce_fwd, _exits_ce_bwd)
+
+
 def _is_target(logits, targets):
     vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
     return vocab == targets[..., None]
 
 
-def _weighted_ce_fwd(count, logits, targets, weights):
+def _weighted_ce_fwd(how, logits, targets, weights):
+    count, learn = how
     x = logits.astype(jnp.float32)
     top = jnp.max(x, axis=-1)
     lse = top + jnp.log(jnp.sum(jnp.exp(x - top[..., None]), axis=-1))
     label = jnp.sum(jnp.where(_is_target(x, targets), x, 0.0), axis=-1)
     loss = jnp.sum((lse - label) * weights) / count
-    return loss, (logits, lse, targets, weights)
+    # Weights that learn get the cross-entropy a token as their cotangent:
+    # kept from here ([B, S]), not made again from the logits.
+    return loss, (logits, lse, targets, weights) + (
+        (lse - label,) if learn else ()
+    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _weighted_ce(count, logits, targets, weights):
-    """``Σ w · CE / count``: ``weights`` broadcasts against ``[B, S]``."""
-    return _weighted_ce_fwd(count, logits, targets, weights)[0]
+def _weighted_ce(how, logits, targets, weights):
+    """``Σ w · CE / count``: ``weights`` broadcasts against ``[B, S]``.
+    ``how`` is ``(count, learn)``: with ``learn`` the weights get their
+    cotangent, a token's cross-entropy scaled as the forward is (a looped
+    LM's exit probabilities are such weights); without it none reaches
+    them and the program is the one it was."""
+    return _weighted_ce_fwd(how, logits, targets, weights)[0]
 
 
-def _weighted_ce_bwd(count, residuals, g):
-    logits, lse, targets, weights = residuals
+def _weighted_ce_bwd(how, residuals, g):
+    count, learn = how
+    logits, lse, targets, weights, *nll = residuals
     x = logits.astype(jnp.float32)
     scale = (weights * (g / count))[..., None]
     grad = (
@@ -127,7 +229,14 @@ def _weighted_ce_bwd(count, residuals, g):
     # each computes it again for every tile of its output: 7.5 ms of the
     # step above, 4.7 at ``[2, 4096, 50304]`` (PERF.md §6, PR 31).
     grad = jax.lax.optimization_barrier(grad.astype(logits.dtype))
-    return grad, None, jnp.zeros_like(weights)
+    if not learn:
+        return grad, None, jnp.zeros_like(weights)
+    to_weights = nll[0] * (g / count)
+    broadcast = tuple(
+        axis for axis, size in enumerate(weights.shape)
+        if size == 1 and to_weights.shape[axis] != 1
+    )
+    return grad, None, jnp.sum(to_weights, axis=broadcast, keepdims=True)
 
 
 _weighted_ce.defvjp(_weighted_ce_fwd, _weighted_ce_bwd)
@@ -144,6 +253,7 @@ LOSSES: Dict[str, Callable] = {
     "sparse_categorical_crossentropy": softmax_crossentropy,
     "lm_ce": lm_crossentropy,
     "blockdiff_ce": blockdiff_crossentropy,
+    "loop_exit_ce": loop_exit_crossentropy,
 }
 
 

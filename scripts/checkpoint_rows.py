@@ -207,7 +207,8 @@ def main():
     print(f"{args.cell}: the rule releases {chosen} ({time.perf_counter() - t0:.2f} s)")
     print("STACK", json.dumps({
         name: [round(v / MIB) for v in value] if isinstance(value, list) else round(value / MIB)
-        for name, value in stack._asdict().items()}), "(MiB)")
+        for name, value in stack._asdict().items() if name not in ("passes", "exits")}),
+        f"(MiB); {stack.passes} pass(es), {stack.exits} exit(s)")
     n = len(stack.released)
     for k in range(n + 1):
         estimate = model_step.estimated_bytes(stack, tuple(range(n - k, n)))

@@ -33,11 +33,12 @@ MIB, GIB = 2 ** 20, 2 ** 30
 V5E = 16_909_336_064
 
 
-def _row(released, checkpointed, working, gradients, fixed, head, stays=0):
+def _row(released, checkpointed, working, gradients, fixed, head, stays=0,
+         passes=1, exits=1):
     return model_step.Stack(
         *([size * MIB for size in sizes]
           for sizes in (released, checkpointed, working, gradients)),
-        fixed * MIB, head * MIB, stays * MIB)
+        fixed * MIB, head * MIB, stays * MIB, passes, exits)
 
 
 # The calibration table's rows (PERF.md section 6, PR 60), in MiB and one
@@ -76,6 +77,12 @@ ROWS = {
     # gathered rows at their gathered size.
     "mellum2": _row(
         [1163] * 4, [50] * 4, [3754] * 4, [388] * 4, 6811, 1536),
+    # A stack run four times with an exit after every pass (PR 61): a
+    # block's bytes count once an application, ``head`` is ONE exit's
+    # logits and ``stays`` the head's own gradient.
+    "ouro": _row(
+        [401] * 6, [64] * 6, [587] * 6, [98] * 6, 5833, 1536, 384,
+        passes=4, exits=4),
 }
 
 
@@ -124,6 +131,12 @@ COMPILED = [
     ("mellum2", _last("mellum2", 2), 11.42),
     ("mellum2", _last("mellum2", 3), 12.43),
     ("mellum2", _last("mellum2", 4), 13.45),
+    # With NO block released the compiled step reads 12.15 GiB and the
+    # estimate 11.56: the compiler starts the last pass's second forward
+    # before the exits and holds it (PERF.md section 7, PR 61). Not a row:
+    # the rule never stops there at this limit.
+    ("ouro", _last("ouro", 1), 13.06),
+    ("ouro", _last("ouro", 2), 14.56),
 ]
 
 
@@ -143,6 +156,7 @@ COMPILED = [
     ("kimi", V5E, (4,)),                      # 14.41: the row that binds
     ("nemotron", V5E, (5, 7, 8)),             # 13.89; block 6 does not fit
     ("mellum2", V5E, (1, 2, 3)),              # 12.43 a chip
+    ("ouro", V5E, (4, 5)),                    # 14.56; 14.11 on the chip
 ])
 def test_the_rule_on_the_calibration_rows(name, limit, want):
     assert model_step.released_blocks(ROWS[name], limit) == want

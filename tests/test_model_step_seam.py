@@ -15,7 +15,8 @@ import pandas as pd
 import pytest
 
 from raydp_tpu.models import (
-    CausalLM, blockdiff, dropout, hyperconn, kda, latent, mamba, moe, olmoe,
+    CausalLM, blockdiff, dropout, hyperconn, kda, latent, loop, mamba, moe,
+    olmoe,
     shortconv, sparse_index, stats, window,
 )
 from raydp_tpu.models import step as model_step
@@ -78,6 +79,7 @@ def _eleven_reports_by_hand(model, params, sample):
     sparse_index.report(cfg, seq_len=seq_len)
     blockdiff.report(model, batch=batch, seq_len=seq_len)
     hyperconn.report(cfg)
+    loop.report(model)      # PR 61: a stack run several times, its exits
     report_flash_tiles(cfg, seq_len=seq_len, batch=batch)
     moe.report(model, tokens_per_step=tokens)
 
