@@ -27,12 +27,18 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from raydp_tpu.ops import causal_conv
-from raydp_tpu.ops.ssd import ssd_chunked
+from raydp_tpu.ops import causal_conv, ssd
 
 logger = logging.getLogger(__name__)
 
-SCAN_IMPLEMENTATION = "chunked state-space dual form in jax.numpy (ops/ssd.py)"
+SCAN_IMPLEMENTATION = (
+    "chunked state-space dual form (ops/ssd.py): two Pallas kernels, "
+    "forward and backward, that hold a chunk's decay matrix and the states "
+    "in VMEM and read x, B and C out of the convolution's one result, on a "
+    "TPU where a head's channels are a multiple of 64, the state and the "
+    "chunk multiples of 128 and a group's heads go in blocks of eight; the "
+    "same form in jax.numpy otherwise"
+)
 CONV_IMPLEMENTATION = "shifted multiply-adds"
 
 
@@ -82,28 +88,33 @@ def causal_depthwise_conv(x, kernel, bias=None):
     return out
 
 
+def _kernels_may_run(mesh) -> bool:
+    """Whether a Mosaic kernel may stand in this program: on a TPU, and
+    where the compiler is not left to partition the call (it cannot). The
+    program has to be one device's (no ``mesh`` told and one device here)
+    or the model's ``mesh`` has to be told, over whose ``dp`` the call is
+    then laid by a ``shard_map``; a mesh that splits the sequence (``sp``
+    > 1) would gather it whole for the kernel."""
+    if jax.default_backend() != "tpu":
+        return False
+    if mesh is None:
+        return jax.device_count() == 1
+    return mesh.shape.get("sp", 1) == 1
+
+
 def conv_takes_kernel(sequence: int, channels: int, taps: int, x_dtype,
                       out_dtype, sequence_minor: bool = False,
                       mesh=None) -> bool:
     """Whether a :class:`CausalConv1d` call of these shapes runs as the
-    Pallas kernels of ``ops/causal_conv.py``: on a TPU, where
-    ``causal_conv.uses_kernel`` takes the shapes and the compiler is not
-    left to partition the call. XLA cannot partition a Mosaic kernel, so
-    the program has to be one device's (no ``mesh`` told and one device
-    here) or the model's ``mesh`` has to be told, over whose ``dp`` the
-    call is then laid by a ``shard_map`` (``causal_conv_silu(mesh=)``); a
-    mesh that splits the sequence (``sp`` > 1) would gather it whole for
-    the kernel. Everywhere else, and at a shape the kernels decline (a
+    Pallas kernels of ``ops/causal_conv.py``: where a Mosaic kernel may
+    stand at all (:func:`_kernels_may_run`; with a ``mesh`` the call is
+    ``causal_conv_silu(mesh=)``'s ``shard_map``) and
+    ``causal_conv.uses_kernel`` takes the shapes. Everywhere else, and at
+    a shape the kernels decline (a
     decode step's single token among them), the call is
     :func:`causal_depthwise_conv` and ``jax.nn.silu``, which the compiler
     partitions as it did."""
-    if jax.default_backend() != "tpu":
-        return False
-    if mesh is None and jax.device_count() > 1:
-        return False
-    if mesh is not None and mesh.shape.get("sp", 1) > 1:
-        return False
-    return causal_conv.uses_kernel(
+    return _kernels_may_run(mesh) and causal_conv.uses_kernel(
         sequence, channels, taps, x_dtype, out_dtype, sequence_minor
     )
 
@@ -158,34 +169,70 @@ def takes_kernel(conv: CausalConv1d, x) -> bool:
     )
 
 
-def counting_convs():
+def _counting(kind, takes):
     """``(interceptor, read)``: a flax method interceptor that counts the
-    :class:`CausalConv1d` calls made under it, a Mamba-2 mixer's and a
-    delta-rule layer's alike, by the form each takes, and the function
-    that reads ``(kernel calls, jax.numpy calls)`` afterwards (as
-    ``models/dropout.counting``)."""
+    calls of modules of ``kind`` made under it by the form each takes
+    (``takes(module, *operands)``), and the function that reads ``(kernel
+    calls, jax.numpy calls)`` afterwards (as ``models/dropout.counting``)."""
     found = [0, 0]
 
     def count(next_fun, args, kwargs, context):
-        if (isinstance(context.module, CausalConv1d)
+        if (isinstance(context.module, kind)
                 and context.method_name == "__call__"):
-            x = args[0] if args else next(iter(kwargs.values()))
-            found[0 if takes_kernel(context.module, x) else 1] += 1
+            taken = takes(context.module, *args, *kwargs.values())
+            found[0 if taken else 1] += 1
         return next_fun(*args, **kwargs)
 
     return count, lambda: tuple(found)
 
 
+def counting_convs():
+    """The census of the :class:`CausalConv1d` calls, a Mamba-2 mixer's
+    and a delta-rule layer's alike (:func:`_counting`)."""
+    return _counting(CausalConv1d, takes_kernel)
+
+
+def scan_takes_kernels(sequence: int, heads: int, head_dim: int, groups: int,
+                       state: int, chunk: int, mesh=None) -> bool:
+    """Whether a :class:`SelectiveScan` call of these shapes runs as the
+    Pallas kernels of ``ops/ssd.py``: where a Mosaic kernel may stand at
+    all (:func:`_kernels_may_run`), the mesh does not split the heads,
+    ``ssd.uses_kernels`` takes the shapes and the sequence is whole
+    chunks. The kernels' ``shard_map`` lays the rows over ``dp`` and
+    nothing else: with ``tp`` > 1 every chip of a ``tp`` group would
+    gather ``in_proj``'s product whole and scan all the heads, where XLA
+    partitions the ``jax.numpy`` form over them, so such a mesh keeps
+    that form (not measured either way: no cell runs a Mamba stack on
+    four chips). Everywhere else (``model.init``'s one-chunk sample among
+    them) the call is ``ssd.ssd_chunked``, which the compiler partitions
+    as it did."""
+    return (
+        _kernels_may_run(mesh)
+        and (mesh is None or mesh.shape.get("tp", 1) == 1)
+        and sequence % chunk == 0
+        and ssd.uses_kernels(heads, groups, head_dim, state, chunk)
+    )
+
+
 class SelectiveScan(nn.Module):
     """The scan's own parameters (``A_log``, ``dt_bias``, ``D``, one a
-    head, float32) and the call of ``ssd_chunked``."""
+    head, float32) and the call of ``ops/ssd.py`` on a mixer's convolved
+    ``xbc`` [b, s, heads · p + 2 · groups · state] (``x``, ``B`` and ``C``
+    side by side) and ``dt`` [b, s, heads], in the form :func:`scan_form`
+    reads from the call: the kernels take ``xbc`` as it stands, the
+    ``jax.numpy`` form its three parts. Returns ``y`` [b, s, heads · p].
+    ``mesh`` is the model's (``cfg.mesh``), for a step compiled for more
+    than one device."""
 
     chunk: int
     param_dtype: jnp.dtype
+    groups: int
+    state: int
+    mesh: Any = None
 
     @nn.compact
-    def __call__(self, x, dt, B, C):
-        heads = x.shape[-2]
+    def __call__(self, xbc, dt):
+        heads, (g, n) = dt.shape[-1], (self.groups, self.state)
         a_log = self.param(
             "A_log", _replicated(_decay_rate_init), (heads,), self.param_dtype
         )
@@ -196,15 +243,45 @@ class SelectiveScan(nn.Module):
         skip = self.param(
             "D", _replicated(nn.initializers.ones), (heads,), self.param_dtype
         )
+        chunk, kernels = scan_form(self, xbc, dt)
+        if not kernels:
+            # Split before the softplus, as the mixer did before the
+            # kernels: the traced step stays the program it was
+            # (tests/test_granite_hybrid.py pins it).
+            inner, lead = xbc.shape[-1] - 2 * g * n, xbc.shape[:-1]
+            x, B, C = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+            x, B, C = (x.reshape(*lead, heads, -1), B.reshape(*lead, g, n),
+                       C.reshape(*lead, g, n))
         dt = jax.nn.softplus(
             dt.astype(jnp.float32) + dt_bias.astype(jnp.float32)
         )
-        # A sequence shorter than a chunk (the batch-1 sample of
-        # ``model.init``, a test) is one chunk.
-        chunk = min(self.chunk, x.shape[1])
-        return ssd_chunked(
-            x, dt, -jnp.exp(a_log.astype(jnp.float32)), B, C, skip, chunk
-        )
+        A = -jnp.exp(a_log.astype(jnp.float32))
+        if kernels:
+            return ssd.ssd_scan_packed(
+                xbc, dt, A, skip, chunk, g, n, mesh=self.mesh)
+        return ssd.ssd_chunked(x, dt, A, B, C, skip, chunk).reshape(
+            *lead, inner)
+
+
+def scan_form(scan: SelectiveScan, xbc, dt):
+    """``(chunk, whether the kernels run)`` of ``scan`` on ``xbc`` and
+    ``dt``. The module's own decision and the census's
+    (:func:`counting_scans`) are this one function. A sequence shorter
+    than a chunk (the batch-1 sample of ``model.init``, a test) is one
+    chunk."""
+    tokens, heads = dt.shape[-2:]
+    chunk = min(scan.chunk, tokens)
+    inner = xbc.shape[-1] - 2 * scan.groups * scan.state
+    return chunk, inner % heads == 0 and scan_takes_kernels(
+        tokens, heads, inner // heads, scan.groups, scan.state, chunk,
+        scan.mesh,
+    )
+
+
+def counting_scans():
+    """The census of the :class:`SelectiveScan` calls (:func:`_counting`)."""
+    return _counting(
+        SelectiveScan, lambda scan, xbc, dt: scan_form(scan, xbc, dt)[1])
 
 
 class GatedRMSNorm(nn.Module):
@@ -267,23 +344,23 @@ class Mamba2Mixer(nn.Module):
         # The compiler lays this mixer's arrays out with the sequence on
         # the lanes, in_proj's product and the convolution's result alike:
         # the chunked scan that consumes them contracts over a chunk's
-        # positions. Read in the compiled mixer at heads of 64 and of 128,
-        # one group and eight (PERF.md §6, PR 59; pinned by
+        # positions, and its kernels read and write blocks with the
+        # sequence on the lanes for the same reason. Read in the compiled
+        # mixer at heads of 64 and of 128, one group and eight, the scan in
+        # either form (PERF.md §6, PR 59 and PR 62; pinned by
         # tests/test_olmoe.py): the convolution's kernels take that.
         xbc = CausalConv1d(
             cfg.ssm_conv, cfg.dtype, cfg.param_dtype, sequence_minor=True,
             mesh=cfg.mesh, name="conv",
         )(xbc)
-        xs, B, C = jnp.split(xbc, [inner, inner + g * n], axis=-1)
-        lead = x.shape[:-1]
-        y = SelectiveScan(cfg.ssm_chunk, cfg.param_dtype, name="ssd")(
-            xs.reshape(*lead, heads, p), dt,
-            B.reshape(*lead, g, n), C.reshape(*lead, g, n),
-        )
+        y = SelectiveScan(
+            cfg.ssm_chunk, cfg.param_dtype, groups=g, state=n, mesh=cfg.mesh,
+            name="ssd",
+        )(xbc, dt)
         y = GatedRMSNorm(
             cfg.norm_eps, cfg.dtype, cfg.param_dtype, groups=g,
             name="gate_norm",
-        )(y.reshape(*lead, inner), z)
+        )(y, z)
         return dense(
             cfg.d_model, name="out_proj",
             kernel_init=nn.with_logical_partitioning(init, (None, "embed")),
@@ -294,20 +371,24 @@ def layers_of(cfg) -> int:
     return sum(1 for kind in getattr(cfg, "kinds", ()) if kind == "mamba")
 
 
-def report(cfg, tokens_per_step: int, convs=(0, 0)) -> None:
+def report(cfg, tokens_per_step: int, convs=(0, 0), scans=(0, 0)) -> None:
     """Static for a compiled step: the ``ssm/*`` gauges, the two
     ``conv/*_calls`` gauges and one log line where the step is built
     (as ``models/dropout.report``), nothing per step. All zero for a
     stack without state-space layers. ``convs`` is what
     :func:`counting_convs` read off the step's abstract apply
     (``models/step.survey``): the :class:`CausalConv1d` calls of the whole
-    model, the delta-rule layers' among them, by the form each took."""
+    model, the delta-rule layers' among them, by the form each took;
+    ``scans`` what :func:`counting_scans` read of the
+    :class:`SelectiveScan` calls."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
     kernel_calls, jnp_calls = convs
     metrics.gauge_set("conv/kernel_calls", kernel_calls)
     metrics.gauge_set("conv/jnp_calls", jnp_calls)
+    metrics.gauge_set("ssm/scan_kernel_calls", scans[0])
+    metrics.gauge_set("ssm/scan_jnp_calls", scans[1])
     chunks = state = 0
     if layers:
         chunks = layers * -(-tokens_per_step // cfg.ssm_chunk)
@@ -328,12 +409,13 @@ def report(cfg, tokens_per_step: int, convs=(0, 0)) -> None:
             "hybrid stack: %d mamba and %d attention layers; attention %d "
             "query / %d key-value heads of %d; scan %d heads of %d in %d "
             "group(s), state %d, chunk %d (%d chunks a step); scan: %s; "
+            "the stack's scans: %d by the kernels, %d in jax.numpy; "
             "convolution: %d taps; the stack's causal convolutions: %d as "
             "one Pallas kernel each way (ops/causal_conv.py), %d as %s in "
             "jax.numpy",
             layers, cfg.kinds.count("attention"), cfg.n_heads,
             cfg.kv_heads, cfg.head_dim, cfg.ssm_heads, cfg.ssm_head_dim,
             cfg.ssm_groups, cfg.ssm_state, cfg.ssm_chunk, chunks,
-            SCAN_IMPLEMENTATION, cfg.ssm_conv, kernel_calls, jnp_calls,
+            SCAN_IMPLEMENTATION, *scans, cfg.ssm_conv, kernel_calls, jnp_calls,
             CONV_IMPLEMENTATION,
         )

@@ -117,11 +117,13 @@ class Survey(NamedTuple):
     which is right for a stack run ``cfg.passes`` times too: every
     application of a block has the same shapes) and the causal
     convolutions ``(kernel calls, jax.numpy calls)`` by the form each
-    takes (``models/mamba.counting_convs``)."""
+    takes (``models/mamba.counting_convs``), and the state-space scans
+    likewise (``models/mamba.counting_scans``)."""
     dropout: Tuple[int, int] = (0, 0)
     out: Any = None
     blocks: dict = {}
     convs: Tuple[int, int] = (0, 0)
+    scans: Tuple[int, int] = (0, 0)
 
 
 def survey(model, params, sample_batch) -> Survey:
@@ -132,6 +134,7 @@ def survey(model, params, sample_batch) -> Survey:
         return Survey()
     count, read = dropout.counting()
     count_convs, read_convs = mamba.counting_convs()
+    count_scans, read_scans = mamba.counting_scans()
 
     def apply(variables, x):
         out, sown = model.apply(
@@ -142,14 +145,15 @@ def survey(model, params, sample_batch) -> Survey:
         )
         return out, sown.get("intermediates", {})
 
-    with nn.intercept_methods(count), nn.intercept_methods(count_convs):
+    with nn.intercept_methods(count), nn.intercept_methods(count_convs), \
+            nn.intercept_methods(count_scans):
         out, captured = jax.eval_shape(apply, params, sample_batch)
     blocks = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(captured):
         keys = [getattr(key, "key", None) for key in path]
         if "__call__" in keys:      # <module path>/block_i/__call__/0
             blocks[keys[keys.index("__call__") - 1]] = leaf
-    return Survey(read(), out, blocks, read_convs())
+    return Survey(read(), out, blocks, read_convs(), read_scans())
 
 
 def report(model, params, sample_batch, surveyed=None) -> None:
@@ -170,7 +174,8 @@ def report(model, params, sample_batch, surveyed=None) -> None:
     )
     report_stack(cfg)
     mamba.report(
-        cfg, tokens_per_step=tokens_per_step, convs=surveyed.convs
+        cfg, tokens_per_step=tokens_per_step, convs=surveyed.convs,
+        scans=surveyed.scans,
     )
     kda.report(cfg, tokens_per_step=tokens_per_step, sequence=seq_len)
     shortconv.report(cfg)

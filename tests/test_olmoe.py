@@ -520,6 +520,45 @@ def test_chunked_scan_compiles_for_the_chip_at_published_widths(one_chip):
     assert temp < 0.25e9, temp
 
 
+# (tokens, heads, groups, chunk) of the two cells' scans: heads of 64,
+# state 128.
+SCAN_CELLS = {"granite": (4096, 64, 1, 256), "nemotron": (16384, 64, 8, 128)}
+
+
+@pytest.mark.parametrize("cell", list(SCAN_CELLS))
+def test_scan_kernels_compile_for_the_chip_at_the_cells_shapes(one_chip, cell):
+    """Mosaic takes both kernels (``ops/ssd.py``) as the cells tile them,
+    and a gradient through one call is the two custom calls with no
+    float32 [chunk, chunk] array a head between them: what is kept is the
+    chunks' entering states (268 MB in Nemotron, 34 in Granite), and the
+    temporaries stay under three times that."""
+    from raydp_tpu.ops import ssd
+
+    s, h, g, chunk = SCAN_CELLS[cell]
+    bf16, f32, like = jnp.bfloat16, jnp.float32, jax.ShapeDtypeStruct
+    channels = h * 64 + 2 * g * 128
+    args = (like((1, s, channels), bf16), like((1, s, h), f32),
+            like((h,), f32), like((h,), f32))
+
+    def loss(*a):
+        return jnp.sum(ssd.ssd_scan_packed(
+            *a, chunk, g, 128, interpret=False).astype(f32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(4)))).lower(
+        *_on(one_chip, args)).compile()
+    hlo = compiled.as_text()
+    calls = re.findall(r"%(ssd_\w+?)[.\d]* = ", hlo)
+    assert sorted(calls) == ["ssd_backward", "ssd_forward"]
+    # The decay matrix would be chunk / 64 times ``x``'s elements; the
+    # largest array is the operand (x, B and C side by side) or the states.
+    states = (s // chunk) * h * 64 * 128
+    largest = max(
+        np.prod([int(n) for n in dims.split(",")])
+        for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", hlo))
+    assert largest == max(states, s * channels)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 4 * states
+
+
 def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
         one_chip, monkeypatch):
     """One sequence of 16,384 tokens, 32 heads of 128 / 128 in chunks of
@@ -922,49 +961,73 @@ def _mixer_gradient(cfg, x, variables_on):
         variables_on(variables), x).compile().as_text()
 
 
+@pytest.mark.parametrize("scan", ["jnp", "kernels"])
 def test_the_compiler_lays_a_mamba2_mixers_convolution_out_sequence_minor(
-        one_chip, head_dim=128, groups=1):
+        one_chip, monkeypatch, scan, head_dim=64, groups=8):
     """What ``Mamba2Mixer`` tells its convolution's kernels
     (``sequence_minor=True``) is what the compiler does on its own: in the
-    mixer's gradient with the convolution in ``jax.numpy``, ``in_proj``'s
-    product, which the convolution reads, and the convolution's result are
-    laid out ``{1,2,0}``, the sequence on the lanes, at heads of 128 (here)
-    as at the cells' 64 (read on the chip; ``head_dim=64, groups=8`` is
-    Nemotron's mixer). The chunked scan that consumes them is why, not the
-    heads' size; a kernel that asked for ``{2,1,0}`` there cost Nemotron
-    6.7% in transposing copies (PERF.md §6, PR 59)."""
+    mixer's gradient, ``in_proj``'s product, which the convolution reads,
+    and the convolution's result are laid out ``{1,2,0}``, the sequence on
+    the lanes, at Nemotron's heads of 64 in 8 groups (here) as at heads of
+    128 in one (``head_dim=128, groups=1``). With the convolution and the
+    scan in ``jax.numpy`` (the CPU's choice of form) the chunked scan that
+    consumes them is why, which contracts over a chunk's positions; with
+    both as their kernels (as a host with one TPU chooses) the scan's
+    blocks have the sequence on their lanes for the same reason, and no
+    array of the sequence's length is copied into another layout between
+    the four kernels. A kernel that asked for ``{2,1,0}`` there cost
+    Nemotron 6.7% in transposing copies (PERF.md §6, PR 59)."""
     from raydp_tpu.models.transformer import granite_h_micro
 
+    if scan == "kernels":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "device_count", lambda: 1)
     cfg = granite_h_micro(
         n_layers=1, layer_types=("mamba",), ssm_heads=4096 // head_dim,
-        ssm_head_dim=head_dim, ssm_groups=groups)
+        ssm_head_dim=head_dim, ssm_groups=groups, ssm_chunk=128)
     tokens, conv = 1024, 4096 + 2 * groups * cfg.ssm_state
     hlo = _mixer_gradient(
         cfg, jax.ShapeDtypeStruct(
             (1, tokens, cfg.d_model), jnp.bfloat16, sharding=one_chip),
         functools.partial(_on, one_chip))
-    assert "tpu_custom_call" not in hlo     # the CPU's choice of form
+    calls = re.findall(r"%(\w+?)[.\d]* = [^\n]*tpu_custom_call", hlo)
+    assert sorted(calls) == ([] if scan == "jnp" else [
+        "causal_conv_backward", "causal_conv_forward", "ssd_backward",
+        "ssd_forward"])
+    width = 4096 + conv + cfg.ssm_heads
     layouts = {
         width: set(re.findall(
-            rf"= bf16\[1,{tokens},{width}\](\{{[\d,]+)[^ ]* fusion\(", hlo))
-        for width in (4096 + conv + cfg.ssm_heads, conv)
+            rf"= bf16\[1,{tokens},{width}\](\{{[\d,]+)[^ ]* fusion\(", hlo)),
     }
+    if scan == "jnp":
+        layouts[conv] = set(re.findall(
+            rf"= bf16\[1,{tokens},{conv}\](\{{[\d,]+)[^ ]* fusion\(", hlo))
+    else:
+        # The convolution's kernel writes [1, channels, tokens] as asked,
+        # and nothing turns an array of the sequence's length around.
+        assert not re.findall(
+            rf"bf16\[1,(?:{tokens},\d+|\d+,{tokens})\]\S* (?:copy|transpose)\(",
+            hlo)
     assert all(found == {"{1,2,0"} for found in layouts.values()), layouts
 
 
+@pytest.mark.parametrize("dp, tp", [(4, 1), (2, 2)])
 def test_a_mamba2_mixers_gradient_compiles_for_four_chips(
-        four_chips, monkeypatch):
-    """dp = 2 by tp = 2 with the model's mesh told: XLA is not asked to
-    partition the convolution's Mosaic calls (it cannot); each chip runs
-    them on its own two sequences inside a ``shard_map``, and the taps'
-    sums meet in an all-reduce."""
+        four_chips, monkeypatch, dp, tp):
+    """With the model's mesh told XLA is not asked to partition a Mosaic
+    call (it cannot). dp = 4: each chip runs the convolution's and the
+    scan's kernels on its own sequence inside ``shard_map``s, and the
+    taps' sums meet in an all-reduce. dp = 2 by tp = 2: the convolution's
+    kernels on a chip's two sequences; the scan, whose heads ``tp``
+    splits, stays the ``jax.numpy`` form that XLA partitions over them
+    (``mamba.scan_takes_kernels``)."""
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
     from raydp_tpu.models.transformer import granite_h_micro
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = Mesh(np.array(four_chips).reshape(2, 2), ("dp", "tp"))
+    mesh = Mesh(np.array(four_chips).reshape(dp, tp), ("dp", "tp"))
     cfg = granite_h_micro(n_layers=1, layer_types=("mamba",), mesh=mesh)
     hlo = _mixer_gradient(
         cfg, jax.ShapeDtypeStruct(
@@ -973,6 +1036,13 @@ def test_a_mamba2_mixers_gradient_compiles_for_four_chips(
         functools.partial(_on, NamedSharding(mesh, P())))
     calls = [line for line in hlo.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
-    assert len(calls) == 2
-    assert all("bf16[2,4352,1024]" in line for line in calls)
+    names = sorted(re.findall(r"%(\w+?)[.\d]* = ", line)[0] for line in calls)
+    convolution = ["causal_conv_backward", "causal_conv_forward"]
+    assert names == convolution + (
+        ["ssd_backward", "ssd_forward"] if tp == 1 else [])
+    rows = 4 // dp
+    # The convolution's result is the scan's operand as it stands.
+    assert all(f"bf16[{rows},4352,1024]" in line for line in calls)
+    assert sum(f"bf16[{rows},4096,1024]" in line for line in calls) == (
+        2 if tp == 1 else 0)
     assert "all-reduce" in hlo
