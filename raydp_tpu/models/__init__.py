@@ -8,6 +8,7 @@ from raydp_tpu.models.transformer import (
     bert_base,
     granite_h_micro,
     kimi_linear_48b_a3b,
+    olmo_hybrid_7b,
     lfm2_8b_a1b,
     olmoe,
     param_shardings,
@@ -25,6 +26,7 @@ from raydp_tpu.models.blockdiff import BlockDiffusionConfig, BlockDiffusionLM
 from raydp_tpu.models.loop import LoopLM
 from raydp_tpu.models.sparse_index import SparseIndexConfig
 from raydp_tpu.models.hyperconn import HyperConfig
+from raydp_tpu.models.gdn import GDNConfig
 from raydp_tpu.models.kda import KDAConfig
 from raydp_tpu.models.latent import LatentConfig
 
@@ -70,6 +72,7 @@ __all__ = [
     "bert_base",
     "granite_h_micro",
     "kimi_linear_48b_a3b",
+    "olmo_hybrid_7b",
     "lfm2_8b_a1b",
     "olmoe",
     "laguna_xs_2",
@@ -86,6 +89,7 @@ __all__ = [
     "BlockDiffusionLM",
     "HyperConfig",
     "KDAConfig",
+    "GDNConfig",
     "LatentConfig",
     "tiny_transformer",
     "param_shardings",

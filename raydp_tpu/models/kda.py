@@ -22,7 +22,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
-from typing import Any
+from typing import Any, Callable
 
 import flax.linen as nn
 import jax
@@ -140,13 +140,14 @@ class ChannelDecay(nn.Module):
 
 
 class HeadGatedRMSNorm(nn.Module):
-    """``rms(o_h) · w ⊙ σ(z_h)``: the norm over each head's values with
-    one weight of ``d_v`` for all heads, the gate after it; float32
-    inside."""
+    """``rms(o_h) · w ⊙ act(z_h)``: the norm over each head's values with
+    one weight of ``d_v`` for all heads, the gate after it (``activation``:
+    the sigmoid here, SiLU in ``models/gdn.py``); float32 inside."""
 
     epsilon: float
     dtype: jnp.dtype
     param_dtype: jnp.dtype
+    activation: Callable = jax.nn.sigmoid
 
     @nn.compact
     def __call__(self, o, z):
@@ -158,7 +159,7 @@ class HeadGatedRMSNorm(nn.Module):
         o = o * jax.lax.rsqrt(
             jnp.mean(o * o, axis=-1, keepdims=True) + self.epsilon
         )
-        gate = jax.nn.sigmoid(z.astype(jnp.float32)).reshape(o.shape)
+        gate = self.activation(z.astype(jnp.float32)).reshape(o.shape)
         return (o * scale.astype(jnp.float32) * gate).astype(self.dtype)
 
 

@@ -21,8 +21,8 @@ import numpy as np
 from jax.extend.core import Jaxpr, Literal
 
 from raydp_tpu.models import (
-    blockdiff, dropout, hyperconn, kda, latent, loop, mamba, moe, shortconv,
-    sparse_index, stats, window,
+    blockdiff, dropout, gdn, hyperconn, kda, latent, loop, mamba, moe,
+    shortconv, sparse_index, stats, window,
 )
 from raydp_tpu.models.stats import merge  # noqa: F401  (two steps' statistics as one)
 from raydp_tpu.models.transformer import (
@@ -178,6 +178,7 @@ def report(model, params, sample_batch, surveyed=None) -> None:
         scans=surveyed.scans,
     )
     kda.report(cfg, tokens_per_step=tokens_per_step, sequence=seq_len)
+    gdn.report(cfg, tokens_per_step=tokens_per_step, sequence=seq_len)
     shortconv.report(cfg)
     latent.report(cfg)
     window.report(cfg)
@@ -504,6 +505,11 @@ class Stack(NamedTuple):
     head_stays: int
     passes: int = 1
     exits: int = 1
+    # A block's gradients in the dtype of the parameters they are for,
+    # which is what a compiled step holds them in (a product in the
+    # compute dtype writes a gradient, and the compiler leaves the rounding
+    # out); empty = not known (taken as ``gradients``).
+    parameters: Sequence[int] = ()
 
 
 class Estimate(NamedTuple):
@@ -526,14 +532,67 @@ def released_blocks(stack: Stack, limit: Optional[int]) -> Tuple[int, ...]:
     earlier block's backward runs; and the order is a function of the
     bytes alone, so two runs of one shape on one device make one program.
     Forward time saved per byte kept would be the better order where
-    kinds differ much (PERF.md section 7)."""
+    kinds differ much (PERF.md section 7).
+
+    Nothing is released where the estimate is known to be short by more
+    than the margin (:func:`uncounted_bytes`): what the configuration
+    wrote, every block checkpointed, is the least a step can hold."""
     if limit is None:
         return ()
     out = ()
     for i in reversed(range(len(stack.released))):
         if estimated_bytes(stack, out + (i,)).total <= limit * (1.0 - MARGIN):
             out += (i,)
+    if uncounted_bytes(stack, out) > limit * MARGIN:
+        return ()
     return tuple(sorted(out))
+
+
+def _walk(stack: Stack, out):
+    """``(held, working, uncounted)`` at every step of the walk that
+    :func:`estimated_bytes` describes; ``uncounted`` is what the
+    gradients held or written at that step are in their parameters' dtype
+    over what the count has them as."""
+    held = [
+        stack.released[i] if i in out else stack.checkpointed[i]
+        for i in range(len(stack.released))
+    ]
+    n, gradients = len(held), sum(stack.gradients)
+    short = [
+        whole - counted for whole, counted in zip(
+            stack.parameters or stack.gradients, stack.gradients)
+    ]
+    walk = [(
+        stack.passes * sum(held) + (stack.head_stays if stack.exits > 1 else 0),
+        2 * stack.head, 0,
+    )]
+    for t in range(stack.passes):
+        last = t + 1 == stack.passes
+        walk += [(
+            t * sum(held) + sum(held[:i]) + stack.head_stays + (
+                sum(stack.gradients[i + 1:]) if last
+                else gradients - stack.gradients[i]
+            ),
+            stack.working[i],
+            sum(short[i:]) if last else sum(short),
+        ) for i in range(n)]
+    return walk
+
+
+def uncounted_bytes(stack: Stack, out) -> int:
+    """How far the estimate is KNOWN to be short with the blocks ``out``
+    released: the gradients' bytes that the count leaves out (it has a
+    gradient as the product that writes it, in the compute dtype; the
+    compiled steps hold it in its parameter's, PR 63's rows of the table)
+    where the walk holds most with them, if that is more than the slack
+    covers there; 0 where the slack covers it, which it does in every row
+    of the table that was fitted (activations are most of those steps).
+    A stack whose STATE is most of the chip (13.8 GiB of 15.75) is short
+    by 1.5 GiB where the slack is 0.5: :func:`released_blocks` releases
+    nothing where this passes the margin it leaves free."""
+    held, working, uncounted = max(_walk(stack, out), key=sum)
+    covered = int((SLACK - 1.0) * (held + working))
+    return uncounted if uncounted > covered else 0
 
 
 def estimated_bytes(stack: Stack, out) -> Estimate:
@@ -560,25 +619,7 @@ def estimated_bytes(stack: Stack, out) -> Estimate:
     what EVERY application holds; their gradients are made there
     (``train/losses._exits_ce``), so where there is more than one the
     head's own (``head_stays``) is held from then on."""
-    held = [
-        stack.released[i] if i in out else stack.checkpointed[i]
-        for i in range(len(stack.released))
-    ]
-    n, gradients = len(held), sum(stack.gradients)
-    walk = [(
-        stack.passes * sum(held) + (stack.head_stays if stack.exits > 1 else 0),
-        2 * stack.head,
-    )]
-    for t in range(stack.passes):
-        last = t + 1 == stack.passes
-        walk += [(
-            t * sum(held) + sum(held[:i]) + stack.head_stays + (
-                sum(stack.gradients[i + 1:]) if last
-                else gradients - stack.gradients[i]
-            ),
-            stack.working[i],
-        ) for i in range(n)]
-    parts = max(walk, key=sum)
+    parts = max((step[:2] for step in _walk(stack, out)), key=sum)
     return Estimate(stack.fixed + int(SLACK * sum(parts)), *parts)
 
 
@@ -663,7 +704,7 @@ def fit_checkpoint(model, state, sample_batch, mesh, surveyed=None):
     inputs = [surveyed.blocks[f"block_{i}"] for i in range(n)]
     # One trace a KIND of block: mixer, FFN and the input's shape.
     kinds = [(*layer, x.shape) for layer, x in zip(cfg.layers, inputs)]
-    counted = {}
+    counted, whole = {}, {}
     for i, (kind, x) in enumerate(zip(kinds, inputs)):
         if kind not in counted:
             # Layer i's own variables, a collection each, out of the
@@ -673,6 +714,10 @@ def fit_checkpoint(model, state, sample_batch, mesh, surveyed=None):
                 if (found := _under(tree, f"block_{i}")) is not None
             }
             counted[kind] = block_bytes(cfg, *kind[:2], variables, x)
+            whole[kind] = sum(
+                _nbytes(leaf) for leaf in jax.tree_util.tree_leaves(variables)
+                if jnp.issubdtype(leaf.dtype, jnp.inexact)
+            )
     exits = loop.exit_bytes(model, surveyed.out)
     if exits is None:       # one head: the model's output is its logits
         head = sum(map(_nbytes, jax.tree_util.tree_leaves(surveyed.out)))
@@ -680,6 +725,7 @@ def fit_checkpoint(model, state, sample_batch, mesh, surveyed=None):
     stack = Stack(
         *([size // batch_chips for size in sizes]
           for sizes in zip(*(counted[kind] for kind in kinds))),
+        parameters=[whole[kind] // batch_chips for kind in kinds],
         fixed=_chip_bytes(state) + _nbytes(sample_batch) // batch_chips,
         head=exits[1] // batch_chips,
         head_stays=exits[2] // batch_chips,
@@ -688,6 +734,15 @@ def fit_checkpoint(model, state, sample_batch, mesh, surveyed=None):
     free = released_blocks(stack, limit)
     estimate = estimated_bytes(stack, free)
     _report_checkpoint(n, n - len(free), estimate, limit, 0)
+    short = uncounted_bytes(stack, free)
+    if short:
+        logger.info(
+            "block checkpoint: the gradients are %d MiB more in their "
+            "parameters' dtype than the estimate counts where the step "
+            "holds most, more than its slack covers: nothing is released "
+            "where that passes the margin (%d MiB)",
+            short >> 20, int(limit * MARGIN) >> 20,
+        )
     logger.info(
         "block checkpoint: %d of %d blocks released %s; the step is "
         "estimated to hold %.2f GiB of the chip's %.2f: %.2f from end to "

@@ -57,7 +57,7 @@ LOGICAL_RULES: Tuple[Tuple[str, Optional[str]], ...] = (
 
 
 MIXERS = frozenset(
-    {"attention", "window", "mamba", "conv", "latent", "kda", "sparse"}
+    {"attention", "window", "mamba", "conv", "latent", "kda", "gdn", "sparse"}
 )
 FFNS = frozenset({"gelu", "swiglu", "moe"})
 # The sublayer a layer of ONE sublayer does not have: ``"<mixer>:none"`` is
@@ -160,7 +160,7 @@ class TransformerConfig:
     # swiglu ``down(silu(gate x) * up x)`` | relu2 ``down(relu(up x)²)``.
     expert_form: str = "swiglu"
     # Layer i as "<mixer>" or "<mixer>:<ffn>": the mixer is "attention" |
-    # "window" | "mamba" | "conv" | "latent" | "kda" | "sparse", the FFN kind one of
+    # "window" | "mamba" | "conv" | "latent" | "kda" | "gdn" | "sparse", the FFN kind one of
     # ``ffn``'s and ``ffn`` itself where the entry names none. A layer of
     # ONE sublayer (one norm, one residual add) names "none" for the other:
     # "<mixer>:none" or "none:<ffn>". None = attention and ``ffn``
@@ -169,6 +169,7 @@ class TransformerConfig:
     # A mixer's or the residual path's own sizes, a record each:
     # ``models/latent.LatentConfig`` for the "latent" mixer,
     # ``models/kda.KDAConfig`` for the "kda" mixer,
+    # ``models/gdn.GDNConfig`` for the "gdn" mixer,
     # ``models/hyperconn.HyperConfig`` for more than one residual stream
     # (None = the plain ``x + F(norm(x))``);
     # ``models/blockdiff.BlockDiffusionConfig`` for a stack that runs on
@@ -178,6 +179,7 @@ class TransformerConfig:
     # (the "attention" mixer over the keys a learned index branch selects).
     latent: Any = None
     kda: Any = None
+    gdn: Any = None
     hyper: Any = None
     diffusion: Any = None
     sparse: Any = None
@@ -226,10 +228,14 @@ class TransformerConfig:
     # in ``models/loop.py`` puts an exit after each). 1 = the stack as it
     # was, op for op.
     passes: int = 1
-    # A norm on each sublayer's OUTPUT too, before the residual add:
-    # ``x + norm_out(F(norm_in(x)))``, under the input norm's name +
-    # ``_out`` (``ln_attn_out``, ``ln_mlp_out``, ...).
-    branch_norm: bool = False
+    # Where a sublayer's norms sit, stated once for every kind of layer:
+    # False  ``x + F(norm_in(x))``            the input alone (pre-norm);
+    # True   ``x + norm_out(F(norm_in(x)))``  input and output;
+    # "only" ``x + norm_out(F(x))``           the output alone.
+    # The input norm has the name it always had (``ln_attn``, ``ln_mlp``,
+    # ...), the output norm that name + ``_out``; a norm that does not sit
+    # is not in the parameter tree.
+    branch_norm: Any = False
     dtype: Any = jnp.bfloat16        # compute dtype (MXU-friendly)
     param_dtype: Any = jnp.float32
     mesh: Any = None                 # ring/ulysses; flash on >1 device
@@ -769,7 +775,17 @@ class TransformerBlock(nn.Module):
 
         # The mixer's input norm, by the name it has always had.
         mixer_norm = {"mamba": "ln_mamba", "conv": "ln_conv",
-                      "kda": "ln_kda"}.get(self.mixer, "ln_attn")
+                      "kda": "ln_kda", "gdn": "ln_gdn"}.get(
+                          self.mixer, "ln_attn")
+        if cfg.branch_norm not in (False, True, "only"):
+            raise ValueError(f"unknown branch_norm {cfg.branch_norm!r}")
+
+        def entering(name, dtype=None):
+            """A sublayer's norm on its INPUT; nothing where the stack
+            norms outputs only."""
+            if cfg.branch_norm == "only":
+                return lambda h: h
+            return _norm(cfg, name, dtype)
 
         def mix(h):
             """The layer's mixer on its own norm of ``h``."""
@@ -783,7 +799,7 @@ class TransformerBlock(nn.Module):
                     name="attn",
                 )
                 return attend(
-                    _norm(cfg, mixer_norm)(h),
+                    entering(mixer_norm)(h),
                     deterministic,
                     cache_mode=cache_mode,
                     cache_positions=cache_positions,
@@ -799,26 +815,34 @@ class TransformerBlock(nn.Module):
                               "(ROADMAP R3)",
                     "kda": "no decode cache for a delta-rule layer's state "
                            "(ROADMAP R11)",
+                    "gdn": "no decode cache for a delta-rule layer's state "
+                           "(ROADMAP R11)",
                 }[self.mixer])
             if self.mixer == "mamba":
                 from raydp_tpu.models.mamba import Mamba2Mixer
 
                 return Mamba2Mixer(cfg, name="mamba")(
-                    _norm(cfg, mixer_norm)(h)
+                    entering(mixer_norm)(h)
                 )
             if self.mixer == "conv":
                 from raydp_tpu.models.shortconv import ShortConv
 
-                return ShortConv(cfg, name="conv")(_norm(cfg, mixer_norm)(h))
+                return ShortConv(cfg, name="conv")(entering(mixer_norm)(h))
             if self.mixer == "kda":
                 from raydp_tpu.models.kda import KimiDeltaMixer
 
                 return KimiDeltaMixer(cfg, name="kda")(
-                    _norm(cfg, mixer_norm)(h)
+                    entering(mixer_norm)(h)
+                )
+            if self.mixer == "gdn":
+                from raydp_tpu.models.gdn import GatedDeltaMixer
+
+                return GatedDeltaMixer(cfg, name="gdn")(
+                    entering(mixer_norm)(h)
                 )
             from raydp_tpu.models.latent import LatentAttention
 
-            return LatentAttention(cfg, name="attn")(_norm(cfg, mixer_norm)(h))
+            return LatentAttention(cfg, name="attn")(entering(mixer_norm)(h))
 
         def feed(h):
             """The layer's FFN on its own norm of ``h``."""
@@ -833,10 +857,10 @@ class TransformerBlock(nn.Module):
                 # The norm's output stays float32 for the router: one bf16
                 # rounding less between near-equal experts. The experts get
                 # it in the compute dtype.
-                y = _norm(cfg, "ln_mlp", jnp.float32)(h)
+                y = entering("ln_mlp", jnp.float32)(h)
                 y = MoELayer(cfg.moe_config(), name="moe")(y)
             elif ffn == "gelu":
-                y = _norm(cfg, "ln_mlp")(h)
+                y = entering("ln_mlp")(h)
                 y = dense(
                     cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
                     name="mlp_up",
@@ -848,7 +872,7 @@ class TransformerBlock(nn.Module):
                 )(y)
             elif ffn == "swiglu":
                 # Dense gated MLP, one fused input projection: [gate, up].
-                y = _norm(cfg, "ln_mlp")(h)
+                y = entering("ln_mlp")(h)
                 gate, up = jnp.split(dense(
                     2 * cfg.d_ff, kernel_init=_dense_init("embed", "mlp"),
                     name="mlp_in",
@@ -865,7 +889,8 @@ class TransformerBlock(nn.Module):
 
         def normed(sublayer, name):
             """``sublayer`` with the configuration's norm on its output
-            (``cfg.branch_norm``), under the input norm's name + ``_out``."""
+            (``cfg.branch_norm`` true or "only"), under the input norm's
+            name + ``_out``."""
             if not cfg.branch_norm:
                 return sublayer
             return lambda h: _norm(cfg, name)(sublayer(h))
@@ -910,7 +935,7 @@ def checkpointed_block():
     HBM's tiling. A block that calls no flash kernel (dense, ring,
     ulysses) holds no such name and keeps its input alone. A delta-rule
     layer's scan names its output and the few states its segments were
-    entered with for the same reason (``ops/kda.KEPT``), the attention
+    entered with for the same reason (``ops/kda.KEPT``, ``ops/gdn.KEPT``), the attention
     over a learned selection its kernels' (``ops/sparse_attention.KEPT``)."""
     return nn.remat(
         TransformerBlock, static_argnums=(2,),
@@ -921,10 +946,11 @@ def checkpointed_block():
 def kept_names() -> Tuple[str, ...]:
     """The names a checkpointed block keeps besides its input."""
     from raydp_tpu.ops.flash_attention import KEPT
+    from raydp_tpu.ops.gdn import KEPT as GDN_KEPT
     from raydp_tpu.ops.kda import KEPT as KDA_KEPT
     from raydp_tpu.ops.sparse_attention import KEPT as SPARSE_KEPT
 
-    return (*KEPT, *KDA_KEPT, *SPARSE_KEPT)
+    return (*KEPT, *KDA_KEPT, *SPARSE_KEPT, *GDN_KEPT)
 
 
 class TransformerEncoder(nn.Module):
@@ -1475,6 +1501,36 @@ def kimi_linear_48b_a3b(**overrides) -> TransformerConfig:
         ),
         kda=KDAConfig(heads=32, key_dim=128, value_dim=128, conv_taps=4,
                       gate_rank=128, chunk=64),
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def olmo_hybrid_7b(**overrides) -> TransformerConfig:
+    """Olmo-Hybrid-7B (Allen AI; ``config.json`` of allenai/Olmo-Hybrid-7B,
+    ``model_type`` olmo_hybrid): 32 decoder layers of width 3840, Gated
+    DeltaNet (arXiv:2412.06464; 30 heads with keys of 96 and values of
+    192, one decay a head, beta in (0, 2): ``models/gdn.py``) in three
+    layers of four and full attention (30 heads of 128, a norm over the
+    whole q and k projections, no positions: the delta-rule layers carry
+    them) in the fourth, a dense SwiGLU FFN of 11008 in every layer, and
+    the OLMo 2 stack's norms: RMSNorm (eps 1e-6) on each sublayer's OUTPUT
+    and none on its input; no biases, vocabulary 100,352, untied head."""
+    from raydp_tpu.models.gdn import GDNConfig
+
+    n_layers = overrides.get("n_layers", 32)
+    defaults = dict(
+        vocab_size=100352, d_model=3840, n_heads=30, n_layers=n_layers,
+        d_ff=11008, max_len=65536, dropout_rate=0.0, causal=True,
+        norm="rmsnorm", norm_eps=1e-6, positions="none",
+        qk_norm="projection", use_bias=False, ffn="swiglu", tie_head=False,
+        branch_norm="only",
+        layer_types=tuple(
+            ("attention" if i % 4 == 3 else "gdn") + ":swiglu"
+            for i in range(n_layers)
+        ),
+        gdn=GDNConfig(heads=30, key_dim=96, value_dim=192, conv_taps=4,
+                      chunk=64, neg_eigval=True),
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

@@ -126,6 +126,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -200,10 +201,12 @@ def _levels(c: int):
     ``j`` not: every pair j < i lies in exactly one level."""
     at = np.arange(c)
     sizes = [1 << n for n in range(c.bit_length() - 1)]
-    late = np.stack([(at // h) % 2 == 1 for h in sizes])
-    same = np.stack([
+    # ``reshape``: a chunk of one token has no level, and no pair.
+    late = np.array(
+        [(at // h) % 2 == 1 for h in sizes], bool).reshape(len(sizes), c)
+    same = np.array([
         (at[:, None] // (2 * h)) == (at[None, :] // (2 * h)) for h in sizes
-    ])
+    ], bool).reshape(len(sizes), c, c)
     pairs = same & late[:, :, None] & ~late[:, None, :]
     return sizes, late, pairs
 
@@ -950,6 +953,39 @@ def _whole(a):
     return a.reshape(a.shape[0], a.shape[1] * a.shape[2], *a.shape[3:])
 
 
+class Rule(NamedTuple):
+    """What the walk over segments needs of a delta rule, as functions of
+    a segment's chunks ``q``, ``k``, ``v``, ``g``, ``beta`` [b, n, h, c,
+    ...] (:func:`_chunks`): ``local(*xs, keep)`` the chunk-local step's six
+    results ``U``, ``W``, ``P``, ``q e^G``, ``k e^{G_C − G}``, ``e^{G_C}``
+    and, with ``keep``, whatever else the backward reads again;
+    ``rebuild(*xs, *kept)`` the six from the inputs and that;
+    ``across(six, state, dtype)`` the output and the state left; ``names``
+    what the forward keeps besides its inputs, for a checkpoint's policy.
+    Hashable: the static argument of :func:`segment_walk`. The two rules
+    of this module name their functions when they are called (a test
+    stands in for one by name)."""
+
+    local: Callable
+    rebuild: Callable
+    across: Callable
+    names: Tuple[str, ...]
+
+
+KERNELS = Rule(
+    lambda *xs, keep: _forward_call(*xs, _interpret(), keep),
+    lambda *xs: chunk_local(*xs),
+    lambda six, state, dtype: across(six, state),
+    KEPT,
+)
+PLAIN = Rule(
+    lambda *xs, keep: _chunk_local_jnp(*xs),
+    lambda *xs: _chunk_local_jnp(*xs),
+    lambda six, state, dtype: _across(six, state, dtype),
+    KEPT,
+)
+
+
 def kda_chunked(q, k, v, g, beta, chunk: int = 64, kernels=None):
     """``q``, ``k`` [b, s, h, d_k], ``v`` [b, s, h, d_v] (``q`` already
     scaled), ``g`` [b, s, h, d_k] float32 log-decays (≤ 0, unbounded
@@ -964,14 +1000,23 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64, kernels=None):
     return _kda(q, k, v, g, beta, chunk, bool(kernels))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _kda(q, k, v, g, beta, chunk, kernels):
-    return _forward(q, k, v, g, beta, chunk, kernels, keep=False)[0]
+    return segment_walk(q, k, v, g, beta, chunk, KERNELS if kernels else PLAIN)
 
 
-def _forward(q, k, v, g, beta, chunk, kernels, keep: bool):
-    """``o``, the states the segments were entered with and, by the
-    kernels with ``keep``, every segment's chunks' T (else nothing)."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def segment_walk(q, k, v, g, beta, chunk, rule):
+    """A chunked delta rule over a whole sequence, ``rule`` (a
+    :class:`Rule`) saying what a chunk costs of itself: the segments one
+    after the other, and a backward that rebuilds ONE segment at a time
+    from its inputs and its entering state. ``g`` is whatever ``rule``
+    reads (a decay a channel here, one a head in ``ops/gdn.py``)."""
+    return _forward(q, k, v, g, beta, chunk, rule, keep=False)[0]
+
+
+def _forward(q, k, v, g, beta, chunk, rule, keep: bool):
+    """``o``, the states the segments were entered with and, with
+    ``keep``, whatever else ``rule.local`` keeps of every segment."""
     b, s, h, d_k = k.shape
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"chunk {chunk} is not a power of two")
@@ -979,11 +1024,8 @@ def _forward(q, k, v, g, beta, chunk, kernels, keep: bool):
         raise ValueError(f"sequence {s} is not a multiple of chunk {chunk}")
 
     def step(state, xs):
-        xs = _chunks(chunk, *xs)
-        local = (_forward_call(*xs, _interpret(), keep) if kernels
-                 else _chunk_local_jnp(*xs))
-        out, left = (across(local[:6], state) if kernels
-                     else _across(local[:6], state, v.dtype))
+        local = rule.local(*_chunks(chunk, *xs), keep=keep)
+        out, left = rule.across(local[:6], state, v.dtype)
         return left, (out, state, *local[6:])
 
     _, (out, *kept) = jax.lax.scan(
@@ -993,15 +1035,15 @@ def _forward(q, k, v, g, beta, chunk, kernels, keep: bool):
     return _whole(out), *kept
 
 
-def _kda_fwd(q, k, v, g, beta, chunk, kernels):
+def _walk_fwd(q, k, v, g, beta, chunk, rule):
     out, *kept = (
         checkpoint_name(a, name) for a, name in zip(
-            _forward(q, k, v, g, beta, chunk, kernels, keep=True), KEPT)
+            _forward(q, k, v, g, beta, chunk, rule, keep=True), rule.names)
     )
     return out, (q, k, v, g, beta, *kept)
 
 
-def _kda_bwd(chunk, kernels, residuals, d_out):
+def _walk_bwd(chunk, rule, residuals, d_out):
     q, k, v, g, beta, entered, *kept = residuals
 
     def step(d_state, xs):
@@ -1009,10 +1051,9 @@ def _kda_bwd(chunk, kernels, residuals, d_out):
 
         def segment(*a):
             *a, state = a
-            a = _chunks(chunk, *a)
-            if kernels:
-                return across(chunk_local(*a, *inverses), state)
-            return _across(_chunk_local_jnp(*a), state, v.dtype)
+            return rule.across(
+                rule.rebuild(*_chunks(chunk, *a), *inverses), state, v.dtype
+            )
 
         _, vjp = jax.vjp(segment, *xs, state)
         *d_xs, d_state = vjp((d_o, d_state))
@@ -1026,4 +1067,4 @@ def _kda_bwd(chunk, kernels, residuals, d_out):
     return tuple(_whole(a) for a in grads)
 
 
-_kda.defvjp(_kda_fwd, _kda_bwd)
+segment_walk.defvjp(_walk_fwd, _walk_bwd)

@@ -15,8 +15,8 @@ import pandas as pd
 import pytest
 
 from raydp_tpu.models import (
-    CausalLM, blockdiff, dropout, hyperconn, kda, latent, loop, mamba, moe,
-    olmoe,
+    CausalLM, blockdiff, dropout, gdn, hyperconn, kda, latent, loop, mamba,
+    moe, olmoe,
     shortconv, sparse_index, stats, window,
 )
 from raydp_tpu.models import step as model_step
@@ -73,6 +73,7 @@ def _eleven_reports_by_hand(model, params, sample):
     report_stack(cfg)       # PR 57: the stack's layers by what they hold
     mamba.report(cfg, tokens_per_step=tokens)
     kda.report(cfg, tokens_per_step=tokens, sequence=seq_len)
+    gdn.report(cfg, tokens_per_step=tokens, sequence=seq_len)   # PR 63
     shortconv.report(cfg)
     latent.report(cfg)
     window.report(cfg)
@@ -109,6 +110,7 @@ def test_the_one_report_is_the_eleven(routed, gauges_set):
     assert values["train/dropout_sites"] > 0
     assert values["moe/experts_routed"] == 4
     assert values["ssm/layers"] == values["kda/layers"] == 0
+    assert values["gdn/layers"] == values["gdn/kept_bytes_per_sequence"] == 0
 
 
 # A model of no family under ``models/``: no ``cfg``, and one of each

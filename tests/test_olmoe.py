@@ -559,6 +559,37 @@ def test_scan_kernels_compile_for_the_chip_at_the_cells_shapes(one_chip, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 4 * states
 
 
+def test_scalar_decay_delta_rule_compiles_for_the_chip_at_olmo_hybrids_shape(
+        one_chip):
+    """One sequence of 4,096 tokens, 30 heads with keys of 96 and values
+    of 192 (neither a multiple of the 128 lanes) in chunks of 64, forward
+    and backward, ``ops/gdn.py``'s plain ``jax.numpy`` form: the TPU's
+    compiler takes it, no kernel stands in it, the loops left are the
+    walk over the two segments each way and the chunk states' scan inside
+    them, and its temporaries are 1.32 GB (a backward holds one
+    32-chunk segment's float32 intermediates, [64, 64] tiles padded to
+    128 lanes and [96, 192] states to 256: what kernels on [64, 96] and
+    [64, 192] tiles would keep in VMEM)."""
+    from raydp_tpu.ops import gdn
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys = jax.ShapeDtypeStruct((1, 4096, 30, 96), bf16)
+    args = (keys, keys, jax.ShapeDtypeStruct((1, 4096, 30, 192), bf16),
+            jax.ShapeDtypeStruct((1, 4096, 30), f32),
+            jax.ShapeDtypeStruct((1, 4096, 30), f32))
+
+    def loss(*a):
+        return jnp.sum(gdn.gdn_chunked(*a, 64).astype(f32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+        *_on(one_chip, args)
+    ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    assert "f32[2,1,30,96,192]" in hlo       # the two segments' states
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
         one_chip, monkeypatch):
     """One sequence of 16,384 tokens, 32 heads of 128 / 128 in chunks of

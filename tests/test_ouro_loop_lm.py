@@ -420,7 +420,12 @@ PARENT_STACK = (
 
 def test_fit_checkpoint_builds_the_parents_stack_at_one_pass(monkeypatch):
     one = _tiny_stack(monkeypatch, 1)
-    assert tuple(one) == PARENT_STACK + (1, 1)
+    assert tuple(one)[:9] == PARENT_STACK + (1, 1)
+    # PR 63: the blocks' gradients in their parameters' dtype, beside the
+    # count of what they are made of (float32 compute here: the same).
+    assert one._fields[9:] == ("parameters",)
+    assert len(one.parameters) == 3 and min(one.parameters) >= min(
+        one.gradients)
     two = _tiny_stack(monkeypatch, 2)
     # Every application has the same shapes: a block's counts are those
     # of one pass; what differs is how often the walk meets them. A
