@@ -32,13 +32,13 @@ import jax.numpy as jnp
 from raydp_tpu.models.kda import HeadGatedRMSNorm, QKVConv
 from raydp_tpu.models.mamba import (
     _decay_rate_init,
+    _kernels_may_run,
     _replicated,
     _step_bias_init,
     conv_takes_kernel,
 )
-from raydp_tpu.ops.gdn import IMPLEMENTATION as SCAN_IMPLEMENTATION
+from raydp_tpu.ops import gdn as gdn_ops
 from raydp_tpu.ops import kda as kda_ops
-from raydp_tpu.ops.gdn import gdn_chunked
 
 logger = logging.getLogger(__name__)
 
@@ -74,6 +74,33 @@ class GDNConfig:
         return layers * self.heads * self.value_dim * (
             2 * sequence + 4 * segments * self.key_dim
         )
+
+    def kept_inverse_bytes(self, layers: int, sequence: int) -> int:
+        """What the chunks' float32 triangular inverses of one sequence
+        hold, all layers, where the scan's kernels keep them (the third
+        of ``ops/gdn.KEPT``); the ``jax.numpy`` form keeps none."""
+        return 4 * layers * self.heads * sequence * self.scan_chunk(sequence)
+
+
+def scan_takes_kernels(key_dim: int, value_dim: int, chunk: int,
+                       mesh=None) -> bool:
+    """Whether a mixer's scan of these shapes runs as the Pallas kernels
+    of ``ops/gdn.py``: where a Mosaic kernel may stand at all
+    (``models/mamba._kernels_may_run``: on a TPU, the program one device's
+    or the model's ``mesh`` told), the mesh does not split the heads and
+    ``ops/gdn.uses_kernels`` takes the shapes. With a ``mesh`` the call is
+    ``gdn_chunked(mesh=)``'s ``shard_map`` over ``dp``; with ``tp`` > 1
+    every chip of a group would gather the projections whole and scan all
+    the heads, where XLA partitions the ``jax.numpy`` form over them, so
+    such a mesh keeps that form (as ``mamba.scan_takes_kernels``; no cell
+    runs this stack on four chips). Everywhere else (``model.init``'s
+    sample, whose chunk is its one token, among them) the plain rule
+    runs, which the compiler partitions as it did."""
+    return (
+        _kernels_may_run(mesh)
+        and (mesh is None or mesh.shape.get("tp", 1) == 1)
+        and gdn_ops.uses_kernels(key_dim, value_dim, chunk)
+    )
 
 
 class ScalarDecay(nn.Module):
@@ -141,8 +168,13 @@ class GatedDeltaMixer(nn.Module):
             beta = jax.nn.sigmoid(beta.astype(jnp.float32))
             if gdn.neg_eigval:
                 beta = 2.0 * beta
+        chunk = gdn.scan_chunk(x.shape[-2])
         with jax.named_scope("scan"):
-            o = gdn_chunked(q, k, v, g, beta, gdn.scan_chunk(x.shape[-2]))
+            o = gdn_ops.gdn_chunked(
+                q, k, v, g, beta, chunk, mesh=cfg.mesh,
+                kernels=scan_takes_kernels(
+                    gdn.key_dim, gdn.value_dim, chunk, cfg.mesh),
+            )
         o = HeadGatedRMSNorm(
             cfg.norm_eps, cfg.dtype, cfg.param_dtype,
             activation=jax.nn.silu, name="gate_norm",
@@ -157,10 +189,11 @@ def layers_of(cfg) -> int:
 
 
 def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
-    """Static for a compiled step: six gauges and one log line where the
+    """Static for a compiled step: nine gauges and one log line where the
     step is built (as ``models/kda.report``). All zero for a stack
-    without such layers. ``sequence`` is a sequence's tokens (all of a
-    step's where left out)."""
+    without such layers, and the three of the scan's kernels where the
+    plain rule runs. ``sequence`` is a sequence's tokens (all of a step's
+    where left out)."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
@@ -168,6 +201,12 @@ def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
     sequence = sequence or tokens_per_step
     chunks = layers * -(-tokens_per_step // gdn.chunk) if gdn else 0
     kept = gdn.kept_bytes(layers, sequence) if gdn else 0
+    kernels = bool(gdn) and scan_takes_kernels(
+        gdn.key_dim, gdn.value_dim, gdn.scan_chunk(sequence), cfg.mesh)
+    inverses = gdn.kept_inverse_bytes(layers, sequence) if kernels else 0
+    metrics.gauge_set("gdn/scan_kernel_layers", layers if kernels else 0)
+    metrics.gauge_set("gdn/state_kernel_layers", layers if kernels else 0)
+    metrics.gauge_set("gdn/kept_inverse_mib", inverses >> 20)
     metrics.gauge_set("gdn/layers", layers)
     metrics.gauge_set("gdn/heads", gdn.heads if gdn else 0)
     metrics.gauge_set("gdn/chunk", gdn.chunk if gdn else 0)
@@ -189,9 +228,11 @@ def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
             "gated delta-rule stack: layers %s; %d heads of %d (q, k) and "
             "%d (v), one decay a head, beta in (0, %d), %d-tap convolutions "
             "(%s); chunk %d (%d chunks a step); scan: %s; a checkpointed "
-            "block keeps %d MiB of outputs and segment states a sequence",
+            "block keeps %d MiB of outputs and segment states and %d MiB of "
+            "chunk inverses a sequence",
             " ".join(cfg.kinds), gdn.heads, gdn.key_dim, gdn.value_dim,
             2 if gdn.neg_eigval else 1, gdn.conv_taps,
             ", ".join(f"{name}: {path}" for name, path in convs.items()),
-            gdn.chunk, chunks, SCAN_IMPLEMENTATION, kept >> 20,
+            gdn.chunk, chunks, gdn_ops.IMPLEMENTATION[kernels], kept >> 20,
+            inverses >> 20,
         )

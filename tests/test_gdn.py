@@ -174,24 +174,37 @@ def test_it_takes_the_inverse_the_recurrence_and_the_walk_by_import():
     assert gdn_ops.unit_lower_inverse is kda_ops.unit_lower_inverse
     assert gdn_ops.segment_walk is kda_ops.segment_walk
     assert gdn_ops._across is kda_ops._across
-    assert isinstance(gdn_ops.RULE, kda_ops.Rule)
-    assert gdn_ops.RULE.names == gdn_ops.KEPT == (
-        "gdn_out", "gdn_segment_states")
+    assert gdn_ops.RULE.across is kda_ops._across
+    assert gdn_ops.RULE.local.__module__ == gdn_ops.__name__
+    for rule in (gdn_ops.RULE, gdn_ops.KERNELS):
+        assert isinstance(rule, kda_ops.Rule)
+        assert rule.names == gdn_ops.KEPT == (
+            "gdn_out", "gdn_segment_states", "gdn_chunk_inverses")
     assert not set(gdn_ops.KEPT) & set(kda_ops.KEPT)
+    # The kernels' rule takes the tile's inverse and the walk from there,
+    # and the recurrence over chunk states from neither: its own kernels.
+    assert gdn_ops._inverse_tile is kda_ops._inverse_tile
+    assert gdn_ops.KERNELS.across is not kda_ops._across
 
 
-def test_a_sequence_runs_in_segments(monkeypatch):
-    """Four segments of two chunks give what one segment of eight gives,
-    and the gradient's jaxpr holds ONE scan over segments each way."""
+@pytest.mark.parametrize("kernels", [False, True], ids=["plain", "kernels"])
+def test_a_sequence_runs_in_segments(kernels, monkeypatch):
+    """Four segments of two chunks give what one segment of eight gives;
+    the forward keeps a state a segment and, by the kernels, every chunk's
+    triangular inverse with its rows side by side in 128 lanes."""
     args = _inputs(s=128, d_k=12, d_v=24)
-    whole = gdn_chunked(*args, 16)
+    whole = gdn_chunked(*args, 16, kernels=kernels)
     monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 2)
-    cut = gdn_chunked(*args, 16)
+    cut = gdn_chunked(*args, 16, kernels=kernels)
     assert _rel(cut, whole) < 1e-5
+    rule = gdn_ops.KERNELS if kernels else gdn_ops.RULE
     states = jax.eval_shape(
-        lambda *a: kda_ops._forward(*a, 16, gdn_ops.RULE, keep=True), *args)
+        lambda *a: kda_ops._forward(*a, 16, rule, keep=True), *args)
     assert states[1].shape == (4, 1, 2, 12, 24)       # a state a segment
-    assert len(states) == 2                            # and nothing else
+    if kernels:
+        assert [a.shape for a in states[2:]] == [(4, 1, 2, 2, 2, 128)]
+    else:
+        assert len(states) == 2                        # and nothing else
 
 
 @pytest.mark.parametrize("s,chunk,message", [
@@ -202,23 +215,27 @@ def test_shapes_it_cannot_cut_are_refused(s, chunk, message):
         gdn_chunked(*_inputs(s=s, d_k=8, d_v=8), chunk)
 
 
-def test_the_forward_names_what_a_checkpoint_keeps(monkeypatch):
+@pytest.mark.parametrize("kernels,mark", [
+    (False, "cumsum"), (True, "name=gdn_chunk_forward"),
+], ids=["plain", "kernels"])
+def test_the_forward_names_what_a_checkpoint_keeps(kernels, mark, monkeypatch):
     """Under a checkpoint whose policy keeps ``KEPT`` the gradient runs
     the chunk quantities as often as without a checkpoint (forward, and
-    the backward's own rebuild); a bare checkpoint runs them once more."""
+    the backward's own rebuild; by the kernels the inverting forward
+    kernel once); a bare checkpoint runs them once more."""
     monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 1)
     args = _inputs(s=32, d_k=8, d_v=16)
 
     def loss(*a):
-        return jnp.sum(gdn_chunked(*a, 16))
+        return jnp.sum(gdn_chunked(*a, 16, kernels=kernels))
 
     policy = jax.checkpoint_policies.save_only_these_names(*gdn_ops.KEPT)
     kept = jax.checkpoint(loss, policy=policy)
 
-    def sums(fn):
-        return str(jax.make_jaxpr(jax.grad(fn))(*args)).count("cumsum")
+    def runs(fn):
+        return str(jax.make_jaxpr(jax.grad(fn))(*args)).count(mark)
 
-    assert sums(kept) == sums(loss) < sums(jax.checkpoint(loss))
+    assert 0 < runs(kept) == runs(loss) < runs(jax.checkpoint(loss))
 
 
 def test_the_states_products_are_float32_at_the_highest_precision():

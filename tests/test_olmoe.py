@@ -559,17 +559,10 @@ def test_scan_kernels_compile_for_the_chip_at_the_cells_shapes(one_chip, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 4 * states
 
 
-def test_scalar_decay_delta_rule_compiles_for_the_chip_at_olmo_hybrids_shape(
-        one_chip):
-    """One sequence of 4,096 tokens, 30 heads with keys of 96 and values
-    of 192 (neither a multiple of the 128 lanes) in chunks of 64, forward
-    and backward, ``ops/gdn.py``'s plain ``jax.numpy`` form: the TPU's
-    compiler takes it, no kernel stands in it, the loops left are the
-    walk over the two segments each way and the chunk states' scan inside
-    them, and its temporaries are 1.32 GB (a backward holds one
-    32-chunk segment's float32 intermediates, [64, 64] tiles padded to
-    128 lanes and [96, 192] states to 256: what kernels on [64, 96] and
-    [64, 192] tiles would keep in VMEM)."""
+def _olmo_hybrids_scan(one_chip, kernels: bool):
+    """The compiled gradient of one layer's scan at Olmo-Hybrid's shape:
+    one sequence of 4,096 tokens, 30 heads with keys of 96 and values of
+    192 (neither a multiple of the 128 lanes) in chunks of 64."""
     from raydp_tpu.ops import gdn
 
     bf16, f32 = jnp.bfloat16, jnp.float32
@@ -579,15 +572,74 @@ def test_scalar_decay_delta_rule_compiles_for_the_chip_at_olmo_hybrids_shape(
             jax.ShapeDtypeStruct((1, 4096, 30), f32))
 
     def loss(*a):
-        return jnp.sum(gdn.gdn_chunked(*a, 64).astype(f32) ** 2)
+        return jnp.sum(
+            gdn.gdn_chunked(*a, 64, kernels=kernels).astype(f32) ** 2)
 
-    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+    return jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
         *_on(one_chip, args)
     ).compile()
+
+
+def test_scalar_decay_delta_rule_compiles_for_the_chip_at_olmo_hybrids_shape(
+        one_chip):
+    """``ops/gdn.py``'s plain ``jax.numpy`` rule, forward and backward:
+    the TPU's compiler takes it, no kernel stands in it, the loops left
+    are the walk over the two segments each way and the chunk states' scan
+    inside them, and its temporaries are 1.32 GB (a backward holds one
+    32-chunk segment's float32 intermediates, [64, 64] tiles padded to
+    128 lanes and [96, 192] states to 256: what the kernels on [64, 96]
+    and [64, 192] tiles keep in VMEM)."""
+    compiled = _olmo_hybrids_scan(one_chip, kernels=False)
     hlo = compiled.as_text()
     assert "tpu_custom_call" not in hlo
     assert "f32[2,1,30,96,192]" in hlo       # the two segments' states
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert 1.2e9 < compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_scalar_decay_kernels_compile_for_the_chip_at_olmo_hybrids_shape(
+        one_chip, monkeypatch):
+    """The same gradient by the kernels' rule: Mosaic accepts blocks that
+    take 96 and 192 as they are (six calls: the chunk-local step's
+    forward, which writes every chunk's inverse ``T``, the backward's
+    rebuild, which reads it and inverts nothing, and the gradient; the
+    walk over the chunk states in the forward pass, again in the rebuild,
+    where it also writes a segment's states and ``w``, and backwards), the
+    only loops left are the two walks over the segments, no float32
+    [64, 64] array is in any buffer, ``T`` is kept with no padded lane,
+    and the temporaries are 0.82 GB where the plain rule's are 1.32."""
+    from raydp_tpu.ops import gdn, kda
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gdn.uses_kernels(96, 192, 64)
+    compiled = _olmo_hybrids_scan(one_chip, kernels=True)
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == hlo.count("tpu_custom_call") == 6
+    named = [re.search(r"%(gdn_[a-z_]+)", line).group(1) for line in calls]
+    assert sorted(named) == [
+        "gdn_chunk_backward", "gdn_chunk_forward", "gdn_chunk_rebuild",
+        "gdn_state_backward", "gdn_state_forward", "gdn_state_forward"]
+    a_segments_states = "f32[1,32,30,96,192]"
+    walks = [line.split(" custom-call(")[0] for line in calls
+             if "%gdn_state_forward" in line]
+    assert sorted(a_segments_states in line for line in walks) == [False, True]
+    loops = re.findall(r"= \([^\n]*\) while\(", hlo)
+    assert len(loops) == 2, len(loops)
+    for body in re.findall(r"while\([^\n]*body=%([\w.]+)", hlo):
+        text = hlo.split(f"\n%{body} (")[1].split("\n}\n")[0]
+        assert " while(" not in text and "gdn_state_" in text, body
+    for line in hlo.splitlines():
+        if re.search(r" (dot|convolution)\(", line):
+            assert not re.search(r"f32\[[\d,]*96,192\]", line), line
+    assert set(re.findall(r"f32\[[\d,]*64,64\]", hlo)) == set()
+    assert "f32[2,1,30,96,192]" in hlo       # the two segments' states
+    kept = kda.inverses_shape((2, 1, 32, 30), 64)
+    assert kept == (2, 1, 32, 30, 32, 128)
+    (layout,) = set(re.findall(r"f32\[2,1,32,30,32,128\]\{[^}]*\}", hlo))
+    assert layout.endswith("{5,4,3,2,1,0:T(8,128)}"), layout
+    assert 4 * int(np.prod(kept)) == 31_457_280
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
 
 
 def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
@@ -976,12 +1028,13 @@ def test_causal_convolution_kernels_compile_for_the_chip_at_the_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * size
 
 
-def _mixer_gradient(cfg, x, variables_on):
-    """The compiled gradient of one ``Mamba2Mixer``'s squared output, as
-    text; ``variables_on`` places the abstract variables."""
+def _mixer_gradient(cfg, x, variables_on, mixer=None):
+    """The compiled gradient of one mixer's squared output (a
+    ``Mamba2Mixer``'s where none is given), as text; ``variables_on``
+    places the abstract variables."""
     from raydp_tpu.models.mamba import Mamba2Mixer
 
-    mixer = Mamba2Mixer(cfg)
+    mixer = (mixer or Mamba2Mixer)(cfg)
     variables = jax.eval_shape(lambda: mixer.init(
         jax.random.PRNGKey(0), jnp.zeros((1,) + x.shape[1:], x.dtype)))
 
@@ -1077,3 +1130,37 @@ def test_a_mamba2_mixers_gradient_compiles_for_four_chips(
     assert sum(f"bf16[{rows},4096,1024]" in line for line in calls) == (
         2 if tp == 1 else 0)
     assert "all-reduce" in hlo
+
+
+@pytest.mark.parametrize("dp, tp", [(4, 1), (2, 2)])
+def test_a_gated_delta_mixers_gradient_compiles_for_four_chips(
+        four_chips, monkeypatch, dp, tp):
+    """The ``gdn`` mixer at the published head sizes with the model's mesh
+    told. dp = 4: each chip walks its own sequence by the scan's kernels
+    inside a ``shard_map``. dp = 2 by tp = 2: the scan, whose heads ``tp``
+    splits, stays the plain rule that XLA partitions over them
+    (``models/gdn.scan_takes_kernels``)."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from raydp_tpu.models.gdn import GatedDeltaMixer
+    from raydp_tpu.models.transformer import olmo_hybrid_7b
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(four_chips).reshape(dp, tp), ("dp", "tp"))
+    cfg = olmo_hybrid_7b(
+        n_layers=1, layer_types=("gdn:swiglu",), d_model=256, mesh=mesh,
+        dtype=jnp.bfloat16)
+    hlo = _mixer_gradient(
+        cfg, jax.ShapeDtypeStruct(
+            (4, 128, cfg.d_model), jnp.bfloat16,
+            sharding=NamedSharding(mesh, P("dp"))),
+        functools.partial(_on, NamedSharding(mesh, P())), GatedDeltaMixer)
+    names = set(re.findall(r"%(gdn_[a-z_]+?)[.\d]* = ", hlo))
+    assert names == ({
+        "gdn_chunk_forward", "gdn_chunk_rebuild", "gdn_chunk_backward",
+        "gdn_state_forward", "gdn_state_backward"} if tp == 1 else set())
+    if tp == 1:
+        # A chip's own sequence: one of four rows, two chunks, thirty heads.
+        assert "f32[1,2,30,64,192]" in hlo
+
