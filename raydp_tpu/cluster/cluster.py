@@ -108,11 +108,13 @@ def call_envelope(client: RpcClient, method: str, payload: dict,
 
 
 def task_stamps(envelope: Optional[dict], res: dict) -> Optional[dict]:
-    """``envelope`` with one task's body ``start``/``end`` (the worker's
-    ``perf_counter``): the fourth argument of a ``meta_sink``."""
+    """``envelope`` with one task's body ``start``/``end`` and the body's
+    ``parts`` (the worker's ``perf_counter``; ``None`` from a worker that
+    stamps none): the fourth argument of a ``meta_sink``."""
     if envelope is None or "start" not in res:
         return None
-    return dict(envelope, start=res["start"], end=res["end"])
+    return dict(envelope, start=res["start"], end=res["end"],
+                parts=res.get("parts"))
 
 
 class Cluster:

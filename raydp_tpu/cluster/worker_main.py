@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import atexit
+import contextlib
 import logging
 import os
 import sys
@@ -47,6 +48,47 @@ _DEDUP_CAPACITY = 1024
 # this long for the first execution to finish before giving up.
 _DEDUP_WAIT_S = 300.0
 
+# The task body open on this thread: the list its ``WorkerContext`` calls
+# stamp into. Thread-local, because an envelope's tasks run side by side
+# on the worker's pool and their parts must not mix.
+_body = threading.local()
+
+
+@contextlib.contextmanager
+def _task_body():
+    """Opens this thread's task body (at its ``start``) and yields the
+    list that rides the task's reply beside ``start`` and ``end``:
+    ``[kind, t0, t1]`` (``perf_counter``) for each ``fetch``, ``put`` and
+    ``register`` the body made through its ``WorkerContext``. What is
+    left of the body is its compute: never stamped."""
+    parts = _body.parts = []
+    try:
+        yield parts
+    finally:
+        _body.parts = None
+
+
+@contextlib.contextmanager
+def _stamped(kind: str):
+    """One ``perf_counter`` pair around a part of the open task body;
+    calls of one kind that follow one another are one interval (a
+    counting body's eight fetches). Outside a body (the resolving of
+    ``data_refs`` before it, a profile's ``put_bytes``) it records
+    nothing."""
+    parts = getattr(_body, "parts", None)
+    if parts is None:
+        yield
+        return
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        t1 = time.perf_counter()
+        if parts and parts[-1][0] == kind:
+            parts[-1][2] = t1
+        else:
+            parts.append([kind, t0, t1])
+
 
 class WorkerContext:
     """Handed to every shipped task as its first argument."""
@@ -77,19 +119,27 @@ class WorkerContext:
         from raydp_tpu.store.object_store import OWNER_HOLDER
 
         owner = OWNER_HOLDER if holder else self.worker_id
-        ref = self.store.put_arrow_table(table, owner=owner)
-        self._master.call("RegisterObject", {"ref": ref})
-        return ref
+        with _stamped("put"):
+            ref = self.store.put_arrow_table(table, owner=owner)
+        return self._register(ref)
 
     def put_bytes(self, data) -> "ObjectRef":
-        ref = self.store.put(data, owner=self.worker_id)
-        self._master.call("RegisterObject", {"ref": ref})
+        with _stamped("put"):
+            ref = self.store.put(data, owner=self.worker_id)
+        return self._register(ref)
+
+    def _register(self, ref):
+        """Enter ``ref`` in the master's object directory: a round trip
+        the caller waits for."""
+        with _stamped("register"):
+            self._master.call("RegisterObject", {"ref": ref})
         return ref
 
     def get_table(self, ref):
         """Read an Arrow table from anywhere in the cluster: local shm
         zero-copy, or a gRPC pull from the owning node's store agent."""
-        return self.resolver.get_arrow_table(ref)
+        with _stamped("fetch"):
+            return self.resolver.get_arrow_table(ref)
 
     def get_bytes(self, ref):
         return self.resolver.get_bytes(ref)
@@ -236,7 +286,8 @@ class Worker:
 
     def _execute_task(self, req: dict) -> dict:
         # The worker's own stamps (``perf_counter``): handler entered,
-        # body start and end, handler about to return. They ride the
+        # body start and end, the body's fetches, puts and registrations
+        # (``_task_body``), handler about to return. They ride the
         # reply beside ``exec_s`` so the driver partitions a stage's
         # wall from inside (``_StageRecorder``) with no extra RPC.
         recv = time.perf_counter()
@@ -274,14 +325,15 @@ class Worker:
                 "worker/task", worker_id=self.worker_id,
                 stall_after_s=_watchdog.long_stall_s(),
             ):
-                with span("worker/task", worker_id=self.worker_id):
+                with span("worker/task", worker_id=self.worker_id), \
+                        _task_body() as parts:
                     result = fn(self.ctx, *args, *data, **kwargs)
             end = time.perf_counter()
             _flight.record("task", "end", worker_id=self.worker_id)
             exec_s = self._task_ran(start, end)
             return {
                 "result": result, "exec_s": exec_s,
-                "start": start, "end": end,
+                "start": start, "end": end, "parts": parts,
                 "recv": recv, "ret": time.perf_counter(),
             }
         except Exception:
@@ -332,7 +384,7 @@ class Worker:
         reports per-task ``{"ok": ...}`` so one bad partition fails only
         its own future, not its siblings in the envelope. The reply
         carries the envelope's ``recv``/``ret`` stamps, each task its
-        body's ``start``/``end``.
+        body's ``start``/``end`` and ``parts``.
         """
         recv = time.perf_counter()
         with self._busy_lock:
@@ -372,12 +424,13 @@ class Worker:
                                 )
                         self._fault_task_hook()
                         start = time.perf_counter()
-                        with span("worker/task", worker_id=self.worker_id):
+                        with span("worker/task", worker_id=self.worker_id), \
+                                _task_body() as parts:
                             value = fn(self.ctx, *args, *data, **kwargs)
                         end = time.perf_counter()
                         exec_s = self._task_ran(start, end)
                     return {"ok": True, "value": value, "exec_s": exec_s,
-                            "start": start, "end": end}
+                            "start": start, "end": end, "parts": parts}
                 except Exception as exc:
                     return {
                         "ok": False,

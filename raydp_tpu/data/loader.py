@@ -165,7 +165,20 @@ class JaxShardLoader:
             wanted = list(self.feature_columns)
             if self.label_column:
                 wanted.append(self.label_column)
-            self._columns = self._dataset.shard_columns(self._rank, wanted)
+            # The hand-off's own work, on this (the producer's) thread:
+            # the dataset opens ``handoff/await_blocks``, ``handoff/fetch``
+            # and ``handoff/convert`` under it.
+            with span("handoff/materialize", rank=self._rank) as sp:
+                cols = self._dataset.shard_columns(self._rank, wanted)
+                # On the recorder's copy (a profiler annotation took its
+                # attrs at entry).
+                plan = getattr(self._dataset, "shard_plan", None) or {}
+                sp.attrs.update(
+                    blocks=len(plan.get(self._rank, ())),
+                    rows=len(next(iter(cols.values()), ())),
+                    bytes=sum(c.nbytes for c in cols.values()),
+                )
+            self._columns = cols
         return self._columns
 
     def _stage_matrix(self):
@@ -480,8 +493,6 @@ class JaxShardLoader:
             # staging holds at most prefetch × chunk bytes.
             source, stop_event = _background(source, self.prefetch)
 
-        batch_counter = metrics.counter_add
-
         def put_chunk(chunk):
             if isinstance(chunk, _PackedChunk):
                 # Bracketed: a host→device transfer that never completes
@@ -507,7 +518,6 @@ class JaxShardLoader:
             n = x.shape[0] if hasattr(x, "shape") else len(x)
             for lo in range(0, n, bs):
                 hi = min(lo + bs, n)
-                batch_counter("ingest/batches")
                 # On-device slicing: an async XLA slice per batch, which
                 # pipelines behind the chunk transfer instead of paying a
                 # host→device trip per batch.

@@ -10,6 +10,7 @@ torch DataLoader (though ``to_torch`` exists for interop).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import threading
 from dataclasses import dataclass
@@ -24,6 +25,7 @@ from raydp_tpu.dataframe.scheduler import (
     resolve_one,
 )
 from raydp_tpu.store.object_store import ObjectRef, ObjectStore
+from raydp_tpu.telemetry import span
 from raydp_tpu.utils.sharding import (
     BlockSlice,
     divide_blocks,
@@ -153,8 +155,14 @@ class MLDataset:
         if self._shard_plan is not None:
             return
         # Resolve OUTSIDE the lock (arbitrarily long); idempotent, so a
-        # racing second consumer just re-resolves the same futures.
-        blocks = [resolve_one(b) for b in self._blocks]
+        # racing second consumer just re-resolves the same futures. Over
+        # pending blocks this is the wait for the lazily run last ETL
+        # stage, seen from the consumer: ``handoff/await_blocks``.
+        pending = sum(1 for b in self._blocks if is_pending(b))
+        with span(
+            "handoff/await_blocks", blocks=len(self._blocks), pending=pending
+        ) if pending else contextlib.nullcontext():
+            blocks = [resolve_one(b) for b in self._blocks]
         sizes = [self._block_rows(b) for b in blocks]
         with self._plan_mu:
             if self._shard_plan is not None:
@@ -330,12 +338,14 @@ class MLDataset:
         if rank not in self._shard_plan:
             raise IndexError(f"rank {rank} out of {self.num_shards}")
         out = []
-        for s in self._shard_plan[rank]:
-            table = self._resolve(self._blocks[s.block_index])
-            if s.offset == 0 and s.num_samples == table.num_rows:
-                out.append(table)
-            else:
-                out.append(table.slice(s.offset, s.num_samples))
+        plan = self._shard_plan[rank]
+        with span("handoff/fetch", rank=rank, blocks=len(plan)):
+            for s in plan:
+                table = self._resolve(self._blocks[s.block_index])
+                if s.offset == 0 and s.num_samples == table.num_rows:
+                    out.append(table)
+                else:
+                    out.append(table.slice(s.offset, s.num_samples))
         return out
 
     def shard_global_indices(self, rank: int) -> np.ndarray:
@@ -369,17 +379,20 @@ class MLDataset:
     ) -> Dict[str, np.ndarray]:
         """Shard materialized as contiguous numpy columns (loader input)."""
         tables = self.shard_tables(rank)
-        merged = (
-            pa.concat_tables(tables, promote_options="default")
-            if len(tables) > 1
-            else tables[0]
-        )
-        names = columns or merged.column_names
-        out: Dict[str, np.ndarray] = {}
-        for name in names:
-            # Direct Arrow→numpy (zero-copy when no nulls + numeric); no
-            # pandas Series intermediary on the ingest path.
-            out[name] = merged.column(name).to_numpy(zero_copy_only=False)
+        with span("handoff/convert", rank=rank, tables=len(tables)):
+            merged = (
+                pa.concat_tables(tables, promote_options="default")
+                if len(tables) > 1
+                else tables[0]
+            )
+            names = columns or merged.column_names
+            out: Dict[str, np.ndarray] = {}
+            for name in names:
+                # Direct Arrow→numpy (zero-copy when no nulls + numeric);
+                # no pandas Series intermediary on the ingest path.
+                out[name] = merged.column(name).to_numpy(
+                    zero_copy_only=False
+                )
         return out
 
     def to_jax(

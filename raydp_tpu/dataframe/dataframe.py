@@ -1017,8 +1017,9 @@ class DataFrame:
         ``analyze=True`` EXECUTES the plan first (EXPLAIN ANALYZE) and
         renders per-stage runtime stats under each node: rows and bytes
         in/out, the wall and (cluster stages) its partition into submit,
-        transit, load, exec and driver time, worker attribution, and the
-        partition-skew ratio. Returns the rendered text (and prints it
+        transit, load, exec and driver time and what its task bodies
+        spent in fetch, compute, put and register, worker attribution,
+        and the partition-skew ratio. Returns the rendered text (and prints it
         unless ``quiet``)."""
         df = self._flush() if analyze else self
         if analyze:
@@ -1618,8 +1619,9 @@ def _fmt_bytes(n: int) -> str:
 
 def _fmt_partition(s) -> str:
     """A cluster stage's wall partitioned along its critical path
-    (``StageStats``: the five sum to the wall); nothing for a local
-    stage, whose wall is the driver's own work."""
+    (``StageStats``: the five sum to the wall), then what all its task
+    bodies together are made of; nothing for a local stage, whose wall
+    is the driver's own work."""
     if s.executor != "cluster":
         return ""
     text = (
@@ -1629,7 +1631,18 @@ def _fmt_partition(s) -> str:
     )
     if s.upstream_s >= 5e-4:
         text += f", of it upstream wait {s.upstream_s * 1e3:.1f}ms"
-    return text + ")"
+    text += ")"
+    if s.tasks_stamped:
+        # Over ALL the stage's bodies (work, not wall): what a worker
+        # stamped where it fetched, stored and registered.
+        compute = s.body_s - s.fetch_s - s.put_s - s.register_s
+        text += (
+            f" (task bodies x{s.tasks_stamped} {s.body_s * 1e3:.1f}ms: fetch"
+            f" {s.fetch_s * 1e3:.1f}ms, compute {compute * 1e3:.1f}ms,"
+            f" put {s.put_s * 1e3:.1f}ms, register"
+            f" {s.register_s * 1e3:.1f}ms)"
+        )
+    return text
 
 
 def _render_plan(lineage: List[Dict[str, Any]], analyze: bool) -> str:

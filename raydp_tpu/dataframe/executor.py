@@ -119,6 +119,9 @@ def _part_meta(part: Any) -> "tuple[int, int]":
 
 #: Envelopes a ``stage/close`` span lists at most (its one string attr).
 _CLOSE_ENVELOPES = 32
+#: What a stage's task bodies add up to (``StageStats``): the three parts
+#: a worker stamps (``fetch``, ``put``, ``register``) and the bodies whole.
+_BODY_PARTS = ("fetch_s", "put_s", "register_s", "body_s")
 
 
 def _union_s(intervals, lo: float, hi: float) -> float:
@@ -175,7 +178,13 @@ class _StageRecorder:
     the rest of ``ret − recv``; ``driver_s`` is what is left of
     ``wall_s`` (before the first round — for a streaming stage the wait
     for upstream partitions, kept apart as ``upstream_s`` — between
-    rounds, and after the last reply). The five sum to ``wall_s``."""
+    rounds, and after the last reply). The five sum to ``wall_s``.
+
+    Beside the partition, ``fetch_s``, ``put_s``, ``register_s`` and
+    ``body_s`` add up what ALL the stage's task bodies spent in
+    ``WorkerContext.get_table``, in the store's write, in the
+    ``RegisterObject`` round trip and in all (``tasks_stamped`` bodies):
+    work, not wall, so no span attr carries them."""
 
     def __init__(self, op: str, parts_in: Sequence[Any], kind: str,
                  total_tasks: Optional[int] = None, streaming: bool = False):
@@ -189,6 +198,8 @@ class _StageRecorder:
         self._t0 = time.perf_counter()
         self._rounds: List[dict] = []
         self._workers: dict = {}
+        self._bodies = dict.fromkeys(_BODY_PARTS, 0.0)
+        self._tasks_stamped = 0
         self._mu = threading.Lock()
         self._outs: Optional[List[Any]] = None
         self._rows_in = self._bytes_in = 0
@@ -247,6 +258,12 @@ class _StageRecorder:
                         "bodies": [],
                     }
                 env["bodies"].append((stamps["start"], stamps["end"]))
+                if stamps.get("parts") is not None:
+                    self._tasks_stamped += 1
+                    self._bodies["body_s"] += stamps["end"] - stamps["start"]
+                    for kind, t0, t1 in stamps["parts"]:
+                        if kind + "_s" in self._bodies:
+                            self._bodies[kind + "_s"] += t1 - t0
         progress.task_done(self.stage_id)
 
     def task_done(self, n: int = 1) -> None:
@@ -402,6 +419,8 @@ class _StageRecorder:
             workers=dict(self._workers),
             part_rows=part_rows,
             part_bytes=part_bytes,
+            tasks_stamped=self._tasks_stamped,
+            **self._bodies,
             **parts,
         )
         stage_store.record(stats)

@@ -9,7 +9,8 @@ families, `/debug/progress`, and the cost-based adaptive planner
   :class:`StageStats` records, one per executed DataFrame stage
   (map / exchange / coalesce), carrying rows and bytes in/out,
   the wall and its partition into submit, transit, load, exec and
-  driver seconds (``queue_s`` is transit + load), per-worker task
+  driver seconds (``queue_s`` is transit + load), what its task bodies
+  spent fetching, storing and registering, per-worker task
   attribution, and the
   per-partition output layout the skew ratio (max/mean rows) is
   computed from. Executors record into it as stages complete;
@@ -105,6 +106,18 @@ class StageStats:
     exec_s: float = 0.0           # union of that envelope's bodies
     driver_s: float = 0.0         # the rest: before, between, after
     upstream_s: float = 0.0       # of driver_s: start -> first round
+    # What the stage's task bodies are made of, from the stamps a worker
+    # puts on the reply where it fetches, stores and registers
+    # (``WorkerContext``). Sums over ALL the stage's bodies, unlike
+    # ``exec_s`` (one envelope's union): they partition work, not wall.
+    # ``body_s`` less the three is the bodies' compute. ``tasks_stamped``
+    # counts the bodies in the sums (a worker that stamps none: short of
+    # the tasks).
+    fetch_s: float = 0.0          # resolver.get_arrow_table
+    put_s: float = 0.0            # the store's write, without the RPC
+    register_s: float = 0.0       # the RegisterObject round trip
+    body_s: float = 0.0           # sum of end - start
+    tasks_stamped: int = 0
     workers: Dict[str, int] = field(default_factory=dict)  # wid -> tasks
     part_rows: List[int] = field(default_factory=list)     # output layout
     part_bytes: List[int] = field(default_factory=list)
@@ -138,6 +151,11 @@ class StageStats:
             "exec_s": round(self.exec_s, 6),
             "driver_s": round(self.driver_s, 6),
             "upstream_s": round(self.upstream_s, 6),
+            "fetch_s": round(self.fetch_s, 6),
+            "put_s": round(self.put_s, 6),
+            "register_s": round(self.register_s, 6),
+            "body_s": round(self.body_s, 6),
+            "tasks_stamped": self.tasks_stamped,
             "workers": dict(self.workers),
             "part_rows": list(self.part_rows),
             "part_bytes": list(self.part_bytes),
