@@ -33,7 +33,9 @@ from raydp_tpu.models.mamba import (
     _decay_rate_init,
     _replicated,
     _step_bias_init,
+    conv_takes_kernel,
 )
+from raydp_tpu.ops.causal_conv import Unit
 from raydp_tpu.ops.kda import IMPLEMENTATION as SCAN_IMPLEMENTATION
 from raydp_tpu.ops.kda import PATHS as SCAN_PATHS
 from raydp_tpu.ops.kda import kda_chunked, uses_kernels
@@ -85,31 +87,52 @@ class KDAConfig:
 class QKVConv(nn.Module):
     """The three causal depthwise convolutions (no bias) with their SiLU,
     and the L2 norm of each head's ``q`` and ``k``; ``q`` times
-    ``d_k^-1/2``. Returns [B, S, H, d] arrays in the compute dtype."""
+    ``d_k^-1/2``. Returns [B, S, H, d] arrays in the compute dtype.
+
+    Where the convolution's kernels take a call with the norm inside
+    (``ops/causal_conv.py``: on a TPU, at a shape they tile, a head of
+    whole 128-lane registers: Kimi Linear's 128), ``q`` and ``k`` leave
+    them normalised, scaled and in the compute dtype, and nothing float32
+    of their size exists in HBM either way. Everywhere else (off the TPU,
+    a decode step, Olmo-Hybrid's heads of 96) the convolution returns
+    float32 and :meth:`unit` runs as ``jax.numpy`` after it: the same
+    float32 arithmetic and one rounding either way. ``v`` has no norm:
+    float32 out of its convolution and cast here."""
 
     kda: KDAConfig
     dtype: jnp.dtype
     param_dtype: jnp.dtype
     mesh: Any = None
 
+    @staticmethod
+    def unit(x):
+        return x * jax.lax.rsqrt(
+            jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS
+        )
+
     @nn.compact
     def __call__(self, q, k, v):
         kda = self.kda
 
-        def conv(x, name, width):
+        def conv(x, name, width, **norm):
             y = CausalConv1d(
-                kda.conv_taps, jnp.float32, self.param_dtype, use_bias=False,
-                mesh=self.mesh, name=name,
+                kda.conv_taps, self.dtype if norm else jnp.float32,
+                self.param_dtype, use_bias=False, mesh=self.mesh, name=name,
+                **norm,
             )(x)
             return y.reshape(*y.shape[:-1], kda.heads, width)
 
-        def unit(x):
-            return x * jax.lax.rsqrt(
-                jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS
-            )
-
-        q = unit(conv(q, "q", kda.key_dim)) * kda.key_dim ** -0.5
-        k = unit(conv(k, "k", kda.key_dim))
+        unit = Unit(kda.key_dim, L2_EPS)
+        # q's and k's calls are one shape: one answer for both.
+        if q.ndim == 3 and conv_takes_kernel(
+                q.shape[1], q.shape[2], kda.conv_taps, q.dtype, self.dtype,
+                mesh=self.mesh, unit=unit):
+            q = conv(q, "q", kda.key_dim, unit=unit,
+                     scale=kda.key_dim ** -0.5)
+            k = conv(k, "k", kda.key_dim, unit=unit)
+        else:
+            q = self.unit(conv(q, "q", kda.key_dim)) * kda.key_dim ** -0.5
+            k = self.unit(conv(k, "k", kda.key_dim))
         v = conv(v, "v", kda.value_dim)
         return q.astype(self.dtype), k.astype(self.dtype), v.astype(self.dtype)
 
@@ -216,11 +239,14 @@ def layers_of(cfg) -> int:
     return sum(1 for kind in getattr(cfg, "kinds", ()) if kind == "kda")
 
 
-def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
+def report(cfg, tokens_per_step: int, sequence: int = 0,
+           convs=(0, 0, 0)) -> None:
     """Static for a compiled step: eight gauges and one log line where the
     step is built (as ``models/mamba.report``). All zero for a stack
     without such layers. ``sequence`` is a sequence's tokens (all of a
-    step's where left out)."""
+    step's where left out); ``convs`` the step's census of causal
+    convolutions (``models/mamba.counting_convs``, whose gauges
+    ``models/mamba.report`` sets), for the log line."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
@@ -242,11 +268,14 @@ def report(cfg, tokens_per_step: int, sequence: int = 0) -> None:
     if kda:
         logger.info(
             "delta-rule stack: layers %s; %d heads of %d (q, k) and %d (v), "
-            "a decay per channel, %d-tap convolutions, gates of rank "
+            "a decay per channel, %d-tap convolutions (the stack's: %d as "
+            "Pallas kernels, %d of those with the L2 norm of q and k "
+            "inside, %d in jax.numpy), gates of rank "
             "%d; chunk %d (%d chunks a step); scan: %s; the forward keeps "
             "%d MiB of chunk inverses a sequence; a chunk's work and the "
             "walk over the chunks at these shapes: %s",
             " ".join(cfg.kinds), kda.heads, kda.key_dim, kda.value_dim,
-            kda.conv_taps, kda.gate_rank, kda.chunk, chunks,
+            kda.conv_taps, convs[0], convs[2], convs[1], kda.gate_rank,
+            kda.chunk, chunks,
             SCAN_IMPLEMENTATION, kept, SCAN_PATHS[kernels],
         )

@@ -104,7 +104,7 @@ def _kernels_may_run(mesh) -> bool:
 
 def conv_takes_kernel(sequence: int, channels: int, taps: int, x_dtype,
                       out_dtype, sequence_minor: bool = False,
-                      mesh=None) -> bool:
+                      mesh=None, unit=None) -> bool:
     """Whether a :class:`CausalConv1d` call of these shapes runs as the
     Pallas kernels of ``ops/causal_conv.py``: where a Mosaic kernel may
     stand at all (:func:`_kernels_may_run`; with a ``mesh`` the call is
@@ -113,9 +113,10 @@ def conv_takes_kernel(sequence: int, channels: int, taps: int, x_dtype,
     a shape the kernels decline (a
     decode step's single token among them), the call is
     :func:`causal_depthwise_conv` and ``jax.nn.silu``, which the compiler
-    partitions as it did."""
+    partitions as it did. ``unit`` (``causal_conv.Unit``) asks for the
+    kernels with each head's L2 norm inside."""
     return _kernels_may_run(mesh) and causal_conv.uses_kernel(
-        sequence, channels, taps, x_dtype, out_dtype, sequence_minor
+        sequence, channels, taps, x_dtype, out_dtype, sequence_minor, unit
     )
 
 
@@ -129,7 +130,10 @@ class CausalConv1d(nn.Module):
     kernels which layout the compiler gives the arrays around the call
     (``ops/causal_conv.py``): the caller's knowledge, not a choice of
     result. ``mesh`` is the model's (``cfg.mesh``), for a step compiled
-    for more than one device."""
+    for more than one device. ``unit`` (``causal_conv.Unit``) has the
+    kernels write each head L2-normalised and times ``scale``: theirs
+    alone, so the caller sets it only where :func:`conv_takes_kernel` took
+    the call with it (``models/kda.QKVConv``)."""
 
     taps: int
     dtype: jnp.dtype
@@ -137,6 +141,8 @@ class CausalConv1d(nn.Module):
     use_bias: bool = True
     sequence_minor: bool = False
     mesh: Any = None
+    unit: Any = None
+    scale: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -153,6 +159,12 @@ class CausalConv1d(nn.Module):
             return causal_conv.causal_conv_silu(
                 x, kernel, bias, dtype=self.dtype,
                 sequence_minor=self.sequence_minor, mesh=self.mesh,
+                unit=self.unit, scale=self.scale,
+            )
+        if self.unit is not None:
+            raise ValueError(
+                f"no kernel holds the norm {self.unit} of x {x.shape} "
+                f"{x.dtype}: ask conv_takes_kernel first"
             )
         return jax.nn.silu(
             causal_depthwise_conv(x, kernel, bias)
@@ -165,22 +177,26 @@ def takes_kernel(conv: CausalConv1d, x) -> bool:
     (:func:`counting_convs`) are this one function."""
     return x.ndim == 3 and conv_takes_kernel(
         x.shape[1], x.shape[-1], conv.taps, x.dtype, conv.dtype,
-        conv.sequence_minor, conv.mesh,
+        conv.sequence_minor, conv.mesh, conv.unit,
     )
 
 
-def _counting(kind, takes):
+def _counting(kind, takes, *marks):
     """``(interceptor, read)``: a flax method interceptor that counts the
     calls of modules of ``kind`` made under it by the form each takes
     (``takes(module, *operands)``), and the function that reads ``(kernel
-    calls, jax.numpy calls)`` afterwards (as ``models/dropout.counting``)."""
-    found = [0, 0]
+    calls, jax.numpy calls)`` afterwards (as ``models/dropout.counting``);
+    after those two, the kernel calls each of ``marks(module)`` holds
+    for."""
+    found = [0, 0] + [0] * len(marks)
 
     def count(next_fun, args, kwargs, context):
         if (isinstance(context.module, kind)
                 and context.method_name == "__call__"):
             taken = takes(context.module, *args, *kwargs.values())
             found[0 if taken else 1] += 1
+            for at, mark in enumerate(marks, 2):
+                found[at] += bool(taken and mark(context.module))
         return next_fun(*args, **kwargs)
 
     return count, lambda: tuple(found)
@@ -188,8 +204,10 @@ def _counting(kind, takes):
 
 def counting_convs():
     """The census of the :class:`CausalConv1d` calls, a Mamba-2 mixer's
-    and a delta-rule layer's alike (:func:`_counting`)."""
-    return _counting(CausalConv1d, takes_kernel)
+    and a delta-rule layer's alike (:func:`_counting`): ``(kernel calls,
+    jax.numpy calls, the kernel calls that hold the L2 norm)``."""
+    return _counting(
+        CausalConv1d, takes_kernel, lambda conv: conv.unit is not None)
 
 
 def scan_takes_kernels(sequence: int, heads: int, head_dim: int, groups: int,
@@ -371,22 +389,27 @@ def layers_of(cfg) -> int:
     return sum(1 for kind in getattr(cfg, "kinds", ()) if kind == "mamba")
 
 
-def report(cfg, tokens_per_step: int, convs=(0, 0), scans=(0, 0)) -> None:
-    """Static for a compiled step: the ``ssm/*`` gauges, the two
+def report(cfg, tokens_per_step: int, convs=(0, 0, 0),
+           scans=(0, 0)) -> None:
+    """Static for a compiled step: the ``ssm/*`` gauges, the three
     ``conv/*_calls`` gauges and one log line where the step is built
     (as ``models/dropout.report``), nothing per step. All zero for a
     stack without state-space layers. ``convs`` is what
     :func:`counting_convs` read off the step's abstract apply
     (``models/step.survey``): the :class:`CausalConv1d` calls of the whole
-    model, the delta-rule layers' among them, by the form each took;
-    ``scans`` what :func:`counting_scans` read of the
+    model, the delta-rule layers' among them, by the form each took, and
+    of the kernel calls those with the L2 norm inside
+    (``conv/unit_kernel_calls``: q's and k's of a delta-rule layer with
+    heads of whole registers; ``models/kda.report`` logs them); ``scans``
+    what :func:`counting_scans` read of the
     :class:`SelectiveScan` calls."""
     from raydp_tpu.utils.profiling import metrics
 
     layers = layers_of(cfg)
-    kernel_calls, jnp_calls = convs
+    kernel_calls, jnp_calls, unit_kernel_calls = convs
     metrics.gauge_set("conv/kernel_calls", kernel_calls)
     metrics.gauge_set("conv/jnp_calls", jnp_calls)
+    metrics.gauge_set("conv/unit_kernel_calls", unit_kernel_calls)
     metrics.gauge_set("ssm/scan_kernel_calls", scans[0])
     metrics.gauge_set("ssm/scan_jnp_calls", scans[1])
     chunks = state = 0

@@ -116,13 +116,14 @@ class Survey(NamedTuple):
     it is given, so its result's shape is its input's; ONE input a name,
     which is right for a stack run ``cfg.passes`` times too: every
     application of a block has the same shapes) and the causal
-    convolutions ``(kernel calls, jax.numpy calls)`` by the form each
-    takes (``models/mamba.counting_convs``), and the state-space scans
-    likewise (``models/mamba.counting_scans``)."""
+    convolutions ``(kernel calls, jax.numpy calls, kernel calls with the
+    L2 norm inside)`` by the form each takes
+    (``models/mamba.counting_convs``), and the state-space scans ``(kernel
+    calls, jax.numpy calls)`` (``models/mamba.counting_scans``)."""
     dropout: Tuple[int, int] = (0, 0)
     out: Any = None
     blocks: dict = {}
-    convs: Tuple[int, int] = (0, 0)
+    convs: Tuple[int, int, int] = (0, 0, 0)
     scans: Tuple[int, int] = (0, 0)
 
 
@@ -177,7 +178,10 @@ def report(model, params, sample_batch, surveyed=None) -> None:
         cfg, tokens_per_step=tokens_per_step, convs=surveyed.convs,
         scans=surveyed.scans,
     )
-    kda.report(cfg, tokens_per_step=tokens_per_step, sequence=seq_len)
+    kda.report(
+        cfg, tokens_per_step=tokens_per_step, sequence=seq_len,
+        convs=surveyed.convs,
+    )
     gdn.report(cfg, tokens_per_step=tokens_per_step, sequence=seq_len)
     shortconv.report(cfg)
     latent.report(cfg)
@@ -223,7 +227,7 @@ def report_epoch(stats_sum: dict, n_batches: int) -> None:
 #:   LFM2 (7)           10.92   10.29     7 of 7   14.19  13.53     -     -
 #:   Xing4.0 (5)        11.51   10.83     5 of 5   14.59  13.90     -     -
 #:   Laguna (5)         13.70   12.29     3, 4     14.95  13.52   16.27 13.69
-#:   Kimi Linear (5)    14.65   13.07     4        14.65  14.41     -     -
+#:   Kimi Linear (5)    14.34   13.18     4        14.34  14.29   15.58 14.43
 #:   SDAR (6)           12.95   11.91     4, 5     14.30  13.49   15.65 13.81
 #:   Keye (5)           11.68   10.24     2, 3, 4  14.15  13.07   15.38 13.82
 #:   Nemotron (9)       12.45   12.10     5, 7, 8  14.57  13.89   15.33 13.97
@@ -238,9 +242,10 @@ def report_epoch(stats_sum: dict, n_batches: int) -> None:
 #: 13.06, are bounded.)
 #: 1.2 is the least slack (in tenths) at which the estimate is no lower
 #: than the compiled figure in any row of the table (Kimi Linear
-#: with its last block released binds: 14.65 against 14.41; it was 1.6 on
-#: a count that took everything to be live at once); 5% of 15.75 GiB
-#: leaves the estimate 14.96, which admits the two stacks measured whole
+#: with its last block released binds: 14.34 against 14.29 since PR 66
+#: took two float32 arrays out of a KDA block, 14.65 against 14.41 before;
+#: it was 1.6 on a count that took everything to be live at once); 5% of
+#: 15.75 GiB leaves the estimate 14.96, which admits the two stacks whole
 #: (Granite 13.45 and LFM2 13.53 GiB compiled) and leaves every compiled
 #: choice 1.3 GiB or more under the limit. Where sequences are long the
 #: count still reads 1-2 GiB over the compiler, which orders the inside

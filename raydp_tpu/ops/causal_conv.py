@@ -7,8 +7,8 @@ a Kimi Delta Attention layer), ONE kernel forward and ONE backward:
 for ``x`` ``[B, S, C]`` in the compute dtype, ``w`` ``[k, C]`` and ``b``
 ``[C]`` float32 (``b`` may be absent), ``y`` in the dtype asked for.
 
-    forward   reads x            writes y
-    backward  reads x, dy        writes dx, and dw, db once a channel block
+    forward   reads x            writes y  (or n, each head normalised)
+    backward  reads x, dy (dn)   writes dx, and dw, db once a channel block
 
 Both walk a grid of (channel blocks, sequences, sequence blocks), the
 sequence blocks one after the other: the forward from the first, with the
@@ -22,6 +22,24 @@ holds on a walk from the end, come through a second, one-tile
 sum_t g_t`` add up in float32 VMEM scratch over a channel block's whole
 walk and are written at its end. No padded copy and nothing float32 of the
 input's size exists in HBM.
+
+**The L2 norm of a head inside** (``unit``: :class:`Unit`, a static
+argument of the same two bodies; ``None`` leaves them what they are). A
+delta-rule layer normalises each head of ``q`` and ``k`` after the SiLU
+(``models/kda.QKVConv``); as ``jax.numpy`` around the kernels that cost a
+float32 ``y`` written and read again, a float32 cotangent for the backward
+to read and the broadcasts between them (69 ms of norm and 23 of a
+``reshape`` a Kimi Linear step: PERF.md §6, PR 66). With ``unit`` the
+forward writes ``n = scale · y · r``, ``r = rsqrt(sum y² + eps)`` over each
+run of ``unit.width`` lanes, in the output's dtype (float32 inside, ONE
+rounding), and the backward takes ``dn``: it makes ``y`` and ``r`` again
+from ``pre`` and forms ``dy = scale · r · (dn − ŷ · sum(dn · ŷ))``, ``ŷ =
+y r``, before ``g``. One lane reduction a head a row forward, two backward;
+a channel block holds whole heads (at most four of 128 lanes: a constant's
+worth of slices, not a shape's). ``scale`` (``d_k^-1/2`` for ``q``, 1 for
+``k``) is an operand, a row after the taps, so ``q``'s and ``k``'s call
+sites share one lowering each way. Only the channel-minor form takes it: on
+the sublanes a head would be 128 rows of one lane.
 
 **Two forms of one body** (:class:`Form`), by which axis of a block the
 sequence runs along. A custom call's operands have ONE layout, and the
@@ -51,8 +69,8 @@ inside, the taps added in the same order, one rounding to the output's
 dtype.
 
 The two calls sit in ``jax.jit``s of their own with every static argument
-hashable, so a step's call sites of one (shape, dtypes, taps, bias, form)
-share one lowering each way.
+hashable, so a step's call sites of one (shape, dtypes, taps, bias, form,
+unit) share one lowering each way.
 
 **On a device mesh** the pair sits in a ``shard_map`` (XLA cannot
 partition a Mosaic kernel: "Mosaic kernels cannot be automatically
@@ -126,6 +144,17 @@ class Blocks(NamedTuple):
     tokens_bwd: int
 
 
+class Unit(NamedTuple):
+    """The L2 norm the kernels take in: each run of ``width`` channels (a
+    head's) of a token leaves as ``scale · y / sqrt(sum y² + eps)``.
+    Hashable: a static argument of the jitted calls (``scale`` is not: it
+    rides beside the taps, so calls that differ in it alone share one
+    lowering)."""
+
+    width: int
+    eps: float
+
+
 def form_of(sequence_minor: bool) -> Form:
     return SEQUENCE_MINOR if sequence_minor else CHANNEL_MINOR
 
@@ -155,10 +184,17 @@ def sequence_block(sequence: int, token_bytes: int,
 
 
 def blocks_of(sequence: int, channels: int, taps: int, x_dtype, out_dtype,
-              sequence_minor: bool = False) -> Optional[Blocks]:
-    """The tiling of one call, a function of its shape, dtypes and form
-    alone; None where the kernels decline it."""
+              sequence_minor: bool = False,
+              unit: Optional[Unit] = None) -> Optional[Blocks]:
+    """The tiling of one call, a function of its shape, dtypes, form and
+    ``unit`` alone; None where the kernels decline it. With ``unit`` a
+    channel block is whole heads (the widest multiple of the head's width
+    that divides the channels): the norm never reaches past its block."""
     form = form_of(sequence_minor)
+    if unit is not None:
+        if sequence_minor or unit.width % form.channel_tile:
+            return None
+        form = form._replace(channel_tile=unit.width)
     x_dtype, out_dtype = jnp.dtype(x_dtype), jnp.dtype(out_dtype)
     floating = all(
         jnp.issubdtype(d, jnp.floating) and d.itemsize in (2, 4)
@@ -176,7 +212,8 @@ def blocks_of(sequence: int, channels: int, taps: int, x_dtype, out_dtype,
 
 
 def uses_kernel(sequence: int, channels: int, taps: int, x_dtype, out_dtype,
-                sequence_minor: bool = False) -> bool:
+                sequence_minor: bool = False,
+                unit: Optional[Unit] = None) -> bool:
     """Whether ``CausalConv1d`` takes the kernels at these shapes, read
     from the shapes alone (the caller asks the backend besides: only a TPU
     compiles them): the channels divide into blocks of whole registers
@@ -184,9 +221,14 @@ def uses_kernel(sequence: int, channels: int, taps: int, x_dtype, out_dtype,
     sequence into whole tiles (16 tokens, or 128), and a tap reaches no
     further back than one float32 tile. A single token (a decode step over
     a tail of ``taps - 1`` tokens), a sample row shorter than a tile and a
-    width no register tiles keep the ``jax.numpy`` form."""
+    width no register tiles keep the ``jax.numpy`` form. With ``unit``
+    (the L2 norm of each head inside the kernels) besides: the channels
+    are the lanes, a head is whole registers of 128 of them and a channel
+    block whole heads (heads of 96 or 192, or any head with the sequence
+    on the lanes, where it would lie down the sublanes, keep the norm
+    outside)."""
     return blocks_of(
-        sequence, channels, taps, x_dtype, out_dtype, sequence_minor
+        sequence, channels, taps, x_dtype, out_dtype, sequence_minor, unit
     ) is not None
 
 
@@ -344,10 +386,47 @@ def _chunk_tokens(form: Form, tokens: int) -> int:
     return chunk
 
 
+def _heads(a, unit: Unit):
+    """A channel-minor chunk's heads, each ``[tokens, width]``: whole
+    registers, and at most ``max_channels / 128`` of them, whatever the
+    call's shape."""
+    return [
+        a[:, at:at + unit.width] for at in range(0, a.shape[1], unit.width)
+    ]
+
+
+def _head_sums(a, unit: Unit):
+    """The array whose every lane holds the sum of ``a`` [tokens, C] over
+    its head's lanes. By the lanes' own reduction: as a product with a
+    block of ones on the otherwise idle MXU (three bfloat16 terms of each
+    float32, exact) the fused pair read 4.31 ms where 3.29 (PERF.md §6,
+    PR 66)."""
+    return jnp.concatenate([
+        jnp.broadcast_to(jnp.sum(h, axis=1, keepdims=True), h.shape)
+        for h in _heads(a, unit)
+    ], axis=1)
+
+
+def _unit(y, scale, unit: Unit):
+    """``scale · y / sqrt(sum y² + eps)`` a head: ``models/kda.QKVConv``'s
+    arithmetic, one lane reduction a head a row."""
+    return y * jax.lax.rsqrt(_head_sums(y * y, unit) + unit.eps) * scale
+
+
+def _unit_pullback(y, dn, scale, unit: Unit):
+    """``dy`` from the cotangent ``dn`` of :func:`_unit`'s result: with
+    ``r = rsqrt(sum y² + eps)`` and ``ŷ = y r``, ``dy = scale · r · (dn −
+    ŷ · sum(dn · ŷ))``; two lane reductions a head a row (``sum(dn · ŷ) =
+    r · sum(dn · y)``: both are of products known before ``r`` is)."""
+    r = jax.lax.rsqrt(_head_sums(y * y, unit) + unit.eps)
+    return scale * r * (dn - (y * r) * (r * _head_sums(dn * y, unit)))
+
+
 def _forward_kernel(x_ref, params_ref, y_ref, before_ref, *, form: Form,
-                    taps: int):
-    """x, the taps (and bias) → y; scratch: the ``edge`` tokens before the
-    block, every channel of it, as :func:`_earlier` shifts them."""
+                    taps: int, unit: Optional[Unit]):
+    """x, the taps (and bias, and with ``unit`` the scale) → y; scratch:
+    the ``edge`` tokens before the block, every channel of it, as
+    :func:`_earlier` shifts them."""
     step = _chunk_tokens(form, _extent(form, x_ref))
     groups, channels_of, chunks, tokens_of = _walk(form, x_ref, step)
     dtype = x_ref.dtype
@@ -360,6 +439,8 @@ def _forward_kernel(x_ref, params_ref, y_ref, before_ref, *, form: Form,
         channels = channels_of(i)
         edge = _at(form, slice(None), channels)
         weights = _weights(form, params_ref[edge], step)
+        if unit is not None:
+            *weights, scale = weights
 
         def chunk(c, before):
             at = _at(form, tokens_of(c), channels)
@@ -369,7 +450,10 @@ def _forward_kernel(x_ref, params_ref, y_ref, before_ref, *, form: Form,
                     form, _earlier(form, cur, before, taps - 1 - j), dtype)
                 for j in range(taps)
             ])
-            y_ref[at] = jax.nn.silu(pre).astype(y_ref.dtype)
+            y = jax.nn.silu(pre)
+            if unit is not None:
+                y = _unit(y, scale, unit)
+            y_ref[at] = y.astype(y_ref.dtype)
             return _span(form, cur, step - form.edge, step)
 
         before_ref[edge] = _stored(form, _trips(
@@ -382,17 +466,20 @@ def _forward_kernel(x_ref, params_ref, y_ref, before_ref, *, form: Form,
 
 def _backward_kernel(x_ref, halo_ref, params_ref, dy_ref, dx_ref,
                      dparams_ref, after_ref, sums_ref, *, form: Form,
-                     taps: int):
+                     taps: int, unit: Optional[Unit]):
     """x, the ``halo`` tokens of x before the block, the taps (and bias),
     dy → dx and, at a channel block's last step, the sums over every token
     of ``g · x_{t-(k-1)+j}`` (and of ``g``), one a token-axis entry of
     ``dparams``; scratch: the first ``edge`` tokens of the following
     block's g, and the partial sums, one edge each. The grid walks the
     sequence blocks, and the loop the chunks, from the last to the
-    first."""
+    first. With ``unit`` the parameters' last row is the scale and ``dy``
+    is the NORMALISED output's cotangent: ``y`` and its norm are made
+    again from ``pre`` as ``pre`` is from ``x``."""
     step = _chunk_tokens(form, _extent(form, x_ref))
     groups, channels_of, chunks, tokens_of = _walk(form, x_ref, step)
-    n_sums, dtype = _extent(form, params_ref), x_ref.dtype
+    n_sums = _extent(form, params_ref) - (unit is not None)
+    dtype = x_ref.dtype
     batch, block = pl.program_id(1), pl.program_id(2)
     last = pl.num_programs(2) - 1
 
@@ -408,6 +495,8 @@ def _backward_kernel(x_ref, halo_ref, params_ref, dy_ref, dx_ref,
         channels = channels_of(i)
         edge = _at(form, slice(None), channels)
         weights = _weights(form, params_ref[edge], step)
+        if unit is not None:
+            *weights, scale = weights
         # The tokens before the block: zeros before the sequence's first
         # (whose halo block is clamped to the block itself).
         halo = _carrier(form, halo_ref[edge])
@@ -432,7 +521,10 @@ def _backward_kernel(x_ref, halo_ref, params_ref, dy_ref, dx_ref,
             ]
             pre = _weighted(weights, shifted)
             sig = jax.nn.sigmoid(pre)
-            g = dy_ref[at].astype(_F32) * (sig * (1.0 + pre * (1.0 - sig)))
+            dy = dy_ref[at].astype(_F32)
+            if unit is not None:
+                dy = _unit_pullback(pre * sig, dy, scale, unit)
+            g = dy * (sig * (1.0 + pre * (1.0 - sig)))
             dx = _weighted(weights[:taps], [
                 _later(form, g, after, taps - 1 - j) for j in range(taps)
             ])
@@ -485,10 +577,14 @@ def _specs(form: Form, channels: int, n_params: int, block_of_step):
     return walked, params
 
 
-def _stacked(form: Form, w, b):
+def _stacked(form: Form, w, b, scale=None):
     """The taps and, after them, the bias: ``[k (+ 1), C]``, or ``[C, k
-    (+ 1)]`` with the sequence on the lanes."""
+    (+ 1)]`` with the sequence on the lanes; last, a row of the norm's
+    ``scale`` where there is one."""
     rows = w if b is None else jnp.concatenate([w, b[None]], axis=0)
+    if scale is not None:
+        rows = jnp.concatenate(
+            [rows, jnp.full_like(rows[:1], scale)], axis=0)
     return rows if form.axis == 0 else rows.T
 
 
@@ -504,16 +600,18 @@ _SEMANTICS = pltpu.CompilerParams(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("out_dtype", "form", "blocks", "interpret"),
+    jax.jit,
+    static_argnames=("out_dtype", "form", "blocks", "unit", "interpret"),
 )
-def _forward_call(x, w, b, *, out_dtype, form: Form, blocks: Blocks,
-                  interpret: bool):
-    params = _stacked(form, w, b)
+def _forward_call(x, w, b, scale, *, out_dtype, form: Form, blocks: Blocks,
+                  unit: Optional[Unit], interpret: bool):
+    params = _stacked(form, w, b, scale)
     walked, params_spec = _specs(
         form, blocks.channels, _extent(form, params), lambda t: t
     )
     return pl.pallas_call(
-        functools.partial(_forward_kernel, form=form, taps=w.shape[0]),
+        functools.partial(
+            _forward_kernel, form=form, taps=w.shape[0], unit=unit),
         out_shape=jax.ShapeDtypeStruct(x.shape, out_dtype),
         grid=_grid(form, x, blocks, blocks.tokens),
         in_specs=[walked(blocks.tokens), params_spec],
@@ -529,22 +627,23 @@ def _forward_call(x, w, b, *, out_dtype, form: Form, blocks: Blocks,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("form", "blocks", "interpret"))
-def _backward_call(x, w, b, dy, *, form: Form, blocks: Blocks,
-                   interpret: bool):
+    jax.jit, static_argnames=("form", "blocks", "unit", "interpret"))
+def _backward_call(x, w, b, scale, dy, *, form: Form, blocks: Blocks,
+                   unit: Optional[Unit], interpret: bool):
     taps, tokens = w.shape[0], blocks.tokens_bwd
-    params = _stacked(form, w, b)
-    n_sums = _extent(form, params)
+    params = _stacked(form, w, b, scale)
+    n_sums = taps + (b is not None)
     grid = _grid(form, x, blocks, tokens)
     n, halos = grid[2], tokens // form.halo
     walked, params_spec = _specs(
-        form, blocks.channels, n_sums, lambda t: n - 1 - t
+        form, blocks.channels, _extent(form, params), lambda t: n - 1 - t
     )
     # The sums leave as whole tiles: the first ``n_sums`` entries along
     # the token axis are read.
     sums_shape = _at(form, form.edge, x.shape[2 - form.axis])
     dx, dparams = pl.pallas_call(
-        functools.partial(_backward_kernel, form=form, taps=taps),
+        functools.partial(
+            _backward_kernel, form=form, taps=taps, unit=unit),
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct(sums_shape, _F32),
@@ -575,24 +674,28 @@ def _backward_call(x, w, b, dy, *, form: Form, blocks: Blocks,
         name="causal_conv_backward",
     )(x, x, params, dy)
     sums = dparams if form.axis == 0 else dparams.T
-    return dx, sums[:taps], None if b is None else sums[taps]
+    # The scale is no parameter: nothing is summed for it.
+    return (dx, sums[:taps], None if b is None else sums[taps],
+            None if scale is None else jnp.zeros_like(scale))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _conv(x, w, b, out_dtype, form, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _conv(x, w, b, scale, out_dtype, form, blocks, unit, interpret):
     return _forward_call(
-        x, w, b, out_dtype=out_dtype, form=form, blocks=blocks,
-        interpret=interpret,
+        x, w, b, scale, out_dtype=out_dtype, form=form, blocks=blocks,
+        unit=unit, interpret=interpret,
     )
 
 
-def _conv_fwd(x, w, b, out_dtype, form, blocks, interpret):
-    return _conv(x, w, b, out_dtype, form, blocks, interpret), (x, w, b)
+def _conv_fwd(x, w, b, scale, out_dtype, form, blocks, unit, interpret):
+    y = _conv(x, w, b, scale, out_dtype, form, blocks, unit, interpret)
+    return y, (x, w, b, scale)
 
 
-def _conv_bwd(out_dtype, form, blocks, interpret, residuals, dy):
+def _conv_bwd(out_dtype, form, blocks, unit, interpret, residuals, dy):
     return _backward_call(
-        *residuals, dy, form=form, blocks=blocks, interpret=interpret
+        *residuals, dy, form=form, blocks=blocks, unit=unit,
+        interpret=interpret,
     )
 
 
@@ -602,7 +705,8 @@ _conv.defvjp(_conv_fwd, _conv_bwd)
 def causal_conv_silu(x, kernel, bias=None, *, dtype,
                      sequence_minor: bool = False, mesh=None,
                      interpret: bool = False,
-                     blocks: Optional[Blocks] = None):
+                     blocks: Optional[Blocks] = None,
+                     unit: Optional[Unit] = None, scale=1.0):
     """``silu(bias + sum_j kernel[j] x_{t-(k-1)+j})`` over the sequence
     axis of ``x`` [B, S, C], zeros before the first token, in ``dtype``:
     the two kernels, tied by one ``custom_vjp`` (differentiable in ``x``,
@@ -612,33 +716,46 @@ def causal_conv_silu(x, kernel, bias=None, *, dtype,
     ``mesh`` of more than one device each device runs them on its own
     sequences (``dp``, where it divides the batch: the batch-1 sample of
     ``model.init`` stays whole), the sequence and the channels whole on
-    every device. The caller has asked :func:`uses_kernel`; ``blocks`` (a
-    test's own tiling) is :func:`blocks_of` the shapes where left out.
-    ``interpret`` runs the bodies in the Pallas interpreter (any
-    backend)."""
+    every device. With ``unit`` each head of ``unit.width`` channels
+    leaves L2-normalised and times ``scale`` (a number, or a traced
+    scalar: an operand of the kernels, not a constant of their bodies),
+    and the cotangent taken is that result's. The caller has asked
+    :func:`uses_kernel`; ``blocks`` (a test's own tiling) is
+    :func:`blocks_of` the shapes where left out. ``interpret`` runs the
+    bodies in the Pallas interpreter (any backend)."""
     dtype, form = jnp.dtype(dtype), form_of(sequence_minor)
     if blocks is None:
         blocks = blocks_of(
             x.shape[1], x.shape[2], kernel.shape[0], x.dtype, dtype,
-            sequence_minor,
+            sequence_minor, unit,
         )
+    elif unit is not None and (
+            sequence_minor or unit.width % form.channel_tile
+            or blocks.channels % unit.width):
+        blocks = None       # a test's tiling that blocks_of would not give
     if blocks is None:
         raise ValueError(
             f"no tiling for x {x.shape} {x.dtype} with {kernel.shape[0]} "
-            f"taps to {dtype}: ask uses_kernel first"
+            f"taps to {dtype} (sequence_minor {sequence_minor}, {unit}): "
+            "ask uses_kernel first"
         )
+    has_bias = bias is not None
 
-    def conv(x, kernel, *bias):
+    def conv(x, kernel, *rest):
         if sequence_minor:
             x = x.swapaxes(1, 2)
-        y = _conv(x, kernel, *(bias or (None,)), dtype, form, blocks,
-                  interpret)
+        y = _conv(
+            x, kernel, rest[0] if has_bias else None,
+            None if unit is None else rest[-1], dtype, form, blocks, unit,
+            interpret,
+        )
         return y.swapaxes(1, 2) if sequence_minor else y
 
     # The parameters enter the kernels in float32, as the ``jax.numpy``
     # form reads them (the configurations hold them so: no cast is made).
     operands = (x, kernel.astype(_F32)) + (
-        () if bias is None else (bias.astype(_F32),))
+        (bias.astype(_F32),) if has_bias else ()) + (
+        () if unit is None else (jnp.asarray(scale, _F32),))
     if mesh is None or mesh.size == 1:
         return conv(*operands)
     dp = mesh.shape.get("dp", 1)

@@ -11,11 +11,19 @@ and how a shape is tiled; the two ``conv/*_calls`` gauges against the calls
 a traced gradient holds; the call on a device mesh (in a ``shard_map``, or
 not taken at all: XLA partitions no Mosaic kernel); and what a step that
 holds the kernels costs to LOWER: one kernel body each way however many
-call sites, and a lowered text whose size does not follow the sequence."""
+call sites, and a lowered text whose size does not follow the sequence.
+The kernels with the L2 norm of each head inside (``unit``: q's and k's of
+a delta-rule layer with heads of whole registers) run through the same
+tests as cases of their own, against the ``jax.numpy`` convolution +
+``models/kda.QKVConv.unit`` + scale + cast; without ``unit`` the traced
+program is the one this file pinned before they could."""
 import contextlib
 import dataclasses
 import functools
+import hashlib
 import itertools
+import os
+import sys
 
 import flax.linen as nn
 import jax
@@ -32,7 +40,7 @@ from raydp_tpu.models import step as model_step
 from raydp_tpu.models.mamba import CausalConv1d, causal_depthwise_conv
 from raydp_tpu.models.transformer import granite_h_micro
 from raydp_tpu.ops import causal_conv
-from raydp_tpu.ops.causal_conv import Blocks, causal_conv_silu
+from raydp_tpu.ops.causal_conv import Blocks, Unit, causal_conv_silu
 from raydp_tpu.utils.profiling import metrics
 
 TAPS = 4
@@ -41,7 +49,8 @@ BF16, F32 = jnp.bfloat16, jnp.float32
 # result's 1e-5, both relative to the array's largest magnitude.
 TOLERANCE = {jnp.dtype(BF16): 2.0 ** -8, jnp.dtype(F32): 1e-5}
 
-# (bias, output dtype, channels, sequence blocks, x dtype, sequence_minor).
+# (bias, output dtype, channels, sequence blocks, x dtype, sequence_minor,
+# (a head's width, scale) of the norm inside or None).
 # The two calls the models make, each over its form's channel counts (3 and
 # 17 of the form's tile: 384 and 2,176 in 128 lanes, 96 and 544 in 32
 # sublanes) and 1, 2 and 5 sequence blocks (one and five at the narrow
@@ -49,33 +58,58 @@ TOLERANCE = {jnp.dtype(BF16): 2.0 ** -8, jnp.dtype(F32): 1e-5}
 # (a bias, bfloat16 out, the sequence on the lanes) and KDA's (no bias,
 # float32 out, the channels on them; x in float32 at one corner, where dx
 # then holds the float32 tolerance too). Then each form with the other's
-# bias and output dtype, at two blocks of seventeen channel blocks.
+# bias and output dtype, at two blocks of seventeen channel blocks. Last,
+# q's and k's of a delta-rule layer with the norm inside (no bias, out in
+# the compute dtype, the channels on the lanes): a channel block of ONE
+# head (a head of 384 in 384 channels; heads of 128 in 2,176 = 17 · 128)
+# and of FOUR (heads of 128 in 1,024 = 2 · 512), q's scale and k's; two
+# sequence blocks, so the carries cross a boundary under the norm, and with
+# them two sequences; x in bfloat16 and in float32.
 MAMBA2, KDA = (True, BF16, True), (False, F32, False)
 CHANNELS = {True: (96, 544), False: (384, 2176)}
 CASES = [
     (bias, out, channels, blocks,
-     F32 if (out, channels) == (F32, 384) else BF16, minor)
+     F32 if (out, channels) == (F32, 384) else BF16, minor, None)
     for bias, out, minor in (MAMBA2, KDA)
     for channels, blocks in itertools.product(CHANNELS[minor], (1, 2, 5))
     if channels == CHANNELS[minor][0] or blocks == 2
 ] + [
-    (bias, out, CHANNELS[minor][1], 2, BF16, minor)
+    (bias, out, CHANNELS[minor][1], 2, BF16, minor, None)
     for bias, out, minor in ((False, F32, True), (True, BF16, False))
+] + [
+    (False, BF16, 1024, 2, BF16, False, (128, 128 ** -0.5)),
+    (False, BF16, 2176, 2, BF16, False, (128, 1.0)),
+    (False, F32, 384, 1, F32, False, (384, 384 ** -0.5)),
+    (False, BF16, 512, 2, F32, False, (256, 1.0)),
 ]
 
 
 def _id(case):
-    bias, out, channels, blocks, x, sequence_minor = case
+    bias, out, channels, blocks, x, sequence_minor, unit = case
     return "-".join((
         "bias" if bias else "nobias", f"x_{jnp.dtype(x).name}",
         f"out_{jnp.dtype(out).name}", f"c{channels}", f"blocks{blocks}",
         "seq_minor" if sequence_minor else "ch_minor",
-    ))
+    ) + (() if unit is None else (f"unit{unit[0]}_scale{unit[1]:.3f}",)))
 
 
-def plain(x, kernel, bias, dtype):
-    """The form ``CausalConv1d`` runs wherever the kernels do not."""
-    return jax.nn.silu(causal_depthwise_conv(x, kernel, bias)).astype(dtype)
+def plain(x, kernel, bias, dtype, unit=None):
+    """The form ``CausalConv1d`` runs wherever the kernels do not, and
+    with ``unit`` (a head's width, scale) what ``QKVConv`` does to its
+    float32 result."""
+    y = jax.nn.silu(causal_depthwise_conv(x, kernel, bias))
+    if unit is not None:
+        width, scale = unit
+        y = kda_model.QKVConv.unit(
+            y.reshape(*y.shape[:-1], -1, width)) * scale
+    return y.reshape(x.shape).astype(dtype)
+
+
+def _norm(unit):
+    """``causal_conv_silu``'s two arguments for a case's ``unit``."""
+    if unit is None:
+        return {}
+    return dict(unit=Unit(unit[0], kda_model.L2_EPS), scale=unit[1])
 
 
 @contextlib.contextmanager
@@ -92,11 +126,14 @@ def small_chunks():
         causal_conv.SEQUENCE_MINOR = form
 
 
-def tiled(channels: int, sequence_minor: bool = False) -> Blocks:
-    """Blocks of two trips of the walk."""
+def tiled(channels: int, sequence_minor: bool = False, unit=None) -> Blocks:
+    """Blocks of two trips of the walk, of ``blocks_of``'s channels."""
     form = causal_conv.form_of(sequence_minor)
     tokens = 2 * form.halo
-    return Blocks(causal_conv.channel_block(channels, form), tokens, tokens)
+    block = causal_conv.blocks_of(
+        tokens, channels, TAPS, BF16, BF16, sequence_minor,
+        unit and Unit(unit[0], kda_model.L2_EPS)).channels
+    return Blocks(block, tokens, tokens)
 
 
 @functools.lru_cache(maxsize=None)
@@ -105,10 +142,10 @@ def both(case):
     form. The reference differentiates at ``x`` in float32 and rounds its
     ``dx`` once, as the kernel does: ``jax.grad`` at a bfloat16 ``x``
     rounds each tap's term and adds them in bfloat16."""
-    bias, out, channels, blocks, x_dtype, sequence_minor = case
+    bias, out, channels, blocks, x_dtype, sequence_minor, unit = case
     batch = 2 if blocks == 2 else 1
     keys = jax.random.split(jax.random.PRNGKey(channels + blocks), 4)
-    tiling = tiled(channels, sequence_minor)
+    tiling = tiled(channels, sequence_minor, unit)
     shape = (batch, blocks * tiling.tokens, channels)
     x = jax.random.normal(keys[0], shape, F32).astype(x_dtype)
     kernel = jax.random.uniform(keys[1], (TAPS, channels), F32, -0.5, 0.5)
@@ -121,11 +158,11 @@ def both(case):
         y, vjp = jax.vjp(
             lambda *a: causal_conv_silu(
                 *a, dtype=out, interpret=True, blocks=tiling,
-                sequence_minor=sequence_minor),
+                sequence_minor=sequence_minor, **_norm(unit)),
             x, kernel, b,
         )
         want, vjp_plain = jax.vjp(
-            lambda *a: plain(*a, out), x.astype(F32), kernel, b
+            lambda *a: plain(*a, out, unit), x.astype(F32), kernel, b
         )
         dx, dk, db = vjp_plain(dy)
         return (y, *vjp(dy)), (want, dx.astype(x.dtype), dk, db)
@@ -174,10 +211,10 @@ def test_no_bias_has_no_bias_gradient():
 
 # ------------------------------------------------ across a block's edge
 
-def _taps():
+def _taps(channels=128):
     """Tap ``j`` weighs ``j + 1`` in every channel."""
     return jnp.broadcast_to(
-        jnp.arange(1.0, TAPS + 1)[:, None], (TAPS, 128)
+        jnp.arange(1.0, TAPS + 1)[:, None], (TAPS, channels)
     ).astype(F32)
 
 
@@ -240,6 +277,26 @@ def test_the_first_tokens_see_zeros_before_the_sequence_in_every_sequence(
     np.testing.assert_allclose(y[1, 0], jax.nn.silu(float(TAPS)), rtol=1e-6)
 
 
+def test_an_all_zero_head_has_eps_alone_under_the_root():
+    """``x`` = 0: ``y`` = 0 in every head, the norm's root holds ``eps``
+    alone (``r`` = 1,000), the output is 0 and ``dy = scale · r · dn``."""
+    unit, scale = Unit(128, kda_model.L2_EPS), 0.5
+    tiling = tiled(256, unit=(128, scale))
+    x = jnp.zeros((1, 2 * tiling.tokens, 256), BF16)
+    dn = jax.random.normal(jax.random.PRNGKey(0), x.shape, F32)
+    y, vjp = jax.vjp(
+        lambda x: causal_conv_silu(
+            x, _taps(256), dtype=F32, interpret=True, blocks=tiling,
+            unit=unit, scale=scale),
+        x)
+    assert not np.asarray(y).any()
+    want = jax.vjp(
+        lambda x: plain(x, _taps(256), None, F32, (128, scale)),
+        x.astype(F32))[1](dn)[0]
+    assert float(jnp.abs(want).max()) > 1e3
+    _close(vjp(dn)[0], want.astype(BF16))
+
+
 # ------------------------------------------------------- the predicate
 
 CELLS = {
@@ -300,10 +357,58 @@ def test_the_predicate_reads_the_shape_alone(
     assert causal_conv.uses_kernel(s, channels, taps, x, out, minor) is takes
 
 
-def test_a_shape_the_kernels_decline_is_an_error_to_call_them_with():
+# A head inside the kernels: (channels, a head's width, sequence_minor).
+HEADS = [
+    (4096, 128, False, True),      # Kimi Linear's: four heads a block
+    (4096, 256, False, True),
+    (768, 256, False, True),       # blocks of 256: 384 would halve a head
+    (4096, 512, False, True),      # one head a block
+    (4096, 1024, False, False),    # wider than any block
+    (2880, 96, False, False),      # Olmo-Hybrid's q and k: no register
+    (384, 96, False, False),       # nor where the plain kernels tile it
+    (5760, 192, False, False),     # its v's width
+    (4096, 64, False, False),
+    (4096, 128, True, False),      # a head would lie down the sublanes
+]
+
+
+@pytest.mark.parametrize("channels, width, minor, takes", HEADS)
+def test_the_predicate_takes_a_norm_over_heads_of_whole_registers(
+        channels, width, minor, takes):
+    shape = (16384, channels, TAPS, BF16, BF16, minor)
+    unit = Unit(width, kda_model.L2_EPS)
+    assert causal_conv.uses_kernel(*shape, unit) is takes
+    if takes:
+        blocks = causal_conv.blocks_of(*shape, unit)
+        assert blocks.channels % width == 0 and channels % blocks.channels == 0
+        assert blocks.channels <= causal_conv.CHANNEL_MINOR.max_channels
+    if channels % 128 == 0:
+        # Without the norm the same call is the kernels' either way.
+        assert causal_conv.uses_kernel(*shape)
+
+
+def test_kimi_linears_q_and_k_are_tiled_in_blocks_of_four_heads():
+    """Half the bytes a token out: twice the tokens a forward block."""
+    assert causal_conv.blocks_of(
+        16384, 4096, TAPS, BF16, BF16, unit=Unit(128, kda_model.L2_EPS)
+    ) == Blocks(512, 2048, 1024)
+
+
+@pytest.mark.parametrize("shape, minor, blocks, width", [
+    ((1, 8, 128), False, None, None),         # shorter than a tile
+    ((1, 64, 384), False, None, 96),          # a head of no register
+    ((1, 256, 128), True, None, 128),         # the sequence on the lanes
+    ((1, 64, 384), False, Blocks(384, 32, 32), 256),  # a test's own tiling
+    ((1, 256, 128), True, Blocks(128, 256, 256), 128),
+], ids=["short", "width_96", "seq_minor", "blocks_halve_a_head",
+        "blocks_seq_minor"])
+def test_a_shape_the_kernels_decline_is_an_error_to_call_them_with(
+        shape, minor, blocks, width):
+    unit = width and Unit(width, kda_model.L2_EPS)
     with pytest.raises(ValueError, match="uses_kernel"):
         causal_conv_silu(
-            jnp.zeros((1, 8, 128), BF16), _taps(), dtype=BF16, interpret=True)
+            jnp.zeros(shape, BF16), _taps(shape[-1]), dtype=BF16,
+            interpret=True, sequence_minor=minor, blocks=blocks, unit=unit)
 
 
 def test_the_channel_block_is_the_widest_that_divides():
@@ -375,10 +480,12 @@ def test_the_module_hands_the_kernels_what_the_jnp_form_gets(
 
 # ----------------------------------------------------------- the gauges
 
-def _tiny(layer_types, **sizes):
+def _tiny(layer_types, key_dim=64, **sizes):
     """A stack of width 64 whose convolutions the kernels take at 128
     tokens: 96 channels (3 of 32 sublanes) in a Mamba-2 layer, 128 (one
-    register of lanes) in each of a delta-rule layer's three."""
+    register of lanes) in each of a delta-rule layer's three: two heads
+    of 64, which keep their norm outside the kernels (``key_dim`` 128:
+    two of whole registers, which do not)."""
     return CausalLM(dataclasses.replace(
         granite_h_micro(
             n_layers=len(layer_types), layer_types=layer_types, d_model=64,
@@ -387,12 +494,12 @@ def _tiny(layer_types, **sizes):
                       ssm_chunk=64), **sizes},
         ),
         kda=kda_model.KDAConfig(
-            heads=2, key_dim=64, value_dim=64, gate_rank=16, chunk=16),
+            heads=2, key_dim=key_dim, value_dim=64, gate_rank=16, chunk=16),
     ))
 
 
 def _surveyed_and_traced(model, batch=1):
-    """The two ``conv/*_calls`` gauges as ``models/step.report`` sets them
+    """The three ``conv/*_calls`` gauges as ``models/step.report`` sets them
     for ``model`` at [batch, 128], and what a gradient of it holds:
     ``(gauges, calls forward, calls backward, kernel bodies each way)``.
     A jaxpr's text names a ``jit`` where it is called and prints a body
@@ -415,30 +522,38 @@ def _surveyed_and_traced(model, batch=1):
 
 def _gauges():
     return (metrics.gauge_value("conv/kernel_calls"),
-            metrics.gauge_value("conv/jnp_calls"))
+            metrics.gauge_value("conv/jnp_calls"),
+            metrics.gauge_value("conv/unit_kernel_calls"))
 
 
 STACKS = {
-    # layer types -> CausalConv1d calls (one a Mamba-2 layer, three a
-    # delta-rule layer: q, k and v) and their distinct shapes.
-    "granite": (("mamba", "attention", "mamba"), 2, 1),
-    "kimi": (("kda", "attention", "kda"), 6, 1),
-    "neither": (("attention",), 0, 0),
+    # (layer types, a delta-rule head's keys) -> CausalConv1d calls (one a
+    # Mamba-2 layer, three a delta-rule layer: q, k and v), those of them
+    # with the norm inside (q's and k's at heads of whole registers) and
+    # the distinct kernel bodies each way (q and k differ in the scale
+    # alone, an operand: one body; v's is another).
+    "granite": (("mamba", "attention", "mamba"), 64, 2, 0, 1),
+    "kimi": (("kda", "attention", "kda"), 64, 6, 0, 1),
+    "kimi_heads_of_128": (("kda", "attention", "kda"), 128, 6, 4, 2),
+    "both": (("mamba", "kda"), 128, 4, 2, 3),
+    "neither": (("attention",), 64, 0, 0, 0),
 }
 
 
 @pytest.mark.parametrize("stack", list(STACKS))
 def test_the_gauges_count_the_calls_a_gradient_holds(as_on_a_tpu, stack):
-    layer_types, calls, shapes = STACKS[stack]
+    layer_types, key_dim, calls, normed, shapes = STACKS[stack]
     gauges, forward, backward, bodies = _surveyed_and_traced(
-        _tiny(layer_types))
-    assert gauges == (calls, 0)
+        _tiny(layer_types, key_dim))
+    assert gauges == (calls, 0, normed)
     assert forward == backward == calls and bodies == shapes
 
 
-def test_off_the_tpu_every_call_counts_as_the_jnp_form():
-    gauges, *kernels = _surveyed_and_traced(_tiny(("mamba", "kda")))
-    assert gauges == (0, 4) and kernels == [0, 0, 0]
+@pytest.mark.parametrize("key_dim", [64, 128])
+def test_off_the_tpu_every_call_counts_as_the_jnp_form(key_dim):
+    gauges, *kernels = _surveyed_and_traced(
+        _tiny(("mamba", "kda"), key_dim))
+    assert gauges == (0, 4, 0) and kernels == [0, 0, 0]
 
 
 def test_a_call_the_kernels_decline_counts_as_the_jnp_form(as_on_a_tpu):
@@ -446,12 +561,12 @@ def test_a_call_the_kernels_decline_counts_as_the_jnp_form(as_on_a_tpu):
     divide them); the delta-rule layer's three keep the kernels."""
     gauges, *kernels = _surveyed_and_traced(
         _tiny(("mamba", "kda"), ssm_state=18))
-    assert gauges == (3, 1) and kernels == [3, 3, 1]
+    assert gauges == (3, 1, 0) and kernels == [3, 3, 1]
 
 
 def test_a_report_without_a_survey_reads_zero_and_zero():
     mamba.report(granite_h_micro(n_layers=1), tokens_per_step=4096)
-    assert _gauges() == (0, 0)
+    assert _gauges() == (0, 0, 0)
 
 
 # ------------------------------------------------------ on a device mesh
@@ -545,11 +660,62 @@ def test_a_granite_gradient_lowers_for_two_chips(monkeypatch):
         _granite_gradient_lowered_for_a_tpu(mesh, told=False)
 
 
+def _qkv_gradient_lowered_for_a_tpu(mesh, told: bool) -> str:
+    """The gradient of a delta-rule layer's three convolutions, heads of
+    128 (q and k with the norm inside, v without), two rows over dp."""
+    module = kda_model.QKVConv(
+        kda_model.KDAConfig(heads=2, key_dim=128, value_dim=128), BF16, F32,
+        mesh=mesh if told else None)
+    x = jax.ShapeDtypeStruct(
+        (2, 128, 256), BF16, sharding=NamedSharding(mesh, P("dp")))
+    variables = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, P())),
+        jax.eval_shape(
+            lambda: module.init(jax.random.PRNGKey(0), *[
+                jnp.zeros((1, 128, 256), BF16)] * 3)),
+    )
+
+    def loss(variables, x):
+        # Squares: a sum alone would need no forward call at all.
+        return sum(
+            (a.astype(F32) ** 2).sum()
+            for a in module.apply(variables, x, x, x))
+
+    return jax.jit(jax.grad(loss)).trace(variables, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def test_the_norm_inside_lowers_for_two_chips(monkeypatch):
+    """As Granite's above: the pair with ``unit`` sits in the same
+    ``shard_map``, which keeps the channels, and so every head, whole on
+    each device."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = _mesh(dp=2)
+    text = _qkv_gradient_lowered_for_a_tpu(mesh, told=True)
+    # q's and k's one body each way, v's another.
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 4
+    assert text.count("call @_forward_call") == 3
+    text = _qkv_gradient_lowered_for_a_tpu(mesh, told=False)
+    assert "tpu_custom_call" not in text
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        _qkv_gradient_lowered_for_a_tpu(mesh, told=False)
+
+
 # ------------------------------------------- what lowering a step costs
 
-def _lowered(s, channels, bias, out, sequence_minor, sites=2):
-    """The TPU lowering (nothing is compiled) of a gradient through
-    ``sites`` convolutions of one shape, as text."""
+# Kimi Linear's q and k as ``QKVConv`` calls them where the kernels take
+# the norm: no bias, out in the compute dtype, heads of 128.
+KIMI_QK = (16384, 4096, False, BF16, False)
+# q's scale, k's, and a third: operands, not constants of the bodies.
+SCALES = (128 ** -0.5, 1.0, 0.5)
+
+
+def _gradient(s, channels, bias, out, sequence_minor, sites=2, width=None):
+    """``(function, arguments)``: a gradient through ``sites``
+    convolutions of one shape, with the norm over heads of ``width``
+    inside where that is given, each site with a scale of its own."""
     x = jax.ShapeDtypeStruct((1, s, channels), BF16)
     kernel = jax.ShapeDtypeStruct((TAPS, channels), F32)
     b = jax.ShapeDtypeStruct((channels,), F32) if bias else None
@@ -559,17 +725,28 @@ def _lowered(s, channels, bias, out, sequence_minor, sites=2):
             with jax.named_scope(f"site_{at}"):
                 x = causal_conv_silu(
                     x, kernel, b, dtype=out, sequence_minor=sequence_minor,
+                    **_norm(width and (width, SCALES[at % len(SCALES)])),
                 ).astype(BF16)
         return (x.astype(F32) ** 2).sum()
 
-    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
-        x, [kernel] * sites, [b] * sites
-    ).lower(lowering_platforms=("tpu",)).as_text()
+    return (jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+            (x, [kernel] * sites, [b] * sites))
 
 
-@pytest.mark.parametrize("cell", list(CELLS))
+def _lowered(*shape, **more):
+    """The TPU lowering (nothing is compiled) of :func:`_gradient`, as
+    text."""
+    gradient, arguments = _gradient(*shape, **more)
+    return gradient.trace(*arguments).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("cell", list(CELLS) + ["kimi_qk"])
 def test_call_sites_of_one_shape_share_one_kernel_body_each_way(cell):
-    text = _lowered(*CELLS[cell], sites=3)
+    if cell == "kimi_qk":
+        text = _lowered(*KIMI_QK, sites=3, width=128)
+    else:
+        text = _lowered(*CELLS[cell], sites=3)
     # One Mosaic body forward and one backward, called three times each.
     assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
     assert text.count("call @_forward_call") == 3
@@ -586,3 +763,63 @@ def test_the_lowered_text_does_not_grow_with_the_sequence(sequence_minor):
     wide = _lowered(4096, 6144, bias, out, sequence_minor)
     assert abs(len(wide) - len(short)) <= 0.05 * len(short)
     assert len(short) < 40_000
+
+
+def test_with_the_norm_inside_it_does_not_grow_with_the_sequence_either():
+    s, channels, bias, out, minor = KIMI_QK
+    short = _lowered(4096, channels, bias, out, minor, width=128)
+    long = _lowered(s, channels, bias, out, minor, width=128)
+    assert abs(len(long) - len(short)) <= 0.03 * len(short)
+    # Nor with the heads a block holds: four of 128, two of 256, one of
+    # 512 are a constant's worth of slices, not a shape's.
+    plain_text = _lowered(s, channels, bias, F32, minor)
+    for width in (128, 256, 512):
+        text = _lowered(s, channels, bias, out, minor, width=width)
+        assert len(text) < len(plain_text) + 6_000
+    assert len(short) < 40_000
+
+
+# sha256 of the traced gradient's text (two sites, as ``_gradient`` makes
+# it) at the parent of the PR that brought ``unit`` (PR 66): a jaxpr's text
+# holds the kernels' bodies and no source location. Without ``unit`` the
+# bodies, and so what Granite's, Nemotron's and Olmo-Hybrid's cells lower,
+# are what they were.
+PINNED = {
+    "granite": "da57301dab089f61c9da72f3722d6711a8b83d26b94f68ca11027c2e38a44ebe",
+    "kimi": "9afea3a967cc504c117c21b3cb92e812053031258e86d4fa538348ede6ec73f5",
+    "nemotron": "8bec6508d04ddca567aa10a6aeaeb4bd61dd867bcc93a1c3dc749e47cde938fa",
+}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_without_the_norm_the_traced_program_is_the_pinned_one(cell):
+    gradient, arguments = _gradient(*CELLS[cell])
+    text = str(gradient.trace(*arguments).jaxpr)
+    assert hashlib.sha256(text.encode()).hexdigest() == PINNED[cell]
+
+
+# --------------------------------------------------- the script for the chip
+
+def test_the_chips_script_measures_every_form():
+    """``scripts/causal_conv_on_chip.py`` at a tiny shape: it cannot rot
+    unseen (its times mean something on a TPU only)."""
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(__file__)), "scripts"))
+    try:
+        import causal_conv_on_chip as script
+    finally:
+        sys.path.pop(0)
+    found = script.measure(
+        (64, 256, 128, TAPS), repeats=1, dtype=F32, interpret=True,
+        blocks=Blocks(256, 32, 32))
+    assert set(found) == {*script.FORMS, "apart"}
+    for form in script.FORMS:
+        assert set(found[form]) == {
+            "forward_ms", "forward_backward_ms", "share_of_least"}
+        assert max(found["apart"][form].values()) < 1e-5
+    assert script.KIMI == (*CELLS["kimi"][:2], 128, TAPS)
+    assert script.least_bytes(script.KIMI) == (
+        2 * 16384 * 4096 * 2, 5 * 16384 * 4096 * 2)
+    if jax.default_backend() != "tpu":
+        # Off the chip it measures nothing under a chip's name.
+        assert script.main([]) == 3

@@ -61,8 +61,10 @@ ROWS = {
         [1093, 1450, 1450, 1450, 1320], [259, 324, 324, 324, 259],
         [1573, 3936, 3936, 3936, 3806], [152, 272, 272, 272, 256], 7915, 784),
     "kimi": _row(
-        [2415, 2289, 2289, 1254, 2289], [344, 344, 344, 202, 344],
-        [3335, 5520, 5520, 4485, 5520], [197, 199, 199, 179, 199],
+        # Since PR 66 (q and k leave their convolutions in bfloat16,
+        # normalised: a KDA block holds two float32 [16384, 4096] less).
+        [2155, 2029, 2029, 1254, 2029], [344, 344, 344, 202, 344],
+        [2819, 5260, 5260, 4485, 5260], [197, 199, 199, 179, 199],
         6895, 1280),
     "sdar": _row(
         [1343] * 6, [194] * 6, [3926] * 6, [181] * 6, 7389, 594),
@@ -109,9 +111,12 @@ COMPILED = [
     ("laguna", (0, 4), 13.47),
     ("laguna", _last("laguna", 3), 13.69),
     ("laguna", _last("laguna", 5), 15.03),
-    ("kimi", _last("kimi", 0), 13.07),
-    ("kimi", _last("kimi", 1), 14.41),
-    ("kimi", _last("kimi", 5), 17.92),         # refused: over the chip
+    ("kimi", _last("kimi", 0), 13.18),
+    ("kimi", _last("kimi", 1), 14.30),         # 14.292: 0.05 under the count
+    # All five released is no row: the compiler refuses it (it read 17.91
+    # GiB before PR 66 and reads 22.24 since, the count 21.65) and returns
+    # no program; the rule's next candidate is.
+    ("kimi", _last("kimi", 2), 14.43),
     ("sdar", _last("sdar", 0), 11.91),
     ("sdar", _last("sdar", 1), 12.04),
     ("sdar", _last("sdar", 2), 13.49),
@@ -153,7 +158,7 @@ COMPILED = [
     ("granite", 8 * GIB, ()),                 # the state alone is over
     ("sdar", V5E, (4, 5)),                    # 13.49 compiled
     ("keye", V5E, (2, 3, 4)),                 # 13.07
-    ("kimi", V5E, (4,)),                      # 14.41: the row that binds
+    ("kimi", V5E, (4,)),                      # 14.30: the row that binds
     ("nemotron", V5E, (5, 7, 8)),             # 13.89; block 6 does not fit
     ("mellum2", V5E, (1, 2, 3)),              # 12.43 a chip
     ("ouro", V5E, (4, 5)),                    # 14.56; 14.11 on the chip

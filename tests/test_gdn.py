@@ -4,19 +4,31 @@ reference's own scan (values and every gradient), at heads whose keys and
 values differ in width (96 and 192) and a chunk of 64, at a sequence that
 is no multiple of the chunk, with beta near 2 and a strong decay; what it
 takes from ``ops/kda.py`` by import; what its forward names for a
-checkpoint's policy."""
+checkpoint's policy; and that what feeds it (``models/kda.QKVConv``, Kimi
+Linear's too) is at heads of 96 and 192 the program it was before the
+convolution's kernels could hold the norm."""
+import dataclasses
 import importlib.util
 import os
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from raydp_tpu.models import CausalLM
+from raydp_tpu.models import step as model_step
 from raydp_tpu.models.gdn import GDNConfig
+from raydp_tpu.models.kda import L2_EPS, QKVConv
+from raydp_tpu.models.mamba import CausalConv1d, conv_takes_kernel
+from raydp_tpu.models.transformer import olmo_hybrid_7b
 from raydp_tpu.ops import gdn as gdn_ops
 from raydp_tpu.ops import kda as kda_ops
+from raydp_tpu.ops.causal_conv import Unit
 from raydp_tpu.ops.gdn import gdn_chunked, gdn_recurrent
+from raydp_tpu.utils.profiling import metrics
+from tests.test_causal_conv_kernel import as_on_a_tpu  # noqa: F401
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -278,3 +290,93 @@ def test_the_configs_chunk_and_kept_bytes_follow_the_sequence(
     assert gdn.state_bytes(3) == 3 * 30 * 96 * 192 * 4
     assert gdn.kept_bytes(3, sequence) == 3 * 30 * 192 * (
         2 * sequence + 4 * segments * 96)
+
+
+# ------------------------------------------- what feeds the rule: QKVConv
+
+class _QKVConvBeforeTheNormCouldBeInside(nn.Module):
+    """``models/kda.QKVConv`` as it stood (PR 65): float32 out of every
+    convolution, the norm and the scale as ``jax.numpy`` after it."""
+
+    gdn: GDNConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, q, k, v):
+        gdn = self.gdn
+
+        def conv(x, name, width):
+            y = CausalConv1d(
+                gdn.conv_taps, jnp.float32, jnp.float32, use_bias=False,
+                name=name,
+            )(x)
+            return y.reshape(*y.shape[:-1], gdn.heads, width)
+
+        def unit(x):
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS
+            )
+
+        q = unit(conv(q, "q", gdn.key_dim)) * gdn.key_dim ** -0.5
+        k = unit(conv(k, "k", gdn.key_dim))
+        v = conv(v, "v", gdn.value_dim)
+        return q.astype(self.dtype), k.astype(self.dtype), v.astype(self.dtype)
+
+
+@pytest.mark.parametrize("heads", [4, 5], ids=["kernels", "jnp"])
+def test_on_a_tpu_the_convolutions_at_heads_of_96_trace_to_what_they_did(
+        as_on_a_tpu, heads):
+    """Four heads: 384 and 768 channels, which the plain kernels tile (as
+    the published v's 5,760); five: 480 and 960, which they do not (as
+    the published q's and k's 2,880). Either way a head of 96 or 192 lanes
+    is no whole register, the norm stays outside, and the gradient's
+    jaxpr is the parent's to the letter."""
+    gdn = GDNConfig(heads=heads, key_dim=96, value_dim=192)
+    inputs = [
+        jax.ShapeDtypeStruct((1, 128, heads * width), jnp.bfloat16)
+        for width in (96, 96, 192)
+    ]
+
+    def gradient(module):
+        variables = jax.eval_shape(
+            lambda: module.init(jax.random.PRNGKey(0), *[
+                jnp.zeros(x.shape, x.dtype) for x in inputs]))
+
+        def loss(variables, *inputs):
+            return sum(
+                (a.astype(jnp.float32) ** 2).sum()
+                for a in module.apply(variables, *inputs))
+
+        return str(jax.make_jaxpr(jax.grad(loss))(variables, *inputs))
+
+    got = gradient(QKVConv(gdn, jnp.bfloat16, jnp.float32))
+    assert got == gradient(
+        _QKVConvBeforeTheNormCouldBeInside(gdn, jnp.bfloat16))
+    assert got.count("name=_forward_call") == (3 if heads == 4 else 0)
+
+
+@pytest.mark.parametrize("channels,width", [(2880, 96), (5760, 192)])
+def test_the_published_widths_keep_the_norm_outside(
+        as_on_a_tpu, channels, width):
+    shape = (4096, channels, 4, jnp.bfloat16, jnp.bfloat16)
+    assert not conv_takes_kernel(*shape, unit=Unit(width, L2_EPS))
+    assert conv_takes_kernel(*shape) is (channels == 5760)
+
+
+def test_on_a_tpu_a_gated_delta_stack_counts_no_norm_inside(as_on_a_tpu):
+    """Four heads of 96 and 192 at 128 tokens: all three convolutions of
+    each delta-rule layer by the plain kernels, none with the norm."""
+    model = CausalLM(dataclasses.replace(
+        olmo_hybrid_7b(
+            n_layers=2, layer_types=("gdn:swiglu", "attention:swiglu"),
+            d_model=64, n_heads=2, d_ff=128, vocab_size=128, max_len=128),
+        gdn=GDNConfig(heads=4, key_dim=96, value_dim=192, chunk=16),
+    ))
+    ids = jax.ShapeDtypeStruct((1, 128), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model_step.parameters(nn.unbox(model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))))
+    model_step.report(model, params, ids)
+    assert metrics.gauge_value("conv/kernel_calls") == 3
+    assert metrics.gauge_value("conv/jnp_calls") == 0
+    assert metrics.gauge_value("conv/unit_kernel_calls") == 0

@@ -9,14 +9,26 @@ policy keeps. The chunk-local step runs by both of its paths, the
 ``jax.numpy`` form and the Pallas kernels (in the interpreter here), and
 so does the recurrence over chunk states (``_across`` in ``jax.numpy``,
 ``across`` by the two state kernels); the shapes decide which, for both
-at once. Tiny sizes, float32, the CPU."""
+at once. Tiny sizes, float32, the CPU. Last, what feeds the rule
+(``models/kda.QKVConv``): on a host made to look like one TPU chip, at
+heads of 128, q and k leave their convolutions' kernels normalised in
+every layer and v's stays the plain one."""
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from raydp_tpu.models import kda as kda_model
+from raydp_tpu.models.mamba import CausalConv1d
 from raydp_tpu.ops import kda as kda_ops
+from raydp_tpu.ops.causal_conv import Unit
 from raydp_tpu.ops.kda import kda_chunked, kda_recurrent, unit_lower_inverse
+from tests.test_causal_conv_kernel import (  # noqa: F401  (a fixture)
+    _surveyed_and_traced,
+    _tiny,
+    as_on_a_tpu,
+)
 from tests.test_checkpoint_keeps import _eqns
 
 NAMES = ("q", "k", "v", "g", "beta")
@@ -537,3 +549,105 @@ def test_the_states_products_are_float32_at_the_highest_precision(
             assert e.params["preferred_element_type"] == jnp.float32
         kinds.append(dtypes.pop())
     assert kinds.count(jnp.float32) == float32_dots * kda_ops.state_heads(2)
+
+
+# ------------------------------------------- what feeds the rule: QKVConv
+
+def _norms_by_site(model):
+    """``{(block, convolution): (unit, scale, output dtype)}`` of every
+    ``CausalConv1d`` call an abstract apply of ``model`` makes."""
+    sites = {}
+
+    def note(next_fun, args, kwargs, context):
+        module = context.module
+        if isinstance(module, CausalConv1d) and (
+                context.method_name == "__call__"):
+            block = next(p for p in module.path if p.startswith("block_"))
+            sites[block, module.name] = (
+                module.unit, module.scale, jnp.dtype(module.dtype))
+        return next_fun(*args, **kwargs)
+
+    ids = jnp.zeros((1, 128), jnp.int32)
+    with nn.intercept_methods(note):
+        jax.eval_shape(
+            lambda: model.apply(model.init(jax.random.PRNGKey(0), ids), ids))
+    return sites
+
+
+def test_on_a_tpu_q_and_k_leave_their_kernels_normalised_in_every_layer(
+        as_on_a_tpu):
+    model = _tiny(("kda", "attention", "kda"), key_dim=128)
+    unit, compute = Unit(128, kda_model.L2_EPS), jnp.dtype(model.cfg.dtype)
+    assert compute == jnp.bfloat16
+    assert _norms_by_site(model) == {
+        (block, name): norm
+        for block in ("block_0", "block_2")
+        for name, norm in (
+            ("q", (unit, 128 ** -0.5, compute)), ("k", (unit, 1.0, compute)),
+            ("v", (None, 1.0, jnp.dtype(jnp.float32))))
+    }
+    # The census and the traced gradient say the same: six call sites each
+    # way, four of them q's and k's, two kernel bodies (the scale is an
+    # operand: q and k share theirs).
+    gauges, forward, backward, bodies = _surveyed_and_traced(model)
+    assert gauges == (6, 0, 4)
+    assert (forward, backward, bodies) == (6, 6, 2)
+
+
+@pytest.mark.parametrize("key_dim", [64, 96, 192])
+def test_a_head_of_no_whole_registers_keeps_its_norm_outside(
+        as_on_a_tpu, key_dim):
+    model = _tiny(("kda",), key_dim=key_dim)
+    assert {norm[0] for norm in _norms_by_site(model).values()} == {None}
+    assert _surveyed_and_traced(model)[0][2] == 0
+
+
+def _qkv(dtype):
+    """Heads of 128 (q, k) and 256 (v) over two sequences of 64 tokens."""
+    module = kda_model.QKVConv(
+        kda_model.KDAConfig(heads=2, key_dim=128, value_dim=256),
+        dtype, jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(3), 7)
+    inputs = [
+        jax.random.normal(key, (2, 64, 2 * width), jnp.float32).astype(dtype)
+        for key, width in zip(keys[:3], (128, 128, 256))
+    ]
+    cotangents = [
+        jax.random.normal(key, (2, 64, 2, width), jnp.float32).astype(dtype)
+        for key, width in zip(keys[3:6], (128, 128, 256))
+    ]
+    return module, module.init(keys[6], *inputs), inputs, cotangents
+
+
+# At a bfloat16 ``x`` the ``jax.numpy`` path's ``dx`` rounds each tap's
+# term and adds them in bfloat16: two roundings' worth.
+@pytest.mark.parametrize("dtype,tol", [
+    (jnp.float32, 2e-6), (jnp.bfloat16, 2.0 ** -7)], ids=["f32", "bf16"])
+def test_the_norm_inside_the_kernels_is_the_norm_after_them(
+        as_on_a_tpu, monkeypatch, dtype, tol):
+    """Values and every gradient of ``QKVConv`` by the kernels that hold
+    the norm against the ``jax.numpy`` path, which a host without a TPU
+    takes: the same float32 arithmetic, one rounding."""
+    module, variables, inputs, cotangents = _qkv(dtype)
+
+    def run():
+        def apply(variables, *inputs):
+            return module.apply(variables, *inputs)
+
+        traced = jax.jit(
+            lambda *a: jax.vjp(apply, *a)[1](tuple(cotangents))
+        ).trace(variables, *inputs)
+        out = jax.jit(apply)(variables, *inputs)
+        return str(traced.jaxpr), out, traced.lower().compile()(
+            variables, *inputs)
+
+    program, out, grads = run()
+    assert program.count("name=_backward_call") == 3
+    monkeypatch.undo()
+    program, want_out, want_grads = run()
+    assert "pallas_call" not in program
+    for got, want in zip(jax.tree_util.tree_leaves((out, grads)),
+                         jax.tree_util.tree_leaves((want_out, want_grads))):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        assert np.abs(got - want).max() <= tol * np.abs(want).max()

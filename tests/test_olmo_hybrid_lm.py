@@ -458,8 +458,8 @@ def test_nothing_is_released_where_the_state_is_most_of_the_chip():
 @pytest.mark.parametrize("name,row,want", [
     # (held + working) where the walk holds most, the gradients there in
     # the compute dtype, and the choice, of three accepted cells (MiB).
-    ("kimi", ([2415, 2289, 2289, 1254, 2289], [344, 344, 344, 202, 344],
-              [3335, 5520, 5520, 4485, 5520], [197, 199, 199, 179, 199],
+    ("kimi", ([2155, 2029, 2029, 1254, 2029], [344, 344, 344, 202, 344],
+              [2819, 5260, 5260, 4485, 5260], [197, 199, 199, 179, 199],
               6895, 1280), (4,)),
     ("laguna", ([1093, 1450, 1450, 1450, 1320], [259, 324, 324, 324, 259],
                 [1573, 3936, 3936, 3936, 3806], [152, 272, 272, 272, 256],

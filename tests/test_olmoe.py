@@ -993,10 +993,13 @@ def test_sparse_attention_kernels_compile_for_the_chip_at_keyes_shape(
 
 
 # (S, channels, bias, output dtype, sequence_minor) of the three cells'
-# causal convolutions, as their mixers call them.
+# causal convolutions, as their mixers call them: "kimi" is its v's,
+# "kimi_qk" its q's and k's, which hold the L2 norm of each head of 128
+# inside (PR 66) and write the compute dtype.
 CONV_CELLS = {
     "granite": (4096, 2 * 2048 + 2 * 128, True, jnp.bfloat16, True),
     "kimi": (16384, 4096, False, jnp.float32, False),
+    "kimi_qk": (16384, 4096, False, jnp.bfloat16, False),
     "nemotron": (16384, 4096 + 2 * 8 * 128, True, jnp.bfloat16, True),
 }
 
@@ -1008,16 +1011,18 @@ def test_causal_convolution_kernels_compile_for_the_chip_at_the_cells_shapes(
     the body as the cells tile them, and a gradient through one call is
     the two custom calls with, between them, the result and its cotangent
     at most: no padded copy, no float32 copy of the input."""
-    from raydp_tpu.ops.causal_conv import causal_conv_silu
+    from raydp_tpu.ops.causal_conv import Unit, causal_conv_silu
 
     s, channels, bias, out, sequence_minor = CONV_CELLS[cell]
+    norm = dict(unit=Unit(128, 1e-6), scale=128 ** -0.5) if (
+        cell == "kimi_qk") else {}
     like, f32 = jax.ShapeDtypeStruct, jnp.float32
     args = (like((1, s, channels), jnp.bfloat16), like((4, channels), f32),
             like((channels,), f32) if bias else None)
 
     def loss(x, kernel, b):
         y = causal_conv_silu(
-            x, kernel, b, dtype=out, sequence_minor=sequence_minor)
+            x, kernel, b, dtype=out, sequence_minor=sequence_minor, **norm)
         return jnp.sum(y.astype(f32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2) if bias else (0, 1)))
