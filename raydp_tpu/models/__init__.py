@@ -21,9 +21,11 @@ from raydp_tpu.models.transformer import (
     ouro_2_6b,
     vocab_rules,
     xing4_0,
+    glm_4_7_flash,
 )
 from raydp_tpu.models.blockdiff import BlockDiffusionConfig, BlockDiffusionLM
 from raydp_tpu.models.loop import LoopLM
+from raydp_tpu.models.mtp import MTPConfig, MTPLM
 from raydp_tpu.models.sparse_index import SparseIndexConfig
 from raydp_tpu.models.hyperconn import HyperConfig
 from raydp_tpu.models.gdn import GDNConfig
@@ -82,6 +84,9 @@ __all__ = [
     "nemotron_3_nano_30b_a3b",
     "ouro_2_6b",
     "LoopLM",
+    "MTPConfig",
+    "MTPLM",
+    "glm_4_7_flash",
     "vocab_rules",
     "SparseIndexConfig",
     "xing4_0",

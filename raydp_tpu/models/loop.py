@@ -27,7 +27,10 @@ residual IS its logits, so T exits held for the backward do not fit beside
 the state: in TRAINING the model returns :class:`LoopExits` — the T normed
 states, ``log p``, and the head's matrix — and the loss takes the head
 over ONE exit at a time from the pass's state (33.5 MB) and makes that
-exit's gradient where its logits are made (``train/losses._exits_ce``):
+exit's gradient where its logits are made (``train/losses._exits_ce``,
+which takes an exit's own targets, count and scope: here the one shifted
+target array T times, ``B(S-1)`` and ``exit_<t>``; a multi-token-prediction
+module's two heads, ``models/mtp.py``, bring two of each):
 an exit's logits and their gradient are made, used and freed before the
 next exit's, and no head runs twice. DETERMINISTIC calls
 (``predict``, ``evaluate``) run all T passes and return the LAST exit's
@@ -163,8 +166,13 @@ def exit_bytes(model, out):
     the block checkpoint's walk (``models/step.estimated_bytes``), from a
     training apply's abstract output; None for a model that is no
     :class:`LoopLM` (its output IS its one head's logits)."""
-    if not isinstance(model, LoopLM):
-        return None
+    return heads_bytes(out) if isinstance(model, LoopLM) else None
+
+
+def heads_bytes(out):
+    """``(states, one state's float32 logits, the head's gradient)`` in
+    bytes of a training output that carries ``states`` and the ``head``
+    they share (:class:`LoopExits`; ``models/mtp.MTPHeads``)."""
     tokens, (_, vocab) = math.prod(out.states[0].shape[:-1]), out.head.shape
     return len(out.states), 4 * tokens * vocab, 4 * math.prod(out.head.shape)
 

@@ -7,12 +7,19 @@ residual path how far its mixing matrix is from doubly stochastic
 (``models/hyperconn.py``), and each reports its own names at the epoch's
 end.
 
+What a LOSS knows about a step (a head that runs in the loss, on what the
+apply returned, has its parts there and no module to sow them from) it
+notes with :func:`note`; :func:`step_stats` takes the notes of its step
+with what the layers sowed.
+
 A sower declares, once and where it is imported, how two values of its
 statistic become one (two layers' within a step, two steps' within an
 epoch); every statistic is a count or a size, so zero starts either
 reduction in use.
 """
 from __future__ import annotations
+
+import threading
 
 import jax.numpy as jnp
 
@@ -22,6 +29,9 @@ import jax.numpy as jnp
 STATS = "moe_stats"
 
 _REDUCE: dict = {}
+# ``{name: value}`` noted since the trace of a step began (a thread's own:
+# a step is traced on the thread that builds it).
+_NOTED = threading.local()
 
 
 def declare(name: str, reduce=jnp.add) -> str:
@@ -40,13 +50,29 @@ def sow(module, name: str, value) -> None:
     )
 
 
+def note(name: str, value) -> None:
+    """``value`` of a declared statistic, from OUTSIDE the model's apply
+    and inside the same trace (a loss's parts). :func:`step_stats` of that
+    trace returns it; :func:`begin_step` forgets what an earlier trace
+    left (a step that asks for no statistics takes none)."""
+    if name not in _REDUCE:
+        raise ValueError(f"statistic {name!r} is not declared")
+    _NOTED.__dict__.setdefault("values", {})[name] = value
+
+
+def begin_step() -> None:
+    """A step's trace begins (``models/step.apply_kwargs``): nothing is
+    noted yet."""
+    _NOTED.__dict__.pop("values", None)
+
+
 def step_stats(variables) -> dict:
     """What one step's ``mutable=[STATS]`` state holds, as device values:
-    each statistic merged over the layers that sowed it; ``{}`` for a
-    model that sows none."""
+    each statistic merged over the layers that sowed it, and what the
+    step's loss noted; ``{}`` for a model that sows none."""
     from flax.traverse_util import flatten_dict
 
-    merged: dict = {}
+    merged: dict = dict(_NOTED.__dict__.pop("values", {}))
     for path, value in flatten_dict(dict(variables.get(STATS, {}))).items():
         name = path[-1]
         merged[name] = _REDUCE[name](merged[name], value) if (

@@ -21,7 +21,7 @@ import numpy as np
 from jax.extend.core import Jaxpr, Literal
 
 from raydp_tpu.models import (
-    blockdiff, dropout, gdn, hyperconn, kda, latent, loop, mamba, moe,
+    blockdiff, dropout, gdn, hyperconn, kda, latent, loop, mamba, moe, mtp,
     shortconv, sparse_index, stats, window,
 )
 from raydp_tpu.models.stats import merge  # noqa: F401  (two steps' statistics as one)
@@ -81,7 +81,9 @@ def apply_kwargs(model, rng) -> dict:
     generator (``models/dropout.py``). A model that draws more than
     dropout's masks in its step names the collections (``step_rngs``:
     block diffusion's ``noise``) and gets a key each, a function of
-    ``rng`` too."""
+    ``rng`` too. A step's trace begins here: what a loss notes about it
+    (``stats.note``) is this step's from now on."""
+    stats.begin_step()
     if not _takes_deterministic(model):
         return {}
     rngs = {"dropout": dropout.key_for(rng)}
@@ -190,6 +192,7 @@ def report(model, params, sample_batch, surveyed=None) -> None:
     blockdiff.report(model, batch=batch, seq_len=seq_len)
     hyperconn.report(cfg)
     loop.report(model)
+    mtp.report(model, params)
     report_flash_tiles(cfg, seq_len=seq_len, batch=batch)
     moe.report(model, tokens_per_step=tokens_per_step)
 
@@ -202,6 +205,7 @@ def report_epoch(stats_sum: dict, n_batches: int) -> None:
     blockdiff.report_epoch(stats_sum)
     sparse_index.report_epoch(stats_sum)
     loop.report_epoch(stats_sum)
+    mtp.report_epoch(stats_sum, n_batches)
 
 
 # ------------------------------------------------- the block checkpoint
@@ -657,6 +661,13 @@ def _under(tree, name: str):
     return None
 
 
+def _under_path(tree, scope):
+    """:func:`_under` one name of ``scope`` after the other."""
+    for name in scope:
+        tree = _under(tree, name)
+    return tree
+
+
 def block_bytes(cfg, mixer: str, ffn: str, variables, x) -> Counted:
     """:class:`Counted` of one block of this kind, with these variables,
     at the input ``x``: what it holds for its backward as the plain block
@@ -699,31 +710,44 @@ def fit_checkpoint(model, state, sample_batch, mesh, surveyed=None):
     if not getattr(cfg, "remat", False):
         _report_checkpoint(0, 0, nothing, None, 0)
         return model
-    n = cfg.n_layers
+    # The stack's blocks by their scopes and, behind them, the block of a
+    # multi-token-prediction module (``models/mtp.py``: ``mtp/block``, a
+    # layer of the stack's last kind that runs after ``ln_final`` and
+    # before the heads; in ``cfg.released`` it is ``cfg.n_layers``).
+    scopes = [(f"block_{i}",) for i in range(cfg.n_layers)]
+    layers = list(cfg.layers)
+    if isinstance(model, mtp.MTPLM):
+        scopes.append(("mtp", "block"))
+        layers.append(cfg.layers[-1])
+    n = len(scopes)
     limit = device_limit(mesh)
     if limit is None or cfg.released:
-        _report_checkpoint(n, sum(cfg.checkpointed), nothing, limit, 0)
+        _report_checkpoint(
+            n, sum(cfg.remat and i not in cfg.released for i in range(n)),
+            nothing, limit, 0,
+        )
         return model
     batch_chips = mesh.shape.get("dp", 1)
     surveyed = surveyed or survey(model, state.params, sample_batch)
-    inputs = [surveyed.blocks[f"block_{i}"] for i in range(n)]
+    inputs = [surveyed.blocks[scope[-1]] for scope in scopes]
     # One trace a KIND of block: mixer, FFN and the input's shape.
-    kinds = [(*layer, x.shape) for layer, x in zip(cfg.layers, inputs)]
+    kinds = [(*layer, x.shape) for layer, x in zip(layers, inputs)]
     counted, whole = {}, {}
-    for i, (kind, x) in enumerate(zip(kinds, inputs)):
+    for scope, kind, x in zip(scopes, kinds, inputs):
         if kind not in counted:
-            # Layer i's own variables, a collection each, out of the
+            # The layer's own variables, a collection each, out of the
             # model's: the block's scope is its name.
             variables = {
                 name: found for name, tree in state.params.items()
-                if (found := _under(tree, f"block_{i}")) is not None
+                if (found := _under_path(tree, scope)) is not None
             }
             counted[kind] = block_bytes(cfg, *kind[:2], variables, x)
             whole[kind] = sum(
                 _nbytes(leaf) for leaf in jax.tree_util.tree_leaves(variables)
                 if jnp.issubdtype(leaf.dtype, jnp.inexact)
             )
-    exits = loop.exit_bytes(model, surveyed.out)
+    exits = loop.exit_bytes(model, surveyed.out) or mtp.exit_bytes(
+        model, surveyed.out)
     if exits is None:       # one head: the model's output is its logits
         head = sum(map(_nbytes, jax.tree_util.tree_leaves(surveyed.out)))
         exits = (1, head, head if cfg.tie_head else 0)
@@ -770,6 +794,9 @@ def checkpoint_all(model):
     from raydp_tpu.utils.profiling import metrics
 
     cfg = dataclasses.replace(model.cfg, released=())
-    metrics.gauge_set("checkpoint/blocks_checkpointed", sum(cfg.checkpointed))
+    metrics.gauge_set(
+        "checkpoint/blocks_checkpointed",
+        sum(cfg.checkpointed) + isinstance(model, mtp.MTPLM),
+    )
     metrics.gauge_set("checkpoint/fell_back", 1)
     return model.clone(cfg=cfg)

@@ -966,7 +966,9 @@ class TransformerEncoder(nn.Module):
     applications), ``ln_final`` closes every pass and its output enters
     the next; pass t's ops lie under the scope ``pass_<t>``. The output is
     the last pass's normed state, or with ``every_pass`` the tuple of all
-    T. Every application of a block has the same shapes.
+    T. Every application of a block has the same shapes. With
+    ``with_prenorm`` (one pass) the output is the pair ``(the last block's
+    output before ln_final, the normed state)``.
     """
 
     cfg: TransformerConfig
@@ -983,8 +985,13 @@ class TransformerEncoder(nn.Module):
         kv_len: Optional[int] = None,
         positions=None,
         every_pass: bool = False,
+        with_prenorm: bool = False,
     ):
         cfg = self.cfg
+        if with_prenorm and (cfg.passes > 1 or every_pass):
+            raise NotImplementedError(
+                "the state before the final norm is the ONE pass's"
+            )
         if cfg.passes > 1 and (
                 cache_mode is not None or cfg.hyper is not None):
             raise NotImplementedError(
@@ -1079,12 +1086,17 @@ class TransformerEncoder(nn.Module):
                 if cfg.hyper is not None:
                     with jax.named_scope("hc_reduce"):
                         x = hyperconn.reduce(x)
+                prenorm = x
                 x = ln_final(x)
             if t + 1 < cfg.passes:
                 # Written once: the next pass and an exit both read it
                 # (as ``CausalLM`` writes the state its head reads).
                 x = jax.lax.optimization_barrier(x)
             states.append(x)
+        if with_prenorm:
+            # What a module BEHIND the stack reads (``models/mtp.py``
+            # norms it itself); a call without it is the call it was.
+            return prenorm, x
         return tuple(states) if every_pass else x
 
 
@@ -1383,7 +1395,8 @@ def xing4_0(**overrides) -> TransformerConfig:
     others, 4 a token by sigmoid score + ``e_score_correction_bias``,
     their scores divided by their sum and times 2; RMSNorm, no biases, no
     auxiliary loss; vocabulary 131072, untied head. The multi-token
-    prediction module is not built (ROADMAP R10). ``experts_held`` /
+    prediction module is ``models/mtp.MTPLM``'s (this preset's benchmark
+    cut leaves it out for memory). ``experts_held`` /
     ``first_expert`` give a layer the share of an expert-parallel
     deployment; ``n_layers`` and ``dense_layers`` keep the model's own
     first layers."""
@@ -1414,6 +1427,46 @@ def xing4_0(**overrides) -> TransformerConfig:
         ),
         hyper=HyperConfig(streams=4, sinkhorn_iters=20, eps=1e-6,
                           clamp=(-30.0, 30.0)),
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def glm_4_7_flash(**overrides) -> TransformerConfig:
+    """GLM-4.7-Flash (30B parameters, about 3.6B active; ``config.json``
+    of zai-org/GLM-4.7-Flash, ``model_type`` glm4_moe_lite): 47 pre-norm
+    layers of width 2048 on ONE residual stream; latent attention in every
+    layer (20 heads of 192 + 64 for q and k and 256 for v from latents of
+    768 and 512, one shared rotary key, theta 1e6, no scaling); a dense
+    SwiGLU FFN of width 10240 in the first layer and 64 SwiGLU experts of
+    width 1536 beside one shared expert in the others, 4 a token by
+    sigmoid score + ``e_score_correction_bias``, their scores divided by
+    their sum and times 1.8; RMSNorm at 1e-5, no biases, no auxiliary
+    loss; vocabulary 154880, untied head. Its multi-token prediction
+    module (``num_nextn_predict_layers`` 1) is ``models/mtp.MTPLM`` over
+    this configuration. ``experts_held`` / ``first_expert`` give a layer
+    the share of an expert-parallel deployment; ``n_layers`` and
+    ``dense_layers`` keep the model's own first layers."""
+    from raydp_tpu.models.latent import LatentConfig
+
+    overrides = dict(overrides)
+    n_layers = overrides.get("n_layers", 47)
+    dense = overrides.pop("dense_layers", 1)
+    defaults = dict(
+        vocab_size=154880, d_model=2048, n_heads=20, n_layers=n_layers,
+        d_ff=10240, max_len=202752, dropout_rate=0.0, causal=True,
+        norm="rmsnorm", norm_eps=1e-5, positions="rotary",
+        rope_theta=1e6, use_bias=False, ffn="moe", n_experts=64,
+        top_k=4, d_expert=1536, shared_experts=1, router_scoring="sigmoid",
+        router_bias=True, norm_top_k=True, routed_scaling=1.8,
+        moe_loss_weights=(0.0, 0.0), tie_head=False,
+        layer_types=tuple(
+            "latent" + (":swiglu" if i < dense else ":moe")
+            for i in range(n_layers)
+        ),
+        latent=LatentConfig(
+            q_rank=768, kv_rank=512, nope_dim=192, rope_dim=64, v_dim=256,
+        ),
     )
     defaults.update(overrides)
     return TransformerConfig(**defaults)

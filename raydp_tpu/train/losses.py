@@ -119,9 +119,54 @@ def loop_exit_crossentropy(preds, tokens):
     count = b * (s - 1)
     valid = (jnp.arange(s) < s - 1).astype(jnp.float32)[None, :]
     probs = jnp.exp(log_probs)
-    expected = _exits_ce(count, tuple(states), head, targets, probs * valid)
+    exits = tuple((count, f"exit_{t}") for t in range(len(states)))
+    expected, _ = _exits_ce(
+        exits, tuple(states), head, (targets,) * len(states), probs * valid
+    )
     entropy = -jnp.sum(probs * log_probs, axis=0)
     return expected - beta * (jnp.sum(entropy * valid) / count)
+
+
+def mtp_crossentropy(preds, tokens):
+    """The loss of a model with a multi-token-prediction module
+    (``models/mtp.py``): ``preds`` is what an ``MTPLM`` returns in training
+    (``MTPHeads``: the main model's normed state and the module's, the
+    head's [D, V] matrix they share, lambda), ``tokens`` the ids; head k
+    at position s predicts token s + 1 + k:
+
+        L_main + lambda * L_mtp,
+        L_main = 1/(B(S-1)) Σ_{s<S-1} CE(head(h_s),  token s+1)
+        L_mtp  = 1/(B(S-2)) Σ_{s<S-2} CE(head(h1_s), token s+2)
+
+    float32 throughout. The head runs HERE, over one state at a time
+    (:func:`_exits_ce`, each head with its own targets, count and weight):
+    the main logits [B, S, V] and their gradient are made, used and freed
+    before the module's, which run under the scope ``mtp_head``. The two
+    losses are noted for the epoch's gauges (``models/mtp.note_losses``).
+    Given an array (what the model returns in ``evaluate``: the main
+    logits) this is :func:`lm_crossentropy` of it."""
+    if not isinstance(preds, tuple):
+        return lm_crossentropy(preds, tokens)
+    from raydp_tpu.models import mtp
+
+    states, head, weight = preds
+    tokens = tokens.astype(jnp.int32)
+    b, s = tokens.shape
+    ahead = range(1, len(states) + 1)
+    exits = tuple(
+        (b * (s - k), "mtp_head" if k > 1 else "main_head") for k in ahead
+    )
+    weights = jnp.stack([
+        (jnp.arange(s) < s - k).astype(jnp.float32)[None, :]
+        * (weight if k > 1 else 1.0) for k in ahead
+    ])
+    total, parts = _exits_ce(
+        exits, tuple(states), head,
+        tuple(jnp.roll(tokens, -k, axis=1) for k in ahead),
+        jax.lax.stop_gradient(weights),
+    )
+    mtp.note_losses(parts, weight)
+    return total
 
 
 def _head_product(h, head):
@@ -133,7 +178,7 @@ def _head_product(h, head):
         )
 
 
-def _exits_ce_fwd(count, states, head, targets, weights):
+def _exits_ce_fwd(exits, states, head, targets, weights):
     """The forward pass makes every gradient too. An exit's loss is linear
     in what comes back to it, so its logits' gradient is made WHERE THE
     LOGITS ARE MADE, by :func:`_weighted_ce`'s own backward expression at
@@ -145,38 +190,50 @@ def _exits_ce_fwd(count, states, head, targets, weights):
     until everything exit t's logits' gradient feeds is written: one
     exit's logits and their gradient live at a time, by the program's
     order and not by the scheduler's choice."""
-    how, one = (count, True), jnp.ones((), jnp.float32)
+    one = jnp.ones((), jnp.float32)
     total = d_head = d_state = None
-    d_states, d_weights = [], []
-    for t, h in enumerate(states):
-        with jax.named_scope(f"exit_{t}"):
+    d_states, d_weights, parts = [], [], []
+    for t, (h, (count, scope)) in enumerate(zip(states, exits)):
+        how = (count, True)
+        with jax.named_scope(scope):
             if t:
                 h, d_head, d_state = jax.lax.optimization_barrier(
                     (h, d_head, d_state)
                 )
                 d_states[-1] = d_state
             logits, back = jax.vjp(_head_product, h, head)
-            loss, kept = _weighted_ce_fwd(how, logits, targets, weights[t])
+            loss, kept = _weighted_ce_fwd(
+                how, logits, targets[t], weights[t]
+            )
             d_logits, _, d_weight = _weighted_ce_bwd(how, kept, one)
             d_state, d_matrix = back(d_logits)
         total = loss if total is None else total + loss
         d_head = d_matrix if d_head is None else d_head + d_matrix
         d_states.append(d_state)
         d_weights.append(d_weight)
-    return total, (tuple(d_states), d_head, jnp.stack(d_weights))
+        parts.append(loss)
+    return (total, tuple(parts)), (
+        tuple(d_states), d_head, jnp.stack(d_weights)
+    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _exits_ce(count, states, head, targets, weights):
-    """``Σ_t Σ w_t · CE(h_t @ head, targets) / count`` over the exits of a
-    looped LM: ``states`` a tuple of T arrays [B, S, D], ``head`` [D, V],
-    ``weights`` [T, B, S] (they learn: their cotangent is a token's
-    cross-entropy at that exit)."""
-    return _exits_ce_fwd(count, states, head, targets, weights)[0]
+def _exits_ce(exits, states, head, targets, weights):
+    """``Σ_t Σ w_t · CE(h_t @ head, targets_t) / count_t`` over the T
+    exits of one head (a looped LM's passes; the main model and its
+    multi-token-prediction module), and the T terms of that sum (a
+    statistic: no gradient goes back through them). ``exits`` is a
+    ``(count_t, scope_t)`` an exit, ``states`` a tuple of T arrays
+    [B, S, D], ``head`` [D, V], ``targets`` a tuple of T arrays [B, S] (an
+    exit's own: a looped LM's are one array T times), ``weights``
+    [T, B or 1, S] (they learn: their cotangent is a token's cross-entropy
+    at that exit)."""
+    return _exits_ce_fwd(exits, states, head, targets, weights)[0]
 
 
-def _exits_ce_bwd(count, gradients, g):
+def _exits_ce_bwd(exits, gradients, g):
     d_states, d_head, d_weights = gradients
+    g, _ = g
     return (
         tuple(d * g.astype(d.dtype) for d in d_states), d_head * g, None,
         d_weights * g,
@@ -254,6 +311,7 @@ LOSSES: Dict[str, Callable] = {
     "lm_ce": lm_crossentropy,
     "blockdiff_ce": blockdiff_crossentropy,
     "loop_exit_ce": loop_exit_crossentropy,
+    "mtp_ce": mtp_crossentropy,
 }
 
 

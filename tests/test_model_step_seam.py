@@ -16,7 +16,7 @@ import pytest
 
 from raydp_tpu.models import (
     CausalLM, blockdiff, dropout, gdn, hyperconn, kda, latent, loop, mamba,
-    moe, olmoe,
+    moe, mtp, olmoe,
     shortconv, sparse_index, stats, window,
 )
 from raydp_tpu.models import step as model_step
@@ -81,6 +81,7 @@ def _eleven_reports_by_hand(model, params, sample):
     blockdiff.report(model, batch=batch, seq_len=seq_len)
     hyperconn.report(cfg)
     loop.report(model)      # PR 61: a stack run several times, its exits
+    mtp.report(model, params)   # PR 67: a module behind the stack
     report_flash_tiles(cfg, seq_len=seq_len, batch=batch)
     moe.report(model, tokens_per_step=tokens)
 

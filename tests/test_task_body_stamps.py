@@ -275,13 +275,26 @@ def _handoff_spans():
 
 
 def test_materialize_runs_under_spans_of_its_own():
-    """A dataset over a block that lands after 50 ms: the wait is
-    ``handoff/await_blocks``, the rest ``handoff/fetch`` and
-    ``handoff/convert``, all inside ``handoff/materialize``; a second
-    ``_materialize`` (the columns are cached) opens none."""
-    futs = [Future(), Future()]
+    """A dataset over a block that lands 50 ms after the consumer began
+    to wait for it: the wait is ``handoff/await_blocks``, the rest
+    ``handoff/fetch`` and ``handoff/convert``, all inside
+    ``handoff/materialize``; a second ``_materialize`` (the columns are
+    cached) opens none."""
+    waiting = threading.Event()
+
+    class Awaited(Future):
+        """Says when a consumer asks for its result: the wait has begun
+        (under the span, which is open by then), whatever the machine's
+        load did to the thread's start."""
+
+        def result(self, timeout=None):
+            waiting.set()
+            return super().result(timeout)
+
+    futs = [Awaited(), Awaited()]
 
     def land():
+        assert waiting.wait(30)
         time.sleep(0.05)
         futs[0].set_result(_block(0, 60))
         futs[1].set_result(_block(60, 100))
