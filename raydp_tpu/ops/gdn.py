@@ -24,7 +24,11 @@ levels and no clamp:
 Those are ``ops/kda.py``'s six chunk-local results with a scalar where it
 has a vector, and the walk over segments whose backward holds one
 segment's intermediates is that module's (``segment_walk``;
-``SEGMENT_CHUNKS`` is read there).
+``SEGMENT_CHUNKS`` is read there). A rule there owns its layout; both of
+this module's are ``chunk_major`` ones, which get each segment copied to
+[b, n, h, c, ...]: a head of 96 or 192 lanes is no whole register, so a
+head's slice of a [c, h · d] block of the model's own array (what
+``ops/kda.KERNELS`` reads in place at 128) would be a lane shift here.
 
 **Two rules, chosen by the shapes** (:func:`uses_kernels`; a test may ask
 for either by argument). :data:`RULE` is plain ``jax.numpy`` batched
@@ -81,7 +85,6 @@ from jax.sharding import PartitionSpec
 
 from raydp_tpu.ops.kda import (
     CHUNKS_A_STEP,
-    Rule,
     _across,
     _dot,
     _interpret,
@@ -90,6 +93,7 @@ from raydp_tpu.ops.kda import (
     _packed,
     _pairs,
     _unpacked,
+    chunk_major,
     inverses_shape,
     kda_recurrent,
     segment_walk,
@@ -158,7 +162,7 @@ def _chunk_local(q, k, v, g, beta):
             (qf * decay).astype(dtype), to_end, decay[..., -1, :])
 
 
-RULE = Rule(
+RULE = chunk_major(
     lambda *xs, keep: _chunk_local(*xs), _chunk_local, _across, KEPT
 )
 
@@ -571,7 +575,7 @@ def across(local, state):
     return jnp.moveaxis(out, 3, 2).reshape(b, n * c, h, d_v), left
 
 
-KERNELS = Rule(
+KERNELS = chunk_major(
     lambda *xs, keep: chunk_local(*xs, keep=keep),
     lambda *xs: chunk_local(*xs),
     lambda six, state, dtype: across(six, state),

@@ -49,49 +49,57 @@ copies than the zero rows do: PERF.md §6, PR 44). The triangular inverse
 is block forward substitution over the same levels, ``T ← T − T a_h T``
 with ``a_h`` the part of ``A`` a level holds: exact, no Neumann series.
 
-**Two implementations of the chunk-local step, chosen by the shapes.**
-``U = T(βv)``, ``W = T(β e^G k)``, ``P``, ``q e^G``, ``k e^{G_C − G}`` and
-``e^{G_C}`` read one chunk's ``q``, ``k``, ``v``, ``g``, ``β`` and nothing
-else. :func:`_chunk_local_jnp` is the form above over all chunks at once:
-its level operands and ``[chunk, chunk]`` intermediates are arrays in HBM,
-about 40 times the bytes the rule needs (PERF.md §6, PR 44). Where
-:func:`uses_kernels` holds (``d_k`` and ``d_v`` multiples of 128, the
-chunk a multiple of 64: the published layer's [64, 128] tiles),
-:func:`chunk_local` runs the same mathematics (:func:`_chunk_math`) a
-chunk and head at a time on tiles in VMEM: ``kda_chunk_forward`` reads the
-five inputs and writes the six results and, differentiated, the chunk's
-``T``; ``kda_chunk_rebuild`` is the same body given ``T``, with no inverse
-and no ``A``; ``kda_chunk_backward`` reads the inputs, ``T`` and the
-results' cotangents and writes the five gradients, evaluating ``jax.vjp``
-of :func:`_chunk_math` in the kernel body. Nothing with a level axis and
-nothing ``[chunk, chunk]`` but ``P`` and the kept ``T`` is written to HBM.
-In a tile a level's factor is ONE ``[chunk, d_k]`` array, ``e^{G_i −
-G_r}`` on late rows and ``e^{G_r − G_j}`` on early ones, made from
-sublane rotations of the cumulative sums; the level's pairs select their
+**Two implementations, chosen by the shapes.** ``U = T(βv)``, ``W = T(β
+e^G k)``, ``P``, ``q e^G``, ``k e^{G_C − G}`` and ``e^{G_C}`` read one
+chunk's ``q``, ``k``, ``v``, ``g``, ``β`` and nothing else.
+:func:`_chunk_local_jnp` is the form above over all chunks at once (its
+level operands and ``[chunk, chunk]`` intermediates are arrays in HBM,
+about 40 times the bytes the rule needs: PERF.md §6, PR 44), and
+:func:`_across` carries the state by a ``lax.scan`` over a segment's
+chunks. Where :func:`uses_kernels` holds (``d_k`` and ``d_v`` multiples
+of 128, the chunk a multiple of 64: the published layer's [64, 128]
+tiles), five Pallas kernels run the same mathematics on tiles in VMEM.
+``kda_chunk_forward`` evaluates :func:`_chunk_math` a chunk and head at a
+time and writes the six results and, differentiated, the chunk's ``T``;
+``kda_chunk_rebuild`` is the same body given ``T``, with no inverse and
+no ``A``; ``kda_chunk_backward`` evaluates ``jax.vjp`` of
+:func:`_chunk_math` in its body. Nothing with a level axis and nothing
+``[chunk, chunk]`` but ``P`` and the kept ``T`` is written to HBM. In a
+tile a level's factor is ONE ``[chunk, d_k]`` array made from sublane
+rotations of the cumulative sums, and the level's pairs select their
 entries from one ``[2·chunk, d_k] × [d_k, chunk]`` product of ``q`` over
-``k`` stacked.
+``k`` stacked. ``kda_state_forward`` runs the recurrence over the grid
+(sequences, groups of :data:`HEADS_A_STEP` heads, chunks), the chunks one
+after the other and a group's states in a VMEM scratch: ``w = U − W S``,
+``o = (q e^G) S + P w``, ``S ← e^{G_C} ⊙ S + (k e^{G_C − G})ᵀ w`` a
+chunk; in the backward's rebuild of ONE segment it also writes that
+segment's chunk states and ``w``, float32, which ``kda_state_backward``
+reads as it walks the chunks from the last to the first. Nothing of the
+recurrence is differentiated by tracing and no loop over chunks is left
+to XLA. No option, field or name chooses: ``kda_chunked`` reads the
+shapes once for both halves (a test may ask for either path by
+argument), and on a backend that is no TPU the kernel bodies run in the
+Pallas interpreter.
 
-**Two implementations of the recurrence over chunk states, chosen with
-it.** :func:`_across` is a ``lax.scan`` over a segment's chunks: two
-float32 products a step, every chunk's entering state stacked in HBM for
-one batched product to read back. Where :func:`uses_kernels` holds,
-:func:`across` runs it in two more kernels over the grid (sequences,
-groups of :data:`HEADS_A_STEP` heads, chunks), the chunks one after the
-other: ``kda_state_forward`` loads a group's states into a VMEM scratch at
-a segment's first chunk, reads the six chunk-local results as the chunk
-kernels wrote them ([b, n, h, c, d]: no transposed copy), computes ``w =
-U − W S``, ``o = (q e^G) S + P w`` and ``S ← e^{G_C} ⊙ S + (k e^{G_C −
-G})ᵀ w`` a chunk, and writes ``o`` and, at the last chunk, the state left;
-under differentiation (the backward's rebuild of ONE segment) it also
-writes that segment's chunk states and ``w``, float32, which
-``kda_state_backward`` reads as it walks the chunks from the last to the
-first with the state's cotangent in the scratch. One ``custom_vjp`` holds
-the pair, so nothing of the recurrence is differentiated by tracing and no
-loop over chunks is left to XLA.
-
-No option, field or name chooses: ``kda_chunked`` reads the shapes once
-for both halves (a test may ask for either path by argument), and on a
-backend that is no TPU the kernel bodies run in the Pallas interpreter.
+**Who owns the layout.** A :class:`Rule` does, and with it the loop over
+the segments. The kernels' rule (:data:`KERNELS`) reads and writes the
+MODEL's arrays: ``q``, ``k``, ``v``, ``g``, ``o`` and their cotangents
+are [b, s, h · d] (what ``QKVConv`` and the dense layers write: a reshape
+of [b, s, h, d], no copy), a grid step's block a chunk's rows × the lanes
+of a group of heads, a head inside it a slice of whole 128-lane registers
+(wherever :func:`uses_kernels` holds there is no other kind). The
+segment's index is a scalar-prefetch argument of every call's index
+maps, so the loops scan the segments' INDICES and carry the state and
+the arrays being written — ``o``, every chunk's ``T``, the five
+gradients — which each call writes into in place; nothing of the
+sequence's size is copied, sliced, stacked or transposed by XLA, forward
+or backward. Only ``beta`` (1/128 of ``k``'s bytes) is read from a
+chunk-major copy. What the kernels leave to one another — the six
+results, the chunk states, ``w``, ``T`` — stays chunk-major, [b, n, h,
+...]. A rule whose chunk step wants chunk-major INPUTS
+(:func:`chunk_major`: :data:`PLAIN` here, both of ``ops/gdn.py``'s, whose
+heads of 96 and 192 lanes are no whole registers) scans the segments'
+slices and copies each to [b, n, h, c, ...], and every gradient back.
 
 **Precision.** ``g``, its cumulative sums, ``β``, ``A``'s inverse and the
 products with it, the chunk states and their recurrence are float32 (the
@@ -106,10 +114,11 @@ chunk's ``T`` (:data:`KEPT`, named for a checkpoint's policy as
 ``ops/flash_attention.KEPT`` are: a checkpointed block then runs no second
 forward); the backward walks the segments from the last to the first,
 rebuilds one segment's chunk quantities from its inputs and its entering
-state, and differentiates that segment as written (the triangular inverse
-by ``−Tᵀ dT Tᵀ``), handing the state's cotangent on. So a backward holds
-one segment's intermediates — a few ``[segment, heads, d]`` float32 arrays
-— and never a sequence's.
+state, and differentiates that segment (the kernels' rule by its two
+backward kernels, a chunk-major one as written; the triangular inverse by
+``−Tᵀ dT Tᵀ``), handing the state's cotangent on. So a backward holds one
+segment's intermediates — a few ``[segment, heads, d]`` float32 arrays —
+and never a sequence's.
 
 By the kernels ``T`` is a function of a chunk's ``k``, ``g`` and ``β`` that
 costs 60 of the forward kernel's 84 MXU passes (ten six-pass float32
@@ -139,17 +148,22 @@ IMPLEMENTATION = (
     "chunked WY form, pairwise decay by dyadic levels: a chunk's work in "
     "Pallas kernels (forward and backward, each chunk's triangular "
     "inverse kept from the one for the other) and the chunk states one "
-    "after the other in two more that hold the state in VMEM, where d_k "
-    "and d_v are multiples of 128 and the chunk of 64; both in jax.numpy "
-    "otherwise (ops/kda.py)"
+    "after the other in two more that hold the state in VMEM, all five on "
+    "blocks of the model's own [b, s, h·d] arrays, where d_k and d_v are "
+    "multiples of 128 and the chunk of 64; both in jax.numpy on "
+    "chunk-major copies otherwise (ops/kda.py)"
 )
 # What makes a chunk's quantities and what carries the state over the
-# chunks, by whether :func:`uses_kernels` says so.
+# chunks, by whether :func:`uses_kernels` says so; the rule that runs owns
+# the layout.
 PATHS = {
     True: "Pallas kernels kda_chunk_forward (kda_chunk_rebuild where the "
           "backward reads the kept inverses) and kda_chunk_backward; the "
-          "chunk states by kda_state_forward and kda_state_backward",
-    False: "jax.numpy, the chunk states by a lax.scan",
+          "chunk states by kda_state_forward and kda_state_backward; "
+          "q, k, v, g, o and their gradients read and written in place "
+          "as [b, s, h·d], the segment's index a scalar-prefetch argument",
+    False: "jax.numpy, the chunk states by a lax.scan, each segment "
+           "copied chunk-major",
 }
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -512,12 +526,24 @@ def _chunk_math(q, k, v, g, beta, dtype, inverse=None, values_only=False):
             k * jnp.exp(last - G), jnp.exp(last), T)
 
 
+def _head(ref, at, heads: int):
+    """Where head ``at`` of a grid step's ``heads`` lies in its block: the
+    tile ``at`` of a chunk-major block [heads, c, d], or, in a block
+    [c, heads · d] of the model's own array, the head's ``d`` lanes (whole
+    128-lane registers wherever Mosaic compiles the kernels)."""
+    if len(ref.shape) == 3:
+        return (at,)
+    d = ref.shape[1] // heads
+    first = at * d if isinstance(at, int) else pl.multiple_of(at * d, d)
+    return (slice(None), pl.ds(first, d))
+
+
 def _tiles(refs, at):
     """A chunk-head's operands from the blocks of a grid step: ``q``,
-    ``k``, ``v`` float32, ``g``, and ``beta`` as a [1, c] row."""
-    q, k, v, g, beta = refs
-    return (*(r[at].astype(_F32) for r in (q, k, v)), g[at],
-            beta[0, pl.ds(at, 1), :])
+    ``k``, ``v`` and ``g`` float32, and ``beta`` as a [1, c] row."""
+    *wide, beta = refs
+    return (*(r[_head(r, at, beta.shape[0])].astype(_F32) for r in wide),
+            beta[pl.ds(at, 1), :])
 
 
 def _fold(c: int) -> int:
@@ -552,7 +578,7 @@ def _forward_kernel(*refs, reads: bool):
     ins, kept, outs = refs[:5], refs[5:5 + reads], refs[5 + reads:]
     dtype = ins[2].dtype
 
-    def chunk(at, _):
+    def head(at, _):
         *full, end, inverse = _chunk_math(
             *_tiles(ins, at), dtype,
             inverse=_unpacked(kept[0][at]) if reads else None,
@@ -560,75 +586,128 @@ def _forward_kernel(*refs, reads: bool):
         )
         for ref, a in zip(outs, full):
             ref[at] = a
-        outs[5][0, pl.ds(at, 1), :] = end
+        outs[5][pl.ds(at, 1), :] = end
         if len(outs) > 6:
             outs[6][at] = _packed(inverse)
         return 0
 
-    jax.lax.fori_loop(0, ins[0].shape[0], chunk, 0)
+    jax.lax.fori_loop(0, ins[4].shape[0], head, 0)
 
 
 def _backward_kernel(*refs):
     """q, k, v, g, beta, T and the six results' cotangents → dq, dk, dv,
     dg, dbeta: the ``jax.vjp`` of :func:`_chunk_math` on a chunk's tiles."""
     ins, kept, cots, outs = refs[:5], refs[5], refs[6:12], refs[12:]
-    dtype = ins[2].dtype
+    dtype, heads = ins[2].dtype, ins[4].shape[0]
 
-    def chunk(at, _):
+    def head(at, _):
         inverse = _unpacked(kept[at])
         _, vjp = jax.vjp(
             lambda *a: _chunk_math(*a, dtype, inverse=inverse)[:6],
             *_tiles(ins, at),
         )
-        *d_full, d_beta = vjp((
-            *(ref[at] for ref in cots[:5]), cots[5][0, pl.ds(at, 1), :]
+        *d_wide, d_beta = vjp((
+            *(ref[at] for ref in cots[:5]), cots[5][pl.ds(at, 1), :]
         ))
-        for ref, a in zip(outs, d_full):
-            ref[at] = a.astype(ref.dtype)
-        outs[4][0, pl.ds(at, 1), :] = d_beta
+        for ref, a in zip(outs, d_wide):
+            ref[_head(ref, at, heads)] = a.astype(ref.dtype)
+        outs[4][pl.ds(at, 1), :] = d_beta
         return 0
 
-    jax.lax.fori_loop(0, ins[0].shape[0], chunk, 0)
+    jax.lax.fori_loop(0, heads, head, 0)
 
 
-def _call(kernel, name, operands, outputs, interpret: bool):
-    """``kernel`` over the chunk-heads of ``operands`` ([..., c, d]
-    tiles; [..., d] rows go as [steps, chunk-heads a step, d]), every
-    grid step independent."""
-    lead = operands[0].shape[:-2]
-    count = math.prod(lead)
-    step = math.gcd(count, CHUNKS_A_STEP)
+class Segment(NamedTuple):
+    """Which part of a sequence a kernel call runs: segment ``at`` (a
+    traced index) of ``chunks`` chunks of ``chunk`` tokens each."""
 
-    def flat(a):
-        if len(a.shape) == len(lead) + 2:
-            return (count, *a.shape[-2:])
-        return (count // step, step, a.shape[-1])
+    at: jax.Array
+    chunks: int
+    chunk: int
 
-    def spec(a):
-        first, *rest = flat(a)
-        return pl.BlockSpec(
-            (first * step // count, *rest), lambda at: (at, 0, 0)
-        )
 
-    def run(*operands):
+def step_heads(h: int, most: int) -> int:
+    """Heads a grid step: ``most`` or the largest divisor of ``h`` in it
+    and, where that is no whole tile of 8 sublanes (the block of a
+    group's [heads, d_k] decay rows), all ``h``."""
+    heads = math.gcd(h, most)
+    return heads if heads % 8 == 0 else h
+
+
+def _call(kernel, name, where: Segment, operands, outputs, h: int, most: int,
+          walk=None, interpret: bool = False):
+    """``kernel`` over segment ``where``, a chunk of :func:`step_heads`
+    of the ``h`` heads a grid step, each array's blocks found by what it
+    is. [b, s, h · d]: the model's array of the whole sequence, a block a
+    chunk's rows × the group's lanes, found by the segment's index (the
+    scalar-prefetch argument). [b, chunks, h, ...]: chunk-major, the
+    segment's chunks or (longer) the whole sequence's. With ``walk`` the
+    chunks are the grid's last axis and run in order (``True``: from the
+    last), the last operand and result are states [b, h, d_k, d_v] whose
+    blocks stay for a group's walk, and the scratch is the group's states,
+    float32, transposed; without it every grid step is independent. A
+    result given as an ARRAY is written into, other segments' blocks left
+    as they are."""
+    b, n, heads = operands[0].shape[0], where.chunks, step_heads(h, most)
+
+    def spec(a, state: bool):
+        zeros = (0,) * (len(a.shape) - 3)
+
+        def index(*grid):
+            *ids, segment = grid
+            i, t, j = ids if walk is None else (
+                ids[0], n - 1 - ids[2] if walk else ids[2], ids[1])
+            if state:
+                return i, j, 0, 0
+            if len(a.shape) == 3:
+                return i, segment[0] * n + t, j
+            return i, t if a.shape[1] == n else segment[0] * n + t, j, *zeros
+
+        if state:
+            return pl.BlockSpec((None, heads, *a.shape[2:]), index)
+        if len(a.shape) == 3:
+            return pl.BlockSpec(
+                (None, where.chunk, a.shape[2] // h * heads), index)
+        return pl.BlockSpec((None, None, heads, *a.shape[3:]), index)
+
+    def specs(arrays):
+        return [spec(a, walk is not None and a is arrays[-1])
+                for a in arrays]
+
+    into = {at: a for at, a in enumerate(outputs)
+            if not isinstance(a, jax.ShapeDtypeStruct)}
+    taken = len(operands)
+
+    def run(at, *operands):
         return pl.pallas_call(
-            kernel,
+            lambda _, *refs: kernel(
+                *refs[:taken], *refs[taken + len(into):]),
             out_shape=[
-                jax.ShapeDtypeStruct(flat(a), a.dtype) for a in outputs
-            ],
-            grid=(count // step,),
-            in_specs=[spec(a) for a in operands],
-            out_specs=[spec(a) for a in outputs],
+                jax.ShapeDtypeStruct(a.shape, a.dtype) for a in outputs],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, n, h // heads) if walk is None
+                else (b, h // heads, n),
+                in_specs=specs(operands[:taken])
+                + [pl.BlockSpec(memory_space=pl.ANY)] * len(into),
+                out_specs=specs(outputs),
+                scratch_shapes=[] if walk is None else [pltpu.VMEM(
+                    (heads, *operands[taken - 1].shape[:1:-1]), _F32)],
+            ),
+            input_output_aliases={
+                1 + taken + order: at for order, at in enumerate(into)},
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",),
+                dimension_semantics=("parallel",) * 3 if walk is None
+                else ("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
             name=name,
-        )(*(a.reshape(flat(a)) for a in operands))
+        )(at, *operands)
 
     run.__name__ = name
-    results = (run if interpret else jax.jit(run))(*operands)
-    return tuple(r.reshape(a.shape) for r, a in zip(results, outputs))
+    return tuple((run if interpret else jax.jit(run))(
+        jnp.reshape(where.at, (1,)).astype(jnp.int32), *operands,
+        *into.values()))
 
 
 def inverses_shape(lead, chunk: int):
@@ -637,66 +716,54 @@ def inverses_shape(lead, chunk: int):
     return (*lead, chunk // fold, chunk * fold)
 
 
-def _forward_call(q, k, v, g, beta, interpret: bool, keep: bool = False,
+def _forward_call(where, q, k, v, g, rows, interpret: bool, keep=None,
                   inverses=None):
-    """The chunk-local step's six results by the forward kernel: with
-    ``keep`` the chunks' T as a seventh, with ``inverses`` (a ``keep``
-    call's seventh) T read in place of the inverse's products."""
+    """The chunk-local step's six results of segment ``where``, [b, n, h,
+    ...], by the forward kernel, from the whole sequence's ``q``, ``k``,
+    ``v``, ``g`` [b, s, h · d] and ``beta`` as ``rows`` [b, chunks, h, c].
+    The segment's T is written into ``keep`` (every chunk's, [b, chunks,
+    h, c / fold, c · fold]) as a seventh result, or with ``inverses``
+    (such an array) read in place of the inverse's products."""
     like = jax.ShapeDtypeStruct
-    lead, c = k.shape[:-2], k.shape[-2]
+    (b, _, h, c), n = rows.shape, where.chunks
+    d_k, d_v = k.shape[2] // h, v.shape[2] // h
     results = (
-        like(v.shape, _F32), like(k.shape, _F32), like((*lead, c, c), v.dtype),
-        like(q.shape, v.dtype), like(k.shape, _F32),
-        like((*lead, k.shape[-1]), _F32),
+        like((b, n, h, c, d_v), _F32), like((b, n, h, c, d_k), _F32),
+        like((b, n, h, c, c), v.dtype), like((b, n, h, c, d_k), v.dtype),
+        like((b, n, h, c, d_k), _F32), like((b, n, h, d_k), _F32),
     )
-    if keep:
-        results += (like(inverses_shape(lead, c), _F32),)
     reads = inverses is not None
     return _call(
         functools.partial(_forward_kernel, reads=reads),
-        "kda_chunk_rebuild" if reads else "kda_chunk_forward",
-        (q, k, v, g, beta) + ((inverses,) if reads else ()), results,
-        interpret,
+        "kda_chunk_rebuild" if reads else "kda_chunk_forward", where,
+        (q, k, v, g, rows) + ((inverses,) if reads else ()),
+        results + (() if keep is None else (keep,)), h, CHUNKS_A_STEP,
+        interpret=interpret,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _chunk_local(q, k, v, g, beta, inverses, interpret: bool):
-    return _forward_call(q, k, v, g, beta, interpret, inverses=inverses)
-
-
-def _chunk_local_fwd(q, k, v, g, beta, inverses, interpret):
-    results = _forward_call(q, k, v, g, beta, interpret, inverses=inverses)
-    return results, (q, k, v, g, beta, inverses)
-
-
-def _chunk_local_bwd(interpret, kept, cotangents):
-    like = jax.ShapeDtypeStruct
-    grads = _call(
-        _backward_kernel, "kda_chunk_backward", (*kept, *cotangents),
-        tuple(like(a.shape, a.dtype) for a in kept[:5]), interpret,
+def _backward_call(where, inputs, inverses, cotangents, grads,
+                   interpret: bool):
+    """The gradients of segment ``where``'s chunk-local step by the
+    backward kernel, written into ``grads``: the whole sequence's, each in
+    the shape of its one of ``inputs`` (:func:`_forward_call`'s five)."""
+    return _call(
+        _backward_kernel, "kda_chunk_backward", where,
+        (*inputs, inverses, *cotangents), grads, inputs[4].shape[2],
+        CHUNKS_A_STEP, interpret=interpret,
     )
-    return (*grads, jnp.zeros_like(kept[5]))
 
 
-_chunk_local.defvjp(_chunk_local_fwd, _chunk_local_bwd)
-
-
-def chunk_local(q, k, v, g, beta, inverses):
-    """:func:`_chunk_local_jnp` by the Pallas kernels, given the chunks'
-    ``T`` as the forward pass kept it (:func:`_forward_call`'s seventh
-    result): the same results, one ``custom_vjp`` that inverts nothing,
-    forward or backward. Compiled by Mosaic on a TPU, run in the Pallas
-    interpreter anywhere else."""
-    return _chunk_local(q, k, v, g, beta, inverses, _interpret())
+def _chunk_major(chunk: int, a):
+    """[b, s, h, ...] as [b, n, h, c, ...]: a copy."""
+    return jnp.moveaxis(a.reshape(a.shape[0], -1, chunk, *a.shape[2:]), 2, 3)
 
 
 def _chunks(chunk: int, q, k, v, g, beta):
-    """[b, s, h, ...] arrays as the chunk-local step takes them, [b, n, h,
-    c, ...], ``g`` and ``beta`` float32."""
+    """[b, s, h, ...] arrays as a chunk-major chunk-local step takes them,
+    [b, n, h, c, ...], ``g`` and ``beta`` float32."""
     return tuple(
-        jnp.moveaxis(
-            a.reshape(a.shape[0], -1, chunk, *a.shape[2:]), 2, 3)
+        _chunk_major(chunk, a)
         for a in (q, k, v, g.astype(_F32), beta.astype(_F32))
     )
 
@@ -740,11 +807,10 @@ def _across(local, state, dtype):
 # --------------------------------------------------------------------------
 # The recurrence over chunk states as Pallas kernels. A grid step holds ONE
 # chunk of :data:`HEADS_A_STEP` heads; the chunk axis is the grid's last and
-# runs in order, so a head's state stays in a VMEM scratch from a segment's
-# first chunk to its last, and no chunk's state is written to HBM but where
-# the backward reads it. The state lies TRANSPOSED there, [d_v, d_k]: a
-# chunk's decay is then a [1, d_k] row over its sublanes, the decay's
-# cotangent a sum over them, and no [1, d] row has to become a column.
+# runs in order, so a head's state stays in a VMEM scratch for a segment.
+# It lies TRANSPOSED there, [d_v, d_k]: a chunk's decay is then a [1, d_k]
+# row over its sublanes, the decay's cotangent a sum over them, and no
+# [1, d] row has to become a column.
 
 # Heads a grid step: blocks of 0.13 MB a head in the forward pass and 0.34
 # in the backward, both buffers of each, and 64 KiB a head of scratch.
@@ -771,7 +837,7 @@ def _state_forward_kernel(*refs, keeps: bool):
     # waiting (PERF.md §6, PR 48).
     ws = [U[at] - _dot(W[at], state[at], (1, 1)) for at in heads]
     for at in heads:
-        out[at] = (
+        out[_head(out, at, len(heads))] = (
             _dot(q_decayed[at], state[at].astype(dtype), (1, 1))
             + _dot(P[at], ws[at].astype(dtype), (1, 0))
         ).astype(dtype)
@@ -796,6 +862,7 @@ def _state_backward_kernel(*refs):
     W, P, q_decayed, to_end, end, entered, w, d_out, d_left = refs[:9]
     dU, dW, dP, dq_decayed, dto_end, dend, d_entering, d_state = refs[9:]
     dtype, heads = d_out.dtype, range(d_state.shape[0])
+    d_outs = [d_out[_head(d_out, at, len(heads))] for at in heads]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -803,10 +870,10 @@ def _state_backward_kernel(*refs):
             d_state[at] = d_left[at].T
 
     for at in heads:
-        dU[at] = (_dot(P[at], d_out[at], (0, 0))
+        dU[at] = (_dot(P[at], d_outs[at], (0, 0))
                   + _dot(to_end[at], d_state[at], (1, 1)))
     for at in heads:
-        S, dS, d_o = entered[at], d_state[at], d_out[at]
+        S, dS, d_o = entered[at], d_state[at], d_outs[at]
         dW[at] = -_dot(dU[at], S, (1, 0))
         dq_decayed[at] = _dot(d_o, S.astype(dtype), (1, 0)).astype(dtype)
         dP[at] = _dot(d_o, w[at].astype(dtype), (1, 1)).astype(dtype)
@@ -815,7 +882,7 @@ def _state_backward_kernel(*refs):
     for at in heads:
         d_state[at] = (
             end[at:at + 1, :] * d_state[at]
-            + _dot(d_out[at], q_decayed[at], (0, 0))
+            + _dot(d_outs[at], q_decayed[at], (0, 0))
             - _dot(dU[at], W[at], (0, 0))
         )
 
@@ -825,116 +892,46 @@ def _state_backward_kernel(*refs):
             d_entering[at] = d_state[at].T
 
 
-def state_heads(h: int) -> int:
-    """Heads a grid step of the state kernels: :data:`HEADS_A_STEP` or
-    the largest divisor of ``h`` in it and, where that is no whole tile
-    of 8 sublanes (the block of a group's [heads, d_k] decay rows), all
-    ``h``."""
-    heads = math.gcd(h, HEADS_A_STEP)
-    return heads if heads % 8 == 0 else h
-
-
-def _state_call(kernel, name, operands, outputs, reverse: bool,
-                interpret: bool):
-    """``kernel`` over a segment, grid (sequences, groups of heads,
-    chunks), the chunks one after the other (from the last with
-    ``reverse``). Of ``operands`` and of ``outputs`` the last is a state,
-    [b, h, d_k, d_v], whose block stays for a group's whole walk; the
-    others are [b, n, h, ...] as the chunk kernels write them and go a
-    chunk of the group's heads a step. The scratch is the group's states,
-    float32, transposed."""
-    b, n, h = operands[0].shape[:3]
-    heads = state_heads(h)
-    d_k, d_v = operands[-1].shape[2:]
-
-    def specs(arrays):
-        *walked, state = arrays
-        return [
-            pl.BlockSpec(
-                (None, None, heads, *a.shape[3:]),
-                lambda i, j, t, zeros=(0,) * (len(a.shape) - 3): (
-                    i, n - 1 - t if reverse else t, j, *zeros),
-            ) for a in walked
-        ] + [pl.BlockSpec(
-            (None, heads, *state.shape[2:]), lambda i, j, t: (i, j, 0, 0)
-        )]
-
-    def run(*operands):
-        return pl.pallas_call(
-            kernel,
-            out_shape=list(outputs),
-            grid=(b, h // heads, n),
-            in_specs=specs(operands),
-            out_specs=specs(outputs),
-            scratch_shapes=[pltpu.VMEM((heads, d_v, d_k), _F32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-            ),
-            interpret=interpret,
-            name=name,
-        )(*operands)
-
-    run.__name__ = name
-    return tuple((run if interpret else jax.jit(run))(*operands))
-
-
-def _state_forward_call(local, state, interpret: bool, keeps: bool):
-    """``o`` [b, n, h, c, d_v] in ``P``'s dtype, with ``keeps`` every
-    chunk's entering state [b, n, h, d_v, d_k] and w [b, n, h, c, d_v],
-    float32, and the state left."""
+def _state_forward_call(where, local, state, interpret: bool, out=None):
+    """The walk over segment ``where``'s chunk states from its six
+    chunk-local results [b, n, h, ...]: ``o`` written into ``out`` (the
+    whole sequence's, [b, s, h · d_v], in ``P``'s dtype) and the state
+    left; with no ``out`` (the backward's rebuild of ONE segment) ``o``
+    [b, n, h, c, d_v], every chunk's entering state [b, n, h, d_v, d_k]
+    and w [b, n, h, c, d_v], float32, and the state left."""
     like = jax.ShapeDtypeStruct
     U, _, P = local[:3]
     d_k, d_v = state.shape[2:]
-    kept = (
-        like((*U.shape[:3], d_v, d_k), _F32), like(U.shape, _F32)
-    ) if keeps else ()
-    return _state_call(
-        functools.partial(_state_forward_kernel, keeps=keeps),
-        "kda_state_forward", (*local, state),
-        (like(U.shape, P.dtype), *kept, like(state.shape, _F32)),
-        False, interpret,
+    results = (
+        like(U.shape, P.dtype), like((*U.shape[:3], d_v, d_k), _F32),
+        like(U.shape, _F32),
+    ) if out is None else (out,)
+    return _call(
+        functools.partial(_state_forward_kernel, keeps=out is None),
+        "kda_state_forward", where, (*local, state),
+        (*results, like(state.shape, _F32)), U.shape[2], HEADS_A_STEP,
+        walk=False, interpret=interpret,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _states(U, W, P, q_decayed, to_end, end, state, interpret: bool):
-    return _state_forward_call(
-        (U, W, P, q_decayed, to_end, end), state, interpret, keeps=False)
-
-
-def _states_fwd(U, W, P, q_decayed, to_end, end, state, interpret):
-    out, entered, w, left = _state_forward_call(
-        (U, W, P, q_decayed, to_end, end), state, interpret, keeps=True)
-    return (out, left), (W, P, q_decayed, to_end, end, entered, w)
-
-
-def _states_bwd(interpret, kept, cotangents):
+def _state_backward_call(where, kept, d_out, d_left, interpret: bool):
+    """The six results' cotangents [b, n, h, ...] and the entering
+    state's from what the rebuild's walk kept, ``o``'s cotangent ``d_out``
+    [b, s, h · d_v] (the whole sequence's, read at ``where``) and the
+    cotangent of the state left."""
     like = jax.ShapeDtypeStruct
     W, P, q_decayed, to_end, end, _, w = kept
-    d_out, d_left = cotangents
-    return _state_call(
-        _state_backward_kernel, "kda_state_backward",
+    return _call(
+        _state_backward_kernel, "kda_state_backward", where,
         (*kept, d_out, d_left),
         tuple(like(a.shape, a.dtype)
               for a in (w, W, P, q_decayed, to_end, end, d_left)),
-        True, interpret,
+        W.shape[2], HEADS_A_STEP, walk=True, interpret=interpret,
     )
 
 
-_states.defvjp(_states_fwd, _states_bwd)
-
-
-def across(local, state):
-    """:func:`_across` by the Pallas kernels: the same two results, the
-    state in VMEM from a segment's first chunk to its last, and one
-    ``custom_vjp`` whose forward keeps a segment's chunk states and w and
-    whose backward is the second kernel, so nothing of the recurrence is
-    differentiated by tracing. Compiled by Mosaic on a TPU, run in the
-    Pallas interpreter anywhere else."""
-    out, left = _states(*local, state, _interpret())
-    b, n, h, c, d_v = out.shape
-    return jnp.moveaxis(out, 3, 2).reshape(b, n * c, h, d_v), left
-
+# --------------------------------------------------------------------------
+# The walk over a sequence's segments: a rule's own loop (it owns the layout).
 
 def _segments(chunk: int, *arrays):
     """[b, s, ...] arrays as [segments, b, s / segments, ...]."""
@@ -954,31 +951,163 @@ def _whole(a):
 
 
 class Rule(NamedTuple):
-    """What the walk over segments needs of a delta rule, as functions of
-    a segment's chunks ``q``, ``k``, ``v``, ``g``, ``beta`` [b, n, h, c,
-    ...] (:func:`_chunks`): ``local(*xs, keep)`` the chunk-local step's six
-    results ``U``, ``W``, ``P``, ``q e^G``, ``k e^{G_C − G}``, ``e^{G_C}``
-    and, with ``keep``, whatever else the backward reads again;
-    ``rebuild(*xs, *kept)`` the six from the inputs and that;
-    ``across(six, state, dtype)`` the output and the state left; ``names``
-    what the forward keeps besides its inputs, for a checkpoint's policy.
-    Hashable: the static argument of :func:`segment_walk`. The two rules
-    of this module name their functions when they are called (a test
-    stands in for one by name)."""
+    """A delta rule over a whole sequence, the segments one after the
+    other, its backward rebuilding ONE segment at a time from its inputs
+    and its entering state. It is handed the model's arrays ``xs`` = ``q``,
+    ``k``, ``v``, ``g``, ``beta`` [b, s, h, ...]; how its chunk step gets a
+    segment's tiles out of them is its own affair. ``forward(chunk, xs,
+    keep)``: ``o`` [b, s, h, d_v], the states the segments were entered
+    with [segments, b, h, d_k, d_v] and, with ``keep``, whatever else the
+    backward reads again; ``backward(chunk, xs, kept, d_out)``: the five
+    gradients, ``kept`` the forward's results after ``o``; ``names`` what
+    the forward keeps besides its inputs, for a checkpoint's policy.
+    Hashable: the static argument of :func:`segment_walk`."""
 
-    local: Callable
-    rebuild: Callable
-    across: Callable
+    forward: Callable
+    backward: Callable
     names: Tuple[str, ...]
 
 
-KERNELS = Rule(
-    lambda *xs, keep: _forward_call(*xs, _interpret(), keep),
-    lambda *xs: chunk_local(*xs),
-    lambda six, state, dtype: across(six, state),
-    KEPT,
-)
-PLAIN = Rule(
+def chunk_major(local: Callable, rebuild: Callable, across: Callable,
+                names: Tuple[str, ...]) -> Rule:
+    """The rule of a chunk step on chunk-major ``q``, ``k``, ``v``, ``g``,
+    ``beta`` [b, n, h, c, ...] (:func:`_chunks` of a segment: a copy):
+    ``local(*xs, keep)`` the six results ``U``, ``W``, ``P``, ``q e^G``,
+    ``k e^{G_C − G}``, ``e^{G_C}`` and, with ``keep``, whatever else the
+    backward reads again; ``rebuild(*xs, *kept)`` the six from the inputs
+    and that; ``across(six, state, dtype)`` the output [b, n · c, h, d_v]
+    and the state left. The loops scan the segments' slices, and the
+    backward differentiates a segment as written."""
+
+    def forward(chunk, xs, keep):
+        v = xs[2]
+
+        def step(state, xs):
+            results = local(*_chunks(chunk, *xs), keep=keep)
+            out, left = across(results[:6], state, v.dtype)
+            return left, (out, state, *results[6:])
+
+        _, (out, *kept) = jax.lax.scan(
+            step, _no_state(xs), _segments(chunk, *xs))
+        return _whole(out), *kept
+
+    def backward(chunk, xs, kept, d_out):
+        entered, dtype = kept[0], xs[2].dtype
+
+        def step(d_state, xs):
+            (*xs, d_o), (state, *inverses) = xs[:6], xs[6:]
+
+            def segment(*a):
+                *a, state = a
+                return across(
+                    rebuild(*_chunks(chunk, *a), *inverses), state, dtype)
+
+            _, vjp = jax.vjp(segment, *xs, state)
+            *d_xs, d_state = vjp((d_o, d_state))
+            return d_state, tuple(d_xs)
+
+        _, grads = jax.lax.scan(
+            step, jnp.zeros(entered.shape[1:], _F32),
+            (*_segments(chunk, *xs, d_out), *kept), reverse=True,
+        )
+        return tuple(_whole(a) for a in grads)
+
+    return Rule(forward, backward, names)
+
+
+def _no_state(xs):
+    b, _, h, d_k = xs[1].shape
+    return jnp.zeros((b, h, d_k, xs[2].shape[-1]), _F32)
+
+
+def _in_place(chunk: int, xs, *more):
+    """What the kernels' index maps read of ``xs``: ``q``, ``k``, ``v``,
+    ``g`` [b, s, h, d] as [b, s, h · d] (no copy) and ``beta`` [b, s, h]
+    chunk-major, [b, chunks, h, c] float32 (the one copy: a chunk's [1, c]
+    row of it is a head's, and a block [c, h] of the model's array holds
+    that as a column); ``more`` such arrays as [b, s, h · d]; a segment's
+    chunks and the segments' count."""
+    *wide, beta = xs
+    wide = [a.reshape(*a.shape[:2], -1) for a in (*wide, *more)]
+    n = math.gcd(beta.shape[1] // chunk, SEGMENT_CHUNKS)
+    return ((*wide[:4], _chunk_major(chunk, beta.astype(_F32))), wide[4:],
+            n, beta.shape[1] // chunk // n)
+
+
+def _unwritten(like, after, interpret: bool):
+    """Arrays for a loop to write into, of the shapes and dtypes ``like``,
+    their contents undefined: the results of a kernel that does nothing
+    and is handed ``after``, an array the loop reads. (An allocation with
+    no operand is scheduled where the PROGRAM starts, and a backward's
+    five gradients would be held through every layer's forward: 1.5 GiB
+    of Kimi Linear's step, PERF.md §6, PR 68.)"""
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+
+    def kda_unwritten(after):
+        return pl.pallas_call(
+            lambda *refs: None,
+            out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in like],
+            in_specs=[anywhere], out_specs=[anywhere] * len(like),
+            interpret=interpret, name="kda_unwritten",
+        )(after)
+
+    return (kda_unwritten if interpret else jax.jit(kda_unwritten))(after)
+
+
+def _kernels_forward(chunk, xs, keep):
+    """The loop carries the state and the arrays being written: ``o`` and,
+    with ``keep``, every chunk's T [b, chunks, h, c / fold, c · fold]."""
+    inputs, _, n, count = _in_place(chunk, xs)
+    (b, chunks, h, _), v, interpret = inputs[4].shape, xs[2], _interpret()
+
+    def step(carry, at):
+        state, out, *inverses = carry
+        where = Segment(at, n, chunk)
+        results = _forward_call(where, *inputs, interpret, *inverses)
+        out, left = _state_forward_call(
+            where, results[:6], state, interpret, out)
+        return (left, out, *results[6:]), state
+
+    written = [jax.ShapeDtypeStruct((*v.shape[:2], h * v.shape[3]), v.dtype)]
+    if keep:
+        written.append(jax.ShapeDtypeStruct(
+            inverses_shape((b, chunks, h), chunk), _F32))
+    (_, out, *inverses), entered = jax.lax.scan(
+        step, (_no_state(xs), *_unwritten(written, inputs[0], interpret)),
+        jnp.arange(count))
+    return out.reshape(v.shape), entered, *inverses
+
+
+def _kernels_backward(chunk, xs, kept, d_out):
+    """A segment's six results again from the kept T, its walk again for
+    the chunk states and ``w``, then the two backward kernels, each
+    gradient written where the model reads it."""
+    inputs, (d_out,), n, count = _in_place(chunk, xs, d_out)
+    (entered, inverses), interpret = kept, _interpret()
+
+    def step(carry, segment):
+        (d_state, *grads), (at, state) = carry, segment
+        where = Segment(at, n, chunk)
+        six = _forward_call(where, *inputs, interpret, inverses=inverses)
+        _, states, w, _ = _state_forward_call(where, six, state, interpret)
+        *d_six, d_state = _state_backward_call(
+            where, (*six[1:], states, w), d_out, d_state, interpret)
+        return (d_state, *_backward_call(
+            where, inputs, inverses, d_six, grads, interpret)), None
+
+    (_, *grads, d_rows), _ = jax.lax.scan(
+        step,
+        (jnp.zeros(entered.shape[1:], _F32),
+         *_unwritten(inputs, d_out, interpret)),
+        (jnp.arange(count), entered), reverse=True,
+    )
+    beta = xs[4]
+    return (*(a.reshape(x.shape) for a, x in zip(grads, xs)),
+            jnp.moveaxis(d_rows, 3, 2).reshape(beta.shape).astype(beta.dtype))
+
+
+KERNELS = Rule(_kernels_forward, _kernels_backward, KEPT)
+PLAIN = chunk_major(
     lambda *xs, keep: _chunk_local_jnp(*xs),
     lambda *xs: _chunk_local_jnp(*xs),
     lambda six, state, dtype: _across(six, state, dtype),
@@ -1006,33 +1135,22 @@ def _kda(q, k, v, g, beta, chunk, kernels):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def segment_walk(q, k, v, g, beta, chunk, rule):
-    """A chunked delta rule over a whole sequence, ``rule`` (a
-    :class:`Rule`) saying what a chunk costs of itself: the segments one
-    after the other, and a backward that rebuilds ONE segment at a time
-    from its inputs and its entering state. ``g`` is whatever ``rule``
-    reads (a decay a channel here, one a head in ``ops/gdn.py``)."""
+    """A chunked delta rule over a whole sequence by ``rule`` (a
+    :class:`Rule`), under one ``custom_vjp`` whose forward names what a
+    checkpoint keeps. ``g`` is whatever ``rule`` reads (a decay a channel
+    here, one a head in ``ops/gdn.py``)."""
     return _forward(q, k, v, g, beta, chunk, rule, keep=False)[0]
 
 
 def _forward(q, k, v, g, beta, chunk, rule, keep: bool):
     """``o``, the states the segments were entered with and, with
-    ``keep``, whatever else ``rule.local`` keeps of every segment."""
-    b, s, h, d_k = k.shape
+    ``keep``, whatever else ``rule`` keeps of every segment."""
     if chunk < 1 or chunk & (chunk - 1):
         raise ValueError(f"chunk {chunk} is not a power of two")
-    if s % chunk:
-        raise ValueError(f"sequence {s} is not a multiple of chunk {chunk}")
-
-    def step(state, xs):
-        local = rule.local(*_chunks(chunk, *xs), keep=keep)
-        out, left = rule.across(local[:6], state, v.dtype)
-        return left, (out, state, *local[6:])
-
-    _, (out, *kept) = jax.lax.scan(
-        step, jnp.zeros((b, h, d_k, v.shape[-1]), _F32),
-        _segments(chunk, q, k, v, g, beta),
-    )
-    return _whole(out), *kept
+    if k.shape[1] % chunk:
+        raise ValueError(
+            f"sequence {k.shape[1]} is not a multiple of chunk {chunk}")
+    return rule.forward(chunk, (q, k, v, g, beta), keep)
 
 
 def _walk_fwd(q, k, v, g, beta, chunk, rule):
@@ -1044,27 +1162,7 @@ def _walk_fwd(q, k, v, g, beta, chunk, rule):
 
 
 def _walk_bwd(chunk, rule, residuals, d_out):
-    q, k, v, g, beta, entered, *kept = residuals
-
-    def step(d_state, xs):
-        (*xs, d_o), (state, *inverses) = xs[:6], xs[6:]
-
-        def segment(*a):
-            *a, state = a
-            return rule.across(
-                rule.rebuild(*_chunks(chunk, *a), *inverses), state, v.dtype
-            )
-
-        _, vjp = jax.vjp(segment, *xs, state)
-        *d_xs, d_state = vjp((d_o, d_state))
-        return d_state, tuple(d_xs)
-
-    _, grads = jax.lax.scan(
-        step, jnp.zeros(entered.shape[1:], _F32),
-        (*_segments(chunk, q, k, v, g, beta, d_out), entered, *kept),
-        reverse=True,
-    )
-    return tuple(_whole(a) for a in grads)
+    return rule.backward(chunk, residuals[:5], residuals[5:], d_out)
 
 
 segment_walk.defvjp(_walk_fwd, _walk_bwd)

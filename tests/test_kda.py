@@ -8,11 +8,17 @@ triangular inverse against ``numpy``; and the residuals a checkpoint's
 policy keeps. The chunk-local step runs by both of its paths, the
 ``jax.numpy`` form and the Pallas kernels (in the interpreter here), and
 so does the recurrence over chunk states (``_across`` in ``jax.numpy``,
-``across`` by the two state kernels); the shapes decide which, for both
-at once. Tiny sizes, float32, the CPU. Last, what feeds the rule
+the two state kernels); the shapes decide which, for both at once. Under
+the kernels' rule the model's [b, s, h · d] arrays are read and written
+in place: against the same kernels on slices of one segment at a time,
+and nothing of the sequence's size moved in the traced gradient. Tiny
+sizes, float32, the CPU. Last, what feeds the rule
 (``models/kda.QKVConv``): on a host made to look like one TPU chip, at
 heads of 128, q and k leave their convolutions' kernels normalised in
 every layer and v's stays the plain one."""
+import functools
+import math
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -30,6 +36,7 @@ from tests.test_causal_conv_kernel import (  # noqa: F401  (a fixture)
     as_on_a_tpu,
 )
 from tests.test_checkpoint_keeps import _eqns
+from tests.test_gdn import CHEAPLY, _run_once
 
 NAMES = ("q", "k", "v", "g", "beta")
 
@@ -53,11 +60,24 @@ def _rel(got, want):
     return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
 
 
-def _grads(fn, args):
+def _value_and_grads(fn, args):
+    """``fn``'s value and the gradients of its sum under fixed weights, in
+    ONE compiled program (op by op the interpreter's kernels take longer)."""
     weights = jnp.cos(jnp.arange(args[2].shape[-1], dtype=jnp.float32))
-    return jax.jit(jax.grad(
-        lambda *a: jnp.sum(fn(*a) * weights), argnums=(0, 1, 2, 3, 4)
-    ))(*args)
+
+    def run(*a):
+        out, vjp = jax.vjp(fn, *a)
+        return out, vjp(jnp.broadcast_to(weights, out.shape).astype(out.dtype))
+
+    return _run_once(run, *args)
+
+
+@functools.lru_cache(maxsize=None)
+def _by_the_scan(**sizes):
+    """``_inputs(**sizes)``, and the token-by-token scan's value and
+    gradients on them: one run for the two paths' cases."""
+    args = _inputs(**sizes)
+    return args, _value_and_grads(kda_recurrent, args)
 
 
 @pytest.mark.parametrize("kernels", [False, True], ids=["jnp", "kernels"])
@@ -72,19 +92,17 @@ def _grads(fn, args):
 ])
 def test_chunked_is_the_token_by_token_scan(chunk, s, d_k, d_v, strength,
                                             tol, kernels):
-    def kda_chunked(*a):             # the path asked for, whatever the shapes
-        return kda_ops.kda_chunked(*a, kernels=kernels)
-
-    args = _inputs(s=s, d_k=d_k, d_v=d_v, strength=strength)
+    args, (want, d_want) = _by_the_scan(
+        s=s, d_k=d_k, d_v=d_v, strength=strength)
     if strength > 1:
         per_chunk = args[3].reshape(2, s // chunk, chunk, 2, d_k).sum(2)
         assert float(per_chunk.min()) < -1000
-    want = jax.jit(kda_recurrent)(*args)
-    got = jax.jit(lambda *x: kda_chunked(*x, chunk))(*args)
+    # The path asked for, whatever the shapes.
+    got, d_got = _value_and_grads(
+        lambda *x: kda_ops.kda_chunked(*x, chunk, kernels=kernels), args)
     assert bool(jnp.all(jnp.isfinite(got)))
     assert _rel(got, want) < tol
-    for name, a, b in zip(NAMES, _grads(lambda *x: kda_chunked(*x, chunk),
-                                        args), _grads(kda_recurrent, args)):
+    for name, a, b in zip(NAMES, d_got, d_want):
         assert bool(jnp.all(jnp.isfinite(a))), name
         assert _rel(a, b) < 3 * tol, name
 
@@ -98,48 +116,86 @@ def _chunked(args, chunk):
     )
 
 
+def _in_place(args, chunk):
+    """``args`` as the kernels' calls read them (``q``, ``k``, ``v``,
+    ``g`` [b, s, h · d] and ``beta``'s rows), and the one segment that
+    holds every chunk of them."""
+    inputs, _, n, count = kda_ops._in_place(chunk, args)
+    assert count == 1
+    return inputs, kda_ops.Segment(jnp.zeros((), jnp.int32), n, chunk)
+
+
+def _as_the_model(a):
+    """[b, n, h, c, d] → [b, n · c, h · d], and rows [b, n, h, c] as they
+    are."""
+    if a.ndim == 4:
+        return a
+    return jnp.moveaxis(a, 3, 2).reshape(
+        a.shape[0], -1, a.shape[2] * a.shape[4])
+
+
 def test_the_kernels_vjp_is_the_jnp_chunk_local_forms():
     """One chunk of the cell's tile, [64, 128] float32, two heads: the six
-    results, and the five gradients of the kernels' ``custom_vjp`` against
+    results, and the five gradients by the backward kernel against
     ``jax.vjp`` of the plain form under the same cotangents. The kernels
     read the chunks' ``T`` as the forward pass keeps it: the inverse of
     ``I + A``, two row blocks of 32 side by side in 128 lanes."""
-    args = _chunked(_inputs(b=1, s=64, d_k=128, d_v=128), 64)
-    want, vjp = jax.vjp(kda_ops._chunk_local_jnp, *args)
-    inverses = kda_ops._forward_call(*args, interpret=True, keep=True)[6]
+    args = _inputs(b=1, s=64, d_k=128, d_v=128)
+    chunked = _chunked(args, 64)
+    rng = np.random.default_rng(1)
+    want = jax.eval_shape(kda_ops._chunk_local_jnp, *chunked)
+    cotangents = tuple(
+        jnp.asarray(rng.standard_normal(a.shape), a.dtype) for a in want)
+
+    def plain(*a):
+        out, vjp = jax.vjp(kda_ops._chunk_local_jnp, *a)
+        return out, vjp(cotangents)
+
+    want, d_want = jax.jit(plain)(*chunked)
+    inputs, where = _in_place(args, 64)
+
+    @jax.jit
+    def by_the_kernels(*inputs):
+        *six, inverses = kda_ops._forward_call(
+            where, *inputs, True,
+            jnp.zeros(kda_ops.inverses_shape((1, 1, 2), 64), jnp.float32))
+        again = kda_ops._forward_call(where, *inputs, True, inverses=inverses)
+        grads = kda_ops._backward_call(
+            where, inputs, inverses, cotangents,
+            tuple(jnp.zeros_like(a) for a in inputs), True)
+        return six, again, inverses, grads
+
+    got, again, inverses, d_got = by_the_kernels(*inputs)
     assert inverses.shape == (1, 1, 2, 32, 128)
-    _, own = kda_ops._pairwise(*args[:2], jnp.cumsum(args[3], -2), jnp.float32)
-    system = jnp.eye(64) + args[4][..., None] * own
+    _, own = kda_ops._pairwise(
+        *chunked[:2], jnp.cumsum(chunked[3], -2), jnp.float32)
+    system = jnp.eye(64) + chunked[4][..., None] * own
     T = jnp.concatenate([inverses[..., :64], inverses[..., 64:]], axis=-2)
     np.testing.assert_allclose(
         jnp.einsum("...ij,...jk->...ik", T, system, precision="highest"),
         jnp.broadcast_to(jnp.eye(64), T.shape), atol=2e-6)
-    got, kernel_vjp = jax.vjp(
-        lambda *a: kda_ops.chunk_local(*a, inverses), *args)
-    rng = np.random.default_rng(1)
-    cotangents = tuple(
-        jnp.asarray(rng.standard_normal(a.shape), a.dtype) for a in want
-    )
-    for a, b in zip(got, want):
-        assert a.shape == b.shape and a.dtype == b.dtype
-        assert _rel(a, b) < 5e-6
-    for name, a, b in zip(NAMES, kernel_vjp(cotangents), vjp(cotangents)):
+    for a, b, c in zip(got, want, again):
+        assert a.shape == b.shape == c.shape and a.dtype == b.dtype == c.dtype
+        assert _rel(a, b) < 5e-6 and _rel(c, b) < 5e-6
+    for name, a, b in zip(NAMES, d_got, d_want):
+        b = _as_the_model(b)
         assert a.shape == b.shape and a.dtype == b.dtype, name
         assert _rel(a, b) < 1e-5, name
+
+
+@functools.lru_cache(maxsize=None)
+def _bfloat16_by(kernels):
+    q, k, v, g, beta = _inputs(b=1, s=128, d_k=128, d_v=128)
+    args = (*(a.astype(jnp.bfloat16) for a in (q, k, v)), g, beta)
+    return _value_and_grads(
+        lambda *a: kda_chunked(*a, 64, kernels).astype(jnp.float32), args)
 
 
 def test_the_kernels_take_bfloat16_as_the_jnp_form_does():
     """``q``, ``k``, ``v`` in bfloat16 (the cell's), decays and ``beta``
     float32: output and gradients by the kernels against the plain form,
     both rounding their products' operands to bfloat16."""
-    q, k, v, g, beta = _inputs(b=1, s=128, d_k=128, d_v=128)
-    args = (*(a.astype(jnp.bfloat16) for a in (q, k, v)), g, beta)
-
-    def run(kernels):
-        fn = lambda *a: kda_chunked(*a, 64, kernels).astype(jnp.float32)
-        return jax.jit(fn)(*args), _grads(fn, args)
-
-    (got, d_got), (want, d_want) = run(True), run(False)
+    (got, d_got), (want, d_want) = _bfloat16_by(True), _bfloat16_by(False)
     assert got.dtype == want.dtype
     assert _rel(got, want) < 2e-2
     for name, a, b in zip(NAMES, d_got, d_want):
@@ -179,7 +235,8 @@ def test_the_shapes_choose_the_path(d_k, d_v, chunk, kernels, monkeypatch):
     assert seen == [kernels]
     halves = []
     for name in ("_chunk_local_jnp", "_across", "_forward_call",
-                 "chunk_local", "across"):
+                 "_backward_call", "_state_forward_call",
+                 "_state_backward_call"):
         def recorded(*a, _name=name, _fn=getattr(kda_ops, name), **kw):
             halves.append(_name)
             return _fn(*a, **kw)
@@ -189,7 +246,8 @@ def test_the_shapes_choose_the_path(d_k, d_v, chunk, kernels, monkeypatch):
             kda_chunked(*a, chunk).astype(jnp.float32)), argnums=(0, 1, 2)),
         *shapes(2 * chunk))
     assert set(halves) == (
-        {"_forward_call", "chunk_local", "across"} if kernels
+        {"_forward_call", "_backward_call", "_state_forward_call",
+         "_state_backward_call"} if kernels
         else {"_chunk_local_jnp", "_across"})
 
 
@@ -197,13 +255,12 @@ def test_a_sequence_runs_in_segments(monkeypatch):
     """Four segments of two chunks give what one segment of eight gives,
     up to float32 rounding, and the gradients handed back from one to the
     one before it are the scan's."""
-    args = _inputs(s=64)
+    args, (_, d_want) = _by_the_scan(s=64)
     whole = jax.jit(lambda *x: kda_chunked(*x, 8))(*args)
     monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 2)
-    cut = jax.jit(lambda *x: kda_chunked(*x, 8))(*args)
+    cut, d_cut = _value_and_grads(lambda *x: kda_chunked(*x, 8), args)
     assert _rel(cut, whole) < 2e-6
-    for a, b in zip(_grads(lambda *x: kda_chunked(*x, 8), args),
-                    _grads(kda_recurrent, args)):
+    for a, b in zip(d_cut, d_want):
         assert _rel(a, b) < 2e-5
 
 
@@ -312,8 +369,9 @@ def test_under_the_policy_the_kernels_inverses_are_kept_with_no_padded_lane(
         monkeypatch):
     """The kernel path at the cell's chunk, two segments of one chunk and
     two heads: what enters the checkpoint's backward holds the chunks'
-    ``T`` as [segments, b, chunks, h, 32, 128] float32 (a [64, 64] float32
-    array's last dimension is padded to 128 lanes in HBM), nothing float32
+    ``T`` as [b, chunks, h, 32, 128] float32, every segment's in the one
+    array the forward kernel wrote them into (a [64, 64] float32 array's
+    last dimension is padded to 128 lanes in HBM), nothing float32
     [64, 64], and the backward runs the rebuild and the gradient kernels
     of the chunk-local step, the two state kernels, and no forward chunk
     kernel."""
@@ -332,11 +390,11 @@ def test_under_the_policy_the_kernels_inverses_are_kept_with_no_padded_lane(
     ]
     assert set(_kernel_calls(backward.params["jaxpr"])) == {
         "kda_chunk_rebuild", "kda_chunk_backward",
-        "kda_state_forward", "kda_state_backward"}
+        "kda_state_forward", "kda_state_backward", "kda_unwritten"}
     kept = [tuple(v.aval.shape) for v in backward.invars
             if v.aval.dtype == jnp.float32]
-    assert (2, 1, 1, 2, 32, 128) in kept
-    assert all(shape[-1] % 128 == 0 for shape in kept if len(shape) == 6)
+    assert (1, 2, 2, 32, 128) in kept and (2, 1, 2, 8, 8) in kept
+    assert not [shape for shape in kept if shape[-1:] == (64,)]
     assert not [shape for shape in kept if shape[-2:] == (64, 64)]
     assert kda_ops.inverses_shape((1, 256, 32), 64) == (1, 256, 32, 32, 128)
     assert kda_ops.inverses_shape((3,), 128) == (3, 128, 128)
@@ -352,10 +410,11 @@ def test_the_backward_by_the_kernels_inverts_nothing(case, h, chunks, segment,
                                                      count, monkeypatch):
     """Under ``jax.grad`` of ``kda_chunked`` on the kernel path the
     triangular inverse is traced ONCE, in the forward pass's kernel, which
-    writes every chunk's ``T``; the backward's rebuild of a segment has
-    ``T`` among its operands and not among its results, the gradient
-    kernel reads the same array, and the gradients are the ``jax.numpy``
-    path's."""
+    writes every chunk's ``T`` into the one array it is handed; the
+    backward's rebuild of a segment has that array among its operands and
+    not among its results, the gradient kernel reads the same array, every
+    call is told its segment by a scalar, and at an odd count of heads (a
+    group that is no eight) the gradients are the ``jax.numpy`` path's."""
     chunk, d = 16, 16
     monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", segment)
     traced = []
@@ -369,26 +428,37 @@ def test_the_backward_by_the_kernels_inverts_nothing(case, h, chunks, segment,
             lambda *a: jnp.sum(jnp.sin(kda_chunked(*a, chunk, kernels))),
             argnums=(0, 1, 2, 3, 4))
 
-    calls = _kernel_calls(jax.make_jaxpr(grads(True))(*args).jaxpr)
+    traced_once = jax.jit(grads(True)).trace(*args)
+    calls = _kernel_calls(traced_once.jaxpr.jaxpr)
     assert len(traced) == 1
+    assert count == h * math.gcd(chunks, segment)    # chunk-heads a segment
+    # ``kda_unwritten`` does nothing: the arrays a loop writes into.
     assert set(calls) == {
         "kda_chunk_forward", "kda_chunk_rebuild", "kda_chunk_backward",
-        "kda_state_forward", "kda_state_backward"}
-    kept = (count, *kda_ops.inverses_shape((), chunk))
+        "kda_state_forward", "kda_state_backward", "kda_unwritten"}
+    kept = kda_ops.inverses_shape((1, chunks, h), chunk)
     assert kept[-1] == 128
-    steps = count // np.gcd(count, kda_ops.CHUNKS_A_STEP)
+    wide, rows = (1, chunk * chunks, h * d), (1, chunks, h, chunk)
+    # The segment's index first; the arrays a call writes into last.
     for name, n_in, n_out, reads in [
-        ("kda_chunk_forward", 5, 7, False),
-        ("kda_chunk_rebuild", 6, 6, True),
-        ("kda_chunk_backward", 12, 5, True),
+        ("kda_chunk_forward", 1 + 5 + 1, 7, False),
+        ("kda_chunk_rebuild", 1 + 6, 6, True),
+        ("kda_chunk_backward", 1 + 12 + 5, 5, True),
     ]:
         operands, results = calls[name]
         assert (len(operands), len(results)) == (n_in, n_out), name
-        assert (operands[5] == kept) if reads else (results[6] == kept), name
-        assert kept not in (results if reads else operands), name
-        assert operands[4] == (steps, count // steps, chunk), name
-    for name, a, b in zip(NAMES, jax.jit(grads(True))(*args),
-                          jax.jit(grads(False))(*args)):
+        assert operands[:6] == [(1,), wide, wide, wide, wide, rows], name
+        assert (operands[6] == kept) if reads else (results[6] == kept), name
+        assert kept not in (results if reads else operands[:6]), name
+    assert calls["kda_chunk_backward"][1] == [wide] * 4 + [rows]
+    if h % 2 == 0:
+        # Two heads against the ``jax.numpy`` path, value and gradients:
+        # ``test_chunked_is_the_token_by_token_scan``.
+        return
+    for name, a, b in zip(
+            NAMES,
+            traced_once.lower().compile(compiler_options=CHEAPLY)(*args),
+            _run_once(grads(False), *args)):
         assert _rel(a, b) < 2e-5, name
 
 
@@ -407,30 +477,53 @@ def _local(dtype, h=2, chunks=3, d_k=128, d_v=128, seed=0):
     args = _chunked((*(a.astype(dtype) for a in (q, k, v)), g, beta), 64)
     rng = np.random.default_rng(seed + 1)
     state = jnp.asarray(rng.standard_normal((1, h, d_k, d_v)), jnp.float32)
-    return kda_ops._chunk_local_jnp(*args), state
+    return jax.jit(kda_ops._chunk_local_jnp)(*args), state
 
 
 @pytest.mark.parametrize("dtype,tol,d_tol", [
     (jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 2e-2, 3e-2),
 ], ids=["float32", "bfloat16"])
 def test_the_state_kernels_are_the_jnp_recurrence(dtype, tol, d_tol):
-    """``across`` against ``_across`` on the same six chunk-local results
-    and a non-zero entering state: ``o``, the state left and all seven
-    cotangents, the bfloat16 case at the tolerance
-    ``test_the_kernels_take_bfloat16_as_the_jnp_form_does`` uses."""
+    """The two state kernels against ``_across`` on the same six
+    chunk-local results and a non-zero entering state: ``o`` (written in
+    the model's layout by the forward pass's call, chunk-major by the
+    rebuild's), the state left and all seven cotangents, the bfloat16 case
+    at the tolerance ``test_the_kernels_take_bfloat16_as_the_jnp_form_does``
+    uses."""
     local, state = _local(dtype)
-    want, vjp = jax.vjp(
-        lambda *a: kda_ops._across(a[:6], a[6], dtype), *local, state)
-    got, kernel_vjp = jax.vjp(
-        lambda *a: kda_ops.across(a[:6], a[6]), *local, state)
+    (_, n, h, c, d_v), where = local[0].shape, None
+    where = kda_ops.Segment(jnp.zeros((), jnp.int32), n, c)
+    rng = np.random.default_rng(2)
+    cotangents = (
+        jnp.asarray(rng.standard_normal((1, n * c, h, d_v)), dtype),
+        jnp.asarray(rng.standard_normal(state.shape), jnp.float32))
+
+    @jax.jit
+    def plain(*a):
+        want, vjp = jax.vjp(
+            lambda *a: kda_ops._across(a[:6], a[6], dtype), *a)
+        return want, vjp(cotangents)
+
+    @jax.jit
+    def kernels(*a):
+        out, left = kda_ops._state_forward_call(
+            where, a[:6], a[6], True,
+            jnp.zeros((1, n * c, h * d_v), dtype))
+        again, states, w, _ = kda_ops._state_forward_call(
+            where, a[:6], a[6], True)
+        d_all = kda_ops._state_backward_call(
+            where, (*a[1:6], states, w),
+            cotangents[0].reshape(out.shape), cotangents[1], True)
+        return (out.reshape(1, n * c, h, d_v), left), again, d_all
+
+    want, d_want = plain(*local, state)
+    got, again, d_got = kernels(*local, state)
+    np.testing.assert_array_equal(
+        _as_the_model(again).reshape(got[0].shape), got[0])
     for a, b in zip(got, want):
         assert a.shape == b.shape and a.dtype == b.dtype
         assert _rel(a.astype(jnp.float32), b.astype(jnp.float32)) < tol
-    rng = np.random.default_rng(2)
-    cotangents = tuple(
-        jnp.asarray(rng.standard_normal(a.shape), a.dtype) for a in want
-    )
-    for name, a, b in zip(LOCAL, kernel_vjp(cotangents), vjp(cotangents)):
+    for name, a, b in zip(LOCAL, d_got, d_want):
         assert a.shape == b.shape and a.dtype == b.dtype, name
         assert _rel(a.astype(jnp.float32), b.astype(jnp.float32)) < d_tol, name
 
@@ -444,14 +537,20 @@ def test_the_kernels_carry_a_state_from_segment_to_segment(strength, tol,
     before it left, and the state's cotangent comes back the same way;
     output and gradients against the token-by-token scan."""
     monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", 2)
-    args = _inputs(b=2, s=128, h=3, d_k=16, d_v=8, strength=strength)
-    fn = lambda *a: kda_chunked(*a, 16, kernels=True)
-    calls = _kernel_calls(jax.make_jaxpr(jax.grad(
-        lambda *a: jnp.sum(fn(*a))))(*args).jaxpr)
+    args, (want, d_want) = _by_the_scan(
+        b=2, s=128, h=3, d_k=16, d_v=8, strength=strength)
+    weights = jnp.cos(jnp.arange(8, dtype=jnp.float32))
+
+    def run(*a):
+        out, vjp = jax.vjp(lambda *a: kda_chunked(*a, 16, kernels=True), *a)
+        return out, vjp(jnp.broadcast_to(weights, out.shape))
+
+    traced = jax.jit(run).trace(*args)
+    calls = _kernel_calls(traced.jaxpr.jaxpr)
     assert calls["kda_state_backward"][0][-1] == (2, 3, 16, 8)
-    assert _rel(jax.jit(fn)(*args), jax.jit(kda_recurrent)(*args)) < tol
-    for name, a, b in zip(NAMES, _grads(fn, args),
-                          _grads(kda_recurrent, args)):
+    got, d_got = traced.lower().compile(compiler_options=CHEAPLY)(*args)
+    assert _rel(got, want) < tol
+    for name, a, b in zip(NAMES, d_got, d_want):
         assert bool(jnp.all(jnp.isfinite(a))), name
         assert _rel(a, b) < 3 * tol, name
 
@@ -463,32 +562,50 @@ def test_the_kernels_carry_a_state_from_segment_to_segment(strength, tol,
     (12, 12), (4, 4), (3, 3),
 ])
 def test_heads_a_grid_step_divide_the_head_count(h, heads):
-    """``HEADS_A_STEP`` or a divisor of the head count (as ``_call``'s
-    ``gcd``), and the grid the kernels run on says so: (sequences, groups
-    of heads, chunks), the state's block a group's."""
+    """``HEADS_A_STEP`` or a divisor of the head count, and the grid the
+    kernels run on says so: (sequences, groups of heads, chunks), the
+    state's block a group's, ``o``'s a chunk's rows of the group's lanes
+    in the model's own array."""
     assert kda_ops.HEADS_A_STEP == 8
-    assert kda_ops.state_heads(h) == heads and h % heads == 0
+    assert kda_ops.step_heads(h, kda_ops.HEADS_A_STEP) == heads
+    assert h % heads == 0
     like = jax.ShapeDtypeStruct
     b, n, c, d = 2, 5, 16, 8
     local = (like((b, n, h, c, d), jnp.float32),) * 2 + (
         like((b, n, h, c, c), jnp.float32), like((b, n, h, c, d), jnp.float32),
         like((b, n, h, c, d), jnp.float32), like((b, n, h, d), jnp.float32))
-    jaxpr = jax.make_jaxpr(lambda *a: kda_ops.across(a[:6], a[6]))(
-        *local, like((b, h, d, d), jnp.float32)).jaxpr
+    where = kda_ops.Segment(jnp.zeros((), jnp.int32), n, c)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: kda_ops._state_forward_call(where, a[:6], a[6], True, a[7])
+    )(*local, like((b, h, d, d), jnp.float32),
+      like((b, 3 * n * c, h * d), jnp.float32)).jaxpr
     (call,) = _eqns(jaxpr, "pallas_call")
     assert call.params["grid_mapping"].grid == (b, h // heads, n)
-    blocks = [tuple(m.block_shape) for m in
-              call.params["grid_mapping"].block_mappings]
-    assert [len(shape) for shape in blocks] == [5, 5, 5, 5, 5, 4, 4, 5, 4]
-    assert all(heads in [getattr(size, "block_size", size) for size in shape]
-               for shape in blocks)
+    blocks = [tuple(size if isinstance(size, int)
+                    else getattr(size, "block_size", None)
+                    for size in m.block_shape)
+              for m in call.params["grid_mapping"].block_mappings]
+    # The six and the state; the array written into, whole and nowhere in
+    # VMEM; ``o``'s block and the state left.
+    assert [len(shape) for shape in blocks] == [5, 5, 5, 5, 5, 4, 4, 3, 3, 4]
+    assert blocks[7] == (b, 3 * n * c, h * d)
+    assert blocks[8] == (None, c, heads * d)
+    assert all(heads in shape for shape in blocks[:7] + blocks[9:])
+
+
+def _state_walks(jaxpr):
+    """The result shapes of a jaxpr's ``kda_state_forward`` calls."""
+    return [[tuple(v.aval.shape) for v in e.outvars]
+            for e in _eqns(jaxpr, "pallas_call")
+            if e.params["name"] == "kda_state_forward"]
 
 
 def test_only_the_rebuild_keeps_a_segments_states():
-    """The forward pass's call of ``kda_state_forward`` writes ``o`` and
-    the state left; under differentiation (the backward's rebuild of ONE
-    segment) it also writes that segment's entering states, transposed,
-    and ``w``, float32: at the cell's shape 67 MB and 34 MB a segment."""
+    """The forward pass's call of ``kda_state_forward`` writes ``o`` into
+    the sequence's array and the state left; under differentiation (the
+    backward's rebuild of ONE segment) it also writes that segment's
+    entering states, transposed, and ``w``, float32: at the cell's shape
+    67 MB and 34 MB a segment."""
     like = jax.ShapeDtypeStruct
     b, n, h, c, d = 1, 32, 32, 64, 128
     tile = like((b, n, h, c, d), jnp.float32)
@@ -496,25 +613,30 @@ def test_only_the_rebuild_keeps_a_segments_states():
              like((b, n, h, c, d), jnp.bfloat16), tile,
              like((b, n, h, d), jnp.float32))
     state = like((b, h, d, d), jnp.float32)
+    where = kda_ops.Segment(jnp.zeros((), jnp.int32), n, c)
+    sequence = like((b, 8 * n * c, h * d), jnp.bfloat16)
     shapes = {
         keeps: [(a.shape, a.dtype) for a in jax.eval_shape(
-            lambda *a: kda_ops._state_forward_call(a[:6], a[6], True, keeps),
-            *local, state)]
+            lambda *a: kda_ops._state_forward_call(
+                where, a[:6], a[6], True, *a[7:]),
+            *local, state, *(() if keeps else (sequence,)))]
         for keeps in (False, True)
     }
     out, left = ((b, n, h, c, d), jnp.bfloat16), ((b, h, d, d), jnp.float32)
-    assert shapes[False] == [out, left]
+    assert shapes[False] == [(sequence.shape, jnp.bfloat16), left]
     assert shapes[True] == [
         out, ((b, n, h, d, d), jnp.float32), ((b, n, h, c, d), jnp.float32),
         left]
     assert 4 * b * n * h * d * d == 67_108_864
-    forward, vjp = (
-        _kernel_calls(jax.make_jaxpr(fn)(*local, state).jaxpr)
-        for fn in (lambda *a: kda_ops.across(a[:6], a[6]),
-                   lambda *a: jax.vjp(kda_ops.across, a[:6], a[6])[0])
+    args = _inputs(b=1, s=32, h=2, d_k=8, d_v=8)
+    forward, grad = (
+        _state_walks(jax.make_jaxpr(fn)(*args).jaxpr)
+        for fn in (lambda *a: kda_chunked(*a, 16, kernels=True),
+                   jax.grad(lambda *a: jnp.sum(
+                       kda_chunked(*a, 16, kernels=True))))
     )
-    assert len(forward["kda_state_forward"][1]) == 2
-    assert len(vjp["kda_state_forward"][1]) == 4
+    assert [len(results) for results in forward] == [2]
+    assert [len(results) for results in grad] == [2, 4]
 
 
 @pytest.mark.parametrize("kernel,float32_dots,rounded_dots", [
@@ -527,15 +649,15 @@ def test_the_states_products_are_float32_at_the_highest_precision(
     their four transposes) is float32 × float32 at ``Precision.HIGHEST``;
     the output products and their transposes multiply bfloat16 operands
     and accumulate in float32."""
-    local, state = _local(jnp.bfloat16, chunks=1)
-    jaxpr = jax.make_jaxpr(
-        lambda *a: jax.vjp(kda_ops.across, a[:6], a[6])[1](
-            (jnp.ones((1, 64, 2, 128), jnp.bfloat16), a[6]))
-    )(*local, state).jaxpr
-    (call,) = [e for e in _eqns(jaxpr, "pallas_call")
-               if e.params["name"] == kernel]
+    q, k, v, g, beta = _inputs(b=1, s=64, d_k=128, d_v=128)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+        kda_chunked(*a, 64, kernels=True).astype(jnp.float32))))(
+        *(a.astype(jnp.bfloat16) for a in (q, k, v)), g, beta).jaxpr
+    call = [e for e in _eqns(jaxpr, "pallas_call")
+            if e.params["name"] == kernel][-1]
     dots = _eqns(call.params["jaxpr"], "dot_general")
-    per_head = len(dots) // kda_ops.state_heads(2)
+    heads = kda_ops.step_heads(2, kda_ops.HEADS_A_STEP)
+    per_head = len(dots) // heads
     assert per_head == float32_dots + rounded_dots
     kinds = []
     for e in dots:
@@ -548,7 +670,134 @@ def test_the_states_products_are_float32_at_the_highest_precision(
             assert dtypes == {jnp.dtype(jnp.bfloat16)}
             assert e.params["preferred_element_type"] == jnp.float32
         kinds.append(dtypes.pop())
-    assert kinds.count(jnp.float32) == float32_dots * kda_ops.state_heads(2)
+    assert kinds.count(jnp.float32) == float32_dots * heads
+
+
+# ---------------------------------- the model's own layout, read in place
+
+def _a_segment_at_a_time(chunk, d_out, *args):
+    """What the kernels' index maps and in-place writes are held to, the
+    walk as it was before them: every segment SLICED out of the sequence
+    by a ``lax.scan`` (a copy) and run as a sequence of its own — each
+    call told segment 0 and handed fresh arrays to write — the state
+    handed on, the results stacked and put together after the loop. The
+    same five kernels on the same tiles in the same order as
+    ``kda_chunked`` under ``KERNELS``. ``o`` and the five gradients under
+    ``o``'s cotangent ``d_out``."""
+    (b, s, h, d_k), v, beta = args[1].shape, args[2], args[4]
+    n = math.gcd(s // chunk, kda_ops.SEGMENT_CHUNKS)
+    where = kda_ops.Segment(jnp.zeros((), jnp.int32), n, chunk)
+
+    def forward(state, xs):
+        inputs = kda_ops._in_place(chunk, xs)[0]
+        *six, T = kda_ops._forward_call(
+            where, *inputs, True,
+            jnp.zeros(kda_ops.inverses_shape((b, n, h), chunk), jnp.float32))
+        out, left = kda_ops._state_forward_call(
+            where, six, state, True,
+            jnp.zeros((b, n * chunk, h * v.shape[-1]), v.dtype))
+        return left, (out, state, T)
+
+    def backward(d_state, xs):
+        *xs, d_o, state, T = xs
+        inputs, (d_o,), _, _ = kda_ops._in_place(chunk, xs, d_o)
+        six = kda_ops._forward_call(where, *inputs, True, inverses=T)
+        _, states, w, _ = kda_ops._state_forward_call(where, six, state, True)
+        *d_six, d_state = kda_ops._state_backward_call(
+            where, (*six[1:], states, w), d_o, d_state, True)
+        return d_state, kda_ops._backward_call(
+            where, inputs, T, d_six,
+            tuple(jnp.zeros_like(a) for a in inputs), True)
+
+    state = jnp.zeros((b, h, d_k, v.shape[-1]), jnp.float32)
+    _, (out, entered, kept) = jax.lax.scan(
+        forward, state, kda_ops._segments(chunk, *args))
+    _, grads = jax.lax.scan(
+        backward, state,
+        (*kda_ops._segments(chunk, *args, d_out), entered, kept),
+        reverse=True)
+    *wide, d_rows = map(kda_ops._whole, grads)
+    return kda_ops._whole(out).reshape(v.shape), (
+        *(a.reshape(x.shape) for a, x in zip(wide, args)),
+        jnp.moveaxis(d_rows, 3, 2).reshape(beta.shape))
+
+
+# float32 arrays: a few ulps of the largest entry (XLA's CPU backend may
+# contract a product and a sum differently by what surrounds them, as
+# ``ops/sparse_attention.py`` records); bfloat16 arrays: one rounding.
+ULPS = {jnp.dtype(jnp.float32): 4 * 2.0 ** -23,
+        jnp.dtype(jnp.bfloat16): 2.0 ** -8}
+
+
+@pytest.mark.parametrize("dtype,h,segment", [
+    (jnp.float32, 8, 1), (jnp.bfloat16, 16, 32),
+], ids=["float32-8 heads-two segments", "bfloat16-16 heads-one segment"])
+def test_in_place_is_a_segment_sliced_out_and_put_back(dtype, h, segment,
+                                                       monkeypatch):
+    """``kda_chunked`` under ``KERNELS`` — blocks of [b, s, h · d] found
+    by the segment's index, ``o``, ``T`` and the gradients written into
+    arrays the loops carry — against the same kernels on copies of one
+    segment at a time."""
+    monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", segment)
+    q, k, v, g, beta = _inputs(b=1, s=32, h=h, d_k=16, d_v=16)
+    args = (*(a.astype(dtype) for a in (q, k, v)), g, beta)
+    d_out = jnp.asarray(np.random.default_rng(3).standard_normal(v.shape),
+                        dtype)
+
+    def in_place(*a):
+        out, vjp = jax.vjp(lambda *a: kda_chunked(*a, 16, kernels=True), *a)
+        return out, vjp(d_out)
+
+    got, d_got = _run_once(in_place, *args)
+    want, d_want = _run_once(
+        lambda *a: _a_segment_at_a_time(16, d_out, *a), *args)
+    for name, a, b in zip(("o", *NAMES), (got, *d_got), (want, *d_want)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert float(jnp.max(jnp.abs(a - b))) <= ULPS[jnp.dtype(d_want[
+            NAMES.index(name)].dtype if name != "o" else dtype)] * float(
+                jnp.max(jnp.abs(b))), name
+
+
+@pytest.mark.parametrize("segment", [32, 2], ids=["one segment", "four"])
+def test_under_the_kernels_nothing_of_the_sequences_size_is_moved(
+        segment, monkeypatch):
+    """The traced gradient of ``kda_chunked`` under ``KERNELS``, eight
+    chunks of 32 heads: outside the kernels' bodies no ``transpose``,
+    ``copy``, ``concatenate``, ``dynamic_slice``, ``dynamic_update_slice``
+    or ``gather`` reads or writes an array as large as ``q`` (``beta``'s
+    rows, 1/128 of it at the cell's width, are turned once each way), and
+    the loops over the segments scan their indices and the states they
+    were entered with, nothing else."""
+    monkeypatch.setattr(kda_ops, "SEGMENT_CHUNKS", segment)
+    like = jax.ShapeDtypeStruct
+    wide = like((1, 128, 32, 128), jnp.bfloat16)
+    args = (wide, wide, wide, like(wide.shape, jnp.float32),
+            like((1, 128, 32), jnp.float32))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(
+            kda_chunked(*a, 16, kernels=True).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4)))(*args).jaxpr
+    moved = [
+        e for e in _eqns(jaxpr, "")
+        if e.primitive.name in (
+            "transpose", "copy", "copy_p", "concatenate", "dynamic_slice",
+            "dynamic_update_slice", "gather", "scatter")
+        and any(math.prod(x.aval.shape) >= math.prod(wide.shape)
+                for x in (*e.invars, *e.outvars) if hasattr(x, "aval"))
+    ]
+    assert not moved, moved
+    # (A kernel's loop over a group's heads is a scan too, in its body.)
+    loops = [e for e in _eqns(jaxpr, "scan")
+             if _eqns(e.params["jaxpr"].jaxpr, "pallas_call")]
+    assert len(loops) == 2
+    count = 128 // 16 // min(segment, 8)
+    for loop in loops:
+        scanned = loop.invars[
+            loop.params["num_consts"] + loop.params["num_carry"]:]
+        assert sorted(tuple(x.aval.shape) for x in scanned) == sorted(
+            [(count,)] + [(count, 1, 32, 128, 128)] * loop.params["reverse"])
 
 
 # ------------------------------------------- what feeds the rule: QKVConv

@@ -36,6 +36,7 @@ from raydp_tpu.models.transformer import (
 )
 from raydp_tpu.train.losses import lm_crossentropy
 from raydp_tpu.utils.profiling import metrics
+from tests.test_gdn import _run_once
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 128
@@ -75,9 +76,8 @@ def builder():
 
 
 def _init(model, *args):
-    variables = jax.jit(
-        lambda: nn.unbox(model.init(jax.random.PRNGKey(0), *args))
-    )()
+    variables = _run_once(
+        lambda: nn.unbox(model.init(jax.random.PRNGKey(0), *args)))
     return {k: variables[k] for k in COLLECTIONS if k in variables}
 
 
@@ -105,11 +105,11 @@ def _rel(got, want):
 def logits(builder, tiny):
     """(program, reference) logits of the seeded model."""
     model, variables, ids = tiny
-    got = jax.jit(
-        lambda v: model.apply(v, ids, mutable=[moe_module.STATS])[0]
-    )(variables)
-    return got, jax.jit(
-        lambda v: builder.reference_logits(v, ids, SIZES))(variables)
+    got = _run_once(
+        lambda v: model.apply(v, ids, mutable=[moe_module.STATS])[0],
+        variables)
+    return got, _run_once(
+        lambda v: builder.reference_logits(v, ids, SIZES), variables)
 
 
 # ---------------------------------------------- program against reference
@@ -183,10 +183,9 @@ def test_loss_and_gradients_match_the_plain_reference(builder, tiny):
             {"params": params, **rest}, ids, mutable=[moe_module.STATS])[0]
         return lm_crossentropy(out, ids)
 
-    got, grads = jax.jit(jax.value_and_grad(loss))(variables["params"])
-    want, want_grads = jax.jit(
-        lambda v: builder.reference_loss_and_grads(v, ids, SIZES)
-    )(variables)
+    got, grads = _run_once(jax.value_and_grad(loss), variables["params"])
+    want, want_grads = _run_once(
+        lambda v: builder.reference_loss_and_grads(v, ids, SIZES), variables)
     assert abs(float(got) - float(want)) < 1e-5
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     want_flat = dict(jax.tree_util.tree_flatten_with_path(
@@ -223,8 +222,8 @@ def test_tolerance_refuses_a_departure_from_the_mathematics(
     (and the chip's check lists it as unseen)."""
     _, variables, ids = tiny
     got, want = logits
-    moved = _rel(jax.jit(lambda v: builder.reference_logits(
-        v, ids, SIZES, depart=depart))(variables), want)
+    moved = _rel(_run_once(lambda v: builder.reference_logits(
+        v, ids, SIZES, depart=depart), variables), want)
     if depart == "decay_clamped":
         assert moved > 100 * _rel(got, want)
         assert depart in builder.UNSEEN_ON_THE_CHIP
@@ -237,8 +236,8 @@ def test_a_bfloat16_trunk_is_within_and_float8_outside(builder, tiny,
     _, variables, ids = tiny
     _, want = logits
     for trunk, inside in ((jnp.bfloat16, None), (jnp.float8_e4m3fn, False)):
-        moved = _rel(jax.jit(lambda v: builder.reference_logits(
-            v, ids, SIZES, trunk=trunk))(variables), want)
+        moved = _rel(_run_once(lambda v: builder.reference_logits(
+            v, ids, SIZES, trunk=trunk), variables), want)
         if inside is False:
             assert moved > builder.TOLERANCE
         else:

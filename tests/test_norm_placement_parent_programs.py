@@ -133,11 +133,16 @@ PARENT = {
         (67664, "9c8ee669665126c21dd8b8f7a042b4789b96a044267d1e44c25635f1638"
                 "4c4be")),
 }
+# The kernels' program is PR 68's, which changed it on purpose (the five
+# kernels read and write the model's [b, s, h · d] arrays in place, the
+# segment's index a scalar: ``ops/kda.KERNELS`` owns its layout and its
+# loop); PR 63's parent gave (197576, "5d40005d...8864c6"). The plain
+# form's is still that parent's.
 PARENT_SCANS = {
     "plain": (77236, "ff82bcc18afa9eca43db3de7feecc45de495e7aa137ea6be0206f4"
                      "e674567bda"),
-    "kernels": (197576, "5d40005d52f4b2ac3321727a4c2c3aa3bb7a0a9a272498a777f"
-                        "effe58a8864c6"),
+    "kernels": (191198, "77ee945f15d3c0ca56eff3ee1b1d1477d35bb57ea3e122ff372"
+                        "9290c111ef814"),
 }
 
 

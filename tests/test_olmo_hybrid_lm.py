@@ -28,6 +28,7 @@ from raydp_tpu.models.transformer import MIXERS, kept_names
 from raydp_tpu.ops import kda as kda_ops
 from raydp_tpu.train.losses import lm_crossentropy
 from raydp_tpu.utils.profiling import metrics
+from tests.test_gdn import _run_once
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 80                    # five chunks of gcd(64, 80) = 16
@@ -66,8 +67,8 @@ def tiny(builder):
     model = CausalLM(builder.model_config(SIZES))
     ids = jnp.asarray(np.random.default_rng(0).integers(
         0, SIZES["vocab_size"], (2, SEQ)).astype(np.int32))
-    variables = jax.jit(
-        lambda: nn.unbox(model.init(jax.random.PRNGKey(0), ids)))()
+    variables = _run_once(
+        lambda: nn.unbox(model.init(jax.random.PRNGKey(0), ids)))
     enc = variables["params"]["encoder"]
     for i in range(3):
         decay = enc[f"block_{i}"]["gdn"]["decay"]
@@ -83,9 +84,9 @@ def _rel(got, want):
 def logits(builder, tiny):
     """(program, reference) logits of the seeded model."""
     model, variables, ids = tiny
-    got = jax.jit(lambda v: model.apply(v, ids))(variables)
-    return got, jax.jit(
-        lambda v: builder.reference_logits(v, ids, SIZES))(variables)
+    got = _run_once(lambda v: model.apply(v, ids), variables)
+    return got, _run_once(
+        lambda v: builder.reference_logits(v, ids, SIZES), variables)
 
 
 # ---------------------------------------------- program against reference
@@ -151,10 +152,9 @@ def test_loss_and_gradients_match_the_plain_reference(builder, tiny,
     def loss(params):
         return lm_crossentropy(model.apply({"params": params}, ids), ids)
 
-    got, grads = jax.jit(jax.value_and_grad(loss))(variables["params"])
-    want, want_grads = jax.jit(
-        lambda v: builder.reference_loss_and_grads(v, ids, SIZES)
-    )(variables)
+    got, grads = _run_once(jax.value_and_grad(loss), variables["params"])
+    want, want_grads = _run_once(
+        lambda v: builder.reference_loss_and_grads(v, ids, SIZES), variables)
     assert abs(float(got) - float(want)) < 1e-5
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     want_flat = dict(jax.tree_util.tree_flatten_with_path(
@@ -189,8 +189,8 @@ def test_tolerance_refuses_a_departure_from_the_mathematics(
     chip's check lists it as unseen)."""
     _, variables, ids = tiny
     got, want = logits
-    moved = _rel(jax.jit(lambda v: builder.reference_logits(
-        v, ids, SIZES, depart=depart))(variables), want)
+    moved = _rel(_run_once(lambda v: builder.reference_logits(
+        v, ids, SIZES, depart=depart), variables), want)
     assert builder.TOLERANCE > 500 * _rel(got, want)
     if depart == "state_bfloat16":
         assert moved > 500 * _rel(got, want)
@@ -204,8 +204,8 @@ def test_a_bfloat16_trunk_is_seen_and_float8_is_outside(builder, tiny,
     _, variables, ids = tiny
     _, want = logits
     for trunk, outside in ((jnp.bfloat16, None), (jnp.float8_e4m3fn, True)):
-        moved = _rel(jax.jit(lambda v: builder.reference_logits(
-            v, ids, SIZES, trunk=trunk))(variables), want)
+        moved = _rel(_run_once(lambda v: builder.reference_logits(
+            v, ids, SIZES, trunk=trunk), variables), want)
         if outside:
             assert moved > builder.TOLERANCE
         else:
@@ -245,9 +245,11 @@ def test_the_mixer_is_the_references_gdn(builder, seq):
     variables = nn.unbox(jax.jit(mixer.init)(jax.random.PRNGKey(2), x))
     p = variables["params"]
     p["decay"]["dt_bias"] = p["decay"]["dt_bias"] + 2.0
-    got = mixer.apply(variables, x)
+    # Each side one compiled program: op by op they take twice as long.
+    got = jax.jit(mixer.apply)(variables, x)
     with jax.default_matmul_precision("highest"):
-        want = builder._gdn(p, x[0], SIZES, lambda a: a, None)
+        want = jax.jit(
+            lambda p, x: builder._gdn(p, x, SIZES, lambda a: a, None))(p, x[0])
     assert cfg.gdn.scan_chunk(seq) == {80: 16, 64: 64, 33: 1}[seq]
     assert _rel(got[0], want) < 2e-5
 
@@ -332,13 +334,13 @@ def test_outputs_only_is_x_plus_norm_of_f_of_x(tiny):
     enc = variables["params"]["encoder"]
     x = enc["tok_embed"]["embedding"][ids]
     mixer = GatedDeltaMixer(model.cfg)
-    branch = mixer.apply({"params": enc["block_0"]["gdn"]}, x)
+    branch = jax.jit(mixer.apply)({"params": enc["block_0"]["gdn"]}, x)
     normed = branch / jnp.sqrt(
         jnp.mean(branch * branch, -1, keepdims=True) + 1e-6)
     want = x + normed * enc["block_0"]["ln_gdn_out"]["scale"]
-    _, state = model.apply(
-        variables, ids, capture_intermediates=lambda m, _: m.name in (
-            "ln_gdn_out",), mutable=["intermediates"])
+    _, state = jax.jit(lambda v: model.apply(
+        v, ids, capture_intermediates=lambda m, _: m.name in (
+            "ln_gdn_out",), mutable=["intermediates"]))(variables)
     got = state["intermediates"]["encoder"]["block_0"]["ln_gdn_out"][
         "__call__"][0]
     np.testing.assert_allclose(x + got, want, rtol=1e-5, atol=1e-5)

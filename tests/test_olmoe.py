@@ -642,48 +642,72 @@ def test_scalar_decay_kernels_compile_for_the_chip_at_olmo_hybrids_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
 
 
+@pytest.fixture(scope="module")
+def kimi_linears_scan(one_chip):
+    """The compiled gradient of one layer's scan at Kimi Linear's shape,
+    as text and with its memory analysis: one sequence of 16,384 tokens,
+    32 heads of 128 / 128 in chunks of 64, the operands as the model holds
+    them (``QKVConv`` and the dense layers write [b, s, h · d]; the mixer
+    hands ``kda_chunked`` a reshape of that, and reads ``o`` through one)."""
+    from raydp_tpu.ops import kda
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    wide = jax.ShapeDtypeStruct((1, 16384, 32 * 128), bf16)
+    args = (wide, wide, wide,
+            jax.ShapeDtypeStruct((1, 16384, 32 * 128), f32),
+            jax.ShapeDtypeStruct((1, 16384, 32), f32))
+
+    def loss(*a):
+        *a, beta = a
+        heads = (a.reshape(1, 16384, 32, 128) for a in a)
+        o = kda.kda_chunked(*heads, beta, 64).reshape(1, 16384, -1)
+        return jnp.sum(o.astype(f32) ** 2)
+
+    default_backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+            *_on(one_chip, args)
+        ).compile()
+    finally:
+        jax.default_backend = default_backend
+    return compiled.as_text(), compiled.memory_analysis()
+
+
 def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
-        one_chip, monkeypatch):
+        kimi_linears_scan):
     """One sequence of 16,384 tokens, 32 heads of 128 / 128 in chunks of
     64, forward and backward: Mosaic accepts the kernels (six calls: the
     chunk-local step's forward, which writes every chunk's inverse ``T``,
     the backward's rebuild, which reads it and inverts nothing, and the
     gradient; the walk over the chunk states in the forward pass, again
     in the rebuild, where it also writes a segment's states and ``w``,
-    and backwards), the only loops left are the two walks over the
-    segments, and what the ``jax.numpy`` form wrote for every segment is
+    and backwards; and before each loop one that does nothing and gives
+    it the arrays to write into), the only loops left are the two walks
+    over the segments, and what the ``jax.numpy`` form wrote for every
+    segment is
     in no buffer: nothing with the six levels' axis, and of float32
     [chunk, chunk] arrays none (the output product's gradient of ``P`` is
     the state kernel's, rounded in VMEM). ``T`` is kept as
-    [segments, 1, 32 chunks, 32 heads, 32, 128] float32, a [64, 64] tile's
-    two row blocks side by side: 134 MB a layer, where a float32
-    [..., 64, 64] array, its last dimension padded to 128 lanes, is 268."""
+    [1, 256 chunks, 32 heads, 32, 128] float32, a [64, 64] tile's two row
+    blocks side by side: 134 MB a layer, where a float32 [..., 64, 64]
+    array, its last dimension padded to 128 lanes, is 268."""
     import re
 
     from raydp_tpu.ops import kda
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert kda.uses_kernels(128, 128, 64)
-    bf16, f32 = jnp.bfloat16, jnp.float32
-    wide = jax.ShapeDtypeStruct((1, 16384, 32, 128), bf16)
-    args = (wide, wide, wide,
-            jax.ShapeDtypeStruct((1, 16384, 32, 128), f32),
-            jax.ShapeDtypeStruct((1, 16384, 32), f32))
-
-    def loss(*a):
-        return jnp.sum(kda.kda_chunked(*a, 64).astype(f32) ** 2)
-
-    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
-        *_on(one_chip, args)
-    ).compile()
-    hlo = compiled.as_text()
+    hlo, memory = kimi_linears_scan
     calls = [line for line in hlo.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
-    assert len(calls) == hlo.count("tpu_custom_call") == 6
+    assert len(calls) == hlo.count("tpu_custom_call") == 8
     named = [re.search(r"%(kda_[a-z_]+)", line).group(1) for line in calls]
+    # ``kda_unwritten`` does nothing: what a loop writes into, allocated
+    # where the loop starts and not where the program does.
     assert sorted(named) == [
         "kda_chunk_backward", "kda_chunk_forward", "kda_chunk_rebuild",
-        "kda_state_backward", "kda_state_forward", "kda_state_forward"]
+        "kda_state_backward", "kda_state_forward", "kda_state_forward",
+        "kda_unwritten", "kda_unwritten"]
     # The forward pass's walk writes o and the state left; the rebuild's
     # also a segment's entering states and w, which the backward reads.
     a_segments_states = "f32[1,32,32,128,128]"
@@ -694,11 +718,6 @@ def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
     # them: no product on the [..., 128, 128] state is left to XLA.
     loops = re.findall(r"= \([^\n]*\) while\(", hlo)
     assert len(loops) == 2, len(loops)
-    bodies = re.findall(r"while\([^\n]*body=%([\w.]+)", hlo)
-    assert len(bodies) == 2
-    for body in bodies:
-        text = hlo.split(f"\n%{body} (")[1].split("\n}\n")[0]
-        assert " while(" not in text and "kda_state_" in text, body
     for line in hlo.splitlines():
         if re.search(r" (dot|convolution)\(", line):
             assert not re.search(r"f32\[[\d,]*128,128\]", line), line
@@ -709,26 +728,83 @@ def test_delta_rule_kernels_compile_for_the_chip_at_kimi_linears_shape(
     # The kept inverses: one array of all eight segments', written a
     # segment at a time by the forward kernel and read by the rebuild and
     # the gradient kernels, in tiles with no padded lane.
-    kept = kda.inverses_shape((8, 1, 32, 32), 64)
-    assert kept == (8, 1, 32, 32, 32, 128)
-    (layout,) = set(re.findall(
-        r"f32\[8,1,32,32,32,128\]\{[^}]*\}", hlo))
-    assert layout.endswith("{5,4,3,2,1,0:T(8,128)}"), layout
+    kept = kda.inverses_shape((1, 256, 32), 64)
+    assert kept == (1, 256, 32, 32, 128)
+    (layout,) = set(re.findall(r"f32\[1,256,32,32,128\]\{[^}]*T[^}]*\}", hlo))
+    assert layout.endswith("{4,3,2,1,0:T(8,128)}"), layout
     assert 4 * int(np.prod(kept)) == 134_217_728
-    a_segment = "f32[1024,32,128]{2,1,0}"
-    for name, reads in (("forward", False), ("rebuild", True),
-                        ("backward", True)):
-        (call,) = [line for line in hlo.splitlines()
-                   if f"%kda_chunk_{name}" in line
-                   and "tpu_custom_call" in line]
-        operands = call.split("operand_layout_constraints=")[1]
-        assert (a_segment in operands) is reads, name
-    # 0.79 GB with the 134 MB of T and a segment's states and w from the
-    # state kernel (0.94 with the loop's stacked residuals at PR 46, 0.69
-    # at PR 45, which kept no T); the jax.numpy form's temporaries were
-    # 2.00 GiB (PR 44).
-    temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 1.0e9, temp
+    for name in ("forward", "rebuild", "backward"):
+        (call,) = [line for line in calls if f"%kda_chunk_{name}" in line]
+        assert "f32[1,256,32,32,128]" in call, name
+    # 0.79 GB at PR 59, with the 134 MB of T and a segment's states and w
+    # from the state kernel (0.94 with the loop's stacked residuals at PR
+    # 46, 0.69 at PR 45, which kept no T); the jax.numpy form's
+    # temporaries were 2.00 GiB (PR 44).
+    assert memory.temp_size_in_bytes < 0.8e9, memory.temp_size_in_bytes
+
+
+def test_the_delta_rule_moves_nothing_as_large_as_q_at_kimi_linears_shape(
+        kimi_linears_scan):
+    """The same compiled gradient, read for what stands BETWEEN the
+    model's arrays and the kernels: the five kernels take ``q``, ``k``,
+    ``v``, ``g``, ``o``'s cotangent and write ``o`` and the four wide
+    gradients as [1, 16384, 4096] arrays of the whole sequence, the
+    segment's index a scalar operand of each call; the two loops' bodies
+    hold those calls, the state's copy and the entering states' slice or
+    update, and no other instruction of 4 MiB or more (``beta``'s rows are
+    2 MiB, turned once each way outside the loops); and nowhere in the
+    program is an array of ``q``'s size or more copied, transposed,
+    sliced, stacked or concatenated."""
+    import re
+
+    hlo, _ = kimi_linears_scan
+    calls = {}
+    for line in hlo.splitlines():
+        if "tpu_custom_call" in line and " custom-call(" in line:
+            # Of the two walks forward, the forward pass's (the first).
+            calls.setdefault(re.search(r"%(kda_[a-z_]+)", line).group(1), line)
+    sequence = r"\[1,16384,4096\]"
+    assert len(re.findall("bf16" + sequence, calls["kda_chunk_forward"])) == 3
+    assert len(re.findall("f32" + sequence, calls["kda_chunk_forward"])) == 1
+    # Read: q, k, v, g; written in place: dq, dk, dv, dg (operand and result).
+    assert len(re.findall(sequence, calls["kda_chunk_backward"])) >= 12
+    for name in ("kda_state_forward", "kda_state_backward"):
+        assert re.search("bf16" + sequence, calls[name]), name
+    for name, line in calls.items():
+        assert name == "kda_unwritten" or re.search(
+            r"s32\[1\]", line.split(" custom-call(")[1]), name
+
+    def mib(line):
+        result = line.split(" = ")[1].split(" ")[0]
+        return max((
+            int(np.prod([int(n) for n in dims.split(",") if n] or [1]))
+            * {"f32": 4, "bf16": 2, "s32": 4, "pred": 1, "u32": 4}[dtype]
+            for dtype, dims in re.findall(
+                r"(f32|bf16|s32|pred|u32)\[([\d,]*)\]", result)), default=0
+        ) / 2 ** 20
+
+    kinds = ("copy|transpose|dynamic-slice|dynamic-update-slice|concatenate"
+             "|gather|scatter")
+    moves = rf" ({kinds}|fusion)\("
+    bodies = re.findall(r"while\([^\n]*body=%([\w.]+)", hlo)
+    assert len(bodies) == 2
+    for body in bodies:
+        text = hlo.split(f"\n%{body} (")[1].split("\n}\n")[0]
+        assert " while(" not in text and "kda_state_" in text, body
+        large = [line.strip()[:160] for line in text.splitlines()
+                 if re.search(moves, line) and mib(line) >= 4
+                 and "128,128]" not in line.split(" = ")[1].split(" ")[0]]
+        assert not large, large
+    # What the loops write into comes from a kernel handed an array they
+    # read: an allocation with no operand is scheduled where the program
+    # starts, and held from there.
+    assert not [line for line in hlo.splitlines()
+                if "AllocateBuffer" in line and mib(line) >= 64]
+    entry = hlo.split("\nENTRY ")[1]
+    moved = [line.strip()[:160] for line in entry.splitlines()
+             if re.search(rf" ({kinds})\(", line)
+             and mib(line) >= 128]
+    assert not moved, moved
 
 
 def test_a_share_of_the_routed_layer_compiles_at_lfm2s_widths(
